@@ -3,7 +3,7 @@
 :mod:`graph` answers "which ops exist, under which scope"; this module
 answers **"where does this value come from and who consumes it"**. The
 builder inlines every sub-jaxpr the call-like primitives carry —
-``pjit`` / ``scan`` / ``while`` / ``cond`` / ``shard_map`` /
+``jit`` / ``scan`` / ``while`` / ``cond`` / ``shard_map`` /
 ``custom_jvp_call`` / ``custom_vjp_call`` / ``remat`` — binding inner
 jaxpr variables to the SAME value nodes as the outer operands, so a
 def-use chain crosses call boundaries the way data actually does. On top
@@ -34,7 +34,7 @@ import dataclasses
 from fnmatch import fnmatch
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
-import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr, Literal
 
 from perceiver_io_tpu.analysis import graph as G
 from perceiver_io_tpu.analysis.graph import _join_scope, _scope_of
@@ -72,9 +72,8 @@ class DfNode:
 # nested jaxpr — sort comparators, custom roots — stays an opaque node)
 CALL_PRIMS = frozenset(
     {
-        "pjit", "closed_call", "core_call", "remat", "checkpoint", "scan",
-        "while", "cond", "shard_map", "custom_jvp_call", "custom_vjp_call",
-        "custom_jvp_call_jaxpr", "custom_vjp_call_jaxpr", "custom_vjp_call_jaxpr_p",
+        "jit", "closed_call", "call", "remat2", "scan", "while", "cond",
+        "shard_map", "custom_jvp_call", "custom_vjp_call",
     }
 )
 
@@ -285,11 +284,11 @@ class Dataflow:
 # ------------------------------------------------------------------ builder
 
 
-def _as_body(value) -> Tuple[Optional[jax.core.Jaxpr], tuple]:
+def _as_body(value) -> Tuple[Optional[Jaxpr], tuple]:
     """``(jaxpr, consts)`` of a Jaxpr/ClosedJaxpr param value."""
-    if isinstance(value, jax.core.ClosedJaxpr):
+    if isinstance(value, ClosedJaxpr):
         return value.jaxpr, tuple(value.consts)
-    if isinstance(value, jax.core.Jaxpr):
+    if isinstance(value, Jaxpr):
         return value, ()
     return None, ()
 
@@ -297,7 +296,7 @@ def _as_body(value) -> Tuple[Optional[jax.core.Jaxpr], tuple]:
 class _Builder:
     def __init__(self):
         self.df = Dataflow()
-        self.env: Dict[Any, int] = {}  # jax.core.Var -> vid
+        self.env: Dict[Any, int] = {}  # Var -> vid
 
     # -- values -----------------------------------------------------------
 
@@ -313,7 +312,7 @@ class _Builder:
             self.df.loop_vids.add(dst)
 
     def read(self, atom) -> int:
-        if isinstance(atom, jax.core.Literal):
+        if isinstance(atom, Literal):
             return self.new_value(G._aval_info(atom), "literal", repr(atom.val))
         vid = self.env.get(atom)
         if vid is None:  # unbound var (defensive): treat as an input
@@ -326,7 +325,7 @@ class _Builder:
             return
         self.env[var] = vid
 
-    def bind_consts(self, jaxpr: jax.core.Jaxpr, consts: tuple, scope: str) -> None:
+    def bind_consts(self, jaxpr: Jaxpr, consts: tuple, scope: str) -> None:
         for cv, c in zip(jaxpr.constvars, consts):
             self.bind(cv, self.new_value(G._aval_info(cv), "const", scope))
 
@@ -367,7 +366,7 @@ class _Builder:
 
     # -- walking ----------------------------------------------------------
 
-    def walk(self, jaxpr: jax.core.Jaxpr, scope: str, depth: int, parent, region) -> None:
+    def walk(self, jaxpr: Jaxpr, scope: str, depth: int, parent, region) -> None:
         for eqn in jaxpr.eqns:
             eqn_scope = _join_scope(scope, _scope_of(eqn))
             prim = eqn.primitive.name
@@ -390,7 +389,7 @@ class _Builder:
             self.alias(src, dst)
 
     def _call_generic(self, eqn, scope, depth, parent, region, invals) -> None:
-        """pjit / remat / closed_call / custom_jvp / custom_vjp: one body,
+        """jit / remat / closed_call / custom_jvp / custom_vjp: one body,
         operands aligned to the body's trailing invars (consts-first calling
         conventions keep their leading operands as plain node inputs)."""
         body = consts = None
@@ -475,7 +474,7 @@ class _Builder:
         self._finish_call(eqn, node, [self.read(v) for v in body.outvars])
 
 
-def build(closed: jax.core.ClosedJaxpr) -> Dataflow:
+def build(closed: ClosedJaxpr) -> Dataflow:
     """The threaded value graph of a ``ClosedJaxpr`` (see :func:`analyze`
     for the trace-and-build convenience)."""
     b = _Builder()
@@ -658,12 +657,12 @@ def replicated_key_findings(df: Dataflow) -> List[ReplicatedKeyFinding]:
     for sm in df.nodes:
         if sm.primitive != "shard_map":
             continue
-        in_names = sm.params.get("in_names") or ()
+        in_specs = sm.params.get("in_specs") or ()
         replicated_keys = {
             vid
             for i, vid in enumerate(sm.invals)
-            if i < len(in_names)
-            and not in_names[i]
+            if i < len(in_specs)
+            and not any(in_specs[i])
             and is_key_like(df.values[vid].aval)
         }
         if not replicated_keys:
@@ -770,7 +769,7 @@ def propagate_shardings(
     never conflicts — missing a reshard is possible, a prediction always
     names a genuine layout break. ``shard_map`` interiors are per-shard
     programs and are skipped; region outputs take their layout from
-    ``out_names``.
+    ``out_specs``.
     """
     state: Dict[int, Dims] = {}
     for vid, spec in zip(df.input_vids, input_specs):
@@ -819,16 +818,12 @@ def propagate_shardings(
             continue  # per-shard interior: mesh layout does not apply
         prim = node.primitive
         if prim == "shard_map":
-            out_names = node.params.get("out_names") or ()
+            out_specs = node.params.get("out_specs") or ()
             for i, vid in enumerate(node.outvals):
                 aval = df.values[vid].aval
-                if aval is None or i >= len(out_names):
+                if aval is None or i >= len(out_specs):
                     continue
-                names = out_names[i] or {}
-                state[vid] = tuple(
-                    tuple(names[d]) if d in names and names[d] else None
-                    for d in range(len(aval.shape))
-                )
+                state[vid] = _spec_to_dims(out_specs[i], len(aval.shape))
             continue
         if prim in CALL_PRIMS:
             continue  # flow resolves through the threaded body aliases

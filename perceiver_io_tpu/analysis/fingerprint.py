@@ -593,28 +593,25 @@ def graphcheck_telemetry(
 ) -> dict:
     """The ``telemetry.graphcheck`` block for bench.py results: diff the two
     cheapest flagship programs against the committed contracts and record
-    the verdict. Mirrors ``graphlint_telemetry``'s contract — never raises;
-    a failure (or a missing contracts/ dir) is a recorded status, the hard
-    gate is ``tools/graphcheck.py`` / ``tasks.py perf``."""
-    try:
-        if contracts_dir is None:
-            contracts_dir = os.path.join(
-                os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-                "contracts",
-            )
-        from perceiver_io_tpu.analysis import ledger as L
+    the verdict. Like ``graphlint_telemetry``, a contract regression (or a
+    missing contracts/ dir) is a recorded status, while an exception inside
+    the check propagates: a gate that cannot run must not read as a pass."""
+    if contracts_dir is None:
+        contracts_dir = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+            "contracts",
+        )
+    from perceiver_io_tpu.analysis import ledger as L
 
-        led = L.load_ledger(contracts_dir)
-        features = None
-        if led is not None and not L.validate_ledger(led):
-            features = L.default_on_features(led) or None
-        result = check_contracts(contracts_dir, programs=programs, features=features)
-        return {
-            "status": result["status"],
-            "programs": {
-                p: {k: v for k, v in entry.items() if k in ("status", "detail")}
-                for p, entry in result["programs"].items()
-            },
-        }
-    except Exception as e:  # noqa: BLE001 — telemetry must not kill the bench
-        return {"status": "error", "error": str(e)}
+    led = L.load_ledger(contracts_dir)
+    features = None
+    if led is not None and not L.validate_ledger(led):
+        features = L.default_on_features(led) or None
+    result = check_contracts(contracts_dir, programs=programs, features=features)
+    return {
+        "status": result["status"],
+        "programs": {
+            p: {k: v for k, v in entry.items() if k in ("status", "detail")}
+            for p, entry in result["programs"].items()
+        },
+    }

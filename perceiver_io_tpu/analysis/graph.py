@@ -25,11 +25,14 @@ that lives in :mod:`perceiver_io_tpu.analysis.rules`.
 from __future__ import annotations
 
 import dataclasses
+import math
 import re
 import warnings
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 import jax
+import numpy as np
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,13 +95,13 @@ def _join_scope(outer: str, inner: str) -> str:
     return f"{outer}/{inner}"
 
 
-def _sub_jaxprs(value) -> List[jax.core.Jaxpr]:
+def _sub_jaxprs(value) -> List[Jaxpr]:
     """Jaxpr bodies hiding in one eqn param value (pjit/scan carry a
     ClosedJaxpr, cond a tuple of branches, custom_vjp nested callables)."""
-    out: List[jax.core.Jaxpr] = []
-    if isinstance(value, jax.core.ClosedJaxpr):
+    out: List[Jaxpr] = []
+    if isinstance(value, ClosedJaxpr):
         out.append(value.jaxpr)
-    elif isinstance(value, jax.core.Jaxpr):
+    elif isinstance(value, Jaxpr):
         out.append(value)
     elif isinstance(value, (tuple, list)):
         for v in value:
@@ -106,7 +109,7 @@ def _sub_jaxprs(value) -> List[jax.core.Jaxpr]:
     return out
 
 
-def trace(fn, *args, **kwargs) -> jax.core.ClosedJaxpr:
+def trace(fn, *args, **kwargs) -> ClosedJaxpr:
     """``jax.make_jaxpr`` with kwargs support — the jaxpr view of ``fn``.
 
     Trace-time feature flags (``fast_kernels`` etc.) must be active around
@@ -116,15 +119,15 @@ def trace(fn, *args, **kwargs) -> jax.core.ClosedJaxpr:
     return jax.make_jaxpr(fn)(*args)
 
 
-def iter_ops(closed: jax.core.ClosedJaxpr) -> Iterator[OpNode]:
+def iter_ops(closed: ClosedJaxpr) -> Iterator[OpNode]:
     """Every equation of ``closed`` and all nested call bodies, in program
     order, as :class:`OpNode` records."""
-    stack: List[Tuple[jax.core.Jaxpr, str, int]] = [(closed.jaxpr, "", 0)]
+    stack: List[Tuple[Jaxpr, str, int]] = [(closed.jaxpr, "", 0)]
     while stack:
         jpr, outer_scope, depth = stack.pop()
         for eqn in jpr.eqns:
             scope = _join_scope(outer_scope, _scope_of(eqn))
-            subs: List[jax.core.Jaxpr] = []
+            subs: List[Jaxpr] = []
             params: Dict[str, Any] = {}
             for k, v in eqn.params.items():
                 nested = _sub_jaxprs(v)
@@ -144,12 +147,12 @@ def iter_ops(closed: jax.core.ClosedJaxpr) -> Iterator[OpNode]:
                 stack.append((sub, scope, depth + 1))
 
 
-def iter_consts(closed: jax.core.ClosedJaxpr) -> Iterator[ConstInfo]:
+def iter_consts(closed: ClosedJaxpr) -> Iterator[ConstInfo]:
     """Array constants closed over anywhere in the graph, deduplicated by
     object identity (a const threaded through nested call bodies counts
     once — at its outermost appearance)."""
     seen: set = set()
-    stack: List[Tuple[jax.core.ClosedJaxpr, str]] = [(closed, "")]
+    stack: List[Tuple[ClosedJaxpr, str]] = [(closed, "")]
     while stack:
         cj, scope = stack.pop()
         for const in cj.consts:
@@ -160,16 +163,17 @@ def iter_consts(closed: jax.core.ClosedJaxpr) -> Iterator[ConstInfo]:
             dtype = getattr(const, "dtype", None)
             if shape is None or dtype is None:
                 continue  # python scalars etc.
-            nbytes = int(getattr(const, "nbytes", 0))
+            # from shape x itemsize: jax's typed ndarray consts carry no nbytes
+            nbytes = math.prod(int(d) for d in shape) * np.dtype(dtype).itemsize
             yield ConstInfo(tuple(int(d) for d in shape), str(dtype), nbytes, scope)
         for eqn in cj.jaxpr.eqns:
             scope = _scope_of(eqn)
             for v in eqn.params.values():
-                if isinstance(v, jax.core.ClosedJaxpr):
+                if isinstance(v, ClosedJaxpr):
                     stack.append((v, scope))
                 elif isinstance(v, (tuple, list)):
                     for item in v:
-                        if isinstance(item, jax.core.ClosedJaxpr):
+                        if isinstance(item, ClosedJaxpr):
                             stack.append((item, scope))
 
 

@@ -103,7 +103,7 @@ class LintPolicy:
     reshard_budget: Optional[Dict[str, int]] = None
     # rng-key-reuse (dataflow): armed when True — a PRNG key identity
     # consumed by >= 2 random draws with no split/fold_in between them, and
-    # keys entering a shard_map region replicated (in_names = {}) that
+    # keys entering a shard_map region replicated (in_specs = P()) that
     # reach a draw without a device-index fold_in on the way (the PR-4
     # replicated-dropout-key class). Inert until declared.
     check_rng: bool = False
@@ -461,9 +461,8 @@ def donation_dropped(ctx: RuleContext) -> List[Violation]:
     aliased = G.count_output_aliases(ctx.compiled_text)
     if aliased > 0 and not dropped:
         return []
-    # XLA:CPU never commits donation — on cpu this is an environment
-    # limitation, not a model bug (and the persistent-cache interaction
-    # makes donation actively unsafe there: utils/compat.py donation notes)
+    # on the CPU backend a dropped donation costs no HBM traffic: a warning
+    # there, an error where it is a real per-step copy
     sev = "warn" if ctx.backend == "cpu" else _severity(ctx, "donation-dropped")
     detail = dropped[0] if dropped else "no input_output_alias in the compiled module"
     return [
@@ -846,7 +845,7 @@ def rng_key_reuse(ctx: RuleContext) -> List[Violation]:
                 op=sink.primitive,
                 message=(
                     "a PRNG key enters the shard_map region REPLICATED "
-                    "(in_names={}) and reaches a random draw with no "
+                    "(in_specs=P()) and reaches a random draw with no "
                     "device-index fold_in on the path — every shard draws "
                     "IDENTICAL randomness (fold in lax.axis_index first, as "
                     "parallel/overlap.py does)"
@@ -1120,7 +1119,7 @@ def cross_program_consistency(ctx: RuleContext) -> List[Violation]:
     return out
 
 
-_CALLBACK_PRIMS = ("pure_callback", "io_callback", "debug_callback")
+_CALLBACK_PRIMS = ("pure_callback", "io_callback", "debug_callback", "debug_print")
 
 
 @register_rule(

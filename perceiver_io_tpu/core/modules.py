@@ -37,7 +37,6 @@ from perceiver_io_tpu.obs.probes import probe
 from perceiver_io_tpu.ops.layernorm import FusedLayerNorm
 from perceiver_io_tpu.core.config import CausalSequenceModelConfig
 from perceiver_io_tpu.core.position import positions
-from perceiver_io_tpu.utils.compat import axis_size
 
 LAYER_NORM_EPSILON = 1e-5  # match torch nn.LayerNorm default
 
@@ -1261,7 +1260,7 @@ class PerceiverAR(nn.Module):
             # the dense path's static-count keep set (see _forward), drawn
             # identically on every device from the replicated rng, then
             # sliced to this device's block
-            p_total = p_local * axis_size(axis_name)
+            p_total = p_local * lax.axis_size(axis_name)
             keep = p_total - int(p_total * self.cross_attention_dropout)
             rand = jax.random.uniform(self.make_rng("dropout"), (b, p_total))
             _, keep_idx = lax.top_k(rand, keep)
@@ -1462,7 +1461,7 @@ class CausalSequenceModel(nn.Module):
         """
         b, n_lat = latent_ids.shape
         p_local = prefix_ids_local.shape[1]
-        n_dev = axis_size(axis_name)
+        n_dev = lax.axis_size(axis_name)
         idx = lax.axis_index(axis_name)
         p_total = p_local * n_dev
 

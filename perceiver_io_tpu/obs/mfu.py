@@ -24,10 +24,8 @@ from typing import Dict, Optional, Tuple
 # Per-device dense peak matmul FLOP/s at the training dtype (bf16 for the
 # accelerators). Matched by substring against the lowercased
 # ``Device.device_kind`` — first hit wins, so more specific patterns come
-# first. The "cpu" entry is a NOMINAL placeholder (order of magnitude of a
-# few laptop cores) so CPU smoke runs report a non-null — but meaningless —
-# MFU; override per run with ``TrainerConfig.peak_flops_per_device`` when
-# the number matters.
+# first. A device off the table (the CPU among them) has no peak, so its
+# MFU is null; ``TrainerConfig.peak_flops_per_device`` overrides per run.
 PEAK_FLOPS = (
     ("v6 lite", 918e12),  # TPU v6e
     ("v6", 918e12),
@@ -39,7 +37,6 @@ PEAK_FLOPS = (
     ("v2", 45e12),
     ("h100", 495e12),  # dense bf16 (989e12 is the 2:1-sparsity figure)
     ("a100", 312e12),
-    ("cpu", 100e9),
 )
 
 
@@ -51,9 +48,8 @@ def device_peak_flops(device=None) -> Optional[float]:
 
         device = jax.devices()[0]
     kind = (getattr(device, "device_kind", "") or "").lower()
-    platform = (getattr(device, "platform", "") or "").lower()
     for pattern, peak in PEAK_FLOPS:
-        if pattern in kind or (pattern == platform == "cpu"):
+        if pattern in kind:
             return peak
     return None
 

@@ -120,6 +120,48 @@ def fast_kernels(mode):
         _FAST_FEATURES.reset(token)
 
 
+# GSPMD cannot partition a Mosaic kernel: inside a multi-device jit every
+# pallas_call must sit in a shard_map ("Mosaic kernels cannot be
+# automatically partitioned"). The attention kernels are independent per
+# batch row, so under ``kernel_mesh`` the wrappers below run them on each
+# device's batch shard. Trace-time and context-scoped like the toggles above.
+_KERNEL_MESH = contextvars.ContextVar("flash_kernel_mesh", default=None)
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh, batch_axes):
+    """Traces inside the block run the flash kernels per batch shard of
+    ``mesh``: the batch dim split over ``batch_axes`` (mesh axis names),
+    everything replicated over the other axes. ``mesh=None`` is a no-op."""
+    token = _KERNEL_MESH.set(None if mesh is None else (mesh, tuple(batch_axes)))
+    try:
+        yield
+    finally:
+        _KERNEL_MESH.reset(token)
+
+
+def _on_batch_shards(kernel, *operands):
+    """``kernel(*operands)``, under :func:`kernel_mesh` as a shard_map over
+    the batch (leading) dim of every operand; ``None`` operands pass through."""
+    scope = _KERNEL_MESH.get()
+    if scope is None or scope[0].size == 1:
+        return kernel(*operands)
+    from jax.sharding import PartitionSpec as P
+
+    mesh, batch_axes = scope
+    axes = tuple(a for a in batch_axes if mesh.shape[a] > 1)
+    spec = P(axes) if axes else P()
+    present = [x for x in operands if x is not None]
+
+    def body(*shards):
+        it = iter(shards)
+        return kernel(*(None if x is None else next(it) for x in operands))
+
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(spec,) * len(present), out_specs=spec, check_vma=False
+    )(*present)
+
+
 def _exp(x, base2: bool):
     return jnp.exp2(x) if base2 else jnp.exp(x)
 
@@ -142,9 +184,7 @@ _VMEM_LIMIT = 100 * 1024 * 1024
 
 def _compiler_params(*dims: str):
     """Grid dimension semantics + raised VMEM ceiling (no-op in interpret)."""
-    from perceiver_io_tpu.utils.compat import pallas_compiler_params_cls
-
-    return pallas_compiler_params_cls()(dimension_semantics=dims, vmem_limit_bytes=_VMEM_LIMIT)
+    return pltpu.CompilerParams(dimension_semantics=dims, vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _dot(a, b, dims):
@@ -1033,7 +1073,12 @@ def flash_attention_packed(
             bias = bias.at[:, nkv:].set(MASK_VALUE)
         bias = bias[:, None, :]
 
-    out = _flash_packed(qf, kf, vf, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2)
+    out = _on_batch_shards(
+        lambda q_, k_, v_, bias_: _flash_packed(
+            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2
+        ),
+        qf, kf, vf, bias,
+    )
     return out[:, :nq, :]
 
 
@@ -1702,7 +1747,14 @@ def flash_attention(
         # kernels index the (B, 1, Nkv_p) bias with (bh // num_heads, 0, j)
         bias = bias[:, None, :]
 
-    out = _flash(qf, kf, vf, bias, causal, offset, sm_scale, block_q, block_kv, h, v2)
+    # (batch*heads) rows are batch-major, so a split of the leading dim over
+    # the batch axes keeps each device's rows aligned with its bias rows
+    out = _on_batch_shards(
+        lambda q_, k_, v_, bias_: _flash(
+            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, v2
+        ),
+        qf, kf, vf, bias,
+    )
     return out[:, :nq, :d_v].reshape(b, h, nq, d_v)
 
 

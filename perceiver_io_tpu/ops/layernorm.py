@@ -40,8 +40,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from perceiver_io_tpu.utils.compat import pallas_compiler_params_cls
-
 STAT_LANES = 8  # residual lanes for per-row mean/rstd (lane 0 carries data)
 
 # None = auto (currently: OFF, see module notes); a contextvar like the
@@ -183,7 +181,7 @@ def _ln2d_fwd_impl(x, scale, bias, eps, block, out_dtype, want_stats):
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=pallas_compiler_params_cls()(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=_interpret_default(),
     )(x, scale[None, :], bias[None, :])
     return outs if want_stats else (outs[0] if isinstance(outs, (list, tuple)) else outs,)
@@ -224,7 +222,7 @@ def _ln2d_bwd(eps, block, out_dtype, residuals, dy):
             pltpu.VMEM((1, c), jnp.float32),
             pltpu.VMEM((1, c), jnp.float32),
         ],
-        compiler_params=pallas_compiler_params_cls()(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=_interpret_default(),
     )(x, scale[None, :], mean, rstd, dy)
     return dx, dg[0].astype(scale.dtype), db[0].astype(scale.dtype)

@@ -61,11 +61,11 @@ def paged_kernel_supported(cache, num_heads: int, d_qk: int, d_v: int) -> bool:
 
 def _paged_kernel(
     table_ref,  # scalar prefetch: (S, pages_per_slot) int32
-    q_ref,  # (1, h*d_qk)
+    q_ref,  # (1, 1, h*d_qk)
     k_ref,  # (1, page, h*d_qk) — the page the index map selected
     v_ref,  # (1, page, h*d_v)
-    bias_ref,  # (1, page) f32 — 0 where visible, MASK_VALUE where masked
-    o_ref,  # (1, h*d_v)
+    bias_ref,  # (1, 1, 1, page) f32 — 0 where visible, MASK_VALUE where masked
+    o_ref,  # (1, 1, h*d_v)
     m_scr,  # (h, 1, LANES) f32
     l_scr,  # (h, 1, LANES) f32
     acc_scr,  # (h, 1, d_v) f32
@@ -84,9 +84,9 @@ def _paged_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    bias = bias_ref[...]  # (1, page)
+    bias = bias_ref[0, 0]  # (1, page)
     for hh in range(h):
-        qh = q_ref[:, hh * d_qk : (hh + 1) * d_qk]  # (1, d_qk)
+        qh = q_ref[0, :, hh * d_qk : (hh + 1) * d_qk]  # (1, d_qk)
         kh = k_ref[0, :, hh * d_qk : (hh + 1) * d_qk]  # (page, d_qk)
         vh = v_ref[0, :, hh * d_v : (hh + 1) * d_v]  # (page, d_v)
         s = _dot(qh, kh, ((1,), (1,))) + bias  # (1, page) f32
@@ -106,7 +106,7 @@ def _paged_kernel(
         for hh in range(h):
             l = l_scr[hh, :, :1]
             l_inv = jnp.where(l == 0.0, 1.0, 1.0 / l)
-            o_ref[:, hh * d_v : (hh + 1) * d_v] = (acc_scr[hh] * l_inv).astype(o_ref.dtype)
+            o_ref[0, :, hh * d_v : (hh + 1) * d_v] = (acc_scr[hh] * l_inv).astype(o_ref.dtype)
 
 
 def paged_decode_attention(qh: jnp.ndarray, cache, mask=None) -> jnp.ndarray:
@@ -128,21 +128,24 @@ def paged_decode_attention(qh: jnp.ndarray, cache, mask=None) -> jnp.ndarray:
     if mask is None:
         kv_idx = jnp.arange(cap, dtype=jnp.int32)
         mask = kv_idx[None, :] >= cache.length[:, None]
-    bias = jnp.where(mask, MASK_VALUE, 0.0).astype(jnp.float32)
+    # Mosaic wants a block's last two dims to tile (8, 128) or span the
+    # array's: q/out/bias blocks are one row, so each gets a unit axis in
+    # front of its lanes and the slot (and page) index moves to leading dims
+    bias = jnp.where(mask, MASK_VALUE, 0.0).astype(jnp.float32).reshape(s_slots, npb, 1, page)
 
-    q_packed = qh.reshape(s_slots, h * d_qk)
+    q_packed = qh.reshape(s_slots, 1, h * d_qk)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(s_slots, npb),
         in_specs=[
-            pl.BlockSpec((1, h * d_qk), lambda s, j, table: (s, 0)),
+            pl.BlockSpec((1, 1, h * d_qk), lambda s, j, table: (s, 0, 0)),
             # the page walk: block (s, j) loads pool page table[s, j]
             pl.BlockSpec((1, page, h * d_qk), lambda s, j, table: (table[s, j], 0, 0)),
             pl.BlockSpec((1, page, h * d_v), lambda s, j, table: (table[s, j], 0, 0)),
-            pl.BlockSpec((1, page), lambda s, j, table: (s, j)),
+            pl.BlockSpec((1, 1, 1, page), lambda s, j, table: (s, j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, h * d_v), lambda s, j, table: (s, 0)),
+        out_specs=pl.BlockSpec((1, 1, h * d_v), lambda s, j, table: (s, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, 1, LANES), jnp.float32),
             pltpu.VMEM((h, 1, LANES), jnp.float32),
@@ -154,7 +157,7 @@ def paged_decode_attention(qh: jnp.ndarray, cache, mask=None) -> jnp.ndarray:
             _paged_kernel, num_heads=h, d_qk=d_qk, d_v=d_v, num_kv_blocks=npb
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_slots, h * d_v), qh.dtype),
+        out_shape=jax.ShapeDtypeStruct((s_slots, 1, h * d_v), qh.dtype),
         compiler_params=_compiler_params("arbitrary", "arbitrary"),
         interpret=_interpret_default(),
     )(cache.page_table, q_packed, cache.k, cache.v, bias)

@@ -27,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from perceiver_io_tpu.utils.compat import shard_map as _shard_map
-
 from perceiver_io_tpu.parallel.mesh import AXIS_SEQ
 from perceiver_io_tpu.utils.arrays import concrete_or_none
 
@@ -106,7 +104,7 @@ def make_seq_parallel_clm_forward(model, mesh: Mesh, *, prefix_len: int, axis_na
                     return _f(*args)
 
             variants[key] = jax.jit(
-                _shard_map(f_plain, mesh=mesh, in_specs=tuple(specs), out_specs=P())
+                jax.shard_map(f_plain, mesh=mesh, in_specs=tuple(specs), out_specs=P())
             )
         return variants[key]
 

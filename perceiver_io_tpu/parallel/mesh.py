@@ -56,8 +56,28 @@ def make_mesh(
         data = n // fixed
     if data * fixed != n:
         raise ValueError(f"mesh {data}x{fsdp}x{tensor}x{seq} != {n} devices")
+    if max(data, fsdp, tensor, seq) == n:
+        devices = _ring_order(devices)
     dev_array = np.asarray(devices).reshape(data, fsdp, tensor, seq)
     return Mesh(dev_array, MESH_AXES)
+
+
+def _ring_order(devices):
+    """Order for a mesh whose one axis spans every device: rows of the
+    physical grid walked boustrophedon, so that consecutive devices are ICI
+    neighbours. TPU devices enumerate row-major — on a v5e 2x2 as (0,0),
+    (1,0), (0,1), (1,1) — where a 4-long axis would step (1,0) -> (0,1)
+    across the diagonal; walked this way it is the closed ring 0, 1, 3, 2.
+    A mesh of several axes keeps the enumeration order, whose axes already
+    follow the grid's. Devices without coordinates (CPU) stay as given."""
+    if any(getattr(d, "coords", None) is None for d in devices):
+        return devices
+
+    def key(d):
+        x, y, *rest = d.coords
+        return (*reversed(rest), y, x if y % 2 == 0 else -x)
+
+    return sorted(devices, key=key)
 
 
 def replicated(mesh: Mesh) -> NamedSharding:
@@ -114,13 +134,24 @@ def _fsdp_dim(shape, fsdp_size: int, min_weight_size: int, exclude=()) -> Option
     return None
 
 
+def _spec(axes) -> P:
+    """``P(*axes)`` without trailing ``None``s — the form jit hands a sharding
+    back in. A state placed under ``P('fsdp', None)`` and returned under
+    ``P('fsdp')`` is the same layout but an unequal sharding, and the next
+    call misses the jit cache on it."""
+    axes = list(axes)
+    while axes and axes[-1] is None:
+        axes.pop()
+    return P(*axes)
+
+
 def _fsdp_spec(shape, fsdp_size: int, min_weight_size: int) -> P:
     dim = _fsdp_dim(shape, fsdp_size, min_weight_size)
     if dim is None:
         return P()
     spec = [None] * len(shape)
     spec[dim] = AXIS_FSDP
-    return P(*spec)
+    return _spec(spec)
 
 
 def fsdp_param_shardings(params, mesh: Mesh, min_weight_size: int = 2**14):
@@ -173,6 +204,6 @@ def param_shardings(params, mesh: Mesh, min_weight_size: int = 2**14):
         dim = _fsdp_dim(shape, fsdp_size, min_weight_size, exclude=taken)
         if dim is not None:
             spec[dim] = AXIS_FSDP
-        return NamedSharding(mesh, P(*spec))
+        return NamedSharding(mesh, _spec(spec))
 
     return jax.tree_util.tree_map_with_path(spec_for, params)

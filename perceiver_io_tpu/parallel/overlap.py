@@ -64,7 +64,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from perceiver_io_tpu.parallel.mesh import AXIS_DATA, AXIS_FSDP, _fsdp_dim
-from perceiver_io_tpu.utils.compat import shard_map as _shard_map
 
 # one collective per ~4 MB of gradient/parameter payload: big enough to
 # amortize per-collective latency, small enough that the first chunk's
@@ -378,7 +377,7 @@ def make_overlap_train_step(
                 return body(*args)
 
         grad_specs = jax.tree_util.tree_unflatten(treedef, param_specs)
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             body_plain,
             mesh=mesh,
             in_specs=(grad_specs, P((AXIS_DATA, AXIS_FSDP)), P()),
@@ -394,9 +393,7 @@ def make_overlap_train_step(
 
     if not jit:
         return train_step
-    from perceiver_io_tpu.utils.compat import donation_safe
-
-    return jax.jit(train_step, donate_argnums=(0,) if donate and donation_safe() else ())
+    return jax.jit(train_step, donate_argnums=(0,) if donate else ())
 
 
 # ------------------------------------------------------------------ auditing

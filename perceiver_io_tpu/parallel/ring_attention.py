@@ -44,8 +44,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from perceiver_io_tpu.utils.compat import axis_size, shard_map as _shard_map
-
 from perceiver_io_tpu.ops.online_softmax import (
     NEG_INF as _NEG_INF,
     block_attention as _block_attention,
@@ -86,7 +84,7 @@ def seq_sharded_cross_attention(
     idx = lax.axis_index(axis_name)
     m_local = k_local.shape[2]
     if kv_len_total is None:
-        kv_len_total = m_local * axis_size(axis_name)
+        kv_len_total = m_local * lax.axis_size(axis_name)
 
     kv_global = idx * m_local + jnp.arange(m_local, dtype=jnp.int32)
     masked = jnp.zeros((1, 1, 1, m_local), dtype=bool)
@@ -131,7 +129,7 @@ def ring_self_attention(
     nothing (they are masked, not skipped — control flow stays static; XLA
     still overlaps the permute with the block matmul).
     """
-    n_dev = axis_size(axis_name)
+    n_dev = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     n_q, m_local = q_local.shape[2], k_local.shape[2]
 
@@ -171,13 +169,13 @@ def _make_wrapper(fn, mesh: Mesh, q_spec: P, out_spec: P):
     shard_maps (one with and one without the optional mask argument)."""
     kv_spec = P(None, None, AXIS_SEQ, None)
     with_mask = jax.jit(
-        _shard_map(
+        jax.shard_map(
             fn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec, P(None, AXIS_SEQ)),
             out_specs=out_spec,
         )
     )
     no_mask = jax.jit(
-        _shard_map(fn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec), out_specs=out_spec)
+        jax.shard_map(fn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec), out_specs=out_spec)
     )
 
     def attend(q, k, v, pad_mask=None):

@@ -201,11 +201,10 @@ def _load_orbax_pretrained(directory: str, template_params=None):
             step = mngr.latest_step()
         if step is None:
             raise FileNotFoundError(f"no checkpoint steps under {root}")
-        # a fresh manager reading another run's checkpoint needs the
-        # restore-args shim on newer orbax (utils/compat.py)
-        from perceiver_io_tpu.utils.compat import orbax_manager_restore
-
-        payload = orbax_manager_restore(mngr, step)
+        # a fresh manager has no handler registered for another run's
+        # saved item, so the restore names one; no target tree = a raw
+        # numpy pytree, template-coerced below
+        payload = mngr.restore(step, args=ocp.args.StandardRestore())
     finally:
         mngr.close()
     params = payload["params"] if isinstance(payload, dict) and "params" in payload else payload

@@ -8,13 +8,14 @@ from the mesh (data/fsdp axes); XLA GSPMD inserts all collectives.
 
 from __future__ import annotations
 
-from typing import Callable
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from perceiver_io_tpu.parallel.mesh import param_shardings
+from perceiver_io_tpu.ops.flash_attention import kernel_mesh
+from perceiver_io_tpu.parallel.mesh import AXIS_DATA, AXIS_FSDP, AXIS_SEQ, AXIS_TENSOR, param_shardings
 from perceiver_io_tpu.training.state import TrainState
 
 
@@ -26,10 +27,22 @@ def make_train_step(
     overlap=None,
     sentinel: bool = False,
     probes=None,
+    mesh: Optional[Mesh] = None,
+    min_weight_size: int = 2**14,
 ) -> Callable:
     """``train_step(state, batch) -> (state, metrics)``, jitted.
 
     ``loss_fn(params, batch, rng) -> (loss, metrics)``.
+
+    ``mesh`` (with ``min_weight_size``, as given to :func:`shard_train_state`)
+    pins the returned state to the layout it was placed in. Left to itself
+    GSPMD hands some leaves back under another sharding than they came in
+    with (small replicated parameters return fsdp-sharded), so the second
+    call sees new input shardings and compiles the step again, and donation
+    cannot alias those leaves. On a data x fsdp mesh it also runs the flash
+    kernels per batch shard (``ops.flash_attention.kernel_mesh``): GSPMD
+    cannot partition a Mosaic kernel, and without it the sharded step does
+    not lower on a TPU. Tensor and sequence meshes are left as they were.
 
     ``overlap``: a ``parallel.overlap.OverlapConfig`` (or a bare ``Mesh``)
     switches to the explicit shard_map distributed step — chunk-interleaved
@@ -210,16 +223,27 @@ def make_train_step(
             metrics["sentinel_skipped"] = 1.0 - ok.astype(jnp.float32)
         return state, metrics
 
+    if mesh is not None:
+        unpinned_step = train_step
+
+        def train_step(state: TrainState, batch):
+            with batch_sharded_kernels(mesh):
+                new_state, metrics = unpinned_step(state, batch)
+            layout = train_state_shardings(new_state, mesh, min_weight_size=min_weight_size)
+            return jax.lax.with_sharding_constraint(new_state, layout), metrics
+
     if not jit:
         return train_step
-    # donation is dropped on XLA:CPU — not just useless there but UNSAFE
-    # in combination with the persistent compilation cache (a cache-hit
-    # executable returns the donated state unchanged; see
-    # utils/compat.donation_safe) — graphlint's donation-dropped rule
-    # audits that TPU/GPU builds actually commit the aliasing
-    from perceiver_io_tpu.utils.compat import donation_safe
+    return jax.jit(train_step, donate_argnums=(0,) if donate else ())
 
-    return jax.jit(train_step, donate_argnums=(0,) if donate and donation_safe() else ())
+
+def batch_sharded_kernels(mesh: Optional[Mesh]):
+    """The trace-time context under which the flash kernels run per batch
+    shard of a data x fsdp ``mesh`` (a no-op for ``None`` and for meshes with
+    a tensor or seq axis, whose attention paths own their shard_maps)."""
+    if mesh is not None and mesh.shape[AXIS_TENSOR] * mesh.shape[AXIS_SEQ] > 1:
+        mesh = None
+    return kernel_mesh(mesh, (AXIS_DATA, AXIS_FSDP))
 
 
 def _chunk(x, i: int, k: int):

@@ -103,9 +103,7 @@ def scale_by_adam_compact(
 
     def update_fn(updates, state, params=None):
         del params
-        from perceiver_io_tpu.utils.compat import safe_increment
-
-        count = safe_increment(state.count)
+        count = optax.safe_increment(state.count)
         bc1 = 1.0 - b1 ** count.astype(jnp.float32)
         bc2 = 1.0 - b2 ** count.astype(jnp.float32)
 

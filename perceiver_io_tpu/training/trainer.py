@@ -25,7 +25,11 @@ from perceiver_io_tpu.obs.mfu import GoodputTracker, device_peak_flops
 from perceiver_io_tpu.obs.recompile import RecompileTracker
 from perceiver_io_tpu.parallel.mesh import AXIS_SEQ, shard_batch
 from perceiver_io_tpu.training.checkpoint import CheckpointManager
-from perceiver_io_tpu.training.loop import make_train_step, shard_train_state
+from perceiver_io_tpu.training.loop import (
+    batch_sharded_kernels,
+    make_train_step,
+    shard_train_state,
+)
 from perceiver_io_tpu.training.metrics import MetricsLogger
 from perceiver_io_tpu.training.state import TrainState
 
@@ -231,12 +235,15 @@ class Trainer:
                 if isinstance(self.config.probes, ProbeConfig)
                 else ProbeConfig()
             )
+        # the step hands the state back in the layout fit() places it in
+        layout = dict(mesh=mesh, min_weight_size=self.config.fsdp_min_weight_size)
         self._train_step = self.recompiles.wrap(
             make_train_step(
                 loss_fn,
                 overlap=overlap_cfg,
                 sentinel=in_graph_sentinel,
                 probes=self._probe_cfg,
+                **layout,
             ),
             "train_step",
         )
@@ -247,7 +254,7 @@ class Trainer:
         # (the jaxpr walker descends into the shard_map body)
         self._lint_step = make_train_step(
             loss_fn, jit=False, overlap=overlap_cfg, sentinel=in_graph_sentinel,
-            probes=self._probe_cfg,
+            probes=self._probe_cfg, **layout,
         )
         # the fit-scoped preemption guard, exposed so tests and the chaos
         # harness can trip it deterministically (tools/chaos.py)
@@ -265,7 +272,8 @@ class Trainer:
                 eval_fn = loss_fn
 
         def eval_step(params, batch, rng):
-            _, metrics = eval_fn(params, batch, rng)
+            with batch_sharded_kernels(mesh):
+                _, metrics = eval_fn(params, batch, rng)
             return metrics
 
         self._eval_step = self.recompiles.wrap(jax.jit(eval_step), "eval_step")
@@ -318,85 +326,62 @@ class Trainer:
             )
         return self._events
 
-    def _shared_lint_trace(self, state: TrainState, batch):
-        """One jaxpr trace of the lint step for BOTH the graphlint and
-        graphcheck emitters (tracing a large step takes seconds; each
-        emitter re-traces on its own only if this shared one failed)."""
-        try:
-            from perceiver_io_tpu.analysis import graph
-
-            return graph.trace(self._lint_step, state, batch)
-        except Exception:  # noqa: BLE001 — emitters retrace + report themselves
-            return None
-
     def _graphlint(self, events: EventLog, state: TrainState, batch, closed=None) -> None:
         """Lint the train step's jaxpr (trace-only rules) and emit the
-        result as a ``graphlint`` event. Telemetry contract: never takes
-        the training loop down — a lint failure is an event, an analysis
-        crash a warning."""
-        import warnings
+        result as a ``graphlint`` event. A lint finding is an event, never
+        a failure; an exception inside the analysis propagates — a gate
+        that cannot run must not read as a clean run."""
+        from perceiver_io_tpu import analysis
+        from perceiver_io_tpu.analysis.flagship import DEAD_COMPUTE_MIN_FLOPS
 
-        try:
-            from perceiver_io_tpu import analysis
-            from perceiver_io_tpu.analysis.flagship import DEAD_COMPUTE_MIN_FLOPS
-
-            report = analysis.check(
-                self._lint_step,
-                (state, batch),
-                rules=self.config.graphlint_rules,
-                allow=self.config.graphlint_allow,
-                # arm the dataflow rules against the ACTUAL trained step:
-                # sharding_flow=True reads whatever NamedShardings the
-                # fit-time state/batch carry (unsharded runs propagate
-                # nothing and stay silent)
-                policy=analysis.LintPolicy(
-                    check_rng=True,
-                    dead_compute_min_flops=DEAD_COMPUTE_MIN_FLOPS,
-                    sharding_flow=True,
-                ),
-                name="train_step",
-                closed_jaxpr=closed,
-            )
-            events.emit(
-                "graphlint",
-                step=int(state.step),
-                ok=report.ok(),
-                clean=report.clean,
-                rules=list(report.rules_run),
-                counts={s: report.count(s) for s in ("error", "warn", "info")},
-                violations=[v.to_dict() for v in report.violations[:20]],
-                n_allowed=len(report.allowed),
-            )
-        except Exception as e:  # noqa: BLE001 — lint must not kill training
-            warnings.warn(f"graphlint failed on the train step: {e}")
-            events.emit("graphlint", step=int(state.step), error=str(e))
+        report = analysis.check(
+            self._lint_step,
+            (state, batch),
+            rules=self.config.graphlint_rules,
+            allow=self.config.graphlint_allow,
+            # arm the dataflow rules against the ACTUAL trained step:
+            # sharding_flow=True reads whatever NamedShardings the
+            # fit-time state/batch carry (unsharded runs propagate
+            # nothing and stay silent)
+            policy=analysis.LintPolicy(
+                check_rng=True,
+                dead_compute_min_flops=DEAD_COMPUTE_MIN_FLOPS,
+                sharding_flow=True,
+            ),
+            name="train_step",
+            closed_jaxpr=closed,
+        )
+        events.emit(
+            "graphlint",
+            step=int(state.step),
+            ok=report.ok(),
+            clean=report.clean,
+            rules=list(report.rules_run),
+            counts={s: report.count(s) for s in ("error", "warn", "info")},
+            violations=[v.to_dict() for v in report.violations[:20]],
+            n_allowed=len(report.allowed),
+        )
 
     def _graphcheck(self, events: EventLog, state: TrainState, batch, closed=None) -> None:
         """Emit the trace-level fingerprint of the train step as a
-        ``graphcheck`` event (same never-kills-training contract as
-        :meth:`_graphlint`; trace-only — no compile)."""
-        import warnings
+        ``graphcheck`` event (trace-only — no compile; exceptions
+        propagate, as in :meth:`_graphlint`)."""
+        from perceiver_io_tpu.analysis.fingerprint import fingerprint
 
-        try:
-            from perceiver_io_tpu.analysis.fingerprint import fingerprint
-
-            fp = fingerprint(
-                self._lint_step, (state, batch), name="train_step", compiled=False,
-                closed_jaxpr=closed,
-            )
-            events.emit(
-                "graphcheck",
-                step=int(state.step),
-                name=fp.name,
-                n_ops=fp.n_ops,
-                features=list(fp.features),
-                hot_concats=[dict(c) for c in fp.hot_concats[:20]],
-                captured_const_bytes=fp.captured_const_bytes,
-                dtype_histogram=fp.dtype_histogram,
-            )
-        except Exception as e:  # noqa: BLE001 — telemetry must not kill training
-            warnings.warn(f"graphcheck failed on the train step: {e}")
-            events.emit("graphcheck", step=int(state.step), error=str(e))
+        fp = fingerprint(
+            self._lint_step, (state, batch), name="train_step", compiled=False,
+            closed_jaxpr=closed,
+        )
+        events.emit(
+            "graphcheck",
+            step=int(state.step),
+            name=fp.name,
+            n_ops=fp.n_ops,
+            features=list(fp.features),
+            hot_concats=[dict(c) for c in fp.hot_concats[:20]],
+            captured_const_bytes=fp.captured_const_bytes,
+            dtype_histogram=fp.dtype_histogram,
+        )
 
     # -- API --------------------------------------------------------------
 
@@ -665,11 +650,13 @@ class Trainer:
                     if lint_pending:
                         lint_pending = False
                         with goodput.measure("graphlint"):
-                            closed = (
-                                self._shared_lint_trace(state, batch)
-                                if cfg.graphlint and cfg.graphcheck
-                                else None
-                            )
+                            closed = None
+                            if cfg.graphlint and cfg.graphcheck:
+                                # one trace for both emitters: tracing a
+                                # large step takes seconds
+                                from perceiver_io_tpu.analysis import graph
+
+                                closed = graph.trace(self._lint_step, state, batch)
                             if cfg.graphlint:
                                 self._graphlint(events, state, batch, closed)
                             if cfg.graphcheck:
@@ -839,6 +826,11 @@ class Trainer:
                         )
                         self._log(step, avg)
                         if events is not None:
+                            if cfg.flops_per_sample and not peak:
+                                # device off obs.mfu.PEAK_FLOPS: an explicit
+                                # null, not a missing key (events only — the
+                                # csv column holds floats)
+                                avg = {**avg, "mfu": None}
                             events.emit("log", step=step, **avg)
                             if probe_ring:
                                 # the log boundary is the agreed host-sync

@@ -203,8 +203,7 @@ def test_donation_dropped_fires_when_donation_unusable():
         policy=LintPolicy(expect_donation=True),
     )
     assert [v.rule for v in report.violations] == ["donation-dropped"]
-    # on CPU the drop is an environment limitation, downgraded to warn
-    # (utils/compat.donation_safe documents why donation is off there)
+    # on CPU a dropped donation costs no HBM traffic: downgraded to warn
     assert report.violations[0].severity == ("warn" if jax.default_backend() == "cpu" else "error")
     assert not report.clean
 
@@ -247,10 +246,8 @@ def test_donation_committed_is_clean():
 def _psum_fn():
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from perceiver_io_tpu.utils.compat import shard_map
-
     mesh = Mesh(np.array(jax.devices()).reshape(-1), ("x",))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda x: jax.lax.psum(x, "x"), mesh=mesh, in_specs=P("x"), out_specs=P()
     )
     return jax.jit(fn), (jnp.ones((len(jax.devices()), 4)),)
@@ -369,11 +366,9 @@ def test_replicated_large_tensor_clean_when_sharded_or_small_or_unpartitioned():
 def _ppermute_fn():
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from perceiver_io_tpu.utils.compat import shard_map
-
     n = len(jax.devices())
     mesh = Mesh(np.array(jax.devices()).reshape(-1), ("x",))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda x: jax.lax.ppermute(x, "x", [(i, (i + 1) % n) for i in range(n)]),
         mesh=mesh, in_specs=P("x"), out_specs=P("x"),
     )
@@ -432,8 +427,6 @@ def test_rng_key_reuse_clean_when_split_and_skipped_undeclared():
 def _shard_map_draw(fold_device_index: bool):
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from perceiver_io_tpu.utils.compat import shard_map
-
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(-1), ("data",))
 
     def body(x, key):
@@ -441,9 +434,9 @@ def _shard_map_draw(fold_device_index: bool):
             key = jax.random.fold_in(key, jax.lax.axis_index("data"))
         return x * jax.random.uniform(key, x.shape)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=(P("data"), P()), out_specs=P("data"),
-        check_rep=False,
+        check_vma=False,
     )
     return fn, (jnp.ones((8, 4)), jax.random.PRNGKey(0))
 

@@ -28,25 +28,6 @@ def _rounds(pattern):
     return out
 
 
-def test_bench_rounds_monotone_and_well_formed():
-    rounds = _rounds("BENCH_r*.json")
-    assert rounds, "no BENCH_r*.json artifacts committed"
-    # contiguous monotone numbering from round 1: a skipped or duplicated
-    # round breaks the floor gate's latest-artifact resolution
-    assert sorted(rounds) == list(range(1, max(rounds) + 1)), sorted(rounds)
-    for n, path in rounds.items():
-        doc = json.load(open(path))
-        base = os.path.basename(path)
-        for key, typ in (("n", int), ("cmd", str), ("rc", int), ("tail", str)):
-            assert isinstance(doc.get(key), typ), f"{base}: {key} must be {typ.__name__}"
-        assert doc["n"] == n, f"{base}: field n={doc['n']} != filename round {n}"
-        if doc.get("parsed") is not None:
-            parsed = doc["parsed"]
-            assert isinstance(parsed.get("metric"), str), base
-            assert isinstance(parsed.get("value"), (int, float)), base
-            assert isinstance(parsed.get("unit"), str), base
-
-
 def test_bench_extra_rounds_well_formed():
     rounds = _rounds("BENCH_extra_r*.json")
     for n, path in rounds.items():

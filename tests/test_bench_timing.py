@@ -2,7 +2,7 @@
 
 robust_slope's contract: per-iteration time from interleaved short/long
 chain timings, min-reduced per estimate, median across estimates, with
-stall-corrupted (non-positive) estimates dropped — a tunnel stall must not
+stall-corrupted (non-positive) estimates dropped — a host stall must not
 surface as inflated throughput (the failure mode the median replaced min
 for), and an all-stall measurement must fail loudly instead of returning a
 garbage sentinel.

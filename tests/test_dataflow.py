@@ -44,10 +44,10 @@ def test_threading_through_pjit_boundary():
     mul = next(n for n in df.nodes if n.primitive == "mul")
     tanh = next(n for n in df.nodes if n.primitive == "tanh")
     red = next(n for n in df.nodes if n.primitive == "reduce_sum")
-    assert tanh.parent is not None and df.nodes[tanh.parent].primitive == "pjit"
+    assert tanh.parent is not None and df.nodes[tanh.parent].primitive == "jit"
     chain = df.find_chain(mul.nid, red.nid)
     assert chain is not None
-    assert [n.primitive for n in chain if n.primitive != "pjit"] == [
+    assert [n.primitive for n in chain if n.primitive != "jit"] == [
         "mul", "tanh", "reduce_sum"
     ]
 
@@ -243,21 +243,19 @@ def test_propagate_shardings_drops_layouts_across_scan_rank_changes():
 def test_propagate_shardings_skips_shard_map_interiors():
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from perceiver_io_tpu.utils.compat import shard_map
-
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(-1), ("data",))
 
     def f(x):
         def body(x):
             return x[0:1] * 2.0  # a slice of the LOCAL shard: not a reshard
 
-        return shard_map(
-            body, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_rep=False
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False
         )(x)
 
     df = D.analyze(f, jnp.ones((8, 4)))
     conflicts, state = D.propagate_shardings(df, [P("data")])
     assert conflicts == []
     sm = next(n for n in df.nodes if n.primitive == "shard_map")
-    # region outputs take their layout from out_names
+    # region outputs take their layout from out_specs
     assert state[sm.outvals[0]] == (("data",), None)

@@ -318,7 +318,7 @@ def test_bench_floor_failure_detected(tmp_path):
         "schema_version": 1,
         "features": {},
         "floors": {
-            "train": {"artifact": "BENCH_r*.json", "key": "parsed.vs_baseline", "min": 99.0},
+            "spec": {"artifact": "BENCH_extra_r*.json", "key": "decode_spec.tokens_per_step", "min": 99.0},
             "ghost": {"artifact": "NO_SUCH_r*.json", "key": "parsed.value", "min": 0.0},
         },
     }
@@ -372,12 +372,12 @@ def test_floor_match_clause_selects_latest_matching_round(tmp_path):
 
 
 def test_graphcheck_telemetry_block_shape():
-    """The `telemetry.graphcheck` block bench results carry: never raises,
-    records the contract verdict for the two cheapest programs."""
+    """The `telemetry.graphcheck` block bench results carry: the contract
+    verdict for the two cheapest programs."""
     from perceiver_io_tpu.analysis.fingerprint import graphcheck_telemetry
 
     block = graphcheck_telemetry()
-    assert block["status"] in ("passed", "regressed", "stale", "missing", "error")
+    assert block["status"] in ("passed", "regressed", "stale", "missing")
     assert block["status"] == "passed", block  # contracts are committed + clean
     assert set(block["programs"]) == {"train_flat", "decode"}
 

@@ -24,7 +24,7 @@ from perceiver_io_tpu.parallel.overlap import (
 )
 from perceiver_io_tpu.training import TrainState, make_optimizer
 from perceiver_io_tpu.training.loop import make_train_step, shard_train_state
-from perceiver_io_tpu.utils.compat import shard_map
+from jax import shard_map
 
 
 # --------------------------------------------------------------- toy harness

@@ -186,12 +186,9 @@ def test_probed_contract_zero_added_collectives():
         probed = json.load(f)["fingerprint"]
     assert probed["collectives"] == flat["collectives"]
     assert probed["captured_const_bytes"] == flat["captured_const_bytes"]
-    # NOTE: on the cpu-extracted contracts both sides record 0 aliases
-    # (utils/compat.donation_safe drops donation on XLA:CPU), so today this
-    # equality is trivially true; it is kept because a TPU re-snapshot
-    # records REAL alias counts and the same assertion (plus graphcheck's
-    # donation_aliases regression class) then pins that the update-ratio
-    # stats' read of the old params does not cost the step its donation
+    # the train step donates on every backend (PR 23), so the contracts
+    # record real alias counts: the update-ratio stats' read of the old
+    # params must not cost the step its donation
     assert probed["donation_aliases"] == flat["donation_aliases"]
     # bounded temp growth: the stats buffers must stay a small fraction of
     # the step's working set (5% gate at micro geometry)

@@ -1,0 +1,145 @@
+"""The main path's kernels compile for a v5e at flagship widths.
+
+Interpret mode proves a Pallas kernel's arithmetic, not that Mosaic will
+lower it: block shapes off the (8, 128) tiling, or too much VMEM, pass every
+interpret-mode test and are refused on the chip. The TPU compiler is
+installed here and compiles for a chip that is described, not attached, so
+these tests catch that class with no chip time. Nothing runs: a passing
+compile says nothing about results or speed.
+
+All in one file, topology described inside a module-scoped fixture: the
+process that describes it holds libtpu until it exits, so a second test file
+(another xdist worker) or a topology call at import would fail.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from perceiver_io_tpu.core.attention import MultiHeadAttention
+from perceiver_io_tpu.core.cache import KVCache, PagedKVCache
+from perceiver_io_tpu.ops import paged_attention as pa
+
+# the package re-exports a function under the module's name
+fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
+
+# flagship attention geometry (bench.flagship_config): 512 channels, 8 heads
+HEADS, CHANNELS = 8, 512
+CONTEXT, LATENTS = 16384, 1024
+TRAIN_CHUNK = 4  # samples per gradient chunk of the batch-32 train step
+SLOTS = 8  # engine decode slots / batched decode
+PAGE = 128  # the page size chip_smoke.py's serve phase runs
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One described (not attached) v5e chip. The persistent compilation
+    cache is off while it is in use: a compile for a described chip is
+    written to the cache but cannot be read back without the chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe means no compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """Lower the Pallas kernels for Mosaic: off the chip the backend is the
+    CPU, and ``_interpret_default`` would pick the interpreter."""
+    monkeypatch.setattr(fa, "_interpret_default", lambda: False)
+    monkeypatch.setattr(pa, "_interpret_default", lambda: False)
+
+
+def _compile(fn, *args):
+    # conftest runs the suite at "highest" matmul precision, which Mosaic
+    # refuses for bf16 operands; the chip runs at the default
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn).lower(*args).compile()
+    return compiled.as_text()
+
+
+@pytest.mark.parametrize("n_kv", [8704, 1024], ids=["cross_8704", "self_1024"])
+def test_flash_attention_packed_fwd_bwd(one_chip, mosaic, n_kv):
+    """Cross-attention after prefix dropout (7680 kept + 1024 latents) and
+    the latent self-attention, forward and backward."""
+    q = jax.ShapeDtypeStruct((TRAIN_CHUNK, LATENTS, CHANNELS), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((TRAIN_CHUNK, n_kv, CHANNELS), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = fa.flash_attention_packed(q, k, v, HEADS, causal=True, sm_scale=0.125)
+        return out.astype(jnp.float32).sum()
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    # forward + dKV + dQ kernels, none replaced by an einsum fallback
+    assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])
+def test_decode_attention_16k_cache(one_chip, cache_dtype):
+    """One decode token per sequence against a full-context cache: the
+    block-diagonal GEMM route of ``MultiHeadAttention`` (XLA, no kernel),
+    with the cache stored bf16 and int8."""
+    mha = MultiHeadAttention(
+        num_heads=HEADS, num_q_input_channels=CHANNELS, num_kv_input_channels=CHANNELS,
+        causal_attention=True, dtype=jnp.bfloat16,
+    )
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x = sds((SLOTS, 1, CHANNELS), jnp.bfloat16)
+    scales = sds((SLOTS, CONTEXT), jnp.bfloat16) if cache_dtype == jnp.int8 else None
+    cache = KVCache(
+        k=sds((SLOTS, CONTEXT, CHANNELS), cache_dtype),
+        v=sds((SLOTS, CONTEXT, CHANNELS), cache_dtype),
+        length=sds((), jnp.int32),
+        k_scale=scales,
+        v_scale=scales,
+    )
+    params = jax.eval_shape(
+        lambda: mha.init(jax.random.PRNGKey(0), jnp.zeros((1, 1, CHANNELS)), jnp.zeros((1, 1, CHANNELS)))
+    )
+    params = jax.tree.map(lambda p: sds(p.shape, p.dtype), params)
+
+    def step(params, x, cache):
+        out = mha.apply(params, x, x, kv_cache=cache)
+        return out.last_hidden_state, out.kv_cache
+
+    text = _compile(step, params, x, cache)
+    # the cache must reach the GEMMs in its stored dtype (no f32 copy of it)
+    assert f"f32[{SLOTS},{CONTEXT},{CHANNELS}]" not in text
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_paged_decode_attention_lowers(one_chip, mosaic, pool_dtype):
+    """The page-walk kernel at the serve phase's geometry: 8 slots, 16k
+    tokens per slot. Its q/out/bias blocks are single rows, which Mosaic
+    refuses unless they sit behind a unit axis (PR 23)."""
+    pages_per_slot = CONTEXT // PAGE
+    num_pages = 1 + SLOTS * pages_per_slot
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = PagedKVCache(
+        k=sds((num_pages, PAGE, CHANNELS), pool_dtype),
+        v=sds((num_pages, PAGE, CHANNELS), pool_dtype),
+        page_table=sds((SLOTS, pages_per_slot), jnp.int32),
+        length=sds((SLOTS,), jnp.int32),
+    )
+    assert pa.paged_kernel_supported(cache, HEADS, CHANNELS // HEADS, CHANNELS // HEADS)
+    q = sds((SLOTS, HEADS, CHANNELS // HEADS), pool_dtype)
+    text = _compile(pa.paged_decode_attention, q, cache)
+    assert "tpu_custom_call" in text

@@ -148,6 +148,65 @@ def test_sharded_training(mesh_shape):
         assert any("fsdp" in str(s.spec) for s in placed if hasattr(s, "spec"))
 
 
+def test_sharded_step_keeps_the_placed_layout():
+    """``make_train_step(mesh=...)`` hands the state back under the shardings
+    ``shard_train_state`` placed it in, so the second call builds nothing.
+    Unpinned, GSPMD returns small replicated parameters fsdp-sharded and the
+    second call compiles the step again."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 (virtual) devices")
+    mesh = make_mesh(data=2, fsdp=2, devices=jax.devices()[:4])
+    model = small_classifier()
+    batch = toy_batch(n=8)
+    params = model.init(jax.random.PRNGKey(0), batch["image"])
+    state = TrainState.create(model.apply, params, make_optimizer(1e-3), jax.random.PRNGKey(1))
+    # a threshold between the model's leaf sizes: some sharded, some replicated
+    sizes = sorted(p.size for p in jax.tree.leaves(params))
+    threshold = sizes[len(sizes) // 2]
+    state = shard_train_state(state, mesh, min_weight_size=threshold)
+    placed = [x.sharding for x in jax.tree.leaves(state)]
+    assert any("fsdp" in str(s.spec) for s in placed) and any(not any(s.spec) for s in placed)
+    batch = shard_batch(batch, mesh)
+
+    step = make_train_step(
+        classification_loss_fn(model.apply), donate=False, mesh=mesh, min_weight_size=threshold
+    )
+    for _ in range(2):
+        state, _ = step(state, batch)
+        assert [x.sharding for x in jax.tree.leaves(state)] == placed
+    assert step._cache_size() == 1
+
+
+def test_flash_kernels_run_per_batch_shard_under_a_mesh():
+    """GSPMD cannot partition a Mosaic kernel, so under ``kernel_mesh`` (what
+    ``make_train_step(mesh=...)`` traces its loss in) the packed flash
+    kernels sit in a shard_map over the batch axes: same values and
+    gradients as the unsharded call (interpret mode here)."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 (virtual) devices")
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from perceiver_io_tpu.ops.flash_attention import flash_attention_packed, kernel_mesh
+
+    mesh = make_mesh(data=2, fsdp=2, devices=jax.devices()[:4])
+    rng = np.random.default_rng(0)
+    q, k, v = (jnp.asarray(rng.normal(size=(4, n, 32)), jnp.float32) for n in (128, 256, 256))
+
+    def loss(q, k, v):
+        out = flash_attention_packed(q, k, v, num_heads=2, causal=True, block_q=64, block_kv=128)
+        return (out * out).sum()
+
+    want = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+    batch = NamedSharding(mesh, P(("data", "fsdp")))
+    sharded = [jax.device_put(x, batch) for x in (q, k, v)]
+    with kernel_mesh(mesh, ("data", "fsdp")):
+        step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+        assert "shard_map" in str(jax.make_jaxpr(loss)(*sharded))
+        got = step(*sharded)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.slow
 def test_gradient_accumulation():
     model = small_classifier()

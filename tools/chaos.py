@@ -169,11 +169,11 @@ import argparse
 import itertools
 import json
 import os
-import re
 import signal
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
 
 TOL = 1e-6
 
@@ -2042,35 +2042,19 @@ SCENARIOS = {
 
 
 def _respawn(scenarios, n_devices=8, phase=None, tmp=None) -> int:
-    """Re-exec scenarios in a subprocess with ``n_devices`` virtual CPU
-    devices (same bootstrap contract as
-    __graft_entry__._respawn_with_virtual_devices: set XLA_FLAGS before any
-    device query, force the platform via jax.config). ``phase``/``tmp``
-    pass through to the child's argv — the elastic scenarios use this to
-    run their kill and resume halves on DIFFERENT topologies over one
-    shared scratch dir."""
-    import subprocess
+    """Re-run scenarios in a subprocess with ``n_devices`` virtual CPU
+    devices (utils/compat.run_with_virtual_devices). ``phase``/``tmp`` pass
+    through to the child's argv — the elastic scenarios use this to run
+    their kill and resume halves on DIFFERENT topologies over one shared
+    scratch dir."""
+    from perceiver_io_tpu.utils.compat import run_with_virtual_devices
 
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    argv = ["chaos.py", "--scenarios", ",".join(scenarios)]
+    argv = [os.path.abspath(__file__), "--scenarios", ",".join(scenarios)]
     if phase:
         argv += ["--phase", phase]
     if tmp:
         argv += ["--tmp", tmp]
-    bootstrap = (
-        "import jax\n"
-        "jax.config.update('jax_platforms', 'cpu')\n"
-        f"import sys; sys.path.insert(0, {repo!r})\n"
-        f"import runpy; sys.argv = {argv!r}\n"
-        f"runpy.run_path({os.path.abspath(__file__)!r}, run_name='__main__')\n"
-    )
-    env = dict(os.environ)
-    env.pop("JAX_PLATFORMS", None)
-    env["_CHAOS_RESPAWNED"] = "1"
-    flags = re.sub(r"--xla_force_host_platform_device_count=\S+", "", env.get("XLA_FLAGS", ""))
-    env["XLA_FLAGS"] = (flags + f" --xla_force_host_platform_device_count={n_devices}").strip()
-    proc = subprocess.run([sys.executable, "-c", bootstrap], cwd=repo, env=env, timeout=540)
-    return proc.returncode
+    return run_with_virtual_devices(n_devices, argv, cwd=REPO, timeout=540).returncode
 
 
 def main(argv=None) -> int:
@@ -2116,15 +2100,11 @@ def main(argv=None) -> int:
     if args.phase and any(s not in ELASTIC_SCENARIOS for s in wanted):
         parser.error("--phase applies only to the elastic scenarios")
 
-    import jax
+    from perceiver_io_tpu.utils.compat import has_virtual_cpu_devices
 
     run_local = list(wanted)
     rc = 0
-    if (
-        "preempt_mesh" in run_local
-        and len(jax.devices()) < 8
-        and not os.environ.get("_CHAOS_RESPAWNED")
-    ):
+    if "preempt_mesh" in run_local and not has_virtual_cpu_devices(8):
         # mesh case needs 8 devices: run it in a virtual-device subprocess,
         # everything else in this process (the elastic scenarios manage
         # their OWN per-phase subprocesses and never need a parent respawn)

@@ -40,14 +40,16 @@ import os
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:  # `python tools/graphcheck.py` from anywhere
+    sys.path.insert(0, REPO)
 
 
 def _ensure_devices(n: int) -> None:
-    """Re-exec with ``n`` virtual CPU devices when fewer are visible
-    (shared respawn: utils/compat.respawn_cli_with_virtual_devices)."""
-    from perceiver_io_tpu.utils.compat import respawn_cli_with_virtual_devices
+    """Re-exec with ``n`` virtual CPU devices unless the environment
+    already provides them (utils/compat.ensure_cli_virtual_devices)."""
+    from perceiver_io_tpu.utils.compat import ensure_cli_virtual_devices
 
-    respawn_cli_with_virtual_devices(n, __file__, "_GRAPHCHECK_RESPAWNED")
+    ensure_cli_virtual_devices(n, __file__)
 
 
 def main(argv=None) -> int:

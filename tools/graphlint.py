@@ -41,9 +41,14 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 
-from perceiver_io_tpu.analysis.lintcli import (
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if _REPO not in sys.path:  # `python tools/graphlint.py` from anywhere
+    sys.path.insert(0, _REPO)
+
+from perceiver_io_tpu.analysis.lintcli import (  # noqa: E402
     add_common_lint_args,
     finish_lint,
     lint_crashed,
@@ -52,11 +57,11 @@ from perceiver_io_tpu.analysis.lintcli import (
 
 
 def _ensure_devices(n: int) -> None:
-    """Re-exec with ``n`` virtual CPU devices when fewer are visible
-    (shared respawn: utils/compat.respawn_cli_with_virtual_devices)."""
-    from perceiver_io_tpu.utils.compat import respawn_cli_with_virtual_devices
+    """Re-exec with ``n`` virtual CPU devices unless the environment
+    already provides them (utils/compat.ensure_cli_virtual_devices)."""
+    from perceiver_io_tpu.utils.compat import ensure_cli_virtual_devices
 
-    respawn_cli_with_virtual_devices(n, __file__, "_GRAPHLINT_RESPAWNED")
+    ensure_cli_virtual_devices(n, __file__)
 
 
 def main(argv=None) -> int:

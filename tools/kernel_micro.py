@@ -25,9 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_probe_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
 # flagship attention geometries at batch 4 (16k ctx, 1024 latents, 8 x 64
 # heads, 0.5 prefix dropout -> CA kv 8704)
 GEOMS = {
@@ -52,6 +49,9 @@ def roofline_ms(g, chain: str) -> float:
 
 
 def main():
+    from perceiver_io_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--variants", nargs="*", default=["none", "all"])
     p.add_argument("--geoms", nargs="*", default=["ca", "sa"])

@@ -1328,6 +1328,12 @@ def main(argv=None) -> int:
     args = p.parse_args(argv)
     if args.diff:
         return run_diff(args)
+    if not args.smoke:
+        # --smoke certifies counts (books, dumps, the event stream) and runs
+        # anywhere; every other run reports rates and latencies
+        from perceiver_io_tpu.utils.device import require_tpu
+
+        require_tpu("tools/loadgen.py without --smoke")
     if args.requests is None:
         args.requests = 24 if args.smoke else (
             240 if args.fleet else (400 if args.engine else 200)

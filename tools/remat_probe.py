@@ -21,9 +21,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_probe_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-
 
 def hbm_bytes(config, batch_size: int, latents: int, seq_len: int):
     from perceiver_io_tpu.models.text import CausalLanguageModel
@@ -60,6 +57,9 @@ def fmt(n):
 
 
 def main():
+    from perceiver_io_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     p = argparse.ArgumentParser()
     p.add_argument("--seq-len", type=int, default=6144)
     p.add_argument("--latents", type=int, default=2048)
