@@ -356,3 +356,60 @@ def summarize(
             for op, d in plane.per_op.most_common(top):
                 print_fn(f"  {d/1e9:9.3f} ms {plane.counts[op]:6d}x  {op[:100]}")
     return planes
+
+
+# ---------------------------------------------------------------------------
+# one capture on one timeline (jax.profiler.ProfileData)
+# ---------------------------------------------------------------------------
+
+DEVICE_PLANE_PREFIX = "/device:"
+DEVICE_OPS_LINE = "XLA Ops"
+HOST_PLANE = "/host:CPU"
+ENVIRONMENT_PLANE = "Task Environment"
+
+
+def load_capture(path: str) -> Dict:
+    """One capture as plain rows on the profiler's timeline, nanoseconds
+    since the capture started:
+
+    - ``profile_start_ns``: the epoch time (``time.time_ns()`` clock) of
+      that zero, from the ``Task Environment`` plane — subtract it from a
+      span row's ``start_ns`` to place the row on this timeline;
+    - ``length_ns``: the capture's length;
+    - ``device_ops``: ``{plane: [(name, start_ns, duration_ns), ...]}``,
+      each device plane's "XLA Ops" line under the instruction names;
+    - ``annotations``: ``[(name, start_ns, duration_ns, span_id), ...]``,
+      the host plane's events that carry a ``span_id`` stat — the spans an
+      ``obs.trace.Tracer`` opened while the capture ran.
+    """
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(resolve_capture(path))
+    start = stop = None
+    device_ops: Dict[str, List[tuple]] = {}
+    annotations: List[tuple] = []
+    for plane in data.planes:
+        if plane.name == ENVIRONMENT_PLANE:
+            stats = dict(plane.stats)
+            start, stop = stats.get("profile_start_time"), stats.get("profile_stop_time")
+        elif plane.name.startswith(DEVICE_PLANE_PREFIX):
+            for line in plane.lines:
+                if line.name == DEVICE_OPS_LINE:
+                    device_ops.setdefault(plane.name, []).extend(
+                        (e.name.split(" = ", 1)[0].lstrip("%"), float(e.start_ns), float(e.duration_ns))
+                        for e in line.events
+                    )
+        elif plane.name == HOST_PLANE:
+            for line in plane.lines:
+                for e in line.events:
+                    span_id = dict(e.stats).get("span_id")
+                    if span_id is not None:
+                        annotations.append((e.name, float(e.start_ns), float(e.duration_ns), str(span_id)))
+    if start is None:
+        raise ValueError(f"{path}: the capture has no '{ENVIRONMENT_PLANE}' plane with profile_start_time")
+    return {
+        "profile_start_ns": int(start),
+        "length_ns": int(stop) - int(start),
+        "device_ops": device_ops,
+        "annotations": sorted(annotations, key=lambda a: a[1]),
+    }

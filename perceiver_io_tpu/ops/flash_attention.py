@@ -450,15 +450,30 @@ def _interpret_default() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def _geometry(n_q: int, n_kv: int) -> str:
+    """The lengths an attention call was made with (before any padding to
+    block multiples), as they appear in its kernels' names."""
+    return f"q{n_q}_kv{n_kv}"
+
+
+def _kernel_name(pass_: str, geom: str) -> str:
+    """``flash_<pass>_q<n_q>_kv<n_kv>``: what a device trace prints for the
+    Mosaic call (XLA names the custom call after the innermost name scope,
+    and ``pallas_call(name=...)`` opens one), so a profile tells forward, dq
+    and dkv apart and a 16k cross-attention from a latent self-attention.
+    ``benchmarks/lib/flash_groups.py`` selects kernels by these names."""
+    return f"flash_{pass_}_{geom}"
+
+
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10)
+    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11)
 )
-def _flash(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2):
-    out, _ = _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2)
+def _flash(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom):
+    out, _ = _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom)
     return out
 
 
-def _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2):
+def _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom):
     bh, nq, d_qk = q.shape
     nkv = k.shape[1]
     d_v = v.shape[2]
@@ -487,6 +502,7 @@ def _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, 
             has_bias=bias is not None,
             v2=v2,
         ),
+        name=_kernel_name("fwd", geom),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -508,8 +524,8 @@ def _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, 
     return out, lse
 
 
-def _flash_fwd(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2):
-    out, lse = _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2)
+def _flash_fwd(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom):
+    out, lse = _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom)
     # the kernel emits lse broadcast across all 128 lanes (tiled loads);
     # keep ONE lane as the residual — at 48 attention calls per step the
     # full-lane buffers alone were ~3GB at batch 32 (measured, image
@@ -525,7 +541,7 @@ BWD_BLOCK_Q: Optional[int] = None
 BWD_BLOCK_KV: Optional[int] = None
 
 
-def _flash_bwd(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, residuals, g):
+def _flash_bwd(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom, residuals, g):
     q, k, v, bias, out, lse_col = residuals
     lse = jnp.broadcast_to(lse_col, lse_col.shape[:2] + (LANES,))
     bh, nq, d_qk = q.shape
@@ -574,6 +590,7 @@ def _flash_bwd(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, resid
             has_bias=has_bias,
             v2=v2,
         ),
+        name=_kernel_name("dkv", geom),
         grid=(bh, nkvb, nqb),
         in_specs=dkv_in_specs,
         out_specs=[
@@ -611,6 +628,7 @@ def _flash_bwd(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, resid
             has_bias=has_bias,
             v2=v2,
         ),
+        name=_kernel_name("dq", geom),
         grid=(bh, nqb, nkvb),
         in_specs=dq_in_specs,
         out_specs=[
@@ -833,15 +851,15 @@ def _dq_packed_kernel(
             dq_ref[0, :, hh * d_qk : (hh + 1) * d_qk] = dq_scr[hh].astype(dq_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
-def _flash_packed(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13))
+def _flash_packed(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom):
     out, _ = _flash_packed_fwd_impl(
-        q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2
+        q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom
     )
     return out
 
 
-def _flash_packed_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2):
+def _flash_packed_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom):
     b, nq, _ = q.shape
     nkv = k.shape[1]
     grid = (b, nq // block_q, nkv // block_kv)
@@ -872,6 +890,7 @@ def _flash_packed_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, blo
             has_bias=bias is not None,
             v2=v2,
         ),
+        name=_kernel_name("fwd", geom),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -893,16 +912,16 @@ def _flash_packed_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, blo
     return out, lse
 
 
-def _flash_packed_fwd(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2):
+def _flash_packed_fwd(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom):
     out, lse = _flash_packed_fwd_impl(
-        q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2
+        q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom
     )
     # slim residual: one lane per head (see the heads-major path note)
     lse_slim = lse.reshape(lse.shape[0], lse.shape[1], h, RES_LANES)[..., :1]
     return out, (q, k, v, bias, out, lse_slim)
 
 
-def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, residuals, g):
+def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom, residuals, g):
     q, k, v, bias, out, lse_slim = residuals
     b, nq, _ = q.shape
     nkv = k.shape[1]
@@ -957,6 +976,7 @@ def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v,
             has_bias=has_bias,
             v2=v2,
         ),
+        name=_kernel_name("dkv", geom),
         grid=(b, nkvb, nqb),
         in_specs=dkv_in_specs,
         out_specs=[
@@ -988,6 +1008,7 @@ def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v,
             has_bias=has_bias,
             v2=v2,
         ),
+        name=_kernel_name("dq", geom),
         grid=(b, nqb, nkvb),
         in_specs=dq_in_specs,
         out_specs=[
@@ -1075,7 +1096,8 @@ def flash_attention_packed(
 
     out = _on_batch_shards(
         lambda q_, k_, v_, bias_: _flash_packed(
-            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2
+            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2,
+            _geometry(nq, nkv),
         ),
         qf, kf, vf, bias,
     )
@@ -1364,14 +1386,14 @@ def _dq_2seg_kernel(
             dq_ref[0, :, hh * d_qk : (hh + 1) * d_qk] = dq_scr[hh].astype(dq_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15, 16))
 def _flash_packed_2seg(
     q, k_p, v_p, k_l, v_l, bias_p, bias_l,
-    prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2,
+    prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, geom,
 ):
     out, _ = _flash_packed_2seg_fwd_impl(
         q, k_p, v_p, k_l, v_l, bias_p, bias_l,
-        prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2,
+        prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, geom,
     )
     return out
 
@@ -1410,7 +1432,7 @@ def _2seg_bias_specs(order, npb, nlb, block_kv_p, block_kv_l):
 
 def _flash_packed_2seg_fwd_impl(
     q, k_p, v_p, k_l, v_l, bias_p, bias_l,
-    prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2,
+    prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, geom,
 ):
     b, nq, _ = q.shape
     npb = k_p.shape[1] // block_kv_p
@@ -1447,6 +1469,7 @@ def _flash_packed_2seg_fwd_impl(
             has_bias=has_bias,
             v2=v2,
         ),
+        name=_kernel_name("fwd", geom),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -1470,18 +1493,18 @@ def _flash_packed_2seg_fwd_impl(
 
 def _flash_packed_2seg_fwd(
     q, k_p, v_p, k_l, v_l, bias_p, bias_l,
-    prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2,
+    prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, geom,
 ):
     out, lse = _flash_packed_2seg_fwd_impl(
         q, k_p, v_p, k_l, v_l, bias_p, bias_l,
-        prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2,
+        prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, geom,
     )
     lse_slim = lse.reshape(lse.shape[0], lse.shape[1], h, RES_LANES)[..., :1]
     return out, (q, k_p, v_p, k_l, v_l, bias_p, bias_l, out, lse_slim)
 
 
 def _flash_packed_2seg_bwd(
-    prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, residuals, g
+    prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, geom, residuals, g
 ):
     q, k_p, v_p, k_l, v_l, bias_p, bias_l, out, lse_slim = residuals
     b, nq, _ = q.shape
@@ -1537,6 +1560,7 @@ def _flash_packed_2seg_bwd(
             has_bias=has_bias,
             v2=v2,
         ),
+        name=_kernel_name("dkv", geom),
         grid=(b, npb + nlb, nqb),
         in_specs=dkv_in_specs,
         out_specs=[
@@ -1589,6 +1613,7 @@ def _flash_packed_2seg_bwd(
             has_bias=has_bias,
             v2=v2,
         ),
+        name=_kernel_name("dq", geom),
         grid=(b, nqb, npb + nlb),
         in_specs=dq_in_specs,
         out_specs=[pl.BlockSpec((1, block_q, h * d_qk), lambda b_, i, j: (b_, i, 0))],
@@ -1682,7 +1707,7 @@ def flash_attention_packed_2seg(
 
     out = _flash_packed_2seg(
         qf, kpf, vpf, klf, vlf, bias_p, bias_l,
-        n_p, sm_scale, block_q, bkv_p, bkv_l, h, d_qk, d_v, v2,
+        n_p, sm_scale, block_q, bkv_p, bkv_l, h, d_qk, d_v, v2, _geometry(nq, n_p + n_l),
     )
     return out[:, :nq, :]
 
@@ -1751,7 +1776,7 @@ def flash_attention(
     # the batch axes keeps each device's rows aligned with its bias rows
     out = _on_batch_shards(
         lambda q_, k_, v_, bias_: _flash(
-            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, v2
+            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, v2, _geometry(nq, nkv)
         ),
         qf, kf, vf, bias,
     )

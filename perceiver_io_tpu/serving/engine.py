@@ -71,6 +71,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from perceiver_io_tpu.obs.trace import maybe_span
 from perceiver_io_tpu.serving.frontend import FrontEndRecord, RequestFrontEnd, _Ticket
 from perceiver_io_tpu.serving.pages import PageAllocator
 from perceiver_io_tpu.serving.prefix import PrefixIndex
@@ -209,7 +210,7 @@ class EngineFrontEnd(RequestFrontEnd):
             "pad_slots": jnp.zeros((s, caches[0].capacity), bool),
             "pos_shift": jnp.zeros((s, 1), jnp.int32),
         }
-        self._tracker = RecompileTracker(events=self.events)
+        self._tracker = RecompileTracker(events=self._tracer)
         if self._spec:
             from perceiver_io_tpu.generation import (
                 make_drafter,
@@ -273,6 +274,12 @@ class EngineFrontEnd(RequestFrontEnd):
         self._m_fill = r.gauge("engine_batch_fill_frac")
         self._m_pages = r.gauge("engine_kv_pages_used")
         self._m_pages_frac = r.gauge("engine_kv_pages_frac")
+        # the step and prefill odometers, at the boundaries of the
+        # engine/step and engine/prefill spans: steps with tokens out give
+        # the batch fill, prefill tokens what the joins cost
+        self._m_steps = r.counter("engine_steps_total")
+        self._m_prefills = r.counter("engine_prefills_total")
+        self._m_prefill_tokens = r.counter("engine_prefill_tokens_total")
         # Evictline counters + the parked-depth gauge (its .peak high-water
         # mark feeds the LOAD artifact's parked_depth_peak)
         self._m_evictions = r.counter("serve_evictions_total")
@@ -499,11 +506,59 @@ class EngineFrontEnd(RequestFrontEnd):
             self._state = dict(self._state, draft_cache=tuple(dcaches))
         return forked
 
+    def _grant_pages(self, ca_tokens: int, sa_tokens: int, matched: tuple = (),
+                     append_pos: Optional[int] = None):
+        """Both page grants of one join or resume, or None when pages are
+        short RIGHT NOW (nothing stays allocated): the CA grant (sharing the
+        ``matched`` run), the SA grant, and the copy-on-write fork of a
+        shared page the first decode append would write into."""
+        with maybe_span(self._tracer, "engine/page_grant", pages=0, evicted=0) as sp:
+            ca_grant = (
+                self.ca_alloc.alloc_tokens_shared(ca_tokens, matched)
+                if matched
+                else self.ca_alloc.alloc_tokens(ca_tokens)
+            )
+            if ca_grant is None:
+                return None
+            sa_grant = self.sa_alloc.alloc_tokens(sa_tokens)
+            if sa_grant is None:
+                self._free_ca(ca_grant)
+                return None
+            if ca_grant.shared_pages:
+                # COW guard: the first decode append (CA position prompt_len)
+                # must never write into a page a prefix co-owner still reads
+                forked = self._fork_shared_append_page(ca_grant, append_pos)
+                if forked is None:
+                    self._free_ca(ca_grant)
+                    self.sa_alloc.free(sa_grant)
+                    return None  # pool dry for the fork: wait like any alloc miss
+                ca_grant = forked
+            if sp is not None:
+                sp.set("pages", ca_grant.n_pages + sa_grant.n_pages)
+            return ca_grant, sa_grant
+
+    def _open_request_span(self, slot: "_EngineSlot") -> None:
+        """The slot's DETACHED span (no contextvar nesting): slot lifetimes
+        overlap and close out of LIFO order, which the nested span stack
+        cannot express — the span row is recorded at retire or eviction."""
+        if self._tracer is None:
+            return
+        attrs = {"request_id": slot.request_id}
+        if slot.ticket.record.tenant is not None:
+            attrs["tenant"] = slot.ticket.record.tenant
+        slot.span = self._tracer.detached("request", **attrs)
+
     def _try_join(self, ticket: _Ticket, slot_id: int) -> bool:
         """Prefill the ticket's request and land it in ``slot_id``. Returns
         False (ticket stays queued) when pages are short RIGHT NOW; raises
         nothing — a prefill failure books the request as a terminal error
         (pages freed), keeping the stream 1:1."""
+        rec = ticket.record
+        with maybe_span(self._tracer, "engine/join", request_index=rec.index,
+                        prompt_len=rec.prompt_len) as sp:
+            return self._join(ticket, slot_id, sp)
+
+    def _join(self, ticket: _Ticket, slot_id: int, sp) -> bool:
         import jax
 
         jnp = self._jnp
@@ -513,26 +568,12 @@ class EngineFrontEnd(RequestFrontEnd):
         ca_tokens = rec.prompt_len + rec.max_new_tokens + self._spec_slack
         sa_tokens = self.num_latents + rec.max_new_tokens + self._spec_slack
         matched = self._match_prefix(ticket)
-        ca_grant = (
-            self.ca_alloc.alloc_tokens_shared(ca_tokens, matched)
-            if matched
-            else self.ca_alloc.alloc_tokens(ca_tokens)
-        )
-        if ca_grant is None:
+        if sp is not None:
+            sp.set("prefix_pages", len(matched))
+        grants = self._grant_pages(ca_tokens, sa_tokens, matched, rec.prompt_len)
+        if grants is None:
             return False
-        sa_grant = self.sa_alloc.alloc_tokens(sa_tokens)
-        if sa_grant is None:
-            self._free_ca(ca_grant)
-            return False
-        if ca_grant.shared_pages:
-            # COW guard: the first decode append (CA position prompt_len)
-            # must never write into a page a prefix co-owner still reads
-            forked = self._fork_shared_append_page(ca_grant, rec.prompt_len)
-            if forked is None:
-                self._free_ca(ca_grant)
-                self.sa_alloc.free(sa_grant)
-                return False  # pool dry for the fork: wait like any alloc miss
-            ca_grant = forked
+        ca_grant, sa_grant = grants
         self._queue.remove(ticket)
         self._set_queue_gauge()
         now = float(self._clock())
@@ -542,51 +583,48 @@ class EngineFrontEnd(RequestFrontEnd):
                            ca_grant=ca_grant, sa_grant=sa_grant)
         slot.t_joined = self._now_s()
         self._tenant_pages_delta(rec, ca_grant.n_pages + sa_grant.n_pages)
-        if self.events is not None and self._tracer is not None:
-            # DETACHED span (no contextvar nesting): slot lifetimes overlap
-            # and close out of LIFO order, which the nested span stack
-            # cannot express — the span row is recorded at retire
-            from perceiver_io_tpu.obs.trace import Span
-
-            attrs = {"request_id": slot.request_id}
-            if rec.tenant is not None:
-                attrs["tenant"] = rec.tenant
-            slot.span = Span(name="request", parent_id=None, attrs=attrs)
+        self._open_request_span(slot)
+        if sp is not None:
+            sp.set("request_id", slot.request_id)
         compiles0 = self._tracker.total_compiles
         t0 = self._now_s()
         try:
-            if self._injector is not None:
-                self._injector.before_attempt(rec.index)
-            serve_params = (
-                self._injector.params_for(rec.index, self.params)
-                if self._injector is not None
-                else self.params
-            )
-            rng = jax.random.PRNGKey(int(ticket.spec.rng_seed))
-            if matched:
-                # Shareline: the matched run's CA rows are already resident
-                # in pool pages — gather them and prefill the suffix alone.
-                # rng handling is IDENTICAL to the unshared prefill (one
-                # split for the first sample), so the stream is token-exact.
-                skip = len(matched) * self.engine_config.page_size
-                shared_prefill = self._shared_prefill_for(
-                    skip, rec.prompt_len, rec.max_new_tokens
+            with maybe_span(self._tracer, "engine/prefill", prompt_len=rec.prompt_len,
+                            shared=bool(matched)) as psp:
+                if self._injector is not None:
+                    self._injector.before_attempt(rec.index)
+                serve_params = (
+                    self._injector.params_for(rec.index, self.params)
+                    if self._injector is not None
+                    else self.params
                 )
-                ca_pool = self._state["cache"][0]
-                token, pstate = shared_prefill(
-                    serve_params,
-                    jnp.asarray(ticket.spec.input_ids)[:, skip:],
-                    ca_pool.k,
-                    ca_pool.v,
-                    jnp.asarray(matched, jnp.int32),
-                    rng,
-                )
-            else:
-                prefill = self._prefill_for(rec.max_new_tokens)
-                token, pstate = prefill(
-                    serve_params, jnp.asarray(ticket.spec.input_ids), None, rng
-                )
-            first = int(token[0])
+                rng = jax.random.PRNGKey(int(ticket.spec.rng_seed))
+                if matched:
+                    # Shareline: the matched run's CA rows are already resident
+                    # in pool pages — gather them and prefill the suffix alone.
+                    # rng handling is IDENTICAL to the unshared prefill (one
+                    # split for the first sample), so the stream is token-exact.
+                    skip = len(matched) * self.engine_config.page_size
+                    shared_prefill = self._shared_prefill_for(
+                        skip, rec.prompt_len, rec.max_new_tokens
+                    )
+                    ca_pool = self._state["cache"][0]
+                    token, pstate = shared_prefill(
+                        serve_params,
+                        jnp.asarray(ticket.spec.input_ids)[:, skip:],
+                        ca_pool.k,
+                        ca_pool.v,
+                        jnp.asarray(matched, jnp.int32),
+                        rng,
+                    )
+                else:
+                    prefill = self._prefill_for(rec.max_new_tokens)
+                    token, pstate = prefill(
+                        serve_params, jnp.asarray(ticket.spec.input_ids), None, rng
+                    )
+                first = int(token[0])
+                if psp is not None:
+                    psp.set("compiled", self._tracker.total_compiles > compiles0)
         except Exception as e:  # noqa: BLE001 — books close, pages return
             self._free_ca(ca_grant)
             self.sa_alloc.free(sa_grant)
@@ -597,6 +635,8 @@ class EngineFrontEnd(RequestFrontEnd):
             return True  # the ticket reached a terminal outcome
         slot.ttft_s = self._now_s() - t0
         rec.attempts += 1
+        self._m_prefills.inc()
+        self._m_prefill_tokens.inc(rec.prompt_len - len(matched) * self.engine_config.page_size)
         slot.compiled = self._tracker.total_compiles > compiles0
         slot.tokens_out = 1
         slot.first_token = first
@@ -637,7 +677,7 @@ class EngineFrontEnd(RequestFrontEnd):
                     row["tenant"] = rec.tenant
                 if slot.span is not None:
                     row["span_id"] = slot.span.span_id
-                self.events.emit("serve.prefix_hit", **row)
+                self._emit("serve.prefix_hit", **row)
         if not slot.compiled:
             self._m_ttft.record(slot.ttft_s)
         # the per-token seam fires for token 0 exactly like the sequential
@@ -688,8 +728,7 @@ class EngineFrontEnd(RequestFrontEnd):
         if slot.span is not None:
             slot.span.set("outcome", outcome)
             slot.span.set("tokens_out", slot.tokens_out)
-            self._tracer.record(slot.span)
-            self._tracer.flush()  # span row BEFORE the request row
+            self._tracer.record(slot.span)  # queued BEFORE the request row
         if emit and self.events is not None:
             row = dict(
                 request_id=slot.request_id,
@@ -719,7 +758,7 @@ class EngineFrontEnd(RequestFrontEnd):
                 row[f"tpot_p{p}_s"] = slot.hist.percentile(p)
             if rec.error is not None:
                 row["error"] = rec.error
-            self.events.emit("request", **row)
+            self._emit("request", **row)
         self._m_requests.inc()
         self._m_tokens.inc(slot.tokens_out)
         if self.events is not None:
@@ -727,20 +766,21 @@ class EngineFrontEnd(RequestFrontEnd):
             # gauges (batch fill, page use) land in `metrics` rows while the
             # batch is still live, not only after the drain zeroes them
             self.registry.maybe_emit(
-                self.events, min_interval_s=self.config.snapshot_interval_s
+                self._tracer, min_interval_s=self.config.snapshot_interval_s
             )
 
     def _retire_slot(self, slot_id: int, outcome: str) -> None:
-        slot = self._slots[slot_id]
-        self._slots[slot_id] = None
-        self._in_flight -= 1
-        self._free_ca(slot.ca_grant)
-        self.sa_alloc.free(slot.sa_grant)
-        self._tenant_pages_delta(slot.ticket.record,
-                                 -(slot.ca_grant.n_pages + slot.sa_grant.n_pages))
-        self._state = self._retire_fn(self._state, self._jnp.int32(slot_id))
-        self._retire_books(slot, outcome, emit=True)
-        self._busy_until = float(self._clock())
+        with maybe_span(self._tracer, "engine/retire", outcome=outcome):
+            slot = self._slots[slot_id]
+            self._slots[slot_id] = None
+            self._in_flight -= 1
+            self._free_ca(slot.ca_grant)
+            self.sa_alloc.free(slot.sa_grant)
+            self._tenant_pages_delta(slot.ticket.record,
+                                     -(slot.ca_grant.n_pages + slot.sa_grant.n_pages))
+            self._state = self._retire_fn(self._state, self._jnp.int32(slot_id))
+            self._retire_books(slot, outcome, emit=True)
+            self._busy_until = float(self._clock())
 
     # -- eviction / park / resume (Evictline) --------------------------------
 
@@ -790,7 +830,6 @@ class EngineFrontEnd(RequestFrontEnd):
             slot.span.set("tokens_out", slot.tokens_out)
             span_id = slot.span.span_id
             self._tracer.record(slot.span)
-            self._tracer.flush()
         slot.span = None
         self._parked.append(slot)
         self._m_parked.set(len(self._parked))
@@ -803,7 +842,7 @@ class EngineFrontEnd(RequestFrontEnd):
                 row["tenant"] = rec.tenant
             if span_id is not None:
                 row["span_id"] = span_id
-            self.events.emit("serve.evict", **row)
+            self._emit("serve.evict", **row)
 
     def _evict_for(self, ticket: _Ticket) -> bool:
         """Reclaim pages for a queued request that fits the pool but not the
@@ -816,15 +855,20 @@ class EngineFrontEnd(RequestFrontEnd):
         rec = ticket.record
         ca_tokens = rec.prompt_len + rec.max_new_tokens + self._spec_slack
         sa_tokens = self.num_latents + rec.max_new_tokens + self._spec_slack
-        while not (
-            self.ca_alloc.can_fit_now(ca_tokens)
-            and self.sa_alloc.can_fit_now(sa_tokens)
-        ):
-            victim = self._select_victim()
-            if victim is None:
-                return False
-            self._evict_slot(victim)
-        return True
+        with maybe_span(self._tracer, "engine/page_grant", pages=0, evicted=0) as sp:
+            evicted = 0
+            while not (
+                self.ca_alloc.can_fit_now(ca_tokens)
+                and self.sa_alloc.can_fit_now(sa_tokens)
+            ):
+                victim = self._select_victim()
+                if victim is None:
+                    return False
+                self._evict_slot(victim)
+                evicted += 1
+                if sp is not None:
+                    sp.set("evicted", evicted)
+            return True
 
     def _park_terminal(self, slot: "_EngineSlot", outcome: str) -> None:
         """A parked request reaching a terminal outcome WITHOUT re-entering a
@@ -844,6 +888,11 @@ class EngineFrontEnd(RequestFrontEnd):
         batched step matches token-exactly. Returns False only when pages
         are short RIGHT NOW (the request stays parked); a replay failure
         books a terminal ``error`` exactly like a join failure."""
+        with maybe_span(self._tracer, "engine/resume", request_id=slot.request_id,
+                        request_index=slot.ticket.record.index):
+            return self._resume(slot, slot_id)
+
+    def _resume(self, slot: "_EngineSlot", slot_id: int) -> bool:
         import jax
 
         jnp = self._jnp
@@ -856,14 +905,10 @@ class EngineFrontEnd(RequestFrontEnd):
         # (num_latents + n) + remaining = num_latents + budget
         ca_tokens = rec.prompt_len + rec.max_new_tokens + self._spec_slack
         sa_tokens = self.num_latents + rec.max_new_tokens + self._spec_slack
-        ca_grant = self.ca_alloc.alloc_tokens(ca_tokens)
-        if ca_grant is None:
+        grants = self._grant_pages(ca_tokens, sa_tokens)
+        if grants is None:
             return False
-        sa_grant = self.sa_alloc.alloc_tokens(sa_tokens)
-        if sa_grant is None:
-            self._free_ca(ca_grant)
-            return False
-        slot.ca_grant, slot.sa_grant = ca_grant, sa_grant
+        slot.ca_grant, slot.sa_grant = ca_grant, sa_grant = grants
         self._tenant_pages_delta(rec, ca_grant.n_pages + sa_grant.n_pages)
         emitted = self.served_tokens[idx]
         replay_ids = np.concatenate(
@@ -871,28 +916,26 @@ class EngineFrontEnd(RequestFrontEnd):
              np.asarray([emitted], np.int32)],
             axis=1,
         )
-        if self.events is not None and self._tracer is not None:
-            from perceiver_io_tpu.obs.trace import Span
-
-            attrs = {"request_id": slot.request_id}
-            if rec.tenant is not None:
-                attrs["tenant"] = rec.tenant
-            slot.span = Span(name="request", parent_id=None, attrs=attrs)
+        self._open_request_span(slot)
         compiles0 = self._tracker.total_compiles
         try:
-            if self._injector is not None:
-                self._injector.before_attempt(idx)
-            from perceiver_io_tpu.generation import advance_rng_chain
+            with maybe_span(self._tracer, "engine/prefill", prompt_len=replay_ids.shape[1],
+                            shared=False) as psp:
+                if self._injector is not None:
+                    self._injector.before_attempt(idx)
+                from perceiver_io_tpu.generation import advance_rng_chain
 
-            prefill = self._prefill_for(remaining, num_latents=self.num_latents + n)
-            serve_params = (
-                self._injector.params_for(idx, self.params)
-                if self._injector is not None
-                else self.params
-            )
-            rng = advance_rng_chain(jax.random.PRNGKey(int(slot.ticket.spec.rng_seed)), n)
-            token, pstate = prefill(serve_params, jnp.asarray(replay_ids), None, rng)
-            first = int(token[0])
+                prefill = self._prefill_for(remaining, num_latents=self.num_latents + n)
+                serve_params = (
+                    self._injector.params_for(idx, self.params)
+                    if self._injector is not None
+                    else self.params
+                )
+                rng = advance_rng_chain(jax.random.PRNGKey(int(slot.ticket.spec.rng_seed)), n)
+                token, pstate = prefill(serve_params, jnp.asarray(replay_ids), None, rng)
+                first = int(token[0])
+                if psp is not None:
+                    psp.set("compiled", self._tracker.total_compiles > compiles0)
         except Exception as e:  # noqa: BLE001 — books close, pages return
             self._free_ca(ca_grant)
             self.sa_alloc.free(sa_grant)
@@ -903,6 +946,8 @@ class EngineFrontEnd(RequestFrontEnd):
             self._park_terminal(slot, "error")
             return True  # reached a terminal outcome
         rec.attempts += 1
+        self._m_prefills.inc()
+        self._m_prefill_tokens.inc(replay_ids.shape[1])
         slot.compiled = slot.compiled or self._tracker.total_compiles > compiles0
         slot.tokens_out = n + 1
         slot.slot_id = slot_id
@@ -935,7 +980,7 @@ class EngineFrontEnd(RequestFrontEnd):
                 row["tenant"] = rec.tenant
             if slot.span is not None:
                 row["span_id"] = slot.span.span_id
-            self.events.emit("serve.resume", **row)
+            self._emit("serve.resume", **row)
         # the per-token seam fires for the replayed prefill's sample exactly
         # like a join's token 0 (injector / cancel / deadline)
         self._token_seam(slot, slot.tokens_out - 1)
@@ -1163,9 +1208,8 @@ class EngineFrontEnd(RequestFrontEnd):
                     ) as sp:
                         sp.set("outcome", "recovered")
                         sp.set("tokens_resumed", len(tokens))
-                    self._tracer.flush()  # span row BEFORE the recover row
-                    row["span_id"] = sp.span_id
-                self.events.emit("serve.recover", **row)
+                    row["span_id"] = sp.span_id  # its row is queued BEFORE the recover row
+                self._emit("serve.recover", **row)
             if slot is not None:
                 if len(tokens) >= rec.max_new_tokens or (
                     eos is not None and tokens[-1] == eos
@@ -1201,6 +1245,16 @@ class EngineFrontEnd(RequestFrontEnd):
         Page backpressure stops the fill — with ``eviction`` enabled a
         blocked queue head may first reclaim pages from the least-progressed
         slot (:meth:`_evict_for`); it never sheds."""
+        with maybe_span(self._tracer, "engine/fill", queue_depth=len(self._queue),
+                        parked=len(self._parked), joins=0) as sp:
+            joins = self._fill()
+            if sp is not None:
+                sp.set("joins", joins)
+
+    def _fill(self) -> int:
+        """The fill itself; returns how many queued tickets it joined (a
+        join that ended in a terminal error counts: its prefill ran)."""
+        joins = 0
         self._resume_parked()
         for slot_id, occupant in enumerate(self._slots):
             if occupant is not None:
@@ -1231,9 +1285,11 @@ class EngineFrontEnd(RequestFrontEnd):
                     # enabled) reclaims from the least-progressed slot so
                     # the queue head proceeds; otherwise backpressure
                     if not self._evict_for(ticket) or not self._try_join(ticket, slot_id):
-                        return  # keep the queue; pages will come back
+                        return joins  # keep the queue; pages will come back
+                joins += 1
                 break  # joined (or terminally booked) — next slot
         self._update_gauges()
+        return joins
 
     def sharing_audit(self) -> List[str]:
         """Cross-layer sharing invariants (empty = clean): both allocators'
@@ -1280,8 +1336,9 @@ class EngineFrontEnd(RequestFrontEnd):
             elif slot.tokens_out >= slot.ticket.record.max_new_tokens:
                 self._retire_slot(slot_id, "ok")
 
-    def _engine_step(self) -> None:
-        """One batched decode step + per-slot accounting/retires. In the
+    def _engine_step(self) -> int:
+        """One batched decode step + per-slot accounting/retires; returns
+        the tokens emitted. In the
         speculative slot mode a step emits ``m ∈ [1, spec_k+1]`` tokens per
         slot — EVERY emitted token streams through the same per-token seam
         (injector / cancel / deadline), so mid-SPAN cancellation retires the
@@ -1290,22 +1347,40 @@ class EngineFrontEnd(RequestFrontEnd):
         self._sweep_terminal()
         active = self._active_ids()
         if not active:
-            return
+            return 0
+        tr = self._tracer
         compiles0 = self._tracker.total_compiles
         t0 = self._now_s()
-        if self._spec:
-            self._state, tokens, m = self._step_fn(self._decode_params, self._state)
-            tokens, m = np.asarray(tokens), np.asarray(m)
-        else:
-            self._state, tokens = self._step_fn(self._decode_params, self._state)
-            tokens = np.asarray(tokens)[:, None]  # ONE host fetch either way
-            m = np.ones(len(self._slots), np.int64)
+        with maybe_span(tr, "engine/decode_dispatch"):
+            if self._spec:
+                self._state, tokens, m = self._step_fn(self._decode_params, self._state)
+            else:
+                self._state, tokens = self._step_fn(self._decode_params, self._state)
+        with maybe_span(tr, "engine/token_fetch"):
+            if self._spec:
+                tokens, m = np.asarray(tokens), np.asarray(m)
+            else:
+                tokens = np.asarray(tokens)[:, None]  # ONE host fetch either way
+                m = np.ones(len(self._slots), np.int64)
         dt = self._now_s() - t0
         self._engine_steps += 1
+        self._m_steps.inc()
         self._fill_sum += len(active)
         cold_step = self._tracker.total_compiles > compiles0
+        with maybe_span(tr, "engine/account", tokens=0) as sp:
+            n_tokens = self._account(active, tokens, m, dt, cold_step)
+            if sp is not None:
+                sp.set("tokens", n_tokens)
+        self._update_gauges()
+        return n_tokens
+
+    def _account(self, active: List[int], tokens, m, dt: float, cold_step: bool) -> int:
+        """The per-slot half of a step: every emitted token through the
+        seam, the histograms, one journal append per slot, and the retires.
+        Returns the tokens emitted."""
         batch_size = len(active)
         eos = self._gen_config.eos_token_id
+        n_tokens = 0
         for slot_id in active:
             slot = self._slots[slot_id]
             rec = slot.ticket.record
@@ -1337,6 +1412,7 @@ class EngineFrontEnd(RequestFrontEnd):
                 if eos is not None and tok == eos:
                     finished = True
                     break
+            n_tokens += len(emitted_now)
             if self.journal is not None and emitted_now:
                 # one progress record per slot per step (not per token):
                 # delivery stays at-least-once — tokens emitted after the
@@ -1349,7 +1425,7 @@ class EngineFrontEnd(RequestFrontEnd):
                 self._retire_slot(slot_id, slot.outcome)
             elif finished:
                 self._retire_slot(slot_id, "ok")
-        self._update_gauges()
+        return n_tokens
 
     def cancel(self, request_index: int) -> bool:
         """Cancel a queued request, one live in a decode SLOT — the slot
@@ -1378,6 +1454,31 @@ class EngineFrontEnd(RequestFrontEnd):
 
     # -- driving (overrides the sequential service loop) ---------------------
 
+    def _turn(self) -> None:
+        """One turn of the service loop: fill, then the decode step, as one
+        ``engine/step`` span. While it is open every span and event row
+        stays in memory (``Tracer.hold``); what a reader waits for (a
+        ``request`` row, a ``compile`` event) is written right after it
+        closed, once, under ``engine/flush``."""
+        tr = self._tracer
+        if tr is None:
+            self._fill_slots()
+            self._engine_step()
+            return
+        with tr.hold(), tr.span("engine/step", step=self._engine_steps) as sp:
+            self._fill_slots()
+            sp.set("active", len(self._active_ids()))
+            sp.set("tokens", self._engine_step() or 0)
+        if tr.flush_due():
+            with tr.span("engine/flush"):
+                tr.flush()
+
+    def _end_drive(self) -> None:
+        """After a drive loop: nothing a finished ``pump``/``run_*`` recorded
+        stays unwritten."""
+        if self._tracer is not None:
+            self._tracer.flush()
+
     def pump(self, max_requests: Optional[int] = None) -> int:
         """Drive the engine until the queue AND the batch drain (or until
         ``max_requests`` reached terminal outcomes)."""
@@ -1388,12 +1489,12 @@ class EngineFrontEnd(RequestFrontEnd):
         # NOTHING queued or in a slot — everything it owes is parked
         while self._queue or self._active_ids() or self._parked:
             self._check_guard()
-            self._fill_slots()
-            self._engine_step()
+            self._turn()
             done = sum(self._n[o] for o in
                        ("ok", "error", "timeout", "cancelled")) - terminal0
             if max_requests is not None and done >= max_requests:
                 break
+        self._end_drive()
         return done
 
     def run_closed(self, specs, *, concurrency: int = 4,
@@ -1418,8 +1519,8 @@ class EngineFrontEnd(RequestFrontEnd):
             admit()
             if not (self._queue or self._active_ids() or self._parked):
                 continue
-            self._fill_slots()
-            self._engine_step()
+            self._turn()
+        self._end_drive()
         if self._draining:
             self.drain()
         return out
@@ -1456,8 +1557,8 @@ class EngineFrontEnd(RequestFrontEnd):
                         self.submit(spec, arrival_s=t0 + off, deadline_s=deadline_s)
                     )
                 continue
-            self._fill_slots()
-            self._engine_step()
+            self._turn()
+        self._end_drive()
         if self._draining:
             self.drain()
         return out

@@ -255,7 +255,7 @@ class RequestFrontEnd:
         from perceiver_io_tpu.obs import trace as obs_trace
 
         self._trace_mod = obs_trace
-        self._tracer = obs_trace.Tracer(events, flush_every=1) if events is not None else None
+        self._tracer = obs_trace.Tracer(events) if events is not None else None
         r = self.registry
         self._m_submitted = r.counter("serve_submitted_total")
         self._m_admitted = r.counter("serve_admitted_total")
@@ -319,7 +319,14 @@ class RequestFrontEnd:
     def _on_breaker(self, prev: str, new: str, reason: str, detail: dict) -> None:
         self._m_breaker_state.set(STATE_VALUES[new])
         if self.events is not None:
-            self.events.emit("serve.breaker", state=new, prev=prev, reason=reason, **detail)
+            self._emit("serve.breaker", state=new, prev=prev, reason=reason, **detail)
+
+    def _emit(self, event: str, **fields) -> None:
+        """One event row, through the tracer's queue: it is written behind
+        the span rows recorded before it, at once here, and in the engine
+        after the open ``engine/step`` has closed (``Tracer.hold``)."""
+        if self._tracer is not None:
+            self._tracer.emit(event, **fields)
 
     def _set_queue_gauge(self) -> None:
         depth = len(self._queue)
@@ -637,7 +644,7 @@ class RequestFrontEnd:
         def on_retry(attempt: int, exc: BaseException, delay: float) -> None:
             self._m_retries.inc()
             if self.events is not None:
-                self.events.emit(
+                self._emit(
                     "serve.retry", request_index=rec.index, attempt=int(attempt),
                     error=str(exc), delay_s=round(delay, 6),
                 )
@@ -657,8 +664,7 @@ class RequestFrontEnd:
                 sp.set("outcome", rec.outcome)
                 if rec.tenant is not None:
                     sp.set("tenant", rec.tenant)
-            self._tracer.flush()  # span row lands BEFORE the request row
-            span_id = sp.span_id
+            span_id = sp.span_id  # its row is queued BEFORE the request row
         row = dict(
             request_id=request_id,
             batch=rec.batch,
@@ -673,7 +679,7 @@ class RequestFrontEnd:
             row["tenant"] = rec.tenant
         if span_id is not None:
             row["span_id"] = span_id
-        self.events.emit("request", **row)
+        self._emit("request", **row)
 
     # -- driving ------------------------------------------------------------
 
@@ -681,7 +687,7 @@ class RequestFrontEnd:
         if self._guard is not None and self._guard.requested and not self._draining:
             self._draining = True
             if self.events is not None:
-                self.events.emit("serve.preempt", queued=len(self._queue),
+                self._emit("serve.preempt", queued=len(self._queue),
                                  in_flight=self._in_flight)
 
     def pump(self, max_requests: Optional[int] = None) -> int:
@@ -786,7 +792,7 @@ class RequestFrontEnd:
             self.registry.maybe_emit(self.events, min_interval_s=0.0)
         books = self.books()
         if self.events is not None:
-            self.events.emit("serve.drain", finished=finished, books=books)
+            self._emit("serve.drain", finished=finished, books=books)
         return books
 
     # -- the books ----------------------------------------------------------

@@ -369,13 +369,7 @@ class SimEngineFrontEnd(EngineFrontEnd):
                            ca_grant=ca_grant, sa_grant=sa_grant)
         slot.t_joined = self._now_s()
         self._tenant_pages_delta(rec, ca_grant.n_pages + sa_grant.n_pages)
-        if self.events is not None and self._tracer is not None:
-            from perceiver_io_tpu.obs.trace import Span
-
-            attrs = {"request_id": slot.request_id}
-            if rec.tenant is not None:
-                attrs["tenant"] = rec.tenant
-            slot.span = Span(name="request", parent_id=None, attrs=attrs)
+        self._open_request_span(slot)
         # the sampled prefill IS the service: it advances the timeline. A
         # matched join is charged only the UNMATCHED token fraction — the
         # real shared prefill skips exactly the matched pages' embed +
@@ -415,16 +409,16 @@ class SimEngineFrontEnd(EngineFrontEnd):
                     row["tenant"] = rec.tenant
                 if slot.span is not None:
                     row["span_id"] = slot.span.span_id
-                self.events.emit("serve.prefix_hit", **row)
+                self._emit("serve.prefix_hit", **row)
         self._m_ttft.record(ttft)
         self._token_seam(slot, 0)
         return True
 
-    def _engine_step(self) -> None:
+    def _engine_step(self) -> int:
         self._sweep_terminal()
         active = self._active_ids()
         if not active:
-            return
+            return 0
         # one batched decode step: lockstep, so the step's wall is the MAX
         # over the active slots' sampled per-token times — the slowest slot
         # gates the batch, the interference the noisy-neighbor scenario
@@ -456,6 +450,7 @@ class SimEngineFrontEnd(EngineFrontEnd):
             elif slot.tokens_out >= rec.max_new_tokens:
                 self._retire_slot(slot_id, "ok")
         self._update_gauges()
+        return len(active)
 
     def _try_resume(self, slot, slot_id: int) -> bool:
         rec = slot.ticket.record
@@ -468,13 +463,7 @@ class SimEngineFrontEnd(EngineFrontEnd):
             return False
         slot.ca_grant, slot.sa_grant = ca_grant, sa_grant
         self._tenant_pages_delta(rec, ca_grant.n_pages + sa_grant.n_pages)
-        if self.events is not None and self._tracer is not None:
-            from perceiver_io_tpu.obs.trace import Span
-
-            attrs = {"request_id": slot.request_id}
-            if rec.tenant is not None:
-                attrs["tenant"] = rec.tenant
-            slot.span = Span(name="request", parent_id=None, attrs=attrs)
+        self._open_request_span(slot)
         # resume replay costs one prefill-shaped service span (prompt +
         # served prefix), exactly the real engine's replay structure
         self.clock.advance(
@@ -501,7 +490,7 @@ class SimEngineFrontEnd(EngineFrontEnd):
                 row["tenant"] = rec.tenant
             if slot.span is not None:
                 row["span_id"] = slot.span.span_id
-            self.events.emit("serve.resume", **row)
+            self._emit("serve.resume", **row)
         self._token_seam(slot, slot.tokens_out - 1)
         return True
 

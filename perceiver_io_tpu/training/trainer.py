@@ -111,7 +111,10 @@ class TrainerConfig:
     # host spans (obs/trace.py): a `fit` span wrapping the run (published
     # ambient, so producer-thread events — fault.poison_batch /
     # fault.fetch_retry — attach to it), a per-step `step` span carrying
-    # input_wait_ms/dispatch_ms attrs, and `checkpoint`/`eval` spans; every
+    # input_wait_ms/dispatch_ms attrs with its `train/input_wait`,
+    # `train/dispatch` and (at a log boundary) `train/metrics_fetch` child
+    # spans, and `checkpoint`/`eval` spans; each also enters the profiler's
+    # host plane under its name while a capture runs; every
     # fault.*/resume/graphlint/compile event emitted inside one is stamped
     # with its span_id, making incidents attributable to the exact step.
     # Span rows are buffered and flushed at log boundaries and fit exits
@@ -633,16 +636,17 @@ class Trainer:
                     # input_wait: host time BLOCKED obtaining the batch this
                     # step consumes — the double buffer below drives it to ~0
                     t_in = time.perf_counter()
-                    if pending_exc is not None:
-                        # a deferred prefetch failure surfaces HERE, where the
-                        # pre-double-buffer loop would have hit it — after the
-                        # previous step's log/eval/checkpoint ran
-                        exc, pending_exc = pending_exc, None
-                        raise exc
-                    if pending_batch is not None:
-                        batch, pending_batch = pending_batch, None
-                    else:
-                        batch = self._prepare_batch(next(train_iter))
+                    with maybe_span(tracer, "train/input_wait"):
+                        if pending_exc is not None:
+                            # a deferred prefetch failure surfaces HERE, where the
+                            # pre-double-buffer loop would have hit it — after the
+                            # previous step's log/eval/checkpoint ran
+                            exc, pending_exc = pending_exc, None
+                            raise exc
+                        if pending_batch is not None:
+                            batch, pending_batch = pending_batch, None
+                        else:
+                            batch = self._prepare_batch(next(train_iter))
                     step_wait_s = time.perf_counter() - t_in
                     input_wait_s += step_wait_s
                     if step_span is not None:
@@ -662,7 +666,8 @@ class Trainer:
                             if cfg.graphcheck:
                                 self._graphcheck(events, state, batch, closed)
                     t_dispatch = time.perf_counter()
-                    state, metrics = self._train_step(state, batch)
+                    with maybe_span(tracer, "train/dispatch"):
+                        state, metrics = self._train_step(state, batch)
                     if (
                         probe_ring is not None
                         and isinstance(metrics, dict)
@@ -795,10 +800,12 @@ class Trainer:
                     # (an entirely-skipped window has no rows to average —
                     # the fault.skip events already tell that story)
                     if (step % cfg.log_interval == 0 or step == cfg.max_steps) and window:
-                        avg = {
-                            cfg.metric_prefix_train + k: float(np.mean([float(m[k]) for m in window]))
-                            for k in window[-1]
-                        }
+                        # the host read of the window's device scalars
+                        with maybe_span(tracer, "train/metrics_fetch", steps=len(window)):
+                            avg = {
+                                cfg.metric_prefix_train + k: float(np.mean([float(m[k]) for m in window]))
+                                for k in window[-1]
+                            }
                         if self.lr_schedule is not None:
                             avg["lr"] = float(self.lr_schedule(step))
                         # throughput/MFU over GROSS window wall time: a window

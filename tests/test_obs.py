@@ -726,33 +726,57 @@ def test_trainer_emits_step_spans_with_phases(tmp_path):
     assert validate_events(str(tmp_path)) == []
 
 
-def test_host_device_breakdown_joins_spans_and_rollups(tmp_path):
-    """The correlation hook: step spans (host) + golden-xplane named-scope
-    rollups (device) produce the per-step breakdown obs_report renders."""
-    from perceiver_io_tpu.obs import xplane as ox
-    from perceiver_io_tpu.obs.trace import host_device_breakdown
+def test_host_device_breakdown_lays_spans_on_one_capture():
+    """The correlation hook: span rows laid on a capture's timeline give, per
+    span name, count, total and self time and the device idle time under it
+    (innermost span covering the gap's midpoint), which obs_report prints."""
+    from perceiver_io_tpu.obs.trace import NO_SPAN, host_device_breakdown
 
-    buf, _ops = golden_xplane()
-    path = os.path.join(str(tmp_path), "golden.xplane.pb")
-    with open(path, "wb") as f:
-        f.write(buf)
-    rollups = ox.rollup(path)
-    span_rows = [
-        {"event": "span", "name": "step", "dur_ms": float(d),
-         "attrs": {"input_wait_ms": 0.5, "dispatch_ms": 2.0}}
-        for d in (10.0, 12.0, 11.0, 50.0, 13.0)
-    ] + [{"event": "span", "name": "checkpoint", "dur_ms": 30.0, "attrs": {}}]
-    bd = host_device_breakdown(span_rows, rollups)
-    assert bd["steps"] == 5
-    assert bd["step_ms"]["p50"] == 12.0 and "low_n" not in bd["step_ms"]
-    assert bd["input_wait_ms"] == pytest.approx(0.5)
-    assert bd["dispatch_ms"] == pytest.approx(2.0)
-    assert bd["checkpoint"] == {"count": 1, "total_ms": 30.0}
-    # device totals: golden plane is 8250 ps, 5 steps
-    assert bd["device"]["total_ms"] == pytest.approx(8250 / 1e9, abs=1e-9)
-    assert bd["device"]["per_step_ms"] == pytest.approx(8250 / 5 / 1e9, abs=1e-9)
-    scopes = {s["scope"] for s in bd["device"]["top_scopes"]}
-    assert "perceiver_ar/cross_attend" in scopes
+    t0 = 1_700_000_000_000_000_000  # the capture's profile_start_time
+
+    def row(name, sid, parent, start, end, **extra):
+        return {"event": "span", "name": name, "span_id": sid, "parent_id": parent,
+                "start_ns": t0 + start, "end_ns": t0 + end, "dur_ms": (end - start) / 1e6,
+                "attrs": {}, **extra}
+
+    ms = 1_000_000
+    rows = [
+        row("step", "s1", None, 0 * ms, 10 * ms),
+        row("train/input_wait", "w1", "s1", 0 * ms, 2 * ms),
+        row("train/dispatch", "d1", "s1", 2 * ms, 3 * ms),
+        row("step", "s2", None, 10 * ms, 20 * ms),
+        row("train/input_wait", "w2", "s2", 10 * ms, 14 * ms),
+        row("train/dispatch", "d2", "s2", 14 * ms, 15 * ms),
+        # a detached span over everything: counted, never given idle time
+        row("request", "r1", None, 0, 20 * ms, detached=True),
+        # recorded before the capture started: not on its timeline
+        row("step", "s0", None, -10 * ms, -1 * ms),
+    ]
+    capture = {
+        "profile_start_ns": t0, "length_ns": 30 * ms, "annotations": [],
+        "device_ops": {"/device:TPU:0": [
+            ("fusion.1", 1 * ms, 8 * ms), ("nested", 2 * ms, 1 * ms),  # busy 1..9
+            ("fusion.1", 14.5 * ms, 5.5 * ms),  # busy 14.5..20
+        ]},
+    }
+    bd = host_device_breakdown(rows, capture)
+    s = bd["spans"]
+    assert s["step"]["count"] == 3 and s["step"]["total_ms"] == pytest.approx(29.0)
+    # self time: the step less its two phases (s0 has no children)
+    assert s["step"]["self_ms"] == pytest.approx(7.0 + 5.0 + 9.0)
+    assert s["train/input_wait"] == {"count": 2, "total_ms": pytest.approx(6.0),
+                                     "self_ms": pytest.approx(6.0), "idle_ms": pytest.approx(1.0 + 5.5)}
+    assert "idle_ms" not in s["train/dispatch"] and "idle_ms" not in s["request"]
+    assert s["request"]["count"] == 1
+    assert bd["device"] == {"window_ms": pytest.approx(20.0), "busy_ms": pytest.approx(13.5),
+                            "idle_ms": pytest.approx(6.5)}
+    assert sum(v.get("idle_ms", 0.0) for v in s.values()) == pytest.approx(bd["device"]["idle_ms"])
+    # a gap no nested span covers goes to "(no span)"
+    gap = host_device_breakdown(rows[:3] + rows[5:6], capture)
+    assert gap["spans"][NO_SPAN]["idle_ms"] == pytest.approx(14.5 - 9.0)
+    # no capture: the host side alone; a capture without device ops: the same
+    assert "device" not in host_device_breakdown(rows)
+    assert "device" not in host_device_breakdown(rows, {**capture, "device_ops": {}})
 
 
 def test_fault_and_resume_events_carry_resolvable_span_ids(tmp_path):

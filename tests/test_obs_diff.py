@@ -78,9 +78,10 @@ def write_run(
     for i in range(n_steps):
         with tracer.span("step", step=i + 1) as sp:
             pass
-        # overwrite the measured duration with the planted one (the last
-        # recorded row) — the differ reads dur_ms, not wall time
-        tracer._rows[-1]["dur_ms"] = p99 if i == n_steps - 1 else step_ms
+        # overwrite the measured duration with the planted one (the row is
+        # built from the closed span at the flush) — the differ reads
+        # dur_ms, not wall time
+        sp._dur_s = (p99 if i == n_steps - 1 else step_ms) / 1e3
     tracer.flush()
     for i in range(2):
         events.emit(

@@ -7,14 +7,15 @@ summary.
 Sections: the manifest (what the run ran on), event counts, compile events
 (the recompile audit — a second compile of the same function within one
 process is a shape leak; resumed runs legitimately append another first
-compile), the latest throughput/MFU/goodput log row, the per-step
-host/device breakdown from ``span`` rows (input_wait → dispatch → compute,
-the device side joined from an xplane capture when one sits in the run
-dir), the goodput breakdown from ``fit_end``, and per-request SLO stats
-(TTFT + histogram-derived TPOT percentiles from ``request`` rows).
-Stdlib-only: runs anywhere the run directory can be copied to (the shard
-merge and percentile math are inlined; the optional device join upgrades
-itself through ``perceiver_io_tpu.obs`` when the package is importable).
+compile), the latest throughput/MFU/goodput log row, the step-time
+percentiles from ``step`` span rows, every span name with its count, total
+and self time and, when a profiler capture sits in the run dir, the device
+idle time under each (``obs.trace.host_device_breakdown``: the span rows
+laid on the capture's timeline), the goodput breakdown from ``fit_end``,
+and per-request SLO stats (TTFT + histogram-derived TPOT percentiles from
+``request`` rows). Stdlib-only but for the span table: runs anywhere the run
+directory can be copied to (the shard merge and percentile math are
+inlined; the span table needs ``perceiver_io_tpu.obs`` importable).
 """
 
 from __future__ import annotations
@@ -179,25 +180,45 @@ def render(run_dir: str, max_compile_rows: int = 20) -> str:
             if rows:
                 total = sum(float(s["dur_ms"]) for s in rows)
                 lines.append(f"  {phase}: {len(rows)}x, total {total:.4g} ms")
-        # device side of the join: an xplane capture in the run dir rolls up
-        # by named scope (needs the package; silently host-only without it)
-        pbs = glob.glob(os.path.join(run_dir, "**", "*.xplane.pb"), recursive=True)
-        if pbs:
+
+    if spans:
+        # every span name with its count, total and self time; with a
+        # profiler capture in the run dir also the device idle time under
+        # each (needs the package; host side only without it)
+        pbs = sorted(glob.glob(os.path.join(run_dir, "**", "*.xplane.pb"), recursive=True))
+        try:
             try:
                 from perceiver_io_tpu.obs.trace import host_device_breakdown
-                from perceiver_io_tpu.obs.xplane import rollup
+            except ImportError:  # run as a script: the package lies beside tools/
+                import sys
 
-                bd = host_device_breakdown(spans, rollup(sorted(pbs)[-1]))
-                dev = bd.get("device")
-                if dev:
-                    lines.append(
-                        f"  device: {dev['total_ms']:.4g} ms total, "
-                        f"{dev['per_step_ms']:.4g} ms/step"
-                    )
-                    for sc in dev["top_scopes"][:5]:
-                        lines.append(f"    {sc['ms']:9.3f} ms  {sc['scope'][:80]}")
-            except ImportError:
-                lines.append("  (xplane capture present; install the package for the device join)")
+                sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+                from perceiver_io_tpu.obs.trace import host_device_breakdown
+
+            bd = host_device_breakdown(spans, pbs[-1] if pbs else None)
+        except ImportError:
+            bd = None
+            lines.append("")
+            lines.append("  (install the package for the per-span breakdown)")
+        if bd is not None:
+            lines.append("")
+            lines.append(
+                f"== spans ({len(spans)} rows"
+                + (f", device idle from {os.path.relpath(pbs[-1], run_dir)}" if "device" in bd else "")
+                + ") =="
+            )
+            rows = [
+                [name, str(v["count"]), f"{v['total_ms']:.4g}", f"{v['self_ms']:.4g}",
+                 f"{v['idle_ms']:.4g}" if "idle_ms" in v else ""]
+                for name, v in sorted(bd["spans"].items(), key=lambda kv: -kv[1]["total_ms"])
+            ]
+            lines.extend("  " + r for r in _table(rows, ["span", "count", "total ms", "self ms", "device idle ms"]))
+            dev = bd.get("device")
+            if dev:
+                lines.append(
+                    f"  device: busy {dev['busy_ms']:.4g} ms, idle {dev['idle_ms']:.4g} ms "
+                    f"of {dev['window_ms']:.4g} ms under the spans"
+                )
 
     # Probeline per-scope trends (probe events: one snapshot per log
     # boundary, scopes keyed "NNN:name" — sorted == topological order) and
