@@ -11,6 +11,11 @@ emitted as a ``compile`` event and accounted against goodput.
 
 The first call's compile is expected; any later ``compile`` event on the
 same function is the smoking gun for a shape leak.
+
+A ``compile`` row also carries what the traces so far fixed about the packed
+flash kernels (``flash_tiles``: one row per distinct attention geometry with
+its blocks and the score tiles run, masked and skipped; the plan is chosen at
+trace time from the lengths alone, ``ops.flash_attention.tile_plan``).
 """
 
 from __future__ import annotations
@@ -87,6 +92,9 @@ class RecompileTracker:
                 if self.goodput is not None:
                     self.goodput.add("compile", dt)
                 if self.events is not None:
+                    from perceiver_io_tpu.ops.flash_attention import tile_plans
+
+                    flash_tiles = tile_plans()
                     self.events.emit(
                         "compile",
                         fn=name,
@@ -94,6 +102,7 @@ class RecompileTracker:
                         n_compiles=st["compiles"],
                         cache_size=after,
                         arg_shapes=shape_signature(args, kwargs),
+                        **({"flash_tiles": flash_tiles} if flash_tiles else {}),
                     )
             return out
 

@@ -34,7 +34,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -65,13 +65,18 @@ LOG2E = 1.4426950408889634  # log2(e)
 # on the 16k flagship, batch 4, v5e — tools/kernel_ab.py): none of these
 # "obvious" VPU trims beats the round-2 kernels; every one is neutral to
 # slightly NEGATIVE (fastmask +0.5%, slimstats +1.4%, base2 +2.0%,
-# nobias +3.5%, all-four +3.9% step time). The kernels are evidently near
-# their schedule optimum — Mosaic hides the elementwise work these flags
-# remove, and the code perturbations only disturb its pipelining. The
-# features stay implemented and toggleable for future re-probing (e.g. on a
-# different TPU generation); the default is the empty set, which reproduces
-# the round-2 kernels bit-for-bit. Read at TRACE time, like
-# set_default_flash. Full table in docs/performance.md.
+# nobias +3.5%, all-four +3.9% step time). That says these trims do not
+# pay, not that the kernels are near an optimum: at batch 32 the benchmark
+# reads the 16k cross-attention kernels at 23.5% of their roofline and the
+# 24 latent self-attention kernels at 13.7% (PERF.md 5, PR 26), the latter
+# because a one-tile square causal call scored the half of its pairs that
+# the mask hides; PR 27 cut that tile into bands (``tile_plan`` below) and
+# took a quarter off those kernels (PERF.md 6). What is left: head width 64
+# fills half of the MXU's contraction, and both backward kernels rebuild
+# the scores. The features stay implemented and toggleable for future
+# re-probing (e.g. on a different TPU generation); the default is the empty
+# set, the round-2 kernels. Read at TRACE time, like set_default_flash.
+# Full table in docs/performance.md.
 #
 # "twoseg" is a STRUCTURAL feature, not a VPU trim: it routes the Perceiver
 # AR prefix cross-attention through the two-segment kernels below (kept
@@ -218,17 +223,59 @@ def _block_fully_visible(iq, ikv, block_q: int, block_kv: int, offset: int):
 # ---------------------------------------------------------------------------
 
 
-def _causal_dispatch(body, causal: bool, fastmask: bool, iq, ikv, block_q, block_kv, offset):
+def _keep_mask(n_rows: int, n_cols: int, shift):
+    """Keep-mask of a score band in its own coordinates: column c is visible
+    to row r iff ``c <= r + shift``."""
+    rows = lax.broadcasted_iota(jnp.int32, (n_rows, n_cols), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (n_rows, n_cols), 1)
+    return cols <= rows + shift
+
+
+def _tile_body(band, iq, ikv, block_q: int, block_kv: int, offset: int):
+    """A packed kernel's ``body(apply_mask, bands=None)`` from its
+    ``band(r0, r1, width, keep)``: the whole tile under the right-aligned
+    mask (or none), or the given bands of it (:func:`_row_bands`)."""
+
+    def body(apply_mask: bool, bands=None):
+        if bands is None:
+            keep = _right_aligned_mask(block_q, block_kv, iq, ikv, block_q, block_kv, offset) if apply_mask else None
+            band(0, block_q, block_kv, keep)
+            return
+        for r0, r1, width, shift in bands:
+            band(r0, r1, width, None if shift is None else _keep_mask(r1 - r0, width, shift))
+
+    return body
+
+
+def _causal_dispatch(body, causal: bool, fastmask: bool, iq, ikv, block_q, block_kv, offset, diagonals=(), whole=True):
     """Run ``body(apply_mask)`` once per visible tile. Under ``fastmask``,
     fully-visible causal tiles take a mask-free branch (no iota/compare/
-    select generation); only diagonal-straddling tiles pay for the mask."""
+    select generation); only diagonal-straddling tiles pay for the mask.
+
+    ``diagonals`` (packed kernels, :func:`_diagonals`): for positions the
+    mask's diagonal takes inside a tile, the bands of that tile that hold its
+    visible scores. Such a tile runs ``body(True, bands)`` and computes
+    nothing outside them; every other visible tile runs whole, as without
+    (``whole`` False: the grid has no such tile, and none is emitted)."""
+    when = pl.when
+    if causal and diagonals:
+        delta = iq * block_q + offset - ikv * block_kv
+        for d, bands in diagonals:
+            pl.when(delta == d)(functools.partial(body, True, bands))
+        if not whole:
+            return
+        uncut = functools.reduce(jnp.logical_and, [delta != d for d, _ in diagonals])
+
+        def when(cond):
+            return pl.when(jnp.logical_and(cond, uncut))
+
     if causal and fastmask:
         full = _block_fully_visible(iq, ikv, block_q, block_kv, offset)
         vis = _block_visible(iq, ikv, block_q, block_kv, offset)
-        pl.when(jnp.logical_and(vis, full))(lambda: body(False))
-        pl.when(jnp.logical_and(vis, jnp.logical_not(full)))(lambda: body(True))
+        when(jnp.logical_and(vis, full))(lambda: body(False))
+        when(jnp.logical_and(vis, jnp.logical_not(full)))(lambda: body(True))
     elif causal:
-        pl.when(_block_visible(iq, ikv, block_q, block_kv, offset))(lambda: body(True))
+        when(_block_visible(iq, ikv, block_q, block_kv, offset))(lambda: body(True))
     else:
         body(False)
 
@@ -647,6 +694,142 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 # ---------------------------------------------------------------------------
+# tile plan (packed path)
+# ---------------------------------------------------------------------------
+#
+# The grid can skip only whole tiles, and a square causal call whose block is
+# its whole length has one tile: it scored all n_q x n_kv pairs for the half
+# the mask keeps (PERF.md 6, PR 27). So a tile that the mask's diagonal
+# crosses is cut into bands of rows, each scored only as far as its last
+# visible kv slot; tiles below the diagonal run whole, as before. What is cut
+# follows from the call's lengths alone: ``tile_plan`` says it, the wrapper
+# uses it, and the ``compile`` event row records it.
+
+
+def _round_up(n: int, multiple: int) -> int:
+    return -(-n // multiple) * multiple
+
+
+def _row_bands(delta: int, block_q: int, block_kv: int) -> tuple:
+    """The visible part of a tile whose row r sees its kv slots c <= r + delta,
+    as ``(r0, r1, width, shift)``: bands of ``_BAND_ROWS`` rows, each over the
+    tile's kv slots up to the band's last visible one (rounded up to LANES).
+    ``shift`` is the band's :func:`_keep_mask` argument, None where all of it
+    is visible."""
+    bands = []
+    for r0 in range(0, block_q, _BAND_ROWS):
+        r1 = min(r0 + _BAND_ROWS, block_q)
+        width = min(block_kv, _round_up(r1 + delta, LANES))
+        if width > 0:
+            bands.append((r0, r1, width, r0 + delta if width - 1 > r0 + delta else None))
+    return tuple(bands)
+
+
+# Measured on the v5e (tools/tile_plan_ab.py; PERF.md 6, PR 27): bands of 256
+# rows beat 128 and 512 in all three passes, bands of rows beat bands of kv
+# slots (also in dkv), and smaller grid blocks with the hidden tiles skipped
+# lost to the one-tile grid they replaced.
+_BAND_ROWS = 256
+# A tile is cut only if that leaves at most this share of it to score: bands
+# are shorter matmuls and cost more per score than a whole tile (the 16k
+# cross-attention's last kv block, 82% visible, ran 8% slower cut).
+_BAND_MAX_SHARE = 0.75
+
+
+def _band_area(bands) -> int:
+    return sum((r1 - r0) * width for r0, r1, width, _ in bands)
+
+
+def _tile_deltas(offset: int, block_q: int, block_kv: int, nqb: int, nkvb: int) -> list:
+    """Where the mask's diagonal lies in each grid tile: ``delta`` such that
+    the tile's row r sees its kv slots c <= r + delta."""
+    return [iq * block_q + offset - ikv * block_kv for iq in range(nqb) for ikv in range(nkvb)]
+
+
+def _diagonals(causal: bool, offset: int, block_q: int, block_kv: int, nqb: int, nkvb: int) -> dict:
+    """``diagonals`` and ``whole`` of :func:`_causal_dispatch` (and of the
+    packed kernels): the tiles on the diagonal that are cut into bands, as
+    ``(delta, bands)`` with one static entry per position of the diagonal,
+    and whether the grid has a visible tile that is not among them."""
+    if not causal or offset < 0:
+        return {"diagonals": (), "whole": True}
+    visible = {d for d in _tile_deltas(offset, block_q, block_kv, nqb, nkvb) if d > -block_q}
+    cut = []
+    for d in sorted(d for d in visible if d < block_kv - 1):
+        bands = _row_bands(d, block_q, block_kv)
+        if _band_area(bands) <= _BAND_MAX_SHARE * block_q * block_kv:
+            cut.append((d, bands))
+    return {"diagonals": tuple(cut), "whole": len(cut) < len(visible)}
+
+
+class TilePlan(NamedTuple):
+    """How one packed attention call cuts its score matrix. The counts are in
+    score tiles of LANES x LANES over the padded lengths."""
+
+    block_q: int
+    block_kv: int
+    band_rows: int  # rows per band of a grid tile cut on the diagonal; 0 = every tile runs whole
+    tiles_run: int
+    tiles_masked: int  # of those run: in a band, or a whole grid tile, that the diagonal crosses
+    tiles_skipped: int
+
+    @property
+    def run_share(self) -> float:
+        return self.tiles_run / (self.tiles_run + self.tiles_skipped)
+
+
+def _make_plan(n_q: int, n_kv: int, causal: bool, block_q: int, block_kv: int) -> TilePlan:
+    """The plan of a call at these grid blocks (what the kernels will do)."""
+    nqb, nkvb = _round_up(n_q, block_q) // block_q, _round_up(n_kv, block_kv) // block_kv
+    unit = LANES * LANES
+    total = nqb * nkvb * block_q * block_kv // unit
+    if not causal:
+        return TilePlan(block_q, block_kv, 0, total, 0, 0)
+    offset = n_kv - n_q
+    cut = dict(_diagonals(causal, offset, block_q, block_kv, nqb, nkvb)["diagonals"])
+    run = masked = 0
+    for delta in _tile_deltas(offset, block_q, block_kv, nqb, nkvb):
+        if delta <= -block_q:
+            continue
+        bands = cut.get(delta, ((0, block_q, block_kv, 0 if delta < block_kv - 1 else None),))
+        run += _band_area(bands) // unit
+        masked += _band_area([band for band in bands if band[3] is not None]) // unit
+    return TilePlan(block_q, block_kv, _BAND_ROWS if cut else 0, run, masked, total - run)
+
+
+def tile_plan(
+    n_q: int, n_kv: int, causal: bool, block_q: Optional[int] = None, block_kv: Optional[int] = None
+) -> TilePlan:
+    """The tile plan of ``flash_attention_packed`` for a call of these
+    lengths: a pure function of its arguments. ``block_q``/``block_kv`` are
+    the wrapper's (None = the tuned hint, a value = an upper bound)."""
+    # The grid blocks are the ones of before PR 27 for every call. Unpadded
+    # blocks for the generator's 768 x 768 prompt pass (768 -> 2 x 512 pads a
+    # quarter) halved those kernels and cost the decode scan 7%: without the
+    # K/V padded to the cache's length XLA laid the caches out differently
+    # (PERF.md 6, PR 27). A change of blocks is a change of the program around
+    # the kernel; the bands are not.
+    bq = _choose_block(n_q, 1024 if block_q is None else block_q, exact=block_q is not None)
+    bkv = _choose_block(n_kv, 2048 if block_kv is None else block_kv, exact=block_kv is not None)
+    return _make_plan(n_q, n_kv, causal, bq, bkv)
+
+
+# plans of the calls traced in this process, by (geometry, causal): a
+# trace-time fact like the feature set, read by obs.recompile for the
+# ``compile`` event row (docs/observability.md)
+_TILE_PLANS: dict = {}
+
+
+def tile_plans() -> list:
+    """One row per distinct packed attention call traced so far: its
+    geometry (as in the kernel names), blocks and tile counts."""
+    return [
+        {"geometry": geom, "causal": causal, **plan._asdict(), "run_share": round(plan.run_share, 4)}
+        for (geom, causal), plan in sorted(_TILE_PLANS.items())
+    ]
+
+
+# ---------------------------------------------------------------------------
 # packed (slots-major) path
 # ---------------------------------------------------------------------------
 #
@@ -672,6 +855,8 @@ def _fwd_packed_kernel(
     d_v: int,
     has_bias: bool,
     v2: frozenset,
+    diagonals: tuple = (),
+    whole: bool = True,
 ):
     # refs: bias (1, 1, block_kv) f32 when has_bias; q (1, block_q, h*d_qk);
     # k (1, block_kv, h*d_qk); v (1, block_kv, h*d_v); outs
@@ -694,35 +879,34 @@ def _fwd_packed_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    def _body(apply_mask: bool):
-        # per-head minor-dim slices: Mosaic supports static lane slices but
-        # not the (block, h*d) -> (block, h, d) vector reshape
-        bias = bias_ref[0] if has_bias else None
-        keep = None
-        if apply_mask:
-            keep = _right_aligned_mask(block_q, block_kv, iq, ikv, block_q, block_kv, offset)
+    def _band(r0, r1, width, keep):
+        # rows [r0, r1) of the q block against the kv block's first ``width``
+        # slots. Per-head minor-dim slices: Mosaic supports static lane
+        # slices but not the (block, h*d) -> (block, h, d) vector reshape
+        bias = bias_ref[0, :, :width] if has_bias else None
         for hh in range(h):
-            qh = q_ref[0, :, hh * d_qk : (hh + 1) * d_qk]
-            kh = k_ref[0, :, hh * d_qk : (hh + 1) * d_qk]
-            vh = v_ref[0, :, hh * d_v : (hh + 1) * d_v]
+            qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
+            kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
+            vh = v_ref[0, :width, hh * d_v : (hh + 1) * d_v]
             s = _dot(qh, kh, ((1,), (1,)))
             s = s * score_scale
             if has_bias:
                 s = s + bias
-            if apply_mask:
+            if keep is not None:
                 s = jnp.where(keep, s, MASK_VALUE)
-            m_prev = m_scr[hh]
-            l_prev = l_scr[hh]
+            m_prev = m_scr[hh, r0:r1]
+            l_prev = l_scr[hh, r0:r1]
             m_curr = jnp.max(s, axis=1)[:, None]
             m_next = jnp.maximum(m_prev, m_curr)
             p = _exp(s - m_next[:, :1], "base2" in v2)
             alpha = _exp(m_prev - m_next, "base2" in v2)
-            l_scr[hh] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
-            m_scr[hh] = m_next
+            l_scr[hh, r0:r1] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
+            m_scr[hh, r0:r1] = m_next
             o_curr = _dot(p.astype(vh.dtype), vh, ((1,), (0,)))
-            acc_scr[hh] = acc_scr[hh] * alpha[:, :1] + o_curr
+            acc_scr[hh, r0:r1] = acc_scr[hh, r0:r1] * alpha[:, :1] + o_curr
 
-    _causal_dispatch(_body, causal, "fastmask" in v2, iq, ikv, block_q, block_kv, offset)
+    _body = _tile_body(_band, iq, ikv, block_q, block_kv, offset)
+    _causal_dispatch(_body, causal, "fastmask" in v2, iq, ikv, block_q, block_kv, offset, diagonals, whole)
 
     @pl.when(ikv == num_kv_blocks - 1)
     def _store():
@@ -749,6 +933,8 @@ def _dkv_packed_kernel(
     d_v: int,
     has_bias: bool,
     v2: frozenset,
+    diagonals: tuple = (),
+    whole: bool = True,
 ):
     # refs: bias (1, 1, block_kv) when has_bias; q (1, block_q, h*d_qk);
     # k (1, block_kv, h*d_qk); v (1, block_kv, h*d_v); do (1, block_q, h*d_v);
@@ -769,25 +955,24 @@ def _dkv_packed_kernel(
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    def _body(apply_mask: bool):
-        bias = bias_ref[0] if has_bias else None
+    def _band(r0, r1, width, keep):
+        # rows [r0, r1) of the q block against the kv block's first ``width`` slots
+        bias = bias_ref[0, :, :width] if has_bias else None
         for hh in range(h):
-            qh = q_ref[0, :, hh * d_qk : (hh + 1) * d_qk]
-            kh = k_ref[0, :, hh * d_qk : (hh + 1) * d_qk]
-            vh = v_ref[0, :, hh * d_v : (hh + 1) * d_v]
-            doh = do_ref[0, :, hh * d_v : (hh + 1) * d_v]
-            lse = lse_ref[0, :, hh * RES_LANES : hh * RES_LANES + 1]
-            delta = delta_ref[0, :, hh * RES_LANES : hh * RES_LANES + 1]
-            p = _recompute_p(
-                qh, kh, bias, lse, iq, ikv,
-                block_q, block_kv, offset, sm_scale, apply_mask, "base2" in v2,
-            )
-            dv_scr[hh] += _dot(p.astype(doh.dtype), doh, ((0,), (0,)))
+            qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
+            kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
+            vh = v_ref[0, :width, hh * d_v : (hh + 1) * d_v]
+            doh = do_ref[0, r0:r1, hh * d_v : (hh + 1) * d_v]
+            lse = lse_ref[0, r0:r1, hh * RES_LANES : hh * RES_LANES + 1]
+            delta = delta_ref[0, r0:r1, hh * RES_LANES : hh * RES_LANES + 1]
+            p = _recompute_p_keep(qh, kh, bias, lse, keep, sm_scale, "base2" in v2)
+            dv_scr[hh, :width] += _dot(p.astype(doh.dtype), doh, ((0,), (0,)))
             dp = _dot(doh, vh, ((1,), (1,)))
             ds = p * (dp - delta) * sm_scale
-            dk_scr[hh] += _dot(ds.astype(qh.dtype), qh, ((0,), (0,)))
+            dk_scr[hh, :width] += _dot(ds.astype(qh.dtype), qh, ((0,), (0,)))
 
-    _causal_dispatch(_body, causal, "fastmask" in v2, iq, ikv, block_q, block_kv, offset)
+    _body = _tile_body(_band, iq, ikv, block_q, block_kv, offset)
+    _causal_dispatch(_body, causal, "fastmask" in v2, iq, ikv, block_q, block_kv, offset, diagonals, whole)
 
     @pl.when(iq == num_q_blocks - 1)
     def _store():
@@ -807,6 +992,8 @@ def _dq_packed_kernel(
     d_v: int,
     has_bias: bool,
     v2: frozenset,
+    diagonals: tuple = (),
+    whole: bool = True,
 ):
     # refs: bias (1, 1, block_kv) when has_bias; q (1, block_q, h*d_qk);
     # k (1, block_kv, h*d_qk); v (1, block_kv, h*d_v); do (1, block_q, h*d_v);
@@ -826,24 +1013,23 @@ def _dq_packed_kernel(
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    def _body(apply_mask: bool):
-        bias = bias_ref[0] if has_bias else None
+    def _band(r0, r1, width, keep):
+        # rows [r0, r1) of the q block against the kv block's first ``width`` slots
+        bias = bias_ref[0, :, :width] if has_bias else None
         for hh in range(h):
-            qh = q_ref[0, :, hh * d_qk : (hh + 1) * d_qk]
-            kh = k_ref[0, :, hh * d_qk : (hh + 1) * d_qk]
-            vh = v_ref[0, :, hh * d_v : (hh + 1) * d_v]
-            doh = do_ref[0, :, hh * d_v : (hh + 1) * d_v]
-            lse = lse_ref[0, :, hh * RES_LANES : hh * RES_LANES + 1]
-            delta = delta_ref[0, :, hh * RES_LANES : hh * RES_LANES + 1]
-            p = _recompute_p(
-                qh, kh, bias, lse, iq, ikv,
-                block_q, block_kv, offset, sm_scale, apply_mask, "base2" in v2,
-            )
+            qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
+            kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
+            vh = v_ref[0, :width, hh * d_v : (hh + 1) * d_v]
+            doh = do_ref[0, r0:r1, hh * d_v : (hh + 1) * d_v]
+            lse = lse_ref[0, r0:r1, hh * RES_LANES : hh * RES_LANES + 1]
+            delta = delta_ref[0, r0:r1, hh * RES_LANES : hh * RES_LANES + 1]
+            p = _recompute_p_keep(qh, kh, bias, lse, keep, sm_scale, "base2" in v2)
             dp = _dot(doh, vh, ((1,), (1,)))
             ds = (p * (dp - delta) * sm_scale).astype(kh.dtype)
-            dq_scr[hh] += _dot(ds, kh, ((1,), (0,)))
+            dq_scr[hh, r0:r1] += _dot(ds, kh, ((1,), (0,)))
 
-    _causal_dispatch(_body, causal, "fastmask" in v2, iq, ikv, block_q, block_kv, offset)
+    _body = _tile_body(_band, iq, ikv, block_q, block_kv, offset)
+    _causal_dispatch(_body, causal, "fastmask" in v2, iq, ikv, block_q, block_kv, offset, diagonals, whole)
 
     @pl.when(ikv == num_kv_blocks - 1)
     def _store():
@@ -889,6 +1075,7 @@ def _flash_packed_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, blo
             d_v=d_v,
             has_bias=bias is not None,
             v2=v2,
+            **_diagonals(causal, offset, block_q, block_kv, grid[1], grid[2]),
         ),
         name=_kernel_name("fwd", geom),
         grid=grid,
@@ -975,6 +1162,7 @@ def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v,
             d_v=d_v,
             has_bias=has_bias,
             v2=v2,
+            **_diagonals(causal, offset, block_q, block_kv, nqb, nkvb),
         ),
         name=_kernel_name("dkv", geom),
         grid=(b, nkvb, nqb),
@@ -1007,6 +1195,7 @@ def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v,
             d_v=d_v,
             has_bias=has_bias,
             v2=v2,
+            **_diagonals(causal, offset, block_q, block_kv, nqb, nkvb),
         ),
         name=_kernel_name("dq", geom),
         grid=(b, nqb, nkvb),
@@ -1024,6 +1213,13 @@ def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v,
 
 
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
+
+
+# One trace for all the calls of one shape: a step's latent layers make the
+# same call 8 to 48 times, and without this every one traces its three
+# kernels anew (the banded bodies of a cut tile are four times the operations
+# to trace). XLA inlines the call; the program is the same.
+_flash_packed_cached = jax.jit(_flash_packed, static_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13))
 
 
 def packed_supported(num_heads: int, d_qk: int, d_v: int) -> bool:
@@ -1073,8 +1269,9 @@ def flash_attention_packed(
     d_v = v.shape[2] // h
     offset = nkv - nq
 
-    block_q = _choose_block(nq, 1024 if block_q is None else block_q, exact=block_q is not None)
-    block_kv = _choose_block(nkv, 2048 if block_kv is None else block_kv, exact=block_kv is not None)
+    geom = _geometry(nq, nkv)
+    plan = _TILE_PLANS[(geom, causal)] = tile_plan(nq, nkv, causal, block_q, block_kv)
+    block_q, block_kv = plan.block_q, plan.block_kv
 
     qf = _pad_to(q, 1, block_q)
     kf = _pad_to(k, 1, block_kv)
@@ -1095,9 +1292,8 @@ def flash_attention_packed(
         bias = bias[:, None, :]
 
     out = _on_batch_shards(
-        lambda q_, k_, v_, bias_: _flash_packed(
-            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2,
-            _geometry(nq, nkv),
+        lambda q_, k_, v_, bias_: _flash_packed_cached(
+            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom
         ),
         qf, kf, vf, bias,
     )
@@ -1720,9 +1916,12 @@ def flash_attention(
     pad_mask: Optional[jnp.ndarray] = None,
     causal: bool = False,
     sm_scale: float = 1.0,
-    # None = tuned defaults, re-tuned at batch 4 on v5e (same-process sweep):
-    # block_q 1024 beats 512 by ~1.6% and 256 by ~8%; block_kv 2048-class is
-    # flat vs 4352. Explicit values are upper bounds (exact _choose_block).
+    # None = tuned defaults, from a same-process sweep of the whole step at
+    # batch 4 on v5e that changed the blocks of every call at once: block_q
+    # 1024 beats 512 by ~1.6% and 256 by ~8%; block_kv 2048-class is flat vs
+    # 4352. It could not tell the cross-attention from the self-attention
+    # kernels; per geometry, at batch 32, PERF.md 6 (PR 27) has the readings.
+    # Explicit values are upper bounds (exact _choose_block).
     block_q: Optional[int] = None,
     block_kv: Optional[int] = None,
 ) -> jnp.ndarray:

@@ -7,12 +7,12 @@ import numpy as np
 import pytest
 
 from perceiver_io_tpu.ops.flash_attention import (
+    MASK_VALUE,
     flash_attention,
     flash_attention_packed,
     set_default_flash,
+    tile_plan,
 )
-
-pytestmark = pytest.mark.slow
 
 B, H, DQK, DV = 2, 4, 16, 16
 
@@ -42,6 +42,7 @@ def _from_heads(x):
     return x.transpose(0, 2, 1, 3).reshape(b, n, h * d)
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("nq,nkv", [(256, 256), (128, 384), (256, 640)])
 def test_packed_matches_heads_major(causal, nq, nkv):
@@ -57,6 +58,7 @@ def test_packed_matches_heads_major(causal, nq, nkv):
     np.testing.assert_allclose(np.asarray(got), np.asarray(_from_heads(ref)), atol=2e-5)
 
 
+@pytest.mark.slow
 @pytest.mark.parametrize("causal", [False, True])
 def test_packed_grads_match_heads_major(causal):
     nq, nkv = 128, 384
@@ -82,6 +84,7 @@ def test_packed_grads_match_heads_major(causal):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4, rtol=1e-4)
 
 
+@pytest.mark.slow
 def test_packed_single_head_wide():
     # 1-head configs (vision-style) with d multiple of 8
     rng = np.random.default_rng(2)
@@ -93,3 +96,117 @@ def test_packed_single_head_wide():
                           k.reshape(1, 1, 256, 136), v.reshape(1, 1, 256, 136),
                           block_q=128, block_kv=128)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref[0].transpose(1, 0, 2).reshape(1, 128, 136)), atol=2e-5)
+
+
+# --- the tile plan (PR 27): what the wrapper chooses from the lengths alone --
+
+
+def _einsum_packed(q, k, v, h, causal, sm_scale):
+    """Plain attention on packed operands, the right-aligned causal mask."""
+    b, nq, _ = q.shape
+    nkv = k.shape[1]
+    q4, k4, v4 = (x.reshape(b, x.shape[1], h, -1) for x in (q, k, v))
+    s = jnp.einsum("bihc,bjhc->bhij", q4, k4) * sm_scale
+    if causal:
+        hidden = jnp.arange(nkv)[None, :] > jnp.arange(nq)[:, None] + (nkv - nq)
+        s = jnp.where(hidden[None, None], MASK_VALUE, s)
+    o = jnp.einsum("bhij,bjhc->bihc", jax.nn.softmax(s, axis=-1), v4)
+    return o.reshape(b, nq, -1)
+
+
+@pytest.mark.parametrize(
+    "nq,nkv,causal",
+    [(1024, 1024, True), (768, 768, True), (1024, 2176, True), (512, 512, False)],
+    ids=["causal-1024x1024", "causal-768x768", "right-aligned-1024x2176", "plain-512x512"],
+)
+def test_default_plan_matches_einsum(nq, nkv, causal):
+    """Forward and gradients at the blocks the wrapper picks itself (no
+    explicit ``block_q``/``block_kv``): the geometries whose plan PR 27
+    changed, and one it must leave alone."""
+    h, d = 2, 8
+    rng = np.random.default_rng(nq + nkv)
+    q, k, v, w = (
+        jnp.asarray(rng.normal(size=(1, n, h * d)), jnp.float32) for n in (nq, nkv, nkv, nq)
+    )
+
+    def loss(attn):
+        return lambda q_, k_, v_: jnp.sum(attn(q_, k_, v_) * w)
+
+    def flash(q_, k_, v_):
+        return flash_attention_packed(q_, k_, v_, num_heads=h, causal=causal, sm_scale=d**-0.5)
+
+    def plain(q_, k_, v_):
+        return _einsum_packed(q_, k_, v_, h, causal, d**-0.5)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)), np.asarray(plain(q, k, v)), atol=2e-5, rtol=2e-5)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
+
+
+def _today(n_q, n_kv, block_q=None, block_kv=None):
+    """The blocks the wrapper chose before PR 27."""
+    from perceiver_io_tpu.ops.flash_attention import _choose_block
+
+    return (
+        _choose_block(n_q, 1024 if block_q is None else block_q, exact=block_q is not None),
+        _choose_block(n_kv, 2048 if block_kv is None else block_kv, exact=block_kv is not None),
+    )
+
+
+@pytest.mark.parametrize(
+    "n_q,n_kv,causal,blocks,max_share",
+    [
+        (1024, 1024, True, (None, None), 0.75),  # latent self-attention of the 16k step
+        (768, 768, True, (None, None), 0.75),  # the generator's prompt pass
+        (2048, 2048, True, (None, None), 0.75),
+        (1024, 8704, True, (None, None), None),  # 16k cross-attention: today's blocks, run whole
+        (1024, 2176, True, (None, None), None),
+        (512, 512, False, (None, None), None),  # image model: no mask, nothing to skip
+        (512, 50176, False, (None, None), None),
+        (1024, 1024, True, (256, 256), 0.75),  # explicit blocks stay an upper bound
+        (1024, 1024, True, (512, 1024), 0.75),
+        (1024, 1024, False, (512, 512), None),
+    ],
+)
+def test_tile_plan(n_q, n_kv, causal, blocks, max_share):
+    plan = tile_plan(n_q, n_kv, causal, *blocks)
+    assert plan == tile_plan(n_q, n_kv, causal, *blocks)  # a pure function of its arguments
+    total = -(-n_q // plan.block_q) * plan.block_q * (-(-n_kv // plan.block_kv) * plan.block_kv) // 128**2
+    assert plan.tiles_run + plan.tiles_skipped == total and plan.tiles_masked <= plan.tiles_run
+    for got, asked in zip((plan.block_q, plan.block_kv), blocks):
+        assert asked is None or got <= asked
+    # the grid blocks are the ones of before PR 27: the program around the kernels does not change
+    assert (plan.block_q, plan.block_kv) == _today(n_q, n_kv, *blocks)
+    if max_share is None:
+        # not worth cutting (or nothing to cut): every tile runs whole
+        assert plan.band_rows == 0 and plan.tiles_skipped == 0 and (causal or plan.tiles_masked == 0)
+    else:
+        # the visible scores of a square causal call are half of all: at most 3/4 are computed
+        assert plan.run_share <= max_share
+        visible = n_q * (n_q + 1) // 2
+        assert plan.tiles_run * 128**2 >= visible
+
+
+def test_compile_event_carries_the_tile_plans():
+    """The plan is fixed at trace time, so it is recorded once per geometry
+    where trace-time facts go: the ``compile`` event row."""
+    from perceiver_io_tpu.obs.recompile import RecompileTracker
+
+    class Sink:
+        rows = []
+
+        def emit(self, kind, **fields):
+            self.rows.append((kind, fields))
+
+    @jax.jit
+    def step(q, k, v):
+        return flash_attention_packed(q, k, v, num_heads=1, causal=True, block_q=256, block_kv=256)
+
+    x = jnp.zeros((1, 512, 8), jnp.float32)
+    tracker = RecompileTracker(events=Sink())
+    tracker.wrap(step, "step")(x, x, x)
+    (kind, fields), = Sink.rows
+    row = next(r for r in fields["flash_tiles"] if r["geometry"] == "q512_kv512" and r["causal"])
+    assert kind == "compile" and row["block_q"] == 256 and row["tiles_skipped"] == 4 and row["run_share"] == 0.75

@@ -145,6 +145,20 @@ def render(run_dir: str, max_compile_rows: int = 20) -> str:
         leaks = sorted({e.get("fn", "?") for e in compiles if e.get("n_compiles", 1) > 1})
         if leaks:
             lines.append(f"  WARNING: recompiles after the first on: {', '.join(leaks)}")
+        # the newest row's flash tile plans hold every geometry traced so far
+        plans = next((e["flash_tiles"] for e in reversed(compiles) if e.get("flash_tiles")), [])
+        if plans:
+            lines.append("  flash tile plans (score tiles of 128 x 128):")
+            rows = [
+                [
+                    r["geometry"] + (" causal" if r["causal"] else ""), f"{r['block_q']} x {r['block_kv']}",
+                    str(r["band_rows"] or "-"), str(r["tiles_run"]), str(r["tiles_masked"]), str(r["tiles_skipped"]),
+                    f"{r['run_share']:.3f}",
+                ]
+                for r in plans
+            ]
+            header = ["call", "blocks", "band_rows", "run", "masked", "skipped", "run_share"]
+            lines.extend("  " + r for r in _table(rows, header))
 
     logs = [e for e in events if e.get("event") == "log"]
     if logs:

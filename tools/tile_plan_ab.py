@@ -1,0 +1,189 @@
+"""Same-process A/B of tile plans for the packed causal flash kernels.
+
+One process builds every variant (grid blocks, and bands inside a tile on
+the diagonal) of one attention geometry, runs them round-robin, each round
+under its own profiler capture, and reads the device time of the forward, dq
+and dkv kernels from the captures: wall clocks drift between processes on
+this chip (tools/kernel_ab.py), kernel device times in one process do not.
+Every variant's outputs and gradients are compared with the first variant's.
+
+    python tools/tile_plan_ab.py --geom sa --variants 1024x1024 512x512 1024x1024/256 plan
+    python tools/tile_plan_ab.py --geom ca --variants 1024x2176 1024x2176/256
+
+A variant is ``<block_q>x<block_kv>[/<band rows>][+feature,...]``: grid blocks
+of that size, every tile run whole, or every tile on the diagonal cut into
+bands of that many rows; ``plan`` is what ``tile_plan`` chooses itself.
+``--compile-only`` lowers and compiles every variant for a described v5e (no
+chip) and runs nothing. PERF.md 6 (PR 27) has the readings that set
+``_BAND_ROWS`` and ``_BAND_MAX_SHARE``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import concurrent.futures
+import importlib
+import json
+import os
+import sys
+import tempfile
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
+
+# the attention calls of the benchmark's cells: latent self-attention and
+# cross-attention of ar16k-train-b32, the prompt pass of ar16k-decode-b64
+GEOMS = {
+    "sa": dict(b=32, nq=1024, nkv=1024, h=8, d=64, bwd=True),
+    "ca": dict(b=32, nq=1024, nkv=8704, h=8, d=64, bwd=True),
+    "prompt": dict(b=64, nq=768, nkv=768, h=8, d=64, bwd=False),
+}
+
+
+def parse_variant(text: str):
+    """``512x512/256+fastmask`` -> (512, 512, 256, {"fastmask"}); ``plan`` -> None blocks."""
+    spec, _, feats = text.partition("+")
+    features = frozenset(f for f in feats.split(",") if f)
+    if spec == "plan":
+        return None, None, None, features
+    blocks, _, band = spec.partition("/")
+    bq, bkv = (int(x) for x in blocks.split("x"))
+    return bq, bkv, int(band or 0), features
+
+
+def build(geom: dict, variant: str, sharding=None):
+    """The jitted call of one variant, lowered while its plan is in force
+    (the plan and the feature set are read at trace time)."""
+    bq, bkv, band, features = parse_variant(variant)
+    h, d = geom["h"], geom["d"]
+
+    def attn(q, k, v):
+        return fa.flash_attention_packed(q, k, v, num_heads=h, causal=True, sm_scale=d**-0.5)
+
+    def fwd_bwd(q, k, v, w):
+        out, vjp = jax.vjp(attn, q, k, v)
+        return (out,) + vjp(w)
+
+    fn = fwd_bwd if geom["bwd"] else lambda q, k, v, w: (attn(q, k, v),)
+    shapes = [(geom["b"], n, h * d) for n in (geom["nq"], geom["nkv"], geom["nkv"], geom["nq"])]
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=sharding) for s in shapes]
+    chosen = fa.tile_plan, fa._BAND_ROWS, fa._BAND_MAX_SHARE
+    if bq is not None:
+        fa.tile_plan = lambda n_q, n_kv, causal, *_: fa._make_plan(n_q, n_kv, causal, bq, bkv)
+        fa._BAND_ROWS, fa._BAND_MAX_SHARE = (band, 1.0) if band else (chosen[1], 0.0)
+    try:
+        with fa.fast_kernels(features), jax.default_matmul_precision("default"):
+            lowered = jax.jit(fn).lower(*args)
+        plan = fa.tile_plan(geom["nq"], geom["nkv"], True)
+    finally:
+        fa.tile_plan, fa._BAND_ROWS, fa._BAND_MAX_SHARE = chosen
+    return lowered, plan
+
+
+def flash_ms(trace_dir: str) -> dict:
+    """Device ms per flash pass (``fwd``, ``dq``, ``dkv``) in one capture."""
+    from perceiver_io_tpu.obs.xplane import load_capture
+
+    out: dict = {}
+    for ops in load_capture(trace_dir)["device_ops"].values():
+        for name, _, duration_ns in ops:
+            if "flash_" in name:
+                pass_ = name.split("flash_", 1)[1].split("_", 1)[0]
+                out[pass_] = out.get(pass_, 0.0) + duration_ns / 1e6
+    return out
+
+
+def main():
+    p = argparse.ArgumentParser()
+    p.add_argument("--geom", choices=sorted(GEOMS), required=True)
+    p.add_argument("--variants", nargs="+", required=True)
+    p.add_argument("--calls", type=int, default=8)
+    p.add_argument("--rounds", type=int, default=3)
+    p.add_argument("--compile-only", action="store_true")
+    p.add_argument("--out", default=None, help="write the table as JSON here")
+    args = p.parse_args()
+    geom = GEOMS[args.geom]
+
+    sharding = None
+    if args.compile_only:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        sharding = SingleDeviceSharding(topo.devices[0])
+        fa._interpret_default = lambda: False
+        jax.config.update("jax_enable_compilation_cache", False)
+    elif jax.default_backend() != "tpu":
+        raise SystemExit("tile_plan_ab times kernels on the chip: no TPU here (use --compile-only)")
+
+    lowered = {}
+    for variant in args.variants:
+        lowered[variant] = build(geom, variant, sharding)
+        print(f"{variant}: {lowered[variant][1]}", flush=True)
+
+    def compile_one(variant):
+        t0 = time.perf_counter()
+        exe = lowered[variant][0].compile()
+        return variant, exe, time.perf_counter() - t0
+
+    compiled = {}
+    with concurrent.futures.ThreadPoolExecutor(max_workers=4) as pool:
+        for variant, exe, secs in pool.map(compile_one, args.variants):
+            compiled[variant] = exe
+            print(f"{variant}: compiled in {secs:.1f} s", flush=True)
+    if args.compile_only:
+        return
+
+    rng = np.random.default_rng(0)
+    h, d = geom["h"], geom["d"]
+    operands = [
+        jnp.asarray(rng.normal(size=(geom["b"], n, h * d)), jnp.bfloat16)
+        for n in (geom["nq"], geom["nkv"], geom["nkv"], geom["nq"])
+    ]
+    base = None
+    gaps = {}
+    for variant in args.variants:
+        outs = [np.asarray(x, np.float32) for x in compiled[variant](*operands)]
+        if base is None:
+            base = outs
+        gaps[variant] = [float(np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-30)) for a, b in zip(outs, base)]
+
+    times = {v: [] for v in args.variants}
+    for round_ in range(args.rounds):
+        for variant in args.variants:
+            with tempfile.TemporaryDirectory() as tmp:
+                jax.profiler.start_trace(tmp)
+                for _ in range(args.calls):
+                    out = compiled[variant](*operands)
+                jax.block_until_ready(out)
+                jax.profiler.stop_trace()
+                ms = flash_ms(tmp)
+            times[variant].append({k: v / args.calls for k, v in ms.items()})
+
+    rows = []
+    passes = ["fwd", "dq", "dkv"] if geom["bwd"] else ["fwd"]
+    print(f"\n{args.geom} {geom}: device ms a call, median of {args.rounds} rounds of {args.calls} calls")
+    head = " ".join(f"{p_:>8}" for p_ in passes)
+    print(f"{'variant':<28} {head} {'sum':>8}  run_share  gap to first (out, dq, dk, dv)")
+    for variant in args.variants:
+        med = {p_: float(np.median([t.get(p_, 0.0) for t in times[variant]])) for p_ in passes}
+        plan = lowered[variant][1]
+        row = dict(geom=args.geom, variant=variant, plan=plan._asdict(), run_share=plan.run_share,
+                   ms=med, ms_sum=sum(med.values()), rounds=times[variant], gap_to_first=gaps[variant])
+        rows.append(row)
+        print(f"{variant:<28} " + " ".join(f"{med[p_]:8.3f}" for p_ in passes)
+              + f" {row['ms_sum']:8.3f}  {plan.run_share:9.3f}  " + " ".join(f"{g:.1e}" for g in gaps[variant]))
+    if args.out:
+        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump(rows, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()
