@@ -404,3 +404,38 @@ def release_slot(paged: PagedKVCache, slot: int) -> PagedKVCache:
         k_scale=paged.k_scale,
         v_scale=paged.v_scale,
     )
+
+
+# ---------------------------------------------------------------------------
+# latent discipline
+# ---------------------------------------------------------------------------
+
+
+@struct.dataclass
+class LatentCache:
+    """Fixed-capacity cache of multi-head latent attention: one joint row a
+    token, ``[c_kv (kv_lora_rank); k_rope (rope dim, rotated at write)]``,
+    shared by every head. ``rows`` is (B, capacity, width) with valid data in
+    slots [0, length); ``length`` is a traced int32 scalar, one for the
+    batch (no row is padded). There is no ``k`` and no ``v``: the absorbed
+    attention reads scores and values from the same row."""
+
+    rows: jnp.ndarray
+    length: jnp.ndarray
+
+    @property
+    def capacity(self) -> int:
+        return self.rows.shape[1]
+
+    @property
+    def row_bytes(self) -> int:
+        return self.rows.shape[2] * self.rows.dtype.itemsize
+
+    def append(self, rows: jnp.ndarray) -> "LatentCache":
+        """Write ``rows`` (B, N, width) at ``length``; returns the advanced cache."""
+        new = lax.dynamic_update_slice(self.rows, rows.astype(self.rows.dtype), (0, self.length, 0))
+        return LatentCache(rows=new, length=self.length + rows.shape[1])
+
+
+def init_latent_cache(batch_size: int, capacity: int, width: int, dtype=jnp.float32) -> LatentCache:
+    return LatentCache(rows=jnp.zeros((batch_size, capacity, width), dtype), length=jnp.zeros((), jnp.int32))

@@ -1,0 +1,181 @@
+"""Multi-head latent attention (DeepSeek-V2/V3, arXiv:2412.19437 section 2.1.1).
+
+Keys and values of all heads are functions of one low-rank latent per token,
+``c_kv`` (``kv_lora_rank`` channels), plus one rotary key ``k_rope`` that every
+head shares. The cache therefore holds one joint row a token
+(:class:`~perceiver_io_tpu.core.cache.LatentCache`), not a key and a value per
+head. One set of weights, two ways to compute the same function:
+
+``expand`` (the prompt pass)
+    per-head keys ``[k_nope; k_rope]`` and values are built from the latent
+    and go through ordinary causal attention (the flash kernels on a TPU,
+    ``d_qk`` = nope + rope, ``d_v`` = v). Right for many queries at once:
+    the up-projection is paid once a token.
+
+``absorb`` (one new token against the cache)
+    ``q_nope . (c_kv W_uk) = (q_nope W_uk^T) . c_kv``: the query is carried
+    into the latent space, scores and values are read from the cached rows
+    that all heads share, and ``W_uv`` is applied to the attended latent.
+    The cache is read once a row for all heads, and nothing per head is
+    ever built for the cached tokens.
+
+Rotary: YaRN frequencies on the ``qk_rope_head_dim`` channels of the queries
+and on ``k_rope``, adjacent channels paired (``core/position.py``); the
+softmax scale is ``(nope + rope)^-0.5 * mscale^2``. RMSNorm on both latents.
+No biases. Scores and the softmax are float32; products take ``dtype``
+operands and accumulate in float32.
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from perceiver_io_tpu.core.cache import LatentCache
+from perceiver_io_tpu.core.position import apply_rotary_interleaved, yarn_inv_freq, yarn_mscale
+from perceiver_io_tpu.ops.flash_attention import flash_attention, flash_enabled
+from perceiver_io_tpu.ops.layernorm import RMSNorm
+
+
+class MultiHeadLatentAttention(nn.Module):
+    """``config`` needs: ``hidden_size``, ``num_attention_heads``,
+    ``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim``,
+    ``qk_rope_head_dim``, ``v_head_dim``, ``rms_norm_eps``, ``rope_theta``,
+    ``rope_scaling`` (``None`` or an object with YaRN's ``factor``,
+    ``beta_fast``, ``beta_slow``, ``mscale``, ``mscale_all_dim``,
+    ``original_max_position_embeddings``) and ``init_scale``."""
+
+    config: object
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    def setup(self):
+        c = self.config
+        heads = c.num_attention_heads
+        init = nn.initializers.normal(c.init_scale)
+        norm = dict(epsilon=c.rms_norm_eps, dtype=self.dtype, param_dtype=self.param_dtype)
+        self.w_dq = self.param("w_dq", init, (c.hidden_size, c.q_lora_rank), self.param_dtype)
+        self.q_norm = RMSNorm(**norm)
+        self.w_uq = self.param(
+            "w_uq", init, (c.q_lora_rank, heads * (c.qk_nope_head_dim + c.qk_rope_head_dim)), self.param_dtype
+        )
+        self.w_dkv = self.param("w_dkv", init, (c.hidden_size, c.kv_lora_rank + c.qk_rope_head_dim), self.param_dtype)
+        self.kv_norm = RMSNorm(**norm)
+        self.w_ukv = self.param(
+            "w_ukv", init, (c.kv_lora_rank, heads * (c.qk_nope_head_dim + c.v_head_dim)), self.param_dtype
+        )
+        self.w_o = self.param("w_o", init, (heads * c.v_head_dim, c.hidden_size), self.param_dtype)
+
+    # ------------------------------------------------------------ shared
+
+    @property
+    def sm_scale(self) -> float:
+        c = self.config
+        scale = (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
+        if c.rope_scaling is not None:
+            m = yarn_mscale(c.rope_scaling.factor, c.rope_scaling.mscale_all_dim)
+            scale *= m * m
+        return scale
+
+    def _inv_freq(self):
+        c, s = self.config, self.config.rope_scaling
+        if s is None:
+            return yarn_inv_freq(c.qk_rope_head_dim, c.rope_theta, 1.0, 1.0, 1.0, 1)  # factor 1: plain rotary
+        return yarn_inv_freq(c.qk_rope_head_dim, c.rope_theta, s.factor, s.beta_fast, s.beta_slow,
+                             s.original_max_position_embeddings)
+
+    def _mm(self, x, w):
+        return jnp.dot(x.astype(self.dtype), w.astype(self.dtype))
+
+    def _queries(self, x, pos) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """``x`` (B, N, h), ``pos`` (B, N) -> ``q_nope`` (B, N, H, nope) and
+        the rotated ``q_rope`` (B, N, H, rope)."""
+        c = self.config
+        b, n, _ = x.shape
+        q = self._mm(self.q_norm(self._mm(x, self.w_dq)), self.w_uq)
+        q = q.reshape(b, n, c.num_attention_heads, c.qk_nope_head_dim + c.qk_rope_head_dim)
+        q_nope, q_rope = q[..., : c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:]
+        return q_nope, apply_rotary_interleaved(q_rope, pos[:, :, None], self._inv_freq())
+
+    def _latent_rows(self, x, pos) -> jnp.ndarray:
+        """The cache's rows for ``x``: ``[RMSNorm(c_kv); rotated k_rope]`` (B, N, rank + rope)."""
+        c = self.config
+        kv = self._mm(x, self.w_dkv)
+        c_kv = self.kv_norm(kv[..., : c.kv_lora_rank])
+        k_rope = apply_rotary_interleaved(kv[..., c.kv_lora_rank:], pos, self._inv_freq())
+        return jnp.concatenate([c_kv, k_rope], axis=-1)
+
+    def _w_ukv(self):
+        c = self.config
+        return self.w_ukv.astype(self.dtype).reshape(
+            c.kv_lora_rank, c.num_attention_heads, c.qk_nope_head_dim + c.v_head_dim
+        )
+
+    # ---------------------------------------------------------- expanded
+
+    def expand(self, x, pos) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """Causal self-attention of ``x`` (B, N, h) over itself, per-head keys
+        and values built from the latent. Returns the output (B, N, h) and
+        the cache rows (B, N, rank + rope) of these tokens."""
+        c = self.config
+        b, n, _ = x.shape
+        heads = c.num_attention_heads
+        with jax.named_scope("mla/expand"):
+            q_nope, q_rope = self._queries(x, pos)
+            rows = self._latent_rows(x, pos)
+            kv = jnp.einsum("bnc,chd->bnhd", rows[..., : c.kv_lora_rank], self._w_ukv())
+            k_nope, v = kv[..., : c.qk_nope_head_dim], kv[..., c.qk_nope_head_dim:]
+            k_rope = jnp.broadcast_to(rows[:, :, None, c.kv_lora_rank:], (b, n, heads, c.qk_rope_head_dim))
+            # heads-major (B, H, N, D) for the attention
+            q = jnp.concatenate([q_nope, q_rope], axis=-1).transpose(0, 2, 1, 3)
+            k = jnp.concatenate([k_nope, k_rope.astype(k_nope.dtype)], axis=-1).transpose(0, 2, 1, 3)
+            v = v.transpose(0, 2, 1, 3)
+            if flash_enabled():
+                o = flash_attention(q, k, v, causal=True, sm_scale=self.sm_scale)
+            else:
+                s = jnp.einsum("bhic,bhjc->bhij", q, k, preferred_element_type=jnp.float32) * self.sm_scale
+                visible = jnp.arange(n)[None, :] <= jnp.arange(n)[:, None]
+                p = jax.nn.softmax(jnp.where(visible[None, None], s, -jnp.inf), axis=-1)
+                o = jnp.einsum("bhij,bhjc->bhic", p.astype(v.dtype), v)
+            o = o.transpose(0, 2, 1, 3).reshape(b, n, heads * c.v_head_dim)
+            return self._mm(o, self.w_o), rows
+
+    # ---------------------------------------------------------- absorbed
+
+    def absorb(self, x, cache: LatentCache, pos) -> Tuple[jnp.ndarray, LatentCache]:
+        """One new token a row, ``x`` (B, 1, h) at positions ``pos`` (B, 1),
+        against ``cache``: its row is appended first, then the query, carried
+        into the latent space, reads scores and values from the cached rows."""
+        c = self.config
+        b = x.shape[0]
+        heads, rank = c.num_attention_heads, c.kv_lora_rank
+        with jax.named_scope("mla/absorb"):
+            q_nope, q_rope = self._queries(x, pos)
+            with jax.named_scope("latent_cache_append"):
+                cache = cache.append(self._latent_rows(x, pos))
+            w_ukv = self._w_ukv()
+            w_uk, w_uv = w_ukv[..., : c.qk_nope_head_dim], w_ukv[..., c.qk_nope_head_dim:]
+            q_abs = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_uk)
+            q_cat = jnp.concatenate([q_abs, q_rope[:, 0].astype(q_abs.dtype)], axis=-1)
+            o_lat = latent_decode_attention(q_cat, cache, self.sm_scale)[..., :rank]
+            o = jnp.einsum("bhc,chd->bhd", o_lat.astype(self.dtype), w_uv)
+            return self._mm(o.reshape(b, 1, heads * c.v_head_dim), self.w_o), cache
+
+
+def latent_decode_attention(q_cat: jnp.ndarray, cache: LatentCache, sm_scale: float) -> jnp.ndarray:
+    """Absorbed attention of one query a row: ``q_cat`` (B, H, width) against
+    the joint rows ``cache.rows`` (B, capacity, width), slots at or past
+    ``cache.length`` masked. Returns ``softmax(q . row) @ row`` (B, H, width)
+    in float32: all ``width`` channels, of which the caller keeps the first
+    ``kv_lora_rank`` (attending over the whole row avoids a copy of the cache
+    without its rope channels; the rope channels of the result are unused).
+
+    Two batched products over the cache in XLA; the softmax is float32."""
+    rows = cache.rows
+    s = jnp.einsum("bhc,bsc->bhs", q_cat.astype(rows.dtype), rows, preferred_element_type=jnp.float32) * sm_scale
+    valid = jnp.arange(cache.capacity, dtype=jnp.int32) < cache.length
+    p = jax.nn.softmax(jnp.where(valid[None, None, :], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhs,bsc->bhc", p.astype(rows.dtype), rows, preferred_element_type=jnp.float32)

@@ -1,0 +1,244 @@
+"""Sigmoid-routed sparse experts with a shared expert (DeepSeek-V3,
+arXiv:2412.19437 section 2.1.2), as **one chip's share** of an expert-parallel
+layer.
+
+The router keeps its published width: every token is scored against all
+``n_routed_experts``. The layer is told which experts it holds
+(``held_experts_start``, ``n_held_experts``) and computes their part of the
+result for the tokens routed to them. A pair routed to an expert held
+elsewhere adds nothing here, and no code stands in for the other chips or for
+the exchange with them. The shared expert is computed on every chip alike.
+With every expert held, the layer is the whole layer.
+
+Routing (``inference/model.py``'s ``Gate``): ``s = sigmoid(x W_g)`` in
+float32; experts are *chosen* on ``s + b`` (``b`` the bias that balances load
+without an auxiliary loss): the experts form ``n_group`` groups, a group's
+score is the sum of its two largest, the ``topk_group`` best groups stay, and
+the ``num_experts_per_tok`` largest within them are chosen. The *weights* are
+the unbiased ``s`` of the chosen, renormalised to sum to one and scaled by
+``routed_scaling_factor``.
+
+Two ways through the held experts, chosen at trace time by the number of
+tokens (``_GROUPED_MIN_TOKENS``, where the two were measured to cross), each
+kept on a chip measurement (``tools/moe_ab.py``; PERF.md 6, PR 28):
+
+``grouped`` (a prompt pass, or a step of 384 tokens and more)
+    the routed pairs are sorted by expert, and the pairs that fall to held
+    experts go, a pass of at most 1024 rows at a time, through a gather, a
+    grouped matrix product (``ops/grouped_matmul.py``) and a scatter-add back
+    to their tokens; as many passes as the routing sent pairs here: no pair
+    is dropped however skewed the routing is, and the work follows the pairs
+    that are really here. ``jax.lax.ragged_dot`` in the kernel's place
+    measured 1.5x slower.
+
+``dense`` (a decode step: under 384 tokens)
+    every held expert on every token, weighted (zero where not routed). Up to
+    the ridge (about 240 tokens an expert layer's worth of weights) a step is
+    bound by reading the experts' weights, and this path reads all of them
+    whatever the step hits: 1.95 ms at 64 tokens, 2.06 at 256, against 2.30
+    and 2.43 for the grouped path with its sort, gather and scatter-add.
+    Inside the cell's generator, where the sort overlaps, the grouped path on
+    a step's pairs was 5.8% faster end to end at batch 64 (it reads the 87%
+    of experts a step hits) and its speed followed the seed's tokens (0.7%
+    spread against 0.1%): left out here, open in PERF.md 7. ``ragged_dot``
+    measured 2x slower than either.
+"""
+
+from __future__ import annotations
+
+from typing import Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from perceiver_io_tpu.obs import probes
+from perceiver_io_tpu.ops.grouped_matmul import grouped_matmul
+
+
+# How the work is cut, not what is computed; each from ``tools/moe_ab.py`` on
+# the v5e at the published widths (PERF.md 6, PR 28). From this many tokens
+# the grouped path is taken: the two cross between 256 tokens (dense 2.06 ms,
+# grouped 2.43) and 384 (2.95 against 2.52; 4.74 against 2.90 at 512).
+_GROUPED_MIN_TOKENS = 384
+# The grouped kernel's row tile: the fastest from 2048 tokens up (by 9% at a
+# prompt chunk's 8192); 128 rows are 6 to 11% faster from 384 to 512 tokens.
+_ROW_TILE = 256
+# A pass gathers at most ``_PASS_ROWS`` sorted pairs, and the path takes as
+# many passes as the routing sent pairs here: 8192 tokens with 4096 pairs here
+# take 10.0 ms in passes of 1024 rows (9.5 to 9.6 in passes of 512 or 768)
+# against 14.2 in one of 5120 and 26 in passes of 1536 or 2048 (XLA's
+# scatter-add into 8192 rows is slow past 1024 updates), and a layer that a
+# seed's routing sends a quarter more pairs costs a pass of 2 ms more, not a
+# second sweep of 8.6. Fewer tokens take one pass of the pairs an even routing
+# sends here and a quarter more (at 2048 tokens that would be 1280 rows in
+# 4.8 ms; capped, two passes take 7.0).
+_PASS_ROWS = 1024
+_PASS_SLACK = 1.25
+
+
+def _pass_rows(pairs: int, held_share: float) -> int:
+    """Rows of one pass of the grouped path for ``pairs`` routed pairs of which ``held_share`` fall here if the routing is even."""
+    want = int(pairs * held_share * _PASS_SLACK)
+    return min(_PASS_ROWS, max(-(-want // _ROW_TILE), 1) * _ROW_TILE)
+
+
+def router_scores(x: jnp.ndarray, w_gate: jnp.ndarray) -> jnp.ndarray:
+    """``sigmoid(x W_g)`` (T, E) in float32 at full precision: which experts a
+    token takes hangs on differences in the last digits."""
+    logits = jnp.dot(x.astype(jnp.float32), w_gate.astype(jnp.float32), precision=lax.Precision.HIGHEST)
+    return jax.nn.sigmoid(logits)
+
+
+def choose_experts(
+    scores: jnp.ndarray,
+    bias: jnp.ndarray,
+    *,
+    n_group: int,
+    topk_group: int,
+    top_k: int,
+    scale: float,
+) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``scores`` (T, E) float32 -> chosen experts (T, top_k) int32 and their
+    combine weights (T, top_k) float32 (see the module docstring)."""
+    t, e = scores.shape
+    biased = (scores + bias.astype(jnp.float32)).reshape(t, n_group, e // n_group)
+    group_score = lax.top_k(biased, 2)[0].sum(-1)
+    _, kept = lax.top_k(group_score, topk_group)
+    group_stays = jax.nn.one_hot(kept, n_group, dtype=jnp.bool_).any(axis=1)
+    masked = jnp.where(group_stays[:, :, None], biased, -jnp.inf).reshape(t, e)
+    _, chosen = lax.top_k(masked, top_k)
+    w = jnp.take_along_axis(scores, chosen, axis=1)
+    return chosen.astype(jnp.int32), w / w.sum(-1, keepdims=True) * scale
+
+
+def _silu_gate(h1, h3, dtype):
+    return (jax.nn.silu(h1.astype(jnp.float32)) * h3.astype(jnp.float32)).astype(dtype)
+
+
+def experts_dense(x, combine, w1, w3, w2):
+    """Every held expert on every token: ``x`` (T, h), ``combine`` (T, G)
+    float32 (a token's weight for each held expert, zero where not routed),
+    weights (G, h, I), (G, h, I), (G, I, h). Returns (T, h) float32."""
+    h1 = jnp.einsum("th,ghi->gti", x, w1)
+    h3 = jnp.einsum("th,ghi->gti", x, w3)
+    a = _silu_gate(h1, h3, jnp.float32) * combine.T[:, :, None]
+    return jnp.einsum("gti,gih->th", a.astype(x.dtype), w2, preferred_element_type=jnp.float32)
+
+
+def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int):
+    """The held experts on the pairs routed to them, sorted by expert.
+
+    ``x`` (T, h); ``local`` (T, k) int32, a pair's held-expert index or ``G``
+    where its expert is not held; ``weights`` (T, k) float32. Returns the
+    sum over a token's local pairs (T, h) float32, the rows a pass left
+    unserved (a scalar that is zero: the loop takes passes of ``pass_rows``
+    rows, a multiple of the row tile, until none is left) and the number of
+    passes it took."""
+    t, k = local.shape
+    g = w1.shape[0]
+    flat = local.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)  # held pairs first, by expert
+    sizes = jnp.zeros((g + 1,), jnp.int32).at[flat].add(1)[:g]
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
+    n_local = offsets[-1]
+    order = jnp.concatenate([order, jnp.zeros((pass_rows,), jnp.int32)])  # a pass may read past the end
+    w_flat = weights.reshape(-1)
+
+    def one_pass(p, y):
+        lo = p * pass_rows
+        live = (lo + jnp.arange(pass_rows, dtype=jnp.int32)) < n_local
+        pair = lax.dynamic_slice(order, (lo,), (pass_rows,))
+        # a row past the last local pair reads token 0 and adds zeros to it: the scatter-add costs by the distinct rows it touches
+        token = jnp.where(live, pair // k, 0)
+        in_pass = jnp.clip(offsets, lo, lo + pass_rows) - lo
+        group_sizes = in_pass[1:] - in_pass[:-1]
+        xs = x[token]
+        mm = lambda a, w: grouped_matmul(a, w, group_sizes, tm=_ROW_TILE)  # noqa: E731
+        ys = mm(_silu_gate(mm(xs, w1), mm(xs, w3), x.dtype), w2)
+        ys = jnp.where(live[:, None], ys.astype(jnp.float32) * w_flat[pair][:, None], 0.0)
+        return y.at[token].add(ys)
+
+    n_pass = (n_local + pass_rows - 1) // pass_rows
+    y = lax.fori_loop(0, n_pass, one_pass, jnp.zeros((t, x.shape[-1]), jnp.float32))
+    return y, n_local - jnp.minimum(n_pass * pass_rows, n_local), n_pass
+
+
+class SwiGLU(nn.Module):
+    """``W_2 (silu(W_1 x) * W_3 x)``, no biases."""
+
+    hidden_size: int
+    width: int
+    init_scale: float
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        init = nn.initializers.normal(self.init_scale)
+        w1 = self.param("w1", init, (self.hidden_size, self.width), self.param_dtype)
+        w3 = self.param("w3", init, (self.hidden_size, self.width), self.param_dtype)
+        w2 = self.param("w2", init, (self.width, self.hidden_size), self.param_dtype)
+        x = x.astype(self.dtype)
+        a = _silu_gate(jnp.dot(x, w1.astype(self.dtype)), jnp.dot(x, w3.astype(self.dtype)), self.dtype)
+        return jnp.dot(a, w2.astype(self.dtype))
+
+
+class MoELayer(nn.Module):
+    """``config`` needs ``hidden_size``, ``moe_intermediate_size``,
+    ``n_routed_experts`` (the router's width), ``n_held_experts``,
+    ``held_experts_start``, ``num_experts_per_tok``, ``n_group``,
+    ``topk_group``, ``routed_scaling_factor``, ``n_shared_experts`` and
+    ``init_scale``."""
+
+    config: object
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.config
+        lead, h = x.shape[:-1], x.shape[-1]
+        x = x.reshape(-1, h).astype(self.dtype)
+        t = x.shape[0]
+        g, start = c.n_held_experts, c.held_experts_start
+        init = nn.initializers.normal(c.init_scale)
+        w_gate = self.param("gate", init, (h, c.n_routed_experts), self.param_dtype)
+        # float32 whatever the rest is stored in, as published: it is added to scores that differ in the last digits
+        gate_bias = self.param("gate_bias", nn.initializers.zeros_init(), (c.n_routed_experts,), jnp.float32)
+        width = c.moe_intermediate_size
+        w1 = self.param("experts_w1", init, (g, h, width), self.param_dtype).astype(self.dtype)
+        w3 = self.param("experts_w3", init, (g, h, width), self.param_dtype).astype(self.dtype)
+        w2 = self.param("experts_w2", init, (g, width, h), self.param_dtype).astype(self.dtype)
+
+        with jax.named_scope("moe/route"):
+            chosen, weights = choose_experts(
+                router_scores(x, w_gate), gate_bias, n_group=c.n_group, topk_group=c.topk_group,
+                top_k=c.num_experts_per_tok, scale=c.routed_scaling_factor,
+            )
+            held = (chosen >= start) & (chosen < start + g)
+            local = jnp.where(held, chosen - start, g)
+
+        with jax.named_scope("moe/experts"):
+            unserved = passes = jnp.zeros((), jnp.int32)
+            if t >= _GROUPED_MIN_TOKENS:
+                rows = _pass_rows(t * c.num_experts_per_tok, g / c.n_routed_experts)
+                y, unserved, passes = experts_grouped(x, local, weights, w1, w3, w2, rows)
+            else:
+                combine = (jax.nn.one_hot(local, g, dtype=jnp.float32) * weights[:, :, None]).sum(axis=1)
+                y = experts_dense(x, combine, w1, w3, w2)
+
+        if probes.active():
+            load = jnp.zeros((g + 1,), jnp.int32).at[local.reshape(-1)].add(1)[:g]
+            probes.tap("moe.load", {
+                "pairs_routed": jnp.asarray(t * c.num_experts_per_tok, jnp.int32),
+                "pairs_local": load.sum(),
+                "pairs_dropped": unserved.astype(jnp.int32),
+                "passes": passes.astype(jnp.int32),
+                "expert_load_max": load.max(),
+            })
+
+        with jax.named_scope("moe/shared"):
+            shared = SwiGLU(h, width * c.n_shared_experts, c.init_scale, self.dtype, self.param_dtype, name="shared")(x)
+        return (y + shared.astype(jnp.float32)).astype(self.dtype).reshape(*lead, h)

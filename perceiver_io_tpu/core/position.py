@@ -86,6 +86,57 @@ def apply_rotary_pos_emb(t: jnp.ndarray, pos_enc: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([rotated, t_pass], axis=-1)
 
 
+def yarn_inv_freq(
+    dim: int,
+    theta: float,
+    factor: float,
+    beta_fast: float,
+    beta_slow: float,
+    original_max_position: int,
+) -> np.ndarray:
+    """YaRN's rotary frequencies (arXiv:2309.00071, as DeepSeek-V3's
+    ``inference/model.py`` computes them): ``dim // 2`` inverse frequencies,
+    of which those that turn more than ``beta_fast`` times over the original
+    context keep their value, those that turn fewer than ``beta_slow`` times
+    are divided by ``factor``, and a linear ramp over the pair index blends
+    the two between (``factor`` 1: the plain frequencies). float32 on the
+    host: a table, not a traced value."""
+    inv_freq = (1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32) / dim))).astype(np.float32)
+    if factor == 1.0:
+        return inv_freq
+
+    def correction_dim(rotations: float) -> float:
+        return dim * math.log(original_max_position / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low) / (high - low), 0.0, 1.0)
+    smooth = 1.0 - ramp
+    return (inv_freq / factor * (1.0 - smooth) + inv_freq * smooth).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: the softmax scale is multiplied by its
+    square (``0.1 * mscale * ln(factor) + 1``, 1 where nothing is scaled)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rotary_interleaved(t: jnp.ndarray, pos: jnp.ndarray, inv_freq) -> jnp.ndarray:
+    """Rotate all channels of ``t`` (..., N, R): adjacent channels
+    ``(2i, 2i+1)`` are one complex number turned by ``pos * inv_freq[i]``.
+    ``pos`` (..., N) broadcasts against ``t``'s leading axes (pass
+    ``pos[:, None]`` for a heads axis). Computed in float32, returned in
+    ``t``'s dtype."""
+    angles = pos.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq, jnp.float32)
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    t32 = t.astype(jnp.float32)
+    x1, x2 = t32[..., 0::2], t32[..., 1::2]
+    out = jnp.stack((x1 * cos - x2 * sin, x1 * sin + x2 * cos), axis=-1)
+    return out.reshape(t.shape).astype(t.dtype)
+
+
 class RotaryPositionEmbedding:
     """Convenience wrapper bundling a frequency encoding with its alignment.
 

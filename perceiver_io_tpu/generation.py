@@ -81,6 +81,8 @@ def _maybe_quantize_weights(model, params, weight_dtype):
         return params, None
     if jnp.dtype(weight_dtype) != jnp.dtype(jnp.int8):
         raise ValueError(f"weight_dtype must be None or jnp.int8, got {weight_dtype}")
+    if hasattr(model, "generation_decoder"):
+        raise ValueError("weight_dtype: int8 weights are defined for the Perceiver AR models' `kernel` leaves only")
     from perceiver_io_tpu.ops.quant import quantize_weights
 
     return quantize_weights(params), getattr(model, "dtype", jnp.float32)
@@ -421,14 +423,148 @@ def beam_search(
     return jnp.concatenate([prompt_tiled, best_seqs], axis=1), best_scores
 
 
-def _decode_step_body(model, mcfg, config, step_params, carry, pad_slots, pos_shift, health=False):
-    """One decode step over the fixed-capacity caches — the SHARED body of
-    :func:`generate`'s compiled scan and the host-driven step fn
-    (:func:`make_decode_fns`), so the two paths cannot drift: slide the
-    windows when full (expired slots derived from the start counters, the
-    roll-free analog of the reference's truncation), apply the model on the
-    last token, sample, handle EOS freezing. Callers own parameter
-    unpacking/dequantization and the ``decode`` named scope.
+class _PerceiverARDecoder:
+    """Perceiver AR's side of the decode loop: what :func:`generate` and
+    :func:`make_decode_fns` ask of a model. The loop itself (sampling, the
+    scan, EOS freezing, parameter packing and dequantization) is shared; the
+    model supplies the prompt pass, the one-token step and the state the two
+    hand each other:
+
+    - ``prefill(params, input_ids, pad_mask, num_latents, max_new_tokens,
+      cache_dtype) -> (logits (B, N, V), window, consts)``, of which the loop
+      reads the last position: validation,
+      cache allocation, the prompt pass. ``window`` is a tuple of pytrees the
+      step advances (it rides the scan's carry, in this order); ``consts`` a
+      tuple of arrays the step only reads.
+    - ``step(step_params, window, consts, token) -> (logits (B, 1, V), window)``.
+    - ``health(logits, window)``: the Probeline decode gauges of a step.
+    - ``window_names`` / ``const_names``: the keys the parts take in the
+      host-driven pair's state dict.
+
+    A model that is not a ``CausalSequenceModel`` brings its own through a
+    ``generation_decoder()`` method (``models/text/decoder_lm.py``: latent
+    caches, no window to slide).
+
+    Here the window is ``(cache, ca_start, sa_start)``: the fixed-capacity
+    caches (cross-attention first, then one a self-attention layer) and the
+    start counters of the two sliding windows; the consts are the slot-aligned
+    pad mask and the per-row position shift."""
+
+    window_names = ("cache", "ca_start", "sa_start")
+    const_names = ("pad_slots", "pos_shift")
+    # Probeline tap sites (``probes.tap``) whose counts the probed decode pair
+    # carries beside the health gauges; Perceiver AR has none
+    tap_scopes = ()
+
+    def __init__(self, model):
+        self.model, self.mcfg = model, model.config
+
+    def prefill(self, params, input_ids, pad_mask, num_latents, max_new_tokens, cache_dtype):
+        mcfg = self.mcfg
+        b, seq_len = input_ids.shape
+        prefix_len = _validate_window(mcfg, seq_len, num_latents)
+        _require_pads_in_prefix(pad_mask, prefix_len)
+
+        from perceiver_io_tpu.core.modules import CausalSequenceModel
+
+        # Roll-free sliding window: allocate `max_new_tokens` slack so the caches
+        # never physically shift (the per-step roll + its aliasing-breaking copies
+        # cost ~60% of a decode step at 16k, measured on v5e). "Truncate the
+        # oldest" becomes marking the expired slot in the pad masks; slot index
+        # stays the token's absolute position, and RoPE only depends on position
+        # differences, so logits are identical to the rolling scheme.
+        ca_capacity = seq_len + max_new_tokens
+        sa_capacity = num_latents + max_new_tokens
+        cache = CausalSequenceModel.init_cache(
+            mcfg, b, ca_capacity=ca_capacity, sa_capacity=sa_capacity, dtype=cache_dtype
+        )
+
+        if pad_mask is None:
+            pad_mask = jnp.zeros((b, seq_len), bool)
+        # left-pad count for position shifts — pad_slots below can't double as
+        # this once expired slots are also marked
+        pos_shift = pad_mask.sum(axis=1, keepdims=True).astype(jnp.int32)
+
+        # slot-aligned pad mask over the cross-attention window (original
+        # left-pads only; expired slots are derived from the start counters)
+        pad_slots = jnp.zeros((b, ca_capacity), bool).at[:, :seq_len].set(pad_mask)
+
+        # prompt pass (populates caches); prefill_mode routes its attention
+        # through the flash kernels over the fresh k/v (see core/attention.py)
+        with jax.named_scope("prefill"), prefill_mode():
+            out = self.model.apply(params, input_ids, prefix_len=prefix_len, pad_mask=pad_mask, kv_cache=cache)
+        zero = jnp.zeros((), jnp.int32)
+        return out.logits, (out.kv_cache, zero, zero), (pad_slots, pos_shift)
+
+    def step(self, step_params, window, consts, token):
+        """Slide the windows when full (expired slots derived from the start
+        counters, the roll-free analog of the reference's truncation), apply
+        the model on the last token."""
+        mcfg = self.mcfg
+        cache, ca_start, sa_start = window
+        pad_slots, pos_shift = consts
+        ca_cache, sa_caches = cache[0], cache[1:]
+        ca_idx = jnp.arange(ca_cache.capacity, dtype=jnp.int32)[None, :]
+        sa_idx = jnp.arange(sa_caches[0].capacity, dtype=jnp.int32)[None, :]
+
+        ca_full = (ca_cache.length - ca_start) >= mcfg.max_seq_len
+        ca_start = ca_start + ca_full.astype(jnp.int32)
+        sa_full = (sa_caches[0].length - sa_start) >= mcfg.max_latents
+        sa_start = sa_start + sa_full.astype(jnp.int32)
+
+        out = self.model.apply(
+            step_params,
+            token[:, None],
+            prefix_len=0,
+            pad_mask=pad_slots | (ca_idx < ca_start),
+            kv_cache=cache,
+            decode=True,
+            sa_pad_mask=sa_idx < sa_start,
+            pos_shift=pos_shift,
+        )
+        return out.logits, (out.kv_cache, ca_start, sa_start)
+
+    def health(self, logits, window):
+        from perceiver_io_tpu.obs.probes import decode_health
+
+        return decode_health(logits, window[0][0], window[1])
+
+    def compile_row(self, batch, prompt_len, max_new_tokens, cache_dtype) -> dict:
+        return {}
+
+
+def _with_taps(scopes, thunk):
+    """``thunk()`` traced under a collector for the tap sites ``scopes``:
+    ``(result, totals)``, the taps reduced over their sites (one an expert
+    layer: counts summed, ``*_max`` maxed). With no scopes ``totals`` is
+    empty and nothing is traced that was not before."""
+    if not scopes:
+        return thunk(), {}
+    from perceiver_io_tpu.obs import probes
+
+    with probes.collecting(probes.ProbeConfig(scopes=scopes, activations=False)) as col:
+        out = thunk()
+    totals = {}
+    for entry in col.stats.values():
+        for name, v in entry.items():
+            reduce = jnp.maximum if name.endswith("_max") else jnp.add
+            totals[name] = v if name not in totals else reduce(totals[name], v)
+    return out, totals
+
+
+def _decoder_of(model):
+    """The model's side of the decode loop (see :class:`_PerceiverARDecoder`)."""
+    bring = getattr(model, "generation_decoder", None)
+    return bring() if bring is not None else _PerceiverARDecoder(model)
+
+
+def _decode_step_body(decoder, config, step_params, carry, consts, health=False):
+    """One decode step — the SHARED body of :func:`generate`'s compiled scan
+    and the host-driven step fn (:func:`make_decode_fns`), so the two paths
+    cannot drift: the model's one-token step over its window (``decoder``),
+    sample, handle EOS freezing. ``carry`` is ``(*window, token, rng,
+    done)``. Callers own parameter unpacking/dequantization and the
+    ``decode`` named scope.
 
     ``health=True`` (trace-time static — the Probeline decode gauges,
     obs/probes.py) additionally returns a third element: the in-graph
@@ -437,37 +573,17 @@ def _decode_step_body(model, mcfg, config, step_params, carry, pad_slots, pos_sh
     post-append cache. The default ``False`` returns the historical
     2-tuple and traces zero extra ops, keeping :func:`generate`'s fused
     scan bitwise identical."""
-    cache, ca_start, sa_start, token, rng, done = carry
-    ca_cache, sa_caches = cache[0], cache[1:]
-    ca_idx = jnp.arange(ca_cache.capacity, dtype=jnp.int32)[None, :]
-    sa_idx = jnp.arange(sa_caches[0].capacity, dtype=jnp.int32)[None, :]
-
-    ca_full = (ca_cache.length - ca_start) >= mcfg.max_seq_len
-    ca_start = ca_start + ca_full.astype(jnp.int32)
-    sa_full = (sa_caches[0].length - sa_start) >= mcfg.max_latents
-    sa_start = sa_start + sa_full.astype(jnp.int32)
-
-    out = model.apply(
-        step_params,
-        token[:, None],
-        prefix_len=0,
-        pad_mask=pad_slots | (ca_idx < ca_start),
-        kv_cache=cache,
-        decode=True,
-        sa_pad_mask=sa_idx < sa_start,
-        pos_shift=pos_shift,
-    )
+    *window, token, rng, done = carry
+    logits, window = decoder.step(step_params, tuple(window), consts, token)
     rng, step_rng = jax.random.split(rng)
-    sampled = _sample(out.logits[:, -1], step_rng, config)
+    sampled = _sample(logits[:, -1], step_rng, config)
     if config.eos_token_id is not None:
         sampled = jnp.where(done, config.pad_token_id, sampled)
         done = done | (sampled == config.eos_token_id)
-    carry_out = (out.kv_cache, ca_start, sa_start, sampled, rng, done)
+    carry_out = (*window, sampled, rng, done)
     if not health:
         return carry_out, sampled
-    from perceiver_io_tpu.obs.probes import decode_health
-
-    return carry_out, sampled, decode_health(out.logits[:, -1], out.kv_cache[0], ca_start)
+    return carry_out, sampled, decoder.health(logits[:, -1], window)
 
 
 def advance_rng_chain(rng: jax.Array, n_tokens: int) -> jax.Array:
@@ -616,7 +732,9 @@ def generate(
 ) -> jnp.ndarray:
     """Generate ``config.max_new_tokens`` continuation tokens.
 
-    :param model: a ``CausalSequenceModel`` (or subclass).
+    :param model: a ``CausalSequenceModel`` (or subclass), or a model that
+        brings its own side of the loop (``generation_decoder()``, see
+        :class:`_PerceiverARDecoder`).
     :param input_ids: left-padded prompt (B, S).
     :param num_latents: initial number of latent positions at the end of the
         prompt (reference: huggingface.py:187-230).
@@ -631,46 +749,17 @@ def generate(
     """
     config = config or GenerationConfig()
     rng = rng if rng is not None else jax.random.PRNGKey(0)
-    mcfg = model.config
-    b, seq_len = input_ids.shape
+    b = input_ids.shape[0]
 
     if config.max_new_tokens <= 0:
         return input_ids
 
-    prefix_len = _validate_window(mcfg, seq_len, num_latents)
-    _require_pads_in_prefix(pad_mask, prefix_len)
-
-    from perceiver_io_tpu.core.modules import CausalSequenceModel
-
-    # Roll-free sliding window: allocate `max_new_tokens` slack so the caches
-    # never physically shift (the per-step roll + its aliasing-breaking copies
-    # cost ~60% of a decode step at 16k, measured on v5e). "Truncate the
-    # oldest" becomes marking the expired slot in the pad masks; slot index
-    # stays the token's absolute position, and RoPE only depends on position
-    # differences, so logits are identical to the rolling scheme.
-    ca_capacity = seq_len + config.max_new_tokens
-    sa_capacity = num_latents + config.max_new_tokens
-    cache = CausalSequenceModel.init_cache(
-        mcfg, b, ca_capacity=ca_capacity, sa_capacity=sa_capacity, dtype=cache_dtype
+    decoder = _decoder_of(model)
+    logits, window, consts = decoder.prefill(
+        params, input_ids, pad_mask, num_latents, config.max_new_tokens, cache_dtype
     )
-
-    if pad_mask is None:
-        pad_mask = jnp.zeros((b, seq_len), bool)
-    # left-pad count for position shifts — pad_slots below can't double as
-    # this once expired slots are also marked
-    pos_shift = pad_mask.sum(axis=1, keepdims=True).astype(jnp.int32)
-
-    # slot-aligned pad mask over the cross-attention window (original
-    # left-pads only; expired slots are derived from the start counters)
-    pad_slots = jnp.zeros((b, ca_capacity), bool).at[:, :seq_len].set(pad_mask)
-
-    # prompt pass (populates caches); prefill_mode routes its attention
-    # through the flash kernels over the fresh k/v (see core/attention.py)
-    with jax.named_scope("prefill"), prefill_mode():
-        out = model.apply(params, input_ids, prefix_len=prefix_len, pad_mask=pad_mask, kv_cache=cache)
     rng, first_rng = jax.random.split(rng)
-    next_token = _sample(out.logits[:, -1], first_rng, config)
-    cache = out.kv_cache
+    next_token = _sample(logits[:, -1], first_rng, config)
 
     decode_params, compute_dtype = _maybe_quantize_weights(model, params, weight_dtype)
     if _pack_enabled(b):
@@ -682,17 +771,14 @@ def generate(
         with jax.named_scope("decode"):
             dp = decode_params if unpack_small is None else unpack_small(packed_small)
             step_params = _maybe_dequantize_weights(dp, compute_dtype)
-            return _decode_step_body(
-                model, mcfg, config, step_params, carry, pad_slots, pos_shift
-            )
+            return _decode_step_body(decoder, config, step_params, carry, consts)
 
     done0 = jnp.zeros((b,), bool)
     if config.eos_token_id is not None:
         done0 = next_token == config.eos_token_id
 
     if config.max_new_tokens > 1:
-        zero = jnp.zeros((), jnp.int32)
-        carry = (cache, zero, zero, next_token, rng, done0)
+        carry = (*window, next_token, rng, done0)
         _, tokens = lax.scan(step, carry, None, length=config.max_new_tokens - 1)
         tokens = jnp.concatenate([next_token[:, None], tokens.T], axis=1)
     else:
@@ -739,76 +825,57 @@ def make_decode_fns(
     config = config or GenerationConfig()
     if config.max_new_tokens < 1:
         raise ValueError("decode fns require max_new_tokens >= 1")
-    mcfg = model.config
+    decoder = _decoder_of(model)
+    names = decoder.window_names + decoder.const_names
     compute_dtype = None if weight_dtype is None else getattr(model, "dtype", jnp.float32)
 
     def prefill(params, input_ids, pad_mask=None, rng=None):
         rng = rng if rng is not None else jax.random.PRNGKey(0)
-        b, seq_len = input_ids.shape
-        prefix_len = _validate_window(mcfg, seq_len, num_latents)
-        _require_pads_in_prefix(pad_mask, prefix_len)
-
-        from perceiver_io_tpu.core.modules import CausalSequenceModel
-
-        ca_capacity = seq_len + config.max_new_tokens
-        sa_capacity = num_latents + config.max_new_tokens
-        cache = CausalSequenceModel.init_cache(
-            mcfg, b, ca_capacity=ca_capacity, sa_capacity=sa_capacity, dtype=cache_dtype
+        b = input_ids.shape[0]
+        (logits, window, consts), taps = _with_taps(
+            decoder.tap_scopes if probes else (),
+            lambda: decoder.prefill(params, input_ids, pad_mask, num_latents, config.max_new_tokens, cache_dtype),
         )
-        if pad_mask is None:
-            pad_mask = jnp.zeros((b, seq_len), bool)
-        pos_shift = pad_mask.sum(axis=1, keepdims=True).astype(jnp.int32)
-        pad_slots = jnp.zeros((b, ca_capacity), bool).at[:, :seq_len].set(pad_mask)
-
-        with jax.named_scope("prefill"), prefill_mode():
-            out = model.apply(
-                params, input_ids, prefix_len=prefix_len, pad_mask=pad_mask, kv_cache=cache
-            )
         rng, first_rng = jax.random.split(rng)
-        next_token = _sample(out.logits[:, -1], first_rng, config)
+        next_token = _sample(logits[:, -1], first_rng, config)
         done = jnp.zeros((b,), bool)
         if config.eos_token_id is not None:
             done = next_token == config.eos_token_id
 
         decode_params, _ = _maybe_quantize_weights(model, params, weight_dtype)
-        zero = jnp.zeros((), jnp.int32)
         state = {
             "params": decode_params,
-            "cache": out.kv_cache,
-            "ca_start": zero,
-            "sa_start": zero,
             "token": next_token,
             "rng": rng,
             "done": done,
-            "pad_slots": pad_slots,
-            "pos_shift": pos_shift,
+            **dict(zip(names, window + consts)),
         }
         if probes:
-            from perceiver_io_tpu.obs.probes import decode_health
-
             # the prompt pass's health (token 0): same gauges, same scopes,
             # so the state pytree is uniform across prefill and every step
-            state["probe"] = decode_health(out.logits[:, -1], out.kv_cache[0], zero)
+            state["probe"] = {**decoder.health(logits[:, -1], window), **taps}
         return next_token, state
 
     def step(state):
         with jax.named_scope("decode"):
             step_params = _maybe_dequantize_weights(state["params"], compute_dtype)
             carry = (
-                state["cache"], state["ca_start"], state["sa_start"],
+                *(state[k] for k in decoder.window_names),
                 state["token"], state["rng"], state["done"],
             )
-            stepped = _decode_step_body(
-                model, mcfg, config, step_params, carry,
-                state["pad_slots"], state["pos_shift"], health=probes,
+            consts = tuple(state[k] for k in decoder.const_names)
+            stepped, taps = _with_taps(
+                decoder.tap_scopes if probes else (),
+                lambda: _decode_step_body(decoder, config, step_params, carry, consts, health=probes),
             )
             carry, token = stepped[0], stepped[1]
+            n = len(decoder.window_names)
             new_state = dict(
-                state, cache=carry[0], ca_start=carry[1], sa_start=carry[2],
-                token=carry[3], rng=carry[4], done=carry[5],
+                state, **dict(zip(decoder.window_names, carry[:n])),
+                token=carry[n], rng=carry[n + 1], done=carry[n + 2],
             )
             if probes:
-                new_state["probe"] = stepped[2]
+                new_state["probe"] = {**stepped[2], **taps}
             return new_state, token
 
     return jax.jit(prefill), jax.jit(step)
@@ -1512,7 +1579,13 @@ def make_instrumented_generate_fn(
     prefill_raw, step_raw = make_decode_fns(
         model, num_latents, config, cache_dtype, weight_dtype, probes=probes
     )
-    prefill_fn = tracker.wrap(prefill_raw, "generate_prefill")
+    decoder = _decoder_of(model)
+    # the cache's geometry rides the prompt pass's ``compile`` row (nothing for Perceiver AR, whose
+    # request rows carry ca_capacity/sa_capacity)
+    prefill_fn = tracker.wrap(
+        prefill_raw, "generate_prefill",
+        extra=lambda args, kwargs: decoder.compile_row(*args[1].shape, config.max_new_tokens, cache_dtype),
+    )
     step_fn = tracker.wrap(step_raw, "generate_decode_step")
     registry = registry if registry is not None else MetricsRegistry()
     m_requests = registry.counter("generate_requests_total")
@@ -1534,6 +1607,12 @@ def make_instrumented_generate_fn(
     m_queue = registry.histogram("generate_queue_wait_s")
     m_entropy = registry.histogram("generate_logit_entropy") if probes else None
     m_kv_frac = registry.gauge("generate_kv_cache_frac") if probes else None
+    # an expert layer's routed-pair books (``core/moe.py`` taps), where the model has them
+    moe_taps = probes and "moe.*" in decoder.tap_scopes
+    m_moe_routed = registry.counter("moe_pairs_routed_total") if moe_taps else None
+    m_moe_local = registry.counter("moe_pairs_local_total") if moe_taps else None
+    m_moe_dropped = registry.counter("moe_pairs_dropped_total") if moe_taps else None
+    m_moe_load = registry.gauge("moe_expert_load_max") if moe_taps else None
     tracer = obs_trace.Tracer(events, flush_every=64) if events is not None else None
 
     def fn(params, input_ids, pad_mask=None, rng=None, queue_wait_s=None, arrival_ts=None,
@@ -1626,6 +1705,16 @@ def make_instrumented_generate_fn(
                         max(float(h["nonfinite_logit_frac"]) for h in hh), 6
                     ),
                 }
+                if moe_taps:
+                    routed, local, dropped = (
+                        sum(int(h[k]) for h in hh) for k in ("pairs_routed", "pairs_local", "pairs_dropped")
+                    )
+                    m_moe_routed.inc(routed)
+                    m_moe_local.inc(local)
+                    m_moe_dropped.inc(dropped)
+                    m_moe_load.set(max(int(h["expert_load_max"]) for h in hh))
+                    health_row["moe_local_share"] = round(local / max(routed, 1), 6)
+                    health_row["moe_pairs_dropped"] = dropped
             except Exception:  # noqa: BLE001 — health is telemetry, never fatal
                 health_row = None
         stats = GenerationStats(

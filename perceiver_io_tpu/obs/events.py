@@ -404,6 +404,11 @@ _OPTIONAL_FIELD_TYPES: Dict[str, Dict[str, tuple]] = {
         # docs/serving.md#multi-tenant-telemetry) — optional so
         # single-tenant streams stay valid, a string when present
         "tenant": (str,),
+        # the expert layers' routed-pair books of a decoder-only model
+        # (core/moe.py taps): pairs a held expert served over pairs routed,
+        # and pairs no pass served (zero, or the layer dropped tokens)
+        "moe_local_share": (int, float),
+        "moe_pairs_dropped": (int, float),
     },
     # Evictline: the engine leg of tools/loadgen.py stamps its eviction
     # behavior into the load.summary row (and the LOAD_r* artifact body) —

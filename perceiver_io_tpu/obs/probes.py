@@ -168,6 +168,24 @@ def probe(scope: str, x):
     return x
 
 
+def tap(scope: str, stats: Dict) -> None:
+    """Deposit ready-made scalar stats (counts, maxima) at a named site: the
+    tap for what is not an activation, such as an expert layer's routed-pair
+    counts (``moe.load``). No-op unless a :func:`collecting` context is open
+    and ``scope`` matches; the caller guards any ops that compute ``stats``
+    with :func:`active`."""
+    col = _ACTIVE.get()
+    if col is not None and col.config.wants(scope):
+        col.add(scope, stats)
+
+
+def current_config() -> Optional[ProbeConfig]:
+    """The open collector's config (``None`` when none is open): lets code
+    that wraps a loop body open a collector of its own inside the body."""
+    col = _ACTIVE.get()
+    return None if col is None else col.config
+
+
 # ---------------------------------------------------------------------------
 # gradient / update-ratio buckets (the train-step half)
 # ---------------------------------------------------------------------------

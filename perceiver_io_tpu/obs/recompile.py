@@ -69,7 +69,9 @@ class RecompileTracker:
         self.goodput = goodput
         self._state: Dict[str, Dict] = {}
 
-    def wrap(self, fn: Callable, name: str) -> Callable:
+    def wrap(self, fn: Callable, name: str, extra: Optional[Callable] = None) -> Callable:
+        """``extra(args, kwargs) -> dict``, where given, adds fields to each
+        ``compile`` row (the generator's cache geometry rides there)."""
         st = self._state.setdefault(
             name, {"calls": 0, "compiles": 0, "compile_s": 0.0}
         )
@@ -103,6 +105,7 @@ class RecompileTracker:
                         cache_size=after,
                         arg_shapes=shape_signature(args, kwargs),
                         **({"flash_tiles": flash_tiles} if flash_tiles else {}),
+                        **(extra(args, kwargs) if extra is not None else {}),
                     )
             return out
 

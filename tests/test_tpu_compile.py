@@ -143,3 +143,37 @@ def test_paged_decode_attention_lowers(one_chip, mosaic, pool_dtype):
     q = sds((SLOTS, HEADS, CHANNELS // HEADS), pool_dtype)
     text = _compile(pa.paged_decode_attention, q, cache)
     assert "tpu_custom_call" in text
+
+
+# DeepSeek-V3's widths (benchmarks/configs/deepseek-v3-ep16.json): the kernels
+# of the decoder-only model's prompt pass and its expert layer
+DSV3_HEADS, DSV3_HIDDEN, DSV3_EXPERT_WIDTH, DSV3_HELD = 128, 7168, 2048, 16
+
+
+def test_flash_attention_at_mla_head_widths(one_chip, mosaic):
+    """The expanded prompt pass: 128 heads of 192 query/key channels (128 +
+    64 rotary, not a multiple of the 128 lanes) and 128 value channels, four
+    1024-token rows a chunk, through the heads-major forward kernel."""
+    def sds(d):
+        return jax.ShapeDtypeStruct((4, DSV3_HEADS, 1024, d), jnp.bfloat16, sharding=one_chip)
+
+    text = _compile(lambda q, k, v: fa.flash_attention(q, k, v, causal=True, sm_scale=0.1), sds(192), sds(192), sds(128))
+    assert text.count("tpu_custom_call") >= 1 and "flash_fwd_q1024_kv1024" in text
+
+
+@pytest.mark.parametrize("rows", [1024, 256], ids=["prompt_chunk", "smallest_pass"])
+@pytest.mark.parametrize("k,n", [(DSV3_HIDDEN, DSV3_EXPERT_WIDTH), (DSV3_EXPERT_WIDTH, DSV3_HIDDEN)], ids=["up", "down"])
+def test_grouped_expert_product_lowers(one_chip, monkeypatch, rows, k, n):
+    """The held experts' grouped product (a traced number of visits in the
+    grid, the group of a visit read from prefetched scalars) at the rows of a
+    pass the expert layer takes for a prompt chunk of 8192 tokens and for the
+    384 tokens from which it takes this path at all."""
+    gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
+    moe = importlib.import_module("perceiver_io_tpu.core.moe")
+    monkeypatch.setattr(gm, "_interpret_default", lambda: False)
+    assert moe._pass_rows(8192 * 8, 16 / 256) == 1024 and moe._pass_rows(moe._GROUPED_MIN_TOKENS * 8, 16 / 256) == 256
+    lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    rhs = jax.ShapeDtypeStruct((DSV3_HELD, k, n), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((DSV3_HELD,), jnp.int32, sharding=one_chip)
+    text = _compile(lambda a, w, s: gm.grouped_matmul(a, w, s, tm=moe._ROW_TILE), lhs, rhs, sizes)
+    assert f"moe_experts_prefill_m{rows}_k{k}_n{n}" in text and "tpu_custom_call" in text
