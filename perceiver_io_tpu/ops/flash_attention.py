@@ -18,10 +18,13 @@ VMEM. One mask form covers every attention in the framework:
 Key padding is an additive f32 bias row per batch (0 or ``MASK_VALUE``),
 streamed in kv blocks — O(B·Nkv) traffic, not O(Nq·Nkv).
 
-Training support is a ``jax.custom_vjp`` with three kernels (forward, dKV,
-dQ) using the standard flash recomputation scheme: forward saves the row
-logsumexp; backward recomputes probabilities blockwise from (q, k, lse) and
-accumulates dk/dv over query blocks and dq over kv blocks.
+Training support is a ``jax.custom_vjp`` using the standard flash
+recomputation scheme: forward saves the row logsumexp; backward recomputes
+probabilities blockwise from (q, k, lse). With several query blocks that is
+two kernels (dKV accumulating over query blocks, dQ over kv blocks), each
+rebuilding the scores; where the queries are one block (every call of the
+Perceiver train steps) it is one kernel that rebuilds each score tile once
+for dq, dk and dv (``_backward``).
 
 All shapes are static; inputs are padded to block multiples by the wrapper
 (padded kv slots are masked via the bias row, padded q rows are sliced off).
@@ -71,9 +74,10 @@ LOG2E = 1.4426950408889634  # log2(e)
 # 24 latent self-attention kernels at 13.7% (PERF.md 5, PR 26), the latter
 # because a one-tile square causal call scored the half of its pairs that
 # the mask hides; PR 27 cut that tile into bands (``tile_plan`` below) and
-# took a quarter off those kernels (PERF.md 6). What is left: head width 64
-# fills half of the MXU's contraction, and both backward kernels rebuild
-# the scores. The features stay implemented and toggleable for future
+# took a quarter off those kernels (PERF.md 6), and PR 29 made the backward
+# of a one-q-block call one kernel where two had each rebuilt the scores
+# (``_backward``). What is left: head width 64 fills half of the MXU's
+# contraction. The features stay implemented and toggleable for future
 # re-probing (e.g. on a different TPU generation); the default is the empty
 # set, the round-2 kernels. Read at TRACE time, like set_default_flash.
 # Full table in docs/performance.md.
@@ -478,6 +482,57 @@ def _dq_kernel(
         dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
 
 
+def _bwd_kernel(
+    *refs,  # [bias?], q, k, v, do, lse, delta, dq, dk, dv, dq_scr
+    causal: bool,
+    offset: int,
+    sm_scale: float,
+    num_kv_blocks: int,
+    has_bias: bool,
+    v2: frozenset,
+):
+    # The call's queries are ONE block (grid (bh, kv blocks)): q, do, lse and
+    # delta stay resident over a row's kv blocks, and each score tile is
+    # rebuilt once for all three gradients. refs as in the two kernels above;
+    # dk/dv of a kv block are whole after its one tile (one q block sees into
+    # every kv block: padding is less than a block), so only dq needs a
+    # scratch (f32, over the kv blocks).
+    if has_bias:
+        bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr = refs
+    else:
+        bias_ref = None
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr = refs
+    ikv = pl.program_id(1)
+    block_q = q_ref.shape[1]
+    block_kv = k_ref.shape[1]
+
+    @pl.when(ikv == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    def _body(apply_mask: bool):
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        do = do_ref[0]
+        lse = lse_ref[0][:, :1]
+        delta = delta_ref[0][:, :1]
+
+        bias = bias_ref[0] if has_bias else None
+        p = _recompute_p(q, k, bias, lse, 0, ikv, block_q, block_kv, offset, sm_scale, apply_mask, "base2" in v2)
+        dv_ref[0] = _dot(p.astype(do.dtype), do, ((0,), (0,))).astype(dv_ref.dtype)
+        dp = _dot(do, v, ((1,), (1,)))
+        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
+        dk_ref[0] = _dot(ds, q, ((0,), (0,))).astype(dk_ref.dtype)
+        dq_scr[...] += _dot(ds, k, ((1,), (0,)))
+
+    _causal_dispatch(_body, causal, "fastmask" in v2, 0, ikv, block_q, block_kv, offset)
+
+    @pl.when(ikv == num_kv_blocks - 1)
+    def _store():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
 # ---------------------------------------------------------------------------
 # host-side wrappers
 # ---------------------------------------------------------------------------
@@ -588,24 +643,107 @@ BWD_BLOCK_Q: Optional[int] = None
 BWD_BLOCK_KV: Optional[int] = None
 
 
-def _flash_bwd(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom, residuals, g):
-    q, k, v, bias, out, lse_col = residuals
-    lse = jnp.broadcast_to(lse_col, lse_col.shape[:2] + (LANES,))
-    bh, nq, d_qk = q.shape
-    nkv = k.shape[1]
-    d_v = v.shape[2]
-    h = num_heads
+def _bwd_blocks(block_q: int, block_kv: int) -> tuple:
+    """The backward kernels' blocks for a call with these forward blocks."""
     if BWD_BLOCK_Q is not None:
         block_q = min(block_q, BWD_BLOCK_Q)
     if BWD_BLOCK_KV is not None:
         block_kv = min(block_kv, BWD_BLOCK_KV)
+    return block_q, block_kv
 
-    # delta_i = sum_c dO_ic * O_ic, broadcast over lanes for tiled loads
+
+def _backward(num_q_blocks: int) -> str:
+    """Which backward a call runs, from its shapes alone. A flash backward
+    is two kernels because dk/dv sum over query blocks and dq over kv blocks,
+    and each rebuilds the score tiles for itself. Where the queries are one
+    block (the Perceiver shape: few latents, a long input) both walk the same
+    (row, kv block) pairs with the same q, do, lse and delta resident, so
+    ``"one"`` kernel rebuilds each tile once for dq, dk and dv (PERF.md 6,
+    PR 29); several query blocks keep the ``"split"`` pair."""
+    return "one" if num_q_blocks == 1 else "split"
+
+
+def _flash_bwd(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom, residuals, g):
+    block_q, block_kv = _bwd_blocks(block_q, block_kv)
+    one = _backward(residuals[0].shape[1] // block_q) == "one"
+    return (_flash_bwd_one if one else _flash_bwd_split)(
+        causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom, residuals, g
+    )
+
+
+def _bwd_operands(residuals, g):
+    """``(inputs, has_bias)`` of the heads-major backward kernels: [bias],
+    q, k, v, do, lse, delta, the last two broadcast over lanes for tiled loads."""
+    q, k, v, bias, out, lse_col = residuals
+    lse = jnp.broadcast_to(lse_col, lse_col.shape[:2] + (LANES,))
+    # delta_i = sum_c dO_ic * O_ic
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    delta = jnp.broadcast_to(delta[..., None], (bh, nq, LANES))
-
-    nqb, nkvb = nq // block_q, nkv // block_kv
+    delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
     has_bias = bias is not None
+    return ([bias] if has_bias else []) + [q, k, v, g, lse, delta], has_bias
+
+
+def _flash_bwd_one(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom, residuals, g):
+    """The backward of a call whose queries are one block: one kernel."""
+    q, k, v = residuals[:3]
+    bh, nq, d_qk = q.shape
+    nkv = k.shape[1]
+    d_v = v.shape[2]
+    h = num_heads
+    assert nq == block_q, (nq, block_q)
+    nkvb = nkv // block_kv
+    inputs, has_bias = _bwd_operands(residuals, g)
+
+    row = lambda b, j: (b, 0, 0)  # the one q block of a batch row
+    kv = lambda b, j: (b, j, 0)
+    in_specs = [pl.BlockSpec((1, 1, block_kv), lambda b, j: (b // h, 0, j))] if has_bias else []
+    in_specs += [
+        pl.BlockSpec((1, block_q, d_qk), row),
+        pl.BlockSpec((1, block_kv, d_qk), kv),
+        pl.BlockSpec((1, block_kv, d_v), kv),
+        pl.BlockSpec((1, block_q, d_v), row),
+        pl.BlockSpec((1, block_q, LANES), row),
+        pl.BlockSpec((1, block_q, LANES), row),
+    ]
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_kernel,
+            causal=causal,
+            offset=offset,
+            sm_scale=sm_scale,
+            num_kv_blocks=nkvb,
+            has_bias=has_bias,
+            v2=v2,
+        ),
+        name=_kernel_name("bwd", geom),
+        grid=(bh, nkvb),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((1, block_q, d_qk), row),
+            pl.BlockSpec((1, block_kv, d_qk), kv),
+            pl.BlockSpec((1, block_kv, d_v), kv),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, nq, d_qk), q.dtype),
+            jax.ShapeDtypeStruct((bh, nkv, d_qk), k.dtype),
+            jax.ShapeDtypeStruct((bh, nkv, d_v), v.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((block_q, d_qk), jnp.float32)],
+        compiler_params=_compiler_params("parallel", "arbitrary"),
+        interpret=_interpret_default(),
+    )(*inputs)
+    return dq, dk, dv, jnp.zeros_like(residuals[3]) if has_bias else None
+
+
+def _flash_bwd_split(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom, residuals, g):
+    """The backward of a call with several query blocks: dkv, then dq."""
+    q, k, v, bias = residuals[:4]
+    bh, nq, d_qk = q.shape
+    nkv = k.shape[1]
+    d_v = v.shape[2]
+    h = num_heads
+    nqb, nkvb = nq // block_q, nkv // block_kv
+    inputs, has_bias = _bwd_operands(residuals, g)
 
     def specs(order):
         # order maps kernel grid dims -> (block index fns); shared between
@@ -625,7 +763,6 @@ def _flash_bwd(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom,
         pl.BlockSpec((1, block_q, d_v), lambda b, j, i: (b, i, 0)),
         pl.BlockSpec((1, block_q, LANES), lambda b, j, i: (b, i, 0)),
     ))
-    inputs = ([bias] if has_bias else []) + [q, k, v, g, lse, delta]
 
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -772,6 +909,7 @@ class TilePlan(NamedTuple):
     tiles_run: int
     tiles_masked: int  # of those run: in a band, or a whole grid tile, that the diagonal crosses
     tiles_skipped: int
+    backward: str  # "one" kernel for dq, dk and dv, or the "split" pair (``_backward``)
 
     @property
     def run_share(self) -> float:
@@ -781,10 +919,11 @@ class TilePlan(NamedTuple):
 def _make_plan(n_q: int, n_kv: int, causal: bool, block_q: int, block_kv: int) -> TilePlan:
     """The plan of a call at these grid blocks (what the kernels will do)."""
     nqb, nkvb = _round_up(n_q, block_q) // block_q, _round_up(n_kv, block_kv) // block_kv
+    backward = _backward(nqb * block_q // _bwd_blocks(block_q, block_kv)[0])
     unit = LANES * LANES
     total = nqb * nkvb * block_q * block_kv // unit
     if not causal:
-        return TilePlan(block_q, block_kv, 0, total, 0, 0)
+        return TilePlan(block_q, block_kv, 0, total, 0, 0, backward)
     offset = n_kv - n_q
     cut = dict(_diagonals(causal, offset, block_q, block_kv, nqb, nkvb)["diagonals"])
     run = masked = 0
@@ -794,7 +933,7 @@ def _make_plan(n_q: int, n_kv: int, causal: bool, block_q: int, block_kv: int) -
         bands = cut.get(delta, ((0, block_q, block_kv, 0 if delta < block_kv - 1 else None),))
         run += _band_area(bands) // unit
         masked += _band_area([band for band in bands if band[3] is not None]) // unit
-    return TilePlan(block_q, block_kv, _BAND_ROWS if cut else 0, run, masked, total - run)
+    return TilePlan(block_q, block_kv, _BAND_ROWS if cut else 0, run, masked, total - run, backward)
 
 
 def tile_plan(
@@ -1037,6 +1176,85 @@ def _dq_packed_kernel(
             dq_ref[0, :, hh * d_qk : (hh + 1) * d_qk] = dq_scr[hh].astype(dq_ref.dtype)
 
 
+def _bwd_packed_kernel(
+    *refs,  # [bias?], q, k, v, do, lse, delta, dq, dk, dv, dq_scr, [dk_scr, dv_scr]
+    causal: bool,
+    offset: int,
+    sm_scale: float,
+    num_kv_blocks: int,
+    num_heads: int,
+    d_qk: int,
+    d_v: int,
+    has_bias: bool,
+    v2: frozenset,
+    diagonals: tuple = (),
+    whole: bool = True,
+):
+    # The call's queries are ONE block (grid (b, kv blocks)): q, do, lse and
+    # delta stay resident over a row's kv blocks, and each score band is
+    # rebuilt once for all three gradients. refs and the sums, in their
+    # order, are those of the two kernels above: dq adds up over the kv
+    # blocks in dq_scr (h, block_q, d_qk) f32; dk/dv of a kv block add up over
+    # the bands of a cut tile in dk_scr/dv_scr (h, block_kv, d) f32, and where
+    # the call cuts no tile there is no such scratch and they are written
+    # straight from the tile's one band (the 1024 x 8704 call ran 6.8% faster
+    # without it: PERF.md 6, PR 29).
+    if has_bias:
+        bias_ref, *refs = refs
+    else:
+        bias_ref = None
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr, *kv_scr = refs
+    ikv = pl.program_id(1)
+    h = num_heads
+    block_q = q_ref.shape[1]
+    block_kv = k_ref.shape[1]
+
+    @pl.when(ikv == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+
+    if kv_scr:
+        dk_scr, dv_scr = kv_scr
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def _band(r0, r1, width, keep):
+        # rows [r0, r1) of the q block against the kv block's first ``width`` slots
+        bias = bias_ref[0, :, :width] if has_bias else None
+        for hh in range(h):
+            qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
+            kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
+            vh = v_ref[0, :width, hh * d_v : (hh + 1) * d_v]
+            doh = do_ref[0, r0:r1, hh * d_v : (hh + 1) * d_v]
+            lse = lse_ref[0, r0:r1, hh * RES_LANES : hh * RES_LANES + 1]
+            delta = delta_ref[0, r0:r1, hh * RES_LANES : hh * RES_LANES + 1]
+            p = _recompute_p_keep(qh, kh, bias, lse, keep, sm_scale, "base2" in v2)
+            dv = _dot(p.astype(doh.dtype), doh, ((0,), (0,)))
+            dp = _dot(doh, vh, ((1,), (1,)))
+            ds = (p * (dp - delta) * sm_scale).astype(qh.dtype)
+            dk = _dot(ds, qh, ((0,), (0,)))
+            dq_scr[hh, r0:r1] += _dot(ds, kh, ((1,), (0,)))
+            if kv_scr:
+                dv_scr[hh, :width] += dv
+                dk_scr[hh, :width] += dk
+            else:
+                dv_ref[0, :, hh * d_v : (hh + 1) * d_v] = dv.astype(dv_ref.dtype)
+                dk_ref[0, :, hh * d_qk : (hh + 1) * d_qk] = dk.astype(dk_ref.dtype)
+
+    _body = _tile_body(_band, 0, ikv, block_q, block_kv, offset)
+    _causal_dispatch(_body, causal, "fastmask" in v2, 0, ikv, block_q, block_kv, offset, diagonals, whole)
+
+    if kv_scr:
+        for hh in range(h):
+            dk_ref[0, :, hh * d_qk : (hh + 1) * d_qk] = dk_scr[hh].astype(dk_ref.dtype)
+            dv_ref[0, :, hh * d_v : (hh + 1) * d_v] = dv_scr[hh].astype(dv_ref.dtype)
+
+    @pl.when(ikv == num_kv_blocks - 1)
+    def _store():
+        for hh in range(h):
+            dq_ref[0, :, hh * d_qk : (hh + 1) * d_qk] = dq_scr[hh].astype(dq_ref.dtype)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13))
 def _flash_packed(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom):
     out, _ = _flash_packed_fwd_impl(
@@ -1109,23 +1327,96 @@ def _flash_packed_fwd(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv
 
 
 def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom, residuals, g):
+    block_q, block_kv = _bwd_blocks(block_q, block_kv)
+    one = _backward(residuals[0].shape[1] // block_q) == "one"
+    return (_flash_packed_bwd_one if one else _flash_packed_bwd_split)(
+        causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom, residuals, g
+    )
+
+
+def _packed_bwd_operands(residuals, g, h, d_v):
+    """``(inputs, has_bias)`` of the packed backward kernels: [bias], q, k,
+    v, do, lse, delta, the last two as RES_LANES lanes per head."""
     q, k, v, bias, out, lse_slim = residuals
     b, nq, _ = q.shape
-    nkv = k.shape[1]
-    if BWD_BLOCK_Q is not None:
-        block_q = min(block_q, BWD_BLOCK_Q)
-    if BWD_BLOCK_KV is not None:
-        block_kv = min(block_kv, BWD_BLOCK_KV)
-
     lse = jnp.broadcast_to(lse_slim, (b, nq, h, RES_LANES)).reshape(b, nq, h * RES_LANES)
     # delta_i = sum_c dO_ic O_ic per head; minor-dim reshapes are bitcasts
     g4 = g.astype(jnp.float32).reshape(b, nq, h, d_v)
     out4 = out.astype(jnp.float32).reshape(b, nq, h, d_v)
     delta = jnp.sum(g4 * out4, axis=-1)  # (b, nq, h)
     delta = jnp.broadcast_to(delta[..., None], (b, nq, h, RES_LANES)).reshape(b, nq, h * RES_LANES)
-
-    nqb, nkvb = nq // block_q, nkv // block_kv
     has_bias = bias is not None
+    return ([bias] if has_bias else []) + [q, k, v, g, lse, delta], has_bias
+
+
+def _flash_packed_bwd_one(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom, residuals, g):
+    """The backward of a call whose queries are one block: one kernel."""
+    q, k, v = residuals[:3]
+    b, nq, _ = q.shape
+    nkv = k.shape[1]
+    assert nq == block_q, (nq, block_q)
+    nkvb = nkv // block_kv
+    inputs, has_bias = _packed_bwd_operands(residuals, g, h, d_v)
+
+    cut = _diagonals(causal, offset, block_q, block_kv, 1, nkvb)
+    # dk/dv of a kv block add up in f32 scratch over the bands of a cut tile;
+    # a call without one writes them straight from its one band a kv block
+    # (one q block sees into every kv block: padding is less than a block)
+    scratch = [pltpu.VMEM((h, block_q, d_qk), jnp.float32)]
+    if cut["diagonals"]:
+        scratch += [pltpu.VMEM((h, block_kv, d_qk), jnp.float32), pltpu.VMEM((h, block_kv, d_v), jnp.float32)]
+    row = lambda b_, j: (b_, 0, 0)  # the one q block of a batch row
+    kv = lambda b_, j: (b_, j, 0)
+    in_specs = [pl.BlockSpec((1, 1, block_kv), lambda b_, j: (b_, 0, j))] if has_bias else []
+    in_specs += [
+        pl.BlockSpec((1, block_q, h * d_qk), row),
+        pl.BlockSpec((1, block_kv, h * d_qk), kv),
+        pl.BlockSpec((1, block_kv, h * d_v), kv),
+        pl.BlockSpec((1, block_q, h * d_v), row),
+        pl.BlockSpec((1, block_q, h * RES_LANES), row),
+        pl.BlockSpec((1, block_q, h * RES_LANES), row),
+    ]
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(
+            _bwd_packed_kernel,
+            causal=causal,
+            offset=offset,
+            sm_scale=sm_scale,
+            num_kv_blocks=nkvb,
+            num_heads=h,
+            d_qk=d_qk,
+            d_v=d_v,
+            has_bias=has_bias,
+            v2=v2,
+            **cut,
+        ),
+        name=_kernel_name("bwd", geom),
+        grid=(b, nkvb),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((1, block_q, h * d_qk), row),
+            pl.BlockSpec((1, block_kv, h * d_qk), kv),
+            pl.BlockSpec((1, block_kv, h * d_v), kv),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, nq, h * d_qk), q.dtype),
+            jax.ShapeDtypeStruct((b, nkv, h * d_qk), k.dtype),
+            jax.ShapeDtypeStruct((b, nkv, h * d_v), v.dtype),
+        ],
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params("parallel", "arbitrary"),
+        interpret=_interpret_default(),
+    )(*inputs)
+    return dq, dk, dv, jnp.zeros_like(residuals[3]) if has_bias else None
+
+
+def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom, residuals, g):
+    """The backward of a call with several query blocks: dkv, then dq."""
+    q, k, v, bias = residuals[:4]
+    b, nq, _ = q.shape
+    nkv = k.shape[1]
+    nqb, nkvb = nq // block_q, nkv // block_kv
+    inputs, has_bias = _packed_bwd_operands(residuals, g, h, d_v)
 
     dkv_in_specs = []
     dq_in_specs = []
@@ -1148,7 +1439,6 @@ def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v,
         pl.BlockSpec((1, block_q, h * RES_LANES), lambda b_, i, j: (b_, i, 0)),
         pl.BlockSpec((1, block_q, h * RES_LANES), lambda b_, i, j: (b_, i, 0)),
     ]
-    inputs = ([bias] if has_bias else []) + [q, k, v, g, lse, delta]
 
     dk, dv = pl.pallas_call(
         functools.partial(

@@ -294,11 +294,12 @@ def _pallas_names(jaxpr, out):
 @pytest.mark.parametrize("latents,seq,keep", [(128, 384, 128), (512, 1024, 256)], ids=["one-tile", "re-blocked"])
 def test_train_step_names_flash_kernels_by_pass_and_geometry(latents, seq, keep):
     """On CPU the kernels run interpreted, but the ``pallas_call`` equations
-    of the traced step keep the names the chip's trace prints: forward, dq
-    and dkv, with the cross-attention's kept-prefix-plus-latents KV length
-    and the self-attention's. The names hold the call's lengths, not its
-    blocks: a self-attention whose tile plan skips the scores the mask hides
-    (512 latents) is named like one that runs one whole tile (128)."""
+    of the traced step keep the names the chip's trace prints: forward and
+    backward (the latents are one q block, so one kernel and no dq / dkv
+    pair), with the cross-attention's kept-prefix-plus-latents KV length and
+    the self-attention's. The names hold the call's lengths, not its blocks:
+    a self-attention whose tile plan skips the scores the mask hides (512
+    latents) is named like one that runs one whole tile (128)."""
     from perceiver_io_tpu.training import clm_loss_fn
 
     fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
@@ -318,10 +319,12 @@ def test_train_step_names_flash_kernels_by_pass_and_geometry(latents, seq, keep)
         jaxpr = jax.make_jaxpr(jax.grad(lambda p: loss(p, batch, jax.random.PRNGKey(1))[0]))(params)
     names = _pallas_names(jaxpr.jaxpr, [])
     cross, self_ = f"q{latents}_kv{keep + latents}", f"q{latents}_kv{latents}"
-    want = {f"flash_{p}_{g}": n for g, n in ((cross, 1), (self_, 2)) for p in ("fwd", "dq", "dkv")}
+    want = {f"flash_{p}_{g}": n for g, n in ((cross, 1), (self_, 2)) for p in ("fwd", "bwd")}
     got = {n: names.count(n) for n in set(names)}
     assert got == want, got
     # the traced calls left their plans where the ``compile`` event row reads them
-    shares = {r["geometry"]: r["run_share"] for r in fa.tile_plans() if r["causal"]}
+    rows = [r for r in fa.tile_plans() if r["causal"]]
+    shares = {r["geometry"]: r["run_share"] for r in rows}
+    assert {r["backward"] for r in rows if r["geometry"] in (cross, self_)} == {"one"}
     assert shares[self_] == (1.0 if latents == 128 else fa.tile_plan(latents, latents, True).run_share)
     assert fa.tile_plan(512, 512, True).run_share <= 0.75

@@ -10,7 +10,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perceiver_io_tpu.ops.flash_attention import flash_attention
+import importlib
+
+from perceiver_io_tpu.ops.flash_attention import MASK_VALUE, flash_attention
+
+# the package re-exports a function under the module's name
+fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
 
 
 def einsum_attention(q, k, v, pad_mask=None, causal=False, sm_scale=1.0):
@@ -147,3 +152,53 @@ def test_fast_kernel_flags_context_scoped():
 
     with _pytest.raises(ValueError, match="unknown kernel features"):
         set_fast_kernels(["warp_speed"])
+
+
+@pytest.mark.parametrize(
+    "nq,nkv,d,causal,padded",
+    [(256, 640, 16, True, False), (128, 600, 16, False, True), (128, 384, 24, False, False), (256, 256, 16, True, True)],
+    ids=["causal-offset-256x640", "padded-128x600", "wide-128x384", "causal-square-padded"],
+)
+def test_one_kernel_backward_equals_the_split_pair(rng, nq, nkv, d, causal, padded):
+    """Heads-major calls whose queries are one block: the one backward kernel
+    returns the dkv + dq pair's gradients on the same residuals."""
+    b, h, block_kv = 2, 2, 128
+    q, k, v, w = (jnp.asarray(rng.normal(size=(b * h, n, d)), jnp.float32) for n in (nq, nkv, nkv, nq))
+    kf, vf = (fa._pad_to(x, 1, block_kv) for x in (k, v))
+    bias = jnp.zeros((b, kf.shape[1]), jnp.float32).at[:, nkv:].set(MASK_VALUE)
+    if padded:
+        bias = bias.at[:, :3].set(MASK_VALUE)
+    statics = (causal, nkv - nq, d**-0.5, nq, block_kv, h, frozenset(), f"q{nq}_kv{nkv}")
+    _, residuals = fa._flash_fwd(q, kf, vf, bias[:, None, :], *statics)
+    one = fa._flash_bwd_one(*statics, residuals, w)
+    split = fa._flash_bwd_split(*statics, residuals, w)
+    for name, x, y in zip(("dq", "dk", "dv"), one, split):
+        assert float(jnp.max(jnp.abs(y))) > 0.1, name
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-6, rtol=1e-6, err_msg=name)
+
+
+def test_one_q_block_gradients_match_einsum(rng):
+    """The public call at one q block (what the image model's cross-attention
+    runs) takes the one-kernel backward and matches plain attention."""
+    b, h, nq, nkv, d = 1, 2, 128, 384, 16
+    q, k, v = (jnp.asarray(rng.normal(size=(b, h, n, d)), jnp.float32) for n in (nq, nkv, nkv))
+    pad = jnp.asarray(rng.random((b, nkv)) < 0.2)
+
+    def loss(attn, **blocks):
+        return lambda q_, k_, v_: jnp.sum(attn(q_, k_, v_, pad_mask=pad, sm_scale=d**-0.5, **blocks) ** 2)
+
+    flash = loss(flash_attention, block_q=128, block_kv=128)
+
+    def pallas_names(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from pallas_names(sub)
+
+    names = sorted(pallas_names(jax.make_jaxpr(jax.grad(flash, argnums=(0, 1, 2)))(q, k, v).jaxpr))
+    assert names == ["flash_bwd_q128_kv384", "flash_fwd_q128_kv384"]
+    got = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(einsum_attention), argnums=(0, 1, 2))(q, k, v)
+    for x, y in zip(got, want):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=5e-4, rtol=1e-4)

@@ -1,6 +1,8 @@
 """Packed (slots-major) flash kernels: parity with the heads-major path and
 the dense reference, values and gradients (interpret mode on CPU)."""
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -13,6 +15,9 @@ from perceiver_io_tpu.ops.flash_attention import (
     set_default_flash,
     tile_plan,
 )
+
+# the package re-exports a function under the module's name
+fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
 
 B, H, DQK, DV = 2, 4, 16, 16
 
@@ -210,3 +215,72 @@ def test_compile_event_carries_the_tile_plans():
     (kind, fields), = Sink.rows
     row = next(r for r in fields["flash_tiles"] if r["geometry"] == "q512_kv512" and r["causal"])
     assert kind == "compile" and row["block_q"] == 256 and row["tiles_skipped"] == 4 and row["run_share"] == 0.75
+
+
+# --- the backward (PR 29): one kernel where the queries are one block --------
+
+
+def _pallas_names(jaxpr, into):
+    """Names of the ``pallas_call`` equations under ``jaxpr``, nested calls included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            into.append(eqn.params["name"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _pallas_names(sub, into)
+    return into
+
+
+@pytest.mark.parametrize(
+    "nq,nkv,h,d,causal,blocks,padded",
+    [
+        (512, 512, 2, 16, True, (None, None), False),  # square causal: its one tile is cut into bands
+        (256, 640, 2, 16, True, (256, 128), False),  # causal with an offset over five kv blocks
+        (128, 384, 2, 16, False, (128, 128), False),
+        (256, 600, 2, 16, False, (256, 128), True),  # a pad mask, and kv padded to 640
+        (256, 600, 2, 16, True, (256, 128), True),
+        (128, 256, 1, 136, False, (128, 128), False),  # one wide head
+    ],
+    ids=["causal-banded-512x512", "causal-offset-256x640", "plain-128x384", "padded-256x600", "causal-padded-256x600",
+         "one-wide-head"],
+)
+def test_one_kernel_backward_equals_the_split_pair(nq, nkv, h, d, causal, blocks, padded):
+    """Same residuals and cotangent through both private backwards: the one
+    kernel forms each score band once and must return the pair's dq, dk and
+    dv (the same f32 sums in the same order)."""
+    rng = np.random.default_rng(nq + nkv + d)
+    q, k, v, w = (jnp.asarray(rng.normal(size=(B, n, h * d)), jnp.float32) for n in (nq, nkv, nkv, nq))
+    plan = tile_plan(nq, nkv, causal, *blocks)
+    assert plan.block_q == nq and plan.backward == "one"
+    kf, vf = (fa._pad_to(x, 1, plan.block_kv) for x in (k, v))
+    bias = jnp.zeros((B, kf.shape[1]), jnp.float32).at[:, nkv:].set(MASK_VALUE)
+    if padded:
+        bias = bias.at[:, :3].set(MASK_VALUE)
+    statics = (causal, nkv - nq, d**-0.5, plan.block_q, plan.block_kv, h, d, d, frozenset(), f"q{nq}_kv{nkv}")
+    _, residuals = fa._flash_packed_fwd(q, kf, vf, bias[:, None, :], *statics)
+    one = fa._flash_packed_bwd_one(*statics, residuals, w)
+    split = fa._flash_packed_bwd_split(*statics, residuals, w)
+    for name, a, b in zip(("dq", "dk", "dv"), one, split):
+        assert float(jnp.max(jnp.abs(b))) > 0.1, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6, rtol=1e-6, err_msg=name)
+
+
+def test_backward_is_chosen_by_the_number_of_q_blocks():
+    """One q block runs ``flash_bwd_*``; two keep ``flash_dq_*`` and
+    ``flash_dkv_*``; the plan rows say which. Nothing but the shapes decides."""
+    x = jnp.zeros((1, 256, 16), jnp.float32)
+
+    def names(block_q):
+        def loss(q, k, v):
+            return jnp.sum(flash_attention_packed(q, k, v, num_heads=2, causal=True, block_q=block_q, block_kv=128))
+
+        return sorted(_pallas_names(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x).jaxpr, []))
+
+    def row():
+        return next(r for r in fa.tile_plans() if r["geometry"] == "q256_kv256" and r["causal"])
+
+    assert names(256) == ["flash_bwd_q256_kv256", "flash_fwd_q256_kv256"]
+    assert (row()["block_q"], row()["backward"]) == (256, "one")
+    assert names(128) == ["flash_dkv_q256_kv256", "flash_dq_q256_kv256", "flash_fwd_q256_kv256"]
+    assert (row()["block_q"], row()["backward"]) == (128, "split")
+    assert {p.backward for p in (tile_plan(1024, 8704, True), tile_plan(1024, 1024, True), tile_plan(512, 512, False))} == {"one"}
+    assert tile_plan(2048, 2048, True).backward == "split"

@@ -82,8 +82,23 @@ def test_flash_attention_packed_fwd_bwd(one_chip, mosaic, n_kv):
         return out.astype(jnp.float32).sum()
 
     text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
-    # forward + dKV + dQ kernels, none replaced by an einsum fallback
-    assert text.count("tpu_custom_call") >= 3
+    # the forward kernel and the one backward kernel (the 1024 latents are
+    # one q block), neither replaced by an einsum fallback
+    assert text.count("tpu_custom_call") >= 2 and f"flash_bwd_q{LATENTS}_kv{n_kv}" in text
+
+
+def test_flash_attention_image_cross_fwd_bwd(one_chip, mosaic):
+    """The image model's cross-attention (512 latents over 224 x 224 pixels,
+    one head of 261 channels, padded to 264): the heads-major forward and its
+    one-kernel backward, two rows."""
+    q = jax.ShapeDtypeStruct((2, 1, 512, 261), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 1, 224 * 224, 261), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, sm_scale=261**-0.5).astype(jnp.float32).sum()
+
+    text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+    assert "flash_fwd_q512_kv50176" in text and "flash_bwd_q512_kv50176" in text
 
 
 @pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])

@@ -37,9 +37,12 @@ PEAK_TFLOPS = 197e12  # v5e bf16
 MXU_CEILING = 1.0
 
 
-# score-tile matmuls executed per alive kernel: fwd kernel = s + o; dq
-# kernel = recompute-s + dp + dq; dkv kernel = recompute-s + dv + dp + dk
-_CHAIN_MATMULS = {"fwd": 2, "dq": 2 + 3, "dkv": 2 + 4, "fwdbwd": 2 + 3 + 4}
+# score-tile matmuls executed per alive kernel: fwd kernel = s + o; the
+# backward of these one-q-block geometries is ONE kernel since PR 29
+# (recompute-s + dv + dp + dk + dq), so the 'dq' and 'dkv' chains below both
+# keep it alive whole and read the same kernel (tools/tile_plan_ab.py
+# --backward times it against the dkv + dq pair)
+_CHAIN_MATMULS = {"fwd": 2, "dq": 2 + 5, "dkv": 2 + 5, "fwdbwd": 2 + 5}
 
 
 def roofline_ms(g, chain: str) -> float:
@@ -111,8 +114,9 @@ def main():
             # per-kernel isolation: a gradient that is not fed back into the
             # carry is dead code and XLA REMOVES its kernel (observed:
             # impossible >100%-of-roofline readings). 'dq' keeps fwd+dq
-            # kernels alive; 'dkv' keeps fwd+dkv alive; a *0 contribution
-            # would likewise DCE the whole backward.
+            # kernels alive; 'dkv' keeps fwd+dkv alive (both the one
+            # backward kernel where the queries are one block); a *0
+            # contribution would likewise DCE the whole backward.
             eps = jnp.bfloat16(1e-3)
 
             @functools.partial(jax.jit, static_argnums=3)

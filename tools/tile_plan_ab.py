@@ -1,4 +1,4 @@
-"""Same-process A/B of tile plans for the packed causal flash kernels.
+"""Same-process A/B of tile plans, and of the two backwards, of the flash kernels.
 
 One process builds every variant (grid blocks, and bands inside a tile on
 the diagonal) of one attention geometry, runs them round-robin, each round
@@ -16,6 +16,13 @@ bands of that many rows; ``plan`` is what ``tile_plan`` chooses itself.
 ``--compile-only`` lowers and compiles every variant for a described v5e (no
 chip) and runs nothing. PERF.md 6 (PR 27) has the readings that set
 ``_BAND_ROWS`` and ``_BAND_MAX_SHARE``.
+
+    python tools/tile_plan_ab.py --geom ca sa img_sa img_ca --backward
+
+``--backward`` times the two backwards of a call whose queries are one block
+on the same residuals: ``split`` (dkv, then dq) against ``one`` kernel, each
+through its private function (``_flash[_packed]_bwd_split`` / ``_bwd_one``; the
+program picks between them by shape and has no switch). PERF.md 6 (PR 29).
 """
 
 from __future__ import annotations
@@ -39,11 +46,17 @@ fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
 
 # the attention calls of the benchmark's cells: latent self-attention and
 # cross-attention of ar16k-train-b32, the prompt pass of ar16k-decode-b64
+# and, for --backward, the two calls of imagenet-train-b16: latent
+# self-attention, and the heads-major cross-attention over the pixels (one
+# head of 261 channels, which the wrapper pads to 264)
 GEOMS = {
     "sa": dict(b=32, nq=1024, nkv=1024, h=8, d=64, bwd=True),
     "ca": dict(b=32, nq=1024, nkv=8704, h=8, d=64, bwd=True),
     "prompt": dict(b=64, nq=768, nkv=768, h=8, d=64, bwd=False),
+    "img_sa": dict(b=16, nq=512, nkv=512, h=8, d=128, bwd=True, causal=False),
+    "img_ca": dict(b=16, nq=512, nkv=50176, h=1, d=264, bwd=True, causal=False, packed=False),
 }
+BACKWARDS = ("split", "one")
 
 
 def parse_variant(text: str):
@@ -86,8 +99,30 @@ def build(geom: dict, variant: str, sharding=None):
     return lowered, plan
 
 
+def build_backward(geom: dict, which: str, sharding=None):
+    """The jitted forward and ``which`` backward of one call, as its wrapper
+    makes them: the plan's blocks, a zero bias row, the padded head width."""
+    b, nq, nkv, h, d = (geom[key] for key in ("b", "nq", "nkv", "h", "d"))
+    causal, packed = geom.get("causal", True), geom.get("packed", True)
+    plan = fa.tile_plan(nq, nkv, causal)  # the heads-major wrapper picks the same blocks
+    assert (nq, nkv % plan.block_kv) == (plan.block_q, 0), "a geometry for --backward has one q block and no padding"
+    statics = (causal, nkv - nq, d**-0.5, plan.block_q, plan.block_kv, h)
+    statics += ((d, d) if packed else ()) + (frozenset(), fa._geometry(nq, nkv))
+    prefix = "_flash_packed" if packed else "_flash"
+    forward, backward = getattr(fa, f"{prefix}_fwd"), getattr(fa, f"{prefix}_bwd_{which}")
+
+    def fwd_bwd(q, k, v, w):
+        out, residuals = forward(q, k, v, jnp.zeros((b, 1, nkv), jnp.float32), *statics)
+        return (out,) + backward(*statics, residuals, w)[:3]
+
+    shape = (lambda n: (b, n, h * d)) if packed else (lambda n: (b * h, n, d))
+    args = [jax.ShapeDtypeStruct(shape(n), jnp.bfloat16, sharding=sharding) for n in (nq, nkv, nkv, nq)]
+    with jax.default_matmul_precision("default"):
+        return jax.jit(fwd_bwd).lower(*args), plan
+
+
 def flash_ms(trace_dir: str) -> dict:
-    """Device ms per flash pass (``fwd``, ``dq``, ``dkv``) in one capture."""
+    """Device ms per flash pass (``fwd``, ``dq``, ``dkv``, ``bwd``) in one capture."""
     from perceiver_io_tpu.obs.xplane import load_capture
 
     out: dict = {}
@@ -101,14 +136,17 @@ def flash_ms(trace_dir: str) -> dict:
 
 def main():
     p = argparse.ArgumentParser()
-    p.add_argument("--geom", choices=sorted(GEOMS), required=True)
-    p.add_argument("--variants", nargs="+", required=True)
+    p.add_argument("--geom", choices=sorted(GEOMS), nargs="+", required=True)
+    p.add_argument("--variants", nargs="+", help="tile plans to compare (not with --backward)")
+    p.add_argument("--backward", action="store_true", help="compare the split backward with the one-kernel one")
     p.add_argument("--calls", type=int, default=8)
     p.add_argument("--rounds", type=int, default=3)
     p.add_argument("--compile-only", action="store_true")
     p.add_argument("--out", default=None, help="write the table as JSON here")
     args = p.parse_args()
-    geom = GEOMS[args.geom]
+    if args.backward == bool(args.variants):
+        p.error("give --variants or --backward")
+    variants = list(BACKWARDS) if args.backward else args.variants
 
     sharding = None
     if args.compile_only:
@@ -122,10 +160,21 @@ def main():
     elif jax.default_backend() != "tpu":
         raise SystemExit("tile_plan_ab times kernels on the chip: no TPU here (use --compile-only)")
 
+    rows = []
+    for name in args.geom:
+        rows += run_geom(name, variants, args, sharding)
+    if args.out and rows:
+        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump(rows, f, indent=1)
+
+
+def run_geom(name: str, variants: list, args, sharding) -> list:
+    geom = GEOMS[name]
     lowered = {}
-    for variant in args.variants:
-        lowered[variant] = build(geom, variant, sharding)
-        print(f"{variant}: {lowered[variant][1]}", flush=True)
+    for variant in variants:
+        lowered[variant] = (build_backward if args.backward else build)(geom, variant, sharding)
+        print(f"{name} {variant}: {lowered[variant][1]}", flush=True)
 
     def compile_one(variant):
         t0 = time.perf_counter()
@@ -134,29 +183,25 @@ def main():
 
     compiled = {}
     with concurrent.futures.ThreadPoolExecutor(max_workers=4) as pool:
-        for variant, exe, secs in pool.map(compile_one, args.variants):
+        for variant, exe, secs in pool.map(compile_one, variants):
             compiled[variant] = exe
-            print(f"{variant}: compiled in {secs:.1f} s", flush=True)
+            print(f"{name} {variant}: compiled in {secs:.1f} s", flush=True)
     if args.compile_only:
-        return
+        return []
 
     rng = np.random.default_rng(0)
-    h, d = geom["h"], geom["d"]
-    operands = [
-        jnp.asarray(rng.normal(size=(geom["b"], n, h * d)), jnp.bfloat16)
-        for n in (geom["nq"], geom["nkv"], geom["nkv"], geom["nq"])
-    ]
+    operands = [jnp.asarray(rng.normal(size=a.shape), a.dtype) for a in lowered[variants[0]][0].args_info[0]]
     base = None
     gaps = {}
-    for variant in args.variants:
+    for variant in variants:
         outs = [np.asarray(x, np.float32) for x in compiled[variant](*operands)]
         if base is None:
             base = outs
         gaps[variant] = [float(np.max(np.abs(a - b)) / (np.max(np.abs(b)) + 1e-30)) for a, b in zip(outs, base)]
 
-    times = {v: [] for v in args.variants}
+    times = {v: [] for v in variants}
     for round_ in range(args.rounds):
-        for variant in args.variants:
+        for variant in variants:
             with tempfile.TemporaryDirectory() as tmp:
                 jax.profiler.start_trace(tmp)
                 for _ in range(args.calls):
@@ -167,22 +212,19 @@ def main():
             times[variant].append({k: v / args.calls for k, v in ms.items()})
 
     rows = []
-    passes = ["fwd", "dq", "dkv"] if geom["bwd"] else ["fwd"]
-    print(f"\n{args.geom} {geom}: device ms a call, median of {args.rounds} rounds of {args.calls} calls")
+    passes = ["fwd"] + (["dq", "dkv"] + (["bwd"] if args.backward else []) if geom["bwd"] else [])
+    print(f"\n{name} {geom}: device ms a call, median of {args.rounds} rounds of {args.calls} calls")
     head = " ".join(f"{p_:>8}" for p_ in passes)
     print(f"{'variant':<28} {head} {'sum':>8}  run_share  gap to first (out, dq, dk, dv)")
-    for variant in args.variants:
+    for variant in variants:
         med = {p_: float(np.median([t.get(p_, 0.0) for t in times[variant]])) for p_ in passes}
         plan = lowered[variant][1]
-        row = dict(geom=args.geom, variant=variant, plan=plan._asdict(), run_share=plan.run_share,
+        row = dict(geom=name, variant=variant, plan=plan._asdict(), run_share=plan.run_share,
                    ms=med, ms_sum=sum(med.values()), rounds=times[variant], gap_to_first=gaps[variant])
         rows.append(row)
         print(f"{variant:<28} " + " ".join(f"{med[p_]:8.3f}" for p_ in passes)
               + f" {row['ms_sum']:8.3f}  {plan.run_share:9.3f}  " + " ".join(f"{g:.1e}" for g in gaps[variant]))
-    if args.out:
-        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
-        with open(args.out, "w") as f:
-            json.dump(rows, f, indent=1)
+    return rows
 
 
 if __name__ == "__main__":
