@@ -676,9 +676,6 @@ def kernel_smoke() -> None:
 
     - packed flash attention (the flagship hot path) fwd AND bwd against
       the materialized-scores einsum reference,
-    - the two-segment packed kernels (the `fast_kernels` "twoseg" prefix
-      cross-attention route) fwd AND bwd against the packed concat path,
-      at an odd prefix length that straddles a kv block boundary,
     - heads-major flash attention fwd (the fallback layout),
     - the cached block-diagonal decode step (bf16 and int8 KV storage)
       against the module's own einsum fallback path (reached via a 2-token
@@ -688,11 +685,7 @@ def kernel_smoke() -> None:
     """
     t0 = time.perf_counter()
     from perceiver_io_tpu.core.attention import MultiHeadAttention, init_kv_cache, prefill_mode
-    from perceiver_io_tpu.ops.flash_attention import (
-        flash_attention,
-        flash_attention_packed,
-        flash_attention_packed_2seg,
-    )
+    from perceiver_io_tpu.ops.flash_attention import flash_attention, flash_attention_packed
 
     rng = np.random.default_rng(0)
     b, h, nq, nkv, d = 2, 4, 256, 512, 64
@@ -737,45 +730,6 @@ def kernel_smoke() -> None:
     o_hm = jax.jit(lambda a, c, w: flash_attention(a, c, w, causal=True, sm_scale=1.0))(q, k, v)
     err = float(jnp.abs(o_hm - o_ref).max())
     assert err < 2e-2, f"heads-major flash fwd diverges from einsum: max abs {err}"
-
-    # two-segment packed kernels vs the packed concat path: kv window of
-    # 456 = odd prefix 200 (straddles the 128-wide kv blocks, exercising the
-    # static tail mask) + the 256 latent rows — fwd and all five gradients
-    n_p = 200
-    kc, vc = packed(k)[:, : n_p + nq], packed(v)[:, : n_p + nq]
-    kp, kl = kc[:, :n_p], kc[:, n_p:]
-    vp, vl = vc[:, :n_p], vc[:, n_p:]
-
-    def loss_2seg(qp, kp_, vp_, kl_, vl_):
-        o = flash_attention_packed_2seg(
-            qp, kp_, vp_, kl_, vl_, num_heads=h, sm_scale=1.0, block_q=128, block_kv=128
-        )
-        return jnp.vdot(o.astype(jnp.float32), packed(cot).astype(jnp.float32))
-
-    def loss_cat(qp, kp_, vp_, kl_, vl_):
-        o = flash_attention_packed(
-            qp, jnp.concatenate([kp_, kl_], 1), jnp.concatenate([vp_, vl_], 1),
-            num_heads=h, causal=True, sm_scale=1.0, block_q=128, block_kv=128,
-        )
-        return jnp.vdot(o.astype(jnp.float32), packed(cot).astype(jnp.float32))
-
-    o_2s = jax.jit(
-        lambda a, c, w, e, f: flash_attention_packed_2seg(
-            a, c, w, e, f, num_heads=h, sm_scale=1.0, block_q=128, block_kv=128
-        )
-    )(packed(q), kp, vp, kl, vl)
-    o_cat = jax.jit(
-        lambda a, c, w: flash_attention_packed(
-            a, c, w, num_heads=h, causal=True, sm_scale=1.0, block_q=128, block_kv=128
-        )
-    )(packed(q), kc, vc)
-    err = float(jnp.abs(o_2s - o_cat).max())
-    assert err < 2e-2, f"two-segment flash fwd diverges from concat path: max abs {err}"
-    g_2s = jax.jit(jax.grad(loss_2seg, argnums=(0, 1, 2, 3, 4)))(packed(q), kp, vp, kl, vl)
-    g_ct = jax.jit(jax.grad(loss_cat, argnums=(0, 1, 2, 3, 4)))(packed(q), kp, vp, kl, vl)
-    for name, a, bb in zip(("dq", "dkp", "dvp", "dkl", "dvl"), g_2s, g_ct):
-        gerr = float(jnp.abs(jnp.asarray(a) - jnp.asarray(bb)).max())
-        assert gerr < 5e-2, f"two-segment flash bwd {name} diverges: max abs {gerr}"
 
     # cached decode: block-diagonal single-token step vs the einsum fallback
     # (2-token step, first query) — bf16 and int8 KV storage
@@ -889,7 +843,7 @@ def main():
                         "tools/graphcheck.py; runs by default in every mode)")
     p.add_argument("--kernel-features", default=None,
                    help="trace-time flash kernel feature set for A/B runs: 'all', "
-                        "'none', or a comma list (e.g. 'twoseg') — see "
+                        "'none', or a comma list (e.g. 'paged') — see "
                         "ops/flash_attention.py ALL_FEATURES; recorded in the "
                         "result's telemetry block")
     p.add_argument("--mesh", default=None, metavar="data=N[,fsdp=M]",

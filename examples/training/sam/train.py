@@ -16,14 +16,8 @@ import numpy as np
 
 from perceiver_io_tpu.data.audio.symbolic import MaestroV3DataModule
 from perceiver_io_tpu.models.audio.symbolic import SymbolicAudioModel, SymbolicAudioModelConfig
-from perceiver_io_tpu.ops.flash_attention import fast_kernels
 from perceiver_io_tpu.scripts import cli
 from perceiver_io_tpu.training.losses import clm_loss_fn
-
-# Trace-time flash kernel features (ops/flash_attention.py ALL_FEATURES).
-# {"twoseg"} routes the prefix cross-attention through the two-segment
-# packed kernels — the [prefix; latents] kv concat is never materialized.
-KERNEL_FEATURES: frozenset = frozenset()
 
 MAX_SEQ_LEN = 6144
 
@@ -65,17 +59,16 @@ def main():
         "prefix_len": MAX_SEQ_LEN - config.max_latents,
         "pad_mask": np.zeros((1, MAX_SEQ_LEN), bool),
     }
-    with fast_kernels(KERNEL_FEATURES):
-        cli.run_training(
-            model,
-            config,
-            lambda apply_fn: clm_loss_fn(apply_fn, config.max_latents),
-            init_batch,
-            cli.cycle(data.train_batches()),
-            data.valid_batches(),
-            trainer_args,
-            opt_args,
-        )
+    cli.run_training(
+        model,
+        config,
+        lambda apply_fn: clm_loss_fn(apply_fn, config.max_latents),
+        init_batch,
+        cli.cycle(data.train_batches()),
+        data.valid_batches(),
+        trainer_args,
+        opt_args,
+    )
 
 
 if __name__ == "__main__":

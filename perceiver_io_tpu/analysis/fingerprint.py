@@ -1,8 +1,8 @@
 """GraphFingerprint — the canonical, diffable summary of one compiled program.
 
 PR 3's graphlint answers "is this graph acceptable *now*"; nothing stopped a
-later PR from silently regressing what an earlier one certified — the twoseg
-no-kv-concat guarantee, the overlap step's collective budget, peak memory.
+later PR from silently regressing what an earlier one certified — the hot
+scopes' concat inventory, the overlap step's collective budget, peak memory.
 This module makes those guarantees *contracts*: a fingerprint is extracted
 from each flagship program (train flat, train data x fsdp, train overlap,
 prefill, decode), committed under ``contracts/``, and every
@@ -15,7 +15,7 @@ A fingerprint records, per program:
 - per-kind collective ``{count, bytes}`` over the compiled HLO
   (GSPMD-inserted included — the jaxpr never sees those);
 - the hot-scope concat inventory (the ``[prefix; latents]`` kv build and
-  friends — a NEW entry is exactly the regression twoseg exists to kill);
+  friends — a NEW entry is a tensor re-materialized on the hot path);
 - committed donation alias count, captured-const bytes, a dtype histogram
   of the traced ops, XLA-reported FLOPs, and the static peak-HBM breakdown
   (:mod:`perceiver_io_tpu.analysis.memory`).
@@ -310,8 +310,7 @@ def diff_fingerprints(
 
     # hot-scope concats: a MULTISET over (scope, axis, shape) — a new site,
     # MORE concats at an existing site (unrolled chunks share one scope), or
-    # a shape change at one site are all the re-materialized kv build the
-    # twoseg kernels exist to kill
+    # a shape change at one site are all a re-materialized kv build
     old_c: Dict[tuple, int] = {}
     for c in old.hot_concats:
         old_c[_concat_key(c)] = old_c.get(_concat_key(c), 0) + 1

@@ -24,22 +24,15 @@ prefill cross-program companion. Geometries:
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import Dict, Optional, Sequence, Tuple
 
 from perceiver_io_tpu.analysis.check import Report, check
 from perceiver_io_tpu.analysis.rules import CompanionProgram, LintPolicy
 
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# the known-good allowlist for DEFAULT kernel features:
-# - kv_concat: the concat prefix route (core/modules.py CrossAttention
-#   "kv_concat" scope) is the default until twoseg graduates from its
-#   staged A/B (PR 2, docs/performance.md) — under features=("twoseg",)
-#   the scope disappears from the trace entirely, which is the point.
-#   This entry is LEDGER-DERIVED: :func:`default_allow` drops it the moment
-#   contracts/ledger.json moves twoseg to default_on, so graduation flips
-#   the allowlist in the same commit that flips the contract;
+# the known-good allowlist of the flagship graphs:
+# - kv_concat: the prefix cross-attention builds its [prefix; latents] kv
+#   tensor (core/modules.py CrossAttention, "kv_concat" scope): what the
+#   program does, reviewed and accepted;
 # - perceiver_ar._attend: the RoPE frequency-table [prefix; latents]
 #   concat — a true sequence-axis concat, but of a (B, N, head_dim/2)
 #   table (~1 MB f32 at 16k vs the kv build's 64 MB), reviewed and accepted
@@ -71,23 +64,6 @@ def features_context(features: Optional[Sequence[str]]):
     ctx.enter_context(fast_kernels(set(features)))
     return ctx
 
-
-def default_allow(contracts_dir: Optional[str] = None) -> Tuple[str, ...]:
-    """The flagship allowlist under CURRENT ledger state: the ``kv_concat``
-    entry exists only while ``twoseg`` is not ``default_on`` in
-    ``contracts/ledger.json`` — once the feature graduates, the concat
-    route is no longer the shipped graph and allowlisting it would mask a
-    regression. Falls back to :data:`DEFAULT_ALLOW` when no ledger exists."""
-    from perceiver_io_tpu.analysis.ledger import default_on_features, load_ledger
-
-    contracts_dir = contracts_dir or os.path.join(_REPO_ROOT, "contracts")
-    try:
-        feats = default_on_features(load_ledger(contracts_dir))
-    except Exception:  # noqa: BLE001 — an unreadable ledger keeps the defaults
-        feats = ()
-    return tuple(
-        a for a in DEFAULT_ALLOW if not ("kv_concat" in a and "twoseg" in feats)
-    )
 
 GEOMETRIES = {
     # same architecture/op structure as the flagship, toy sizes; latents
@@ -186,8 +162,6 @@ def build_targets(
     # the dataflow rules run on every flagship target: RNG hygiene and dead
     # compute are program-shape properties, not geometry or mesh properties
     dataflow_policy = dict(check_rng=True, dead_compute_min_flops=DEAD_COMPUTE_MIN_FLOPS)
-    allow = default_allow()
-
     out: Dict[str, LintTarget] = {}
     if "train" in targets:
         from perceiver_io_tpu.training.prefix_dropout import sample_prefix_keep_idx
@@ -278,7 +252,7 @@ def build_targets(
             fn=step,
             args=(state, batch),
             policy=policy,
-            allow=allow,
+            allow=DEFAULT_ALLOW,
         )
 
     if "prefill" in targets or "decode" in targets or "decode_paged" in targets:
@@ -316,7 +290,7 @@ def build_targets(
                     ),
                     **dataflow_policy,
                 ),
-                allow=allow,
+                allow=DEFAULT_ALLOW,
             )
         if "decode_paged" in targets:
             # the ENGINE's batched paged decode step (serving.engine drives
@@ -337,7 +311,7 @@ def build_targets(
                     paged_cache_scopes=("*paged_kv_append*",),
                     **dataflow_policy,
                 ),
-                allow=allow,
+                allow=DEFAULT_ALLOW,
             )
     if "decode_spec" in targets:
         # the SPECULATIVE draft/verify span (Specline): drafter scan + ONE
@@ -354,7 +328,7 @@ def build_targets(
                 collective_budget=collective_budget,
                 **dataflow_policy,
             ),
-            allow=allow,
+            allow=DEFAULT_ALLOW,
         )
     return out
 
@@ -465,7 +439,7 @@ def lint_flagship(
     distributed step — see :func:`build_targets`.
 
     ``features``: trace-time kernel feature set to lint under (e.g.
-    ``("twoseg",)``); ``None`` keeps the ambient/default set. Feature sets
+    ``("paged",)``); ``None`` keeps the ambient/default set. Feature sets
     only exist on the flash kernel routes, which auto-enable on TPU only —
     so an explicit ``features`` also forces flash on (interpret-capable
     trace off-TPU), making the linted graph match the TPU program the

@@ -1,7 +1,7 @@
 """The feature-graduation ledger — staged → measured → default_on as data.
 
-Both flagship perf levers (twoseg flash cross-attention, the overlap-
-scheduled distributed step) shipped default-off with A/Bs staged but
+Perf levers (the overlap-scheduled distributed step, the paged decode
+kernel, speculative decode) shipped default-off with A/Bs staged but
 unmeasured; "remember to flip it after the TPU run" is not a system. The
 ledger (``contracts/ledger.json``, committed next to the BENCH_*.json
 artifacts it cites) makes graduation a state machine:
@@ -10,8 +10,8 @@ artifacts it cites) makes graduation a state machine:
 - ``measured``   — the named A/B ran on real hardware and the delta is
   recorded in a committed BENCH artifact;
 - ``default_on`` — the feature is the default path; graphcheck fingerprints
-  the flagship programs UNDER the feature, so its graph guarantees (e.g.
-  twoseg's no-kv-concat) become contract terms.
+  the flagship programs UNDER the feature, so its graph guarantees become
+  contract terms.
 
 Transitions are forward one step at a time (staged → measured →
 default_on); demotions may jump anywhere backward but, like every

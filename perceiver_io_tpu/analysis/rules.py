@@ -67,7 +67,7 @@ class LintPolicy:
     min_concat_axis: int = 128
     # any concatenate whose OUTPUT has a dimension of one of these sizes
     # fires regardless of scope — the "this exact tensor must never be
-    # built" form of the rule (the PR 2 twoseg kv-concat guarantee)
+    # built" form of the rule
     concat_dim_sizes: Tuple[int, ...] = ()
     # unsorted/non-unique gathers are only suspicious where a sorted or
     # fused access was the design (attention kv reads, decode cache reads)
@@ -409,8 +409,8 @@ def hot_concat(ctx: RuleContext) -> List[Violation]:
                     severity=_severity(ctx, "hot-concat"),
                     scope=op.scope,
                     op="concatenate",
-                    message=f"concatenate {why} — feed the segments to the kernel "
-                    "as separate operands (see ops/flash_attention.py twoseg)",
+                    message=f"concatenate {why} — feed the segments to the consumer "
+                    "as separate operands",
                 )
             )
         elif op.primitive == "gather":

@@ -56,7 +56,6 @@ from perceiver_io_tpu.core.position import apply_rotary_pos_emb
 from perceiver_io_tpu.ops.flash_attention import (
     flash_attention,
     flash_attention_packed,
-    flash_attention_packed_2seg,
     flash_enabled,
     flash_supported,
     packed_supported,
@@ -188,10 +187,9 @@ class MultiHeadAttention(nn.Module):
         return self.o_proj(o.transpose(0, 2, 1, 3).reshape(b, n, self.v_channels))
 
     def packed_route_ok(self, n_q: int, n_kv: int, dropout_active: bool) -> bool:
-        """Gate shared by every packed-flash route — the cache-free path and
-        prefill path below, and the two-segment dispatch
-        (``CrossAttention._two_segment_ok``): flash on, head dims packable,
-        shapes kernel-supported. One predicate so the routes cannot drift."""
+        """Gate shared by the packed-flash routes — the cache-free path and
+        the prefill path below: flash on, head dims packable, shapes
+        kernel-supported. One predicate so the routes cannot drift."""
         h = self.num_heads
         d_qk = self.qk_channels // h
         d_v = self.v_channels // h
@@ -223,62 +221,6 @@ class MultiHeadAttention(nn.Module):
             causal=self.causal_attention,
             sm_scale=1.0,
         )
-
-    def two_segment(
-        self,
-        x_q: jnp.ndarray,
-        x_kv_prefix: jnp.ndarray,
-        pad_mask_prefix: Optional[jnp.ndarray] = None,
-        pad_mask_latent: Optional[jnp.ndarray] = None,
-        rope_q: Optional[jnp.ndarray] = None,
-        rope_k_prefix: Optional[jnp.ndarray] = None,
-        rope_k_latent: Optional[jnp.ndarray] = None,
-    ) -> AttentionOutput:
-        """Causal prefix cross-attention of ``x_q`` over the logical kv
-        sequence ``[x_kv_prefix; x_q]`` WITHOUT materializing the
-        concatenation (the ``fast_kernels`` "twoseg" route — see
-        :func:`~perceiver_io_tpu.ops.flash_attention.flash_attention_packed_2seg`).
-
-        Both inputs arrive already layer-normed by the caller
-        (``CrossAttention`` applies ``q_norm``/``kv_norm`` before
-        dispatching). Projections are row-wise, so projecting the segments
-        separately is arithmetically identical to projecting the concat;
-        RoPE is per-position, so each segment rotates with its own
-        encodings. No KV cache and no attention-prob dropout on this route
-        (callers gate; see ``CrossAttention._two_segment_ok``)."""
-        h = self.num_heads
-        qk_per_head = self.qk_channels // h
-        with jax.named_scope("qkv_proj"):
-            q = self.q_proj(x_q)
-            k_l = self.k_proj(x_q)
-            v_l = self.v_proj(x_q)
-            k_p = self.k_proj(x_kv_prefix)
-            v_p = self.v_proj(x_kv_prefix)
-
-        q4 = q.reshape(q.shape[0], q.shape[1], h, qk_per_head) * qk_per_head**-0.5
-        if rope_q is not None:
-            q4 = apply_rotary_pos_emb(q4, rope_q[:, :, None, :])
-
-        def rotate(k, rope):
-            if rope is None:
-                return k
-            k4 = k.reshape(k.shape[0], k.shape[1], h, qk_per_head)
-            return apply_rotary_pos_emb(k4, rope[:, :, None, :]).reshape(k.shape)
-
-        k_p = rotate(k_p, rope_k_prefix)
-        k_l = rotate(k_l, rope_k_latent)
-        o = flash_attention_packed_2seg(
-            q4.reshape(q.shape),
-            k_p,
-            v_p,
-            k_l,
-            v_l,
-            num_heads=h,
-            pad_mask_prefix=pad_mask_prefix,
-            pad_mask_latent=pad_mask_latent,
-            sm_scale=1.0,
-        )
-        return AttentionOutput(last_hidden_state=self.o_proj(o), kv_cache=None)
 
     def _paged_decode_attend(
         self, q, cache: PagedKVCache, pad_mask, rope_q, deterministic

@@ -34,8 +34,8 @@ Layout invariants the seam pins (and the ``decode_paged`` contract checks):
 - keys stored **rotated** (rotate-at-write): a token's rotation rides it
   into whichever discipline stores it, so positions never need re-rotation;
 - appends never concatenate: ``dynamic_update_slice`` (contiguous) or a
-  page-indexed scatter (paged) — the kv-axis concatenate the twoseg kernels
-  killed must not reappear in any discipline's graph.
+  page-indexed scatter (paged) — no discipline's graph holds a kv-axis
+  concatenate.
 """
 
 from __future__ import annotations

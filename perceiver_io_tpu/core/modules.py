@@ -34,7 +34,7 @@ from jax import lax
 
 from perceiver_io_tpu.core.attention import AttentionOutput, KVCache, MultiHeadAttention, init_kv_cache
 from perceiver_io_tpu.obs.probes import probe
-from perceiver_io_tpu.ops.layernorm import FusedLayerNorm
+from perceiver_io_tpu.ops.layernorm import LayerNorm
 from perceiver_io_tpu.core.config import CausalSequenceModelConfig
 from perceiver_io_tpu.core.position import positions
 
@@ -108,8 +108,8 @@ class CrossAttention(nn.Module):
     use_flash: Optional[bool] = None
 
     def setup(self):
-        self.q_norm = FusedLayerNorm(epsilon=LAYER_NORM_EPSILON, dtype=self.dtype)
-        self.kv_norm = FusedLayerNorm(epsilon=LAYER_NORM_EPSILON, dtype=self.dtype)
+        self.q_norm = LayerNorm(epsilon=LAYER_NORM_EPSILON, dtype=self.dtype)
+        self.kv_norm = LayerNorm(epsilon=LAYER_NORM_EPSILON, dtype=self.dtype)
         self.attention = MultiHeadAttention(
             num_heads=self.num_heads,
             num_q_input_channels=self.num_q_input_channels,
@@ -126,26 +126,6 @@ class CrossAttention(nn.Module):
             use_flash=self.use_flash,
         )
 
-    def _two_segment_ok(self, x_q, x_kv_prefix, kv_cache, deterministic) -> bool:
-        """Gate for the two-segment kv route (the `fast_kernels` "twoseg"
-        feature): the prefix-mode causal cross-attention with no KV cache,
-        no active attention-prob dropout, and kernel-supported shapes. When
-        False the concat path below runs — the two are identical in
-        semantics, so the flag off reproduces the old path exactly."""
-        from perceiver_io_tpu.ops.flash_attention import fast_features
-
-        if "twoseg" not in fast_features():
-            return False
-        if kv_cache is not None or not self.causal_attention:
-            return False
-        if x_kv_prefix.shape[1] < 1:
-            return False
-        n_q = x_q.shape[1]
-        dropout_active = self.dropout > 0.0 and not deterministic
-        return self.attention.packed_route_ok(
-            n_q, x_kv_prefix.shape[1] + n_q, dropout_active
-        )
-
     def __call__(
         self,
         x_q,
@@ -159,27 +139,11 @@ class CrossAttention(nn.Module):
     ) -> AttentionOutput:
         x_q = self.q_norm(x_q)
         if x_kv is None:
-            if self._two_segment_ok(x_q, x_kv_prefix, kv_cache, deterministic):
-                # segmented route: the concatenated [prefix; latents] kv
-                # tensor (and its K/V projections) are never materialized —
-                # the Pallas kernels read the two segments as separate
-                # operands (ops/flash_attention.py two-segment path)
-                n_p = x_kv_prefix.shape[1]
-                return self.attention.two_segment(
-                    x_q,
-                    self.kv_norm(x_kv_prefix),
-                    pad_mask_prefix=None if pad_mask is None else pad_mask[:, :n_p],
-                    pad_mask_latent=None if pad_mask is None else pad_mask[:, n_p:],
-                    rope_q=rope_q,
-                    rope_k_prefix=None if rope_k is None else rope_k[:, :n_p],
-                    rope_k_latent=None if rope_k is None else rope_k[:, n_p:],
-                )
             with jax.named_scope("kv_concat"):
-                # the materialized [prefix; latents] kv tensor the twoseg
-                # route exists to kill — labeled so graphlint's hot-concat
-                # rule attributes it precisely (analysis/flagship.py
-                # DEFAULT_ALLOW allowlists exactly this scope while the
-                # concat route remains the default)
+                # the materialized [prefix; latents] kv tensor — labeled so
+                # graphlint's hot-concat rule attributes it precisely
+                # (analysis/flagship.py DEFAULT_ALLOW allowlists exactly
+                # this scope)
                 x_kv_prefix = self.kv_norm(x_kv_prefix)
                 x_kv = jnp.concatenate([x_kv_prefix, x_q], axis=1)
         else:
@@ -287,7 +251,7 @@ class SelfAttention(nn.Module):
     use_flash: Optional[bool] = None
 
     def setup(self):
-        self.norm = FusedLayerNorm(epsilon=LAYER_NORM_EPSILON, dtype=self.dtype)
+        self.norm = LayerNorm(epsilon=LAYER_NORM_EPSILON, dtype=self.dtype)
         self.attention = MultiHeadAttention(
             num_heads=self.num_heads,
             num_q_input_channels=self.num_channels,
@@ -346,7 +310,7 @@ class MLP(nn.Module):
         )
         with jax.named_scope("mlp"):
             # name pinned: auto-naming would differ from nn.LayerNorm's
-            x = FusedLayerNorm(epsilon=LAYER_NORM_EPSILON, dtype=self.dtype, name="LayerNorm_0")(x)
+            x = LayerNorm(epsilon=LAYER_NORM_EPSILON, dtype=self.dtype, name="LayerNorm_0")(x)
             x = dense(self.widening_factor * self.num_channels, "dense_1")(x)
             x = nn.gelu(x, approximate=False)
             x = dense(self.num_channels, "dense_2")(x)
@@ -1372,7 +1336,7 @@ class CausalSequenceModel(nn.Module):
             **ar_kwargs,
         )
         if cfg.output_norm:
-            self.out_norm = FusedLayerNorm(epsilon=LAYER_NORM_EPSILON, dtype=self.dtype)
+            self.out_norm = LayerNorm(epsilon=LAYER_NORM_EPSILON, dtype=self.dtype)
         self.output_adapter = TiedTokenOutputAdapter(
             vocab_size=cfg.vocab_size, emb_bias=cfg.output_bias, dtype=self.dtype
         )

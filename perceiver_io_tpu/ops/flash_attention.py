@@ -47,50 +47,20 @@ from jax.experimental.pallas import tpu as pltpu
 
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 LANES = 128
-LOG2E = 1.4426950408889634  # log2(e)
 
-# v2 kernel optimizations (measured A/B on v5e, see docs/performance.md):
-#   - base-2 softmax: the score tile is scaled by sm_scale*log2(e) (a multiply
-#     the kernel already paid for sm_scale) and probabilities use the VPU's
-#     native exp2 instead of exp; the lse residual is kept in base-2 units and
-#     the backward recompute mirrors it. The softmax-backward ds formula is
-#     UNCHANGED: d/ds of p = 2^(s*c*log2e - lse2) is p*c — the log2e*ln2
-#     factors cancel.
-#   - zero-bias skip: the flagship packed path (no pad_mask, divisor blocks =
-#     no kv padding) carries an all-zero bias row; the wrappers pass
-#     ``bias=None`` and the kernels drop the stream + add entirely.
-#   - full-tile fast path: causal tiles strictly below the masked diagonal
-#     skip iota/compare/select generation (3 of 4 CA kv blocks at the 16k
-#     flagship are fully visible).
-#   - slim running stats: the packed kernels' m/l scratch carries RES_LANES
-#     lanes instead of 128 (only lane 0 is information).
-# MEASURED AND REJECTED as defaults (same-process interleaved full-step A/B
-# on the 16k flagship, batch 4, v5e — tools/kernel_ab.py): none of these
-# "obvious" VPU trims beats the round-2 kernels; every one is neutral to
-# slightly NEGATIVE (fastmask +0.5%, slimstats +1.4%, base2 +2.0%,
-# nobias +3.5%, all-four +3.9% step time). That says these trims do not
-# pay, not that the kernels are near an optimum: at batch 32 the benchmark
-# reads the 16k cross-attention kernels at 23.5% of their roofline and the
-# 24 latent self-attention kernels at 13.7% (PERF.md 5, PR 26), the latter
-# because a one-tile square causal call scored the half of its pairs that
-# the mask hides; PR 27 cut that tile into bands (``tile_plan`` below) and
-# took a quarter off those kernels (PERF.md 6), and PR 29 made the backward
-# of a one-q-block call one kernel where two had each rebuilt the scores
-# (``_backward``). What is left: head width 64 fills half of the MXU's
-# contraction. The features stay implemented and toggleable for future
-# re-probing (e.g. on a different TPU generation); the default is the empty
-# set, the round-2 kernels. Read at TRACE time, like set_default_flash.
-# Full table in docs/performance.md.
+# One forward kernel per family (heads-major, packed) and the backward
+# ``_backward`` picks by shape. Four VPU trims of these kernels (a base-2
+# softmax, a skipped all-zero bias stream, a mask-free branch for fully
+# visible causal tiles, narrower running-stat scratch) were measured on the
+# v5e and lost, each alone and all together (+0.5% to +3.9% step time); they
+# were deleted in PR 30 (table: docs/performance.md, "Round-3 ... REJECTED").
 #
-# "twoseg" is a STRUCTURAL feature, not a VPU trim: it routes the Perceiver
-# AR prefix cross-attention through the two-segment kernels below (kept
-# prefix and latent K/V as separate operands — the concatenated x_kv tensor
-# and its LayerNorm output are never materialized). Gated like the trims so
-# tools/step_ab.py can A/B it same-process; see docs/performance.md round 6.
-# "paged" is structural like "twoseg": it routes the engine's paged decode
-# attention through the page-walk kernel (ops/paged_attention.py) instead of
-# the gather-view fallback; default-off until a real-TPU A/B graduates it.
-ALL_FEATURES = frozenset({"base2", "nobias", "fastmask", "slimstats", "twoseg", "paged"})
+# The one trace-time choice left is "paged": it routes the engine's paged
+# decode attention through the page-walk kernel (ops/paged_attention.py)
+# instead of the gather view. The chip has not made that choice yet: no
+# serve cell exists, and the two have single readings on the v5e, not an A/B
+# (ROADMAP R2 settles it). Read at TRACE time, like set_default_flash.
+ALL_FEATURES = frozenset({"paged"})
 # scoped per-context (contextvar, not a module global): a probe thread
 # toggling features cannot leak them into another thread's traces
 _FAST_FEATURES = contextvars.ContextVar("flash_fast_features", default=frozenset())
@@ -108,14 +78,15 @@ def _parse_features(mode) -> frozenset:
 
 
 def fast_features() -> frozenset:
-    """The active feature set (read at trace time by the kernel builders)."""
+    """The active feature set (read at trace time where a feature routes)."""
     return _FAST_FEATURES.get()
 
 
 def set_fast_kernels(mode) -> None:
-    """Select kernel optimizations (trace-time, for A/B probes): True = all,
-    False = none (round-2 kernels), or an iterable of feature names. Affects
-    the CURRENT context only; prefer :func:`fast_kernels` for scoped use."""
+    """Select trace-time kernel features (for A/B probes): True = all of
+    ``ALL_FEATURES``, False = none (the default), or an iterable of feature
+    names. Affects the CURRENT context only; prefer :func:`fast_kernels` for
+    scoped use."""
     _FAST_FEATURES.set(_parse_features(mode))
 
 
@@ -151,7 +122,7 @@ def kernel_mesh(mesh, batch_axes):
 
 def _on_batch_shards(kernel, *operands):
     """``kernel(*operands)``, under :func:`kernel_mesh` as a shard_map over
-    the batch (leading) dim of every operand; ``None`` operands pass through."""
+    the batch (leading) dim of every operand."""
     scope = _KERNEL_MESH.get()
     if scope is None or scope[0].size == 1:
         return kernel(*operands)
@@ -160,23 +131,9 @@ def _on_batch_shards(kernel, *operands):
     mesh, batch_axes = scope
     axes = tuple(a for a in batch_axes if mesh.shape[a] > 1)
     spec = P(axes) if axes else P()
-    present = [x for x in operands if x is not None]
-
-    def body(*shards):
-        it = iter(shards)
-        return kernel(*(None if x is None else next(it) for x in operands))
-
     return jax.shard_map(
-        body, mesh=mesh, in_specs=(spec,) * len(present), out_specs=spec, check_vma=False
-    )(*present)
-
-
-def _exp(x, base2: bool):
-    return jnp.exp2(x) if base2 else jnp.exp(x)
-
-
-def _log(x, base2: bool):
-    return jnp.log2(x) if base2 else jnp.log(x)
+        kernel, mesh=mesh, in_specs=(spec,) * len(operands), out_specs=spec, check_vma=False
+    )(*operands)
 
 
 # Residual lane width for the packed kernels' lse/delta side-channels: only
@@ -216,12 +173,6 @@ def _block_visible(iq, ikv, block_q: int, block_kv: int, offset: int):
     return ikv * block_kv <= (iq + 1) * block_q - 1 + offset
 
 
-def _block_fully_visible(iq, ikv, block_q: int, block_kv: int, offset: int):
-    """True iff EVERY entry of score tile (iq, ikv) is unmasked — the tile's
-    last kv column is within the first query row's limit."""
-    return (ikv + 1) * block_kv - 1 <= iq * block_q + offset
-
-
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -251,10 +202,8 @@ def _tile_body(band, iq, ikv, block_q: int, block_kv: int, offset: int):
     return body
 
 
-def _causal_dispatch(body, causal: bool, fastmask: bool, iq, ikv, block_q, block_kv, offset, diagonals=(), whole=True):
-    """Run ``body(apply_mask)`` once per visible tile. Under ``fastmask``,
-    fully-visible causal tiles take a mask-free branch (no iota/compare/
-    select generation); only diagonal-straddling tiles pay for the mask.
+def _causal_dispatch(body, causal: bool, iq, ikv, block_q, block_kv, offset, diagonals=(), whole=True):
+    """Run ``body(apply_mask)`` once per visible tile.
 
     ``diagonals`` (packed kernels, :func:`_diagonals`): for positions the
     mask's diagonal takes inside a tile, the bands of that tile that hold its
@@ -273,41 +222,27 @@ def _causal_dispatch(body, causal: bool, fastmask: bool, iq, ikv, block_q, block
         def when(cond):
             return pl.when(jnp.logical_and(cond, uncut))
 
-    if causal and fastmask:
-        full = _block_fully_visible(iq, ikv, block_q, block_kv, offset)
-        vis = _block_visible(iq, ikv, block_q, block_kv, offset)
-        when(jnp.logical_and(vis, full))(lambda: body(False))
-        when(jnp.logical_and(vis, jnp.logical_not(full)))(lambda: body(True))
-    elif causal:
+    if causal:
         when(_block_visible(iq, ikv, block_q, block_kv, offset))(lambda: body(True))
     else:
         body(False)
 
 
 def _fwd_kernel(
-    *refs,  # [bias?], q, k, v, o, lse, m_scr, l_scr, acc_scr
+    *refs,  # bias, q, k, v, o, lse, m_scr, l_scr, acc_scr
     causal: bool,
     offset: int,
     sm_scale: float,
     num_kv_blocks: int,
-    has_bias: bool,
-    v2: frozenset,
 ):
-    # refs: bias (1, 1, block_kv) f32 when has_bias; q (1, block_q, d_qk);
+    # refs: bias (1, 1, block_kv) f32; q (1, block_q, d_qk);
     # k (1, block_kv, d_qk); v (1, block_kv, d_v); outs o (1, block_q, d_v),
     # lse (1, block_q, LANES) f32; scratch m/l (block_q, LANES) f32,
     # acc (block_q, d_v) f32
-    if has_bias:
-        bias_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        bias_ref = None
-        q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    bias_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     iq, ikv = pl.program_id(1), pl.program_id(2)
     block_q, d_v = acc_scr.shape
     block_kv = k_ref.shape[1]
-    # v2: fold the base-2 conversion into the score multiply the kernel
-    # already pays for sm_scale (see module notes on FAST_FEATURES)
-    score_scale = sm_scale * (LOG2E if "base2" in v2 else 1.0)
 
     @pl.when(ikv == 0)
     def _init():
@@ -319,9 +254,8 @@ def _fwd_kernel(
         q = q_ref[0]
         k = k_ref[0]
         s = _dot(q, k, ((1,), (1,)))  # (block_q, block_kv)
-        s = s * score_scale
-        if has_bias:
-            s = s + bias_ref[0]
+        s = s * sm_scale
+        s = s + bias_ref[0]
         if apply_mask:
             keep = _right_aligned_mask(block_q, block_kv, iq, ikv, block_q, block_kv, offset)
             s = jnp.where(keep, s, MASK_VALUE)
@@ -330,8 +264,8 @@ def _fwd_kernel(
         l_prev = l_scr[...]
         m_curr = jnp.max(s, axis=1)[:, None]  # (block_q, 1)
         m_next = jnp.maximum(m_prev, m_curr)  # (block_q, LANES)
-        p = _exp(s - m_next[:, :1], "base2" in v2)  # lane-broadcast subtract
-        alpha = _exp(m_prev - m_next, "base2" in v2)
+        p = jnp.exp(s - m_next[:, :1])  # lane-broadcast subtract
+        alpha = jnp.exp(m_prev - m_next)
         # flash-v2 style: keep the accumulator unnormalized; only rescale by
         # alpha when the running max moves. Normalization happens at store.
         l_scr[...] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
@@ -341,18 +275,18 @@ def _fwd_kernel(
         o_curr = _dot(p.astype(v.dtype), v, ((1,), (0,)))
         acc_scr[...] = acc_scr[...] * alpha[:, :1] + o_curr
 
-    _causal_dispatch(_body, causal, "fastmask" in v2, iq, ikv, block_q, block_kv, offset)
+    _causal_dispatch(_body, causal, iq, ikv, block_q, block_kv, offset)
 
     @pl.when(ikv == num_kv_blocks - 1)
     def _store():
         l = l_scr[...]
         l_inv = jnp.where(l == 0.0, 1.0, 1.0 / l)
         o_ref[0] = (acc_scr[...] * l_inv[:, :1]).astype(o_ref.dtype)
-        # lse = m + log(l) (base-2 under v2, matching the backward recompute).
+        # lse = m + log(l).
         # Rows with l == 0 only occur when every kv block was causally
         # invisible for the whole q block; the backward pass skips exactly
         # those blocks, so their lse is never read.
-        lse_ref[0] = m_scr[...] + _log(jnp.where(l == 0.0, 1.0, l), "base2" in v2)
+        lse_ref[0] = m_scr[...] + jnp.log(jnp.where(l == 0.0, 1.0, l))
 
 
 # ---------------------------------------------------------------------------
@@ -360,46 +294,37 @@ def _fwd_kernel(
 # ---------------------------------------------------------------------------
 
 
-def _recompute_p_keep(q, k, bias_row, lse_col, keep, sm_scale, base2):
-    """Recompute the probability tile p = exp(s_masked - lse) (base-2 under
-    v2 — the lse residual is in matching units) from a caller-built keep
-    mask (None = no mask; the two-segment kernels build segment-local
-    masks — tail / latent-causal — in their dispatcher)."""
+def _recompute_p_keep(q, k, bias_row, lse_col, keep, sm_scale):
+    """Recompute the probability tile p = exp(s_masked - lse) from a
+    caller-built keep mask (None = no mask)."""
     s = _dot(q, k, ((1,), (1,)))
-    s = s * (sm_scale * (LOG2E if base2 else 1.0))
-    if bias_row is not None:
-        s = s + bias_row
+    s = s * sm_scale
+    s = s + bias_row
     if keep is not None:
         s = jnp.where(keep, s, MASK_VALUE)
-    return _exp(s - lse_col, base2)
+    return jnp.exp(s - lse_col)
 
 
-def _recompute_p(q, k, bias_row, lse_col, iq, ikv, block_q, block_kv, offset, sm_scale, apply_mask, base2):
+def _recompute_p(q, k, bias_row, lse_col, iq, ikv, block_q, block_kv, offset, sm_scale, apply_mask):
     """`_recompute_p_keep` with the standard right-aligned causal keep mask."""
     keep = None
     if apply_mask:
         keep = _right_aligned_mask(q.shape[0], k.shape[0], iq, ikv, block_q, block_kv, offset)
-    return _recompute_p_keep(q, k, bias_row, lse_col, keep, sm_scale, base2)
+    return _recompute_p_keep(q, k, bias_row, lse_col, keep, sm_scale)
 
 
 def _dkv_kernel(
-    *refs,  # [bias?], q, k, v, do, lse, delta, dk, dv, dk_scr, dv_scr
+    *refs,  # bias, q, k, v, do, lse, delta, dk, dv, dk_scr, dv_scr
     causal: bool,
     offset: int,
     sm_scale: float,
     num_q_blocks: int,
-    has_bias: bool,
-    v2: frozenset,
 ):
-    # refs: bias (1, 1, block_kv) when has_bias; q (1, block_q, d_qk);
+    # refs: bias (1, 1, block_kv); q (1, block_q, d_qk);
     # k (1, block_kv, d_qk); v (1, block_kv, d_v); do (1, block_q, d_v);
     # lse/delta (1, block_q, LANES); outs dk (1, block_kv, d_qk),
     # dv (1, block_kv, d_v); scratch dk/dv f32
-    if has_bias:
-        bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
-    else:
-        bias_ref = None
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
+    bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
     ikv, iq = pl.program_id(1), pl.program_id(2)
     block_kv, _ = dk_scr.shape
     block_q = q_ref.shape[1]
@@ -417,8 +342,8 @@ def _dkv_kernel(
         lse = lse_ref[0][:, :1]  # (block_q, 1)
         delta = delta_ref[0][:, :1]
 
-        bias = bias_ref[0] if has_bias else None
-        p = _recompute_p(q, k, bias, lse, iq, ikv, block_q, block_kv, offset, sm_scale, apply_mask, "base2" in v2)
+        bias = bias_ref[0]
+        p = _recompute_p(q, k, bias, lse, iq, ikv, block_q, block_kv, offset, sm_scale, apply_mask)
         # dv += p^T do
         dv_scr[...] += _dot(p.astype(do.dtype), do, ((0,), (0,)))
         # dp = do v^T ; ds = p * (dp - delta) * sm_scale (the base-2 factors
@@ -428,7 +353,7 @@ def _dkv_kernel(
         # dk += ds^T q
         dk_scr[...] += _dot(ds.astype(q.dtype), q, ((0,), (0,)))
 
-    _causal_dispatch(_body, causal, "fastmask" in v2, iq, ikv, block_q, block_kv, offset)
+    _causal_dispatch(_body, causal, iq, ikv, block_q, block_kv, offset)
 
     @pl.when(iq == num_q_blocks - 1)
     def _store():
@@ -437,22 +362,16 @@ def _dkv_kernel(
 
 
 def _dq_kernel(
-    *refs,  # [bias?], q, k, v, do, lse, delta, dq, dq_scr
+    *refs,  # bias, q, k, v, do, lse, delta, dq, dq_scr
     causal: bool,
     offset: int,
     sm_scale: float,
     num_kv_blocks: int,
-    has_bias: bool,
-    v2: frozenset,
 ):
-    # refs: bias (1, 1, block_kv) when has_bias; q (1, block_q, d_qk);
+    # refs: bias (1, 1, block_kv); q (1, block_q, d_qk);
     # k (1, block_kv, d_qk); v (1, block_kv, d_v); do (1, block_q, d_v);
     # lse/delta (1, block_q, LANES); out dq (1, block_q, d_qk); scratch f32
-    if has_bias:
-        bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs
-    else:
-        bias_ref = None
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs
+    bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs
     iq, ikv = pl.program_id(1), pl.program_id(2)
     block_q, _ = dq_scr.shape
     block_kv = k_ref.shape[1]
@@ -469,13 +388,13 @@ def _dq_kernel(
         lse = lse_ref[0][:, :1]
         delta = delta_ref[0][:, :1]
 
-        bias = bias_ref[0] if has_bias else None
-        p = _recompute_p(q, k, bias, lse, iq, ikv, block_q, block_kv, offset, sm_scale, apply_mask, "base2" in v2)
+        bias = bias_ref[0]
+        p = _recompute_p(q, k, bias, lse, iq, ikv, block_q, block_kv, offset, sm_scale, apply_mask)
         dp = _dot(do, v, ((1,), (1,)))
         ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
         dq_scr[...] += _dot(ds, k, ((1,), (0,)))
 
-    _causal_dispatch(_body, causal, "fastmask" in v2, iq, ikv, block_q, block_kv, offset)
+    _causal_dispatch(_body, causal, iq, ikv, block_q, block_kv, offset)
 
     @pl.when(ikv == num_kv_blocks - 1)
     def _store():
@@ -483,13 +402,11 @@ def _dq_kernel(
 
 
 def _bwd_kernel(
-    *refs,  # [bias?], q, k, v, do, lse, delta, dq, dk, dv, dq_scr
+    *refs,  # bias, q, k, v, do, lse, delta, dq, dk, dv, dq_scr
     causal: bool,
     offset: int,
     sm_scale: float,
     num_kv_blocks: int,
-    has_bias: bool,
-    v2: frozenset,
 ):
     # The call's queries are ONE block (grid (bh, kv blocks)): q, do, lse and
     # delta stay resident over a row's kv blocks, and each score tile is
@@ -497,11 +414,7 @@ def _bwd_kernel(
     # dk/dv of a kv block are whole after its one tile (one q block sees into
     # every kv block: padding is less than a block), so only dq needs a
     # scratch (f32, over the kv blocks).
-    if has_bias:
-        bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr = refs
-    else:
-        bias_ref = None
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr = refs
+    bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr = refs
     ikv = pl.program_id(1)
     block_q = q_ref.shape[1]
     block_kv = k_ref.shape[1]
@@ -518,15 +431,15 @@ def _bwd_kernel(
         lse = lse_ref[0][:, :1]
         delta = delta_ref[0][:, :1]
 
-        bias = bias_ref[0] if has_bias else None
-        p = _recompute_p(q, k, bias, lse, 0, ikv, block_q, block_kv, offset, sm_scale, apply_mask, "base2" in v2)
+        bias = bias_ref[0]
+        p = _recompute_p(q, k, bias, lse, 0, ikv, block_q, block_kv, offset, sm_scale, apply_mask)
         dv_ref[0] = _dot(p.astype(do.dtype), do, ((0,), (0,))).astype(dv_ref.dtype)
         dp = _dot(do, v, ((1,), (1,)))
         ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
         dk_ref[0] = _dot(ds, q, ((0,), (0,))).astype(dk_ref.dtype)
         dq_scr[...] += _dot(ds, k, ((1,), (0,)))
 
-    _causal_dispatch(_body, causal, "fastmask" in v2, 0, ikv, block_q, block_kv, offset)
+    _causal_dispatch(_body, causal, 0, ikv, block_q, block_kv, offset)
 
     @pl.when(ikv == num_kv_blocks - 1)
     def _store():
@@ -567,32 +480,25 @@ def _kernel_name(pass_: str, geom: str) -> str:
     return f"flash_{pass_}_{geom}"
 
 
-@functools.partial(
-    jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11)
-)
-def _flash(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom):
-    out, _ = _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, geom):
+    out, _ = _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, geom)
     return out
 
 
-def _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom):
+def _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, geom):
     bh, nq, d_qk = q.shape
     nkv = k.shape[1]
     d_v = v.shape[2]
     h = num_heads
     grid = (bh, nq // block_q, nkv // block_kv)
 
-    in_specs = []
-    inputs = []
-    if bias is not None:
-        in_specs.append(pl.BlockSpec((1, 1, block_kv), lambda b, i, j: (b // h, 0, j)))
-        inputs.append(bias)
-    in_specs += [
+    in_specs = [
+        pl.BlockSpec((1, 1, block_kv), lambda b, i, j: (b // h, 0, j)),
         pl.BlockSpec((1, block_q, d_qk), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_kv, d_qk), lambda b, i, j: (b, j, 0)),
         pl.BlockSpec((1, block_kv, d_v), lambda b, i, j: (b, j, 0)),
     ]
-    inputs += [q, k, v]
 
     out, lse = pl.pallas_call(
         functools.partial(
@@ -601,8 +507,6 @@ def _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, 
             offset=offset,
             sm_scale=sm_scale,
             num_kv_blocks=grid[2],
-            has_bias=bias is not None,
-            v2=v2,
         ),
         name=_kernel_name("fwd", geom),
         grid=grid,
@@ -622,12 +526,12 @@ def _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, 
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=_interpret_default(),
-    )(*inputs)
+    )(bias, q, k, v)
     return out, lse
 
 
-def _flash_fwd(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom):
-    out, lse = _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom)
+def _flash_fwd(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, geom):
+    out, lse = _flash_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, num_heads, geom)
     # the kernel emits lse broadcast across all 128 lanes (tiled loads);
     # keep ONE lane as the residual — at 48 attention calls per step the
     # full-lane buffers alone were ~3GB at batch 32 (measured, image
@@ -663,27 +567,26 @@ def _backward(num_q_blocks: int) -> str:
     return "one" if num_q_blocks == 1 else "split"
 
 
-def _flash_bwd(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom, residuals, g):
+def _flash_bwd(causal, offset, sm_scale, block_q, block_kv, num_heads, geom, residuals, g):
     block_q, block_kv = _bwd_blocks(block_q, block_kv)
     one = _backward(residuals[0].shape[1] // block_q) == "one"
     return (_flash_bwd_one if one else _flash_bwd_split)(
-        causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom, residuals, g
+        causal, offset, sm_scale, block_q, block_kv, num_heads, geom, residuals, g
     )
 
 
 def _bwd_operands(residuals, g):
-    """``(inputs, has_bias)`` of the heads-major backward kernels: [bias],
-    q, k, v, do, lse, delta, the last two broadcast over lanes for tiled loads."""
+    """The operands of the heads-major backward kernels: bias, q, k, v, do,
+    lse, delta, the last two broadcast over lanes for tiled loads."""
     q, k, v, bias, out, lse_col = residuals
     lse = jnp.broadcast_to(lse_col, lse_col.shape[:2] + (LANES,))
     # delta_i = sum_c dO_ic * O_ic
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
     delta = jnp.broadcast_to(delta[..., None], delta.shape + (LANES,))
-    has_bias = bias is not None
-    return ([bias] if has_bias else []) + [q, k, v, g, lse, delta], has_bias
+    return [bias, q, k, v, g, lse, delta]
 
 
-def _flash_bwd_one(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom, residuals, g):
+def _flash_bwd_one(causal, offset, sm_scale, block_q, block_kv, num_heads, geom, residuals, g):
     """The backward of a call whose queries are one block: one kernel."""
     q, k, v = residuals[:3]
     bh, nq, d_qk = q.shape
@@ -692,12 +595,12 @@ def _flash_bwd_one(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, g
     h = num_heads
     assert nq == block_q, (nq, block_q)
     nkvb = nkv // block_kv
-    inputs, has_bias = _bwd_operands(residuals, g)
+    inputs = _bwd_operands(residuals, g)
 
     row = lambda b, j: (b, 0, 0)  # the one q block of a batch row
     kv = lambda b, j: (b, j, 0)
-    in_specs = [pl.BlockSpec((1, 1, block_kv), lambda b, j: (b // h, 0, j))] if has_bias else []
-    in_specs += [
+    in_specs = [
+        pl.BlockSpec((1, 1, block_kv), lambda b, j: (b // h, 0, j)),
         pl.BlockSpec((1, block_q, d_qk), row),
         pl.BlockSpec((1, block_kv, d_qk), kv),
         pl.BlockSpec((1, block_kv, d_v), kv),
@@ -712,8 +615,6 @@ def _flash_bwd_one(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, g
             offset=offset,
             sm_scale=sm_scale,
             num_kv_blocks=nkvb,
-            has_bias=has_bias,
-            v2=v2,
         ),
         name=_kernel_name("bwd", geom),
         grid=(bh, nkvb),
@@ -732,10 +633,10 @@ def _flash_bwd_one(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, g
         compiler_params=_compiler_params("parallel", "arbitrary"),
         interpret=_interpret_default(),
     )(*inputs)
-    return dq, dk, dv, jnp.zeros_like(residuals[3]) if has_bias else None
+    return dq, dk, dv, jnp.zeros_like(residuals[3])
 
 
-def _flash_bwd_split(causal, offset, sm_scale, block_q, block_kv, num_heads, v2, geom, residuals, g):
+def _flash_bwd_split(causal, offset, sm_scale, block_q, block_kv, num_heads, geom, residuals, g):
     """The backward of a call with several query blocks: dkv, then dq."""
     q, k, v, bias = residuals[:4]
     bh, nq, d_qk = q.shape
@@ -743,26 +644,17 @@ def _flash_bwd_split(causal, offset, sm_scale, block_q, block_kv, num_heads, v2,
     d_v = v.shape[2]
     h = num_heads
     nqb, nkvb = nq // block_q, nkv // block_kv
-    inputs, has_bias = _bwd_operands(residuals, g)
+    inputs = _bwd_operands(residuals, g)
 
-    def specs(order):
-        # order maps kernel grid dims -> (block index fns); shared between
-        # the dkv grid (b, j, i) and the dq grid (b, i, j)
-        bias_spec, qi, kj, vj, doi, li = order
-        s = []
-        if has_bias:
-            s.append(bias_spec)
-        s += [qi, kj, vj, doi, li, li]
-        return s
-
-    dkv_in_specs = specs((
+    dkv_in_specs = [
         pl.BlockSpec((1, 1, block_kv), lambda b, j, i: (b // h, 0, j)),
         pl.BlockSpec((1, block_q, d_qk), lambda b, j, i: (b, i, 0)),
         pl.BlockSpec((1, block_kv, d_qk), lambda b, j, i: (b, j, 0)),
         pl.BlockSpec((1, block_kv, d_v), lambda b, j, i: (b, j, 0)),
         pl.BlockSpec((1, block_q, d_v), lambda b, j, i: (b, i, 0)),
         pl.BlockSpec((1, block_q, LANES), lambda b, j, i: (b, i, 0)),
-    ))
+        pl.BlockSpec((1, block_q, LANES), lambda b, j, i: (b, i, 0)),
+    ]
 
     dk, dv = pl.pallas_call(
         functools.partial(
@@ -771,8 +663,6 @@ def _flash_bwd_split(causal, offset, sm_scale, block_q, block_kv, num_heads, v2,
             offset=offset,
             sm_scale=sm_scale,
             num_q_blocks=nqb,
-            has_bias=has_bias,
-            v2=v2,
         ),
         name=_kernel_name("dkv", geom),
         grid=(bh, nkvb, nqb),
@@ -793,14 +683,15 @@ def _flash_bwd_split(causal, offset, sm_scale, block_q, block_kv, num_heads, v2,
         interpret=_interpret_default(),
     )(*inputs)
 
-    dq_in_specs = specs((
+    dq_in_specs = [
         pl.BlockSpec((1, 1, block_kv), lambda b, i, j: (b // h, 0, j)),
         pl.BlockSpec((1, block_q, d_qk), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_kv, d_qk), lambda b, i, j: (b, j, 0)),
         pl.BlockSpec((1, block_kv, d_v), lambda b, i, j: (b, j, 0)),
         pl.BlockSpec((1, block_q, d_v), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, block_q, LANES), lambda b, i, j: (b, i, 0)),
-    ))
+        pl.BlockSpec((1, block_q, LANES), lambda b, i, j: (b, i, 0)),
+    ]
 
     (dq,) = pl.pallas_call(
         functools.partial(
@@ -809,8 +700,6 @@ def _flash_bwd_split(causal, offset, sm_scale, block_q, block_kv, num_heads, v2,
             offset=offset,
             sm_scale=sm_scale,
             num_kv_blocks=nkvb,
-            has_bias=has_bias,
-            v2=v2,
         ),
         name=_kernel_name("dq", geom),
         grid=(bh, nqb, nkvb),
@@ -824,7 +713,7 @@ def _flash_bwd_split(causal, offset, sm_scale, block_q, block_kv, num_heads, v2,
         interpret=_interpret_default(),
     )(*inputs)
 
-    return dq, dk, dv, jnp.zeros_like(bias) if has_bias else None
+    return dq, dk, dv, jnp.zeros_like(bias)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -984,7 +873,7 @@ def tile_plans() -> list:
 
 
 def _fwd_packed_kernel(
-    *refs,  # [bias?], q, k, v, o, lse, m_scr, l_scr, acc_scr
+    *refs,  # bias, q, k, v, o, lse, m_scr, l_scr, acc_scr
     causal: bool,
     offset: int,
     sm_scale: float,
@@ -992,25 +881,18 @@ def _fwd_packed_kernel(
     num_heads: int,
     d_qk: int,
     d_v: int,
-    has_bias: bool,
-    v2: frozenset,
     diagonals: tuple = (),
     whole: bool = True,
 ):
-    # refs: bias (1, 1, block_kv) f32 when has_bias; q (1, block_q, h*d_qk);
+    # refs: bias (1, 1, block_kv) f32; q (1, block_q, h*d_qk);
     # k (1, block_kv, h*d_qk); v (1, block_kv, h*d_v); outs
     # o (1, block_q, h*d_v), lse (1, block_q, h*RES_LANES) f32; scratch
-    # m/l (h, block_q, RES_LANES if v2 else LANES) f32, acc (h, block_q, d_v)
-    if has_bias:
-        bias_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-    else:
-        bias_ref = None
-        q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    # m/l (h, block_q, LANES) f32, acc (h, block_q, d_v)
+    bias_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     iq, ikv = pl.program_id(1), pl.program_id(2)
     h = num_heads
     block_q = q_ref.shape[1]
     block_kv = k_ref.shape[1]
-    score_scale = sm_scale * (LOG2E if "base2" in v2 else 1.0)
 
     @pl.when(ikv == 0)
     def _init():
@@ -1022,30 +904,29 @@ def _fwd_packed_kernel(
         # rows [r0, r1) of the q block against the kv block's first ``width``
         # slots. Per-head minor-dim slices: Mosaic supports static lane
         # slices but not the (block, h*d) -> (block, h, d) vector reshape
-        bias = bias_ref[0, :, :width] if has_bias else None
+        bias = bias_ref[0, :, :width]
         for hh in range(h):
             qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
             kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
             vh = v_ref[0, :width, hh * d_v : (hh + 1) * d_v]
             s = _dot(qh, kh, ((1,), (1,)))
-            s = s * score_scale
-            if has_bias:
-                s = s + bias
+            s = s * sm_scale
+            s = s + bias
             if keep is not None:
                 s = jnp.where(keep, s, MASK_VALUE)
             m_prev = m_scr[hh, r0:r1]
             l_prev = l_scr[hh, r0:r1]
             m_curr = jnp.max(s, axis=1)[:, None]
             m_next = jnp.maximum(m_prev, m_curr)
-            p = _exp(s - m_next[:, :1], "base2" in v2)
-            alpha = _exp(m_prev - m_next, "base2" in v2)
+            p = jnp.exp(s - m_next[:, :1])
+            alpha = jnp.exp(m_prev - m_next)
             l_scr[hh, r0:r1] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
             m_scr[hh, r0:r1] = m_next
             o_curr = _dot(p.astype(vh.dtype), vh, ((1,), (0,)))
             acc_scr[hh, r0:r1] = acc_scr[hh, r0:r1] * alpha[:, :1] + o_curr
 
     _body = _tile_body(_band, iq, ikv, block_q, block_kv, offset)
-    _causal_dispatch(_body, causal, "fastmask" in v2, iq, ikv, block_q, block_kv, offset, diagonals, whole)
+    _causal_dispatch(_body, causal, iq, ikv, block_q, block_kv, offset, diagonals, whole)
 
     @pl.when(ikv == num_kv_blocks - 1)
     def _store():
@@ -1055,14 +936,12 @@ def _fwd_packed_kernel(
             o_ref[0, :, hh * d_v : (hh + 1) * d_v] = (
                 acc_scr[hh] * l_inv[:, :1]
             ).astype(o_ref.dtype)
-            lse = m_scr[hh] + _log(jnp.where(l == 0.0, 1.0, l), "base2" in v2)
-            if lse.shape[1] != RES_LANES:
-                lse = lse[:, :RES_LANES]
-            lse_ref[0, :, hh * RES_LANES : (hh + 1) * RES_LANES] = lse
+            lse = m_scr[hh] + jnp.log(jnp.where(l == 0.0, 1.0, l))
+            lse_ref[0, :, hh * RES_LANES : (hh + 1) * RES_LANES] = lse[:, :RES_LANES]
 
 
 def _dkv_packed_kernel(
-    *refs,  # [bias?], q, k, v, do, lse, delta, dk, dv, dk_scr, dv_scr
+    *refs,  # bias, q, k, v, do, lse, delta, dk, dv, dk_scr, dv_scr
     causal: bool,
     offset: int,
     sm_scale: float,
@@ -1070,20 +949,14 @@ def _dkv_packed_kernel(
     num_heads: int,
     d_qk: int,
     d_v: int,
-    has_bias: bool,
-    v2: frozenset,
     diagonals: tuple = (),
     whole: bool = True,
 ):
-    # refs: bias (1, 1, block_kv) when has_bias; q (1, block_q, h*d_qk);
+    # refs: bias (1, 1, block_kv); q (1, block_q, h*d_qk);
     # k (1, block_kv, h*d_qk); v (1, block_kv, h*d_v); do (1, block_q, h*d_v);
     # lse/delta (1, block_q, h*RES_LANES); outs dk (1, block_kv, h*d_qk),
     # dv (1, block_kv, h*d_v); scratch dk/dv (h, block, d) f32
-    if has_bias:
-        bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
-    else:
-        bias_ref = None
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
+    bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
     ikv, iq = pl.program_id(1), pl.program_id(2)
     h = num_heads
     block_kv = k_ref.shape[1]
@@ -1096,7 +969,7 @@ def _dkv_packed_kernel(
 
     def _band(r0, r1, width, keep):
         # rows [r0, r1) of the q block against the kv block's first ``width`` slots
-        bias = bias_ref[0, :, :width] if has_bias else None
+        bias = bias_ref[0, :, :width]
         for hh in range(h):
             qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
             kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
@@ -1104,14 +977,14 @@ def _dkv_packed_kernel(
             doh = do_ref[0, r0:r1, hh * d_v : (hh + 1) * d_v]
             lse = lse_ref[0, r0:r1, hh * RES_LANES : hh * RES_LANES + 1]
             delta = delta_ref[0, r0:r1, hh * RES_LANES : hh * RES_LANES + 1]
-            p = _recompute_p_keep(qh, kh, bias, lse, keep, sm_scale, "base2" in v2)
+            p = _recompute_p_keep(qh, kh, bias, lse, keep, sm_scale)
             dv_scr[hh, :width] += _dot(p.astype(doh.dtype), doh, ((0,), (0,)))
             dp = _dot(doh, vh, ((1,), (1,)))
             ds = p * (dp - delta) * sm_scale
             dk_scr[hh, :width] += _dot(ds.astype(qh.dtype), qh, ((0,), (0,)))
 
     _body = _tile_body(_band, iq, ikv, block_q, block_kv, offset)
-    _causal_dispatch(_body, causal, "fastmask" in v2, iq, ikv, block_q, block_kv, offset, diagonals, whole)
+    _causal_dispatch(_body, causal, iq, ikv, block_q, block_kv, offset, diagonals, whole)
 
     @pl.when(iq == num_q_blocks - 1)
     def _store():
@@ -1121,7 +994,7 @@ def _dkv_packed_kernel(
 
 
 def _dq_packed_kernel(
-    *refs,  # [bias?], q, k, v, do, lse, delta, dq, dq_scr
+    *refs,  # bias, q, k, v, do, lse, delta, dq, dq_scr
     causal: bool,
     offset: int,
     sm_scale: float,
@@ -1129,20 +1002,14 @@ def _dq_packed_kernel(
     num_heads: int,
     d_qk: int,
     d_v: int,
-    has_bias: bool,
-    v2: frozenset,
     diagonals: tuple = (),
     whole: bool = True,
 ):
-    # refs: bias (1, 1, block_kv) when has_bias; q (1, block_q, h*d_qk);
+    # refs: bias (1, 1, block_kv); q (1, block_q, h*d_qk);
     # k (1, block_kv, h*d_qk); v (1, block_kv, h*d_v); do (1, block_q, h*d_v);
     # lse/delta (1, block_q, h*RES_LANES); out dq (1, block_q, h*d_qk);
     # scratch dq (h, block_q, d_qk) f32
-    if has_bias:
-        bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs
-    else:
-        bias_ref = None
-        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs
+    bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs
     iq, ikv = pl.program_id(1), pl.program_id(2)
     h = num_heads
     block_q = q_ref.shape[1]
@@ -1154,7 +1021,7 @@ def _dq_packed_kernel(
 
     def _band(r0, r1, width, keep):
         # rows [r0, r1) of the q block against the kv block's first ``width`` slots
-        bias = bias_ref[0, :, :width] if has_bias else None
+        bias = bias_ref[0, :, :width]
         for hh in range(h):
             qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
             kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
@@ -1162,13 +1029,13 @@ def _dq_packed_kernel(
             doh = do_ref[0, r0:r1, hh * d_v : (hh + 1) * d_v]
             lse = lse_ref[0, r0:r1, hh * RES_LANES : hh * RES_LANES + 1]
             delta = delta_ref[0, r0:r1, hh * RES_LANES : hh * RES_LANES + 1]
-            p = _recompute_p_keep(qh, kh, bias, lse, keep, sm_scale, "base2" in v2)
+            p = _recompute_p_keep(qh, kh, bias, lse, keep, sm_scale)
             dp = _dot(doh, vh, ((1,), (1,)))
             ds = (p * (dp - delta) * sm_scale).astype(kh.dtype)
             dq_scr[hh, r0:r1] += _dot(ds, kh, ((1,), (0,)))
 
     _body = _tile_body(_band, iq, ikv, block_q, block_kv, offset)
-    _causal_dispatch(_body, causal, "fastmask" in v2, iq, ikv, block_q, block_kv, offset, diagonals, whole)
+    _causal_dispatch(_body, causal, iq, ikv, block_q, block_kv, offset, diagonals, whole)
 
     @pl.when(ikv == num_kv_blocks - 1)
     def _store():
@@ -1177,7 +1044,7 @@ def _dq_packed_kernel(
 
 
 def _bwd_packed_kernel(
-    *refs,  # [bias?], q, k, v, do, lse, delta, dq, dk, dv, dq_scr, [dk_scr, dv_scr]
+    *refs,  # bias, q, k, v, do, lse, delta, dq, dk, dv, dq_scr, [dk_scr, dv_scr]
     causal: bool,
     offset: int,
     sm_scale: float,
@@ -1185,8 +1052,6 @@ def _bwd_packed_kernel(
     num_heads: int,
     d_qk: int,
     d_v: int,
-    has_bias: bool,
-    v2: frozenset,
     diagonals: tuple = (),
     whole: bool = True,
 ):
@@ -1199,11 +1064,7 @@ def _bwd_packed_kernel(
     # the call cuts no tile there is no such scratch and they are written
     # straight from the tile's one band (the 1024 x 8704 call ran 6.8% faster
     # without it: PERF.md 6, PR 29).
-    if has_bias:
-        bias_ref, *refs = refs
-    else:
-        bias_ref = None
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr, *kv_scr = refs
+    bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr, *kv_scr = refs
     ikv = pl.program_id(1)
     h = num_heads
     block_q = q_ref.shape[1]
@@ -1220,7 +1081,7 @@ def _bwd_packed_kernel(
 
     def _band(r0, r1, width, keep):
         # rows [r0, r1) of the q block against the kv block's first ``width`` slots
-        bias = bias_ref[0, :, :width] if has_bias else None
+        bias = bias_ref[0, :, :width]
         for hh in range(h):
             qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
             kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
@@ -1228,7 +1089,7 @@ def _bwd_packed_kernel(
             doh = do_ref[0, r0:r1, hh * d_v : (hh + 1) * d_v]
             lse = lse_ref[0, r0:r1, hh * RES_LANES : hh * RES_LANES + 1]
             delta = delta_ref[0, r0:r1, hh * RES_LANES : hh * RES_LANES + 1]
-            p = _recompute_p_keep(qh, kh, bias, lse, keep, sm_scale, "base2" in v2)
+            p = _recompute_p_keep(qh, kh, bias, lse, keep, sm_scale)
             dv = _dot(p.astype(doh.dtype), doh, ((0,), (0,)))
             dp = _dot(doh, vh, ((1,), (1,)))
             ds = (p * (dp - delta) * sm_scale).astype(qh.dtype)
@@ -1242,7 +1103,7 @@ def _bwd_packed_kernel(
                 dk_ref[0, :, hh * d_qk : (hh + 1) * d_qk] = dk.astype(dk_ref.dtype)
 
     _body = _tile_body(_band, 0, ikv, block_q, block_kv, offset)
-    _causal_dispatch(_body, causal, "fastmask" in v2, 0, ikv, block_q, block_kv, offset, diagonals, whole)
+    _causal_dispatch(_body, causal, 0, ikv, block_q, block_kv, offset, diagonals, whole)
 
     if kv_scr:
         for hh in range(h):
@@ -1255,31 +1116,25 @@ def _bwd_packed_kernel(
             dq_ref[0, :, hh * d_qk : (hh + 1) * d_qk] = dq_scr[hh].astype(dq_ref.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13))
-def _flash_packed(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
+def _flash_packed(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom):
     out, _ = _flash_packed_fwd_impl(
-        q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom
+        q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom
     )
     return out
 
 
-def _flash_packed_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom):
+def _flash_packed_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom):
     b, nq, _ = q.shape
     nkv = k.shape[1]
     grid = (b, nq // block_q, nkv // block_kv)
-    stat_lanes = RES_LANES if "slimstats" in v2 else LANES
 
-    in_specs = []
-    inputs = []
-    if bias is not None:
-        in_specs.append(pl.BlockSpec((1, 1, block_kv), lambda b_, i, j: (b_, 0, j)))
-        inputs.append(bias)
-    in_specs += [
+    in_specs = [
+        pl.BlockSpec((1, 1, block_kv), lambda b_, i, j: (b_, 0, j)),
         pl.BlockSpec((1, block_q, h * d_qk), lambda b_, i, j: (b_, i, 0)),
         pl.BlockSpec((1, block_kv, h * d_qk), lambda b_, i, j: (b_, j, 0)),
         pl.BlockSpec((1, block_kv, h * d_v), lambda b_, i, j: (b_, j, 0)),
     ]
-    inputs += [q, k, v]
 
     out, lse = pl.pallas_call(
         functools.partial(
@@ -1291,8 +1146,6 @@ def _flash_packed_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, blo
             num_heads=h,
             d_qk=d_qk,
             d_v=d_v,
-            has_bias=bias is not None,
-            v2=v2,
             **_diagonals(causal, offset, block_q, block_kv, grid[1], grid[2]),
         ),
         name=_kernel_name("fwd", geom),
@@ -1307,36 +1160,36 @@ def _flash_packed_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, blo
             jax.ShapeDtypeStruct((b, nq, h * RES_LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((h, block_q, stat_lanes), jnp.float32),
-            pltpu.VMEM((h, block_q, stat_lanes), jnp.float32),
+            pltpu.VMEM((h, block_q, LANES), jnp.float32),
+            pltpu.VMEM((h, block_q, LANES), jnp.float32),
             pltpu.VMEM((h, block_q, d_v), jnp.float32),
         ],
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=_interpret_default(),
-    )(*inputs)
+    )(bias, q, k, v)
     return out, lse
 
 
-def _flash_packed_fwd(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom):
+def _flash_packed_fwd(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom):
     out, lse = _flash_packed_fwd_impl(
-        q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom
+        q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom
     )
     # slim residual: one lane per head (see the heads-major path note)
     lse_slim = lse.reshape(lse.shape[0], lse.shape[1], h, RES_LANES)[..., :1]
     return out, (q, k, v, bias, out, lse_slim)
 
 
-def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom, residuals, g):
+def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom, residuals, g):
     block_q, block_kv = _bwd_blocks(block_q, block_kv)
     one = _backward(residuals[0].shape[1] // block_q) == "one"
     return (_flash_packed_bwd_one if one else _flash_packed_bwd_split)(
-        causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom, residuals, g
+        causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom, residuals, g
     )
 
 
 def _packed_bwd_operands(residuals, g, h, d_v):
-    """``(inputs, has_bias)`` of the packed backward kernels: [bias], q, k,
-    v, do, lse, delta, the last two as RES_LANES lanes per head."""
+    """The operands of the packed backward kernels: bias, q, k, v, do, lse,
+    delta, the last two as RES_LANES lanes per head."""
     q, k, v, bias, out, lse_slim = residuals
     b, nq, _ = q.shape
     lse = jnp.broadcast_to(lse_slim, (b, nq, h, RES_LANES)).reshape(b, nq, h * RES_LANES)
@@ -1345,18 +1198,17 @@ def _packed_bwd_operands(residuals, g, h, d_v):
     out4 = out.astype(jnp.float32).reshape(b, nq, h, d_v)
     delta = jnp.sum(g4 * out4, axis=-1)  # (b, nq, h)
     delta = jnp.broadcast_to(delta[..., None], (b, nq, h, RES_LANES)).reshape(b, nq, h * RES_LANES)
-    has_bias = bias is not None
-    return ([bias] if has_bias else []) + [q, k, v, g, lse, delta], has_bias
+    return [bias, q, k, v, g, lse, delta]
 
 
-def _flash_packed_bwd_one(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom, residuals, g):
+def _flash_packed_bwd_one(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom, residuals, g):
     """The backward of a call whose queries are one block: one kernel."""
     q, k, v = residuals[:3]
     b, nq, _ = q.shape
     nkv = k.shape[1]
     assert nq == block_q, (nq, block_q)
     nkvb = nkv // block_kv
-    inputs, has_bias = _packed_bwd_operands(residuals, g, h, d_v)
+    inputs = _packed_bwd_operands(residuals, g, h, d_v)
 
     cut = _diagonals(causal, offset, block_q, block_kv, 1, nkvb)
     # dk/dv of a kv block add up in f32 scratch over the bands of a cut tile;
@@ -1367,8 +1219,8 @@ def _flash_packed_bwd_one(causal, offset, sm_scale, block_q, block_kv, h, d_qk, 
         scratch += [pltpu.VMEM((h, block_kv, d_qk), jnp.float32), pltpu.VMEM((h, block_kv, d_v), jnp.float32)]
     row = lambda b_, j: (b_, 0, 0)  # the one q block of a batch row
     kv = lambda b_, j: (b_, j, 0)
-    in_specs = [pl.BlockSpec((1, 1, block_kv), lambda b_, j: (b_, 0, j))] if has_bias else []
-    in_specs += [
+    in_specs = [
+        pl.BlockSpec((1, 1, block_kv), lambda b_, j: (b_, 0, j)),
         pl.BlockSpec((1, block_q, h * d_qk), row),
         pl.BlockSpec((1, block_kv, h * d_qk), kv),
         pl.BlockSpec((1, block_kv, h * d_v), kv),
@@ -1386,8 +1238,6 @@ def _flash_packed_bwd_one(causal, offset, sm_scale, block_q, block_kv, h, d_qk, 
             num_heads=h,
             d_qk=d_qk,
             d_v=d_v,
-            has_bias=has_bias,
-            v2=v2,
             **cut,
         ),
         name=_kernel_name("bwd", geom),
@@ -1407,23 +1257,19 @@ def _flash_packed_bwd_one(causal, offset, sm_scale, block_q, block_kv, h, d_qk, 
         compiler_params=_compiler_params("parallel", "arbitrary"),
         interpret=_interpret_default(),
     )(*inputs)
-    return dq, dk, dv, jnp.zeros_like(residuals[3]) if has_bias else None
+    return dq, dk, dv, jnp.zeros_like(residuals[3])
 
 
-def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom, residuals, g):
+def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom, residuals, g):
     """The backward of a call with several query blocks: dkv, then dq."""
     q, k, v, bias = residuals[:4]
     b, nq, _ = q.shape
     nkv = k.shape[1]
     nqb, nkvb = nq // block_q, nkv // block_kv
-    inputs, has_bias = _packed_bwd_operands(residuals, g, h, d_v)
+    inputs = _packed_bwd_operands(residuals, g, h, d_v)
 
-    dkv_in_specs = []
-    dq_in_specs = []
-    if has_bias:
-        dkv_in_specs.append(pl.BlockSpec((1, 1, block_kv), lambda b_, j, i: (b_, 0, j)))
-        dq_in_specs.append(pl.BlockSpec((1, 1, block_kv), lambda b_, i, j: (b_, 0, j)))
-    dkv_in_specs += [
+    dkv_in_specs = [
+        pl.BlockSpec((1, 1, block_kv), lambda b_, j, i: (b_, 0, j)),
         pl.BlockSpec((1, block_q, h * d_qk), lambda b_, j, i: (b_, i, 0)),
         pl.BlockSpec((1, block_kv, h * d_qk), lambda b_, j, i: (b_, j, 0)),
         pl.BlockSpec((1, block_kv, h * d_v), lambda b_, j, i: (b_, j, 0)),
@@ -1431,7 +1277,8 @@ def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk
         pl.BlockSpec((1, block_q, h * RES_LANES), lambda b_, j, i: (b_, i, 0)),
         pl.BlockSpec((1, block_q, h * RES_LANES), lambda b_, j, i: (b_, i, 0)),
     ]
-    dq_in_specs += [
+    dq_in_specs = [
+        pl.BlockSpec((1, 1, block_kv), lambda b_, i, j: (b_, 0, j)),
         pl.BlockSpec((1, block_q, h * d_qk), lambda b_, i, j: (b_, i, 0)),
         pl.BlockSpec((1, block_kv, h * d_qk), lambda b_, i, j: (b_, j, 0)),
         pl.BlockSpec((1, block_kv, h * d_v), lambda b_, i, j: (b_, j, 0)),
@@ -1450,8 +1297,6 @@ def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk
             num_heads=h,
             d_qk=d_qk,
             d_v=d_v,
-            has_bias=has_bias,
-            v2=v2,
             **_diagonals(causal, offset, block_q, block_kv, nqb, nkvb),
         ),
         name=_kernel_name("dkv", geom),
@@ -1483,8 +1328,6 @@ def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk
             num_heads=h,
             d_qk=d_qk,
             d_v=d_v,
-            has_bias=has_bias,
-            v2=v2,
             **_diagonals(causal, offset, block_q, block_kv, nqb, nkvb),
         ),
         name=_kernel_name("dq", geom),
@@ -1499,7 +1342,7 @@ def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk
         interpret=_interpret_default(),
     )(*inputs)
 
-    return dq, dk, dv, jnp.zeros_like(bias) if has_bias else None
+    return dq, dk, dv, jnp.zeros_like(bias)
 
 
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
@@ -1509,7 +1352,7 @@ _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
 # same call 8 to 48 times, and without this every one traces its three
 # kernels anew (the banded bodies of a cut tile are four times the operations
 # to trace). XLA inlines the call; the program is the same.
-_flash_packed_cached = jax.jit(_flash_packed, static_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13))
+_flash_packed_cached = jax.jit(_flash_packed, static_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
 
 
 def packed_supported(num_heads: int, d_qk: int, d_v: int) -> bool:
@@ -1567,633 +1410,20 @@ def flash_attention_packed(
     kf = _pad_to(k, 1, block_kv)
     vf = _pad_to(v, 1, block_kv)
 
-    v2 = fast_features()
+    # additive kv bias per batch row: padded slots + user pad mask
     nkv_p = kf.shape[1]
-    if "nobias" in v2 and pad_mask is None and nkv_p == nkv:
-        # all-zero bias: drop the stream + per-tile add entirely (the
-        # flagship path — packed full windows, divisor blocks)
-        bias = None
-    else:
-        bias = jnp.zeros((b, nkv_p), jnp.float32)
-        if pad_mask is not None:
-            bias = bias.at[:, :nkv].set(jnp.where(pad_mask, MASK_VALUE, 0.0))
-        if nkv_p != nkv:
-            bias = bias.at[:, nkv:].set(MASK_VALUE)
-        bias = bias[:, None, :]
+    bias = jnp.zeros((b, nkv_p), jnp.float32)
+    if pad_mask is not None:
+        bias = bias.at[:, :nkv].set(jnp.where(pad_mask, MASK_VALUE, 0.0))
+    if nkv_p != nkv:
+        bias = bias.at[:, nkv:].set(MASK_VALUE)
+    bias = bias[:, None, :]
 
     out = _on_batch_shards(
         lambda q_, k_, v_, bias_: _flash_packed_cached(
-            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, v2, geom
+            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom
         ),
         qf, kf, vf, bias,
-    )
-    return out[:, :nq, :]
-
-
-# ---------------------------------------------------------------------------
-# two-segment packed path (Perceiver AR prefix cross-attention)
-# ---------------------------------------------------------------------------
-#
-# The Perceiver AR cross-attention attends the latent queries to the LOGICAL
-# kv sequence [kept-prefix; latents]. The concat route materializes that
-# sequence — ``x_kv = concat(kv_norm(prefix), q_norm(latents))`` — plus its
-# K/V projections (~0.86 ms of async copy per chunk at the 16k flagship,
-# profiled) before the kernels start. The kernels below take the two
-# segments as SEPARATE operands: a kv-block index either reads from the
-# prefix refs or the latent refs (clamped BlockSpec index maps — Pallas only
-# re-fetches when a block index CHANGES, so the off-segment refs cost one
-# stale fetch per grid row, not a doubled stream), and the seam is handled
-# by a static tail mask on the last prefix block (the prefix pads to its own
-# block multiple) plus the standard right-aligned causal machinery in
-# LATENT-LOCAL coordinates: with n_latent_kv == n_q, query i sees logical kv
-# j iff j <= i + prefix_len, i.e. the whole prefix plus latent slots t <= i
-# — causal offset 0 in local coords, independent of the prefix length. Each
-# segment picks its own divisor block size, so the flagship geometry
-# (prefix 7680 / latents 1024) runs with zero kv padding.
-#
-# Semantics contract (pinned by tests/test_flash_twoseg.py): identical to
-# ``flash_attention_packed(q, concat(k_p, k_l), concat(v_p, v_l),
-# causal=True)`` up to online-softmax block-partitioning rounding — the same
-# tolerance class as changing block sizes on the concat path.
-
-
-def _twoseg_dispatch(body, iq, ikv, *, block_q, block_kv_p, block_kv_l, prefix_len, npb, fastmask):
-    """Run ``body(segment, keep_mask_or_None)`` for kv block ``ikv``:
-    segment 0 (prefix, fully visible, static tail mask on the last block
-    when the prefix is not a block multiple) or segment 1 (latents, causal
-    at offset 0 in latent-local block coordinates). ``segment`` is a static
-    Python int — the kernel body specializes its refs on it."""
-    tail_cols = prefix_len - (npb - 1) * block_kv_p
-    if tail_cols != block_kv_p:
-
-        def prefix_tail():
-            keep = lax.broadcasted_iota(jnp.int32, (block_q, block_kv_p), 1) < tail_cols
-            body(0, keep)
-
-        pl.when(ikv == npb - 1)(prefix_tail)
-        if npb > 1:
-            pl.when(ikv < npb - 1)(lambda: body(0, None))
-    else:
-        pl.when(ikv < npb)(lambda: body(0, None))
-
-    def latent():
-        ikv_l = ikv - npb
-        _causal_dispatch(
-            lambda m: body(
-                1,
-                _right_aligned_mask(block_q, block_kv_l, iq, ikv_l, block_q, block_kv_l, 0)
-                if m
-                else None,
-            ),
-            True,
-            fastmask,
-            iq,
-            ikv_l,
-            block_q,
-            block_kv_l,
-            0,
-        )
-
-    pl.when(ikv >= npb)(latent)
-
-
-def _fwd_2seg_kernel(
-    *refs,  # [bias_p?, bias_l?], q, k_p, v_p, k_l, v_l, o, lse, m_scr, l_scr, acc_scr
-    prefix_len: int,
-    num_prefix_blocks: int,
-    block_kv_p: int,
-    block_kv_l: int,
-    sm_scale: float,
-    num_kv_blocks: int,
-    num_heads: int,
-    d_qk: int,
-    d_v: int,
-    has_bias: bool,
-    v2: frozenset,
-):
-    # refs: bias_p (1, 1, bkv_p) / bias_l (1, 1, bkv_l) f32 when has_bias;
-    # q (1, block_q, h*d_qk); k_p/v_p (1, bkv_p, h*d); k_l/v_l (1, bkv_l, h*d);
-    # outs o (1, block_q, h*d_v), lse (1, block_q, h*RES_LANES) f32; scratch
-    # m/l (h, block_q, stat_lanes) f32, acc (h, block_q, d_v) f32
-    if has_bias:
-        bias_p_ref, bias_l_ref, q_ref, k_p_ref, v_p_ref, k_l_ref, v_l_ref = refs[:7]
-        o_ref, lse_ref, m_scr, l_scr, acc_scr = refs[7:]
-    else:
-        bias_p_ref = bias_l_ref = None
-        q_ref, k_p_ref, v_p_ref, k_l_ref, v_l_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
-    iq, ikv = pl.program_id(1), pl.program_id(2)
-    h = num_heads
-    block_q = q_ref.shape[1]
-    score_scale = sm_scale * (LOG2E if "base2" in v2 else 1.0)
-
-    @pl.when(ikv == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    def _body(seg, keep):
-        if seg == 0:
-            k_ref, v_ref, bias_ref = k_p_ref, v_p_ref, bias_p_ref
-        else:
-            k_ref, v_ref, bias_ref = k_l_ref, v_l_ref, bias_l_ref
-        bias = bias_ref[0] if has_bias else None
-        for hh in range(h):
-            qh = q_ref[0, :, hh * d_qk : (hh + 1) * d_qk]
-            kh = k_ref[0, :, hh * d_qk : (hh + 1) * d_qk]
-            vh = v_ref[0, :, hh * d_v : (hh + 1) * d_v]
-            s = _dot(qh, kh, ((1,), (1,)))
-            s = s * score_scale
-            if has_bias:
-                s = s + bias
-            if keep is not None:
-                s = jnp.where(keep, s, MASK_VALUE)
-            m_prev = m_scr[hh]
-            l_prev = l_scr[hh]
-            m_curr = jnp.max(s, axis=1)[:, None]
-            m_next = jnp.maximum(m_prev, m_curr)
-            p = _exp(s - m_next[:, :1], "base2" in v2)
-            alpha = _exp(m_prev - m_next, "base2" in v2)
-            l_scr[hh] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
-            m_scr[hh] = m_next
-            o_curr = _dot(p.astype(vh.dtype), vh, ((1,), (0,)))
-            acc_scr[hh] = acc_scr[hh] * alpha[:, :1] + o_curr
-
-    _twoseg_dispatch(
-        _body, iq, ikv,
-        block_q=block_q, block_kv_p=block_kv_p, block_kv_l=block_kv_l,
-        prefix_len=prefix_len, npb=num_prefix_blocks, fastmask="fastmask" in v2,
-    )
-
-    @pl.when(ikv == num_kv_blocks - 1)
-    def _store():
-        for hh in range(h):
-            l = l_scr[hh]
-            l_inv = jnp.where(l == 0.0, 1.0, 1.0 / l)
-            o_ref[0, :, hh * d_v : (hh + 1) * d_v] = (
-                acc_scr[hh] * l_inv[:, :1]
-            ).astype(o_ref.dtype)
-            lse = m_scr[hh] + _log(jnp.where(l == 0.0, 1.0, l), "base2" in v2)
-            if lse.shape[1] != RES_LANES:
-                lse = lse[:, :RES_LANES]
-            lse_ref[0, :, hh * RES_LANES : (hh + 1) * RES_LANES] = lse
-
-
-def _dkv_2seg_kernel(
-    *refs,  # [bias_p?, bias_l?], q, k_p, v_p, k_l, v_l, do, lse, delta,
-    #         dk_p, dv_p, dk_l, dv_l, dk_scr, dv_scr
-    prefix_len: int,
-    num_prefix_blocks: int,
-    block_kv_p: int,
-    block_kv_l: int,
-    sm_scale: float,
-    num_q_blocks: int,
-    num_heads: int,
-    d_qk: int,
-    d_v: int,
-    has_bias: bool,
-    v2: frozenset,
-):
-    # scratch dk/dv are (h, max(bkv_p, bkv_l), d) f32; each segment reads and
-    # writes its own leading rows (static slices)
-    if has_bias:
-        bias_p_ref, bias_l_ref = refs[:2]
-        refs = refs[2:]
-    else:
-        bias_p_ref = bias_l_ref = None
-    (q_ref, k_p_ref, v_p_ref, k_l_ref, v_l_ref, do_ref, lse_ref, delta_ref,
-     dk_p_ref, dv_p_ref, dk_l_ref, dv_l_ref, dk_scr, dv_scr) = refs
-    ikv, iq = pl.program_id(1), pl.program_id(2)
-    h = num_heads
-    block_q = q_ref.shape[1]
-
-    @pl.when(iq == 0)
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    def _body(seg, keep):
-        if seg == 0:
-            k_ref, v_ref, bias_ref, bkv = k_p_ref, v_p_ref, bias_p_ref, block_kv_p
-        else:
-            k_ref, v_ref, bias_ref, bkv = k_l_ref, v_l_ref, bias_l_ref, block_kv_l
-        bias = bias_ref[0] if has_bias else None
-        for hh in range(h):
-            qh = q_ref[0, :, hh * d_qk : (hh + 1) * d_qk]
-            kh = k_ref[0, :, hh * d_qk : (hh + 1) * d_qk]
-            vh = v_ref[0, :, hh * d_v : (hh + 1) * d_v]
-            doh = do_ref[0, :, hh * d_v : (hh + 1) * d_v]
-            lse = lse_ref[0, :, hh * RES_LANES : hh * RES_LANES + 1]
-            delta = delta_ref[0, :, hh * RES_LANES : hh * RES_LANES + 1]
-            p = _recompute_p_keep(qh, kh, bias, lse, keep, sm_scale, "base2" in v2)
-            dv_scr[hh, :bkv] += _dot(p.astype(doh.dtype), doh, ((0,), (0,)))
-            dp = _dot(doh, vh, ((1,), (1,)))
-            ds = p * (dp - delta) * sm_scale
-            dk_scr[hh, :bkv] += _dot(ds.astype(qh.dtype), qh, ((0,), (0,)))
-
-    _twoseg_dispatch(
-        _body, iq, ikv,
-        block_q=block_q, block_kv_p=block_kv_p, block_kv_l=block_kv_l,
-        prefix_len=prefix_len, npb=num_prefix_blocks, fastmask="fastmask" in v2,
-    )
-
-    @pl.when(iq == num_q_blocks - 1)
-    def _store():
-        def store_prefix():
-            for hh in range(h):
-                dk_p_ref[0, :, hh * d_qk : (hh + 1) * d_qk] = dk_scr[hh, :block_kv_p].astype(dk_p_ref.dtype)
-                dv_p_ref[0, :, hh * d_v : (hh + 1) * d_v] = dv_scr[hh, :block_kv_p].astype(dv_p_ref.dtype)
-
-        def store_latent():
-            for hh in range(h):
-                dk_l_ref[0, :, hh * d_qk : (hh + 1) * d_qk] = dk_scr[hh, :block_kv_l].astype(dk_l_ref.dtype)
-                dv_l_ref[0, :, hh * d_v : (hh + 1) * d_v] = dv_scr[hh, :block_kv_l].astype(dv_l_ref.dtype)
-
-        pl.when(ikv < num_prefix_blocks)(store_prefix)
-        pl.when(ikv >= num_prefix_blocks)(store_latent)
-
-
-def _dq_2seg_kernel(
-    *refs,  # [bias_p?, bias_l?], q, k_p, v_p, k_l, v_l, do, lse, delta, dq, dq_scr
-    prefix_len: int,
-    num_prefix_blocks: int,
-    block_kv_p: int,
-    block_kv_l: int,
-    sm_scale: float,
-    num_kv_blocks: int,
-    num_heads: int,
-    d_qk: int,
-    d_v: int,
-    has_bias: bool,
-    v2: frozenset,
-):
-    if has_bias:
-        bias_p_ref, bias_l_ref = refs[:2]
-        refs = refs[2:]
-    else:
-        bias_p_ref = bias_l_ref = None
-    (q_ref, k_p_ref, v_p_ref, k_l_ref, v_l_ref, do_ref, lse_ref, delta_ref,
-     dq_ref, dq_scr) = refs
-    iq, ikv = pl.program_id(1), pl.program_id(2)
-    h = num_heads
-    block_q = q_ref.shape[1]
-
-    @pl.when(ikv == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    def _body(seg, keep):
-        if seg == 0:
-            k_ref, v_ref, bias_ref = k_p_ref, v_p_ref, bias_p_ref
-        else:
-            k_ref, v_ref, bias_ref = k_l_ref, v_l_ref, bias_l_ref
-        bias = bias_ref[0] if has_bias else None
-        for hh in range(h):
-            qh = q_ref[0, :, hh * d_qk : (hh + 1) * d_qk]
-            kh = k_ref[0, :, hh * d_qk : (hh + 1) * d_qk]
-            vh = v_ref[0, :, hh * d_v : (hh + 1) * d_v]
-            doh = do_ref[0, :, hh * d_v : (hh + 1) * d_v]
-            lse = lse_ref[0, :, hh * RES_LANES : hh * RES_LANES + 1]
-            delta = delta_ref[0, :, hh * RES_LANES : hh * RES_LANES + 1]
-            p = _recompute_p_keep(qh, kh, bias, lse, keep, sm_scale, "base2" in v2)
-            dp = _dot(doh, vh, ((1,), (1,)))
-            ds = (p * (dp - delta) * sm_scale).astype(kh.dtype)
-            dq_scr[hh] += _dot(ds, kh, ((1,), (0,)))
-
-    _twoseg_dispatch(
-        _body, iq, ikv,
-        block_q=block_q, block_kv_p=block_kv_p, block_kv_l=block_kv_l,
-        prefix_len=prefix_len, npb=num_prefix_blocks, fastmask="fastmask" in v2,
-    )
-
-    @pl.when(ikv == num_kv_blocks - 1)
-    def _store():
-        for hh in range(h):
-            dq_ref[0, :, hh * d_qk : (hh + 1) * d_qk] = dq_scr[hh].astype(dq_ref.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12, 13, 14, 15, 16))
-def _flash_packed_2seg(
-    q, k_p, v_p, k_l, v_l, bias_p, bias_l,
-    prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, geom,
-):
-    out, _ = _flash_packed_2seg_fwd_impl(
-        q, k_p, v_p, k_l, v_l, bias_p, bias_l,
-        prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, geom,
-    )
-    return out
-
-
-def _2seg_kv_specs(order, npb, nlb, block_kv_p, block_kv_l, width_p, width_l):
-    """BlockSpecs for the prefix/latent kv operand pair. ``order`` picks the
-    grid-axis layout: "ij" for the fwd/dq grid (b, i, j) and "ji" for the dkv
-    grid (b, j, i), with j the combined kv-block axis. The index maps CLAMP
-    into each segment, so during the other segment's blocks the index is
-    constant and the pipeline fetches nothing new."""
-    if order == "ij":
-        p_map = lambda b_, i, j: (b_, jnp.minimum(j, npb - 1), 0)  # noqa: E731
-        l_map = lambda b_, i, j: (b_, jnp.clip(j - npb, 0, nlb - 1), 0)  # noqa: E731
-    else:
-        p_map = lambda b_, j, i: (b_, jnp.minimum(j, npb - 1), 0)  # noqa: E731
-        l_map = lambda b_, j, i: (b_, jnp.clip(j - npb, 0, nlb - 1), 0)  # noqa: E731
-    return (
-        pl.BlockSpec((1, block_kv_p, width_p), p_map),
-        pl.BlockSpec((1, block_kv_l, width_l), l_map),
-        p_map,
-        l_map,
-    )
-
-
-def _2seg_bias_specs(order, npb, nlb, block_kv_p, block_kv_l):
-    if order == "ij":
-        return (
-            pl.BlockSpec((1, 1, block_kv_p), lambda b_, i, j: (b_, 0, jnp.minimum(j, npb - 1))),
-            pl.BlockSpec((1, 1, block_kv_l), lambda b_, i, j: (b_, 0, jnp.clip(j - npb, 0, nlb - 1))),
-        )
-    return (
-        pl.BlockSpec((1, 1, block_kv_p), lambda b_, j, i: (b_, 0, jnp.minimum(j, npb - 1))),
-        pl.BlockSpec((1, 1, block_kv_l), lambda b_, j, i: (b_, 0, jnp.clip(j - npb, 0, nlb - 1))),
-    )
-
-
-def _flash_packed_2seg_fwd_impl(
-    q, k_p, v_p, k_l, v_l, bias_p, bias_l,
-    prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, geom,
-):
-    b, nq, _ = q.shape
-    npb = k_p.shape[1] // block_kv_p
-    nlb = k_l.shape[1] // block_kv_l
-    grid = (b, nq // block_q, npb + nlb)
-    stat_lanes = RES_LANES if "slimstats" in v2 else LANES
-    has_bias = bias_p is not None
-
-    kp_spec, kl_spec, _, _ = _2seg_kv_specs("ij", npb, nlb, block_kv_p, block_kv_l, h * d_qk, h * d_qk)
-    vp_spec, vl_spec, _, _ = _2seg_kv_specs("ij", npb, nlb, block_kv_p, block_kv_l, h * d_v, h * d_v)
-    in_specs = []
-    inputs = []
-    if has_bias:
-        in_specs += list(_2seg_bias_specs("ij", npb, nlb, block_kv_p, block_kv_l))
-        inputs += [bias_p, bias_l]
-    in_specs += [
-        pl.BlockSpec((1, block_q, h * d_qk), lambda b_, i, j: (b_, i, 0)),
-        kp_spec, vp_spec, kl_spec, vl_spec,
-    ]
-    inputs += [q, k_p, v_p, k_l, v_l]
-
-    out, lse = pl.pallas_call(
-        functools.partial(
-            _fwd_2seg_kernel,
-            prefix_len=prefix_len,
-            num_prefix_blocks=npb,
-            block_kv_p=block_kv_p,
-            block_kv_l=block_kv_l,
-            sm_scale=sm_scale,
-            num_kv_blocks=grid[2],
-            num_heads=h,
-            d_qk=d_qk,
-            d_v=d_v,
-            has_bias=has_bias,
-            v2=v2,
-        ),
-        name=_kernel_name("fwd", geom),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, h * d_v), lambda b_, i, j: (b_, i, 0)),
-            pl.BlockSpec((1, block_q, h * RES_LANES), lambda b_, i, j: (b_, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, nq, h * d_v), q.dtype),
-            jax.ShapeDtypeStruct((b, nq, h * RES_LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((h, block_q, stat_lanes), jnp.float32),
-            pltpu.VMEM((h, block_q, stat_lanes), jnp.float32),
-            pltpu.VMEM((h, block_q, d_v), jnp.float32),
-        ],
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
-        interpret=_interpret_default(),
-    )(*inputs)
-    return out, lse
-
-
-def _flash_packed_2seg_fwd(
-    q, k_p, v_p, k_l, v_l, bias_p, bias_l,
-    prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, geom,
-):
-    out, lse = _flash_packed_2seg_fwd_impl(
-        q, k_p, v_p, k_l, v_l, bias_p, bias_l,
-        prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, geom,
-    )
-    lse_slim = lse.reshape(lse.shape[0], lse.shape[1], h, RES_LANES)[..., :1]
-    return out, (q, k_p, v_p, k_l, v_l, bias_p, bias_l, out, lse_slim)
-
-
-def _flash_packed_2seg_bwd(
-    prefix_len, sm_scale, block_q, block_kv_p, block_kv_l, h, d_qk, d_v, v2, geom, residuals, g
-):
-    q, k_p, v_p, k_l, v_l, bias_p, bias_l, out, lse_slim = residuals
-    b, nq, _ = q.shape
-    if BWD_BLOCK_Q is not None:
-        block_q = min(block_q, BWD_BLOCK_Q)
-    if BWD_BLOCK_KV is not None:
-        block_kv_p = min(block_kv_p, BWD_BLOCK_KV)
-        block_kv_l = min(block_kv_l, BWD_BLOCK_KV)
-    npb = k_p.shape[1] // block_kv_p
-    nlb = k_l.shape[1] // block_kv_l
-    has_bias = bias_p is not None
-
-    lse = jnp.broadcast_to(lse_slim, (b, nq, h, RES_LANES)).reshape(b, nq, h * RES_LANES)
-    g4 = g.astype(jnp.float32).reshape(b, nq, h, d_v)
-    out4 = out.astype(jnp.float32).reshape(b, nq, h, d_v)
-    delta = jnp.sum(g4 * out4, axis=-1)
-    delta = jnp.broadcast_to(delta[..., None], (b, nq, h, RES_LANES)).reshape(b, nq, h * RES_LANES)
-
-    nqb = nq // block_q
-    inputs = ([bias_p, bias_l] if has_bias else []) + [q, k_p, v_p, k_l, v_l, g, lse, delta]
-
-    # dkv: grid (b, kv, q) — kv is marked "arbitrary" (not parallel like the
-    # single-segment kernels): the clamped output index maps revisit a block
-    # across the segment boundary, which requires sequential iteration order
-    kp_spec, kl_spec, p_map, l_map = _2seg_kv_specs(
-        "ji", npb, nlb, block_kv_p, block_kv_l, h * d_qk, h * d_qk
-    )
-    vp_spec, vl_spec, _, _ = _2seg_kv_specs("ji", npb, nlb, block_kv_p, block_kv_l, h * d_v, h * d_v)
-    dkv_in_specs = []
-    if has_bias:
-        dkv_in_specs += list(_2seg_bias_specs("ji", npb, nlb, block_kv_p, block_kv_l))
-    dkv_in_specs += [
-        pl.BlockSpec((1, block_q, h * d_qk), lambda b_, j, i: (b_, i, 0)),
-        kp_spec, vp_spec, kl_spec, vl_spec,
-        pl.BlockSpec((1, block_q, h * d_v), lambda b_, j, i: (b_, i, 0)),
-        pl.BlockSpec((1, block_q, h * RES_LANES), lambda b_, j, i: (b_, i, 0)),
-        pl.BlockSpec((1, block_q, h * RES_LANES), lambda b_, j, i: (b_, i, 0)),
-    ]
-    bkv_max = max(block_kv_p, block_kv_l)
-
-    dk_p, dv_p, dk_l, dv_l = pl.pallas_call(
-        functools.partial(
-            _dkv_2seg_kernel,
-            prefix_len=prefix_len,
-            num_prefix_blocks=npb,
-            block_kv_p=block_kv_p,
-            block_kv_l=block_kv_l,
-            sm_scale=sm_scale,
-            num_q_blocks=nqb,
-            num_heads=h,
-            d_qk=d_qk,
-            d_v=d_v,
-            has_bias=has_bias,
-            v2=v2,
-        ),
-        name=_kernel_name("dkv", geom),
-        grid=(b, npb + nlb, nqb),
-        in_specs=dkv_in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_kv_p, h * d_qk), p_map),
-            pl.BlockSpec((1, block_kv_p, h * d_v), p_map),
-            pl.BlockSpec((1, block_kv_l, h * d_qk), l_map),
-            pl.BlockSpec((1, block_kv_l, h * d_v), l_map),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k_p.shape, k_p.dtype),
-            jax.ShapeDtypeStruct(v_p.shape, v_p.dtype),
-            jax.ShapeDtypeStruct(k_l.shape, k_l.dtype),
-            jax.ShapeDtypeStruct(v_l.shape, v_l.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((h, bkv_max, d_qk), jnp.float32),
-            pltpu.VMEM((h, bkv_max, d_v), jnp.float32),
-        ],
-        compiler_params=_compiler_params("parallel", "arbitrary", "arbitrary"),
-        interpret=_interpret_default(),
-    )(*inputs)
-
-    kp_spec, kl_spec, _, _ = _2seg_kv_specs(
-        "ij", npb, nlb, block_kv_p, block_kv_l, h * d_qk, h * d_qk
-    )
-    vp_spec, vl_spec, _, _ = _2seg_kv_specs("ij", npb, nlb, block_kv_p, block_kv_l, h * d_v, h * d_v)
-    dq_in_specs = []
-    if has_bias:
-        dq_in_specs += list(_2seg_bias_specs("ij", npb, nlb, block_kv_p, block_kv_l))
-    dq_in_specs += [
-        pl.BlockSpec((1, block_q, h * d_qk), lambda b_, i, j: (b_, i, 0)),
-        kp_spec, vp_spec, kl_spec, vl_spec,
-        pl.BlockSpec((1, block_q, h * d_v), lambda b_, i, j: (b_, i, 0)),
-        pl.BlockSpec((1, block_q, h * RES_LANES), lambda b_, i, j: (b_, i, 0)),
-        pl.BlockSpec((1, block_q, h * RES_LANES), lambda b_, i, j: (b_, i, 0)),
-    ]
-
-    (dq,) = pl.pallas_call(
-        functools.partial(
-            _dq_2seg_kernel,
-            prefix_len=prefix_len,
-            num_prefix_blocks=npb,
-            block_kv_p=block_kv_p,
-            block_kv_l=block_kv_l,
-            sm_scale=sm_scale,
-            num_kv_blocks=npb + nlb,
-            num_heads=h,
-            d_qk=d_qk,
-            d_v=d_v,
-            has_bias=has_bias,
-            v2=v2,
-        ),
-        name=_kernel_name("dq", geom),
-        grid=(b, nqb, npb + nlb),
-        in_specs=dq_in_specs,
-        out_specs=[pl.BlockSpec((1, block_q, h * d_qk), lambda b_, i, j: (b_, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((b, nq, h * d_qk), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((h, block_q, d_qk), jnp.float32)],
-        compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
-        interpret=_interpret_default(),
-    )(*inputs)
-
-    return (
-        dq, dk_p, dv_p, dk_l, dv_l,
-        jnp.zeros_like(bias_p) if has_bias else None,
-        jnp.zeros_like(bias_l) if has_bias else None,
-    )
-
-
-_flash_packed_2seg.defvjp(_flash_packed_2seg_fwd, _flash_packed_2seg_bwd)
-
-
-@jax.named_scope("flash_attention_packed_2seg")
-def flash_attention_packed_2seg(
-    q: jnp.ndarray,
-    k_prefix: jnp.ndarray,
-    v_prefix: jnp.ndarray,
-    k_latent: jnp.ndarray,
-    v_latent: jnp.ndarray,
-    num_heads: int,
-    pad_mask_prefix: Optional[jnp.ndarray] = None,
-    pad_mask_latent: Optional[jnp.ndarray] = None,
-    sm_scale: float = 1.0,
-    block_q: Optional[int] = None,
-    block_kv: Optional[int] = None,
-) -> jnp.ndarray:
-    """Blockwise fused attention of ``q`` over the logical kv sequence
-    ``[prefix; latents]`` WITHOUT concatenating the segments.
-
-    The right-aligned causal mask is always applied (this is the Perceiver AR
-    prefix cross-attention): with ``n_latent_kv == n_q``, query *i* attends
-    the whole prefix plus latent slots ``t <= i`` — exactly the concat path's
-    ``j <= i + prefix_len``.
-
-    :param q: latent queries (B, Nq, H*Dqk), already scaled/rotated.
-    :param k_prefix: kept-prefix keys (B, Np, H*Dqk), Np >= 1, already rotated.
-    :param v_prefix: kept-prefix values (B, Np, H*Dv).
-    :param k_latent: latent keys (B, Nq, H*Dqk), already rotated.
-    :param v_latent: latent values (B, Nq, H*Dv).
-    :param pad_mask_prefix: optional (B, Np) boolean, True = padding slot.
-    :param pad_mask_latent: optional (B, Nq) boolean, True = padding slot.
-    :returns: (B, Nq, H*Dv) in q's dtype.
-
-    Each segment is padded to its own divisor block size; the seam (a prefix
-    that is not a block multiple) is masked with a STATIC tail mask on the
-    last prefix block, so no bias stream exists unless a pad mask does.
-    """
-    b, nq, cq = q.shape
-    n_p = k_prefix.shape[1]
-    n_l = k_latent.shape[1]
-    if n_l != nq:
-        raise ValueError(f"latent kv length ({n_l}) must equal query length ({nq})")
-    if n_p < 1:
-        raise ValueError("two-segment attention requires a non-empty prefix; "
-                         "use flash_attention_packed(causal=True) when prefix_len == 0")
-    h = num_heads
-    d_qk = cq // h
-    d_v = v_latent.shape[2] // h
-
-    block_q = _choose_block(nq, 1024 if block_q is None else block_q, exact=block_q is not None)
-    bkv_p = _choose_block(n_p, 2048 if block_kv is None else block_kv, exact=block_kv is not None)
-    bkv_l = _choose_block(n_l, 2048 if block_kv is None else block_kv, exact=block_kv is not None)
-
-    qf = _pad_to(q, 1, block_q)
-    kpf = _pad_to(k_prefix, 1, bkv_p)
-    vpf = _pad_to(v_prefix, 1, bkv_p)
-    klf = _pad_to(k_latent, 1, bkv_l)
-    vlf = _pad_to(v_latent, 1, bkv_l)
-
-    v2 = fast_features()
-    if pad_mask_prefix is not None or pad_mask_latent is not None:
-        # prefix pad slots beyond n_p are masked by the static tail mask and
-        # latent pad slots beyond n_l are causally invisible to every valid
-        # query row, so the biases only carry the user masks
-        bias_p = jnp.zeros((b, kpf.shape[1]), jnp.float32)
-        if pad_mask_prefix is not None:
-            bias_p = bias_p.at[:, :n_p].set(jnp.where(pad_mask_prefix, MASK_VALUE, 0.0))
-        bias_l = jnp.zeros((b, klf.shape[1]), jnp.float32)
-        if pad_mask_latent is not None:
-            bias_l = bias_l.at[:, :n_l].set(jnp.where(pad_mask_latent, MASK_VALUE, 0.0))
-        bias_p, bias_l = bias_p[:, None, :], bias_l[:, None, :]
-    else:
-        bias_p = bias_l = None
-
-    out = _flash_packed_2seg(
-        qf, kpf, vpf, klf, vlf, bias_p, bias_l,
-        n_p, sm_scale, block_q, bkv_p, bkv_l, h, d_qk, d_v, v2, _geometry(nq, n_p + n_l),
     )
     return out[:, :nq, :]
 
@@ -2248,24 +1478,20 @@ def flash_attention(
     vf = _pad_to(vf, 2, 8)
 
     # additive kv bias per (batch*head) row: padded slots + user pad mask
-    v2 = fast_features()
     nkv_p = kf.shape[1]
-    if "nobias" in v2 and pad_mask is None and nkv_p == nkv:
-        bias = None  # all-zero: drop the stream + per-tile add (see packed)
-    else:
-        bias = jnp.zeros((b, nkv_p), jnp.float32)
-        if pad_mask is not None:
-            bias = bias.at[:, :nkv].set(jnp.where(pad_mask, MASK_VALUE, 0.0))
-        if nkv_p != nkv:
-            bias = bias.at[:, nkv:].set(MASK_VALUE)
-        # kernels index the (B, 1, Nkv_p) bias with (bh // num_heads, 0, j)
-        bias = bias[:, None, :]
+    bias = jnp.zeros((b, nkv_p), jnp.float32)
+    if pad_mask is not None:
+        bias = bias.at[:, :nkv].set(jnp.where(pad_mask, MASK_VALUE, 0.0))
+    if nkv_p != nkv:
+        bias = bias.at[:, nkv:].set(MASK_VALUE)
+    # kernels index the (B, 1, Nkv_p) bias with (bh // num_heads, 0, j)
+    bias = bias[:, None, :]
 
     # (batch*heads) rows are batch-major, so a split of the leading dim over
     # the batch axes keeps each device's rows aligned with its bias rows
     out = _on_batch_shards(
         lambda q_, k_, v_, bias_: _flash(
-            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, v2, _geometry(nq, nkv)
+            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, _geometry(nq, nkv)
         ),
         qf, kf, vf, bias,
     )

@@ -10,18 +10,15 @@ query over that slot's pages. Two implementations, one contract:
   CPU tier-1 certifies token-exact against the contiguous cache, and it is
   the default everywhere (the ``decode_paged`` graphcheck contract budgets
   its gathers and pins that no kv-axis concatenate appears);
-- **page-walk kernel** (this module): the PR-2 twoseg family's
-  segment-select machinery taken one step further — instead of selecting
-  between two static kv operands, the kv BlockSpec *index maps* read the
-  scalar-prefetched page table, so block ``(s, j)`` DMAs page
+- **page-walk kernel** (this module): the kv BlockSpec *index maps* read
+  the scalar-prefetched page table, so block ``(s, j)`` DMAs page
   ``page_table[s, j]`` straight from the pool (*Ragged Paged Attention*,
   arXiv:2604.15464). The contiguous view is never materialized and the
   per-step HBM traffic is O(valid tokens), not O(slots x capacity).
 
 The kernel is forward-only (decode has no backward), gated behind the
-``paged`` kernel feature (``ops.flash_attention.fast_kernels``) exactly
-like twoseg — default-off until a real-TPU A/B graduates it through the
-ledger; the gather fallback is the shipping semantics either way.
+``paged`` kernel feature (``ops.flash_attention.fast_kernels``) —
+default-off until a real-TPU A/B graduates it through the ledger; the gather fallback is the shipping semantics either way.
 Equivalence kernel-vs-fallback is pinned in interpret mode by
 ``tests/test_paged_engine.py``.
 """

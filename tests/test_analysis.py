@@ -123,7 +123,7 @@ def test_hot_concat_clean_outside_hot_scope_and_for_channel_glue():
 
 
 def test_hot_concat_forbidden_dim_fires_anywhere():
-    """The twoseg-style guarantee: a concat producing a tensor with the
+    """The "never build this tensor" guarantee: a concat producing a tensor with the
     forbidden kv-length dimension ON THE CONCATENATED AXIS fires regardless
     of scope."""
     n_kv = _A.shape[1] + _B.shape[1]
@@ -684,39 +684,6 @@ def test_cross_program_consistency_skipped_without_companion():
     assert report.rules_skipped == ("cross-program-consistency",)
 
 
-# ------------------------------------------------- ledger-derived allowlist
-
-
-def test_default_allow_derives_from_ledger(tmp_path):
-    from perceiver_io_tpu.analysis import ledger as L
-    from perceiver_io_tpu.analysis.flagship import DEFAULT_ALLOW, default_allow
-
-    # no ledger: the full static defaults
-    assert default_allow(str(tmp_path)) == DEFAULT_ALLOW
-    led = {
-        "schema_version": 1,
-        "features": {
-            "twoseg": {"state": "staged",
-                       "history": [{"state": "staged", "reason": "seed"}]}
-        },
-        "floors": {},
-    }
-    L.save_ledger(str(tmp_path), led)
-    assert default_allow(str(tmp_path)) == DEFAULT_ALLOW  # staged: entry stays
-
-    led = L.advance(led, "twoseg", "measured", "A/B ran", evidence={"ab": "BENCH_rX"})
-    led = L.advance(led, "twoseg", "default_on", "graduated")
-    L.save_ledger(str(tmp_path), led)
-    flipped = default_allow(str(tmp_path))
-    assert not any("kv_concat" in a for a in flipped), (
-        "graduating twoseg must drop the kv_concat allowlist entry"
-    )
-    assert any("perceiver_ar._attend" in a for a in flipped)
-
-    # today's repo ledger has twoseg staged, so the entry is still live
-    assert any("kv_concat" in a for a in default_allow())
-
-
 # ----------------------------------------------------- allowlist + report API
 
 
@@ -894,18 +861,6 @@ def test_flagship_micro_lint_is_clean():
         assert report.ok(), f"{name}:\n{report.format()}"
         # the default-route kv concat is allowlisted, not silently absent
     assert any("kv_concat" in v.key for v in reports["train"].allowed)
-
-
-def test_flagship_twoseg_feature_removes_kv_concat():
-    """Linting under features=('twoseg',) the kv_concat scope disappears
-    from the trace entirely — the PR 2 guarantee at flagship level."""
-    from perceiver_io_tpu.analysis.flagship import lint_flagship
-
-    off = lint_flagship(geometry="micro", targets=("train",), features=())["train"]
-    on = lint_flagship(geometry="micro", targets=("train",), features=("twoseg",))["train"]
-    assert any("kv_concat" in v.key for v in off.allowed)
-    assert not any("kv_concat" in v.key for v in on.allowed + on.violations)
-    assert on.ok()
 
 
 def test_graphlint_telemetry_block_shape():

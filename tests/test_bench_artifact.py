@@ -96,9 +96,9 @@ def test_bench_telemetry_records_kernel_features_and_smoke_status():
     assert t["kernel_features"] == []
     assert "kernel_smoke" not in t  # unresolved outside main()
 
-    with fast_kernels({"twoseg"}):
+    with fast_kernels({"paged"}):
         t = bench.telemetry_fields(None, 0.01)["telemetry"]
-    assert t["kernel_features"] == ["twoseg"]
+    assert t["kernel_features"] == ["paged"]
 
     old = bench._SMOKE_STATUS
     try:

@@ -136,16 +136,18 @@ def test_fast_kernel_flags_context_scoped():
     )
 
     assert fast_features() == frozenset()
-    with fast_kernels(["base2", "nobias"]):
-        assert fast_features() == {"base2", "nobias"}
+    with fast_kernels(["paged"]):
+        assert fast_features() == {"paged"}
         seen = {}
         t = threading.Thread(target=lambda: seen.setdefault("f", fast_features()))
         t.start()
         t.join()
         assert seen["f"] == frozenset()  # fresh thread, fresh context
+        with fast_kernels(False):
+            assert fast_features() == frozenset()
         with fast_kernels(True):
-            assert fast_features() == ALL_FEATURES
-        assert fast_features() == {"base2", "nobias"}
+            assert fast_features() == ALL_FEATURES == {"paged"}
+        assert fast_features() == {"paged"}
     assert fast_features() == frozenset()
 
     import pytest as _pytest
@@ -168,7 +170,7 @@ def test_one_kernel_backward_equals_the_split_pair(rng, nq, nkv, d, causal, padd
     bias = jnp.zeros((b, kf.shape[1]), jnp.float32).at[:, nkv:].set(MASK_VALUE)
     if padded:
         bias = bias.at[:, :3].set(MASK_VALUE)
-    statics = (causal, nkv - nq, d**-0.5, nq, block_kv, h, frozenset(), f"q{nq}_kv{nkv}")
+    statics = (causal, nkv - nq, d**-0.5, nq, block_kv, h, f"q{nq}_kv{nkv}")
     _, residuals = fa._flash_fwd(q, kf, vf, bias[:, None, :], *statics)
     one = fa._flash_bwd_one(*statics, residuals, w)
     split = fa._flash_bwd_split(*statics, residuals, w)

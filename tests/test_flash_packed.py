@@ -106,12 +106,14 @@ def test_packed_single_head_wide():
 # --- the tile plan (PR 27): what the wrapper chooses from the lengths alone --
 
 
-def _einsum_packed(q, k, v, h, causal, sm_scale):
+def _einsum_packed(q, k, v, h, causal, sm_scale, pad_mask=None):
     """Plain attention on packed operands, the right-aligned causal mask."""
     b, nq, _ = q.shape
     nkv = k.shape[1]
     q4, k4, v4 = (x.reshape(b, x.shape[1], h, -1) for x in (q, k, v))
     s = jnp.einsum("bihc,bjhc->bhij", q4, k4) * sm_scale
+    if pad_mask is not None:
+        s = jnp.where(pad_mask[:, None, None, :], MASK_VALUE, s)
     if causal:
         hidden = jnp.arange(nkv)[None, :] > jnp.arange(nq)[:, None] + (nkv - nq)
         s = jnp.where(hidden[None, None], MASK_VALUE, s)
@@ -148,6 +150,51 @@ def test_default_plan_matches_einsum(nq, nkv, causal):
     want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5)
+
+
+# --- the Perceiver AR cross-attention: latents over [kept prefix; latents] ---
+#
+# The kv window is a kept prefix of any length followed by the latents. 70 and
+# 200 end inside a 128-wide kv block (K/V are padded and the bias row masks the
+# tail), 1 is the shortest prefix, 128 and 384 are whole blocks.
+
+PREFIXES = [1, 70, 128, 200, 384]
+LATENTS = 128
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "plain"])
+@pytest.mark.parametrize("prefix", PREFIXES)
+def test_right_aligned_prefix_matches_einsum(prefix, causal, dtype):
+    q, k, v = (x.astype(dtype) for x in _data(LATENTS, prefix + LATENTS, seed=prefix))
+    got = flash_attention_packed(q, k, v, num_heads=H, causal=causal, sm_scale=DQK**-0.5, block_q=128, block_kv=128)
+    assert got.dtype == dtype
+    want = _einsum_packed(*(x.astype(jnp.float32) for x in (q, k, v)), H, causal, DQK**-0.5)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "plain"])
+def test_right_aligned_prefix_gradients_match_einsum(causal):
+    prefix = 200
+    q, k, v = _data(LATENTS, prefix + LATENTS, seed=9)
+    pad = jnp.zeros((B, prefix + LATENTS), bool).at[:, :5].set(True)
+
+    def loss(attn):
+        return lambda q_, k_, v_: jnp.sum(attn(q_, k_, v_) ** 2)
+
+    def flash(q_, k_, v_):
+        return flash_attention_packed(
+            q_, k_, v_, num_heads=H, pad_mask=pad, causal=causal, sm_scale=DQK**-0.5, block_q=128, block_kv=128
+        )
+
+    def plain(q_, k_, v_):
+        return _einsum_packed(q_, k_, v_, H, causal, DQK**-0.5, pad_mask=pad)
+
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip(("dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5, rtol=5e-5, err_msg=name)
 
 
 def _today(n_q, n_kv, block_q=None, block_kv=None):
@@ -255,7 +302,7 @@ def test_one_kernel_backward_equals_the_split_pair(nq, nkv, h, d, causal, blocks
     bias = jnp.zeros((B, kf.shape[1]), jnp.float32).at[:, nkv:].set(MASK_VALUE)
     if padded:
         bias = bias.at[:, :3].set(MASK_VALUE)
-    statics = (causal, nkv - nq, d**-0.5, plan.block_q, plan.block_kv, h, d, d, frozenset(), f"q{nq}_kv{nkv}")
+    statics = (causal, nkv - nq, d**-0.5, plan.block_q, plan.block_kv, h, d, d, f"q{nq}_kv{nkv}")
     _, residuals = fa._flash_packed_fwd(q, kf, vf, bias[:, None, :], *statics)
     one = fa._flash_packed_bwd_one(*statics, residuals, w)
     split = fa._flash_packed_bwd_split(*statics, residuals, w)

@@ -154,7 +154,7 @@ def test_diff_catches_dropped_donation(tmp_path):
 def test_diff_refuses_cross_environment_comparison(base_fp):
     d = diff_fingerprints(base_fp, _doctor(base_fp, backend="tpu"))
     assert not d.comparable and "backend" in d.reason and not d.ok
-    d = diff_fingerprints(base_fp, _doctor(base_fp, features=["twoseg"]))
+    d = diff_fingerprints(base_fp, _doctor(base_fp, features=["paged"]))
     assert not d.comparable and "feature" in d.reason
 
 
@@ -259,8 +259,7 @@ def test_committed_ledger_validates_and_floors_hold():
     ledger = L.load_ledger(CONTRACTS)
     assert ledger is not None, "contracts/ledger.json must be committed"
     assert L.validate_ledger(ledger) == []
-    # both flagship levers tracked, still staged until a TPU A/B lands
-    assert L.feature_state(ledger, "twoseg") == "staged"
+    # the overlap step is tracked, still staged until a TPU A/B lands
     assert L.feature_state(ledger, "overlap") == "staged"
     assert L.default_on_features(ledger) == ()
     # the committed BENCH artifacts meet their own pinned floors

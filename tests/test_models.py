@@ -337,3 +337,175 @@ def test_pos_embedding_slice_path_matches_gather():
     fast_long = adapter.apply(params, x_long)
     ref_long = adapter.apply(params, x_long, positions(2, 15))
     np.testing.assert_allclose(np.asarray(fast_long), np.asarray(ref_long), atol=1e-7)
+
+
+# --- the parameter tree, written down at PR 29 (commit 42ece42) ----------------
+#
+# PR 30 renamed the LayerNorm module class of ``ops/layernorm.py``. Every site
+# is a named attribute or passes ``name=``, so no path may move: a checkpoint
+# of before loads after.
+
+PARAMETER_TREES = {
+    "perceiver_ar": """
+        params/input_adapter/pos_embedding/embedding 32x16
+        params/input_adapter/txt_embedding/embedding 101x16
+        params/out_norm/bias 16
+        params/out_norm/scale 16
+        params/output_adapter/bias 101
+        params/perceiver_ar/cross_attention/cross_attn/attention/k_proj/kernel 16x16
+        params/perceiver_ar/cross_attention/cross_attn/attention/o_proj/bias 16
+        params/perceiver_ar/cross_attention/cross_attn/attention/o_proj/kernel 16x16
+        params/perceiver_ar/cross_attention/cross_attn/attention/q_proj/kernel 16x16
+        params/perceiver_ar/cross_attention/cross_attn/attention/v_proj/kernel 16x16
+        params/perceiver_ar/cross_attention/cross_attn/kv_norm/bias 16
+        params/perceiver_ar/cross_attention/cross_attn/kv_norm/scale 16
+        params/perceiver_ar/cross_attention/cross_attn/q_norm/bias 16
+        params/perceiver_ar/cross_attention/cross_attn/q_norm/scale 16
+        params/perceiver_ar/cross_attention/mlp/LayerNorm_0/bias 16
+        params/perceiver_ar/cross_attention/mlp/LayerNorm_0/scale 16
+        params/perceiver_ar/cross_attention/mlp/dense_1/kernel 16x64
+        params/perceiver_ar/cross_attention/mlp/dense_2/kernel 64x16
+        params/perceiver_ar/self_attention/layer_0/mlp/LayerNorm_0/bias 16
+        params/perceiver_ar/self_attention/layer_0/mlp/LayerNorm_0/scale 16
+        params/perceiver_ar/self_attention/layer_0/mlp/dense_1/kernel 16x64
+        params/perceiver_ar/self_attention/layer_0/mlp/dense_2/kernel 64x16
+        params/perceiver_ar/self_attention/layer_0/self_attn/attention/k_proj/kernel 16x16
+        params/perceiver_ar/self_attention/layer_0/self_attn/attention/o_proj/kernel 16x16
+        params/perceiver_ar/self_attention/layer_0/self_attn/attention/q_proj/kernel 16x16
+        params/perceiver_ar/self_attention/layer_0/self_attn/attention/v_proj/kernel 16x16
+        params/perceiver_ar/self_attention/layer_0/self_attn/norm/bias 16
+        params/perceiver_ar/self_attention/layer_0/self_attn/norm/scale 16
+    """,
+    "image_classifier": """
+        params/decoder/cross_attn/cross_attn/attention/k_proj/bias 16
+        params/decoder/cross_attn/cross_attn/attention/k_proj/kernel 16x16
+        params/decoder/cross_attn/cross_attn/attention/o_proj/bias 16
+        params/decoder/cross_attn/cross_attn/attention/o_proj/kernel 16x16
+        params/decoder/cross_attn/cross_attn/attention/q_proj/bias 16
+        params/decoder/cross_attn/cross_attn/attention/q_proj/kernel 16x16
+        params/decoder/cross_attn/cross_attn/attention/v_proj/bias 16
+        params/decoder/cross_attn/cross_attn/attention/v_proj/kernel 16x16
+        params/decoder/cross_attn/cross_attn/kv_norm/bias 16
+        params/decoder/cross_attn/cross_attn/kv_norm/scale 16
+        params/decoder/cross_attn/cross_attn/q_norm/bias 16
+        params/decoder/cross_attn/cross_attn/q_norm/scale 16
+        params/decoder/cross_attn/mlp/LayerNorm_0/bias 16
+        params/decoder/cross_attn/mlp/LayerNorm_0/scale 16
+        params/decoder/cross_attn/mlp/dense_1/bias 16
+        params/decoder/cross_attn/mlp/dense_1/kernel 16x16
+        params/decoder/cross_attn/mlp/dense_2/bias 16
+        params/decoder/cross_attn/mlp/dense_2/kernel 16x16
+        params/decoder/output_adapter/linear/bias 5
+        params/decoder/output_adapter/linear/kernel 16x5
+        params/decoder/output_query_provider/query 1x16
+        params/encoder/cross_attn_1/cross_attn/attention/k_proj/bias 21
+        params/encoder/cross_attn_1/cross_attn/attention/k_proj/kernel 21x21
+        params/encoder/cross_attn_1/cross_attn/attention/o_proj/bias 16
+        params/encoder/cross_attn_1/cross_attn/attention/o_proj/kernel 21x16
+        params/encoder/cross_attn_1/cross_attn/attention/q_proj/bias 21
+        params/encoder/cross_attn_1/cross_attn/attention/q_proj/kernel 16x21
+        params/encoder/cross_attn_1/cross_attn/attention/v_proj/bias 21
+        params/encoder/cross_attn_1/cross_attn/attention/v_proj/kernel 21x21
+        params/encoder/cross_attn_1/cross_attn/kv_norm/bias 21
+        params/encoder/cross_attn_1/cross_attn/kv_norm/scale 21
+        params/encoder/cross_attn_1/cross_attn/q_norm/bias 16
+        params/encoder/cross_attn_1/cross_attn/q_norm/scale 16
+        params/encoder/cross_attn_1/mlp/LayerNorm_0/bias 16
+        params/encoder/cross_attn_1/mlp/LayerNorm_0/scale 16
+        params/encoder/cross_attn_1/mlp/dense_1/bias 16
+        params/encoder/cross_attn_1/mlp/dense_1/kernel 16x16
+        params/encoder/cross_attn_1/mlp/dense_2/bias 16
+        params/encoder/cross_attn_1/mlp/dense_2/kernel 16x16
+        params/encoder/latent_provider/query 4x16
+        params/encoder/self_attn_1/layer_0/mlp/LayerNorm_0/bias 16
+        params/encoder/self_attn_1/layer_0/mlp/LayerNorm_0/scale 16
+        params/encoder/self_attn_1/layer_0/mlp/dense_1/bias 16
+        params/encoder/self_attn_1/layer_0/mlp/dense_1/kernel 16x16
+        params/encoder/self_attn_1/layer_0/mlp/dense_2/bias 16
+        params/encoder/self_attn_1/layer_0/mlp/dense_2/kernel 16x16
+        params/encoder/self_attn_1/layer_0/self_attn/attention/k_proj/bias 16
+        params/encoder/self_attn_1/layer_0/self_attn/attention/k_proj/kernel 16x16
+        params/encoder/self_attn_1/layer_0/self_attn/attention/o_proj/bias 16
+        params/encoder/self_attn_1/layer_0/self_attn/attention/o_proj/kernel 16x16
+        params/encoder/self_attn_1/layer_0/self_attn/attention/q_proj/bias 16
+        params/encoder/self_attn_1/layer_0/self_attn/attention/q_proj/kernel 16x16
+        params/encoder/self_attn_1/layer_0/self_attn/attention/v_proj/bias 16
+        params/encoder/self_attn_1/layer_0/self_attn/attention/v_proj/kernel 16x16
+        params/encoder/self_attn_1/layer_0/self_attn/norm/bias 16
+        params/encoder/self_attn_1/layer_0/self_attn/norm/scale 16
+    """,
+}
+
+
+def _tiny_perceiver_ar():
+    model = CausalLanguageModel(CausalLanguageModelConfig(
+        vocab_size=101, max_seq_len=32, max_latents=8, num_channels=16, num_heads=2,
+        num_self_attention_layers=1, output_norm=True))
+    return lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 32), jnp.int32), prefix_len=24)
+
+
+def _tiny_image_classifier():
+    model = ImageClassifier(ImageClassifierConfig(
+        encoder=ImageEncoderConfig(image_shape=(8, 8, 3), num_frequency_bands=4, num_cross_attention_heads=1,
+                                   num_self_attention_heads=2, num_self_attention_layers_per_block=1,
+                                   num_self_attention_blocks=2),
+        decoder=ClassificationDecoderConfig(num_classes=5, num_output_query_channels=16, num_cross_attention_heads=1),
+        num_latents=4, num_latent_channels=16))
+    return lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, 8, 3), jnp.float32))
+
+
+@pytest.mark.parametrize("name,build", [("perceiver_ar", _tiny_perceiver_ar), ("image_classifier", _tiny_image_classifier)],
+                         ids=["perceiver_ar", "image_classifier"])
+def test_parameter_tree_paths_and_shapes_are_the_written_ones(name, build):
+    shapes = jax.eval_shape(build())
+    got = [
+        "/".join(str(k.key) for k in path) + " " + "x".join(str(n) for n in leaf.shape)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(shapes)
+    ]
+    assert got == [line.strip() for line in PARAMETER_TREES[name].strip().splitlines()]
+
+
+# --- CrossAttention in prefix mode: the flash route against the einsum route --
+
+
+def _prefix_cross_attention(rotary: bool):
+    from perceiver_io_tpu.core.modules import CrossAttention
+    from perceiver_io_tpu.core.position import frequency_position_encoding, positions
+
+    heads, d, n_p, n_q = 4, 16, 200, 128  # the prefix ends inside a 128-wide kv block
+    ca = CrossAttention(num_heads=heads, num_q_input_channels=heads * d, num_kv_input_channels=heads * d,
+                        causal_attention=True)
+    rng = np.random.default_rng(3)
+    x_q = jnp.asarray(rng.normal(size=(B, n_q, heads * d)), jnp.float32)
+    x_p = jnp.asarray(rng.normal(size=(B, n_p, heads * d)), jnp.float32)
+    kwargs = {}
+    if rotary:
+        rope_k = frequency_position_encoding(positions(B, n_p + n_q), d // 2)
+        kwargs = {"rope_q": rope_k[:, n_p:], "rope_k": rope_k}
+    params = ca.init(jax.random.PRNGKey(0), x_q, x_kv_prefix=x_p)
+    return ca, params, x_q, x_p, kwargs
+
+
+@pytest.mark.parametrize("case", ["plain", "rotary", "pad_mask", "param_grads"])
+def test_cross_attention_prefix_mode_flash_matches_einsum(case):
+    """``CrossAttention(x_q, x_kv_prefix=...)`` builds [prefix; latents] and
+    attends right-aligned causally: with the packed flash kernels (interpret
+    mode here) and with the einsum path, outputs and parameter gradients."""
+    from perceiver_io_tpu.ops.flash_attention import default_flash
+
+    ca, params, x_q, x_p, kwargs = _prefix_cross_attention(rotary=case == "rotary")
+    if case == "pad_mask":
+        kwargs["pad_mask"] = jnp.zeros((B, x_p.shape[1] + x_q.shape[1]), bool).at[:, :7].set(True)
+
+    def out(params, flash):
+        with default_flash(flash):
+            return ca.apply(params, x_q, x_kv_prefix=x_p, **kwargs).last_hidden_state
+
+    if case != "param_grads":
+        np.testing.assert_allclose(np.asarray(out(params, True)), np.asarray(out(params, False)), atol=2e-5)
+        return
+    on = jax.grad(lambda p: jnp.sum(out(p, True) ** 2))(params)
+    off = jax.grad(lambda p: jnp.sum(out(p, False) ** 2))(params)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(on), jax.tree.leaves(off)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-4, rtol=1e-4,
+                                   err_msg=jax.tree_util.keystr(path))

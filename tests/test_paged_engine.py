@@ -271,7 +271,7 @@ def _decode_paged_target():
 
 
 def test_decode_paged_graph_no_kv_concat_and_budgeted_gathers(model_and_params):
-    """The ISSUE 13 graph pin (mirrors the twoseg jaxpr-walk test): the
+    """The ISSUE 13 graph pin (a jaxpr walk): the
     batched paged decode step's traced graph contains NO concatenate over a
     kv-capacity axis, and exactly the BUDGETED page-table gathers — the
     k/v gather-view pair per cache plus one page-id lookup per append (the

@@ -16,7 +16,7 @@ committed BENCH_*.json numbers.
 
     python tools/graphcheck.py                          # the gate (tasks.py perf)
     python tools/graphcheck.py --programs train_flat,decode
-    python tools/graphcheck.py --update --reason "twoseg graduated (BENCH_r07 A/B)"
+    python tools/graphcheck.py --update --reason "paged graduated (serve cell A/B)"
     python tools/graphcheck.py --json graphcheck.json
 
 --update etiquette: a snapshot move is a REVIEWED decision — the reason

@@ -8,7 +8,7 @@ this is the CI gate `tasks.py graphlint` wraps:
 
     python tools/graphlint.py --fail-on error
     python tools/graphlint.py --geometry flagship --no-compiled   # trace-only
-    python tools/graphlint.py --kernel-features twoseg            # A/B the lint
+    python tools/graphlint.py --kernel-features paged             # A/B the lint
     python tools/graphlint.py --json graphlint.json --allow 'hot-concat:*mlp*'
     python tools/graphlint.py --mesh data=2,fsdp=4 --targets train  # sharded step
     python tools/graphlint.py --programs all --no-compiled  # the 5 graphcheck
@@ -92,7 +92,7 @@ def main(argv=None) -> int:
                    help="forbid compiling — trace-only rules")
     p.add_argument("--kernel-features", default=None,
                    help="trace-time flash kernel feature set to lint under: "
-                        "'all', 'none', or a comma list (e.g. 'twoseg') — same "
+                        "'all', 'none', or a comma list (e.g. 'paged') — same "
                         "tokens as bench.py --kernel-features")
     p.add_argument("--collective-budget", default=None,
                    help="JSON dict enabling the collective-budget rule, e.g. "

@@ -2,8 +2,8 @@
 the VERDICT r3 image roofline treatment). The image step's exclusive profile
 (tools/profile_step.py --mode img) puts ~22.6 ms/step (12.7%) in XLA
 layernorm stat fusions — an order of magnitude more LN work than the CLM
-flagship (96 LN applications per forward over the 48-layer shared SA stack),
-where the fused Pallas LN lost by 1%.
+flagship (96 LN applications per forward over the 48-layer shared SA stack).
+A Pallas LayerNorm lost by 1% there and by 5% here (deleted in PR 30).
 
     python tools/img_ab.py [--batch-size 16] [--steps 8]
 """
@@ -34,7 +34,7 @@ def main():
     p.add_argument("--steps", type=int, default=8)
     p.add_argument("--reps", type=int, default=4)
     # base = concat input route (round-3); split = fused split-kv input
-    # (round-4 default); fusedln = base + Pallas LN (measured SLOWER)
+    # (round-4 default)
     p.add_argument("--variants", nargs="*", default=["base", "split"])
     args = p.parse_args()
 
@@ -44,7 +44,6 @@ def main():
         ImageClassifierConfig,
         ImageEncoderConfig,
     )
-    from perceiver_io_tpu.ops.layernorm import fused_ln
     from perceiver_io_tpu.training import TrainState, classification_loss_fn, make_optimizer
     from perceiver_io_tpu.training.loop import make_train_step
 
@@ -91,14 +90,13 @@ def main():
             return l
 
         def call(k):
-            # trace-time routing: 'base'/'fusedln' force the concat input
-            # route by disabling the split gate; 'split' leaves the default
+            # trace-time routing: 'base' forces the concat input route by
+            # disabling the split gate; 'split' leaves the default
             orig = PerceiverEncoder._use_split_input
             if variant != "split":
                 PerceiverEncoder._use_split_input = lambda self, pm, det: False
             try:
-                with fused_ln(True if variant == "fusedln" else None):
-                    return float(run(state, batch, k))
+                return float(run(state, batch, k))
             finally:
                 PerceiverEncoder._use_split_input = orig
 

@@ -16,15 +16,6 @@ embedding — the current default); append ``_embed`` to any variant name
 (e.g. ``host+bf16m_embed``) to pin the round-4 embedded-row gather that the
 historical numbers in docs/performance.md were measured on.
 
-Since round 6, add a ``twoseg`` token (e.g. ``host+bf16m+twoseg``) to route
-the prefix cross-attention through the two-segment packed flash kernels
-(`fast_kernels({"twoseg"})` — the concatenated [prefix; latents] kv tensor
-and its LayerNorm/K/V-projection materializations disappear). The flag is
-trace-time: this harness compiles each variant inside its feature context,
-which is the same-process A/B the kernel's docs/performance.md entry cites:
-
-    python tools/step_ab.py --variants host+bf16m host+bf16m+twoseg
-
     python tools/step_ab.py [--batch-size 4] [--steps 20] [--microbatch 2]
 
 Since round 14 (Specline) the harness also takes DECODE variants, so the
@@ -181,24 +172,16 @@ def main():
 
         return run
 
-    from perceiver_io_tpu.ops.flash_attention import fast_kernels
-
     n_short, n_long = 2, 2 + args.steps
     decode_family = {
         v for v in args.variants if v == "decode" or _re.fullmatch(r"spec\d+x\d+", v)
     }
     runs = {}
     for name in args.variants:
-        # kernel features are read at TRACE time: build AND compile each
-        # variant inside its feature context (measurement trap (a) in
-        # docs/performance.md round 3 — a variant compiled under the wrong
-        # flag silently measures the other kernel)
-        feats = frozenset({"twoseg"}) if "twoseg" in name.split("+") else frozenset()
-        with fast_kernels(feats):
-            runs[name] = build_decode(name) if name in decode_family else build(name)
-            t0 = time.perf_counter()
-            runs[name](n_short)
-            runs[name](n_long)
+        runs[name] = build_decode(name) if name in decode_family else build(name)
+        t0 = time.perf_counter()
+        runs[name](n_short)
+        runs[name](n_long)
         print(f"{name}: compiled in {time.perf_counter() - t0:.0f}s", flush=True)
 
     meds = interleaved_slopes(runs, n_short, n_long, reps=args.reps)

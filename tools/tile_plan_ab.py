@@ -4,13 +4,13 @@ One process builds every variant (grid blocks, and bands inside a tile on
 the diagonal) of one attention geometry, runs them round-robin, each round
 under its own profiler capture, and reads the device time of the forward, dq
 and dkv kernels from the captures: wall clocks drift between processes on
-this chip (tools/kernel_ab.py), kernel device times in one process do not.
+this chip (docs/performance.md, round 3), kernel device times in one process do not.
 Every variant's outputs and gradients are compared with the first variant's.
 
     python tools/tile_plan_ab.py --geom sa --variants 1024x1024 512x512 1024x1024/256 plan
     python tools/tile_plan_ab.py --geom ca --variants 1024x2176 1024x2176/256
 
-A variant is ``<block_q>x<block_kv>[/<band rows>][+feature,...]``: grid blocks
+A variant is ``<block_q>x<block_kv>[/<band rows>]``: grid blocks
 of that size, every tile run whole, or every tile on the diagonal cut into
 bands of that many rows; ``plan`` is what ``tile_plan`` chooses itself.
 ``--compile-only`` lowers and compiles every variant for a described v5e (no
@@ -60,20 +60,18 @@ BACKWARDS = ("split", "one")
 
 
 def parse_variant(text: str):
-    """``512x512/256+fastmask`` -> (512, 512, 256, {"fastmask"}); ``plan`` -> None blocks."""
-    spec, _, feats = text.partition("+")
-    features = frozenset(f for f in feats.split(",") if f)
-    if spec == "plan":
-        return None, None, None, features
-    blocks, _, band = spec.partition("/")
+    """``512x512/256`` -> (512, 512, 256); ``plan`` -> None blocks."""
+    if text == "plan":
+        return None, None, None
+    blocks, _, band = text.partition("/")
     bq, bkv = (int(x) for x in blocks.split("x"))
-    return bq, bkv, int(band or 0), features
+    return bq, bkv, int(band or 0)
 
 
 def build(geom: dict, variant: str, sharding=None):
     """The jitted call of one variant, lowered while its plan is in force
-    (the plan and the feature set are read at trace time)."""
-    bq, bkv, band, features = parse_variant(variant)
+    (the plan is read at trace time)."""
+    bq, bkv, band = parse_variant(variant)
     h, d = geom["h"], geom["d"]
 
     def attn(q, k, v):
@@ -91,7 +89,7 @@ def build(geom: dict, variant: str, sharding=None):
         fa.tile_plan = lambda n_q, n_kv, causal, *_: fa._make_plan(n_q, n_kv, causal, bq, bkv)
         fa._BAND_ROWS, fa._BAND_MAX_SHARE = (band, 1.0) if band else (chosen[1], 0.0)
     try:
-        with fa.fast_kernels(features), jax.default_matmul_precision("default"):
+        with jax.default_matmul_precision("default"):
             lowered = jax.jit(fn).lower(*args)
         plan = fa.tile_plan(geom["nq"], geom["nkv"], True)
     finally:
@@ -107,7 +105,7 @@ def build_backward(geom: dict, which: str, sharding=None):
     plan = fa.tile_plan(nq, nkv, causal)  # the heads-major wrapper picks the same blocks
     assert (nq, nkv % plan.block_kv) == (plan.block_q, 0), "a geometry for --backward has one q block and no padding"
     statics = (causal, nkv - nq, d**-0.5, plan.block_q, plan.block_kv, h)
-    statics += ((d, d) if packed else ()) + (frozenset(), fa._geometry(nq, nkv))
+    statics += ((d, d) if packed else ()) + (fa._geometry(nq, nkv),)
     prefix = "_flash_packed" if packed else "_flash"
     forward, backward = getattr(fa, f"{prefix}_fwd"), getattr(fa, f"{prefix}_bwd_{which}")
 
