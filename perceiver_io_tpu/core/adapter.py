@@ -144,11 +144,15 @@ class TokenInputAdapterWithRotarySupport(TokenInputAdapter):
 
         The point is the backward: the full-length (B, N, C) embedding and
         its dropout row-gather never materialize, so the gather's
-        inverse-gather VJP (~0.8 ms/step at the 16k flagship) disappears.
-        What remains is the token one-hot contraction over the *compact*
-        row count and a position-table VJP whose feature rows are gathered,
-        not scattered (ops/gathers.gather_table_rows — index-map inversion
-        via two tiny int scatters). Semantics: reference modules.py:809-830.
+        inverse-gather VJP (~0.8 ms/step at the 16k flagship at batch 4,
+        August) disappears. What remains is the token one-hot contraction
+        over the *compact* row count and the position-table gradient as
+        tile-local one-hot products (ops/gathers.gather_table_rows: the
+        kernel ``embed_pos_grad_n<prefix_len>_k<K>``, 1.9 ms a step at the
+        benchmark's batch 32 where inverting the index map and gathering the
+        cotangent's rows took 11.5; PERF.md 6, PR 31). The id gather below
+        (2.5 ms a step at batch 32) and the two forward row gathers are what
+        XLA makes of them. Semantics: reference modules.py:809-830.
         """
         b, n = x.shape[0], x.shape[1]
         ids_kept = jnp.take_along_axis(x[:, :prefix_len], keep_idx, axis=1)

@@ -995,7 +995,7 @@ class PerceiverAR(nn.Module):
         # token adapter): apply the dropout selection to token ids and
         # position-table rows BEFORE embedding, so the full-length (B, N, C)
         # embedding and its row-gather (forward + inverse-gather backward,
-        # ~1.2 ms/step at the 16k flagship) never exist. Numerically the
+        # ~1.2 ms/step at the 16k flagship at batch 4) never exist. Numerically the
         # embedded-row gather below: embedding is a per-position lookup, so
         # gather-then-embed == embed-then-gather row for row.
         if (

@@ -15,7 +15,11 @@ same function is the smoking gun for a shape leak.
 A ``compile`` row also carries what the traces so far fixed about the packed
 flash kernels (``flash_tiles``: one row per distinct attention geometry with
 its blocks and the score tiles run, masked and skipped; the plan is chosen at
-trace time from the lengths alone, ``ops.flash_attention.tile_plan``).
+trace time from the lengths alone, ``ops.flash_attention.tile_plan``), and
+about the position-table gradient of the compact prefix-dropout embedding
+(``embed_tiles``: one row per distinct call with its tiles, grid steps and
+one-hot FLOPs, and whether it took the kernel or XLA's scatter-add;
+``ops.gathers.embed_tile_plan``, from the shapes alone).
 """
 
 from __future__ import annotations
@@ -95,8 +99,9 @@ class RecompileTracker:
                     self.goodput.add("compile", dt)
                 if self.events is not None:
                     from perceiver_io_tpu.ops.flash_attention import tile_plans
+                    from perceiver_io_tpu.ops.gathers import embed_tile_plans
 
-                    flash_tiles = tile_plans()
+                    flash_tiles, embed_tiles = tile_plans(), embed_tile_plans()
                     self.events.emit(
                         "compile",
                         fn=name,
@@ -105,6 +110,7 @@ class RecompileTracker:
                         cache_size=after,
                         arg_shapes=shape_signature(args, kwargs),
                         **({"flash_tiles": flash_tiles} if flash_tiles else {}),
+                        **({"embed_tiles": embed_tiles} if embed_tiles else {}),
                         **(extra(args, kwargs) if extra is not None else {}),
                     )
             return out

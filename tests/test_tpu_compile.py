@@ -35,10 +35,11 @@ PAGE = 128  # the page size chip_smoke.py's serve phase runs
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One described (not attached) v5e chip. The persistent compilation
-    cache is off while it is in use: a compile for a described chip is
-    written to the cache but cannot be read back without the chip."""
+def four_chips():
+    """The four described (not attached) chips of a v5e 2x2 host. The
+    persistent compilation cache is off while they are in use: a compile for
+    a described chip is written to the cache but cannot be read back without
+    the chip."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -49,15 +50,22 @@ def one_chip():
     enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(four_chips):
+    """One described v5e chip."""
+    return SingleDeviceSharding(four_chips[0])
 
 
 @pytest.fixture
 def mosaic(monkeypatch):
     """Lower the Pallas kernels for Mosaic: off the chip the backend is the
-    CPU, and ``_interpret_default`` would pick the interpreter."""
+    CPU, and ``_interpret_default`` would pick the interpreter (the embedding
+    gradient kernel of ``ops/gathers.py`` asks the flash module's rule)."""
     monkeypatch.setattr(fa, "_interpret_default", lambda: False)
     monkeypatch.setattr(pa, "_interpret_default", lambda: False)
 
@@ -99,6 +107,54 @@ def test_flash_attention_image_cross_fwd_bwd(one_chip, mosaic):
 
     text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)), q, kv, kv)
     assert "flash_fwd_q512_kv50176" in text and "flash_bwd_q512_kv50176" in text
+
+
+def test_embed_position_table_grad_at_the_16k_step(one_chip, mosaic):
+    """The backward of the compact prefix-dropout embedding's position
+    lookup at the shape of ``ar16k-train-b32`` (32 rows x 7 680 kept of
+    15 360 positions, 512 channels): the named kernel, and nothing of the
+    inverse-gather VJP it replaced (no (B, N, C) rows, no sort)."""
+    from perceiver_io_tpu.ops import gathers
+
+    batch, kept, positions = 32, CONTEXT // 2 - LATENTS // 2, CONTEXT - LATENTS
+    table = jax.ShapeDtypeStruct((positions, CHANNELS), jnp.bfloat16, sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((batch, kept), jnp.int32, sharding=one_chip)
+    cot = jax.ShapeDtypeStruct((batch, kept, CHANNELS), jnp.bfloat16, sharding=one_chip)
+
+    def table_grad(t, i, g):
+        return jax.grad(lambda t_: jnp.vdot(gathers.gather_table_rows(t_, i).astype(jnp.float32), g.astype(jnp.float32)))(t)
+
+    text = _compile(table_grad, table, idx, cot)
+    assert gathers.embed_grad_kernel_name(positions, kept) == "embed_pos_grad_n15360_k7680"
+    assert "embed_pos_grad_n15360_k7680" in text and "tpu_custom_call" in text
+    assert f"[{batch * positions},{CHANNELS}]" not in text and f"[{batch},{positions},{CHANNELS}]" not in text
+    assert " sort(" not in text and "scatter(" not in text
+
+
+def test_embed_position_table_grad_on_batch_shards(four_chips, mosaic):
+    """The same backward inside a data x fsdp program: GSPMD cannot partition
+    a Mosaic call, so under ``kernel_mesh`` the kernel runs on each chip's 8
+    rows and the partial tables are summed across the chips."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from perceiver_io_tpu.ops import gathers
+
+    mesh = Mesh(np.asarray(four_chips).reshape(2, 2), ("data", "fsdp"))
+    rows, whole = NamedSharding(mesh, P(("data", "fsdp"))), NamedSharding(mesh, P())
+    batch, kept, positions = 32, CONTEXT // 2 - LATENTS // 2, CONTEXT - LATENTS
+    table = jax.ShapeDtypeStruct((positions, CHANNELS), jnp.bfloat16, sharding=whole)
+    idx = jax.ShapeDtypeStruct((batch, kept), jnp.int32, sharding=rows)
+    cot = jax.ShapeDtypeStruct((batch, kept, CHANNELS), jnp.bfloat16, sharding=rows)
+
+    def table_grad(t, i, g):
+        with fa.kernel_mesh(mesh, ("data", "fsdp")):
+            return jax.grad(lambda t_: jnp.vdot(gathers.gather_table_rows(t_, i).astype(jnp.float32), g.astype(jnp.float32)))(t)
+
+    text = _compile(table_grad, table, idx, cot)
+    assert "embed_pos_grad_n15360_k7680" in text and "all-reduce" in text
+    assert f"[{batch // 4 * positions},{CHANNELS}]" not in text and "all-gather" not in text
 
 
 @pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])

@@ -159,6 +159,15 @@ def render(run_dir: str, max_compile_rows: int = 20) -> str:
             ]
             header = ["call", "blocks", "band_rows", "run", "masked", "skipped", "run_share"]
             lines.extend("  " + r for r in _table(rows, header))
+        plans = next((e["embed_tiles"] for e in reversed(compiles) if e.get("embed_tiles")), [])
+        if plans:
+            lines.append("  position-table gradient of the compact embedding:")
+            rows = [
+                [f"{r['kept']} of {r['positions']} x {r['batch']}", r["route"], str(r["tile"] or "-"),
+                 str(r["grid_steps"] or "-"), f"{r['onehot_flops'] / 1e9:.1f}"]
+                for r in plans
+            ]
+            lines.extend("  " + r for r in _table(rows, ["call", "route", "tile", "grid_steps", "one-hot GFLOP"]))
 
     logs = [e for e in events if e.get("event") == "log"]
     if logs:
