@@ -439,3 +439,67 @@ class LatentCache:
 
 def init_latent_cache(batch_size: int, capacity: int, width: int, dtype=jnp.float32) -> LatentCache:
     return LatentCache(rows=jnp.zeros((batch_size, capacity, width), dtype), length=jnp.zeros((), jnp.int32))
+
+
+# ---------------------------------------------------------------------------
+# window discipline
+# ---------------------------------------------------------------------------
+
+
+@struct.dataclass
+class WindowKVCache:
+    """The cache of a sliding-window attention layer: a ring of ``window``
+    slots. ``k``/``v`` are (B, window, C); the token at position ``p`` lives
+    in slot ``p % window``, so a write at ``length % window`` overwrites the
+    one position that has just left the window. Keys are stored rotated, so
+    the order of the slots does not matter to the softmax and nothing is ever
+    moved. ``length`` is a traced int32 scalar, the tokens seen so far (one
+    for the batch); slots ``[0, min(length, window))`` hold live tokens.
+
+    Beside it, in the same generator state, a full-attention layer keeps a
+    :class:`KVCache` that grows with the context."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    length: jnp.ndarray
+
+    @property
+    def capacity(self) -> int:
+        return self.k.shape[1]
+
+    def fill(self, k: jnp.ndarray, v: jnp.ndarray, n: int) -> "WindowKVCache":
+        """The cache after a prompt pass of ``n`` positions over an empty
+        ring: ``k``/``v`` (B, min(n, window), C) are the rows of the prompt's
+        last positions, each put in its slot."""
+        w = self.capacity
+        if k.shape[1] != min(n, w):
+            raise ValueError(f"a prompt of {n} positions fills a ring of {w} with its last {min(n, w)}, got {k.shape[1]}")
+
+        def place(buf, rows):
+            rows = rows.astype(buf.dtype)
+            if n <= w:
+                return lax.dynamic_update_slice(buf, rows, (0, 0, 0))
+            # position p sits at slot p % w: the rows from n - w on, turned by (n - w) % w
+            return jnp.roll(rows, (n - w) % w, axis=1)
+
+        return WindowKVCache(k=place(self.k, k), v=place(self.v, v), length=jnp.asarray(n, jnp.int32))
+
+    def append(self, k: jnp.ndarray, v: jnp.ndarray) -> "WindowKVCache":
+        """Write one token a row, ``k``/``v`` (B, 1, C), keys already rotated, at ``length % window``."""
+        if k.shape[1] != 1:
+            raise ValueError(f"a window cache takes one token a step, got {k.shape[1]}")
+        slot = self.length % self.capacity
+        return WindowKVCache(
+            k=lax.dynamic_update_slice(self.k, k.astype(self.k.dtype), (0, slot, 0)),
+            v=lax.dynamic_update_slice(self.v, v.astype(self.v.dtype), (0, slot, 0)),
+            length=self.length + 1,
+        )
+
+
+def init_window_kv_cache(batch_size: int, window: int, num_qk_channels: int, num_v_channels: int,
+                         dtype=jnp.float32) -> WindowKVCache:
+    return WindowKVCache(
+        k=jnp.zeros((batch_size, window, num_qk_channels), dtype),
+        v=jnp.zeros((batch_size, window, num_v_channels), dtype),
+        length=jnp.zeros((), jnp.int32),
+    )

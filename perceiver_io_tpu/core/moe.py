@@ -1,6 +1,7 @@
-"""Sigmoid-routed sparse experts with a shared expert (DeepSeek-V3,
-arXiv:2412.19437 section 2.1.2), as **one chip's share** of an expert-parallel
-layer.
+"""Sparse experts, sigmoid-routed with a shared expert (DeepSeek-V3,
+arXiv:2412.19437 section 2.1.2) or softmax-routed with none (the Mellum
+family: ``choose_experts_softmax``), as **one chip's share** of an
+expert-parallel layer.
 
 The router keeps its published width: every token is scored against all
 ``n_routed_experts``. The layer is told which experts it holds
@@ -19,12 +20,12 @@ the unbiased ``s`` of the chosen, renormalised to sum to one and scaled by
 ``routed_scaling_factor``.
 
 Two ways through the held experts, chosen at trace time by the number of
-tokens (``_GROUPED_MIN_TOKENS``, where the two were measured to cross), each
+tokens (``_cuts(...).grouped_min_tokens``, where the two were measured to cross), each
 kept on a chip measurement (``tools/moe_ab.py``; PERF.md 6, PR 28):
 
 ``grouped`` (a prompt pass, or a step of 384 tokens and more)
     the routed pairs are sorted by expert, and the pairs that fall to held
-    experts go, a pass of at most 1024 rows at a time, through a gather, a
+    experts go, a pass of at most ``_cuts(...).pass_rows`` rows at a time, through a gather, a
     grouped matrix product (``ops/grouped_matmul.py``) and a scatter-add back
     to their tokens; as many passes as the routing sent pairs here: no pair
     is dropped however skewed the routing is, and the work follows the pairs
@@ -46,7 +47,7 @@ kept on a chip measurement (``tools/moe_ab.py``; PERF.md 6, PR 28):
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import flax.linen as nn
 import jax
@@ -57,38 +58,65 @@ from perceiver_io_tpu.obs import probes
 from perceiver_io_tpu.ops.grouped_matmul import grouped_matmul
 
 
-# How the work is cut, not what is computed; each from ``tools/moe_ab.py`` on
-# the v5e at the published widths (PERF.md 6, PR 28). From this many tokens
-# the grouped path is taken: the two cross between 256 tokens (dense 2.06 ms,
-# grouped 2.43) and 384 (2.95 against 2.52; 4.74 against 2.90 at 512).
-_GROUPED_MIN_TOKENS = 384
-# The grouped kernel's row tile: the fastest from 2048 tokens up (by 9% at a
-# prompt chunk's 8192); 128 rows are 6 to 11% faster from 384 to 512 tokens.
-_ROW_TILE = 256
-# A pass gathers at most ``_PASS_ROWS`` sorted pairs, and the path takes as
-# many passes as the routing sent pairs here: 8192 tokens with 4096 pairs here
-# take 10.0 ms in passes of 1024 rows (9.5 to 9.6 in passes of 512 or 768)
-# against 14.2 in one of 5120 and 26 in passes of 1536 or 2048 (XLA's
+# How the work is cut, not what is computed: a function of the expert layer's
+# geometry (held experts, hidden size, expert width), each value from
+# ``tools/moe_ab.py`` on the v5e at the published widths of the two
+# geometries the program runs (PERF.md 6, PR 28 and PR 32).
+class _Cuts(NamedTuple):
+    grouped_min_tokens: int  # from this many tokens the grouped path is taken
+    row_tile: int  # the grouped kernel's row tile
+    pass_rows: int  # a pass gathers at most this many sorted pairs
+
+
+# 16 held experts of width 2048, hidden 7168 (DeepSeek-V3, one chip of sixteen).
+# Dense and grouped cross between 256 tokens (dense 2.06 ms, grouped 2.43) and
+# 384 (2.95 against 2.52; 4.74 against 2.90 at 512). Row tile: 256 is the
+# fastest from 2048 tokens up (by 9% at a prompt chunk's 8192); 128 rows are 6
+# to 11% faster from 384 to 512 tokens. Rows a pass: 8192 tokens with 4096
+# pairs here take 10.0 ms in passes of 1024 rows (9.5 to 9.6 in passes of 512
+# or 768) against 14.2 in one of 5120 and 26 in passes of 1536 or 2048 (XLA's
 # scatter-add into 8192 rows is slow past 1024 updates), and a layer that a
 # seed's routing sends a quarter more pairs costs a pass of 2 ms more, not a
-# second sweep of 8.6. Fewer tokens take one pass of the pairs an even routing
-# sends here and a quarter more (at 2048 tokens that would be 1280 rows in
-# 4.8 ms; capped, two passes take 7.0).
-_PASS_ROWS = 1024
+# second sweep of 8.6.
+_WIDE_EXPERTS = _Cuts(grouped_min_tokens=384, row_tile=256, pass_rows=1024)
+# 64 held experts of width 896, hidden 2304 (Mellum 2, every expert held, 8
+# pairs a token all of them here). Dense and grouped cross between 256 tokens
+# (dense 1.17 ms, grouped 1.50) and 512 (2.24 against 1.98); a decode step's 32
+# tokens take 1.08 ms dense, 1.09 to 1.29 grouped. Row tile: 256 and 512 within
+# 1% at a prompt chunk's 8192 tokens (128 is 8% slower). Rows a pass: the
+# 65 536 pairs of such a chunk take 17.4 ms in one pass against 19.5 in two of
+# 32 768 rows, 19.4 in passes of 16 384, 23.2 of 8192, 28.5 of 1024 and 42.7
+# of 2048: the scatter-add's cliff past 1024 updates is there at rows of 2304
+# channels too, and beyond it fewer passes win, so a chunk takes one.
+_SMALL_EXPERTS = _Cuts(grouped_min_tokens=384, row_tile=256, pass_rows=65536)
+# Fewer tokens than fill a pass take one pass of the pairs an even routing
+# sends here and a quarter more (at 2048 tokens of the wide geometry that would
+# be 1280 rows in 4.8 ms; capped, two passes take 7.0).
 _PASS_SLACK = 1.25
 
 
-def _pass_rows(pairs: int, held_share: float) -> int:
+def _cuts(hidden: int, width: int) -> _Cuts:
+    """The cuts of an expert layer whose experts are ``hidden`` x ``width``:
+    those of the measured geometry whose expert is nearer in size (by ratio)."""
+    wide, small = 7168 * 2048, 2304 * 896
+    return _WIDE_EXPERTS if (hidden * width) ** 2 >= wide * small else _SMALL_EXPERTS
+
+
+def _pass_rows(pairs: int, held_share: float, cuts: _Cuts) -> int:
     """Rows of one pass of the grouped path for ``pairs`` routed pairs of which ``held_share`` fall here if the routing is even."""
     want = int(pairs * held_share * _PASS_SLACK)
-    return min(_PASS_ROWS, max(-(-want // _ROW_TILE), 1) * _ROW_TILE)
+    return min(cuts.pass_rows, max(-(-want // cuts.row_tile), 1) * cuts.row_tile)
+
+
+def router_logits(x: jnp.ndarray, w_gate: jnp.ndarray) -> jnp.ndarray:
+    """``x W_g`` (T, E) in float32 at full precision: which experts a token
+    takes hangs on differences in the last digits."""
+    return jnp.dot(x.astype(jnp.float32), w_gate.astype(jnp.float32), precision=lax.Precision.HIGHEST)
 
 
 def router_scores(x: jnp.ndarray, w_gate: jnp.ndarray) -> jnp.ndarray:
-    """``sigmoid(x W_g)`` (T, E) in float32 at full precision: which experts a
-    token takes hangs on differences in the last digits."""
-    logits = jnp.dot(x.astype(jnp.float32), w_gate.astype(jnp.float32), precision=lax.Precision.HIGHEST)
-    return jax.nn.sigmoid(logits)
+    """``sigmoid(x W_g)`` (T, E), float32."""
+    return jax.nn.sigmoid(router_logits(x, w_gate))
 
 
 def choose_experts(
@@ -113,6 +141,16 @@ def choose_experts(
     return chosen.astype(jnp.int32), w / w.sum(-1, keepdims=True) * scale
 
 
+def choose_experts_softmax(logits: jnp.ndarray, top_k: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The softmax rule (``norm_topk_prob``): ``p = softmax(logits)`` over all
+    experts in float32, the ``top_k`` largest are chosen, and their weights
+    are ``p`` renormalised over the chosen. No bias, no groups, no scale.
+    Returns chosen (T, top_k) int32 and weights (T, top_k) float32."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, chosen = lax.top_k(p, top_k)
+    return chosen.astype(jnp.int32), w / w.sum(-1, keepdims=True)
+
+
 def _silu_gate(h1, h3, dtype):
     return (jax.nn.silu(h1.astype(jnp.float32)) * h3.astype(jnp.float32)).astype(dtype)
 
@@ -127,15 +165,15 @@ def experts_dense(x, combine, w1, w3, w2):
     return jnp.einsum("gti,gih->th", a.astype(x.dtype), w2, preferred_element_type=jnp.float32)
 
 
-def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int):
+def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int, row_tile: int):
     """The held experts on the pairs routed to them, sorted by expert.
 
     ``x`` (T, h); ``local`` (T, k) int32, a pair's held-expert index or ``G``
     where its expert is not held; ``weights`` (T, k) float32. Returns the
     sum over a token's local pairs (T, h) float32, the rows a pass left
     unserved (a scalar that is zero: the loop takes passes of ``pass_rows``
-    rows, a multiple of the row tile, until none is left) and the number of
-    passes it took."""
+    rows, a multiple of ``row_tile``, the grouped kernel's, until none is
+    left) and the number of passes it took."""
     t, k = local.shape
     g = w1.shape[0]
     flat = local.reshape(-1)
@@ -155,7 +193,7 @@ def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int):
         in_pass = jnp.clip(offsets, lo, lo + pass_rows) - lo
         group_sizes = in_pass[1:] - in_pass[:-1]
         xs = x[token]
-        mm = lambda a, w: grouped_matmul(a, w, group_sizes, tm=_ROW_TILE)  # noqa: E731
+        mm = lambda a, w: grouped_matmul(a, w, group_sizes, tm=row_tile)  # noqa: E731
         ys = mm(_silu_gate(mm(xs, w1), mm(xs, w3), x.dtype), w2)
         ys = jnp.where(live[:, None], ys.astype(jnp.float32) * w_flat[pair][:, None], 0.0)
         return y.at[token].add(ys)
@@ -188,9 +226,11 @@ class SwiGLU(nn.Module):
 class MoELayer(nn.Module):
     """``config`` needs ``hidden_size``, ``moe_intermediate_size``,
     ``n_routed_experts`` (the router's width), ``n_held_experts``,
-    ``held_experts_start``, ``num_experts_per_tok``, ``n_group``,
-    ``topk_group``, ``routed_scaling_factor``, ``n_shared_experts`` and
-    ``init_scale``."""
+    ``held_experts_start``, ``num_experts_per_tok``, ``n_shared_experts``
+    (0: no shared expert), ``init_scale`` and ``scoring_func``: ``"sigmoid"``
+    (the module docstring's rule; also needs ``n_group``, ``topk_group``,
+    ``routed_scaling_factor``, and the layer has a ``gate_bias``) or
+    ``"softmax"`` (:func:`choose_experts_softmax`: no bias, groups or scale)."""
 
     config: object
     dtype: jnp.dtype = jnp.float32
@@ -205,26 +245,33 @@ class MoELayer(nn.Module):
         g, start = c.n_held_experts, c.held_experts_start
         init = nn.initializers.normal(c.init_scale)
         w_gate = self.param("gate", init, (h, c.n_routed_experts), self.param_dtype)
-        # float32 whatever the rest is stored in, as published: it is added to scores that differ in the last digits
-        gate_bias = self.param("gate_bias", nn.initializers.zeros_init(), (c.n_routed_experts,), jnp.float32)
+        if c.scoring_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring_func {c.scoring_func!r}: 'sigmoid' or 'softmax'")
+        if c.scoring_func == "sigmoid":
+            # float32 whatever the rest is stored in, as published: it is added to scores that differ in the last digits
+            gate_bias = self.param("gate_bias", nn.initializers.zeros_init(), (c.n_routed_experts,), jnp.float32)
         width = c.moe_intermediate_size
         w1 = self.param("experts_w1", init, (g, h, width), self.param_dtype).astype(self.dtype)
         w3 = self.param("experts_w3", init, (g, h, width), self.param_dtype).astype(self.dtype)
         w2 = self.param("experts_w2", init, (g, width, h), self.param_dtype).astype(self.dtype)
 
         with jax.named_scope("moe/route"):
-            chosen, weights = choose_experts(
-                router_scores(x, w_gate), gate_bias, n_group=c.n_group, topk_group=c.topk_group,
-                top_k=c.num_experts_per_tok, scale=c.routed_scaling_factor,
-            )
+            if c.scoring_func == "softmax":
+                chosen, weights = choose_experts_softmax(router_logits(x, w_gate), c.num_experts_per_tok)
+            else:
+                chosen, weights = choose_experts(
+                    router_scores(x, w_gate), gate_bias, n_group=c.n_group, topk_group=c.topk_group,
+                    top_k=c.num_experts_per_tok, scale=c.routed_scaling_factor,
+                )
             held = (chosen >= start) & (chosen < start + g)
             local = jnp.where(held, chosen - start, g)
 
         with jax.named_scope("moe/experts"):
             unserved = passes = jnp.zeros((), jnp.int32)
-            if t >= _GROUPED_MIN_TOKENS:
-                rows = _pass_rows(t * c.num_experts_per_tok, g / c.n_routed_experts)
-                y, unserved, passes = experts_grouped(x, local, weights, w1, w3, w2, rows)
+            cuts = _cuts(h, width)
+            if t >= cuts.grouped_min_tokens:
+                rows = _pass_rows(t * c.num_experts_per_tok, g / c.n_routed_experts, cuts)
+                y, unserved, passes = experts_grouped(x, local, weights, w1, w3, w2, rows, cuts.row_tile)
             else:
                 combine = (jax.nn.one_hot(local, g, dtype=jnp.float32) * weights[:, :, None]).sum(axis=1)
                 y = experts_dense(x, combine, w1, w3, w2)
@@ -239,6 +286,8 @@ class MoELayer(nn.Module):
                 "expert_load_max": load.max(),
             })
 
-        with jax.named_scope("moe/shared"):
-            shared = SwiGLU(h, width * c.n_shared_experts, c.init_scale, self.dtype, self.param_dtype, name="shared")(x)
-        return (y + shared.astype(jnp.float32)).astype(self.dtype).reshape(*lead, h)
+        if c.n_shared_experts:
+            with jax.named_scope("moe/shared"):
+                shared = SwiGLU(h, width * c.n_shared_experts, c.init_scale, self.dtype, self.param_dtype, name="shared")(x)
+            y = y + shared.astype(jnp.float32)
+        return y.astype(self.dtype).reshape(*lead, h)

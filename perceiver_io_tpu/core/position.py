@@ -137,6 +137,22 @@ def apply_rotary_interleaved(t: jnp.ndarray, pos: jnp.ndarray, inv_freq) -> jnp.
     return out.reshape(t.shape).astype(t.dtype)
 
 
+def apply_rotary_half(t: jnp.ndarray, pos: jnp.ndarray, inv_freq, attention_factor: float = 1.0) -> jnp.ndarray:
+    """Rotate all channels of ``t`` (..., N, R) in the half-split pairing
+    (Hugging Face's ``rotate_half``): channel ``i`` of the first half and
+    channel ``i`` of the second are one complex number turned by
+    ``pos * inv_freq[i]``. ``attention_factor`` multiplies cos and sin (YaRN's
+    temperature carried by the rotation, on queries and keys alike). ``pos``
+    (..., N) broadcasts against ``t``'s leading axes. Computed in float32,
+    returned in ``t``'s dtype."""
+    angles = pos.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq, jnp.float32)
+    cos, sin = jnp.cos(angles) * attention_factor, jnp.sin(angles) * attention_factor
+    t32 = t.astype(jnp.float32)
+    half = t.shape[-1] // 2
+    x1, x2 = t32[..., :half], t32[..., half:]
+    return jnp.concatenate((x1 * cos - x2 * sin, x2 * cos + x1 * sin), axis=-1).astype(t.dtype)
+
+
 class RotaryPositionEmbedding:
     """Convenience wrapper bundling a frequency encoding with its alignment.
 

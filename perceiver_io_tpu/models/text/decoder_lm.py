@@ -1,33 +1,50 @@
-"""A decoder-only language model of the DeepSeek-V3 family: token embedding,
-``first_k_dense_replace`` blocks with a dense SwiGLU, then blocks with sparse
-experts, every block ``x + MLA(RMSNorm(x))`` then ``x + FFN(RMSNorm(x))``, a
-final RMSNorm and an untied head (``docs/decoder-lm.md``).
+"""A decoder-only language model: token embedding, blocks
+``x + Attn(RMSNorm(x))`` then ``x + FFN(RMSNorm(x))``, a final RMSNorm and an
+untied head (``docs/decoder-lm.md``). One class, two published families, told
+apart by the configuration:
+
+- **DeepSeek-V3** (``layer_types`` None): multi-head latent attention
+  (``core/mla.py``) in every block, ``first_k_dense_replace`` blocks with a
+  dense SwiGLU, then sigmoid-routed experts with a shared expert. The cache is
+  one :class:`LatentCache` a layer; a prompt pass fills it through the
+  expanded attention and a decode step reads it through the absorbed one.
+- **Mellum 2** (``layer_types`` a tuple of ``"sliding_attention"`` and
+  ``"full_attention"``): grouped-query attention (``core/gqa.py``) with the
+  rotary of the layer's kind, softmax-routed experts in every block, no shared
+  expert. A full layer's cache is a :class:`KVCache` that grows with the
+  context, a window layer's a :class:`WindowKVCache` ring of
+  ``sliding_window`` slots, both kinds side by side in one generator state.
 
 Unlike the Perceiver models every position passes the whole stack, so there
-is no latent window: the cache is one :class:`LatentCache` a layer, a prompt
-pass fills it through the expanded attention and a decode step reads it
-through the absorbed one. The model meets :mod:`perceiver_io_tpu.generation`
+is no latent window. The model meets :mod:`perceiver_io_tpu.generation`
 through :meth:`DecoderLanguageModel.generation_decoder`.
 
-The multi-token-prediction module of the published model is not part of
-serving (report section 2.2) and is not here.
+The multi-token-prediction modules of the published models are not part of
+serving (DeepSeek-V3's report section 2.2; Mellum's config has no key for
+one) and are not here.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from perceiver_io_tpu.core.cache import LatentCache, init_latent_cache
+from perceiver_io_tpu.core.cache import (
+    KVCache, LatentCache, WindowKVCache, init_kv_cache, init_latent_cache, init_window_kv_cache,
+)
+from perceiver_io_tpu.core.gqa import GroupedQueryAttention
 from perceiver_io_tpu.core.mla import MultiHeadLatentAttention
 from perceiver_io_tpu.core.moe import MoELayer, SwiGLU
 from perceiver_io_tpu.obs import probes
 from perceiver_io_tpu.ops.layernorm import RMSNorm
+
+
+_LAYER_TYPES = ("sliding_attention", "full_attention")
 
 
 @dataclass(frozen=True)
@@ -38,14 +55,22 @@ class YarnConfig:
     mscale: float = 1.0
     mscale_all_dim: float = 1.0
     original_max_position_embeddings: int = 4096
+    # grouped-query layers carry YaRN's temperature on cos and sin (Hugging Face's ``attention_factor``)
+    attention_factor: float = 1.0
 
 
 @dataclass(frozen=True)
 class DecoderLanguageModelConfig:
-    """Key names follow the published ``config.json``. ``n_routed_experts`` is
-    the router's width; ``n_held_experts`` of them, from
-    ``held_experts_start``, live here (``None``: all of them). ``vocab_size``
-    is the number of rows held of the embedding and the head."""
+    """Key names follow the published ``config.json`` of DeepSeek-V3.
+    ``n_routed_experts`` is the router's width; ``n_held_experts`` of them,
+    from ``held_experts_start``, live here (``None``: all of them).
+    ``vocab_size`` is the number of rows held of the embedding and the head.
+
+    ``layer_types`` (one entry a layer, ``"sliding_attention"`` or
+    ``"full_attention"``) selects grouped-query attention with
+    ``num_key_value_heads``, ``head_dim`` and ``sliding_window``, and
+    ``rope_scaling`` then applies to the full layers only; ``None`` selects
+    latent attention. ``scoring_func`` is the router's rule (``core/moe.py``)."""
 
     vocab_size: int = 129280
     hidden_size: int = 7168
@@ -72,8 +97,21 @@ class DecoderLanguageModelConfig:
     rope_scaling: Optional[YarnConfig] = YarnConfig()
     max_position_embeddings: int = 163840
     init_scale: float = 0.02
+    scoring_func: str = "sigmoid"
+    layer_types: Optional[Tuple[str, ...]] = None
+    num_key_value_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    sliding_window: Optional[int] = None
 
     def __post_init__(self):
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            if len(self.layer_types) != self.num_hidden_layers or set(self.layer_types) - set(_LAYER_TYPES):
+                raise ValueError(f"layer_types: one of {_LAYER_TYPES} for each of the {self.num_hidden_layers} layers")
+            if not (self.num_key_value_heads and self.head_dim and self.sliding_window):
+                raise ValueError("layer_types needs num_key_value_heads, head_dim and sliding_window")
+            if self.num_attention_heads % self.num_key_value_heads:
+                raise ValueError("num_key_value_heads must divide num_attention_heads")
         if self.n_held_experts is None:
             object.__setattr__(self, "n_held_experts", self.n_routed_experts)
         if self.held_experts_start + self.n_held_experts > self.n_routed_experts:
@@ -99,6 +137,7 @@ def _chunks(n: int, want: int) -> int:
 class DecoderBlock(nn.Module):
     config: DecoderLanguageModelConfig
     sparse: bool
+    layer_type: Optional[str] = None  # None: latent attention
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -106,7 +145,10 @@ class DecoderBlock(nn.Module):
         c = self.config
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         self.attn_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
-        self.attn = MultiHeadLatentAttention(c, **kw)
+        if self.layer_type is None:
+            self.attn = MultiHeadLatentAttention(c, **kw)
+        else:
+            self.attn = GroupedQueryAttention(c, window=self.layer_type == "sliding_attention", **kw)
         self.ffn_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
         if self.sparse:
             self.ffn = MoELayer(c, **kw)
@@ -114,15 +156,20 @@ class DecoderBlock(nn.Module):
             self.ffn = SwiGLU(c.hidden_size, c.intermediate_size, c.init_scale, **kw)
 
     def attend(self, x, pos):
-        """``x + MLA(RMSNorm(x))`` over whole rows, expanded; also the cache rows."""
+        """``x + Attn(RMSNorm(x))`` over whole rows, expanded; also the cache
+        rows (latent attention: one array; grouped-query: rotated keys and
+        values, of which a window layer hands on its last ``sliding_window``)."""
         a, rows = self.attn.expand(self.attn_norm(x), pos)
+        if self.layer_type == "sliding_attention":
+            rows = tuple(r[:, :, -self.config.sliding_window:] for r in rows)
         return x + a, rows
 
     def feed_forward(self, x):
         return x + self.ffn(self.ffn_norm(x))
 
-    def step(self, x, cache: LatentCache, pos):
-        a, cache = self.attn.absorb(self.attn_norm(x), cache, pos)
+    def step(self, x, cache, pos):
+        one_token = self.attn.absorb if self.layer_type is None else self.attn.step
+        a, cache = one_token(self.attn_norm(x), cache, pos)
         return self.feed_forward(x + a), cache
 
 
@@ -177,7 +224,8 @@ class DecoderLanguageModel(nn.Module):
             "embedding", nn.initializers.normal(c.init_scale), (c.vocab_size, c.hidden_size), self.param_dtype
         )
         self.layers = [
-            DecoderBlock(c, sparse=i >= c.first_k_dense_replace, name=f"layer_{i}", **kw)
+            DecoderBlock(c, sparse=i >= c.first_k_dense_replace, layer_type=c.layer_types and c.layer_types[i],
+                         name=f"layer_{i}", **kw)
             for i in range(c.num_hidden_layers)
         ]
         self.out_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
@@ -211,7 +259,7 @@ class DecoderLanguageModel(nn.Module):
             x = layer.feed_forward(x)
         return self.logits(x)
 
-    def decode_step(self, token, caches: Tuple[LatentCache, ...]):
+    def decode_step(self, token, caches: Tuple[Union[LatentCache, KVCache, WindowKVCache], ...]):
         """One new token a row against the caches: logits (B, V) and the advanced caches."""
         b = token.shape[0]
         pos = jnp.broadcast_to(caches[0].length, (b, 1)).astype(jnp.int32)
@@ -228,9 +276,11 @@ class DecoderLanguageModel(nn.Module):
         return _Decoder(self)
 
 
-def prefill(model: DecoderLanguageModel, params, input_ids) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, ...]]:
+def prefill(model: DecoderLanguageModel, params, input_ids) -> Tuple[jnp.ndarray, tuple]:
     """The prompt pass: last-position logits (B, V) and, a layer, the cache
-    rows (B, N, width) of the prompt. The hidden state of the whole batch
+    rows of the prompt: (B, N, width) of a latent layer; of a grouped-query
+    layer the keys and the values, each (B * Hkv, N, D), a window layer's
+    last ``sliding_window`` positions only. The hidden state of the whole batch
     stays in memory between layers (B * N * h); within a layer the attention
     runs over chunks of whole rows and the feed-forward over chunks of tokens
     (``_PREFILL_ATTENTION_TOKENS``, ``_PREFILL_FFN_TOKENS``), inside the one
@@ -251,7 +301,10 @@ def prefill(model: DecoderLanguageModel, params, input_ids) -> Tuple[jnp.ndarray
     cache_rows = []
     for i in range(c.num_hidden_layers):
         x, rows = _over_chunks(lambda xc, i=i: scoped("attend_layer", xc, pos, i), x.reshape(b // rows_a, rows_a, n, h))
-        cache_rows.append(rows.reshape(b, n, rows.shape[-1]))
+        if c.layer_types is None:
+            cache_rows.append(rows.reshape(b, n, rows.shape[-1]))
+        else:  # (chunks, rows a chunk, Hkv, positions, D): a key-value head is a row of the cache
+            cache_rows.append(tuple(r.reshape(b * r.shape[2], *r.shape[3:]) for r in rows))
         x, _ = _over_chunks(lambda xc, i=i: (scoped("ffn_layer", xc, i), ()), x.reshape(b * n // tokens_f, tokens_f, h))
         x = x.reshape(b, n, h)
     return scoped("logits", x[:, -1]), tuple(cache_rows)
@@ -261,8 +314,12 @@ class _Decoder:
     """What :mod:`perceiver_io_tpu.generation` asks of a model (see
     ``generation._PerceiverARDecoder`` for the other one): the prompt pass,
     the one-token step, and the state they hand each other. The window is the
-    tuple of latent caches; nothing slides (the capacity is the prompt plus
-    the new tokens, and must fit ``max_position_embeddings``)."""
+    tuple of the layers' caches, each of its layer's kind (latent; or, under
+    grouped-query attention, a growing :class:`KVCache` for a full layer and a
+    :class:`WindowKVCache` ring for a window layer, side by side). Nothing the
+    generator owns slides: a growing cache's capacity is the prompt plus the
+    new tokens, which must fit ``max_position_embeddings``, and a ring
+    overwrites the position that left its window."""
 
     window_names = ("cache",)
     const_names = ()
@@ -270,6 +327,17 @@ class _Decoder:
 
     def __init__(self, model: DecoderLanguageModel):
         self.model = model
+
+    def _caches(self, rows, batch: int, n: int, max_new_tokens: int, cache_dtype):
+        c = self.model.config
+        if c.layer_types is None:
+            return tuple(init_latent_cache(batch, n + max_new_tokens, r.shape[-1], cache_dtype).append(r) for r in rows)
+        slots, d = batch * c.num_key_value_heads, c.head_dim
+        return tuple(
+            init_window_kv_cache(slots, c.sliding_window, d, d, cache_dtype).fill(k, v, n)
+            if kind == "sliding_attention" else init_kv_cache(slots, n + max_new_tokens, d, d, cache_dtype).append(k, v)
+            for kind, (k, v) in zip(c.layer_types, rows)
+        )
 
     def prefill(self, params, input_ids, pad_mask, num_latents, max_new_tokens, cache_dtype):
         del num_latents  # no latent window: every position passes the whole stack
@@ -283,7 +351,7 @@ class _Decoder:
                 f"({c.max_position_embeddings})"
             )
         logits, rows = prefill(self.model, params, input_ids)
-        caches = tuple(init_latent_cache(b, n + max_new_tokens, r.shape[-1], cache_dtype).append(r) for r in rows)
+        caches = self._caches(rows, b, n, max_new_tokens, cache_dtype)
         return logits[:, None], (caches,), ()
 
     def step(self, step_params, window, consts, token):
@@ -292,12 +360,26 @@ class _Decoder:
         return logits[:, None], (caches,)
 
     def health(self, logits, window):
-        return probes.decode_health(logits, window[0][0], jnp.zeros((), jnp.int32))
+        # the occupancy gauge reads a cache that grows: a ring is full from its window on
+        grows = next((cache for cache in window[0] if not isinstance(cache, WindowKVCache)), window[0][0])
+        return probes.decode_health(logits, grows, jnp.zeros((), jnp.int32))
 
     def compile_row(self, batch: int, prompt_len: int, max_new_tokens: int, cache_dtype) -> dict:
-        """The cache's geometry for a ``compile`` event row."""
+        """The caches' geometry for a ``compile`` event row."""
         c = self.model.config
-        row_bytes = (c.kv_lora_rank + c.qk_rope_head_dim) * jnp.dtype(cache_dtype).itemsize
+        itemsize = jnp.dtype(cache_dtype).itemsize
+        if c.layer_types is not None:
+            row_bytes = 2 * c.num_key_value_heads * c.head_dim * itemsize  # a token's keys and values in one layer
+            n_window = c.layer_types.count("sliding_attention")
+            n_full = c.num_hidden_layers - n_window
+            return {
+                "kv_cache_full_layers": n_full,
+                "kv_cache_window_layers": n_window,
+                "kv_cache_full_bytes": batch * (prompt_len + max_new_tokens) * row_bytes * n_full,
+                "kv_cache_window_bytes": batch * c.sliding_window * row_bytes * n_window,
+                "kv_cache_window_rows": c.sliding_window,
+            }
+        row_bytes = (c.kv_lora_rank + c.qk_rope_head_dim) * itemsize
         return {
             "latent_cache_row_bytes": row_bytes,
             "latent_cache_capacity": prompt_len + max_new_tokens,

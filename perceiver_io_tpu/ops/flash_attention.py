@@ -798,7 +798,7 @@ class TilePlan(NamedTuple):
     tiles_run: int
     tiles_masked: int  # of those run: in a band, or a whole grid tile, that the diagonal crosses
     tiles_skipped: int
-    backward: str  # "one" kernel for dq, dk and dv, or the "split" pair (``_backward``)
+    backward: str  # "one" kernel for dq, dk and dv, the "split" pair (``_backward``), or "none" (forward only)
 
     @property
     def run_share(self) -> float:
@@ -826,11 +826,21 @@ def _make_plan(n_q: int, n_kv: int, causal: bool, block_q: int, block_kv: int) -
 
 
 def tile_plan(
-    n_q: int, n_kv: int, causal: bool, block_q: Optional[int] = None, block_kv: Optional[int] = None
+    n_q: int, n_kv: int, causal: bool, block_q: Optional[int] = None, block_kv: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> TilePlan:
     """The tile plan of ``flash_attention_packed`` for a call of these
     lengths: a pure function of its arguments. ``block_q``/``block_kv`` are
-    the wrapper's (None = the tuned hint, a value = an upper bound)."""
+    the wrapper's (None = the tuned hint, a value = an upper bound).
+
+    With a ``window`` (query i sees ``i - window < j <= i``) it is the plan
+    of :func:`flash_attention_gqa`, whose grid walks only the kv blocks a q
+    block can see: square blocks, self-attention, forward only."""
+    if window is not None:
+        if not causal or n_q != n_kv:
+            raise ValueError("a window needs causal self-attention (n_q == n_kv)")
+        block = _choose_block(n_q, 1024 if block_q is None else block_q, exact=block_q is not None)
+        return _make_window_plan(n_q, block, window)
     # The grid blocks are the ones of before PR 27 for every call. Unpadded
     # blocks for the generator's 768 x 768 prompt pass (768 -> 2 x 512 pads a
     # quarter) halved those kernels and cost the decode scan 7%: without the
@@ -852,9 +862,15 @@ def tile_plans() -> list:
     """One row per distinct packed attention call traced so far: its
     geometry (as in the kernel names), blocks and tile counts."""
     return [
-        {"geometry": geom, "causal": causal, **plan._asdict(), "run_share": round(plan.run_share, 4)}
+        {"geometry": geom, "causal": causal, "window": _window_of(geom), **plan._asdict(),
+         "run_share": round(plan.run_share, 4)}
         for (geom, causal), plan in sorted(_TILE_PLANS.items())
     ]
+
+
+def _window_of(geom: str) -> Optional[int]:
+    """The window a call's geometry names (``q<n_q>_kv<n_kv>_w<window>``), None where it names none."""
+    return int(geom.rsplit("_w", 1)[1]) if "_w" in geom else None
 
 
 # ---------------------------------------------------------------------------
@@ -1591,3 +1607,199 @@ def flash_enabled(explicit: Optional[bool] = None) -> bool:
 # batch 4 (25.5 vs 29.0 ms/step) and within 4% at batch 1. Keep flash
 # everywhere it is supported; re-measure with tools/flash_ab.py before
 # revisiting.
+
+
+# ---------------------------------------------------------------------------
+# grouped-query causal self-attention with a sliding window (forward only)
+# ---------------------------------------------------------------------------
+#
+# A decoder-only model's prompt pass (models/text/decoder_lm.py, core/gqa.py):
+# position i sees j <= i and, on a window layer, j > i - window; the keys and
+# values have fewer heads than the queries. Blocks are square, and grid step
+# ``s`` of q block ``iq`` takes kv block ``iq - s``: the diagonal tile first,
+# then the blocks before it, as many as the window reaches (all of them
+# without one). The index maps carry the offset, so a hidden kv block is
+# never fetched; what a tile shows is the same for every q block and depends
+# on ``s`` alone, so each step's visible bands (``_BAND_ROWS`` rows over the
+# kv slots they see, as the packed kernels cut their diagonal tile) are
+# static. Queries and the output stay in the projection layout (B, N, H*D)
+# and a grid step takes one head's D columns; keys and values come heads-major
+# with their own head count (the layout the decode caches keep), and query
+# head h reads key-value head ``h // group``: nothing is written out 8 times.
+# No cell differentiates it: there is no backward, and asking for one raises.
+
+
+def _gqa_steps(n_blocks: int, block: int, window: int) -> int:
+    """kv blocks a q block can see, its own included."""
+    return min(n_blocks, -(-(window + block - 1) // block))
+
+
+def _gqa_bands(step: int, block: int, window: int) -> tuple:
+    """What the tile ``step`` blocks before the diagonal shows, as bands
+    ``(r0, r1, c0, c1, masked)``: rows [r0, r1) of the q block against the kv
+    slots [c0, c1) of the block (multiples of LANES), ``masked`` where some
+    score of the band is hidden. Row r sees slot c iff ``c <= r + shift`` and
+    ``c > r + shift - window`` with ``shift = step * block``. A tile that
+    would keep more than ``_BAND_MAX_SHARE`` of its scores runs whole."""
+    shift = step * block
+
+    def band(r0, r1):
+        c0 = max(0, (r0 + shift - window + 1) // LANES * LANES)
+        c1 = min(block, _round_up(r1 + shift, LANES))
+        masked = c1 - 1 > r0 + shift or c0 <= r1 - 1 + shift - window
+        return (r0, r1, c0, c1, masked)
+
+    bands = tuple(b for b in (band(r0, min(r0 + _BAND_ROWS, block)) for r0 in range(0, block, _BAND_ROWS)) if b[3] > b[2])
+    if sum((r1 - r0) * (c1 - c0) for r0, r1, c0, c1, _ in bands) > _BAND_MAX_SHARE * block * block:
+        return (band(0, block),)
+    return bands
+
+
+def _gqa_tiles(n_blocks: int, block: int, window: int) -> tuple:
+    """``((first step, last step, bands), ...)``: consecutive steps that show the same bands, merged."""
+    tiles = []
+    for step in range(_gqa_steps(n_blocks, block, window)):
+        bands = _gqa_bands(step, block, window)
+        if tiles and tiles[-1][2] == bands:
+            tiles[-1] = (tiles[-1][0], step, bands)
+        else:
+            tiles.append((step, step, bands))
+    return tuple(tiles)
+
+
+def _make_window_plan(n: int, block: int, window: int) -> TilePlan:
+    n_blocks = _round_up(n, block) // block
+    unit = LANES * LANES
+    tiles = _gqa_tiles(n_blocks, block, window)
+    run = masked = 0
+    for lo, hi, bands in tiles:
+        # q blocks that have a kv block ``step`` before them, over the steps that show these bands
+        q_blocks = sum(n_blocks - step for step in range(lo, hi + 1))
+        run += q_blocks * sum((r1 - r0) * (c1 - c0) for r0, r1, c0, c1, _ in bands) // unit
+        masked += q_blocks * sum((r1 - r0) * (c1 - c0) for r0, r1, c0, c1, m in bands if m) // unit
+    cut = any(bands[0][:4] != (0, block, 0, block) for _, _, bands in tiles)
+    return TilePlan(block, block, _BAND_ROWS if cut else 0, run, masked, (n_blocks * block) ** 2 // unit - run, "none")
+
+
+def _fwd_gqa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, sm_scale: float, window: int, tiles: tuple):
+    # q, o (1, block, d); k, v (1, block, d); scratch m/l (block, LANES) f32, acc (block, d) f32
+    iq, s = pl.program_id(2), pl.program_id(3)
+    block = q_ref.shape[1]
+
+    @pl.when(s == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def _band(r0, r1, c0, c1, masked):
+        scores = _dot(q_ref[0, r0:r1, :], k_ref[0, c0:c1, :], ((1,), (1,))) * sm_scale
+        if masked:
+            rows = lax.broadcasted_iota(jnp.int32, scores.shape, 0) + (r0 + s * block)
+            cols = lax.broadcasted_iota(jnp.int32, scores.shape, 1) + c0
+            # the diagonal tile runs first and shows every row its own slot, so a row that a
+            # later tile hides whole already has a finite running maximum and adds exp(-huge) = 0
+            scores = jnp.where((cols <= rows) & (cols > rows - window), scores, MASK_VALUE)
+        m_prev, l_prev = m_scr[r0:r1], l_scr[r0:r1]
+        m_next = jnp.maximum(m_prev, jnp.max(scores, axis=1)[:, None])
+        p = jnp.exp(scores - m_next[:, :1])
+        alpha = jnp.exp(m_prev - m_next)
+        l_scr[r0:r1] = alpha * l_prev + jnp.sum(p, axis=1)[:, None]
+        m_scr[r0:r1] = m_next
+        v = v_ref[0, c0:c1, :]
+        acc_scr[r0:r1] = acc_scr[r0:r1] * alpha[:, :1] + _dot(p.astype(v.dtype), v, ((1,), (0,)))
+
+    for lo, hi, bands in tiles:
+        @pl.when((s >= lo) & (s <= hi) & (iq >= s))
+        def _tile(bands=bands):
+            for b in bands:
+                _band(*b)
+
+    @pl.when(s == pl.num_programs(3) - 1)
+    def _store():
+        l = l_scr[...]
+        o_ref[0] = (acc_scr[...] / l[:, :1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_gqa(q, k, v, num_heads, sm_scale, block, window, geom):
+    b, n, _ = q.shape
+    d = k.shape[2]
+    group = num_heads // (k.shape[0] // b)
+    kv_heads = num_heads // group
+    n_blocks = n // block
+    steps = _gqa_steps(n_blocks, block, window)
+
+    def kv_map(b_, h, i, s):
+        return (b_ * kv_heads + h // group, jnp.maximum(i - s, 0), 0)
+
+    return pl.pallas_call(
+        functools.partial(_fwd_gqa_kernel, sm_scale=sm_scale, window=window, tiles=_gqa_tiles(n_blocks, block, window)),
+        name=_kernel_name("fwd", geom),
+        grid=(b, num_heads, n_blocks, steps),
+        in_specs=[
+            pl.BlockSpec((1, block, d), lambda b_, h, i, s: (b_, i, h)),
+            pl.BlockSpec((1, block, d), kv_map),
+            pl.BlockSpec((1, block, d), kv_map),
+        ],
+        out_specs=pl.BlockSpec((1, block, d), lambda b_, h, i, s: (b_, i, h)),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block, LANES), jnp.float32),
+            pltpu.VMEM((block, LANES), jnp.float32),
+            pltpu.VMEM((block, d), jnp.float32),
+        ],
+        compiler_params=_compiler_params("parallel", "parallel", "parallel", "arbitrary"),
+        interpret=_interpret_default(),
+    )(q, k, v)
+
+
+def _flash_gqa_no_backward(*_):
+    raise NotImplementedError(
+        "flash_attention_gqa is forward only (the prompt pass of a served decoder): no backward kernel is written"
+    )
+
+
+_flash_gqa.defvjp(_flash_gqa_no_backward, _flash_gqa_no_backward)
+
+
+def gqa_flash_supported(n: int, head_dim: int) -> bool:
+    """A head's columns are one block of the projection layout: whole lanes
+    on the chip (any width in interpret mode), and rows worth a kernel."""
+    return n >= LANES and (head_dim % LANES == 0 or _interpret_default())
+
+
+@jax.named_scope("flash_attention_gqa")
+def flash_attention_gqa(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    num_heads: int,
+    window: Optional[int] = None,
+    sm_scale: float = 1.0,
+    block: Optional[int] = None,
+) -> jnp.ndarray:
+    """Causal self-attention, grouped queries, an optional sliding window.
+
+    :param q: queries (B, N, H*D) in the projection layout, already rotated.
+    :param k: keys (B, Hkv, N, D), already rotated; ``Hkv`` divides ``H``
+        and query head h reads key-value head ``h // (H // Hkv)``.
+    :param v: values (B, Hkv, N, D).
+    :param window: position i sees ``i - window < j <= i`` (None: ``j <= i``).
+    :param block: None = the tuned hint, a value = an upper bound.
+    :returns: (B, N, H*D) in q's dtype. Forward only.
+    """
+    b, n, _ = q.shape
+    kv_heads, d = k.shape[1], k.shape[3]
+    if num_heads % kv_heads or q.shape[2] != num_heads * d or k.shape[2] != n:
+        raise ValueError(f"flash_attention_gqa: q {q.shape}, k {k.shape}, {num_heads} heads do not fit")
+    block = _choose_block(n, 1024 if block is None else block, exact=block is not None)
+    geom = _geometry(n, n) + ("" if window is None else f"_w{window}")
+    n_pad = _round_up(n, block)
+    reach = n_pad if window is None else window  # without a window every earlier block is seen
+    _TILE_PLANS[(geom, True)] = _make_window_plan(n, block, reach)
+    # padded kv slots lie after every real query: the causal mask hides them
+    qf = _pad_to(q, 1, block)
+    kf = _pad_to(k.reshape(b * kv_heads, n, d), 1, block)
+    vf = _pad_to(v.reshape(b * kv_heads, n, d), 1, block)
+    return _flash_gqa(qf, kf, vf, num_heads, sm_scale, block, reach, geom)[:, :n]

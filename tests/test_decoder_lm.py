@@ -45,6 +45,7 @@ from perceiver_io_tpu.ops.layernorm import rms_norm
 
 TOL = 2e-4  # float32 against float32, see the module docstring
 VOCAB = 96
+CUTS = moe._cuts(64, 32)  # how the expert layer cuts its work at these tiny widths (hidden 64, width 32)
 
 
 def tiny_config(**kw) -> DecoderLanguageModelConfig:
@@ -176,7 +177,7 @@ def test_a_prompt_pass_cut_into_chunks_is_the_uncut_forward(seed):
     Rows are independent, so the reference's forward over two of them holds
     the whole batch's last-position logits and cache rows to account."""
     b, n = 64, 256
-    assert b * n > decoder_lm._PREFILL_FFN_TOKENS > decoder_lm._PREFILL_ATTENTION_TOKENS >= moe._GROUPED_MIN_TOKENS
+    assert b * n > decoder_lm._PREFILL_FFN_TOKENS > decoder_lm._PREFILL_ATTENTION_TOKENS >= CUTS.grouped_min_tokens
     config = tiny_config(max_position_embeddings=n + 1)
     model, params, ids = seeded(config, seed, batch=b, n=n)
 
@@ -316,7 +317,7 @@ def test_the_shares_add_up_to_the_uncut_layer(seed, path):
     """Four chips with four experts each: what each share adds beyond the
     shared expert (which every chip computes alike, counted once) sums to
     the uncut reference's layer."""
-    config, x, params = moe_layer_and_weights(seed, moe._GROUPED_MIN_TOKENS if path == "grouped" else 48)
+    config, x, params = moe_layer_and_weights(seed, CUTS.grouped_min_tokens if path == "grouped" else 48)
     w = {"l/" + k: v for k, v in flat_dict(params["params"]).items()}
     whole = np.asarray(reference.experts(x, w, "l", reference_cfg(config), "float32"))
     shared = np.asarray(reference.swiglu(x, w["l/shared/w1"], w["l/shared/w3"], w["l/shared/w2"], "float32"))
@@ -342,8 +343,8 @@ def test_no_pair_is_dropped_under_a_skewed_routing(held):
     routing would send it and so more than two of the grouped path's passes,
     and serves them all; the share that holds neither gets none. Both agree
     with the reference."""
-    tokens = 2 * moe._GROUPED_MIN_TOKENS
-    assert 2 * moe._pass_rows(2 * tokens, 4 / 16) < 2 * tokens  # two pairs a token, all of them here
+    tokens = 2 * CUTS.grouped_min_tokens
+    assert 2 * moe._pass_rows(2 * tokens, 4 / 16, CUTS) < 2 * tokens  # two pairs a token, all of them here
     config, x, params = moe_layer_and_weights(0, tokens)
     start, n = held
     share = dataclasses.replace(config, n_held_experts=n, held_experts_start=start)

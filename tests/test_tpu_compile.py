@@ -242,9 +242,119 @@ def test_grouped_expert_product_lowers(one_chip, monkeypatch, rows, k, n):
     gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
     moe = importlib.import_module("perceiver_io_tpu.core.moe")
     monkeypatch.setattr(gm, "_interpret_default", lambda: False)
-    assert moe._pass_rows(8192 * 8, 16 / 256) == 1024 and moe._pass_rows(moe._GROUPED_MIN_TOKENS * 8, 16 / 256) == 256
+    cuts = moe._cuts(DSV3_HIDDEN, DSV3_EXPERT_WIDTH)
+    assert cuts == (384, 256, 1024)  # the values PR 28 measured at this geometry stay
+    assert moe._pass_rows(8192 * 8, 16 / 256, cuts) == 1024 and moe._pass_rows(cuts.grouped_min_tokens * 8, 16 / 256, cuts) == 256
     lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
     rhs = jax.ShapeDtypeStruct((DSV3_HELD, k, n), jnp.bfloat16, sharding=one_chip)
     sizes = jax.ShapeDtypeStruct((DSV3_HELD,), jnp.int32, sharding=one_chip)
-    text = _compile(lambda a, w, s: gm.grouped_matmul(a, w, s, tm=moe._ROW_TILE), lhs, rhs, sizes)
+    text = _compile(lambda a, w, s: gm.grouped_matmul(a, w, s, tm=cuts.row_tile), lhs, rhs, sizes)
     assert f"moe_experts_prefill_m{rows}_k{k}_n{n}" in text and "tpu_custom_call" in text
+
+
+# ------------------------------------------ Mellum 2: the windowed forward, the cell's generator, what stays the parent's
+
+
+def _canonical(text: str):
+    """A lowered program's text with the source locations stripped and every
+    serialized Mosaic body replaced by the hash of its module printed without
+    debug info: two trees give the same text iff they lower to the same
+    program, wherever their lines and checkouts lie. Returns the text and the
+    number of bodies found."""
+    import base64
+    import hashlib
+    import re
+
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    def body(match):
+        with ir.Context() as ctx:
+            tpu.register_dialect(ctx)
+            ctx.allow_unregistered_dialects = True  # the serialized module names its dialect ``stable_mosaic``
+            printed = ir.Module.parse(base64.b64decode(match.group(1))).operation.get_asm(enable_debug_info=False)
+        return "body: " + hashlib.sha256(printed.encode()).hexdigest()
+
+    text, n = re.subn(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
+    return re.sub(r"loc\(.*", "", text), n
+
+
+# sha256 of the canonical lowered text of two calls without a window, taken on
+# the parent commit (PR 31) and read again on this tree: PR 32 gave
+# ``tile_plan`` a window and the file a windowed forward, and a call without
+# one lowers to the parent's program (the four accepted cells' whole programs
+# were compared the same way before any chip time: PERF.md 6, PR 32). A PR
+# that means to change these kernels updates the hashes.
+UNWINDOWED_GOLDEN = {
+    "heads_major_causal": "fa11ab7986d9763ab6888566fb0ec5e8eb3db4dfbe0539feb8d1760a20997966",
+    "packed_causal_fwd_bwd": "5f0174ba637f05b3a4129548c87440e98f775340c1be894d1a535740f9a0c42e",
+}
+
+
+@pytest.mark.parametrize("call", sorted(UNWINDOWED_GOLDEN))
+def test_a_call_without_a_window_lowers_to_the_parents_program(one_chip, mosaic, call):
+    import hashlib
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    if call == "heads_major_causal":  # the expanded MLA prompt pass's call, two rows of four heads
+        fn = lambda q, k, v: fa.flash_attention(q, k, v, causal=True, sm_scale=0.1)  # noqa: E731
+        args = (sds(2, 4, 1024, 192), sds(2, 4, 1024, 192), sds(2, 4, 1024, 128))
+    else:  # the Perceiver AR cross-attention's call, forward and backward, kv cut to 2176
+        def fn(q, k, v):
+            loss = lambda q, k, v: fa.flash_attention_packed(q, k, v, 8, causal=True, sm_scale=0.125).astype(jnp.float32).sum()  # noqa: E731
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        args = (sds(2, 1024, 512), sds(2, 2176, 512), sds(2, 2176, 512))
+    with jax.default_matmul_precision("default"):
+        text, bodies = _canonical(jax.jit(fn).lower(*args).as_text())
+    assert bodies == (1 if call == "heads_major_causal" else 2)
+    assert hashlib.sha256(text.encode()).hexdigest() == UNWINDOWED_GOLDEN[call]
+
+
+MELLUM_HEADS, MELLUM_KV_HEADS, MELLUM_HEAD_DIM, MELLUM_WINDOW, MELLUM_PROMPT = 32, 4, 128, 1024, 8192
+
+
+@pytest.mark.parametrize("window", [MELLUM_WINDOW, None], ids=["window_layer", "full_layer"])
+def test_grouped_query_flash_forward_lowers(one_chip, mosaic, window):
+    """One 8192-token row of the prompt pass: 32 query heads in the projection
+    layout on 4 key-value heads, blocks of 1024, two kv blocks a q block under
+    the window and up to eight without."""
+    q = jax.ShapeDtypeStruct((1, MELLUM_PROMPT, MELLUM_HEADS * MELLUM_HEAD_DIM), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, MELLUM_KV_HEADS, MELLUM_PROMPT, MELLUM_HEAD_DIM), jnp.bfloat16, sharding=one_chip)
+    text = _compile(lambda q, k, v: fa.flash_attention_gqa(q, k, v, MELLUM_HEADS, window=window, sm_scale=0.088), q, kv, kv)
+    name = "flash_fwd_q8192_kv8192" + ("" if window is None else "_w1024")
+    assert "tpu_custom_call" in text and name in text
+    plan = fa.tile_plan(MELLUM_PROMPT, MELLUM_PROMPT, True, window=window or MELLUM_PROMPT)
+    assert plan.block_q == 1024 and plan.tiles_run == (600 if window else 2112)
+
+
+def test_the_mellum_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch):
+    """``mellum2-pp4-decode-b32`` as the benchmark builds it (the family's
+    model and generator at the cell's sizes: 3.795B bfloat16 parameters, 32
+    prompts of 8192 tokens, 256 new tokens, bfloat16 caches), compiled for a
+    described v5e: under the 16.9 GB the runtime offers with 2 GB to spare
+    (14.56 GB here reads 12.4 GB on the chip, PERF.md 6), both flash kernels
+    and the grouped expert kernels in it."""
+    import re
+
+    from benchmarks import run
+
+    gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
+    monkeypatch.setattr(gm, "_interpret_default", lambda: False)
+    cell = run.load_json("workloads", "mellum2-pp4-decode-b32")
+    family = importlib.import_module("benchmarks.families.mellum").Family(run.load_json("configs", cell["config"]))
+    p = cell["params"]
+    model = family.model()
+    shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), family.param_shapes(model))
+    ids = jax.ShapeDtypeStruct((p["batch_size"], p["prompt_len"]), jnp.int32, sharding=one_chip)
+    generate = family.generate_fn(model, p["num_latents"], p["new_tokens"], p["cache_dtype"])
+    with fa.default_flash(True), jax.default_matmul_precision("default"):
+        compiled = generate.lower(shapes, ids).compile()
+    m = compiled.memory_analysis()
+    assert 7.58e9 < m.argument_size_in_bytes < 7.60e9  # the weights and the prompts
+    total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    assert total < 14.9e9, f"{total / 1e9:.2f} GB"
+    text = compiled.as_text()
+    assert set(re.findall(r"flash_fwd_q\d+_kv\d+(?:_w\d+)?", text)) == {"flash_fwd_q8192_kv8192", "flash_fwd_q8192_kv8192_w1024"}
+    assert "moe_experts_prefill_m65536_k2304_n896" in text and "moe_experts_prefill_m65536_k896_n2304" in text

@@ -1,8 +1,15 @@
 """Time, on the chip and in one process, the ways through the held experts
-and through the absorbed decode attention at DeepSeek-V3's widths, so that
-``core/moe.py`` and ``core/mla.py`` keep one per path on a measurement:
+and through the decode attention at a decoder-only model's published widths,
+so that ``core/moe.py``, ``core/mla.py`` and ``core/gqa.py`` keep one per path
+on a measurement:
 
-    chiprun -- python tools/moe_ab.py [--only experts_layer] [--compile-only]
+    chiprun -- python tools/moe_ab.py [--geom dsv3|mellum] [--only experts_layer] [--compile-only]
+
+``--geom dsv3`` (the default) is DeepSeek-V3's share of PR 28 (16 held experts
+of 256, hidden 7168, width 2048; absorbed MLA); ``--geom mellum`` is Mellum 2
+(64 experts of width 896 all held, hidden 2304; grouped-query attention over a
+growing cache of 8448 slots and over a ring of 1024, batch 32 x 4 key-value
+heads, 8 query heads each).
 
 - the grouped product alone (8192 live rows of 16384, 16 experts, even and
   skewed group sizes): the Pallas kernel (``ops/grouped_matmul.py``) against
@@ -14,7 +21,12 @@ and through the absorbed decode attention at DeepSeek-V3's widths, so that
   the rows ``moe._pass_rows`` gives), for ``T`` from a decode step's 64 to a
   prompt chunk's 8192: where the two cross is ``moe._GROUPED_MIN_TOKENS``, the
   fastest tile ``moe._ROW_TILE``, the fastest rows a pass at 8192 tokens
-  ``moe._PASS_ROWS``;
+  ``moe._PASS_ROWS`` (since PR 32 the three are ``moe._cuts`` of the geometry);
+- grouped-query decode attention (``--geom mellum``): ``core/gqa.py``'s two
+  batched products against a Pallas kernel kept in this file, over both caches;
+  and the prompt pass's flash forward (``flash_attention_gqa``) on one
+  8192-token row, window and full, its edge tiles in bands or whole, blocks of
+  1024 or 512;
 - absorbed decode attention (batch 64, 128 heads, 1280 x 576 cache): XLA's two
   batched products against a Pallas kernel kept in this file (measured, not kept in the program).
 
@@ -39,6 +51,17 @@ LAYER_TOKENS = (64, 128, 256, 384, 512, 1024, 2048, 8192)
 LAYER_TILINGS = ((128, 0), (256, 0), (512, 0))
 # other rows a pass at a prompt chunk's tokens: the scatter-add of a pass has a sweet spot
 SHORT_PASSES = {8192: ((256, 512), (256, 768), (256, 1536), (256, 2048), (256, 3072), (256, 5120), (256, 8192))}
+GEOM = "dsv3"
+
+
+def set_geometry(name: str) -> None:
+    """``--geom mellum``: every expert held, a decode step of 32 tokens and a prompt chunk of 8192."""
+    global H, WIDTH, EXPERTS, ROUTED, TOP_K, LAYER_TOKENS, SHORT_PASSES, GEOM
+    GEOM = name
+    if name == "mellum":
+        H, WIDTH, EXPERTS, ROUTED, TOP_K = 2304, 896, 64, 64, 8
+        LAYER_TOKENS = (32, 256, 512, 8192)
+        SHORT_PASSES = {8192: ((256, 512), (256, 2048), (256, 4096), (256, 8192), (512, 8192), (256, 16384), (256, 32768), (256, 65536))}
 
 
 # ---------------------------------------------------------------------------
@@ -94,6 +117,47 @@ def mla_decode_attention(q_cat, rows, length, *, sm_scale: float):
     )(jnp.reshape(length, (1,)).astype(jnp.int32), q_cat.astype(rows.dtype), rows)
 
 
+# The Pallas decode attention measured for grouped queries and not kept
+# (PERF.md 6, PR 32): a grid step takes one key-value head of one row, holds
+# its slots' keys and values in VMEM and reads each once for the 8 query heads
+# of its group.
+
+
+def _gqa_kernel(length_ref, q_ref, k_ref, v_ref, out_ref, *, sm_scale: float):
+    q, k, v = q_ref[0], k_ref[0], v_ref[0]  # (group, D), (S, D), (S, D)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * sm_scale
+    slot = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(slot < length_ref[0], s, -jnp.inf)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    out_ref[0] = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale",))
+def gqa_decode_attention(q, k, v, length, *, sm_scale: float):
+    """``softmax(q . k) @ v``: ``q`` (B * Hkv, group, D) against ``k``, ``v``
+    (B * Hkv, slots, D), slots at or past ``length`` (a scalar) masked. (B * Hkv, group, D) float32."""
+    b, g, d = q.shape
+    s = k.shape[1]
+    return pl.pallas_call(
+        functools.partial(_gqa_kernel, sm_scale=sm_scale),
+        name=f"gqa_decode_g{g}_s{s}_d{d}",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, g, d), lambda i, n: (i, 0, 0)),
+                pl.BlockSpec((1, s, d), lambda i, n: (i, 0, 0)),
+                pl.BlockSpec((1, s, d), lambda i, n: (i, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, g, d), lambda i, n: (i, 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, g, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",), vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=False,  # this tool runs on the chip or compiles for one
+    )(jnp.reshape(length, (1,)).astype(jnp.int32), q.astype(k.dtype), k, v)
+
+
 def variants():
     import jax
     import jax.numpy as jnp
@@ -112,9 +176,9 @@ def variants():
 
     def grouped(tile, pass_rows):
         def run(x, local, weights, w1, w3, w2):
-            moe._ROW_TILE = tile  # read when the path is traced
-            rows = pass_rows or moe._pass_rows(local.size, EXPERTS / ROUTED)  # 0: the rows the program takes
-            return moe.experts_grouped(x, local, weights, w1, w3, w2, min(rows, -(-local.size // tile) * tile))[0]
+            cuts = moe._cuts(H, WIDTH)._replace(row_tile=tile)
+            rows = pass_rows or moe._pass_rows(local.size, EXPERTS / ROUTED, cuts)  # 0: the rows the program takes
+            return moe.experts_grouped(x, local, weights, w1, w3, w2, min(rows, -(-local.size // tile) * tile), tile)[0]
         return run
 
     def dense(x, local, weights, w1, w3, w2):
@@ -137,6 +201,42 @@ def variants():
         for tile, pass_rows in LAYER_TILINGS + SHORT_PASSES.get(t, ()):
             if t * TOP_K >= tile:
                 out[f"experts_layer/T{t}/grouped_tm{tile}_rows{pass_rows}"] = (grouped(tile, pass_rows), layer, "layer")
+    if GEOM == "mellum":
+        from perceiver_io_tpu.core.cache import KVCache
+        from perceiver_io_tpu.core.gqa import cached_decode_attention
+
+        out = {k: v for k, v in out.items() if "experts_layer" in k}
+        # the prompt pass's flash forward on one 8192-token row (an attention chunk of the cell): the edge
+        # tiles cut into bands of 256 rows (what ``_BAND_MAX_SHARE`` 0.75 chooses) against run whole, blocks of 1024 and 512
+        import importlib
+
+        fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
+
+        def flash(window, bands, block):
+            def run(q, k, v):
+                fa._BAND_MAX_SHARE = 0.75 if bands else 0.0  # read when the call is traced
+                try:
+                    return fa.flash_attention_gqa(q, k, v, 32, window=window, sm_scale=128 ** -0.5, block=block)
+                finally:
+                    fa._BAND_MAX_SHARE = 0.75
+            return run
+
+        fq = jax.ShapeDtypeStruct((1, 8192, 32 * 128), bf)
+        fkv = jax.ShapeDtypeStruct((1, 4, 8192, 128), bf)
+        for kind, window in (("window", 1024), ("full", None)):
+            for bands in (True, False):
+                for block in (1024, 512):
+                    name = f"gqa_prefill/{kind}/{'bands' if bands else 'whole'}_block{block}"
+                    out[name] = (flash(window, bands, block), (fq, fkv, fkv), "gqa")
+        q = jax.ShapeDtypeStruct((32 * 4, 8, 128), bf)
+        for kind, slots, length in (("full", 8448, 8320), ("window", 1024, 8320)):
+            kv = jax.ShapeDtypeStruct((32 * 4, slots, 128), bf)
+            n = jnp.asarray(length, jnp.int32)
+            out[f"gqa_decode/{kind}/xla"] = (
+                lambda q, k, v, n=n: cached_decode_attention(q, KVCache(k=k, v=v, length=n), 128 ** -0.5), (q, kv, kv), "gqa")
+            out[f"gqa_decode/{kind}/pallas"] = (
+                lambda q, k, v, n=n: gqa_decode_attention(q, k, v, n, sm_scale=128 ** -0.5), (q, kv, kv), "gqa")
+        return out
     q = jax.ShapeDtypeStruct((64, 128, 576), bf)
     cache = jax.ShapeDtypeStruct((64, 1280, 576), bf)
     scale = 192 ** -0.5
@@ -152,6 +252,8 @@ def group_sizes(skew: bool):
     import numpy as np
 
     live = 8192
+    if EXPERTS != 16:
+        return np.full((EXPERTS,), live // EXPERTS, np.int32)
     if skew:
         s = np.array([3000, 40, 900, 0, 512, 511, 513, 100, 1, 700, 300, 200, 200, 115, 50, 50])
         return (s * live // s.sum()).astype(np.int32)
@@ -159,7 +261,7 @@ def group_sizes(skew: bool):
 
 
 def routing(tokens: int):
-    """``local`` (T, 8): each token's 8 distinct experts of 256, uniform; the held ones (0 to 15) keep their index, the rest read 16."""
+    """``local`` (T, 8): each token's 8 distinct experts of those routed over, uniform; the held ones keep their index, the rest read ``EXPERTS``."""
     import numpy as np
 
     rng = np.random.default_rng(tokens)
@@ -171,8 +273,10 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--compile-only", action="store_true")
     p.add_argument("--iters", type=int, default=5)
+    p.add_argument("--geom", default="dsv3", choices=("dsv3", "mellum"))
     p.add_argument("--only", default="", help="substrings of variant names, comma-separated; a variant runs if it holds one")
     args = p.parse_args(argv)
+    set_geometry(args.geom)
     wanted = lambda name: any(part in name for part in args.only.split(","))  # noqa: E731
     import jax
     import jax.numpy as jnp
@@ -186,6 +290,7 @@ def main(argv=None) -> int:
         from jax.sharding import SingleDeviceSharding
 
         importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")._interpret_default = lambda: False
+        importlib.import_module("perceiver_io_tpu.ops.flash_attention")._interpret_default = lambda: False
         one = SingleDeviceSharding(topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])
         for name, (fn, shapes, _) in variants().items():
             if wanted(name):
@@ -240,7 +345,7 @@ def main(argv=None) -> int:
                 print(f"{label}: FAILED {results[label]['error']}", flush=True)
             del operands
     os.makedirs("chiprun_out", exist_ok=True)
-    with open("chiprun_out/moe_ab.json", "w") as f:
+    with open("chiprun_out/moe_ab.json" if GEOM == "dsv3" else f"chiprun_out/moe_ab_{GEOM}.json", "w") as f:
         json.dump(results, f, indent=1)
     return 0
 
