@@ -25,19 +25,33 @@ kept on a chip measurement (``tools/moe_ab.py``; PERF.md 6, PR 28):
 
 ``grouped`` (a prompt pass, or a step of 384 tokens and more)
     the routed pairs are sorted by expert, and the pairs that fall to held
-    experts go, a pass of at most ``_cuts(...).pass_rows`` rows at a time, through a gather, a
-    grouped matrix product (``ops/grouped_matmul.py``) and a scatter-add back
-    to their tokens; as many passes as the routing sent pairs here: no pair
-    is dropped however skewed the routing is, and the work follows the pairs
-    that are really here. ``jax.lax.ragged_dot`` in the kernel's place
-    measured 1.5x slower.
+    experts go, a pass of at most ``_cuts(...).pass_rows`` rows at a time, through a gather
+    and a grouped matrix product (``ops/grouped_matmul.py``); as many passes
+    as the routing sent pairs here: no pair is dropped however skewed the
+    routing is, and the work follows the pairs that are really here.
+    ``jax.lax.ragged_dot`` in the kernel's place measured 1.5x slower. How a
+    pass's rows get back to their tokens (``grouped_combine``) is a fact of
+    the configuration, the share of the experts the layer holds:
+
+    *every expert held* (Mellum 2): the sort is a permutation of all ``T * k``
+    pairs, so the passes leave their rows in the kernel's dtype in sorted
+    order, the inverse permutation reads pair ``(t, j)`` back, and a token is
+    the float32 sum of its ``k`` weighted rows: a row gather and one fused
+    reduction where the scatter-add of the same 65 536 rows of 2304 channels
+    was the prompt pass's largest single operation (PERF.md 6, PR 33).
+
+    *a share held* (DeepSeek-V3, 16 of 256): a token has 0 to ``k`` local
+    pairs, about a sixteenth of all; only those are moved, weighted in
+    float32 and scatter-added into the tokens' buffer, rows past the last
+    local pair adding zeros to token 0. The inverse gather would move all
+    ``T * k`` rows to use that sixteenth.
 
 ``dense`` (a decode step: under 384 tokens)
     every held expert on every token, weighted (zero where not routed). Up to
     the ridge (about 240 tokens an expert layer's worth of weights) a step is
     bound by reading the experts' weights, and this path reads all of them
     whatever the step hits: 1.95 ms at 64 tokens, 2.06 at 256, against 2.30
-    and 2.43 for the grouped path with its sort, gather and scatter-add.
+    and 2.43 for the grouped path with its sort, gather and combine.
     Inside the cell's generator, where the sort overlaps, the grouped path on
     a step's pairs was 5.8% faster end to end at batch 64 (it reads the 87%
     of experts a step hits) and its speed followed the seed's tokens (0.7%
@@ -75,19 +89,23 @@ class _Cuts(NamedTuple):
 # to 11% faster from 384 to 512 tokens. Rows a pass: 8192 tokens with 4096
 # pairs here take 10.0 ms in passes of 1024 rows (9.5 to 9.6 in passes of 512
 # or 768) against 14.2 in one of 5120 and 26 in passes of 1536 or 2048 (XLA's
-# scatter-add into 8192 rows is slow past 1024 updates), and a layer that a
-# seed's routing sends a quarter more pairs costs a pass of 2 ms more, not a
-# second sweep of 8.6.
+# scatter-add into 8192 rows is slow past 1024 updates: this geometry holds a
+# share of the experts and keeps the scatter-add, so the cliff is its own), and
+# a layer that a seed's routing sends a quarter more pairs costs a pass of 2 ms
+# more, not a second sweep of 8.6.
 _WIDE_EXPERTS = _Cuts(grouped_min_tokens=384, row_tile=256, pass_rows=1024)
 # 64 held experts of width 896, hidden 2304 (Mellum 2, every expert held, 8
-# pairs a token all of them here). Dense and grouped cross between 256 tokens
-# (dense 1.17 ms, grouped 1.50) and 512 (2.24 against 1.98); a decode step's 32
-# tokens take 1.08 ms dense, 1.09 to 1.29 grouped. Row tile: 256 and 512 within
-# 1% at a prompt chunk's 8192 tokens (128 is 8% slower). Rows a pass: the
-# 65 536 pairs of such a chunk take 17.4 ms in one pass against 19.5 in two of
-# 32 768 rows, 19.4 in passes of 16 384, 23.2 of 8192, 28.5 of 1024 and 42.7
-# of 2048: the scatter-add's cliff past 1024 updates is there at rows of 2304
-# channels too, and beyond it fewer passes win, so a chunk takes one.
+# pairs a token all of them here: the grouped path combines by the gather).
+# Dense and grouped cross between 256 tokens (dense 1.17 ms, grouped 1.50) and
+# 512 (2.24 against 1.98); a decode step's 32 tokens take 1.08 ms dense, 1.09 to
+# 1.29 grouped. Row tile: 256 and 512 within 1.5% at a prompt chunk's 8192
+# tokens (128 was 8% slower). Rows a pass were set under the scatter-add (PR
+# 32: the 65 536 pairs of such a chunk 17.4 ms in one pass, 19.5 in two, 23.2
+# in passes of 8192, 28.5 of 1024 and 42.7 of 2048, the cliff past 1024
+# updates, which describes the share-held side alone now). Under the gather
+# (PR 33) one pass takes 13.76 ms, two of 32 768 rows 13.36, passes of 16 384
+# 14.97 and of 8192 14.76: no cliff, and within 3% the passes do not matter;
+# one pass stays, whose body needs no loop (PERF.md 7).
 _SMALL_EXPERTS = _Cuts(grouped_min_tokens=384, row_tile=256, pass_rows=65536)
 # Fewer tokens than fill a pass take one pass of the pairs an even routing
 # sends here and a quarter more (at 2048 tokens of the wide geometry that would
@@ -165,15 +183,25 @@ def experts_dense(x, combine, w1, w3, w2):
     return jnp.einsum("gti,gih->th", a.astype(x.dtype), w2, preferred_element_type=jnp.float32)
 
 
-def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int, row_tile: int):
+def grouped_combine(n_held: int, n_routed: int) -> str:
+    """How the grouped path's rows get back to their tokens: ``"gather"``
+    where the layer holds every expert (a token's ``k`` pairs are all here, so
+    the sorted order is a permutation of all pairs and its inverse finds
+    them), ``"scatter"`` where it holds a share (a token has 0 to ``k`` local
+    pairs: only those are moved)."""
+    return "gather" if n_held == n_routed else "scatter"
+
+
+def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int, row_tile: int, combine: str):
     """The held experts on the pairs routed to them, sorted by expert.
 
     ``x`` (T, h); ``local`` (T, k) int32, a pair's held-expert index or ``G``
-    where its expert is not held; ``weights`` (T, k) float32. Returns the
-    sum over a token's local pairs (T, h) float32, the rows a pass left
-    unserved (a scalar that is zero: the loop takes passes of ``pass_rows``
-    rows, a multiple of ``row_tile``, the grouped kernel's, until none is
-    left) and the number of passes it took."""
+    where its expert is not held; ``weights`` (T, k) float32; ``combine`` is
+    :func:`grouped_combine` of the layer (``"gather"`` needs every pair
+    local). Returns the sum over a token's local pairs (T, h) float32, the
+    rows a pass left unserved (a scalar that is zero: the passes are of
+    ``pass_rows`` rows, a multiple of ``row_tile``, the grouped kernel's, and
+    as many as serve every local pair) and the number of passes it took."""
     t, k = local.shape
     g = w1.shape[0]
     flat = local.reshape(-1)
@@ -181,25 +209,44 @@ def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int, row_tile: int
     sizes = jnp.zeros((g + 1,), jnp.int32).at[flat].add(1)[:g]
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
     n_local = offsets[-1]
-    order = jnp.concatenate([order, jnp.zeros((pass_rows,), jnp.int32)])  # a pass may read past the end
-    w_flat = weights.reshape(-1)
+    padded = jnp.concatenate([order, jnp.zeros((pass_rows,), jnp.int32)])  # a pass may read past the end
 
-    def one_pass(p, y):
+    def one_pass(p):
+        """The kernels' rows for pass ``p``'s sorted pairs; also the pairs, which rows are pairs at all, and their tokens."""
         lo = p * pass_rows
         live = (lo + jnp.arange(pass_rows, dtype=jnp.int32)) < n_local
-        pair = lax.dynamic_slice(order, (lo,), (pass_rows,))
-        # a row past the last local pair reads token 0 and adds zeros to it: the scatter-add costs by the distinct rows it touches
-        token = jnp.where(live, pair // k, 0)
+        pair = lax.dynamic_slice(padded, (lo,), (pass_rows,))
+        token = jnp.where(live, pair // k, 0)  # a row past the last local pair reads token 0
         in_pass = jnp.clip(offsets, lo, lo + pass_rows) - lo
         group_sizes = in_pass[1:] - in_pass[:-1]
         xs = x[token]
         mm = lambda a, w: grouped_matmul(a, w, group_sizes, tm=row_tile)  # noqa: E731
-        ys = mm(_silu_gate(mm(xs, w1), mm(xs, w3), x.dtype), w2)
+        return mm(_silu_gate(mm(xs, w1), mm(xs, w3), x.dtype), w2), pair, live, token
+
+    if combine == "gather":
+        # every pair is local: the passes are counted at trace time, their rows stay in the kernel's dtype in
+        # sorted order, and pair (t, j) is read back from row ``rank[t * k + j]``; no row past the last pair is read
+        n_pass = -(-t * k // pass_rows)
+        if n_pass == 1:
+            sorted_rows = one_pass(0)[0]
+        else:
+            sorted_rows = lax.fori_loop(
+                0, n_pass, lambda p, rows: lax.dynamic_update_slice(rows, one_pass(p)[0], (p * pass_rows, 0)),
+                jnp.zeros((n_pass * pass_rows, x.shape[-1]), x.dtype))
+        rank = jnp.argsort(order).astype(jnp.int32)
+        y = (sorted_rows[rank].reshape(t, k, -1).astype(jnp.float32) * weights[:, :, None]).sum(axis=1)
+        return y, jnp.zeros((), jnp.int32), jnp.asarray(n_pass, jnp.int32)
+
+    w_flat = weights.reshape(-1)
+
+    def add_pass(p, y):
+        ys, pair, live, token = one_pass(p)
+        # dead rows add zeros to token 0: the scatter-add costs by the distinct rows it touches
         ys = jnp.where(live[:, None], ys.astype(jnp.float32) * w_flat[pair][:, None], 0.0)
         return y.at[token].add(ys)
 
     n_pass = (n_local + pass_rows - 1) // pass_rows
-    y = lax.fori_loop(0, n_pass, one_pass, jnp.zeros((t, x.shape[-1]), jnp.float32))
+    y = lax.fori_loop(0, n_pass, add_pass, jnp.zeros((t, x.shape[-1]), jnp.float32))
     return y, n_local - jnp.minimum(n_pass * pass_rows, n_local), n_pass
 
 
@@ -269,18 +316,22 @@ class MoELayer(nn.Module):
         with jax.named_scope("moe/experts"):
             unserved = passes = jnp.zeros((), jnp.int32)
             cuts = _cuts(h, width)
+            back = None  # how a grouped pass's rows get back to their tokens; the dense path has no such step
             if t >= cuts.grouped_min_tokens:
                 rows = _pass_rows(t * c.num_experts_per_tok, g / c.n_routed_experts, cuts)
-                y, unserved, passes = experts_grouped(x, local, weights, w1, w3, w2, rows, cuts.row_tile)
+                back = grouped_combine(g, c.n_routed_experts)
+                y, unserved, passes = experts_grouped(x, local, weights, w1, w3, w2, rows, cuts.row_tile, back)
             else:
                 combine = (jax.nn.one_hot(local, g, dtype=jnp.float32) * weights[:, :, None]).sum(axis=1)
                 y = experts_dense(x, combine, w1, w3, w2)
 
         if probes.active():
             load = jnp.zeros((g + 1,), jnp.int32).at[local.reshape(-1)].add(1)[:g]
+            pairs_local = load.sum()
             probes.tap("moe.load", {
                 "pairs_routed": jnp.asarray(t * c.num_experts_per_tok, jnp.int32),
-                "pairs_local": load.sum(),
+                "pairs_local": pairs_local,
+                "pairs_gathered": pairs_local if back == "gather" else jnp.zeros((), jnp.int32),
                 "pairs_dropped": unserved.astype(jnp.int32),
                 "passes": passes.astype(jnp.int32),
                 "expert_load_max": load.max(),

@@ -1611,6 +1611,7 @@ def make_instrumented_generate_fn(
     moe_taps = probes and "moe.*" in decoder.tap_scopes
     m_moe_routed = registry.counter("moe_pairs_routed_total") if moe_taps else None
     m_moe_local = registry.counter("moe_pairs_local_total") if moe_taps else None
+    m_moe_gathered = registry.counter("moe_pairs_gathered_total") if moe_taps else None
     m_moe_dropped = registry.counter("moe_pairs_dropped_total") if moe_taps else None
     m_moe_load = registry.gauge("moe_expert_load_max") if moe_taps else None
     tracer = obs_trace.Tracer(events, flush_every=64) if events is not None else None
@@ -1706,11 +1707,13 @@ def make_instrumented_generate_fn(
                     ),
                 }
                 if moe_taps:
-                    routed, local, dropped = (
-                        sum(int(h[k]) for h in hh) for k in ("pairs_routed", "pairs_local", "pairs_dropped")
+                    routed, local, gathered, dropped = (
+                        sum(int(h[k]) for h in hh)
+                        for k in ("pairs_routed", "pairs_local", "pairs_gathered", "pairs_dropped")
                     )
                     m_moe_routed.inc(routed)
                     m_moe_local.inc(local)
+                    m_moe_gathered.inc(gathered)
                     m_moe_dropped.inc(dropped)
                     m_moe_load.set(max(int(h["expert_load_max"]) for h in hh))
                     health_row["moe_local_share"] = round(local / max(routed, 1), 6)

@@ -39,7 +39,7 @@ from perceiver_io_tpu.core.cache import (
 )
 from perceiver_io_tpu.core.gqa import GroupedQueryAttention
 from perceiver_io_tpu.core.mla import MultiHeadLatentAttention
-from perceiver_io_tpu.core.moe import MoELayer, SwiGLU
+from perceiver_io_tpu.core.moe import MoELayer, SwiGLU, grouped_combine
 from perceiver_io_tpu.obs import probes
 from perceiver_io_tpu.ops.layernorm import RMSNorm
 
@@ -368,6 +368,8 @@ class _Decoder:
         """The caches' geometry for a ``compile`` event row."""
         c = self.model.config
         itemsize = jnp.dtype(cache_dtype).itemsize
+        # how a prompt chunk's expert rows get back to their tokens (a step of few tokens takes the dense path)
+        moe = {"moe_combine": grouped_combine(c.n_held_experts, c.n_routed_experts)}
         if c.layer_types is not None:
             row_bytes = 2 * c.num_key_value_heads * c.head_dim * itemsize  # a token's keys and values in one layer
             n_window = c.layer_types.count("sliding_attention")
@@ -378,6 +380,7 @@ class _Decoder:
                 "kv_cache_full_bytes": batch * (prompt_len + max_new_tokens) * row_bytes * n_full,
                 "kv_cache_window_bytes": batch * c.sliding_window * row_bytes * n_window,
                 "kv_cache_window_rows": c.sliding_window,
+                **moe,
             }
         row_bytes = (c.kv_lora_rank + c.qk_rope_head_dim) * itemsize
         return {
@@ -385,6 +388,7 @@ class _Decoder:
             "latent_cache_capacity": prompt_len + max_new_tokens,
             "latent_cache_layers": c.num_hidden_layers,
             "latent_cache_bytes": batch * (prompt_len + max_new_tokens) * row_bytes * c.num_hidden_layers,
+            **moe,
         }
 
 

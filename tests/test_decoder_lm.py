@@ -191,6 +191,7 @@ def test_a_prompt_pass_cut_into_chunks_is_the_uncut_forward(seed):
     for load in stats.values():
         assert int(load["pairs_routed"]) == 2 * b * n and int(load["pairs_dropped"]) == 0
         assert 0 < int(load["pairs_local"]) < 2 * b * n and int(load["expert_load_max"]) >= b * n // 16
+        assert int(load["pairs_gathered"]) == 0  # a share of the experts: the grouped path's rows are scatter-added
     rows = np.array([0, b - 1])
     want = np.asarray(reference.logits(flat_dict(params), ids[rows], reference_cfg(config), last=1))[:, 0]
     np.testing.assert_allclose(np.asarray(logits)[rows], want, atol=TOL, rtol=0)
@@ -366,6 +367,37 @@ def test_no_pair_is_dropped_under_a_skewed_routing(held):
     np.testing.assert_allclose(np.asarray(grouped), np.asarray(want), atol=TOL, rtol=0)
 
 
+def test_a_share_of_the_experts_keeps_the_scatter_add():
+    """The rule between the grouped path's two combines is the
+    configuration's own fact: a layer that holds 4 of 16 experts has 0 to 2
+    local pairs a token, moves only those, and adds them into the tokens'
+    buffer as before (``pairs_gathered`` 0, a float32 scatter-add in its
+    jaxpr, ``moe_combine`` ``"scatter"`` in the ``compile`` row); the whole
+    layer, every expert held, gathers all of them and has no such scatter."""
+    tokens = CUTS.grouped_min_tokens
+    config, x, params = moe_layer_and_weights(0, tokens)
+    adds_rows = lambda c, p: bool(re.search(  # noqa: E731
+        rf"f32\[{tokens},64\] = scatter-add", str(jax.make_jaxpr(moe.MoELayer(c).apply)({"params": p}, x))))
+
+    def tapped(c, p):
+        with probes.collecting(probes.ProbeConfig(scopes=("moe.*",))) as col:
+            moe.MoELayer(c).apply({"params": p}, x)
+            (load,) = col.stats.values()
+            return load
+
+    share = dataclasses.replace(config, n_held_experts=4, held_experts_start=4)
+    p = {k: v[4:8] if k.startswith("experts_") else v for k, v in params["params"].items()}
+    load = tapped(share, p)
+    assert int(load["pairs_gathered"]) == 0 < int(load["pairs_local"]) < int(load["pairs_routed"]) == 2 * tokens
+    assert adds_rows(share, p) and moe.grouped_combine(4, 16) == "scatter"
+    load = tapped(config, params["params"])
+    assert int(load["pairs_gathered"]) == int(load["pairs_local"]) == int(load["pairs_routed"]) == 2 * tokens
+    assert not adds_rows(config, params["params"]) and moe.grouped_combine(16, 16) == "gather"
+    row = lambda c: generation._decoder_of(DecoderLanguageModel(c)).compile_row(4, 8, 3, jnp.float32)  # noqa: E731
+    assert row(tiny_config())["moe_combine"] == "scatter"
+    assert row(tiny_config(n_held_experts=16, held_experts_start=0))["moe_combine"] == "gather"
+
+
 GROUP_SIZES = [[3, 0, 9, 1, 0, 7, 0, 0], [8, 8, 8, 8, 0, 0, 0, 0], [0, 0, 0, 0, 0, 0, 0, 32], [1, 1, 1, 1, 1, 1, 1, 1],
                [0, 0, 0, 0, 0, 0, 0, 0], [5, 11, 0, 0, 2, 0, 14, 0]]
 
@@ -418,11 +450,13 @@ def test_scopes_and_taps_reach_the_compiled_programs_and_the_registry(tmp_path):
     assert snap["moe_pairs_routed_total"] == 2 * 2 * (32 + 4 + 4)
     assert 0 < snap["moe_pairs_local_total"] < snap["moe_pairs_routed_total"]
     assert snap["moe_pairs_dropped_total"] == 0 and snap["moe_expert_load_max"] >= 1
+    assert snap["moe_pairs_gathered_total"] == 0
     import json
 
     rows = [json.loads(line) for line in open(tmp_path / "events.jsonl")]
     compiles = [r for r in rows if r.get("event") == "compile" and "latent_cache_row_bytes" in r]
     assert compiles and compiles[0]["latent_cache_row_bytes"] == 24 * 4 and compiles[0]["latent_cache_capacity"] == 11
+    assert compiles[0]["moe_combine"] == "scatter"
     request = [r for r in rows if r.get("event") == "request"][-1]
     assert request["moe_pairs_dropped"] == 0 and request["moe_local_share"] == pytest.approx(
         snap["moe_pairs_local_total"] / snap["moe_pairs_routed_total"], abs=1e-6)

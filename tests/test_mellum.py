@@ -15,6 +15,7 @@ the ring's slots, and the decode steps wrap the ring."""
 
 import dataclasses
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -179,6 +180,43 @@ def test_the_generator_runs_it_through_the_same_interface_as_the_other_decoder(t
     assert compiled["kv_cache_window_rows"] == WINDOW
     assert compiled["kv_cache_full_bytes"] == 4 * (13 + 6) * row_bytes
     assert compiled["kv_cache_window_bytes"] == 4 * WINDOW * row_bytes * 3
+    # 52 prompt tokens are under the 384 of the grouped path: nothing was gathered, whatever a longer prompt would take
+    assert compiled["moe_combine"] == "gather" and snap["moe_pairs_gathered_total"] == 0
+
+
+def test_the_gather_combine_is_counted_where_it_ran(tmp_path):
+    """32 rows of 13 tokens are 416 prompt tokens, over the 384 from which the
+    experts take the grouped path, and a step's 32 are under it: every pair of
+    the prompt pass comes back through the gather (``moe_pairs_gathered_total``
+    over the prompt's share of ``moe_pairs_local_total`` is 1.0), no pair of a
+    step does, and the ``compile`` row says which combine the configuration takes."""
+    import json
+
+    from perceiver_io_tpu.generation import make_instrumented_generate_fn
+    from perceiver_io_tpu.obs.events import EventLog
+
+    model, params, ids = seeded(tiny_config(), 4, batch=32, n=13)
+    probed = make_instrumented_generate_fn(model, config=GenerationConfig(max_new_tokens=3), events=EventLog(str(tmp_path)), probes=True)
+    _, stats = probed(params, ids)
+    assert stats.outcome == "ok"
+    counters = probed.registry.snapshot()["counters"]
+    prompt_pairs, step_pairs = 4 * 2 * 32 * 13, 4 * 2 * 32 * 2  # 4 expert layers, 2 experts a token, 2 steps
+    assert counters["moe_pairs_gathered_total"] == prompt_pairs
+    assert counters["moe_pairs_local_total"] == counters["moe_pairs_routed_total"] == prompt_pairs + step_pairs
+    rows = [json.loads(line) for line in open(tmp_path / "events.jsonl")]
+    assert [r["moe_combine"] for r in rows if r.get("event") == "compile" and "moe_combine" in r][:1] == ["gather"]
+
+
+def test_the_benchmarks_two_configurations_sit_on_either_side_of_the_rule():
+    """``mellum2-12b-pp4`` holds its 64 experts and gathers; ``deepseek-v3-ep16`` holds 16 of 256 and keeps the scatter-add."""
+    from benchmarks import run
+
+    def combine(name):
+        config = run.load_json("configs", name)
+        model = importlib.import_module(f"benchmarks.families.{config['family']}").Family(config).model()
+        return generation._decoder_of(model).compile_row(32, 8192, 256, jnp.bfloat16)["moe_combine"]
+
+    assert combine("mellum2-12b-pp4") == "gather" and combine("deepseek-v3-ep16") == "scatter"
 
 
 def test_the_prompt_pass_through_the_flash_kernels_matches_the_reference():
@@ -224,12 +262,37 @@ def test_a_softmax_routed_layer_has_no_bias_and_no_shared_expert():
         moe.MoELayer(tiny_config(scoring_func="tanh")).init(jax.random.PRNGKey(1), x)
 
 
-@pytest.mark.parametrize("tokens", [48, 768], ids=["dense_path", "grouped_path"])
-def test_both_expert_paths_serve_every_pair_when_all_experts_are_held(tokens):
+# (tokens, rows a pass or None for the shipped cut, every token to experts 0 and 1): 768 tokens send 1536 pairs, six row
+# tiles of 256 in one pass of 2048 rows; 777 send 1554, so the seventh tile holds 18 pairs and 238 dead rows; passes of
+# 512 rows cut them into three (768 tokens) or four, the last one nearly empty (777); under the skew two experts get 768
+# rows each, across the passes' edges
+EXPERT_PATH_CASES = {
+    "dense_path": (48, None, False),
+    "grouped_path": (768, None, False),
+    "dead_rows_in_the_last_tile": (777, None, False),
+    "three_passes": (768, 512, False),
+    "four_passes_the_last_nearly_empty": (777, 512, False),
+    "skewed_routing": (768, None, True),
+    "skewed_routing_in_three_passes": (768, 512, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(EXPERT_PATH_CASES))
+def test_both_expert_paths_serve_every_pair_when_all_experts_are_held(case, monkeypatch):
+    """Every expert held: the grouped path brings its rows back by the inverse
+    of the sort and a sum over a token's pairs (``pairs_gathered`` counts them
+    all), the dense path has no such step (0)."""
+    tokens, pass_rows, skewed = EXPERT_PATH_CASES[case]
     config = tiny_config()
-    assert (tokens >= moe._cuts(64, 32).grouped_min_tokens) == (tokens == 768)
+    grouped = tokens >= moe._cuts(64, 32).grouped_min_tokens
+    assert grouped == (case != "dense_path")
+    if pass_rows:
+        monkeypatch.setattr(moe, "_SMALL_EXPERTS", moe._SMALL_EXPERTS._replace(pass_rows=pass_rows))
     x = jax.random.normal(jax.random.PRNGKey(5), (tokens, 64))
     params = moe.MoELayer(config).init(jax.random.PRNGKey(6), x)
+    if skewed:  # one channel the same in every token, and a router that reads it for experts 0 and 1
+        x = x.at[:, 0].set(5.0)
+        params = {"params": {**params["params"], "gate": params["params"]["gate"].at[0, :2].set(10.0)}}
 
     def tapped(p, x):
         with probes.collecting(probes.ProbeConfig(scopes=("moe.*",))) as col:
@@ -238,6 +301,12 @@ def test_both_expert_paths_serve_every_pair_when_all_experts_are_held(tokens):
     y, stats = jax.jit(tapped)(params, x)
     (load,) = stats.values()
     assert int(load["pairs_local"]) == int(load["pairs_routed"]) == 2 * tokens and int(load["pairs_dropped"]) == 0
+    assert int(load["pairs_gathered"]) == (2 * tokens if grouped else 0)
+    assert int(load["expert_load_max"]) == tokens or not skewed
+    if grouped:
+        assert int(load["passes"]) == -(-2 * tokens // (pass_rows or 2048))
+        # no row is added into the tokens' buffer (the integer scatter-adds left count group sizes)
+        assert not re.search(r"f32\[\d+,64\] = scatter-add", str(jax.make_jaxpr(moe.MoELayer(config).apply)(params, x)))
     w = {"l/" + k: v for k, v in flat_dict(params["params"]).items()}
     np.testing.assert_allclose(np.asarray(y), np.asarray(reference.experts(x, w, "l", reference_cfg(config), "float32")),
                                atol=TOL, rtol=0)

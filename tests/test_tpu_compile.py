@@ -334,7 +334,7 @@ def test_the_mellum_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch)
     model and generator at the cell's sizes: 3.795B bfloat16 parameters, 32
     prompts of 8192 tokens, 256 new tokens, bfloat16 caches), compiled for a
     described v5e: under the 16.9 GB the runtime offers with 2 GB to spare
-    (14.56 GB here reads 12.4 GB on the chip, PERF.md 6), both flash kernels
+    (13.24 GB here reads 11.08 GB on the chip, PERF.md 6, PR 33), both flash kernels
     and the grouped expert kernels in it."""
     import re
 
@@ -358,3 +358,6 @@ def test_the_mellum_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch)
     text = compiled.as_text()
     assert set(re.findall(r"flash_fwd_q\d+_kv\d+(?:_w\d+)?", text)) == {"flash_fwd_q8192_kv8192", "flash_fwd_q8192_kv8192_w1024"}
     assert "moe_experts_prefill_m65536_k2304_n896" in text and "moe_experts_prefill_m65536_k896_n2304" in text
+    # every expert is held: the expert rows come back by a gather, and no layer scatter-adds into the chunk's tokens (PR 33)
+    assert not re.search(r"f32\[8192,2304\]\{[^}]*\} scatter\(", text)
+    assert len(re.findall(r"bf16\[65536,2304\]\{[^}]*\} gather\(", text)) == 2 * 8  # ``x[token]`` and the combine, a layer

@@ -15,13 +15,17 @@ heads, 8 query heads each).
   skewed group sizes): the Pallas kernel (``ops/grouped_matmul.py``) against
   ``jax.lax.ragged_dot``;
 - the expert layer's two paths as the program runs them, whole (sort, gather,
-  kernels, scatter-add against one weighted einsum), on ``T`` tokens routed
+  kernels, combine against one weighted einsum), on ``T`` tokens routed
   uniformly over 256 experts of which 16 are held: ``moe.experts_dense``
   against ``moe.experts_grouped`` at several row tiles and rows a pass (``rows0``:
   the rows ``moe._pass_rows`` gives), for ``T`` from a decode step's 64 to a
   prompt chunk's 8192: where the two cross is ``moe._GROUPED_MIN_TOKENS``, the
   fastest tile ``moe._ROW_TILE``, the fastest rows a pass at 8192 tokens
-  ``moe._PASS_ROWS`` (since PR 32 the three are ``moe._cuts`` of the geometry);
+  ``moe._PASS_ROWS`` (since PR 32 the three are ``moe._cuts`` of the geometry).
+  The grouped path's combine is the geometry's own (``moe.grouped_combine``:
+  the scatter-add at ``dsv3``, the inverse-permutation gather at ``mellum``,
+  where every expert is held); at ``mellum``'s prompt chunk each variant runs
+  both, ``.../gather`` beside ``.../scatter`` (PR 33);
 - grouped-query decode attention (``--geom mellum``): ``core/gqa.py``'s two
   batched products against a Pallas kernel kept in this file, over both caches;
   and the prompt pass's flash forward (``flash_attention_gqa``) on one
@@ -174,11 +178,12 @@ def variants():
             return mm(moe._silu_gate(mm(xs, w1, sizes), mm(xs, w3, sizes), bf), w2, sizes)
         return run
 
-    def grouped(tile, pass_rows):
+    def grouped(tile, pass_rows, combine):
         def run(x, local, weights, w1, w3, w2):
             cuts = moe._cuts(H, WIDTH)._replace(row_tile=tile)
             rows = pass_rows or moe._pass_rows(local.size, EXPERTS / ROUTED, cuts)  # 0: the rows the program takes
-            return moe.experts_grouped(x, local, weights, w1, w3, w2, min(rows, -(-local.size // tile) * tile), tile)[0]
+            rows = min(rows, -(-local.size // tile) * tile)
+            return moe.experts_grouped(x, local, weights, w1, w3, w2, rows, tile, combine)[0]
         return run
 
     def dense(x, local, weights, w1, w3, w2):
@@ -194,13 +199,18 @@ def variants():
         out[f"experts_kernel/pallas_tm{tm}"] = (
             ffn(lambda a, w, s, tm=tm: grouped_matmul(a, w, s, tm=tm)), (rows(16384), sizes, *w), "kernel")
     out["experts_kernel/ragged_dot"] = (ragged, (rows(16384), sizes, *w), "kernel")
+    own = moe.grouped_combine(EXPERTS, ROUTED)
     for t in LAYER_TOKENS:
         layer = (rows(t), jax.ShapeDtypeStruct((t, TOP_K), jnp.int32), jax.ShapeDtypeStruct((t, TOP_K), jnp.float32), *w)
         if t <= 2048:
             out[f"experts_layer/T{t}/dense"] = (dense, layer, "layer")
+        # where every expert is held a prompt chunk takes both combines, side by side
+        combines = {"/gather": "gather", "/scatter": "scatter"} if own == "gather" and t in SHORT_PASSES else {"": own}
         for tile, pass_rows in LAYER_TILINGS + SHORT_PASSES.get(t, ()):
             if t * TOP_K >= tile:
-                out[f"experts_layer/T{t}/grouped_tm{tile}_rows{pass_rows}"] = (grouped(tile, pass_rows), layer, "layer")
+                for suffix, combine in combines.items():
+                    name = f"experts_layer/T{t}/grouped_tm{tile}_rows{pass_rows}{suffix}"
+                    out[name] = (grouped(tile, pass_rows, combine), layer, "layer")
     if GEOM == "mellum":
         from perceiver_io_tpu.core.cache import KVCache
         from perceiver_io_tpu.core.gqa import cached_decode_attention
@@ -337,7 +347,7 @@ def main(argv=None) -> int:
                 data = trace.load_xplane(trace.find_xplane(trace_dir))
                 events = data["devices"][sorted(data["devices"])[0]]
                 busy_ms = trace.busy_ns(events) / 1e6 / args.iters
-                top = trace.top(trace.totals_by_name(events), 4)
+                top = trace.top(trace.totals_by_name(events), 8)
                 results[label] = {"device_ms": busy_ms, "top": [[n, 1e3 * s / args.iters] for n, s in top]}
                 print(f"{label}: {busy_ms:.4f} ms a call; {results[label]['top']}", flush=True)
             except Exception as e:  # noqa: BLE001 - one variant failing must not lose the others
