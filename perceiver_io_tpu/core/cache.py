@@ -503,3 +503,163 @@ def init_window_kv_cache(batch_size: int, window: int, num_qk_channels: int, num
         v=jnp.zeros((batch_size, window, num_v_channels), dtype),
         length=jnp.zeros((), jnp.int32),
     )
+
+
+# ---------------------------------------------------------------------------
+# a length a row: the caches of a step that yields one or two tokens a row
+# ---------------------------------------------------------------------------
+#
+# A speculative step (``generation._generate_speculative``) runs the model on
+# a row's last emitted token and on the draft of the next one, and each row
+# keeps the draft's position only where the model agreed with it: rows advance
+# by different counts, so the length is a row's own. ``k``/``v`` are
+# (B * H, slots, C) as in :class:`KVCache` under grouped-query attention (a
+# key-value head is a row of the products); ``length`` is (B,) int32, shared by
+# a row's H heads. ``write`` puts ``n`` positions a row at ``length`` and on
+# and leaves ``length`` alone; ``keep`` advances it by the positions that
+# stay. What was written past the kept positions is dead: no query sees it
+# (``visible``) and the next step's write starts on top of it. These stand
+# beside the classes above, whose programs are the accepted cells'.
+
+
+def _query_positions(length: jnp.ndarray, heads: int, n: int, group: int) -> jnp.ndarray:
+    """(B * H, n * group) int32: the position of each query of a step of ``n`` positions, ``group`` queries a position."""
+    return jnp.repeat(length, heads)[:, None] + (jnp.arange(n * group, dtype=jnp.int32) // group)[None, :]
+
+
+def _row_scatter(buf: jnp.ndarray, slots: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
+    """``buf[r, slots[r, i]] = rows[r, i]``: ``buf`` (R, S, C), ``slots`` (R, n)
+    distinct within a row, ``rows`` (R, n, C). A slot past ``S`` is dropped."""
+    r = jnp.arange(buf.shape[0], dtype=jnp.int32)[:, None]
+    return buf.at[r, slots].set(rows.astype(buf.dtype), mode="drop", unique_indices=True)
+
+
+@struct.dataclass
+class RaggedKVCache:
+    """A growing cache with a length a row: position ``p`` of row ``b`` lives
+    in slot ``p`` of rows ``b * H .. b * H + H - 1``."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    length: jnp.ndarray  # (B,) int32
+
+    @property
+    def capacity(self) -> int:
+        return self.k.shape[1]
+
+    @property
+    def heads(self) -> int:
+        return self.k.shape[0] // self.length.shape[0]
+
+    def fill(self, k: jnp.ndarray, v: jnp.ndarray) -> "RaggedKVCache":
+        """The cache after a prompt pass over an empty one: ``k``/``v`` (B * H, n, C), every row ``n`` long."""
+        n = k.shape[1]
+        return RaggedKVCache(
+            k=lax.dynamic_update_slice(self.k, k.astype(self.k.dtype), (0, 0, 0)),
+            v=lax.dynamic_update_slice(self.v, v.astype(self.v.dtype), (0, 0, 0)),
+            length=jnp.full_like(self.length, n),
+        )
+
+    def write(self, k: jnp.ndarray, v: jnp.ndarray) -> "RaggedKVCache":
+        """``k``/``v`` (B * H, n, C), keys already rotated, at each row's ``length .. length + n - 1``."""
+        slots = jnp.repeat(self.length, self.heads)[:, None] + jnp.arange(k.shape[1], dtype=jnp.int32)[None, :]
+        return self.replace(k=_row_scatter(self.k, slots, k), v=_row_scatter(self.v, slots, v))
+
+    def keep(self, m: jnp.ndarray) -> "RaggedKVCache":
+        """Advance each row by ``m`` (B,) of the positions last written."""
+        return self.replace(length=self.length + m.astype(jnp.int32))
+
+    def visible(self, n: int, group: int = 1) -> jnp.ndarray:
+        """(B * H, n * group, slots) bool, in the layout of the attention
+        products (``group`` queries a position, position-major): what the
+        query at ``length + i`` sees once ``n`` positions are written, slots
+        up to its own."""
+        q_pos = _query_positions(self.length, self.heads, n, group)
+        return jnp.arange(self.capacity, dtype=jnp.int32)[None, None, :] <= q_pos[:, :, None]
+
+
+def init_ragged_kv_cache(batch_size: int, heads: int, capacity: int, num_qk_channels: int, num_v_channels: int,
+                         dtype=jnp.float32) -> RaggedKVCache:
+    return RaggedKVCache(
+        k=jnp.zeros((batch_size * heads, capacity, num_qk_channels), dtype),
+        v=jnp.zeros((batch_size * heads, capacity, num_v_channels), dtype),
+        length=jnp.zeros((batch_size,), jnp.int32),
+    )
+
+
+@struct.dataclass
+class RaggedWindowKVCache:
+    """A window layer's ring with a length a row and ``slack`` slots more
+    than the window: position ``p`` lives in slot ``p % (window + slack)``.
+    A step that writes ``n <= slack + 1`` positions at ``length`` and on
+    overwrites positions ``length + i - window - slack``, which no query of the
+    step or after it sees, so a position that is written and not kept has
+    destroyed nothing: a ring of ``window`` slots alone would lose position
+    ``length - window + 1`` to the draft at ``length + 1`` while the query at
+    ``length`` still sees it. ``visible`` reads each slot's position back from
+    the row's length, so a dead slot (a rejected draft, or a slot of the
+    slack) is masked by where it lies, not by what it holds."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    length: jnp.ndarray  # (B,) int32: the positions kept so far
+    window: int = struct.field(pytree_node=False)
+
+    @property
+    def capacity(self) -> int:
+        return self.k.shape[1]
+
+    @property
+    def slack(self) -> int:
+        return self.capacity - self.window
+
+    @property
+    def heads(self) -> int:
+        return self.k.shape[0] // self.length.shape[0]
+
+    def fill(self, k: jnp.ndarray, v: jnp.ndarray, n: int) -> "RaggedWindowKVCache":
+        """The cache after a prompt pass of ``n`` positions over an empty
+        ring: ``k``/``v`` (B * H, min(n, window), C) are the rows of the
+        prompt's last positions, each put in its slot."""
+        w, ring = self.window, self.capacity
+        if k.shape[1] != min(n, w):
+            raise ValueError(f"a prompt of {n} positions fills a ring of window {w} with its last {min(n, w)}, got {k.shape[1]}")
+        slots = (max(n - w, 0) + jnp.arange(min(n, w), dtype=jnp.int32)) % ring  # fixed at trace time
+
+        def place(buf, rows):
+            return buf.at[:, slots].set(rows.astype(buf.dtype), unique_indices=True)
+
+        return self.replace(k=place(self.k, k), v=place(self.v, v), length=jnp.full_like(self.length, n))
+
+    def write(self, k: jnp.ndarray, v: jnp.ndarray) -> "RaggedWindowKVCache":
+        """``k``/``v`` (B * H, n, C), keys already rotated, at each row's positions ``length .. length + n - 1``."""
+        n = k.shape[1]
+        if n > self.slack + 1:
+            raise ValueError(f"a ring with {self.slack} slots of slack takes at most {self.slack + 1} positions a step, got {n}")
+        slots = (jnp.repeat(self.length, self.heads)[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]) % self.capacity
+        return self.replace(k=_row_scatter(self.k, slots, k), v=_row_scatter(self.v, slots, v))
+
+    def keep(self, m: jnp.ndarray) -> "RaggedWindowKVCache":
+        return self.replace(length=self.length + m.astype(jnp.int32))
+
+    def visible(self, n: int, group: int = 1) -> jnp.ndarray:
+        """(B * H, n * group, slots) bool, in the layout of the attention
+        products, once ``n`` positions are written at ``length`` and on: slot
+        ``s`` holds the largest position ``p <= length + n - 1`` with
+        ``p % slots == s``, and the query at ``q = length + i`` sees it iff
+        ``q - window < p <= q`` (and ``p >= 0``: the ring may not be full)."""
+        ring = self.capacity
+        last = jnp.repeat(self.length, self.heads)[:, None] + (n - 1)  # (B * H, 1): the largest position written
+        held = last - (last - jnp.arange(ring, dtype=jnp.int32)[None, :]) % ring  # (B * H, slots)
+        p, q = held[:, None, :], _query_positions(self.length, self.heads, n, group)[:, :, None]
+        return (p <= q) & (p > q - self.window) & (p >= 0)
+
+
+def init_ragged_window_kv_cache(batch_size: int, heads: int, window: int, slack: int, num_qk_channels: int,
+                                num_v_channels: int, dtype=jnp.float32) -> RaggedWindowKVCache:
+    return RaggedWindowKVCache(
+        k=jnp.zeros((batch_size * heads, window + slack, num_qk_channels), dtype),
+        v=jnp.zeros((batch_size * heads, window + slack, num_v_channels), dtype),
+        length=jnp.zeros((batch_size,), jnp.int32),
+        window=window,
+    )

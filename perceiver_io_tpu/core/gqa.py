@@ -3,11 +3,15 @@
 
 ``q = x W_q`` has ``num_attention_heads`` heads, ``k = x W_k`` and
 ``v = x W_v`` have ``num_key_value_heads``; query head i reads key-value head
-``i // group``. No biases, no q/k norm. Rotary on q and k in the half-split
+``i // group``. No biases. With ``qk_norm`` (the EXAONE family) each head of q
+and of k passes an RMSNorm over its ``head_dim`` channels before the rotary.
+Rotary on q and k in the half-split
 pairing (``core/position.py::apply_rotary_half``): a window layer rotates with
 the plain frequencies, a full layer with YaRN's (``rope_scaling``:
 frequencies blended between ``f`` and ``f / factor``, cos and sin times
-``attention_factor``). Position i sees ``j <= i`` and, on a window layer,
+``attention_factor``), or not at all where ``full_attention_rotary`` is off
+(the EXAONE family's full layers carry no position). Position i sees
+``j <= i`` and, on a window layer,
 ``j > i - sliding_window``. The softmax scale is ``head_dim ** -0.5``.
 
 One set of weights, two ways through them, as in ``core/mla.py``:
@@ -30,6 +34,14 @@ One set of weights, two ways through them, as in ``core/mla.py``:
     cache once, in place (a Pallas kernel in their place measured slower:
     ``tools/moe_ab.py --geom mellum``, PERF.md 6, PR 32).
 
+``verify`` (a speculative step: a row's last token and the draft after it)
+    ``n`` positions a row, each row at its own length, against the caches
+    with a length a row (``core/cache.py::RaggedKVCache``,
+    ``RaggedWindowKVCache``): the positions are written, not yet kept, and
+    each query sees what ``cache.visible`` says of its position. The same two
+    batched products, ``n`` times the group's queries against one read of the
+    cache.
+
 Scores and the softmax are float32; products take ``dtype`` operands and
 accumulate in float32.
 """
@@ -42,11 +54,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from perceiver_io_tpu.core.cache import KVCache, WindowKVCache
+from perceiver_io_tpu.core.cache import KVCache, RaggedKVCache, RaggedWindowKVCache, WindowKVCache
 from perceiver_io_tpu.core.position import apply_rotary_half, yarn_inv_freq
 from perceiver_io_tpu.ops.flash_attention import flash_attention_gqa, flash_enabled, gqa_flash_supported
+from perceiver_io_tpu.ops.layernorm import RMSNorm
 
 Cache = Union[KVCache, WindowKVCache]
+RaggedCache = Union[RaggedKVCache, RaggedWindowKVCache]
 
 
 class GroupedQueryAttention(nn.Module):
@@ -54,8 +68,9 @@ class GroupedQueryAttention(nn.Module):
     ``num_key_value_heads``, ``head_dim``, ``rope_theta``, ``rope_scaling``
     (``None`` or YaRN's ``factor``, ``beta_fast``, ``beta_slow``,
     ``attention_factor``, ``original_max_position_embeddings``; full layers
-    only), ``sliding_window`` and ``init_scale``. ``window`` says which kind
-    of layer this is."""
+    only), ``sliding_window``, ``init_scale``, ``qk_norm`` (with ``rms_norm_eps``)
+    and ``full_attention_rotary``. ``window`` says
+    which kind of layer this is."""
 
     config: object
     window: bool
@@ -70,6 +85,9 @@ class GroupedQueryAttention(nn.Module):
         self.w_k = self.param("w_k", init, (c.hidden_size, kv_width), self.param_dtype)
         self.w_v = self.param("w_v", init, (c.hidden_size, kv_width), self.param_dtype)
         self.w_o = self.param("w_o", init, (q_width, c.hidden_size), self.param_dtype)
+        if c.qk_norm:
+            kw = dict(epsilon=c.rms_norm_eps, dtype=self.dtype, param_dtype=self.param_dtype)
+            self.q_norm, self.k_norm = RMSNorm(**kw), RMSNorm(**kw)
 
     @property
     def span(self) -> str:
@@ -95,6 +113,10 @@ class GroupedQueryAttention(nn.Module):
         q = self._mm(x, self.w_q).reshape(b, n, c.num_attention_heads, c.head_dim)
         k = self._mm(x, self.w_k).reshape(b, n, c.num_key_value_heads, c.head_dim)
         v = self._mm(x, self.w_v).reshape(b, n, c.num_key_value_heads, c.head_dim)
+        if c.qk_norm:
+            q, k = self.q_norm(q), self.k_norm(k)
+        if not (self.window or c.full_attention_rotary):
+            return q, k, v
         return (apply_rotary_half(q, pos[:, :, None], inv_freq, factor),
                 apply_rotary_half(k, pos[:, :, None], inv_freq, factor), v)
 
@@ -136,6 +158,38 @@ class GroupedQueryAttention(nn.Module):
                 cache = cache.append(k.reshape(b * kv_heads, 1, d), v.reshape(b * kv_heads, 1, d))
             o = cached_decode_attention(q.reshape(b * kv_heads, heads // kv_heads, d), cache, d ** -0.5)
             return self._mm(o.astype(self.dtype).reshape(b, 1, heads * d), self.w_o), cache
+
+
+    # ---------------------------------------------------- a speculative step
+
+    def verify(self, x, cache: RaggedCache, pos) -> Tuple[jnp.ndarray, RaggedCache]:
+        """``n`` positions a row, ``x`` (B, n, h) at ``pos`` (B, n) =
+        ``cache.length + 0 .. n - 1``: their keys and values are written (the
+        caller keeps those that stay), and query i sees the cache as of its
+        own position."""
+        c = self.config
+        b, n, _ = x.shape
+        heads, kv_heads, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        group = heads // kv_heads
+        with jax.named_scope(self.span):
+            q, k, v = self._project(x, pos)
+            with jax.named_scope("kv_cache_write"):
+                cache = cache.write(k.transpose(0, 2, 1, 3).reshape(b * kv_heads, n, d),
+                                    v.transpose(0, 2, 1, 3).reshape(b * kv_heads, n, d))
+            qg = q.reshape(b, n, kv_heads, group, d).transpose(0, 2, 1, 3, 4).reshape(b * kv_heads, n * group, d)
+            o = cached_verify_attention(qg, cache, cache.visible(n, group), d ** -0.5)
+            o = o.astype(self.dtype).reshape(b, kv_heads, n, group, d).transpose(0, 2, 1, 3, 4).reshape(b, n, heads * d)
+            return self._mm(o, self.w_o), cache
+
+
+def cached_verify_attention(q: jnp.ndarray, cache: RaggedCache, visible: jnp.ndarray, sm_scale: float) -> jnp.ndarray:
+    """``q`` (B * Hkv, queries, D) against ``cache.k`` / ``cache.v``
+    (B * Hkv, slots, D), query by query what ``visible`` (B * Hkv, queries,
+    slots) shows. Returns ``softmax(q . k) @ v`` (B * Hkv, queries, D) in
+    float32: :func:`cached_decode_attention`'s two products with a mask a query."""
+    s = jnp.einsum("bqd,bsd->bqs", q.astype(cache.k.dtype), cache.k, preferred_element_type=jnp.float32) * sm_scale
+    p = jax.nn.softmax(jnp.where(visible, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bqs,bsd->bqd", p.astype(cache.v.dtype), cache.v, preferred_element_type=jnp.float32)
 
 
 def cached_decode_attention(q: jnp.ndarray, cache: Cache, sm_scale: float) -> jnp.ndarray:

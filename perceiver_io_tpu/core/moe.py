@@ -74,8 +74,8 @@ from perceiver_io_tpu.ops.grouped_matmul import grouped_matmul
 
 # How the work is cut, not what is computed: a function of the expert layer's
 # geometry (held experts, hidden size, expert width), each value from
-# ``tools/moe_ab.py`` on the v5e at the published widths of the two
-# geometries the program runs (PERF.md 6, PR 28 and PR 32).
+# ``tools/moe_ab.py`` on the v5e at the published widths of the
+# geometries the program runs (PERF.md 6, PR 28, PR 32 and PR 34).
 class _Cuts(NamedTuple):
     grouped_min_tokens: int  # from this many tokens the grouped path is taken
     row_tile: int  # the grouped kernel's row tile
@@ -92,7 +92,14 @@ class _Cuts(NamedTuple):
 # scatter-add into 8192 rows is slow past 1024 updates: this geometry holds a
 # share of the experts and keeps the scatter-add, so the cliff is its own), and
 # a layer that a seed's routing sends a quarter more pairs costs a pass of 2 ms
-# more, not a second sweep of 8.6.
+# more, not a second sweep of 8.6. Measured again at 16 held experts of width
+# 2048, hidden 6144 (K-EXAONE, one chip of eight: one local pair a token, twice
+# this share's; ``tools/moe_ab.py --geom kexaone``, PERF.md 6, PR 34), with the
+# same outcome: 128 positions dense 1.64 ms, grouped 1.71 (tile 128) to 1.94;
+# 256 tokens 1.81 against 2.01; 384 tokens 2.51 against 2.16; a prompt chunk's
+# 8192 pairs at tile 256 12.81 ms in passes of 1024 rows (12.81 at 512, 12.84 at
+# 768, 13.62 at 4096, 13.00 in one pass), 23.3 at 1536 and 18.2 at 2048 (the
+# same cliff); tile 128 15.3, tile 512 14.0.
 _WIDE_EXPERTS = _Cuts(grouped_min_tokens=384, row_tile=256, pass_rows=1024)
 # 64 held experts of width 896, hidden 2304 (Mellum 2, every expert held, 8
 # pairs a token all of them here: the grouped path combines by the gather).

@@ -755,6 +755,8 @@ def generate(
         return input_ids
 
     decoder = _decoder_of(model)
+    if getattr(decoder, "speculative", False):
+        return _generate_speculative(decoder, model, params, input_ids, pad_mask, config, cache_dtype, weight_dtype)
     logits, window, consts = decoder.prefill(
         params, input_ids, pad_mask, num_latents, config.max_new_tokens, cache_dtype
     )
@@ -785,6 +787,114 @@ def generate(
         tokens = next_token[:, None]
 
     return jnp.concatenate([input_ids, tokens], axis=1)
+
+
+# ---------------------------------------------------------------------------
+# a model that drafts for itself: a step that yields one or two tokens a row
+# ---------------------------------------------------------------------------
+#
+# Where the model's decoder is ``speculative`` (a multi-token-prediction
+# module: ``models/text/decoder_lm.py``) the generator drafts and verifies
+# greedily; nothing selects this but the configuration having the module. A
+# row's state is its last emitted token ``t_l`` (not yet in a cache), the
+# module's draft ``d_{l+1}`` of the token after it, and caches that hold
+# positions ``0 .. l - 1``. A step runs the stack on both (``spec/verify``),
+# emits ``g = argmax`` at ``l`` and, where ``g == d``, also the argmax at
+# ``l + 1`` (``spec/accept``: :func:`_speculative_accept`'s greedy rule with
+# one draft), runs the module on the same two positions with the tokens that
+# followed them and takes the draft for the next step from the last position
+# kept (``mtp/*``), and every cache keeps position ``l`` and, where the draft
+# was accepted, ``l + 1`` (``spec/rollback``: a length a row). The emitted
+# stream is the stack's own greedy stream token for token, whatever the module
+# drafts: a draft only decides whether a step yields one token or two.
+
+
+def _refuse_sampled_speculation(config: GenerationConfig):
+    if config.do_sample:
+        raise ValueError(
+            "a model with a multi-token-prediction module generates greedily: drafting and verifying with a "
+            "temperature (the rejection rule of _speculative_accept) is not wired to the module's draft"
+        )
+
+
+def _spec_first(decoder, params, input_ids, pad_mask, config, cache_dtype):
+    """The speculative prompt pass: the first token, the first draft, the window, the rows done, and the first token's logits."""
+    token, logits, draft_logits, window = decoder.spec_prefill(
+        params, input_ids, pad_mask, config.max_new_tokens, cache_dtype, lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32))
+    with jax.named_scope("prefill"), jax.named_scope("mtp/draft"):
+        draft = jnp.argmax(draft_logits, axis=-1).astype(jnp.int32)
+    done = jnp.zeros(token.shape, bool)
+    if config.eos_token_id is not None:
+        done = token == config.eos_token_id
+    return token, draft, window, done, logits
+
+
+def _spec_step_body(decoder, config, step_params, window, token, draft, done, budget):
+    """One speculative step, shared by the compiled loop and the host-driven
+    pair. ``budget`` (B,) is how many tokens each row may still emit (0: the
+    row is finished and keeps nothing). Returns the window, the step's tokens
+    (B, 2), how many of them each row emits ``m`` (B,) in 0..2, the next
+    ``token`` and ``draft``, ``done``, and the stack's logits at the row's
+    first position (B, V) for the health gauges. Under a probe collector the
+    step taps ``spec.step``: the drafts verified (one a live row), those
+    accepted, the tokens emitted and the rows still live."""
+    from perceiver_io_tpu.obs import probes
+
+    b = token.shape[0]
+    with jax.named_scope("spec/verify"):
+        p_logits, hidden, window = decoder.spec_verify(step_params, window, jnp.stack([token, draft], axis=1))
+    with jax.named_scope("spec/accept"):
+        # the greedy rule needs no keys; the chain it threads is dead code here
+        tokens, m, new_token, _, new_done = _speculative_accept(
+            config, draft[:, None], None, p_logits, jnp.zeros((b, 2), jnp.uint32), done)
+        accepted = (m == 2) & (budget > 0)
+        m = jnp.minimum(m, budget)
+        live = m > 0
+        if probes.active():
+            n_live = live.sum().astype(jnp.int32)  # one draft is verified a live row
+            probes.tap("spec.step", {"drafts": n_live, "accepted": accepted.sum().astype(jnp.int32),
+                                     "tokens_out": m.sum().astype(jnp.int32), "rows_live": n_live})
+        token = jnp.where(live, new_token, token)
+        done = jnp.where(live, new_done, done)
+    # the module reads the token that followed each position: the two the stack put there
+    m_logits, window = decoder.spec_draft(step_params, window, hidden, tokens)
+    with jax.named_scope("mtp/draft"):
+        kept_last = jnp.take_along_axis(m_logits, jnp.maximum(m - 1, 0)[:, None, None], axis=1)[:, 0]
+        draft = jnp.where(live, jnp.argmax(kept_last, axis=-1).astype(jnp.int32), draft)
+    with jax.named_scope("spec/rollback"):
+        window = decoder.spec_keep(window, m)
+    return window, tokens, m, token, draft, done, p_logits[:, 0]
+
+
+def _generate_speculative(decoder, model, params, input_ids, pad_mask, config, cache_dtype, weight_dtype):
+    """:func:`generate` for a model that drafts for itself: the prompt pass,
+    then speculative steps in one compiled ``while`` until every row has its
+    ``max_new_tokens``. A row that accepts drafts finishes in fewer steps and
+    waits, finished, for the others."""
+    _refuse_sampled_speculation(config)
+    b = input_ids.shape[0]
+    n_new = config.max_new_tokens
+    token, draft, window, done, _ = _spec_first(decoder, params, input_ids, pad_mask, config, cache_dtype)
+    decode_params, compute_dtype = _maybe_quantize_weights(model, params, weight_dtype)
+    out = jnp.full((b, n_new), config.pad_token_id, jnp.int32).at[:, 0].set(token)
+    rows = jnp.arange(b)[:, None]
+
+    def step(carry):
+        with jax.named_scope("decode"):
+            window, out, count, token, draft, done = carry
+            step_params = _maybe_dequantize_weights(decode_params, compute_dtype)
+            window, tokens, m, token, draft, done, _ = _spec_step_body(
+                decoder, config, step_params, window, token, draft, done, n_new - count)
+            # a row writes its ``m`` tokens from column ``count`` on; what is not emitted goes past the edge and is dropped
+            j = jnp.arange(tokens.shape[1])[None, :]
+            cols = jnp.where(j < m[:, None], count[:, None] + j, n_new)
+            out = out.at[rows, cols].set(tokens, mode="drop")
+            return window, out, count + m, token, draft, done
+
+    if n_new > 1:
+        carry = (window, out, jnp.ones((b,), jnp.int32), token, draft, done)
+        out = lax.while_loop(lambda carry: jnp.any(carry[2] < n_new), step, carry)[1]
+    return jnp.concatenate([input_ids, out.astype(input_ids.dtype)], axis=1)
 
 
 def make_decode_fns(
@@ -826,6 +936,8 @@ def make_decode_fns(
     if config.max_new_tokens < 1:
         raise ValueError("decode fns require max_new_tokens >= 1")
     decoder = _decoder_of(model)
+    if getattr(decoder, "speculative", False):
+        return _make_self_drafting_decode_fns(decoder, model, config, cache_dtype, weight_dtype, probes)
     names = decoder.window_names + decoder.const_names
     compute_dtype = None if weight_dtype is None else getattr(model, "dtype", jnp.float32)
 
@@ -879,6 +991,74 @@ def make_decode_fns(
             return new_state, token
 
     return jax.jit(prefill), jax.jit(step)
+
+
+def _make_self_drafting_decode_fns(decoder, model, config, cache_dtype, weight_dtype, probes):
+    """:func:`make_decode_fns` for a model that drafts for itself (a
+    ``speculative`` decoder): the same prompt pass and step as
+    :func:`_generate_speculative`'s compiled loop, driven from the host.
+
+    - ``prefill_fn(params, input_ids, pad_mask=None, rng=None) ->
+      (first_token, state)``; ``state`` also carries the first ``draft`` and
+      ``count`` (B,), the tokens each row has emitted (1).
+    - ``step_fn(state) -> (state, tokens (B, 2))``: one speculative step. Row
+      ``r`` emits ``tokens[r, :state["emitted"][r]]``, 0 to 2 tokens: none once
+      it has its ``max_new_tokens``, and the caller stops when every
+      ``count`` has reached that. ``rng`` is taken and unused: the pair is greedy.
+    """
+    _refuse_sampled_speculation(config)
+    compute_dtype = None if weight_dtype is None else getattr(model, "dtype", jnp.float32)
+    scopes = decoder.tap_scopes if probes else ()
+    # The weights stay out of the compiled programs' results: a state that carried them through ``jit`` would hold
+    # them twice (an output is a buffer of its own), and this model's are most of a chip. They ride the state dict
+    # from outside, as the arguments of both programs.
+    if weight_dtype is None:
+        decode_weights = lambda params: params  # noqa: E731
+    else:
+        decode_weights = jax.jit(lambda params: _maybe_quantize_weights(model, params, weight_dtype)[0])
+
+    @jax.jit
+    def first(params, input_ids, pad_mask):
+        (token, draft, window, done, logits), taps = _with_taps(
+            scopes, lambda: _spec_first(decoder, params, input_ids, pad_mask, config, cache_dtype))
+        state = {
+            "token": token, "draft": draft, "done": done,
+            "count": jnp.ones(token.shape, jnp.int32), "emitted": jnp.ones(token.shape, jnp.int32),
+            **dict(zip(decoder.window_names, window)),
+        }
+        if probes:
+            # the prompt pass ran no speculative step: its books read zero, so the state is one pytree throughout
+            zeros = {k: jnp.zeros((), jnp.int32) for k in ("drafts", "accepted", "tokens_out", "rows_live")}
+            state["probe"] = {**decoder.health(logits, window), **zeros, **taps}
+        return token, state
+
+    @jax.jit
+    def advance(decode_params, state):
+        with jax.named_scope("decode"):
+            step_params = _maybe_dequantize_weights(decode_params, compute_dtype)
+            window = tuple(state[k] for k in decoder.window_names)
+            stepped, taps = _with_taps(scopes, lambda: _spec_step_body(
+                decoder, config, step_params, window, state["token"], state["draft"], state["done"],
+                config.max_new_tokens - state["count"]))
+            window, tokens, m, token, draft, done, logits = stepped
+            new_state = dict(state, **dict(zip(decoder.window_names, window)), token=token, draft=draft, done=done,
+                             count=state["count"] + m, emitted=m)
+            if probes:
+                new_state["probe"] = {**decoder.health(logits, window), **taps}
+            return new_state, tokens
+
+    def prefill(params, input_ids, pad_mask=None, rng=None):
+        del rng
+        token, state = first(params, input_ids, pad_mask)
+        return token, dict(state, params=decode_weights(params))
+
+    def step(state):
+        new_state, tokens = advance(state["params"], {k: v for k, v in state.items() if k != "params"})
+        return dict(new_state, params=state["params"]), tokens
+
+    # what ``obs.recompile.RecompileTracker`` asks a jitted callable for
+    prefill._cache_size, step._cache_size = first._cache_size, advance._cache_size
+    return prefill, step
 
 
 def make_shared_prefill_fn(
@@ -1213,8 +1393,11 @@ def make_speculative_decode_fns(
 
     - ``prefill_fn(params, input_ids, pad_mask=None, rng=None) ->
       (first_token, state)`` — the :func:`make_decode_fns` prefill contract
-      (batch 1; batched speculative decode is the engine's paged slot mode,
-      :func:`make_speculative_paged_step_fn`) plus the drafter wiring: the
+      (batch 1: this pair's Perceiver AR caches keep one length for the
+      batch; batched speculative decode of Perceiver AR is the engine's
+      paged slot mode, :func:`make_speculative_paged_step_fn`, and a model
+      that drafts for itself runs batched over caches with a length a row,
+      :func:`_generate_speculative`) plus the drafter wiring: the
       drafter's caches are the flagship prefill caches' PREFIX (CA + first
       ``draft_depth`` SA layers — shared weights make them identical, see
       :func:`make_drafter`), so there is no second prompt pass. Caches get
@@ -1251,9 +1434,11 @@ def make_speculative_decode_fns(
         b, seq_len = input_ids.shape
         if b != 1:
             raise ValueError(
-                "the speculative host-driven pair serves batch 1 (ragged "
-                "accepted-prefix lengths need per-row cache lengths — "
-                "batched speculative decode is the engine's paged slot mode)"
+                "the speculative host-driven pair serves batch 1: its Perceiver AR caches "
+                "(core/cache.py::KVCache) keep one length for the batch, and ragged "
+                "accepted-prefix lengths need one a row (core/cache.py::RaggedKVCache holds "
+                "one, for the decoder-only model's own drafting) — batched speculative "
+                "decode of Perceiver AR is the engine's paged slot mode"
             )
         prefix_len = _validate_window(mcfg, seq_len, num_latents)
         _require_pads_in_prefix(pad_mask, prefix_len)
@@ -1614,6 +1799,14 @@ def make_instrumented_generate_fn(
     m_moe_gathered = registry.counter("moe_pairs_gathered_total") if moe_taps else None
     m_moe_dropped = registry.counter("moe_pairs_dropped_total") if moe_taps else None
     m_moe_load = registry.gauge("moe_expert_load_max") if moe_taps else None
+    # a model that drafts for itself (a ``speculative`` decoder): a step yields 0 to 2 tokens a row, every
+    # step is host-timed as one TPOT sample, and the ``spec.step`` taps keep the drafting's books
+    self_drafting = getattr(decoder, "speculative", False)
+    spec_taps = probes and self_drafting
+    m_spec_steps = registry.counter("spec_steps_total") if self_drafting else None
+    m_spec_drafts = registry.counter("spec_drafts_total") if spec_taps else None
+    m_spec_accepted = registry.counter("spec_accepted_total") if spec_taps else None
+    m_spec_rate = registry.gauge("spec_accept_rate") if spec_taps else None
     tracer = obs_trace.Tracer(events, flush_every=64) if events is not None else None
 
     def fn(params, input_ids, pad_mask=None, rng=None, queue_wait_s=None, arrival_ts=None,
@@ -1623,6 +1816,7 @@ def make_instrumented_generate_fn(
         request_id = obs_trace.new_span_id()
         hist = Histogram("tpot_s")  # THIS request's decode latencies
         toks = []
+        spans = []  # a self-drafting model's steps: (tokens (B, 2), how many of them each row emitted)
         healths = []  # device-array health dicts; fetched once, after the loop
         outcome, err = "ok", None
         ttft = 0.0
@@ -1651,20 +1845,33 @@ def make_instrumented_generate_fn(
                     healths.append(state["probe"])
                 if on_token is not None:
                     on_token(0, token)
-                for i in range(1, config.max_new_tokens):
+                def timed_step(state, fetch):
+                    """One host-timed step: the time ends when ``fetch(state, out)`` has the step's tokens on the host."""
                     c0 = tracker.total_compiles
                     t1 = time.perf_counter()
-                    state, token = step_fn(state)
-                    float(token[0])
+                    state, out = step_fn(state)
+                    held = fetch(state, out)
                     dt = time.perf_counter() - t1
                     hist.record(dt)
                     if tracker.total_compiles == c0:
                         m_tpot.record(dt)
-                    toks.append(token)
                     if probes:
                         healths.append(state["probe"])
-                    if on_token is not None:
-                        on_token(i, token)
+                    return state, out, held
+
+                if self_drafting:
+                    while int(state["count"].min()) < config.max_new_tokens:
+                        state, span, emitted = timed_step(state, lambda s, _: np.asarray(s["emitted"]))
+                        spans.append((np.asarray(span), emitted))
+                        m_spec_steps.inc()
+                        if on_token is not None:
+                            on_token(len(spans), span)
+                else:
+                    for i in range(1, config.max_new_tokens):
+                        state, token, _ = timed_step(state, lambda _, t: float(t[0]))
+                        toks.append(token)
+                        if on_token is not None:
+                            on_token(i, token)
             except BaseException as e:  # noqa: BLE001 — event out, then reraise
                 # the cancellation seam: an on_token callback raising
                 # GenerationAborted (deadline expiry, explicit cancel)
@@ -1680,7 +1887,8 @@ def make_instrumented_generate_fn(
                     sp.set("tenant", str(tenant))
         elapsed = time.perf_counter() - t_all0
         decode_s = max(elapsed - ttft, 0.0)
-        tokens_out = len(toks)
+        # tokens every row has: a self-drafting model's rows emit 0 to 2 a step
+        tokens_out = len(toks) + (int(sum(e for _, e in spans).min()) if spans else 0)
         compiled = tracker.total_compiles > compiles_before
         health_row = None
         if probes and healths:
@@ -1718,6 +1926,13 @@ def make_instrumented_generate_fn(
                     m_moe_load.set(max(int(h["expert_load_max"]) for h in hh))
                     health_row["moe_local_share"] = round(local / max(routed, 1), 6)
                     health_row["moe_pairs_dropped"] = dropped
+                if spec_taps:
+                    drafts, accepted = (sum(int(h[k]) for h in hh) for k in ("drafts", "accepted"))
+                    m_spec_drafts.inc(drafts)
+                    m_spec_accepted.inc(accepted)
+                    m_spec_rate.set(accepted / max(drafts, 1))
+                    health_row["spec_drafts"] = drafts
+                    health_row["spec_accept_rate"] = round(accepted / max(drafts, 1), 6)
             except Exception:  # noqa: BLE001 — health is telemetry, never fatal
                 health_row = None
         stats = GenerationStats(
@@ -1797,6 +2012,9 @@ def make_instrumented_generate_fn(
                 pass
             raise err
         out = jnp.concatenate([input_ids] + [t[:, None] for t in toks], axis=1)
+        if spans:  # each row's emitted tokens, in order: exactly max_new_tokens of them with the first
+            rest = [np.concatenate([span[r, :e[r]] for span, e in spans]) for r in range(b)]
+            out = jnp.concatenate([out, jnp.asarray(np.stack(rest), out.dtype)], axis=1)
         return out, stats
 
     fn.registry = registry  # exporter access (to_prometheus / snapshot)

@@ -1,7 +1,8 @@
 """A decoder-only language model: token embedding, blocks
 ``x + Attn(RMSNorm(x))`` then ``x + FFN(RMSNorm(x))``, a final RMSNorm and an
 untied head (``docs/decoder-lm.md``). One class, two published families, told
-apart by the configuration:
+apart by the configuration (a third, K-EXAONE, puts the first's expert layer
+on the second's skeleton and adds the drafting module below):
 
 - **DeepSeek-V3** (``layer_types`` None): multi-head latent attention
   (``core/mla.py``) in every block, ``first_k_dense_replace`` blocks with a
@@ -19,13 +20,22 @@ Unlike the Perceiver models every position passes the whole stack, so there
 is no latent window. The model meets :mod:`perceiver_io_tpu.generation`
 through :meth:`DecoderLanguageModel.generation_decoder`.
 
-The multi-token-prediction modules of the published models are not part of
-serving (DeepSeek-V3's report section 2.2; Mellum's config has no key for
-one) and are not here.
+**The multi-token-prediction module** (``num_nextn_predict_layers`` 1; the
+K-EXAONE family, in DeepSeek-V3's form): ``u_i = W_eh [RMSNorm_e(Emb(t_{i+1}));
+RMSNorm_h(h_i)]`` with ``h_i`` the last block's output at position i, one
+block of its own over ``u`` (``mtp_layer_types[0]``, a sparse feed-forward),
+its own final RMSNorm, the model's embedding and head: logits for
+``t_{i+2}``. Where a configuration has it the generator drafts with it and a
+step verifies two positions a row (``_Decoder``'s ``spec_*`` methods;
+``generation._generate_speculative``): the caches then keep a length a row
+(``core/cache.py``'s ragged classes). DeepSeek-V3's own published module is
+still not built (its configuration here keeps ``num_nextn_predict_layers`` 0),
+and Mellum's config has no key for one.
 """
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Optional, Tuple, Union
 
@@ -35,7 +45,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from perceiver_io_tpu.core.cache import (
-    KVCache, LatentCache, WindowKVCache, init_kv_cache, init_latent_cache, init_window_kv_cache,
+    KVCache, LatentCache, RaggedKVCache, RaggedWindowKVCache, WindowKVCache, init_kv_cache, init_latent_cache,
+    init_ragged_kv_cache, init_ragged_window_kv_cache, init_window_kv_cache,
 )
 from perceiver_io_tpu.core.gqa import GroupedQueryAttention
 from perceiver_io_tpu.core.mla import MultiHeadLatentAttention
@@ -70,7 +81,11 @@ class DecoderLanguageModelConfig:
     ``"full_attention"``) selects grouped-query attention with
     ``num_key_value_heads``, ``head_dim`` and ``sliding_window``, and
     ``rope_scaling`` then applies to the full layers only; ``None`` selects
-    latent attention. ``scoring_func`` is the router's rule (``core/moe.py``)."""
+    latent attention. ``scoring_func`` is the router's rule (``core/moe.py``).
+    ``qk_norm`` and ``full_attention_rotary`` are the grouped-query layers'
+    (``core/gqa.py``). ``num_nextn_predict_layers`` 1 builds the
+    multi-token-prediction module, a block of ``mtp_layer_types[0]``; the
+    generator then drafts with it (no option selects that)."""
 
     vocab_size: int = 129280
     hidden_size: int = 7168
@@ -102,6 +117,10 @@ class DecoderLanguageModelConfig:
     num_key_value_heads: Optional[int] = None
     head_dim: Optional[int] = None
     sliding_window: Optional[int] = None
+    qk_norm: bool = False
+    full_attention_rotary: bool = True
+    num_nextn_predict_layers: int = 0
+    mtp_layer_types: Tuple[str, ...] = ("full_attention",)
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -112,6 +131,11 @@ class DecoderLanguageModelConfig:
                 raise ValueError("layer_types needs num_key_value_heads, head_dim and sliding_window")
             if self.num_attention_heads % self.num_key_value_heads:
                 raise ValueError("num_key_value_heads must divide num_attention_heads")
+        if self.num_nextn_predict_layers:
+            object.__setattr__(self, "mtp_layer_types", tuple(self.mtp_layer_types))
+            if self.layer_types is None or self.num_nextn_predict_layers != 1 or len(self.mtp_layer_types) != 1 \
+                    or set(self.mtp_layer_types) - set(_LAYER_TYPES):
+                raise ValueError("a multi-token-prediction module: one block of a grouped-query layer type, under layer_types")
         if self.n_held_experts is None:
             object.__setattr__(self, "n_held_experts", self.n_routed_experts)
         if self.held_experts_start + self.n_held_experts > self.n_routed_experts:
@@ -172,31 +196,63 @@ class DecoderBlock(nn.Module):
         a, cache = one_token(self.attn_norm(x), cache, pos)
         return self.feed_forward(x + a), cache
 
+    def verify(self, x, cache, pos):
+        """A speculative step's positions, each row at its own length: written to ``cache``, not yet kept."""
+        a, cache = self.attn.verify(self.attn_norm(x), cache, pos)
+        return self.feed_forward(x + a), cache
 
-def _over_chunks(fn, x):
+
+class MTPModule(nn.Module):
+    """The multi-token-prediction module's own weights (the module
+    docstring): two norms, the ``2h -> h`` projection, one block and a final
+    norm. The embedding and the head are the model's."""
+
+    config: DecoderLanguageModelConfig
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    def setup(self):
+        c = self.config
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        self.embed_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
+        self.hidden_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
+        self.w_eh = self.param("w_eh", nn.initializers.normal(c.init_scale), (2 * c.hidden_size, c.hidden_size), self.param_dtype)
+        self.block = DecoderBlock(c, sparse=True, layer_type=c.mtp_layer_types[0], **kw)
+        self.out_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
+
+    def project(self, embedded, hidden):
+        """``W_eh [RMSNorm_e(Emb(t_{i+1})); RMSNorm_h(h_i)]``: both (..., h) -> (..., h)."""
+        with jax.named_scope("mtp/project"):
+            both = jnp.concatenate([self.embed_norm(embedded), self.hidden_norm(hidden)], axis=-1)
+            return jnp.dot(both.astype(self.dtype), self.w_eh.astype(self.dtype))
+
+
+def _over_chunks(fn, x, *beside):
     """Apply ``fn`` to each ``x[i]`` of ``x`` (n, ...) and write the result
     over ``x[i]``: a loop that updates the one buffer in place (a ``lax.map``
     would hold the input and the stacked output both, the whole batch's
     hidden state twice). ``fn`` returns ``(chunk, aux)``; the ``aux`` of every
-    chunk is stacked into (n, ...) buffers. Probe taps inside ``fn`` are
-    carried out of the loop: counts summed over the chunks, ``*_max`` maxed."""
+    chunk is stacked into (n, ...) buffers. Arrays ``beside`` (n, ...) are
+    read chunk by chunk with ``x`` and handed to ``fn`` after it. Probe taps
+    inside ``fn`` are carried out of the loop: counts summed over the chunks,
+    ``*_max`` maxed."""
     n = x.shape[0]
     tapping = probes.active()
 
-    def call(chunk):
+    def call(chunk, *more):
         if not tapping:
-            return fn(chunk), {}
+            return fn(chunk, *more), {}
         with probes.collecting(probes.current_config()) as col:
-            out = fn(chunk)
+            out = fn(chunk, *more)
         return out, col.stats
 
-    (_, aux_shape), stats_shape = jax.eval_shape(call, x[0])
+    (_, aux_shape), stats_shape = jax.eval_shape(call, x[0], *(a[0] for a in beside))
     aux = jax.tree.map(lambda s: jnp.zeros((n,) + s.shape, s.dtype), aux_shape)
     stats = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), stats_shape)
 
     def body(i, carry):
         x, aux, stats = carry
-        (chunk, a), found = call(lax.dynamic_index_in_dim(x, i, 0, keepdims=False))
+        (chunk, a), found = call(*(lax.dynamic_index_in_dim(arr, i, 0, keepdims=False) for arr in (x, *beside)))
         x = lax.dynamic_update_index_in_dim(x, chunk, i, 0)
         aux = jax.tree.map(lambda buf, v: lax.dynamic_update_index_in_dim(buf, v, i, 0), aux, a)
         stats = {
@@ -232,6 +288,8 @@ class DecoderLanguageModel(nn.Module):
         self.head = self.param(
             "head", nn.initializers.normal(c.init_scale), (c.hidden_size, c.vocab_size), self.param_dtype
         )
+        if c.num_nextn_predict_layers:
+            self.mtp = MTPModule(c, **kw)
 
     # the two ends and one layer's halves, as methods ``apply`` can reach: the
     # prompt pass (:func:`prefill`) loops them over chunks from outside the
@@ -249,15 +307,38 @@ class DecoderLanguageModel(nn.Module):
     def ffn_layer(self, x, i: int):
         return self.layers[i].feed_forward(x)
 
-    def __call__(self, input_ids):
-        """Logits (B, N, V) float32 of a full causal forward, no cache."""
+    # the module's parts, for the prompt pass's chunk loops in the same way
+
+    def mtp_project(self, x, next_ids):
+        return self.mtp.project(self.embed(next_ids), x)
+
+    def mtp_attend(self, x, pos):
+        with jax.named_scope("mtp/block"):
+            return self.mtp.block.attend(x, pos)
+
+    def mtp_ffn(self, x):
+        with jax.named_scope("mtp/block"):
+            return self.mtp.block.feed_forward(x)
+
+    def mtp_logits(self, x):
+        with jax.named_scope("mtp/draft"):
+            return jnp.dot(self.mtp.out_norm(x), self.head.astype(self.dtype), preferred_element_type=jnp.float32)
+
+    def __call__(self, input_ids, drafts: bool = False):
+        """Logits (B, N, V) float32 of a full causal forward, no cache. With
+        ``drafts`` also the module's logits (B, N - 1, V): at position i, from
+        ``h_i`` and ``t_{i+1}``, its prediction of ``t_{i+2}``."""
         b, n = input_ids.shape
         pos = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None], (b, n))
         x = self.embed(input_ids)
         for layer in self.layers:
             x, _ = layer.attend(x, pos)
             x = layer.feed_forward(x)
-        return self.logits(x)
+        if not drafts:
+            return self.logits(x)
+        u = self.mtp_project(x[:, :-1], input_ids[:, 1:])
+        u, _ = self.mtp_attend(u, pos[:, :-1])
+        return self.logits(x), self.mtp_logits(self.mtp_ffn(u))
 
     def decode_step(self, token, caches: Tuple[Union[LatentCache, KVCache, WindowKVCache], ...]):
         """One new token a row against the caches: logits (B, V) and the advanced caches."""
@@ -270,13 +351,49 @@ class DecoderLanguageModel(nn.Module):
             new.append(cache)
         return self.logits(x[:, 0]), tuple(new)
 
+    def verify_step(self, tokens, caches: Tuple[Union[RaggedKVCache, RaggedWindowKVCache], ...]):
+        """A speculative step of the stack: ``tokens`` (B, n), a row's last
+        emitted token and the drafts after it, at the row's own positions
+        ``length .. length + n - 1``. Logits (B, n, V), the last block's
+        output (B, n, h) and the caches with the positions written, not kept."""
+        n = tokens.shape[1]
+        pos = caches[0].length[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
+        x = self.embed(tokens)
+        new = []
+        for layer, cache in zip(self.layers, caches):
+            x, cache = layer.verify(x, cache, pos)
+            new.append(cache)
+        return self.logits(x), x, tuple(new)
+
+    def draft_step(self, next_tokens, hidden, cache: Union[RaggedKVCache, RaggedWindowKVCache]):
+        """The module on a step's positions: ``hidden`` (B, n, h) of
+        :meth:`verify_step` and the token after each position (B, n). Its
+        logits (B, n, V) for the token after that, and its cache written."""
+        n = next_tokens.shape[1]
+        pos = cache.length[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
+        u = self.mtp_project(hidden, next_tokens)
+        with jax.named_scope("mtp/block"):
+            u, cache = self.mtp.block.verify(u, cache, pos)
+        return self.mtp_logits(u), cache
+
     # ------------------------------------------------ the generator's side
 
     def generation_decoder(self):
         return _Decoder(self)
 
 
-def prefill(model: DecoderLanguageModel, params, input_ids) -> Tuple[jnp.ndarray, tuple]:
+def _scoped(model, params, method, *args):
+    # a loop's body does not inherit the scope around the loop: it is opened again inside
+    with jax.named_scope("prefill"):
+        return model.apply(params, *args, method=method)
+
+
+def _prefill_cuts(b: int, n: int) -> Tuple[int, int]:
+    """Rows an attention chunk and tokens a feed-forward chunk of a prompt pass over ``b`` rows of ``n``."""
+    return _chunks(b, _PREFILL_ATTENTION_TOKENS // n), _chunks(b * n, _PREFILL_FFN_TOKENS)
+
+
+def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = False) -> Tuple[jnp.ndarray, tuple]:
     """The prompt pass: last-position logits (B, V) and, a layer, the cache
     rows of the prompt: (B, N, width) of a latent layer; of a grouped-query
     layer the keys and the values, each (B * Hkv, N, D), a window layer's
@@ -284,18 +401,16 @@ def prefill(model: DecoderLanguageModel, params, input_ids) -> Tuple[jnp.ndarray
     stays in memory between layers (B * N * h); within a layer the attention
     runs over chunks of whole rows and the feed-forward over chunks of tokens
     (``_PREFILL_ATTENTION_TOKENS``, ``_PREFILL_FFN_TOKENS``), inside the one
-    program."""
+    program. ``keep_hidden`` also returns the last block's output (B, N, h),
+    which the multi-token-prediction module's own prompt pass reads
+    (:func:`prefill_module`)."""
     c = model.config
     b, n = input_ids.shape
     h = c.hidden_size
-    rows_a = _chunks(b, _PREFILL_ATTENTION_TOKENS // n)
-    tokens_f = _chunks(b * n, _PREFILL_FFN_TOKENS)
+    rows_a, tokens_f = _prefill_cuts(b, n)
     pos = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None], (rows_a, n))
 
-    def scoped(method, *args):
-        # a loop's body does not inherit the scope around the loop: it is opened again inside
-        with jax.named_scope("prefill"):
-            return model.apply(params, *args, method=method)
+    scoped = functools.partial(_scoped, model, params)
 
     x = scoped("embed", input_ids)
     cache_rows = []
@@ -307,7 +422,30 @@ def prefill(model: DecoderLanguageModel, params, input_ids) -> Tuple[jnp.ndarray
             cache_rows.append(tuple(r.reshape(b * r.shape[2], *r.shape[3:]) for r in rows))
         x, _ = _over_chunks(lambda xc, i=i: (scoped("ffn_layer", xc, i), ()), x.reshape(b * n // tokens_f, tokens_f, h))
         x = x.reshape(b, n, h)
+    if keep_hidden:
+        return scoped("logits", x[:, -1]), tuple(cache_rows), x
     return scoped("logits", x[:, -1]), tuple(cache_rows)
+
+
+def prefill_module(model: DecoderLanguageModel, params, hidden, next_ids) -> Tuple[jnp.ndarray, tuple]:
+    """The multi-token-prediction module's prompt pass, cut as the stack's:
+    ``hidden`` (B, N, h) is the last block's output over the prompt (its
+    buffer is written over), ``next_ids`` (B, N) the token after each
+    position (the prompt from its second token on, then the first token
+    sampled). Returns the module's last-position logits (B, V), its draft of
+    the token after the sampled one, and its block's cache rows."""
+    b, n, h = hidden.shape
+    rows_a, tokens_f = _prefill_cuts(b, n)
+    pos = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None], (rows_a, n))
+
+    scoped = functools.partial(_scoped, model, params)
+
+    x, _ = _over_chunks(lambda xc, ids: (scoped("mtp_project", xc, ids), ()),
+                        hidden.reshape(b * n // tokens_f, tokens_f, h), next_ids.reshape(b * n // tokens_f, tokens_f))
+    x, rows = _over_chunks(lambda xc: scoped("mtp_attend", xc, pos), x.reshape(b // rows_a, rows_a, n, h))
+    rows = tuple(r.reshape(b * r.shape[2], *r.shape[3:]) for r in rows)
+    x, _ = _over_chunks(lambda xc: (scoped("mtp_ffn", xc), ()), x.reshape(b * n // tokens_f, tokens_f, h))
+    return scoped("mtp_logits", x.reshape(b, n, h)[:, -1]), rows
 
 
 class _Decoder:
@@ -319,14 +457,29 @@ class _Decoder:
     :class:`WindowKVCache` ring for a window layer, side by side). Nothing the
     generator owns slides: a growing cache's capacity is the prompt plus the
     new tokens, which must fit ``max_position_embeddings``, and a ring
-    overwrites the position that left its window."""
+    overwrites the position that left its window.
+
+    Where the configuration has a multi-token-prediction module the decoder is
+    ``speculative`` and the generator runs the ``spec_*`` methods in the
+    one-token step's place (``generation._generate_speculative``): the prompt
+    pass of the stack and of the module, then steps that verify
+    ``spec_positions`` positions a row. The window is then the stack's caches
+    and the module's, the last one, all with a length a row."""
 
     window_names = ("cache",)
     const_names = ()
-    tap_scopes = ("moe.*",)
+    tap_scopes = ("moe.*", "spec.*")
+    # a speculative step verifies a row's last emitted token and one draft after it; a ring
+    # needs a slot of slack for each position that may be written and not kept
+    spec_positions = 2
+    ring_slack = spec_positions - 1
 
     def __init__(self, model: DecoderLanguageModel):
         self.model = model
+
+    @property
+    def speculative(self) -> bool:
+        return bool(self.model.config.num_nextn_predict_layers)
 
     def _caches(self, rows, batch: int, n: int, max_new_tokens: int, cache_dtype):
         c = self.model.config
@@ -339,10 +492,8 @@ class _Decoder:
             for kind, (k, v) in zip(c.layer_types, rows)
         )
 
-    def prefill(self, params, input_ids, pad_mask, num_latents, max_new_tokens, cache_dtype):
-        del num_latents  # no latent window: every position passes the whole stack
+    def _refuse(self, pad_mask, n: int, max_new_tokens: int):
         c = self.model.config
-        b, n = input_ids.shape
         if pad_mask is not None:
             raise ValueError("the decoder-only model takes no pad_mask: batch prompts of one length")
         if n + max_new_tokens > c.max_position_embeddings:
@@ -350,6 +501,11 @@ class _Decoder:
                 f"prompt ({n}) + max_new_tokens ({max_new_tokens}) exceeds max_position_embeddings "
                 f"({c.max_position_embeddings})"
             )
+
+    def prefill(self, params, input_ids, pad_mask, num_latents, max_new_tokens, cache_dtype):
+        del num_latents  # no latent window: every position passes the whole stack
+        b, n = input_ids.shape
+        self._refuse(pad_mask, n, max_new_tokens)
         logits, rows = prefill(self.model, params, input_ids)
         caches = self._caches(rows, b, n, max_new_tokens, cache_dtype)
         return logits[:, None], (caches,), ()
@@ -359,9 +515,58 @@ class _Decoder:
         logits, caches = self.model.apply(step_params, token, window[0], method="decode_step")
         return logits[:, None], (caches,)
 
+    # ---------------------------------------------- the speculative decoder
+
+    def _ragged_cache(self, kind: str, k, v, batch: int, n: int, max_new_tokens: int, cache_dtype):
+        c = self.model.config
+        heads, d = c.num_key_value_heads, c.head_dim
+        if kind == "sliding_attention":
+            return init_ragged_window_kv_cache(batch, heads, c.sliding_window, self.ring_slack, d, d, cache_dtype).fill(k, v, n)
+        # the last step of a row writes its draft one slot past the last token the row is asked for
+        return init_ragged_kv_cache(batch, heads, n + max_new_tokens + self.ring_slack, d, d, cache_dtype).fill(k, v)
+
+    def spec_prefill(self, params, input_ids, pad_mask, max_new_tokens, cache_dtype, sample):
+        """The prompt pass of the stack, the first token by ``sample(logits
+        (B, V))``, then the module's prompt pass over the same positions
+        (position i takes ``h_i`` and token ``i + 1``, the last one the token
+        just sampled). Returns that token (B,), the logits (B, V) it was
+        sampled from, the module's logits (B, V) for the token after it, and
+        the window: every cache filled."""
+        c = self.model.config
+        b, n = input_ids.shape
+        self._refuse(pad_mask, n, max_new_tokens)
+        logits, rows, hidden = prefill(self.model, params, input_ids, keep_hidden=True)
+        token = sample(logits)
+        next_ids = jnp.concatenate([input_ids[:, 1:], token[:, None].astype(input_ids.dtype)], axis=1)
+        draft_logits, module_rows = prefill_module(self.model, params, hidden, next_ids)
+        caches = tuple(
+            self._ragged_cache(kind, k, v, b, n, max_new_tokens, cache_dtype)
+            for kind, (k, v) in zip(c.layer_types + c.mtp_layer_types, rows + (module_rows,))
+        )
+        return token, logits, draft_logits, (caches,)
+
+    def spec_verify(self, step_params, window, tokens):
+        """The stack on ``tokens`` (B, ``spec_positions``): logits (B, n, V),
+        the last block's output (B, n, h), and the window with the positions
+        written to the stack's caches, none kept yet."""
+        caches = window[0]
+        logits, hidden, stack = self.model.apply(step_params, tokens, caches[:-1], method="verify_step")
+        return logits, hidden, (stack + caches[-1:],)
+
+    def spec_draft(self, step_params, window, hidden, next_tokens):
+        """The module on the same positions, given the token that followed each: its logits (B, n, V), its cache written."""
+        caches = window[0]
+        logits, cache = self.model.apply(step_params, next_tokens, hidden, caches[-1], method="draft_step")
+        return logits, (caches[:-1] + (cache,),)
+
+    def spec_keep(self, window, m):
+        """Every cache keeps a row's first ``m`` (B,) of the positions last written."""
+        return (tuple(cache.keep(m) for cache in window[0]),)
+
     def health(self, logits, window):
         # the occupancy gauge reads a cache that grows: a ring is full from its window on
-        grows = next((cache for cache in window[0] if not isinstance(cache, WindowKVCache)), window[0][0])
+        rings = (WindowKVCache, RaggedWindowKVCache)
+        grows = next((cache for cache in window[0] if not isinstance(cache, rings)), window[0][0])
         return probes.decode_health(logits, grows, jnp.zeros((), jnp.int32))
 
     def compile_row(self, batch: int, prompt_len: int, max_new_tokens: int, cache_dtype) -> dict:
@@ -372,16 +577,24 @@ class _Decoder:
         moe = {"moe_combine": grouped_combine(c.n_held_experts, c.n_routed_experts)}
         if c.layer_types is not None:
             row_bytes = 2 * c.num_key_value_heads * c.head_dim * itemsize  # a token's keys and values in one layer
-            n_window = c.layer_types.count("sliding_attention")
-            n_full = c.num_hidden_layers - n_window
-            return {
+            # the module's block keeps a cache of its own kind beside the stack's
+            kinds = c.layer_types + (c.mtp_layer_types if self.speculative else ())
+            slack = self.ring_slack if self.speculative else 0
+            n_window = kinds.count("sliding_attention")
+            n_full = len(kinds) - n_window
+            row = {
                 "kv_cache_full_layers": n_full,
                 "kv_cache_window_layers": n_window,
-                "kv_cache_full_bytes": batch * (prompt_len + max_new_tokens) * row_bytes * n_full,
-                "kv_cache_window_bytes": batch * c.sliding_window * row_bytes * n_window,
+                "kv_cache_full_bytes": batch * (prompt_len + max_new_tokens + slack) * row_bytes * n_full,
+                "kv_cache_window_bytes": batch * (c.sliding_window + slack) * row_bytes * n_window,
                 "kv_cache_window_rows": c.sliding_window,
+                "kv_cache_lengths": "row" if self.speculative else "batch",
                 **moe,
             }
+            if self.speculative:
+                row.update(mtp_layers=c.num_nextn_predict_layers, spec_positions_per_step=self.spec_positions,
+                           kv_cache_window_slack_rows=slack)
+            return row
         row_bytes = (c.kv_lora_rank + c.qk_rope_head_dim) * itemsize
         return {
             "latent_cache_row_bytes": row_bytes,

@@ -283,7 +283,8 @@ def attach_train_stats(pstats: Dict, config: ProbeConfig, grads, old_params, new
 def decode_health(logits, kv_cache, kv_start) -> Dict:
     """The per-token decode gauges, computed in-graph from the step body's
     last-position logits and the post-append cross-attention cache:
-    KV-window occupancy fraction, mean logit entropy (nats — collapsing
+    KV-window occupancy fraction (the batch's mean where the cache keeps a
+    length a row), mean logit entropy (nats — collapsing
     entropy is the classic degenerate-sampling signal), and the non-finite
     logit fraction (the serving-side numerics probe)."""
     import jax
@@ -294,6 +295,8 @@ def decode_health(logits, kv_cache, kv_start) -> Dict:
         logp = jax.nn.log_softmax(l32, axis=-1)
         ent = -jnp.sum(jnp.where(jnp.isfinite(logp), jnp.exp(logp) * logp, 0.0), axis=-1)
         used = (kv_cache.length - kv_start).astype(jnp.float32)
+        if used.ndim:  # a cache that keeps a length a row
+            used = jnp.mean(used)
         return {
             "logit_entropy": jnp.mean(ent),
             "kv_cache_frac": used / float(kv_cache.capacity),

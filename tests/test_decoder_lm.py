@@ -303,8 +303,13 @@ def test_router_against_a_loop(seed, bias_scale):
 # -------------------------------------------------- the expert layer's share
 
 
-def moe_layer_and_weights(seed, tokens=48):
-    config = tiny_config(n_held_experts=16, held_experts_start=0)
+# (chips that share a layer, groups of the router, groups kept): DeepSeek-V3's share of PR 28 in small, and K-EXAONE's
+# (PR 34): eight chips with two experts each of sixteen, one group, so no group limit
+SHARE_GEOMETRIES = {"four_shares_of_four_groups": (4, 4, 2), "eight_shares_of_one_group": (8, 1, 1)}
+
+
+def moe_layer_and_weights(seed, tokens=48, **kw):
+    config = tiny_config(n_held_experts=16, held_experts_start=0, **kw)
     layer = moe.MoELayer(config)
     x = jax.random.normal(jax.random.PRNGKey(seed), (tokens, config.hidden_size))
     params = layer.init(jax.random.PRNGKey(seed + 1), x)
@@ -312,22 +317,26 @@ def moe_layer_and_weights(seed, tokens=48):
     return config, x, params
 
 
+@pytest.mark.parametrize("geometry", sorted(SHARE_GEOMETRIES))
 @pytest.mark.parametrize("path", ["grouped", "dense"])
 @pytest.mark.parametrize("seed", [0, 1])
-def test_the_shares_add_up_to_the_uncut_layer(seed, path):
-    """Four chips with four experts each: what each share adds beyond the
-    shared expert (which every chip computes alike, counted once) sums to
-    the uncut reference's layer."""
-    config, x, params = moe_layer_and_weights(seed, CUTS.grouped_min_tokens if path == "grouped" else 48)
+def test_the_shares_add_up_to_the_uncut_layer(seed, path, geometry):
+    """The chips that share a layer, each with its experts: what each share
+    adds beyond the shared expert (which every chip computes alike, counted
+    once) sums to the uncut reference's layer."""
+    shares, n_group, topk_group = SHARE_GEOMETRIES[geometry]
+    held = 16 // shares
+    config, x, params = moe_layer_and_weights(seed, CUTS.grouped_min_tokens if path == "grouped" else 48,
+                                              n_group=n_group, topk_group=topk_group)
     w = {"l/" + k: v for k, v in flat_dict(params["params"]).items()}
     whole = np.asarray(reference.experts(x, w, "l", reference_cfg(config), "float32"))
     shared = np.asarray(reference.swiglu(x, w["l/shared/w1"], w["l/shared/w3"], w["l/shared/w2"], "float32"))
     total = shared.copy()
-    for start in (0, 4, 8, 12):
-        share = dataclasses.replace(config, n_held_experts=4, held_experts_start=start)
+    for start in range(0, 16, held):
+        share = dataclasses.replace(config, n_held_experts=held, held_experts_start=start)
         p = dict(params["params"])
         for name in ("experts_w1", "experts_w3", "experts_w2"):
-            p[name] = params["params"][name][start:start + 4]
+            p[name] = params["params"][name][start:start + held]
         y = np.asarray(moe.MoELayer(share).apply({"params": p}, x))
         # the same share in the reference
         ws = {**w, **{"l/" + name: p[name] for name in ("experts_w1", "experts_w3", "experts_w2")}}
@@ -499,3 +508,48 @@ def ar_lowered_texts():
 @pytest.mark.parametrize("program", sorted(AR_GOLDEN))
 def test_the_perceiver_ar_generators_lowered_program_is_the_parents(program):
     assert ar_lowered_texts()[program] == AR_GOLDEN[program]
+
+
+# ---------------------- the decoder-only generators of PR 28 and PR 32 are untouched
+
+# The same pin for the decoder-only model's two older configurations at tiny
+# sizes, taken on the parent commit of PR 34 (which gave the class a third
+# configuration, caches with a length a row and a speculative generator
+# beside these): the one-token generator, prompt pass and step that the
+# latent-attention and the window/full grouped-query configurations trace are
+# the parent's to the character. A PR that means to change them updates these.
+DECODER_GOLDEN = {
+    "dsv3_generate": "cc2078bfe6bb6924fca1cbf90373efac1e20b8d647febe0c165013bcb1139976",
+    "dsv3_prefill": "fba0c6827d7026a9a0db2f512985207aa72cb3abb63a34efcf7b030b8c094e9b",
+    "dsv3_step": "4261771d334aa9c299470092e1e0f08a6dc844f910de7c1b16533133701c6feb",
+    "mellum_generate": "67b1747f7062e960de892be626600397e8c99b4ccda74c499b1c2b96bbe91302",
+    "mellum_prefill": "3bbf084c3ae43a405f9111f379c590a20da7deee040ec4c9ee9e6b7dcd2d753b",
+    "mellum_step": "97e7bf94b89628ce650a95460eb7bd0c59e6c3618a1af7dd8585cd9b01bf0c21",
+}
+
+
+def decoder_lowered_texts():
+    mellum = DecoderLanguageModelConfig(
+        vocab_size=VOCAB, hidden_size=64, num_hidden_layers=4, first_k_dense_replace=0, moe_intermediate_size=32,
+        num_attention_heads=8, num_key_value_heads=2, head_dim=16,
+        layer_types=("sliding_attention", "sliding_attention", "sliding_attention", "full_attention"), sliding_window=8,
+        n_routed_experts=8, num_experts_per_tok=2, n_shared_experts=0, n_group=1, topk_group=1, scoring_func="softmax",
+        rope_theta=500000.0, init_scale=0.3, max_position_embeddings=512,
+        rope_scaling=YarnConfig(factor=4.0, beta_fast=32.0, beta_slow=1.0, original_max_position_embeddings=8, attention_factor=1.1386),
+    )
+    texts = {}
+    for name, config in (("dsv3", tiny_config()), ("mellum", mellum)):
+        model = DecoderLanguageModel(config)
+        ids = jnp.zeros((4, 12), jnp.int32)
+        params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids))
+        gen_cfg = GenerationConfig(max_new_tokens=6, eos_token_id=3)
+        texts[name + "_generate"] = make_generate_fn(model, config=gen_cfg, cache_dtype=jnp.bfloat16).lower(params, ids).as_text()
+        prefill, step = make_decode_fns(model, 1, gen_cfg, probes=True)
+        texts[name + "_prefill"] = prefill.lower(params, ids).as_text()
+        texts[name + "_step"] = step.lower(jax.eval_shape(prefill, params, ids)[1]).as_text()
+    return {k: hashlib.sha256(re.sub(r"loc\(.*", "", t).encode()).hexdigest() for k, t in texts.items()}
+
+
+@pytest.mark.parametrize("program", sorted(DECODER_GOLDEN))
+def test_the_decoder_only_generators_lowered_program_is_the_parents(program):
+    assert decoder_lowered_texts()[program] == DECODER_GOLDEN[program]

@@ -1,0 +1,383 @@
+"""The decoder-only model's third configuration (the K-EXAONE family) against
+its plain reference, at tiny widths that keep the published ratios: hidden 64,
+8 query heads on 2 key-value heads of 16 with a q/k norm, five layers
+``sliding`` (dense), ``sliding``, ``sliding``, ``full``, ``sliding`` (sparse)
+with a window of 4 and no rotary on the full layer, 16 sigmoid-routed experts in
+one group of which 4 are held and 2 taken a token, one shared expert, and the
+multi-token-prediction module: one full-attention block with a sparse
+feed-forward. The module makes the generator speculative: a step verifies two
+positions a row over caches with a length a row.
+
+Tolerances are ``tests/test_decoder_lm.py``'s: float32 products at "highest"
+precision differ from ``benchmarks/reference/exaone_moe.py`` only in the order
+of float32 sums, 2e-4 absolute on logits of magnitude up to about 10 (observed
+under 1e-4); bfloat16 in the reference's place moves them by 5e-2 and more, and
+a test says so. Routing is discrete, so a seed with a near-tie would fail
+loudly, not flakily: none of the seeds used has one."""
+
+import dataclasses
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.lib.weights import flat_dict
+from benchmarks.reference import exaone_moe as reference
+from perceiver_io_tpu import generation
+from perceiver_io_tpu.core.cache import init_ragged_kv_cache, init_ragged_window_kv_cache
+from perceiver_io_tpu.core.gqa import cached_verify_attention
+from perceiver_io_tpu.generation import GenerationConfig, make_decode_fns, make_generate_fn, make_instrumented_generate_fn
+from perceiver_io_tpu.models.text.decoder_lm import DecoderLanguageModel, DecoderLanguageModelConfig
+
+TOL = 2e-4
+VOCAB = 96
+WINDOW = 4
+LAYER_TYPES = ("sliding_attention", "sliding_attention", "sliding_attention", "full_attention", "sliding_attention")
+
+
+def tiny_config(**kw) -> DecoderLanguageModelConfig:
+    base = dict(
+        vocab_size=VOCAB, hidden_size=64, num_hidden_layers=5, first_k_dense_replace=1, intermediate_size=160,
+        moe_intermediate_size=32, num_attention_heads=8, num_key_value_heads=2, head_dim=16, layer_types=LAYER_TYPES,
+        sliding_window=WINDOW, n_routed_experts=16, n_held_experts=4, held_experts_start=4, num_experts_per_tok=2,
+        n_shared_experts=1, n_group=1, topk_group=1, scoring_func="sigmoid", routed_scaling_factor=2.5,
+        rms_norm_eps=1e-5, rope_theta=1e6, rope_scaling=None, qk_norm=True, full_attention_rotary=False,
+        num_nextn_predict_layers=1, mtp_layer_types=("full_attention",), init_scale=0.3, max_position_embeddings=512,
+    )
+    base.update(kw)
+    return DecoderLanguageModelConfig(**base)
+
+
+def reference_cfg(config: DecoderLanguageModelConfig) -> dict:
+    return dataclasses.asdict(config)
+
+
+def seeded(config, seed: int, batch: int = 3, n: int = 7):
+    """A model, weights drawn from the seed (every leaf noisy, the norms'
+    scales 1 + noise, the router's bias too), and prompt ids."""
+    model = DecoderLanguageModel(config)
+    k_ids, k_init, k_noise = jax.random.split(jax.random.PRNGKey(seed), 3)
+    ids = jax.random.randint(k_ids, (batch, n), 0, config.vocab_size)
+    params = model.init(k_init, ids, drafts=bool(config.num_nextn_predict_layers))
+    leaves, tree = jax.tree.flatten(params)
+    keys = jax.random.split(k_noise, len(leaves))
+    params = jax.tree.unflatten(tree, [p + 0.1 * jax.random.normal(k, p.shape) for p, k in zip(leaves, keys)])
+    return model, params, ids
+
+
+def without_module(config, params):
+    """The same stack with no module: the plain one-token generator's model and weights."""
+    plain = dataclasses.replace(config, num_nextn_predict_layers=0)
+    return DecoderLanguageModel(plain), {"params": {k: v for k, v in params["params"].items() if k != "mtp"}}
+
+
+# --------------------------------------------------- the whole model, no cache
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("n", [3, WINDOW, 11], ids=["shorter_than_the_window", "the_window", "longer"])
+def test_full_forward_and_the_modules_logits_match_the_reference(seed, n):
+    config = tiny_config()
+    model, params, ids = seeded(config, seed, n=n)
+    got, got_drafts = model.apply(params, ids, drafts=True)
+    w = flat_dict(params)
+    want = np.asarray(reference.logits(w, ids, reference_cfg(config)))
+    want_drafts = np.asarray(reference.mtp_logits(w, ids, reference_cfg(config)))
+    assert np.abs(want).max() > 1.0 and np.abs(want_drafts).max() > 1.0
+    np.testing.assert_allclose(np.asarray(got), want, atol=TOL, rtol=0)
+    np.testing.assert_allclose(np.asarray(got_drafts), want_drafts, atol=TOL, rtol=0)
+    assert got_drafts.shape == (3, n - 1, VOCAB)
+
+
+def test_bfloat16_in_the_references_place_fails_the_float32_tolerance():
+    config = tiny_config()
+    _, params, ids = seeded(config, 0, n=11)
+    w = flat_dict(params)
+    for fn in (reference.logits, reference.mtp_logits):
+        want = np.asarray(fn(w, ids, reference_cfg(config)))
+        lower = np.asarray(fn(w, ids, reference_cfg(config), precision="bfloat16"))
+        assert np.abs(lower - want).max() > 50 * TOL
+
+
+@pytest.mark.parametrize("change", ["no_qk_norm", "rotary_on_the_full_layers", "the_window_layers_run_full"])
+def test_each_assumed_equation_is_held_by_the_comparison(change):
+    """The q/k norm, which layers rotate and the window are in the compared numbers: a program without one fails."""
+    config = tiny_config()
+    model, params, ids = seeded(config, 0, n=11)
+    wrong = {"no_qk_norm": dict(qk_norm=False), "rotary_on_the_full_layers": dict(full_attention_rotary=True),
+             "the_window_layers_run_full": dict(sliding_window=64)}[change]
+    got = np.asarray(DecoderLanguageModel(dataclasses.replace(config, **wrong)).apply(params, ids))
+    want = np.asarray(reference.logits(flat_dict(params), ids, reference_cfg(config)))
+    assert np.abs(got - want).max() > 50 * TOL
+
+
+# ------------------------------------- prompt pass, then speculative steps
+
+
+def served(model, params, ids, new_tokens: int, cache_dtype=jnp.float32):
+    """The decoder's own methods driven as ``generation._generate_speculative``
+    drives them, keeping what the generator throws away: for every row the
+    stack's logits at each emitted position, the module's logits at each kept
+    position, the tokens, and a row's count after each step."""
+    decoder = model.generation_decoder()
+    b, n = ids.shape
+    token, first, draft_logits, window = decoder.spec_prefill(
+        params, ids, None, new_tokens, cache_dtype, lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32))
+    main = [[np.asarray(first)[r]] for r in range(b)]
+    drafts = [[np.asarray(draft_logits)[r]] for r in range(b)]
+    tokens = [[int(token[r])] for r in range(b)]
+    draft = jnp.argmax(draft_logits, axis=-1).astype(jnp.int32)
+    count, counts, accepted, verified = np.ones(b, int), [], 0, 0
+    while (count < new_tokens).any():
+        p_logits, hidden, window = decoder.spec_verify(params, window, jnp.stack([token, draft], axis=1))
+        g = np.asarray(jnp.argmax(p_logits, axis=-1))
+        live = count < new_tokens
+        m = np.where(live, np.minimum(np.where(g[:, 0] == np.asarray(draft), 2, 1), new_tokens - count), 0)
+        verified, accepted = verified + live.sum(), accepted + (live & (g[:, 0] == np.asarray(draft))).sum()
+        m_logits, window = decoder.spec_draft(params, window, hidden, jnp.asarray(g, jnp.int32))
+        for r in range(b):
+            for j in range(m[r]):
+                main[r].append(np.asarray(p_logits)[r, j])
+                drafts[r].append(np.asarray(m_logits)[r, j])
+                tokens[r].append(int(g[r, j]))
+        last = np.maximum(m - 1, 0)
+        token = jnp.where(live, jnp.asarray(g[np.arange(b), last], jnp.int32), token)
+        draft = jnp.where(live, jnp.argmax(jnp.asarray(np.asarray(m_logits)[np.arange(b), last]), axis=-1).astype(jnp.int32), draft)
+        window = decoder.spec_keep(window, jnp.asarray(m, jnp.int32))
+        count = count + m
+        counts.append(count.copy())
+    return (np.stack([np.stack(x) for x in main]), np.stack([np.stack(x) for x in drafts]), np.asarray(tokens),
+            np.stack(counts), accepted / max(verified, 1))
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("n", [3, WINDOW, 6, 9], ids=lambda n: f"prompt{n}")
+def test_prompt_pass_then_speculative_steps_match_the_references_full_forward(seed, n):
+    """(a) and (b): every logit the generator emits a token from, and every
+    draft logit it kept, against the reference's one forward over the prompt
+    with the served tokens. Prompts shorter than the window, as long, and
+    longer; 12 new tokens so that every ring wraps (5 slots: window 4 and one of
+    slack). Vocabulary 12: some drafts are accepted, so both branches write."""
+    config = tiny_config(vocab_size=12)
+    model, params, ids = seeded(config, seed, n=n)
+    new = 12
+    main, drafts, tokens, _, _ = served(model, params, ids, new)
+    full = jnp.concatenate([ids, jnp.asarray(tokens, ids.dtype)], axis=1)
+    w = flat_dict(params)
+    want = np.asarray(reference.logits(w, full, reference_cfg(config)))[:, n - 1:n - 1 + new]
+    np.testing.assert_allclose(main, want, atol=TOL, rtol=0)
+    np.testing.assert_array_equal(tokens, want.argmax(-1))
+    # the module at position i read token i + 1, the one served: positions n - 1 .. n + new - 2
+    want_drafts = np.asarray(reference.mtp_logits(w, full, reference_cfg(config)))[:, n - 1:n - 1 + new]
+    np.testing.assert_allclose(drafts, want_drafts, atol=TOL, rtol=0)
+
+
+def test_bfloat16_caches_serve_within_the_caches_rounding():
+    config = tiny_config(vocab_size=12)
+    model, params, ids = seeded(config, 0, n=9)
+    exact = served(model, params, ids, 8)[0]
+    rounded = served(model, params, ids, 8, cache_dtype=jnp.bfloat16)[0]
+    # a row may take another token once its logits moved: compare the positions both served from the same tokens
+    assert 1e-4 < np.abs(rounded[:, 0:2] - exact[:, 0:2]).max() < 0.3
+
+
+# ------------------------------------------------ the generator, token for token
+
+SPEC_SEEDS = [0, 1, 4, 7]  # of seeds 0 to 7 these accept 12 to 23% of their drafts (seed 3: 3%, under the asserted range)
+
+
+@pytest.mark.parametrize("seed", SPEC_SEEDS)
+def test_speculative_greedy_output_is_plain_greedy_output_token_for_token(seed, tmp_path):
+    """(c): vocabulary 8, so that the module's draft is often the stack's
+    next token. The compiled generator, the host-driven pair and the
+    instrumented wrapper all serve the plain one-token generator's tokens;
+    between 5% and 60% of the drafts are accepted and the rows of one batch
+    differ in length along the way."""
+    config = tiny_config(vocab_size=8)
+    model, params, ids = seeded(config, seed, batch=4, n=9)
+    plain_model, plain_params = without_module(config, params)
+    gen_cfg = GenerationConfig(max_new_tokens=24)
+    want = np.asarray(make_generate_fn(plain_model, config=gen_cfg)(plain_params, ids))
+    got = np.asarray(make_generate_fn(model, config=gen_cfg)(params, ids))
+    assert got.shape == want.shape == (4, 9 + 24)
+    np.testing.assert_array_equal(got, want)
+
+    prefill, step = make_decode_fns(model, config=gen_cfg)
+    token, state = prefill(params, ids)
+    rows, counts = [[int(t)] for t in token], []
+    while int(state["count"].min()) < 24:
+        state, span = step(state)
+        for r in range(4):
+            rows[r].extend(int(t) for t in span[r, :int(state["emitted"][r])])
+        counts.append(np.asarray(state["count"]))
+    np.testing.assert_array_equal(np.asarray(rows), want[:, 9:])
+    counts = np.stack(counts)
+    assert (counts.max(1) != counts.min(1)).any(), "every row advanced alike: no ragged lengths were run"
+    assert counts[-1].tolist() == [24] * 4 and len(counts) < 23  # fewer steps than one token a step takes, for every row
+
+    from perceiver_io_tpu.obs.events import EventLog
+
+    fn = make_instrumented_generate_fn(model, config=gen_cfg, events=EventLog(str(tmp_path)), probes=True)
+    out, stats = fn(params, ids)
+    np.testing.assert_array_equal(np.asarray(out), want)
+    snapshot = fn.registry.snapshot()
+    snap = {**snapshot["counters"], **snapshot["gauges"]}
+    assert stats.outcome == "ok" and stats.tokens_out == 24 and snap["spec_steps_total"] == len(counts)
+    # a draft a live row a step: the rows' steps are the steps until each had its 24 tokens
+    live_steps = int((np.concatenate([np.ones((1, 4), int), counts[:-1]]) < 24).sum())
+    assert snap["spec_drafts_total"] == live_steps
+    rate = snap["spec_accepted_total"] / snap["spec_drafts_total"]
+    assert 0.05 <= rate <= 0.60 and snap["spec_accept_rate"] == pytest.approx(rate)
+
+
+def test_an_eos_token_freezes_a_row_as_the_plain_generator_does():
+    config = tiny_config(vocab_size=12)
+    model, params, ids = seeded(config, 1, batch=4, n=9)
+    plain_model, plain_params = without_module(config, params)
+    free = np.asarray(make_generate_fn(plain_model, config=GenerationConfig(max_new_tokens=16))(plain_params, ids))
+    eos = int(free[0, 9 + 5])  # a token the first row emits on its way
+    gen_cfg = GenerationConfig(max_new_tokens=16, eos_token_id=eos, pad_token_id=0)
+    want = np.asarray(make_generate_fn(plain_model, config=gen_cfg)(plain_params, ids))
+    assert (want[0, 9 + 6:] == 0).all()
+    np.testing.assert_array_equal(np.asarray(make_generate_fn(model, config=gen_cfg)(params, ids)), want)
+
+
+def test_the_generator_refuses_a_temperature_and_a_pad_mask():
+    config = tiny_config()
+    model, params, ids = seeded(config, 0)
+    with pytest.raises(ValueError, match="generates greedily"):
+        make_generate_fn(model, config=GenerationConfig(max_new_tokens=4, do_sample=True, temperature=0.8))(params, ids)
+    with pytest.raises(ValueError, match="generates greedily"):
+        make_decode_fns(model, config=GenerationConfig(max_new_tokens=4, do_sample=True))
+    with pytest.raises(ValueError, match="no pad_mask"):
+        make_generate_fn(model, config=GenerationConfig(max_new_tokens=4))(params, ids, jnp.zeros(ids.shape, bool))
+    with pytest.raises(ValueError, match="multi-token-prediction module"):
+        tiny_config(layer_types=None, num_key_value_heads=None)
+
+
+def test_one_new_token_needs_no_step():
+    config = tiny_config()
+    model, params, ids = seeded(config, 0)
+    out = np.asarray(make_generate_fn(model, config=GenerationConfig(max_new_tokens=1))(params, ids))
+    np.testing.assert_array_equal(out[:, -1], np.asarray(model.apply(params, ids))[:, -1].argmax(-1))
+
+
+# ----------------------------------------------------- the caches, a length a row
+
+
+def dense_window_attention(q, keys, values, q_pos, window, scale):
+    """One query at ``q_pos`` over true positions ``0 .. q_pos``: ``q`` (D,), ``keys``/``values`` (P, D)."""
+    lo = 0 if window is None else max(q_pos - window + 1, 0)
+    s = (keys[lo:q_pos + 1] @ q) * scale
+    p = np.exp(s - s.max())
+    return (p / p.sum()) @ values[lo:q_pos + 1]
+
+
+@pytest.mark.parametrize("kind", ["ring", "growing"])
+def test_a_rejected_draft_leaves_the_next_step_as_if_never_written(kind):
+    """(d): a ring of window 4 (5 slots) and a growing cache, three rows with
+    their own accept patterns over 14 steps from a prompt of 6 (the ring has
+    wrapped and wraps again twice). Every step writes a row's true position
+    and a draft after it: the true next position where the pattern accepts,
+    noise where it rejects. Each query's output equals a dense softmax over
+    the row's true keys alone, as if no rejected draft had ever been written."""
+    rng = np.random.default_rng(0)
+    b, heads, d, n, steps, window = 3, 2, 8, 6, 14, 4 if kind == "ring" else None
+    total = n + 2 * steps + 2
+    keys, values = rng.normal(size=(2, b, heads, total, d)).astype(np.float32)
+    queries = rng.normal(size=(b, heads, total, d)).astype(np.float32)
+    accepts = rng.random((steps, b)) < np.array([0.0, 0.5, 1.0])  # never, sometimes, always
+    flat = lambda a: jnp.asarray(a.reshape(b * heads, *a.shape[2:]))  # noqa: E731
+    if kind == "ring":
+        cache = init_ragged_window_kv_cache(b, heads, window, 1, d, d).fill(flat(keys[:, :, n - window:n]), flat(values[:, :, n - window:n]), n)
+    else:
+        cache = init_ragged_kv_cache(b, heads, total, d, d).fill(flat(keys[:, :, :n]), flat(values[:, :, :n]))
+    length = np.full(b, n)
+    for step in range(steps):
+        at = length[:, None] + np.arange(2)[None, :]  # (B, 2): the position and the one after it
+        take = lambda a: np.take_along_axis(a, at[:, None, :, None], axis=2)  # noqa: E731
+        k_new, v_new, q_new = take(keys), take(values), take(queries)
+        noise = rng.normal(size=(2, b, heads, d)).astype(np.float32) * 3
+        k_new[:, :, 1] = np.where(accepts[step][:, None, None], k_new[:, :, 1], noise[0])
+        v_new[:, :, 1] = np.where(accepts[step][:, None, None], v_new[:, :, 1], noise[1])
+        cache = cache.write(flat(k_new), flat(v_new))
+        out = np.asarray(cached_verify_attention(flat(q_new), cache, cache.visible(2), 0.5)).reshape(b, heads, 2, d)  # one query head a key-value head
+        for r in range(b):
+            for h in range(heads):
+                for j in range(2 if accepts[step][r] else 1):
+                    want = dense_window_attention(queries[r, h, at[r, j]], keys[r, h], values[r, h], at[r, j], window, 0.5)
+                    np.testing.assert_allclose(out[r, h, j], want, atol=1e-5)
+        m = np.where(accepts[step], 2, 1)
+        cache = cache.keep(jnp.asarray(m))
+        length = length + m
+    assert length.tolist() == [n + steps, n + steps + accepts[:, 1].sum(), n + 2 * steps]
+    np.testing.assert_array_equal(np.asarray(cache.length), length)
+
+
+def test_a_ring_without_slack_refuses_a_second_position():
+    cache = init_ragged_window_kv_cache(2, 1, 4, 0, 8, 8)
+    with pytest.raises(ValueError, match="slots of slack"):
+        cache.write(jnp.zeros((2, 2, 8)), jnp.zeros((2, 2, 8)))
+    with pytest.raises(ValueError, match="fills a ring of window 4"):
+        cache.fill(jnp.zeros((2, 3, 8)), jnp.zeros((2, 3, 8)), 9)
+
+
+# ---------------------------------------------------- spans, counters, the compile row
+
+
+def test_scopes_taps_and_the_compile_row(tmp_path):
+    import json
+
+    from perceiver_io_tpu.obs.events import EventLog
+
+    config = tiny_config(vocab_size=12)
+    model, params, ids = seeded(config, 0, batch=4, n=9)
+    gen_cfg = GenerationConfig(max_new_tokens=6)
+    text = make_generate_fn(model, config=gen_cfg).lower(params, ids).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    # (outer, inner): an operation whose path opens ``outer`` and, inside it (module names lie between), ``inner``
+    for outer, inner in (("prefill", "mtp/project"), ("prefill", "mtp/block"), ("prefill", "mtp/draft"),
+                         ("while/body/decode", "spec/verify"), ("while/body/decode", "spec/accept"),
+                         ("while/body/decode", "spec/rollback"), ("while/body/decode", "mtp/project"),
+                         ("while/body/decode", "mtp/block"), ("while/body/decode", "mtp/draft"),
+                         ("spec/verify", "attn/window/kv_cache_write"), ("spec/verify", "attn/full/kv_cache_write"),
+                         ("mtp/block", "attn/full/kv_cache_write"), ("spec/verify", "moe/shared")):
+        assert any(re.search(rf"/{outer}/(.*/)?{inner}(/|$)", n) for n in names), (outer, inner)
+    fn = make_instrumented_generate_fn(model, config=gen_cfg, events=EventLog(str(tmp_path)), probes=True)
+    fn(params, ids)
+    rows = [json.loads(line) for line in open(tmp_path / "events.jsonl")]
+    compiled = [r for r in rows if r.get("event") == "compile" and "kv_cache_lengths" in r][0]
+    assert compiled["kv_cache_lengths"] == "row" and compiled["mtp_layers"] == 1 and compiled["spec_positions_per_step"] == 2
+    assert compiled["kv_cache_window_slack_rows"] == 1 and compiled["kv_cache_window_rows"] == WINDOW
+    # four rings of the stack; the stack's full layer and the module's, each one slot past the prompt and the new tokens
+    assert compiled["kv_cache_window_layers"] == 4 and compiled["kv_cache_full_layers"] == 2
+    row_bytes = 2 * 2 * 16 * 4
+    assert compiled["kv_cache_full_bytes"] == 4 * (9 + 6 + 1) * row_bytes * 2
+    assert compiled["kv_cache_window_bytes"] == 4 * (WINDOW + 1) * row_bytes * 4 and compiled["moe_combine"] == "scatter"
+    request = [r for r in rows if r.get("event") == "request"][-1]
+    assert request["tokens_out"] == 6 and 0.0 <= request["spec_accept_rate"] <= 1.0 and request["spec_drafts"] >= 4
+
+
+def test_the_probe_tool_runs_at_a_tiny_size(tmp_path, monkeypatch, capsys):
+    """``tools/mtp_probe.py`` (the builder's one chip comparison of the draft
+    logits, and the acceptance counter) on the benchmark's tiny cell."""
+    import importlib.util
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location("mtp_probe", os.path.join(root, "tools", "mtp_probe.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    monkeypatch.chdir(tmp_path)
+    data = os.path.join(root, "benchmarks", "tests", "data")
+    assert tool.main(["--workload", "tiny-exaone-decode", "--data-root", data, "--new-tokens", "10", "--steps", "6", "--seed", "5"]) == 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    books = out["instrumented"]
+    assert books["spec_drafts_total"] <= books["rows_x_steps_x_requests"] and books["spec_drafts_total"] >= 2 * 3 * 5
+    assert out["compile_row"]["kv_cache_lengths"] == "row"
+    found = out["against_reference"]
+    assert found["positions"] >= 3 * 6 and found["main_abs_diff"] < TOL and found["draft_abs_diff"] < TOL
+    assert found["main_gap"] == 0.0 and found["draft_gap"] == 0.0

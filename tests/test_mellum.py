@@ -314,6 +314,7 @@ def test_both_expert_paths_serve_every_pair_when_all_experts_are_held(case, monk
 
 def test_the_cuts_follow_the_geometry_they_were_measured_at():
     assert moe._cuts(7168, 2048) == (384, 256, 1024)  # DeepSeek-V3's share keeps PR 28's values
+    assert moe._cuts(6144, 2048) == (384, 256, 1024)  # K-EXAONE's share: its own readings came out the same (PR 34)
     small = moe._cuts(2304, 896)
     assert small == moe._cuts(64, 32) and small.pass_rows % small.row_tile == 0 and small.row_tile % 128 == 0
 
@@ -425,6 +426,12 @@ def test_the_windowed_flash_forward_has_no_backward():
     (512, 128, 1, 4, 4, 0),
     # 500 positions are padded to 512
     (500, 128, 128, 7, 7, 0),
+    # K-EXAONE's window layers (PR 34): a row of 1024 is one block of 1024, so one tile, the diagonal one, in four bands
+    # of 256 rows; a band sees the 256 slots under it and the 128 before them (the first band none before): 2 x 2 score
+    # tiles, then 3 times 2 x 3, 22 of the 64, every one of them masked; visible are 1024 x 128 scores less the corner, 7.5 tiles' worth
+    (1024, None, 128, 4 + 3 * 6, 4 + 3 * 6, 256),
+    # the same row in blocks of 128 (what a window as narrow as a score tile would like): 8 diagonal tiles and 7 before them
+    (1024, 128, 128, 8 + 7, 8 + 7, 0),
 ])
 def test_tile_plan_with_a_window_counted_by_hand(n, block, window, run, masked, bands):
     plan = fa.tile_plan(n, n, True, block, block, window=window)

@@ -361,3 +361,41 @@ def test_the_mellum_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch)
     # every expert is held: the expert rows come back by a gather, and no layer scatter-adds into the chunk's tokens (PR 33)
     assert not re.search(r"f32\[8192,2304\]\{[^}]*\} scatter\(", text)
     assert len(re.findall(r"bf16\[65536,2304\]\{[^}]*\} gather\(", text)) == 2 * 8  # ``x[token]`` and the combine, a layer
+
+
+# ------------------------------------------ K-EXAONE: the speculative generator at the cell's sizes
+
+
+def test_the_kexaone_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch):
+    """``kexaone-ep8-mtp-decode-b64`` as the benchmark builds it (4.54B
+    bfloat16 parameters, 64 prompts of 1024 tokens, 512 new tokens, bfloat16
+    caches with a length a row), compiled for a described v5e: under the
+    16.9 GB the runtime offers with 2 GB to spare, the window-128 and the full
+    flash kernels and the grouped expert kernels in it, and the decode loop a
+    ``while`` whose body scatters two positions a row into every cache."""
+    import re
+
+    from benchmarks import run
+
+    gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
+    monkeypatch.setattr(gm, "_interpret_default", lambda: False)
+    cell = run.load_json("workloads", "kexaone-ep8-mtp-decode-b64")
+    family = importlib.import_module("benchmarks.families.exaone_moe").Family(run.load_json("configs", cell["config"]))
+    p = cell["params"]
+    model = family.model()
+    shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), family.param_shapes(model))
+    ids = jax.ShapeDtypeStruct((p["batch_size"], p["prompt_len"]), jnp.int32, sharding=one_chip)
+    generate = family.generate_fn(model, p["num_latents"], p["new_tokens"], p["cache_dtype"])
+    with fa.default_flash(True), jax.default_matmul_precision("default"):
+        compiled = generate.lower(shapes, ids).compile()
+    m = compiled.memory_analysis()
+    assert 9.08e9 < m.argument_size_in_bytes < 9.10e9  # the weights and the prompts
+    total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    assert total < 14.9e9, f"{total / 1e9:.2f} GB"
+    text = compiled.as_text()
+    assert set(re.findall(r"flash_fwd_q\d+_kv\d+(?:_w\d+)?", text)) == {"flash_fwd_q1024_kv1024", "flash_fwd_q1024_kv1024_w128"}
+    assert "moe_experts_prefill_m1024_k6144_n2048" in text and "moe_experts_prefill_m1024_k2048_n6144" in text
+    # six caches, keys and values: twelve per-row writes a step (XLA flattens the rows and slots of some). The two
+    # that grow to 1537 slots are filled by the prompt pass in place; the four rings of 129 slots by a scatter too
+    assert len(re.findall(r"bf16\[(?:512,1537|786944),128\]\{[^}]*\} scatter\(", text)) == 2 * 2
+    assert len(re.findall(r"bf16\[(?:512,129|66048),128\]\{[^}]*\} scatter\(", text)) == 2 * 4 + 2 * 4

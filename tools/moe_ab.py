@@ -3,13 +3,21 @@ and through the decode attention at a decoder-only model's published widths,
 so that ``core/moe.py``, ``core/mla.py`` and ``core/gqa.py`` keep one per path
 on a measurement:
 
-    chiprun -- python tools/moe_ab.py [--geom dsv3|mellum] [--only experts_layer] [--compile-only]
+    chiprun -- python tools/moe_ab.py [--geom dsv3|mellum|kexaone] [--only experts_layer] [--compile-only]
 
 ``--geom dsv3`` (the default) is DeepSeek-V3's share of PR 28 (16 held experts
 of 256, hidden 7168, width 2048; absorbed MLA); ``--geom mellum`` is Mellum 2
 (64 experts of width 896 all held, hidden 2304; grouped-query attention over a
 growing cache of 8448 slots and over a ring of 1024, batch 32 x 4 key-value
-heads, 8 query heads each).
+heads, 8 query heads each); ``--geom kexaone`` is K-EXAONE's share of PR 34
+(16 held experts of 128, hidden 6144, width 2048: a speculative step's 128
+positions dense against grouped, rows a pass at a prompt chunk's 8192 tokens
+with one local pair a token; the speculative step's attention, eight steps in
+one program so that the caches are written in place: the per-row write of two
+positions, XLA's scatter, and the two batched products with a mask a query,
+over a growing cache of 1537 slots and a ring of 129, batch 64 x 8 key-value
+heads; and the prompt pass's window-128 flash forward on a chunk of four
+1024-token rows, in bands or whole, blocks of 1024 down to 128).
 
 - the grouped product alone (8192 live rows of 16384, 16 experts, even and
   skewed group sizes): the Pallas kernel (``ops/grouped_matmul.py``) against
@@ -66,6 +74,10 @@ def set_geometry(name: str) -> None:
         H, WIDTH, EXPERTS, ROUTED, TOP_K = 2304, 896, 64, 64, 8
         LAYER_TOKENS = (32, 256, 512, 8192)
         SHORT_PASSES = {8192: ((256, 512), (256, 2048), (256, 4096), (256, 8192), (512, 8192), (256, 16384), (256, 32768), (256, 65536))}
+    if name == "kexaone":
+        H, WIDTH, EXPERTS, ROUTED, TOP_K = 6144, 2048, 16, 128, 8
+        LAYER_TOKENS = (128, 256, 384, 512, 8192)
+        SHORT_PASSES = {8192: ((256, 512), (256, 768), (256, 1536), (256, 2048), (256, 4096), (256, 10240))}
 
 
 # ---------------------------------------------------------------------------
@@ -211,6 +223,8 @@ def variants():
                 for suffix, combine in combines.items():
                     name = f"experts_layer/T{t}/grouped_tm{tile}_rows{pass_rows}{suffix}"
                     out[name] = (grouped(tile, pass_rows, combine), layer, "layer")
+    if GEOM == "kexaone":
+        return {**{k: v for k, v in out.items() if "experts_layer" in k}, **kexaone_attention_variants()}
     if GEOM == "mellum":
         from perceiver_io_tpu.core.cache import KVCache
         from perceiver_io_tpu.core.gqa import cached_decode_attention
@@ -258,6 +272,66 @@ def variants():
     return out
 
 
+def kexaone_attention_variants():
+    """The speculative step's attention and the prompt pass's window kernel at K-EXAONE's sizes (the module docstring)."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from perceiver_io_tpu.core.cache import RaggedKVCache, RaggedWindowKVCache
+    from perceiver_io_tpu.core.gqa import cached_verify_attention
+
+    fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
+    bf = jnp.bfloat16
+    batch, kv_heads, group, d, steps = 64, 8, 8, 128, 8
+    rows = batch * kv_heads
+    out = {}
+
+    def step_loop(make, what):
+        def run(q, k, v, k_new, v_new):
+            cache = make(k, v)
+
+            def body(_, carry):
+                cache, acc = carry
+                cache = cache.write(k_new, v_new)
+                if what == "write":  # the write alone: read one row back so that it is not dead
+                    return cache.keep(jnp.ones((batch,), jnp.int32)), acc + cache.k[:, :1].astype(jnp.float32).sum()
+                o = cached_verify_attention(q, cache, cache.visible(2, group), d ** -0.5)
+                return cache.keep(jnp.ones((batch,), jnp.int32)), acc + o.sum()
+
+            return lax.fori_loop(0, steps, body, (cache, jnp.zeros((), jnp.float32)))[1]
+        return run
+
+    q = jax.ShapeDtypeStruct((rows, 2 * group, d), bf)
+    new = jax.ShapeDtypeStruct((rows, 2, d), bf)
+    length = jnp.full((batch,), 1280, jnp.int32)
+    for kind, slots, make in (
+        ("full", 1537, lambda k, v: RaggedKVCache(k=k, v=v, length=length)),
+        ("window", 129, lambda k, v: RaggedWindowKVCache(k=k, v=v, length=length, window=128)),
+    ):
+        kv = jax.ShapeDtypeStruct((rows, slots, d), bf)
+        for what in ("write", "write_and_attend"):
+            out[f"gqa_verify/{kind}/{what}_x{steps}"] = (step_loop(make, what), (q, kv, kv, new, new), "gqa")
+
+    def flash(bands, block):
+        def run(q, k, v):
+            fa._BAND_MAX_SHARE = 0.75 if bands else 0.0  # read when the call is traced
+            try:
+                return fa.flash_attention_gqa(q, k, v, 64, window=128, sm_scale=d ** -0.5, block=block)
+            finally:
+                fa._BAND_MAX_SHARE = 0.75
+        return run
+
+    fq = jax.ShapeDtypeStruct((4, 1024, 64 * d), bf)
+    fkv = jax.ShapeDtypeStruct((4, kv_heads, 1024, d), bf)
+    for bands in (True, False):
+        for block in (1024, 512, 256, 128):
+            out[f"gqa_prefill/window/{'bands' if bands else 'whole'}_block{block}"] = (flash(bands, block), (fq, fkv, fkv), "gqa")
+    return out
+
+
 def group_sizes(skew: bool):
     import numpy as np
 
@@ -283,7 +357,7 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--compile-only", action="store_true")
     p.add_argument("--iters", type=int, default=5)
-    p.add_argument("--geom", default="dsv3", choices=("dsv3", "mellum"))
+    p.add_argument("--geom", default="dsv3", choices=("dsv3", "mellum", "kexaone"))
     p.add_argument("--only", default="", help="substrings of variant names, comma-separated; a variant runs if it holds one")
     args = p.parse_args(argv)
     set_geometry(args.geom)
