@@ -24,10 +24,13 @@ Behavioral parity with the reference core
 
 from __future__ import annotations
 
+import collections
+import math
 from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 from flax import struct
 from jax import lax
@@ -289,6 +292,88 @@ class SelfAttention(nn.Module):
         )
 
 
+# The exact GELU between ``dense_1`` and ``dense_2``, with its own
+# differentiation rule. Left to autodiff, XLA keeps only ``h`` (the bf16
+# ``dense_1`` output) and two bit-packed predicate masks, and expands the
+# whole ``erfc`` (both branches, two polynomials, an exponential and two
+# divides an element, in float32 on the VPU) again inside the input of every
+# GEMM that consumes ``gelu(h)``: the forward ``dense_2``, its ``dW2`` and,
+# with the density, its ``dy W2^T``. It counts the bytes it saves and not the
+# VPU work, and a GEMM of [32768, 2048] x [2048, 512] that takes 0.39 to 0.44
+# ms plain took 1.14 to 1.34 ms behind the expansion (v5e, PERF.md 6, PR 35).
+#
+# The rule splits ``nn.gelu``'s expression where it rounds: ``e = erfc(-h /
+# sqrt 2)`` in the compute dtype, then ``0.5 h e``. The forward evaluates
+# ``e`` once, in ``dense_1``'s epilogue, and an ``optimization_barrier`` on
+# ``(h, e)`` makes XLA keep both; ``dense_2`` and ``dW2`` read them and
+# multiply, and the backward differentiates the product at the kept ``e`` and
+# takes only ``erfc``'s derivative (one exponential) at the kept ``h``. Values
+# and gradients are autodiff's to the bit (tests/test_mlp_gelu.py). Why ``e``
+# and not ``gelu(h)`` or ``gelu'(h)``: an XLA fusion has one root, and its
+# other results are expensive values on the way to it, as ``h`` is to ``e``;
+# two results that share the ``erfc`` leave ``dense_1`` as a float32 array of
+# the hidden shape and a second fusion (tools/mlp_gelu_ab.py, PERF.md 6).
+#
+# The primal is ``nn.gelu`` itself: a program that does not differentiate
+# (the generator, the serving engine) lowers to what it lowered to before.
+
+
+def _erfc_term(x):
+    return lax.erfc(-x * np.sqrt(0.5).astype(x.dtype))  # as nn.gelu writes it
+
+
+def _gelu_from(x, e):
+    return jnp.array(0.5 * x * e, dtype=x.dtype)
+
+
+@jax.custom_vjp
+def gelu_exact(x):
+    """``nn.gelu(x, approximate=False)``, with ``erfc`` evaluated once a site
+    under differentiation (see above)."""
+    return nn.gelu(x, approximate=False)
+
+
+def _gelu_exact_fwd(x):
+    _MLP_GELU_SITES[(math.prod(x.shape[:-1]), x.shape[-1] if x.ndim else 1, jnp.dtype(x.dtype).name)] += 1
+    x, e = lax.optimization_barrier((x, _erfc_term(x)))
+    return _gelu_from(x, e), (x, e)
+
+
+def _gelu_exact_bwd(residuals, da):
+    x, e = residuals
+    dx, de = jax.vjp(_gelu_from, x, e)[1](da)
+    # erfc's own value is dead code here: its derivative is an exponential of x alone
+    return (dx + jax.vjp(_erfc_term, x)[1](de)[0],)
+
+
+gelu_exact.defvjp(_gelu_exact_fwd, _gelu_exact_bwd)
+
+# forward-rule traces by (rows, width, dtype): a trace-time fact like
+# ``ops.flash_attention._TILE_PLANS``, read by obs.recompile for the
+# ``compile`` event row
+_MLP_GELU_SITES: collections.Counter = collections.Counter()
+
+
+def mlp_gelu_sites() -> collections.Counter:
+    """A snapshot of the forward-rule traces so far, for :func:`mlp_gelu_plans`'s ``since``."""
+    return collections.Counter(_MLP_GELU_SITES)
+
+
+def mlp_gelu_plans(since: Optional[collections.Counter] = None) -> list:
+    """One row per distinct ``(rows, width)`` at which :func:`gelu_exact` was
+    differentiated (the ``mlp_gelu`` rows of the ``compile`` event,
+    docs/observability.md): ``sites`` (traces of the forward rule, since the
+    snapshot ``since`` where given: the trace of one step reads its MLPs of
+    that shape), what each keeps for its backward and how often it evaluates
+    ``erfc``. Counts from shapes."""
+    traced = _MLP_GELU_SITES - since if since is not None else _MLP_GELU_SITES
+    return [
+        {"rows": rows, "width": width, "dtype": dtype, "sites": sites, "residuals": "h+erfc",
+         "residual_bytes": 2 * rows * width * jnp.dtype(dtype).itemsize, "erfc_evals_per_site": 1}
+        for (rows, width, dtype), sites in sorted(traced.items())
+    ]
+
+
 class MLP(nn.Module):
     """LayerNorm -> Dense(widening * C) -> GELU(exact) -> Dense(C)
     (reference: modules.py:444-454)."""
@@ -312,7 +397,7 @@ class MLP(nn.Module):
             # name pinned: auto-naming would differ from nn.LayerNorm's
             x = LayerNorm(epsilon=LAYER_NORM_EPSILON, dtype=self.dtype, name="LayerNorm_0")(x)
             x = dense(self.widening_factor * self.num_channels, "dense_1")(x)
-            x = nn.gelu(x, approximate=False)
+            x = gelu_exact(x)
             x = dense(self.num_channels, "dense_2")(x)
         return x
 

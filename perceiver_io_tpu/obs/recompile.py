@@ -19,7 +19,11 @@ trace time from the lengths alone, ``ops.flash_attention.tile_plan``), and
 about the position-table gradient of the compact prefix-dropout embedding
 (``embed_tiles``: one row per distinct call with its tiles, grid steps and
 one-hot FLOPs, and whether it took the kernel or XLA's scatter-add;
-``ops.gathers.embed_tile_plan``, from the shapes alone).
+``ops.gathers.embed_tile_plan``, from the shapes alone), and about the MLPs'
+exact GELU under differentiation (``mlp_gelu``: one row per distinct hidden
+shape with its sites, what each keeps for the backward and its ``erfc``
+evaluations; ``core.modules.mlp_gelu_plans``, counted over this call's trace
+alone: the trainer traces its step a second time for graphlint).
 """
 
 from __future__ import annotations
@@ -81,6 +85,10 @@ class RecompileTracker:
         )
 
         def wrapped(*args, **kwargs):
+            if self.events is not None:
+                from perceiver_io_tpu.core.modules import mlp_gelu_plans, mlp_gelu_sites
+
+                gelu_sites = mlp_gelu_sites()  # the row below counts this call's trace alone
             before = _cache_size(fn)
             t0 = time.perf_counter()
             out = fn(*args, **kwargs)
@@ -101,7 +109,7 @@ class RecompileTracker:
                     from perceiver_io_tpu.ops.flash_attention import tile_plans
                     from perceiver_io_tpu.ops.gathers import embed_tile_plans
 
-                    flash_tiles, embed_tiles = tile_plans(), embed_tile_plans()
+                    flash_tiles, embed_tiles, mlp_gelu = tile_plans(), embed_tile_plans(), mlp_gelu_plans(since=gelu_sites)
                     self.events.emit(
                         "compile",
                         fn=name,
@@ -111,6 +119,7 @@ class RecompileTracker:
                         arg_shapes=shape_signature(args, kwargs),
                         **({"flash_tiles": flash_tiles} if flash_tiles else {}),
                         **({"embed_tiles": embed_tiles} if embed_tiles else {}),
+                        **({"mlp_gelu": mlp_gelu} if mlp_gelu else {}),
                         **(extra(args, kwargs) if extra is not None else {}),
                     )
             return out

@@ -399,3 +399,64 @@ def test_the_kexaone_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch
     # that grow to 1537 slots are filled by the prompt pass in place; the four rings of 129 slots by a scatter too
     assert len(re.findall(r"bf16\[(?:512,1537|786944),128\]\{[^}]*\} scatter\(", text)) == 2 * 2
     assert len(re.findall(r"bf16\[(?:512,129|66048),128\]\{[^}]*\} scatter\(", text)) == 2 * 4 + 2 * 4
+
+
+# ------------------------------------------ the MLP's exact GELU: evaluated once a layer and kept
+
+
+@pytest.mark.parametrize("mesh_shape", [None, (2, 2)], ids=["one_chip", "data_x_fsdp"])
+def test_mlp_gelu_is_not_expanded_again_inside_the_gemms(four_chips, one_chip, mesh_shape):
+    """An ``MLP`` with its residual, forward and backward, at the hidden shape
+    of ``ar16k-train-b32`` ([32, 1024, 512] bfloat16, widening 4), compiled
+    for a described v5e. Left to autodiff XLA expands ``erfc`` again on the
+    input of the forward ``dense_2``, ``dW2`` and ``dy W2^T`` GEMMs (three
+    fusions with an ``exponential`` and two ``divide``s of the hidden shape,
+    each bound by the VPU: PERF.md 6, PR 35). Under ``core.modules.gelu_exact``
+    ``erfc`` is expanded once, in ``dense_1``'s epilogue, which hands on ``h``
+    and ``erfc`` in bfloat16; the ``dy W2^T`` GEMM holds the one exponential
+    of ``erfc``'s derivative and no divide, the other two GEMMs none, and no
+    float32 array of the hidden shape lies between fusions: the pin that keeps
+    a later JAX or XLA from re-expanding in silence. The same on a data x
+    fsdp mesh of the four chips (rows over both axes, the kernels' rows over
+    fsdp, as ``parallel.mesh`` places them): the rule is plain XLA, GSPMD
+    partitions it, and each chip holds a quarter of the rows."""
+    import importlib.util
+    import os
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from perceiver_io_tpu.core.modules import MLP
+
+    spec = importlib.util.spec_from_file_location(
+        "step_hlo", os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "tools", "step_hlo.py"))
+    step_hlo = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(step_hlo)
+
+    mlp = MLP(num_channels=CHANNELS, widening_factor=4, dtype=jnp.bfloat16)
+    rows = 32
+    if mesh_shape is None:
+        batch, weights = one_chip, lambda leaf: one_chip
+    else:
+        mesh = Mesh(np.asarray(four_chips).reshape(mesh_shape), ("data", "fsdp"))
+        batch = NamedSharding(mesh, P(("data", "fsdp")))
+        weights = lambda leaf: NamedSharding(mesh, P("fsdp") if leaf.ndim == 2 else P())  # noqa: E731
+        rows //= 4
+    x = jax.ShapeDtypeStruct((32, LATENTS, CHANNELS), jnp.bfloat16, sharding=batch)
+    params = jax.eval_shape(lambda: mlp.init(jax.random.PRNGKey(0), jnp.zeros((1, 8, CHANNELS), jnp.bfloat16)))
+    params = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=weights(s)), params)
+
+    def loss(params, x):
+        return (x + mlp.apply(params, x)).astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), params, x)
+    hidden = f"[{rows},{LATENTS},{4 * CHANNELS}]"
+    holding = [r for r in step_hlo.entry_fusions(text, "f32" + hidden) if r["exponential"]]
+    assert len(holding) == 2, [(r["name"], r["op_name"]) for r in holding]
+    forward, backward = sorted(holding, key=lambda r: "transpose" in r["op_name"])
+    assert forward["op_name"].endswith("dense_1/dot_general") and (forward["exponential"], forward["divide"]) == (1, 2)
+    assert forward["shapes"] == ["bf16" + hidden] * 2  # h and erfc, as the barrier holds them
+    assert "transpose" in backward["op_name"] and "/mlp/" in backward["op_name"]  # the ``dy W2^T`` GEMM, named by its root
+    assert (backward["exponential"], backward["divide"]) == (1, 0)
+    assert step_hlo.entry_buffers(text, "f32" + hidden) == []

@@ -168,6 +168,15 @@ def render(run_dir: str, max_compile_rows: int = 20) -> str:
                 for r in plans
             ]
             lines.extend("  " + r for r in _table(rows, ["call", "route", "tile", "grid_steps", "one-hot GFLOP"]))
+        plans = next((e["mlp_gelu"] for e in reversed(compiles) if e.get("mlp_gelu")), [])
+        if plans:
+            lines.append("  the MLPs' exact GELU under differentiation:")
+            rows = [
+                [f"{r['rows']} x {r['width']} {r['dtype']}", str(r["sites"]), r["residuals"],
+                 f"{r['residual_bytes'] / 1e6:.1f}", str(r["erfc_evals_per_site"])]
+                for r in plans
+            ]
+            lines.extend("  " + r for r in _table(rows, ["hidden", "sites", "kept", "MB a site", "erfc a site"]))
 
     logs = [e for e in events if e.get("event") == "log"]
     if logs:
