@@ -34,6 +34,8 @@ import jax
 import numpy as np
 from jax.extend.core import ClosedJaxpr, Jaxpr
 
+from perceiver_io_tpu.obs.xplane import op_scope
+
 
 @dataclasses.dataclass(frozen=True)
 class AvalInfo:
@@ -250,7 +252,7 @@ class HloInstr:
     name: str
     opcode: str
     operands: Tuple[str, ...]  # operand instruction names (same computation)
-    scope: str  # named_scope-ish path recovered from metadata op_name
+    scope: str  # the path of obs.xplane.op_scope (the one rule) over metadata op_name, '' when none
     line: str
 
 
@@ -269,23 +271,6 @@ def _shape_bytes(text: str) -> int:
                 n *= int(d)
         total += n * itemsize
     return total
-
-
-def _scope_from_op_name(line: str) -> str:
-    """Recover a named_scope-ish path from an instruction's metadata
-    ``op_name`` — transform wrappers (``jit(...)``, ``transpose(...)``, ...)
-    are dropped and the final primitive segment trimmed, leaving the
-    ``jax.named_scope`` path the op was traced under ('' when none)."""
-    m = _OP_NAME_RE.search(line)
-    if not m:
-        return ""
-    segments = [
-        s for s in m.group(1).split("/")
-        if s and not re.fullmatch(r"\w+\(.*\)", s)
-    ]
-    if segments:
-        segments = segments[:-1]  # the last segment is the primitive itself
-    return "/".join(segments)
 
 
 def parse_hlo_computations(hlo_text: str) -> Dict[str, List[HloInstr]]:
@@ -338,8 +323,9 @@ def parse_hlo_computations(hlo_text: str) -> Dict[str, List[HloInstr]]:
         operands = tuple(
             op for op in re.findall(r"%([\w.\-]+)", seg[:j]) if op in names_in_comp
         )
+        named = _OP_NAME_RE.search(raw)
         comps[cur].append(
-            HloInstr(name, om.group(1), operands, _scope_from_op_name(raw), raw.strip())
+            HloInstr(name, om.group(1), operands, op_scope(named.group(1)).path if named else "", raw.strip())
         )
         names_in_comp.add(name)
     return comps

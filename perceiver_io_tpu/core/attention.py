@@ -206,14 +206,17 @@ class MultiHeadAttention(nn.Module):
         h = self.num_heads
         qk_per_head = self.qk_channels // h
         q4 = q.reshape(q.shape[0], q.shape[1], h, qk_per_head) * qk_per_head**-0.5
-        if rope_q is not None:
-            q4 = apply_rotary_pos_emb(q4, rope_q[:, :, None, :])
-        if rope_k is not None and not already_rotated_k:
-            k4 = k.reshape(k.shape[0], k.shape[1], h, qk_per_head)
-            k4 = apply_rotary_pos_emb(k4, rope_k[:, :, None, :])
-            k = k4.reshape(k.shape)
+        # the rotation with the reshapes around it: the layout copies they cost are the rotation's
+        with jax.named_scope("rotary"):
+            if rope_q is not None:
+                q4 = apply_rotary_pos_emb(q4, rope_q[:, :, None, :])
+            if rope_k is not None and not already_rotated_k:
+                k4 = k.reshape(k.shape[0], k.shape[1], h, qk_per_head)
+                k4 = apply_rotary_pos_emb(k4, rope_k[:, :, None, :])
+                k = k4.reshape(k.shape)
+            q = q4.reshape(q.shape)
         return flash_attention_packed(
-            q4.reshape(q.shape),
+            q,
             k,
             v,
             num_heads=h,
@@ -401,9 +404,10 @@ class MultiHeadAttention(nn.Module):
             # head transpose: a transpose here showed up as two full-buffer
             # re-layout copies of the prompt pass in the compiled HLO.
             if rope_k is not None:
-                k4 = k.reshape(k.shape[0], k.shape[1], h, qk_per_head)
-                k4 = apply_rotary_pos_emb(k4, rope_k[:, :, None, :])
-                k = k4.reshape(k.shape)
+                with jax.named_scope("rotary"):
+                    k4 = k.reshape(k.shape[0], k.shape[1], h, qk_per_head)
+                    k4 = apply_rotary_pos_emb(k4, rope_k[:, :, None, :])
+                    k = k4.reshape(k.shape)
             if isinstance(kv_cache, PagedKVCache):
                 # paged discipline (the engine decode step): page-table-
                 # indexed append, then the paged attend — the contiguous

@@ -1099,8 +1099,9 @@ class PerceiverAR(nn.Module):
             with jax.named_scope("embed"):
                 x_emb, frq = self.input_adapter.embed_compact(x, keep_idx, prefix_len)
             x_emb = probe("perceiver_ar.embed", x_emb)
-            x_prefix, x_latent = x_emb[:, :keep], x_emb[:, keep:]
-            frq_prefix, frq_latent = frq[:, :keep], frq[:, keep:]
+            with jax.named_scope("embed"):  # the split, and in the backward the sum of the two halves' gradients
+                x_prefix, x_latent = x_emb[:, :keep], x_emb[:, keep:]
+                frq_prefix, frq_latent = frq[:, :keep], frq[:, keep:]
             return self._attend(
                 x_latent, x_prefix, frq_latent, frq_prefix,
                 pad_latent=None, pad_prefix=None,
@@ -1120,8 +1121,9 @@ class PerceiverAR(nn.Module):
                 pad_latent, pad_prefix = pad_mask[:, prefix_len:], pad_mask[:, :prefix_len]
 
         x_emb = probe("perceiver_ar.embed", x_emb)
-        x_latent, x_prefix = x_emb[:, prefix_len:], x_emb[:, :prefix_len]
-        frq_latent, frq_prefix = frq[:, prefix_len:], frq[:, :prefix_len]
+        with jax.named_scope("embed"):
+            x_latent, x_prefix = x_emb[:, prefix_len:], x_emb[:, :prefix_len]
+            frq_latent, frq_prefix = frq[:, prefix_len:], frq[:, :prefix_len]
 
         if dropout_active:
             with jax.named_scope("prefix_dropout"):

@@ -240,17 +240,19 @@ def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int, row_tile: int
             sorted_rows = lax.fori_loop(
                 0, n_pass, lambda p, rows: lax.dynamic_update_slice(rows, one_pass(p)[0], (p * pass_rows, 0)),
                 jnp.zeros((n_pass * pass_rows, x.shape[-1]), x.dtype))
-        rank = jnp.argsort(order).astype(jnp.int32)
-        y = (sorted_rows[rank].reshape(t, k, -1).astype(jnp.float32) * weights[:, :, None]).sum(axis=1)
+        with jax.named_scope("moe/combine"):
+            rank = jnp.argsort(order).astype(jnp.int32)
+            y = (sorted_rows[rank].reshape(t, k, -1).astype(jnp.float32) * weights[:, :, None]).sum(axis=1)
         return y, jnp.zeros((), jnp.int32), jnp.asarray(n_pass, jnp.int32)
 
     w_flat = weights.reshape(-1)
 
     def add_pass(p, y):
         ys, pair, live, token = one_pass(p)
-        # dead rows add zeros to token 0: the scatter-add costs by the distinct rows it touches
-        ys = jnp.where(live[:, None], ys.astype(jnp.float32) * w_flat[pair][:, None], 0.0)
-        return y.at[token].add(ys)
+        with jax.named_scope("moe/combine"):
+            # dead rows add zeros to token 0: the scatter-add costs by the distinct rows it touches
+            ys = jnp.where(live[:, None], ys.astype(jnp.float32) * w_flat[pair][:, None], 0.0)
+            return y.at[token].add(ys)
 
     n_pass = (n_local + pass_rows - 1) // pass_rows
     y = lax.fori_loop(0, n_pass, add_pass, jnp.zeros((t, x.shape[-1]), jnp.float32))

@@ -14,6 +14,7 @@ import functools
 import math
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -42,6 +43,7 @@ def positions(
     return jnp.maximum(pos, 0)
 
 
+@jax.named_scope("rotary")
 def frequency_position_encoding(abs_pos: jnp.ndarray, dim: int) -> jnp.ndarray:
     """Inverse-frequency rotary position features.
 
@@ -67,6 +69,7 @@ def rotate_half(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack((-x2, x1), axis=-1).reshape(x.shape)
 
 
+@jax.named_scope("rotary")
 def apply_rotary_pos_emb(t: jnp.ndarray, pos_enc: jnp.ndarray) -> jnp.ndarray:
     """Rotate the first ``pos_enc.shape[-1]`` channels of ``t``.
 
@@ -123,6 +126,7 @@ def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
     return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
 
 
+@jax.named_scope("rotary")
 def apply_rotary_interleaved(t: jnp.ndarray, pos: jnp.ndarray, inv_freq) -> jnp.ndarray:
     """Rotate all channels of ``t`` (..., N, R): adjacent channels
     ``(2i, 2i+1)`` are one complex number turned by ``pos * inv_freq[i]``.
@@ -137,6 +141,7 @@ def apply_rotary_interleaved(t: jnp.ndarray, pos: jnp.ndarray, inv_freq) -> jnp.
     return out.reshape(t.shape).astype(t.dtype)
 
 
+@jax.named_scope("rotary")
 def apply_rotary_half(t: jnp.ndarray, pos: jnp.ndarray, inv_freq, attention_factor: float = 1.0) -> jnp.ndarray:
     """Rotate all channels of ``t`` (..., N, R) in the half-split pairing
     (Hugging Face's ``rotate_half``): channel ``i`` of the first half and

@@ -475,25 +475,27 @@ class _PerceiverARDecoder:
         # differences, so logits are identical to the rolling scheme.
         ca_capacity = seq_len + max_new_tokens
         sa_capacity = num_latents + max_new_tokens
-        cache = CausalSequenceModel.init_cache(
-            mcfg, b, ca_capacity=ca_capacity, sa_capacity=sa_capacity, dtype=cache_dtype
-        )
+        with jax.named_scope("prefill"):
+            with jax.named_scope("cache_fill"):  # the empty caches and the masks over their slots
+                cache = CausalSequenceModel.init_cache(
+                    mcfg, b, ca_capacity=ca_capacity, sa_capacity=sa_capacity, dtype=cache_dtype
+                )
 
-        if pad_mask is None:
-            pad_mask = jnp.zeros((b, seq_len), bool)
-        # left-pad count for position shifts — pad_slots below can't double as
-        # this once expired slots are also marked
-        pos_shift = pad_mask.sum(axis=1, keepdims=True).astype(jnp.int32)
+                if pad_mask is None:
+                    pad_mask = jnp.zeros((b, seq_len), bool)
+                # left-pad count for position shifts — pad_slots below can't double as
+                # this once expired slots are also marked
+                pos_shift = pad_mask.sum(axis=1, keepdims=True).astype(jnp.int32)
 
-        # slot-aligned pad mask over the cross-attention window (original
-        # left-pads only; expired slots are derived from the start counters)
-        pad_slots = jnp.zeros((b, ca_capacity), bool).at[:, :seq_len].set(pad_mask)
+                # slot-aligned pad mask over the cross-attention window (original
+                # left-pads only; expired slots are derived from the start counters)
+                pad_slots = jnp.zeros((b, ca_capacity), bool).at[:, :seq_len].set(pad_mask)
 
-        # prompt pass (populates caches); prefill_mode routes its attention
-        # through the flash kernels over the fresh k/v (see core/attention.py)
-        with jax.named_scope("prefill"), prefill_mode():
-            out = self.model.apply(params, input_ids, prefix_len=prefix_len, pad_mask=pad_mask, kv_cache=cache)
-        zero = jnp.zeros((), jnp.int32)
+            # prompt pass (populates caches); prefill_mode routes its attention
+            # through the flash kernels over the fresh k/v (see core/attention.py)
+            with prefill_mode():
+                out = self.model.apply(params, input_ids, prefix_len=prefix_len, pad_mask=pad_mask, kv_cache=cache)
+            zero = jnp.zeros((), jnp.int32)
         return out.logits, (out.kv_cache, zero, zero), (pad_slots, pos_shift)
 
     def step(self, step_params, window, consts, token):
@@ -575,11 +577,12 @@ def _decode_step_body(decoder, config, step_params, carry, consts, health=False)
     scan bitwise identical."""
     *window, token, rng, done = carry
     logits, window = decoder.step(step_params, tuple(window), consts, token)
-    rng, step_rng = jax.random.split(rng)
-    sampled = _sample(logits[:, -1], step_rng, config)
-    if config.eos_token_id is not None:
-        sampled = jnp.where(done, config.pad_token_id, sampled)
-        done = done | (sampled == config.eos_token_id)
+    with jax.named_scope("sample"):  # the key chain and the EOS freeze are the sampling's
+        rng, step_rng = jax.random.split(rng)
+        sampled = _sample(logits[:, -1], step_rng, config)
+        if config.eos_token_id is not None:
+            sampled = jnp.where(done, config.pad_token_id, sampled)
+            done = done | (sampled == config.eos_token_id)
     carry_out = (*window, sampled, rng, done)
     if not health:
         return carry_out, sampled
@@ -757,17 +760,23 @@ def generate(
     decoder = _decoder_of(model)
     if getattr(decoder, "speculative", False):
         return _generate_speculative(decoder, model, params, input_ids, pad_mask, config, cache_dtype, weight_dtype)
+    # every operation of the program lies under one of two phases: what is made once a call (the prompt pass, which
+    # opens the scope itself; the first token; the decode loop's weights) under ``prefill``, the loop and what it
+    # hands back under ``decode``
     logits, window, consts = decoder.prefill(
         params, input_ids, pad_mask, num_latents, config.max_new_tokens, cache_dtype
     )
-    rng, first_rng = jax.random.split(rng)
-    next_token = _sample(logits[:, -1], first_rng, config)
+    with jax.named_scope("prefill"):
+        with jax.named_scope("sample"):
+            rng, first_rng = jax.random.split(rng)
+            next_token = _sample(logits[:, -1], first_rng, config)
 
-    decode_params, compute_dtype = _maybe_quantize_weights(model, params, weight_dtype)
-    if _pack_enabled(b):
-        packed_small, unpack_small = _pack_small_params(decode_params)
-    else:
-        packed_small = unpack_small = None
+        with jax.named_scope("loop_io"):  # what is laid out for the decode loop once a call
+            decode_params, compute_dtype = _maybe_quantize_weights(model, params, weight_dtype)
+            if _pack_enabled(b):
+                packed_small, unpack_small = _pack_small_params(decode_params)
+            else:
+                packed_small = unpack_small = None
 
     def step(carry, _):
         with jax.named_scope("decode"):
@@ -775,18 +784,21 @@ def generate(
             step_params = _maybe_dequantize_weights(dp, compute_dtype)
             return _decode_step_body(decoder, config, step_params, carry, consts)
 
-    done0 = jnp.zeros((b,), bool)
-    if config.eos_token_id is not None:
-        done0 = next_token == config.eos_token_id
+    with jax.named_scope("prefill"), jax.named_scope("sample"):
+        done0 = jnp.zeros((b,), bool)
+        if config.eos_token_id is not None:
+            done0 = next_token == config.eos_token_id
 
-    if config.max_new_tokens > 1:
-        carry = (*window, next_token, rng, done0)
-        _, tokens = lax.scan(step, carry, None, length=config.max_new_tokens - 1)
-        tokens = jnp.concatenate([next_token[:, None], tokens.T], axis=1)
-    else:
-        tokens = next_token[:, None]
+    # ``loop_io``: the loop's own carry and stacked tokens, and their assembly behind the prompt
+    with jax.named_scope("decode"), jax.named_scope("loop_io"):
+        if config.max_new_tokens > 1:
+            carry = (*window, next_token, rng, done0)
+            _, tokens = lax.scan(step, carry, None, length=config.max_new_tokens - 1)
+            tokens = jnp.concatenate([next_token[:, None], tokens.T], axis=1)
+        else:
+            tokens = next_token[:, None]
 
-    return jnp.concatenate([input_ids, tokens], axis=1)
+        return jnp.concatenate([input_ids, tokens], axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -823,9 +835,10 @@ def _spec_first(decoder, params, input_ids, pad_mask, config, cache_dtype):
         params, input_ids, pad_mask, config.max_new_tokens, cache_dtype, lambda logits: jnp.argmax(logits, axis=-1).astype(jnp.int32))
     with jax.named_scope("prefill"), jax.named_scope("mtp/draft"):
         draft = jnp.argmax(draft_logits, axis=-1).astype(jnp.int32)
-    done = jnp.zeros(token.shape, bool)
-    if config.eos_token_id is not None:
-        done = token == config.eos_token_id
+    with jax.named_scope("prefill"), jax.named_scope("sample"):
+        done = jnp.zeros(token.shape, bool)
+        if config.eos_token_id is not None:
+            done = token == config.eos_token_id
     return token, draft, window, done, logits
 
 
@@ -875,9 +888,10 @@ def _generate_speculative(decoder, model, params, input_ids, pad_mask, config, c
     b = input_ids.shape[0]
     n_new = config.max_new_tokens
     token, draft, window, done, _ = _spec_first(decoder, params, input_ids, pad_mask, config, cache_dtype)
-    decode_params, compute_dtype = _maybe_quantize_weights(model, params, weight_dtype)
-    out = jnp.full((b, n_new), config.pad_token_id, jnp.int32).at[:, 0].set(token)
-    rows = jnp.arange(b)[:, None]
+    with jax.named_scope("prefill"), jax.named_scope("loop_io"):  # the two phases of :func:`generate`
+        decode_params, compute_dtype = _maybe_quantize_weights(model, params, weight_dtype)
+        out = jnp.full((b, n_new), config.pad_token_id, jnp.int32).at[:, 0].set(token)
+        rows = jnp.arange(b)[:, None]
 
     def step(carry):
         with jax.named_scope("decode"):
@@ -886,15 +900,17 @@ def _generate_speculative(decoder, model, params, input_ids, pad_mask, config, c
             window, tokens, m, token, draft, done, _ = _spec_step_body(
                 decoder, config, step_params, window, token, draft, done, n_new - count)
             # a row writes its ``m`` tokens from column ``count`` on; what is not emitted goes past the edge and is dropped
-            j = jnp.arange(tokens.shape[1])[None, :]
-            cols = jnp.where(j < m[:, None], count[:, None] + j, n_new)
-            out = out.at[rows, cols].set(tokens, mode="drop")
-            return window, out, count + m, token, draft, done
+            with jax.named_scope("loop_io"):
+                j = jnp.arange(tokens.shape[1])[None, :]
+                cols = jnp.where(j < m[:, None], count[:, None] + j, n_new)
+                out = out.at[rows, cols].set(tokens, mode="drop")
+                return window, out, count + m, token, draft, done
 
-    if n_new > 1:
-        carry = (window, out, jnp.ones((b,), jnp.int32), token, draft, done)
-        out = lax.while_loop(lambda carry: jnp.any(carry[2] < n_new), step, carry)[1]
-    return jnp.concatenate([input_ids, out.astype(input_ids.dtype)], axis=1)
+    with jax.named_scope("decode"), jax.named_scope("loop_io"):
+        if n_new > 1:
+            carry = (window, out, jnp.ones((b,), jnp.int32), token, draft, done)
+            out = lax.while_loop(lambda carry: jnp.any(carry[2] < n_new), step, carry)[1]
+        return jnp.concatenate([input_ids, out.astype(input_ids.dtype)], axis=1)
 
 
 def make_decode_fns(

@@ -158,6 +158,12 @@ def _chunks(n: int, want: int) -> int:
     return max(d for d in range(1, n + 1) if n % d == 0 and d <= max(want, 1))
 
 
+@jax.named_scope("residual")
+def _residual(x, y):
+    """A block's skip connection: a layer of its own in the scope vocabulary (``obs/xplane.py``)."""
+    return x + y
+
+
 class DecoderBlock(nn.Module):
     config: DecoderLanguageModelConfig
     sparse: bool
@@ -185,21 +191,25 @@ class DecoderBlock(nn.Module):
         values, of which a window layer hands on its last ``sliding_window``)."""
         a, rows = self.attn.expand(self.attn_norm(x), pos)
         if self.layer_type == "sliding_attention":
-            rows = tuple(r[:, :, -self.config.sliding_window:] for r in rows)
-        return x + a, rows
+            with jax.named_scope("chunk_io"):  # what a window layer hands on to its cache
+                rows = tuple(r[:, :, -self.config.sliding_window:] for r in rows)
+        return _residual(x, a), rows
 
     def feed_forward(self, x):
-        return x + self.ffn(self.ffn_norm(x))
+        if self.sparse:  # the expert layer opens its own scopes (``moe/*``)
+            return _residual(x, self.ffn(self.ffn_norm(x)))
+        with jax.named_scope("dense_mlp"):
+            return x + self.ffn(self.ffn_norm(x))
 
     def step(self, x, cache, pos):
         one_token = self.attn.absorb if self.layer_type is None else self.attn.step
         a, cache = one_token(self.attn_norm(x), cache, pos)
-        return self.feed_forward(x + a), cache
+        return self.feed_forward(_residual(x, a)), cache
 
     def verify(self, x, cache, pos):
         """A speculative step's positions, each row at its own length: written to ``cache``, not yet kept."""
         a, cache = self.attn.verify(self.attn_norm(x), cache, pos)
-        return self.feed_forward(x + a), cache
+        return self.feed_forward(_residual(x, a)), cache
 
 
 class MTPModule(nn.Module):
@@ -262,7 +272,9 @@ def _over_chunks(fn, x, *beside):
         }
         return x, aux, stats
 
-    x, aux, stats = lax.fori_loop(0, n, body, (x, aux, stats))
+    # the loop's own reads and writes of the one buffer, beside the layers that ``fn`` names
+    with jax.named_scope("chunk_io"):
+        x, aux, stats = lax.fori_loop(0, n, body, (x, aux, stats))
     for key, entry in stats.items():
         probes.tap(probes.scope_of(key), entry)
     return x, aux
@@ -296,10 +308,12 @@ class DecoderLanguageModel(nn.Module):
     # module (a jax loop may not wrap a bound submodule's call)
 
     def embed(self, input_ids):
-        return self.embedding[input_ids].astype(self.dtype)
+        with jax.named_scope("embed"):
+            return self.embedding[input_ids].astype(self.dtype)
 
     def logits(self, x):
-        return jnp.dot(self.out_norm(x), self.head.astype(self.dtype), preferred_element_type=jnp.float32)
+        with jax.named_scope("logits"):
+            return jnp.dot(self.out_norm(x), self.head.astype(self.dtype), preferred_element_type=jnp.float32)
 
     def attend_layer(self, x, pos, i: int):
         return self.layers[i].attend(x, pos)
@@ -506,8 +520,10 @@ class _Decoder:
         del num_latents  # no latent window: every position passes the whole stack
         b, n = input_ids.shape
         self._refuse(pad_mask, n, max_new_tokens)
-        logits, rows = prefill(self.model, params, input_ids)
-        caches = self._caches(rows, b, n, max_new_tokens, cache_dtype)
+        with jax.named_scope("prefill"):
+            logits, rows = prefill(self.model, params, input_ids)
+            with jax.named_scope("cache_fill"):
+                caches = self._caches(rows, b, n, max_new_tokens, cache_dtype)
         return logits[:, None], (caches,), ()
 
     def step(self, step_params, window, consts, token):
@@ -535,14 +551,17 @@ class _Decoder:
         c = self.model.config
         b, n = input_ids.shape
         self._refuse(pad_mask, n, max_new_tokens)
-        logits, rows, hidden = prefill(self.model, params, input_ids, keep_hidden=True)
-        token = sample(logits)
-        next_ids = jnp.concatenate([input_ids[:, 1:], token[:, None].astype(input_ids.dtype)], axis=1)
-        draft_logits, module_rows = prefill_module(self.model, params, hidden, next_ids)
-        caches = tuple(
-            self._ragged_cache(kind, k, v, b, n, max_new_tokens, cache_dtype)
-            for kind, (k, v) in zip(c.layer_types + c.mtp_layer_types, rows + (module_rows,))
-        )
+        with jax.named_scope("prefill"):
+            logits, rows, hidden = prefill(self.model, params, input_ids, keep_hidden=True)
+            with jax.named_scope("sample"):
+                token = sample(logits)
+            next_ids = jnp.concatenate([input_ids[:, 1:], token[:, None].astype(input_ids.dtype)], axis=1)
+            draft_logits, module_rows = prefill_module(self.model, params, hidden, next_ids)
+            with jax.named_scope("cache_fill"):
+                caches = tuple(
+                    self._ragged_cache(kind, k, v, b, n, max_new_tokens, cache_dtype)
+                    for kind, (k, v) in zip(c.layer_types + c.mtp_layer_types, rows + (module_rows,))
+                )
         return token, logits, draft_logits, (caches,)
 
     def spec_verify(self, step_params, window, tokens):

@@ -36,8 +36,9 @@ from __future__ import annotations
 import collections
 import glob
 import os
+import re
 from dataclasses import dataclass, field
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 
 def _varint(buf: bytes, i: int):
@@ -260,23 +261,201 @@ def iter_planes(path: str, line_filter: str = "") -> Iterator[PlaneSummary]:
 
 UNSCOPED = "<unscoped>"
 
+# ---------------------------------------------------------------------------
+# the scope vocabulary: one rule from an ``op_name`` to (phase, layer, path)
+# ---------------------------------------------------------------------------
+#
+# An ``op_name`` is the name stack JAX gave the operation: transform wrappers,
+# ``jax.named_scope`` names, flax module and method names, and the primitive
+# last (``jit(train_step)/transpose(jvp(CausalLanguageModel))/perceiver_ar/
+# self_attend/self_attention/layer_3/mlp/mlp/dense_1/dot_general``). The
+# vocabulary below is every layer boundary the program marks; the table of
+# docs/observability.md#scopes lists each with its module and its readers.
+
+# a named scope that says which part of the program runs (the train step's
+# ``forward`` and ``backward`` are read from the transform wrappers instead)
+PHASE_SCOPES = {
+    "prefill": "prefill", "shared_prefill": "prefill",
+    "decode": "decode", "decode_spec": "decode", "decode_paged": "decode",
+    "optimizer": "optimizer",
+}
+# named scopes that are layers; a two-part scope (``moe/route``) is listed whole
+LAYER_SCOPES = frozenset({
+    # Perceiver family (core/modules.py, core/attention.py, core/position.py)
+    "embed", "prefix_dropout", "input_adapter", "output_adapter", "cross_attend", "self_attend", "mlp",
+    "logits", "qkv_proj", "rotary", "loss", "sample", "optimizer",
+    # decoder-only class (models/text/decoder_lm.py, core/mla.py, core/gqa.py, core/moe.py)
+    "mla/expand", "mla/absorb", "attn/window", "attn/full", "moe/route", "moe/experts", "moe/combine",
+    "moe/shared", "mtp/project", "mtp/block", "mtp/draft", "spec/verify", "spec/accept", "spec/rollback",
+    "dense_mlp", "norm", "residual", "chunk_io", "cache_fill", "loop_io",
+})
+# flax module names that mark a layer no scope is opened for
+MODULE_LAYERS = {"q_proj": "qkv_proj", "k_proj": "qkv_proj", "v_proj": "qkv_proj", "o_proj": "o_proj"}
+_NORM_MODULE = re.compile(r"(^|_)norm$|^(Layer|RMS)Norm_\d+$")
+# a layer that is read whole: what it holds inside (the MLP's own LayerNorm, an attention's projections
+# and q/k norms) is its own
+CLOSED_LAYERS = frozenset({"mlp", "dense_mlp", "mla/expand", "mla/absorb", "attn/window", "attn/full"})
+# parts of a name stack that are no scope: what a transform or a loop wraps around the names. A transform
+# wraps the first scope opened under it (``transpose(jvp(loss))`` is the scope ``loss``); ``jit`` wraps the name
+# of a function, which is no scope
+_WRAPPER = re.compile(r"^(\w+)\((.*)\)$")
+_STRUCTURE = frozenset({"while", "body", "cond", "closed_call", "checkpoint", "remat", "rematted_computation",
+                        "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr", "pjit", "branch_0_fun",
+                        "branch_1_fun"})
+
+
+def _unwrap(part: str) -> str:
+    """The scope a name-stack part holds once its transform wrappers are off ('' for a jitted function's name)."""
+    while (wrapped := _WRAPPER.match(part)) is not None:
+        if wrapped.group(1) in ("jit", "pjit"):
+            return ""
+        part = wrapped.group(2)
+    return part
+
+
+class OpScope(NamedTuple):
+    """Where an operation belongs: ``phase`` (``prefill``, ``decode``,
+    ``forward``, ``backward``, ``optimizer`` or ''), ``layer`` (a name of the
+    vocabulary, or ``<unscoped>``) and ``path`` (every scope and module name
+    of the stack, wrappers and the primitive dropped; '' where none is left)."""
+
+    phase: str
+    layer: str
+    path: str
+
+
+def _layer_of(parts: List[str], i: int) -> Optional[str]:
+    """The layer that ``parts[i]`` marks, alone or with the part before it."""
+    part = parts[i]
+    if i and f"{parts[i - 1]}/{part}" in LAYER_SCOPES:
+        return f"{parts[i - 1]}/{part}"
+    if part in LAYER_SCOPES:
+        return part
+    if part in MODULE_LAYERS:
+        return MODULE_LAYERS[part]
+    return "norm" if _NORM_MODULE.search(part) else None
+
+
+def op_scope(op_name: str) -> OpScope:
+    """The one rule from an ``op_name`` to its phase, layer and path.
+
+    Transform wrappers (``jit(..)``, ``jvp(..)``, ``transpose(..)``,
+    ``vmap(..)``, ``checkpoint``), loop parts (``while/body/cond``) and the
+    trailing primitive are dropped. Of what is left the phase is the first
+    phase scope (in a train step: ``backward`` under a ``transpose(`` wrapper,
+    else ``forward`` under a ``jvp(``), and the layer the innermost part the
+    vocabulary knows, except that nothing is looked for inside a closed layer
+    (the MLP keeps its LayerNorm). Forward and backward of one module so give
+    one layer; a name that holds no known part is ``<unscoped>``. XLA joins
+    the names of merged instructions with ``;``: the first is read."""
+    raw = op_name.split(";", 1)[0].split("/")
+    parts = [p for p in map(_unwrap, raw[:-1]) if p and p not in _STRUCTURE]
+    phase = next((PHASE_SCOPES[p] for p in parts if p in PHASE_SCOPES), "")
+    if not phase and any(p.startswith("transpose(") for p in raw):
+        phase = "backward"
+    elif not phase and any("jvp(" in p for p in raw):
+        phase = "forward"
+    layer = None
+    for i in range(len(parts)):
+        layer = _layer_of(parts, i) or layer
+        if layer in CLOSED_LAYERS:
+            break
+    return OpScope(phase, layer or UNSCOPED, "/".join(parts))
+
 
 def scope_of(op_name: str, depth: Optional[int] = None) -> str:
-    """The module-scope path of an XLA op name.
-
-    ``jit(train_step)/jit(main)/perceiver_ar/cross_attention/fusion.3`` →
-    ``perceiver_ar/cross_attention``: jit-wrapper components are dropped, the
-    final component (the raw HLO op) is dropped, and ``depth`` optionally
-    truncates to the leading components. Names with no scope path aggregate
-    under ``<unscoped>``.
-    """
-    parts = [p for p in op_name.split("/") if "jit(" not in p]
-    parts = parts[:-1]
-    if not parts:
+    """The path of :func:`op_scope`, cut to its ``depth`` leading parts:
+    ``jit(train_step)/jit(main)/perceiver_ar/cross_attention/fusion.3`` is
+    ``perceiver_ar/cross_attention``; a name with no scope path is ``<unscoped>``."""
+    path = op_scope(op_name).path
+    if not path:
         return UNSCOPED
-    if depth is not None:
-        parts = parts[:depth]
-    return "/".join(parts)
+    return path if depth is None else "/".join(path.split("/")[:depth])
+
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(r"(?:body|condition|to_apply|calls|true_computation|false_computation)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+CONTAINER_OPCODES = frozenset({"while", "conditional", "call"})
+
+
+def _called_computations(line: str) -> List[str]:
+    branches = _BRANCHES.search(line)
+    return _CALLED.findall(line) + ([b.strip().lstrip("%") for b in branches.group(1).split(",")] if branches else [])
+
+
+def instruction_scopes(hlo_text: str) -> Dict[str, Dict]:
+    """``{instruction name: {opcode, phase, layer, path, container, inherited}}``
+    over a compiled module's entry computation and every computation a
+    ``while``, ``conditional``, ``call`` or async ``-start`` of it runs (the
+    device trace reports those instructions; what a fusion holds inside is
+    the fusion's). Built on ``analysis/graph.py::parse_hlo_computations``.
+
+    ``container`` marks the instructions whose time their bodies'
+    instructions also report. A fusion without an ``op_name`` takes its
+    root's, or the one scope that all it holds agree on. An instruction the
+    compiler made and gave no ``op_name`` (a layout ``copy``, a
+    ``slice-done``) takes the phase, and the layer, of the instructions it
+    feeds where they all have one and the same (a computation's root: of the
+    instruction that runs the computation; where that gives nothing: of the
+    instructions it reads), and is marked ``inherited``."""
+    from perceiver_io_tpu.analysis.graph import parse_hlo_computations
+
+    computations = parse_hlo_computations(hlo_text)
+    entry = re.search(r"^ENTRY\s+%?([\w.\-]+)", hlo_text, re.M)
+    if entry is None:
+        raise ValueError("the text holds no ENTRY computation")
+    table: Dict[str, Dict] = {}
+    pending = [(entry.group(1), None)]  # (computation, the scope of the instruction that runs it)
+    seen = set()
+    while pending:
+        name, caller = pending.pop()
+        if name in seen or name not in computations:
+            continue
+        seen.add(name)
+        instructions = computations[name]
+        users: Dict[str, List[str]] = {}
+        unnamed = []  # in the order walked: users first
+        for ins in instructions:
+            for operand in ins.operands:
+                users.setdefault(operand, []).append(ins.name)
+        # users come later in the text: walk it backwards, so that a chain of unnamed instructions resolves in one pass
+        for ins in reversed(instructions):
+            called = _called_computations(ins.line)
+            container = ins.opcode in CONTAINER_OPCODES or (ins.opcode.endswith("-start") and bool(called))
+            named = _OP_NAME.search(ins.line)
+            inside = []  # an unnamed fusion: the scopes of what it holds, the root's first
+            if named is None and ins.opcode == "fusion" and called:
+                held = sorted(computations.get(called[0], ()), key=lambda i: not i.line.startswith("ROOT "))
+                inside = [op_scope(m.group(1)) for m in map(_OP_NAME.search, (i.line for i in held)) if m]
+            inherited = False
+            if named is not None:
+                scope = op_scope(named.group(1))
+            elif inside and len({(s.phase, s.layer) for s in inside}) == 1:
+                scope = inside[0]
+            else:
+                fed = [table[u] for u in users.get(ins.name, ())] or ([caller] if caller else [])
+                phases, layers = {f["phase"] for f in fed}, {f["layer"] for f in fed}
+                agreed = len(layers) == 1 and layers != {UNSCOPED}  # the layer comes with its path: readers ask both
+                scope = OpScope(phases.pop() if len(phases) == 1 else "", layers.pop() if agreed else UNSCOPED,
+                                fed[0]["path"] if agreed else "")
+                inherited = bool(scope.phase) or agreed
+            table[ins.name] = {"opcode": ins.opcode, "phase": scope.phase, "layer": scope.layer, "path": scope.path,
+                               "container": container, "inherited": inherited}
+            if named is None and not inside:
+                unnamed.append(ins)
+            if container:
+                pending.extend((c, table[ins.name]) for c in called)
+        # what feeds nothing that has a scope (a copy into the program's result) takes the scope of what it reads
+        for ins in reversed(unnamed):
+            row, read = table[ins.name], [table[o] for o in ins.operands]
+            for key, none in (("phase", ""), ("layer", UNSCOPED)):
+                found = {r[key] for r in read}
+                if row[key] == none and len(found) == 1 and found != {none}:
+                    row[key], row["inherited"] = found.pop(), True
+                    if key == "layer":
+                        row["path"] = read[0]["path"]
+    return table
 
 
 @dataclass

@@ -158,7 +158,8 @@ def make_train_step(
             return loss, metrics
 
     def train_step(state: TrainState, batch):
-        rng, step_rng = jax.random.split(state.rng)
+        with jax.named_scope("optimizer"):  # the state's update holds the key chain's advance
+            rng, step_rng = jax.random.split(state.rng)
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
         if microbatch <= 1:
             (loss, metrics), grads = grad_fn(state.params, batch, step_rng)
@@ -202,7 +203,9 @@ def make_train_step(
             return metrics
 
         if not sentinel:
-            new_state = state.apply_gradients(grads).replace(rng=rng)
+            # gradient clipping, the AdamW update and apply_updates: the step's third phase, after forward and backward
+            with jax.named_scope("optimizer"):
+                new_state = state.apply_gradients(grads).replace(rng=rng)
             return new_state, attach_probes(metrics, new_state)
         # in-graph divergence sentinel: finiteness reduced inside the same
         # XLA program, the update SELECTED rather than branched (cond would
@@ -214,7 +217,8 @@ def make_train_step(
         for g in jax.tree.leaves(grads):
             if jnp.issubdtype(g.dtype, jnp.inexact):
                 ok = ok & jnp.all(jnp.isfinite(g))
-        updated = state.apply_gradients(grads).replace(rng=rng)
+        with jax.named_scope("optimizer"):
+            updated = state.apply_gradients(grads).replace(rng=rng)
         metrics = attach_probes(metrics, updated)
         held = state.replace(step=state.step + 1, rng=rng)
         state = jax.tree.map(lambda n, o: jnp.where(ok, n, o), updated, held)

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 IGNORE_INDEX = -100  # torch CrossEntropyLoss ignore_index parity
 
 
+@jax.named_scope("loss")
 def _cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Mean CE over labels != IGNORE_INDEX. Returns (loss, num_valid)."""
     valid = labels != IGNORE_INDEX

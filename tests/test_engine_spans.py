@@ -278,16 +278,18 @@ def test_trainer_step_span_holds_its_phases(tmp_path):
     assert {"fit", "step", "train/input_wait", "train/dispatch", "train/metrics_fetch"} <= names
 
 
-def _pallas_names(jaxpr, out):
+def _pallas_names(jaxpr, out, stacks=None):
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             out.append(eqn.params["name"])
+            if stacks is not None:
+                stacks.append(str(eqn.source_info.name_stack))
         for v in eqn.params.values():
             for x in v if isinstance(v, (list, tuple)) else [v]:
                 inner = getattr(x, "jaxpr", x)
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    _pallas_names(inner, out)
+                    _pallas_names(inner, out, stacks)
     return out
 
 
@@ -317,7 +319,12 @@ def test_train_step_names_flash_kernels_by_pass_and_geometry(latents, seq, keep)
     loss = clm_loss_fn(model.apply, max_latents=latents)
     with fa.default_flash(True):
         jaxpr = jax.make_jaxpr(jax.grad(lambda p: loss(p, batch, jax.random.PRNGKey(1))[0]))(params)
-    names = _pallas_names(jaxpr.jaxpr, [])
+    stacks = []
+    names = _pallas_names(jaxpr.jaxpr, [], stacks)
+    # a kernel's name is its innermost scope (XLA names the custom call after it): the scope vocabulary's layers
+    # (obs/xplane.py: ``rotary`` beside the kernels, the blocks around them) are opened outside it
+    assert len(stacks) == len(names) and all(stack.split("/")[-1] == name for stack, name in zip(stacks, names))
+    assert all("rotary" not in stack.split("/") for stack in stacks)
     cross, self_ = f"q{latents}_kv{keep + latents}", f"q{latents}_kv{latents}"
     want = {f"flash_{p}_{g}": n for g, n in ((cross, 1), (self_, 2)) for p in ("fwd", "bwd")}
     got = {n: names.count(n) for n in set(names)}

@@ -339,6 +339,32 @@ def test_scope_of_rules():
     assert scope_of("jit(f)/a/b/op", depth=1) == "a"
     assert scope_of("fusion.12") == UNSCOPED
     assert scope_of("jit(f)/op") == UNSCOPED
+    # the one rule (op_scope): forward and backward of a module land in one bucket, loop parts are no scope
+    forward = "jit(train_step)/jvp(CausalLanguageModel)/perceiver_ar/self_attend/mlp/dot_general"
+    backward = "jit(train_step)/transpose(jvp(CausalLanguageModel))/perceiver_ar/self_attend/mlp/dot_general"
+    assert scope_of(forward) == scope_of(backward) == "CausalLanguageModel/perceiver_ar/self_attend/mlp"
+    assert scope_of("jit(fn)/decode/while/body/closed_call/decode/sample/argmax") == "decode/decode/sample"
+
+
+def test_rollup_buckets_follow_the_one_rule():
+    """``rollup_planes`` puts an operation where ``op_scope`` puts it: the
+    backward's ``transpose(jvp(..))`` names join the forward's bucket."""
+    import collections
+
+    from perceiver_io_tpu.obs.xplane import UNSCOPED, PlaneSummary, op_scope, rollup_planes
+
+    names = {
+        "fusion.1": "jit(train_step)/jvp(CausalLanguageModel)/perceiver_ar/self_attend/mlp/dot_general",
+        "fusion.2": "jit(train_step)/transpose(jvp(CausalLanguageModel))/perceiver_ar/self_attend/mlp/dot_general",
+        "fusion.3": "jit(train_step)/optimizer/jit(_adamw_update)/mul",
+        "copy.4": "copy.4",
+    }
+    plane = PlaneSummary(name="/device:TPU:0", per_op=collections.Counter({"fusion.1": 300, "fusion.2": 500, "fusion.3": 40, "copy.4": 7}),
+                         counts=collections.Counter({op: 1 for op in names}), op_scopes=dict(names))
+    scopes = rollup_planes([plane])[0].scopes
+    assert scopes == {"CausalLanguageModel/perceiver_ar/self_attend/mlp": (800, 2), "optimizer": (40, 1), UNSCOPED: (7, 1)}
+    assert {op_scope(n).layer for n in names.values()} == {"mlp", "optimizer", UNSCOPED}
+    assert rollup_planes([plane], depth=1)[0].scopes["CausalLanguageModel"] == (800, 2)
 
 
 # ------------------------------------------------------- metrics resume

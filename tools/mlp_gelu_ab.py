@@ -155,7 +155,7 @@ def main():
               f"{len(step_hlo.entry_buffers(text, hidden))} entry instructions write an array of that shape", flush=True)
         for r in rows:
             print(f"    {r['name']:32s} {r['estimated_cycles']:9d} cycles, {r['exponential']} exponential, {r['divide']} divide, "
-                  f"{step_hlo.scope_tail(r['op_name'], 2)}")
+                  f"{step_hlo.scope_column(r['op_name'], 2)}")
     if args.compile_only:
         return
 
