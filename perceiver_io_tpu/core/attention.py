@@ -60,6 +60,20 @@ from perceiver_io_tpu.ops.flash_attention import (
     flash_supported,
     packed_supported,
 )
+from perceiver_io_tpu.ops.rotary import rotary_angles, rotary_supported, rotate_packed
+
+
+def rotate_slots_major(t: jnp.ndarray, pos_enc: jnp.ndarray, kernels: bool) -> jnp.ndarray:
+    """``apply_rotary_pos_emb(t, pos_enc[:, :, None, :])`` for the (B, N, H, d)
+    view of a packed array and (B, N, R) angles: by the lane-rotating kernel
+    (ops/rotary.py) where ``kernels`` may run (the caller's ``flash_enabled``)
+    and ``rotary_supported`` holds, else by that function. The two agree to
+    the bit forward (tests/test_rotary_kernel.py)."""
+    if kernels and rotary_supported(t.shape, pos_enc.shape):
+        b, n, h, d = t.shape
+        packed = rotate_packed(t.reshape(b, n, h * d), rotary_angles(pos_enc), h)
+        return packed.reshape(t.shape)
+    return apply_rotary_pos_emb(t, pos_enc[:, :, None, :])
 
 
 @struct.dataclass
@@ -207,12 +221,13 @@ class MultiHeadAttention(nn.Module):
         qk_per_head = self.qk_channels // h
         q4 = q.reshape(q.shape[0], q.shape[1], h, qk_per_head) * qk_per_head**-0.5
         # the rotation with the reshapes around it: the layout copies they cost are the rotation's
+        # (``packed_route_ok`` held, so the flash kernels run here, and the rotation's may)
         with jax.named_scope("rotary"):
             if rope_q is not None:
-                q4 = apply_rotary_pos_emb(q4, rope_q[:, :, None, :])
+                q4 = rotate_slots_major(q4, rope_q, True)
             if rope_k is not None and not already_rotated_k:
                 k4 = k.reshape(k.shape[0], k.shape[1], h, qk_per_head)
-                k4 = apply_rotary_pos_emb(k4, rope_k[:, :, None, :])
+                k4 = rotate_slots_major(k4, rope_k, True)
                 k = k4.reshape(k.shape)
             q = q4.reshape(q.shape)
         return flash_attention_packed(
@@ -406,7 +421,7 @@ class MultiHeadAttention(nn.Module):
             if rope_k is not None:
                 with jax.named_scope("rotary"):
                     k4 = k.reshape(k.shape[0], k.shape[1], h, qk_per_head)
-                    k4 = apply_rotary_pos_emb(k4, rope_k[:, :, None, :])
+                    k4 = rotate_slots_major(k4, rope_k, flash_enabled(self.use_flash))
                     k = k4.reshape(k.shape)
             if isinstance(kv_cache, PagedKVCache):
                 # paged discipline (the engine decode step): page-table-

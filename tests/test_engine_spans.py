@@ -335,3 +335,51 @@ def test_train_step_names_flash_kernels_by_pass_and_geometry(latents, seq, keep)
     assert {r["backward"] for r in rows if r["geometry"] in (cross, self_)} == {"one"}
     assert shares[self_] == (1.0 if latents == 128 else fa.tile_plan(latents, latents, True).run_share)
     assert fa.tile_plan(512, 512, True).run_share <= 0.75
+
+
+def test_train_step_names_rotary_kernels_under_the_rotary_layer():
+    """At a packed width on the 128 lanes the rotation of queries and keys is
+    the kernel ``rotary_<fwd|bwd>_n<rows>_c<channels>`` (ops/rotary.py), called
+    inside the ``rotary`` scope its call sites open: ``obs.xplane.op_scope``
+    gives its ``op_name`` the layer ``rotary`` in ``forward`` and in
+    ``backward``, and the name holds no ``flash`` (the benchmark's readers
+    select the flash kernels by that word)."""
+    from perceiver_io_tpu.obs.xplane import op_scope
+    from perceiver_io_tpu.training import clm_loss_fn
+
+    fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
+    latents, seq, keep = 128, 384, 128
+    config = CausalLanguageModelConfig(
+        vocab_size=VOCAB, max_seq_len=seq, max_latents=latents, num_channels=128,
+        num_heads=2, num_self_attention_layers=2, cross_attention_dropout=0.5,
+    )
+    model = CausalLanguageModel(config)
+    ids = jnp.zeros((1, seq), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), ids, prefix_len=seq - latents))
+    batch = {
+        "input_ids": jnp.zeros((2, seq), jnp.int32), "labels": jnp.zeros((2, seq), jnp.int32), "pad_mask": None,
+        "prefix_keep_idx": jnp.tile(jnp.arange(keep, dtype=jnp.int32), (2, 1)),
+    }
+    loss = clm_loss_fn(model.apply, max_latents=latents)
+    with fa.default_flash(True):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p: loss(p, batch, jax.random.PRNGKey(1))[0]))(params)
+    # (kernel name, op_name as the compiled program carries it: the name stacks of the enclosing equations joined)
+    calls = []
+
+    def walk(jaxpr, outer):
+        for eqn in jaxpr.eqns:
+            stack = "/".join(p for p in (outer, str(eqn.source_info.name_stack)) if p)
+            if eqn.primitive.name == "pallas_call":
+                calls.append((eqn.params["name"], f"jit(train_step)/{stack}/pallas_call"))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, stack)
+
+    walk(jaxpr.jaxpr, "")
+    rotary = [(name, op_scope(op_name)) for name, op_name in calls if name.startswith("rotary")]
+    assert all("flash" in name or name.startswith(("rotary", "embed_pos_grad")) for name, _ in calls)
+    # the cross-attention's keys (kept prefix + latents) and queries, the one rotary latent layer's queries and keys
+    want = {f"rotary_{p}_n{n}_c128": count for p in ("fwd", "bwd") for n, count in ((keep + latents, 1), (latents, 3))}
+    assert {n: [name for name, _ in rotary].count(n) for n in want} == want and len(rotary) == 8
+    for name, scope in rotary:
+        assert scope.layer == "rotary" and scope.path.endswith(f"rotary/{name}"), (name, scope)
+        assert scope.phase == ("forward" if "_fwd_" in name else "backward"), (name, scope)

@@ -157,6 +157,48 @@ def test_embed_position_table_grad_on_batch_shards(four_chips, mosaic):
     assert f"[{batch // 4 * positions},{CHANNELS}]" not in text and "all-gather" not in text
 
 
+@pytest.mark.parametrize(
+    "batch,rows,mesh_shape",
+    [(32, 8704, None), (64, 16128, None), (2, 300, None), (32, 8704, (2, 2))],
+    ids=["train_keys", "prompt_keys_part_block", "rows_under_a_block", "data_x_fsdp"],
+)
+def test_rotary_kernel_forward_and_backward(four_chips, one_chip, mosaic, batch, rows, mesh_shape):
+    """The rotation of a packed (B, N, 512) array at the two cells' key
+    shapes (``ar16k-train-b32``'s 8704 kept-prefix-plus-latent rows; the
+    decode cell's 16 128-row prompt, whose last block is half a block) and at
+    a row count under one block: two named kernels fed in the packed layout,
+    no float32 array of the keys' size, no head-broadcast table, no copy of
+    either. Under ``kernel_mesh`` each chip rotates its own rows."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from perceiver_io_tpu.core.attention import rotate_slots_major
+    from perceiver_io_tpu.core.position import frequency_position_encoding
+
+    d, r = CHANNELS // HEADS, CHANNELS // HEADS // 2
+    mesh = None if mesh_shape is None else Mesh(np.asarray(four_chips).reshape(mesh_shape), ("data", "fsdp"))
+    sharding = one_chip if mesh is None else NamedSharding(mesh, P(("data", "fsdp")))
+    t = jax.ShapeDtypeStruct((batch, rows, CHANNELS), jnp.bfloat16, sharding=sharding)
+    pos = jax.ShapeDtypeStruct((batch, rows), jnp.int32, sharding=sharding)
+
+    def rotate(x, pos):  # as the call sites do: the 4-D view of the packed projection
+        x4 = rotate_slots_major(x.reshape(*x.shape[:2], HEADS, d), frequency_position_encoding(pos, r), True)
+        return x4.reshape(x.shape)
+
+    def both(t, g, pos):
+        with fa.kernel_mesh(mesh, ("data", "fsdp")):
+            out, vjp = jax.vjp(lambda x: rotate(x, pos), t)
+            return out, vjp(g)[0]
+
+    text = _compile(both, t, t, pos)
+    local = batch if mesh is None else batch // 4
+    assert f"rotary_fwd_n{rows}_c{CHANNELS}" in text and f"rotary_bwd_n{rows}_c{CHANNELS}" in text
+    assert f"f32[{local},{rows},{CHANNELS}]" not in text and f"f32[{local},{rows},{HEADS}," not in text
+    assert f"bf16[{local},{rows},{CHANNELS}]{{2,1,0" in text and f"[{local},{rows},{CHANNELS}]{{1," not in text
+    assert "all-gather" not in text and "all-reduce" not in text
+
+
 @pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.int8], ids=["bf16", "int8"])
 def test_decode_attention_16k_cache(one_chip, cache_dtype):
     """One decode token per sequence against a full-context cache: the
