@@ -24,6 +24,14 @@ and on ``k_rope``, adjacent channels paired (``core/position.py``); the
 softmax scale is ``(nope + rope)^-0.5 * mscale^2``. RMSNorm on both latents.
 No biases. Scores and the softmax are float32; products take ``dtype``
 operands and accumulate in float32.
+
+Two switches of the LongCat-Flash family scale the normed latents, so that the
+up-projections see an input of the hidden state's variance whatever the rank:
+``mla_scale_q_lora`` gives ``c_q = RMSNorm(x W_dq) * sqrt(hidden / q_lora_rank)``
+and ``mla_scale_kv_lora`` ``c_kv = RMSNorm(.) * sqrt(hidden / kv_lora_rank)``
+(``k_rope`` is not scaled). The cache's rows hold the scaled ``c_kv``: both
+paths read it from :meth:`_latent_rows`, so the absorbed step agrees with the
+expanded pass by construction. ``rope_scaling`` ``None`` is plain rotary.
 """
 
 from __future__ import annotations
@@ -46,7 +54,8 @@ class MultiHeadLatentAttention(nn.Module):
     ``qk_rope_head_dim``, ``v_head_dim``, ``rms_norm_eps``, ``rope_theta``,
     ``rope_scaling`` (``None`` or an object with YaRN's ``factor``,
     ``beta_fast``, ``beta_slow``, ``mscale``, ``mscale_all_dim``,
-    ``original_max_position_embeddings``) and ``init_scale``."""
+    ``original_max_position_embeddings``), ``mla_scale_q_lora``,
+    ``mla_scale_kv_lora`` and ``init_scale``."""
 
     config: object
     dtype: jnp.dtype = jnp.float32
@@ -90,21 +99,26 @@ class MultiHeadLatentAttention(nn.Module):
     def _mm(self, x, w):
         return jnp.dot(x.astype(self.dtype), w.astype(self.dtype))
 
+    def _scaled(self, latent, rank: int, on: bool):
+        """A normed latent times ``sqrt(hidden / rank)`` where the configuration switches that on."""
+        return latent * (self.config.hidden_size / rank) ** 0.5 if on else latent
+
     def _queries(self, x, pos) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """``x`` (B, N, h), ``pos`` (B, N) -> ``q_nope`` (B, N, H, nope) and
         the rotated ``q_rope`` (B, N, H, rope)."""
         c = self.config
         b, n, _ = x.shape
-        q = self._mm(self.q_norm(self._mm(x, self.w_dq)), self.w_uq)
+        c_q = self._scaled(self.q_norm(self._mm(x, self.w_dq)), c.q_lora_rank, c.mla_scale_q_lora)
+        q = self._mm(c_q, self.w_uq)
         q = q.reshape(b, n, c.num_attention_heads, c.qk_nope_head_dim + c.qk_rope_head_dim)
         q_nope, q_rope = q[..., : c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:]
         return q_nope, apply_rotary_interleaved(q_rope, pos[:, :, None], self._inv_freq())
 
     def _latent_rows(self, x, pos) -> jnp.ndarray:
-        """The cache's rows for ``x``: ``[RMSNorm(c_kv); rotated k_rope]`` (B, N, rank + rope)."""
+        """The cache's rows for ``x``: ``[RMSNorm(c_kv), scaled where switched on; rotated k_rope]`` (B, N, rank + rope)."""
         c = self.config
         kv = self._mm(x, self.w_dkv)
-        c_kv = self.kv_norm(kv[..., : c.kv_lora_rank])
+        c_kv = self._scaled(self.kv_norm(kv[..., : c.kv_lora_rank]), c.kv_lora_rank, c.mla_scale_kv_lora)
         k_rope = apply_rotary_interleaved(kv[..., c.kv_lora_rank:], pos, self._inv_freq())
         return jnp.concatenate([c_kv, k_rope], axis=-1)
 

@@ -1,15 +1,27 @@
 """Sparse experts, sigmoid-routed with a shared expert (DeepSeek-V3,
-arXiv:2412.19437 section 2.1.2) or softmax-routed with none (the Mellum
-family: ``choose_experts_softmax``), as **one chip's share** of an
-expert-parallel layer.
+arXiv:2412.19437 section 2.1.2), softmax-routed with none (the Mellum
+family: ``choose_experts_softmax``), or softmax-routed under a bias over
+experts of which some have no weights (the LongCat-Flash family:
+``choose_experts_softmax_biased`` and ``zero_expert_num``), as **one chip's
+share** of an expert-parallel layer.
 
 The router keeps its published width: every token is scored against all
-``n_routed_experts``. The layer is told which experts it holds
-(``held_experts_start``, ``n_held_experts``) and computes their part of the
-result for the tokens routed to them. A pair routed to an expert held
-elsewhere adds nothing here, and no code stands in for the other chips or for
-the exchange with them. The shared expert is computed on every chip alike.
-With every expert held, the layer is the whole layer.
+``n_routed_experts`` (and the ``zero_expert_num`` outputs after them). The
+layer is told which experts it holds (``held_experts_start``,
+``n_held_experts``, counted among the experts that have weights) and computes
+their part of the result for the tokens routed to them. A pair routed to an
+expert held elsewhere adds nothing here, and no code stands in for the other
+chips or for the exchange with them. The shared expert is computed on every
+chip alike. With every expert held, the layer is the whole layer.
+
+**Experts without weights** (``zero_expert_num`` > 0; arXiv:2509.01322 section
+2.1): router outputs ``n_routed_experts`` to ``n_routed_experts +
+zero_expert_num - 1`` are identity experts, ``E(x) = x``. A pair routed to one
+has no row in any pass and no column in the dense path: a token's identity
+pairs are summed into one weight ``w0`` and ``w0 * x`` is added under the
+scope ``moe/zero``, on every chip alike (the token is here, so nothing is
+exchanged for it), like the shared expert. How many experts with weights a
+token runs is then 0 to ``num_experts_per_tok``.
 
 Routing (``inference/model.py``'s ``Gate``): ``s = sigmoid(x W_g)`` in
 float32; experts are *chosen* on ``s + b`` (``b`` the bias that balances load
@@ -18,6 +30,12 @@ score is the sum of its two largest, the ``topk_group`` best groups stay, and
 the ``num_experts_per_tok`` largest within them are chosen. The *weights* are
 the unbiased ``s`` of the chosen, renormalised to sum to one and scaled by
 ``routed_scaling_factor``.
+
+The third rule (``scoring_func="softmax_biased"``): ``p = softmax(x W_r)`` in
+float32 over every output, the ``num_experts_per_tok`` largest of ``p + b``
+chosen, no groups; the weights are the unbiased ``p`` of the chosen times
+``routed_scaling_factor`` and are **not** renormalised (the bias chooses and
+never weighs, as above).
 
 Two ways through the held experts, chosen at trace time by the number of
 tokens (``_cuts(...).grouped_min_tokens``, where the two were measured to cross), each
@@ -176,6 +194,16 @@ def choose_experts_softmax(logits: jnp.ndarray, top_k: int) -> Tuple[jnp.ndarray
     return chosen.astype(jnp.int32), w / w.sum(-1, keepdims=True)
 
 
+def choose_experts_softmax_biased(logits: jnp.ndarray, bias: jnp.ndarray, top_k: int, scale: float) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The softmax rule under a bias: ``p = softmax(logits)`` over all outputs
+    in float32, the ``top_k`` largest of ``p + bias`` are chosen, and their
+    weights are the unbiased ``p`` times ``scale``, not renormalised. Returns
+    chosen (T, top_k) int32 and weights (T, top_k) float32."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, chosen = lax.top_k(p + bias.astype(jnp.float32), top_k)
+    return chosen.astype(jnp.int32), jnp.take_along_axis(p, chosen, axis=1) * scale
+
+
 def _silu_gate(h1, h3, dtype):
     return (jax.nn.silu(h1.astype(jnp.float32)) * h3.astype(jnp.float32)).astype(dtype)
 
@@ -195,7 +223,9 @@ def grouped_combine(n_held: int, n_routed: int) -> str:
     where the layer holds every expert (a token's ``k`` pairs are all here, so
     the sorted order is a permutation of all pairs and its inverse finds
     them), ``"scatter"`` where it holds a share (a token has 0 to ``k`` local
-    pairs: only those are moved)."""
+    pairs: only those are moved). ``n_routed`` is the router's width: a layer
+    with experts that have no weights never holds every output, since an
+    identity pair has no row to find."""
     return "gather" if n_held == n_routed else "scatter"
 
 
@@ -281,12 +311,16 @@ class SwiGLU(nn.Module):
 
 class MoELayer(nn.Module):
     """``config`` needs ``hidden_size``, ``moe_intermediate_size``,
-    ``n_routed_experts`` (the router's width), ``n_held_experts``,
-    ``held_experts_start``, ``num_experts_per_tok``, ``n_shared_experts``
-    (0: no shared expert), ``init_scale`` and ``scoring_func``: ``"sigmoid"``
-    (the module docstring's rule; also needs ``n_group``, ``topk_group``,
-    ``routed_scaling_factor``, and the layer has a ``gate_bias``) or
-    ``"softmax"`` (:func:`choose_experts_softmax`: no bias, groups or scale)."""
+    ``n_routed_experts`` (the experts that have weights), ``zero_expert_num``
+    (identity experts after them: the router's width is the sum),
+    ``n_held_experts``, ``held_experts_start``, ``num_experts_per_tok``,
+    ``n_shared_experts`` (0: no shared expert), ``init_scale`` and
+    ``scoring_func``: ``"sigmoid"`` (the module docstring's rule; also needs
+    ``n_group``, ``topk_group``, ``routed_scaling_factor``, and the layer has a
+    ``gate_bias``), ``"softmax"`` (:func:`choose_experts_softmax`: no bias,
+    groups or scale) or ``"softmax_biased"``
+    (:func:`choose_experts_softmax_biased`: ``gate_bias`` and
+    ``routed_scaling_factor``, no groups)."""
 
     config: object
     dtype: jnp.dtype = jnp.float32
@@ -299,13 +333,14 @@ class MoELayer(nn.Module):
         x = x.reshape(-1, h).astype(self.dtype)
         t = x.shape[0]
         g, start = c.n_held_experts, c.held_experts_start
+        n_outputs = c.n_routed_experts + c.zero_expert_num  # the router's width: the identity experts come last
         init = nn.initializers.normal(c.init_scale)
-        w_gate = self.param("gate", init, (h, c.n_routed_experts), self.param_dtype)
-        if c.scoring_func not in ("sigmoid", "softmax"):
-            raise ValueError(f"scoring_func {c.scoring_func!r}: 'sigmoid' or 'softmax'")
-        if c.scoring_func == "sigmoid":
+        w_gate = self.param("gate", init, (h, n_outputs), self.param_dtype)
+        if c.scoring_func not in ("sigmoid", "softmax", "softmax_biased"):
+            raise ValueError(f"scoring_func {c.scoring_func!r}: 'sigmoid', 'softmax' or 'softmax_biased'")
+        if c.scoring_func != "softmax":
             # float32 whatever the rest is stored in, as published: it is added to scores that differ in the last digits
-            gate_bias = self.param("gate_bias", nn.initializers.zeros_init(), (c.n_routed_experts,), jnp.float32)
+            gate_bias = self.param("gate_bias", nn.initializers.zeros_init(), (n_outputs,), jnp.float32)
         width = c.moe_intermediate_size
         w1 = self.param("experts_w1", init, (g, h, width), self.param_dtype).astype(self.dtype)
         w3 = self.param("experts_w3", init, (g, h, width), self.param_dtype).astype(self.dtype)
@@ -314,6 +349,9 @@ class MoELayer(nn.Module):
         with jax.named_scope("moe/route"):
             if c.scoring_func == "softmax":
                 chosen, weights = choose_experts_softmax(router_logits(x, w_gate), c.num_experts_per_tok)
+            elif c.scoring_func == "softmax_biased":
+                chosen, weights = choose_experts_softmax_biased(
+                    router_logits(x, w_gate), gate_bias, c.num_experts_per_tok, c.routed_scaling_factor)
             else:
                 chosen, weights = choose_experts(
                     router_scores(x, w_gate), gate_bias, n_group=c.n_group, topk_group=c.topk_group,
@@ -327,24 +365,35 @@ class MoELayer(nn.Module):
             cuts = _cuts(h, width)
             back = None  # how a grouped pass's rows get back to their tokens; the dense path has no such step
             if t >= cuts.grouped_min_tokens:
-                rows = _pass_rows(t * c.num_experts_per_tok, g / c.n_routed_experts, cuts)
-                back = grouped_combine(g, c.n_routed_experts)
+                rows = _pass_rows(t * c.num_experts_per_tok, g / n_outputs, cuts)
+                back = grouped_combine(g, n_outputs)
                 y, unserved, passes = experts_grouped(x, local, weights, w1, w3, w2, rows, cuts.row_tile, back)
             else:
                 combine = (jax.nn.one_hot(local, g, dtype=jnp.float32) * weights[:, :, None]).sum(axis=1)
                 y = experts_dense(x, combine, w1, w3, w2)
 
+        if c.zero_expert_num:
+            with jax.named_scope("moe/zero"):
+                # a token's identity pairs as one weight: no row of a pass, no column of the dense path
+                is_zero = chosen >= c.n_routed_experts
+                w_zero = jnp.where(is_zero, weights, 0.0).sum(axis=1)
+                y = y + w_zero[:, None] * x.astype(jnp.float32)
+
         if probes.active():
             load = jnp.zeros((g + 1,), jnp.int32).at[local.reshape(-1)].add(1)[:g]
             pairs_local = load.sum()
-            probes.tap("moe.load", {
+            taps = {
                 "pairs_routed": jnp.asarray(t * c.num_experts_per_tok, jnp.int32),
                 "pairs_local": pairs_local,
                 "pairs_gathered": pairs_local if back == "gather" else jnp.zeros((), jnp.int32),
                 "pairs_dropped": unserved.astype(jnp.int32),
                 "passes": passes.astype(jnp.int32),
                 "expert_load_max": load.max(),
-            })
+            }
+            if c.zero_expert_num:  # the pairs that need no expert's weights, and the most experts with weights a token runs
+                n_zero = is_zero.sum(axis=1).astype(jnp.int32)
+                taps.update(pairs_zero=n_zero.sum(), real_experts_per_token_max=(c.num_experts_per_tok - n_zero).max())
+            probes.tap("moe.load", taps)
 
         if c.n_shared_experts:
             with jax.named_scope("moe/shared"):

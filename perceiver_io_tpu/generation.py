@@ -1942,6 +1942,11 @@ def make_instrumented_generate_fn(
                     m_moe_load.set(max(int(h["expert_load_max"]) for h in hh))
                     health_row["moe_local_share"] = round(local / max(routed, 1), 6)
                     health_row["moe_pairs_dropped"] = dropped
+                    if "pairs_zero" in hh[0]:  # experts without weights: the pairs they took, the most real experts a token ran
+                        zero = sum(int(h["pairs_zero"]) for h in hh)
+                        registry.counter("moe_pairs_zero_total").inc(zero)
+                        registry.gauge("moe_real_experts_per_token").set(max(int(h["real_experts_per_token_max"]) for h in hh))
+                        health_row["moe_zero_share"] = round(zero / max(routed, 1), 6)
                 if spec_taps:
                     drafts, accepted = (sum(int(h[k]) for h in hh) for k in ("drafts", "accepted"))
                     m_spec_drafts.inc(drafts)

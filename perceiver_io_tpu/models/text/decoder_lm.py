@@ -2,7 +2,9 @@
 ``x + Attn(RMSNorm(x))`` then ``x + FFN(RMSNorm(x))``, a final RMSNorm and an
 untied head (``docs/decoder-lm.md``). One class, two published families, told
 apart by the configuration (a third, K-EXAONE, puts the first's expert layer
-on the second's skeleton and adds the drafting module below):
+on the second's skeleton and adds the drafting module below; a fourth,
+LongCat-Flash, changes the block itself: **the shortcut-connected block**,
+further down):
 
 - **DeepSeek-V3** (``layer_types`` None): multi-head latent attention
   (``core/mla.py``) in every block, ``first_k_dense_replace`` blocks with a
@@ -31,6 +33,27 @@ step verifies two positions a row (``_Decoder``'s ``spec_*`` methods;
 (``core/cache.py``'s ragged classes). DeepSeek-V3's own published module is
 still not built (its configuration here keeps ``num_nextn_predict_layers`` 0),
 and Mellum's config has no key for one.
+
+**The shortcut-connected block** (``block="shortcut"``; the LongCat-Flash
+family, arXiv:2509.01322 section 2.2, the topology of arXiv:2404.05019): a
+layer is two latent attentions and two dense SwiGLUs in series, and the expert
+layer is a branch that reads the first sublayer's normed state and joins the
+residual at the layer's end::
+
+    a0 = h  + MLA_0(RMS(h))
+    u  = RMS(a0)
+    s  = MoE(u)                      # the shortcut: reads u, joins at the end
+    b0 = a0 + FFN_0(u)
+    a1 = b0 + MLA_1(RMS(b0))
+    h' = a1 + FFN_1(RMS(a1)) + s
+
+Every layer is such a block (no leading dense layers), a layer owns two
+:class:`LatentCache` (the generator's state holds ``2 * num_hidden_layers``,
+a layer's pair side by side), and the prompt pass runs a whole layer over a
+chunk of whole rows, so the branch's output lives a chunk long. The expert
+layer routes by the third rule of ``core/moe.py`` over experts of which
+``zero_expert_num`` have no weights, and the attentions scale their normed
+latents (``mla_scale_q_lora``, ``mla_scale_kv_lora``: ``core/mla.py``).
 """
 
 from __future__ import annotations
@@ -56,6 +79,7 @@ from perceiver_io_tpu.ops.layernorm import RMSNorm
 
 
 _LAYER_TYPES = ("sliding_attention", "full_attention")
+_BLOCKS = ("serial", "shortcut")
 
 
 @dataclass(frozen=True)
@@ -121,8 +145,17 @@ class DecoderLanguageModelConfig:
     full_attention_rotary: bool = True
     num_nextn_predict_layers: int = 0
     mtp_layer_types: Tuple[str, ...] = ("full_attention",)
+    block: str = "serial"
+    zero_expert_num: int = 0
+    mla_scale_q_lora: bool = False
+    mla_scale_kv_lora: bool = False
 
     def __post_init__(self):
+        if self.block not in _BLOCKS:
+            raise ValueError(f"block {self.block!r}: one of {_BLOCKS}")
+        if self.block == "shortcut" and (self.layer_types is not None or self.first_k_dense_replace
+                                         or self.num_nextn_predict_layers):
+            raise ValueError("the shortcut-connected block: latent attention, an expert branch in every layer, no module")
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             if len(self.layer_types) != self.num_hidden_layers or set(self.layer_types) - set(_LAYER_TYPES):
@@ -206,10 +239,57 @@ class DecoderBlock(nn.Module):
         a, cache = one_token(self.attn_norm(x), cache, pos)
         return self.feed_forward(_residual(x, a)), cache
 
+    def __call__(self, x, pos):
+        """Whole rows ``x`` (B, N, h): the block's output and its cache rows."""
+        x, rows = self.attend(x, pos)
+        return self.feed_forward(x), rows
+
     def verify(self, x, cache, pos):
         """A speculative step's positions, each row at its own length: written to ``cache``, not yet kept."""
         a, cache = self.attn.verify(self.attn_norm(x), cache, pos)
         return self.feed_forward(_residual(x, a)), cache
+
+
+class ShortcutBlock(nn.Module):
+    """The shortcut-connected block (the module docstring's equations): two
+    latent attentions and two dense SwiGLUs in series, the expert layer a
+    branch from the first sublayer's normed state to the layer's end."""
+
+    config: DecoderLanguageModelConfig
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    def setup(self):
+        c = self.config
+        kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
+        norm = functools.partial(RMSNorm, epsilon=c.rms_norm_eps, **kw)
+        dense = functools.partial(SwiGLU, c.hidden_size, c.intermediate_size, c.init_scale, **kw)
+        self.attn0_norm, self.attn0 = norm(), MultiHeadLatentAttention(c, **kw)
+        self.ffn0_norm, self.ffn0, self.moe = norm(), dense(), MoELayer(c, **kw)
+        self.attn1_norm, self.attn1 = norm(), MultiHeadLatentAttention(c, **kw)
+        self.ffn1_norm, self.ffn1 = norm(), dense()
+
+    def _layer(self, x, attend0, attend1):
+        """The block around its two attentions, ``attend_j(normed x) -> (output, what the path keeps)``."""
+        a, kept0 = attend0(self.attn0_norm(x))
+        x = _residual(x, a)
+        u = self.ffn0_norm(x)
+        shortcut = self.moe(u)  # opens its own scopes (``moe/*``)
+        with jax.named_scope("dense_mlp"):
+            x = x + self.ffn0(u)
+        a, kept1 = attend1(self.attn1_norm(x))
+        x = _residual(x, a)
+        with jax.named_scope("dense_mlp"):
+            x = x + self.ffn1(self.ffn1_norm(x))
+        return _residual(x, shortcut), (kept0, kept1)
+
+    def __call__(self, x, pos):
+        """Whole rows ``x`` (B, N, h), expanded: the layer's output and its two caches' rows."""
+        return self._layer(x, lambda y: self.attn0.expand(y, pos), lambda y: self.attn1.expand(y, pos))
+
+    def step(self, x, caches: Tuple[LatentCache, LatentCache], pos):
+        """One new token a row against the layer's two caches: the output and the advanced pair."""
+        return self._layer(x, lambda y: self.attn0.absorb(y, caches[0], pos), lambda y: self.attn1.absorb(y, caches[1], pos))
 
 
 class MTPModule(nn.Module):
@@ -291,11 +371,14 @@ class DecoderLanguageModel(nn.Module):
         self.embedding = self.param(
             "embedding", nn.initializers.normal(c.init_scale), (c.vocab_size, c.hidden_size), self.param_dtype
         )
-        self.layers = [
-            DecoderBlock(c, sparse=i >= c.first_k_dense_replace, layer_type=c.layer_types and c.layer_types[i],
-                         name=f"layer_{i}", **kw)
-            for i in range(c.num_hidden_layers)
-        ]
+        if c.block == "shortcut":
+            self.layers = [ShortcutBlock(c, name=f"layer_{i}", **kw) for i in range(c.num_hidden_layers)]
+        else:
+            self.layers = [
+                DecoderBlock(c, sparse=i >= c.first_k_dense_replace, layer_type=c.layer_types and c.layer_types[i],
+                             name=f"layer_{i}", **kw)
+                for i in range(c.num_hidden_layers)
+            ]
         self.out_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
         self.head = self.param(
             "head", nn.initializers.normal(c.init_scale), (c.hidden_size, c.vocab_size), self.param_dtype
@@ -320,6 +403,9 @@ class DecoderLanguageModel(nn.Module):
 
     def ffn_layer(self, x, i: int):
         return self.layers[i].feed_forward(x)
+
+    def whole_layer(self, x, pos, i: int):
+        return self.layers[i](x, pos)
 
     # the module's parts, for the prompt pass's chunk loops in the same way
 
@@ -346,8 +432,7 @@ class DecoderLanguageModel(nn.Module):
         pos = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None], (b, n))
         x = self.embed(input_ids)
         for layer in self.layers:
-            x, _ = layer.attend(x, pos)
-            x = layer.feed_forward(x)
+            x, _ = layer(x, pos)
         if not drafts:
             return self.logits(x)
         u = self.mtp_project(x[:, :-1], input_ids[:, 1:])
@@ -360,6 +445,11 @@ class DecoderLanguageModel(nn.Module):
         pos = jnp.broadcast_to(caches[0].length, (b, 1)).astype(jnp.int32)
         x = self.embed(token)[:, None]
         new = []
+        if self.config.block == "shortcut":  # a layer's two caches lie side by side
+            for i, layer in enumerate(self.layers):
+                x, pair = layer.step(x, caches[2 * i: 2 * i + 2], pos)
+                new.extend(pair)
+            return self.logits(x[:, 0]), tuple(new)
         for layer, cache in zip(self.layers, caches):
             x, cache = layer.step(x, cache, pos)
             new.append(cache)
@@ -409,7 +499,8 @@ def _prefill_cuts(b: int, n: int) -> Tuple[int, int]:
 
 def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = False) -> Tuple[jnp.ndarray, tuple]:
     """The prompt pass: last-position logits (B, V) and, a layer, the cache
-    rows of the prompt: (B, N, width) of a latent layer; of a grouped-query
+    rows of the prompt: (B, N, width) of a latent layer (of a shortcut-connected
+    layer two of them, side by side in the tuple); of a grouped-query
     layer the keys and the values, each (B * Hkv, N, D), a window layer's
     last ``sliding_window`` positions only. The hidden state of the whole batch
     stays in memory between layers (B * N * h); within a layer the attention
@@ -429,6 +520,11 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
     x = scoped("embed", input_ids)
     cache_rows = []
     for i in range(c.num_hidden_layers):
+        if c.block == "shortcut":  # the whole layer over a chunk of whole rows: the branch's output lives a chunk long
+            x, pair = _over_chunks(lambda xc, i=i: scoped("whole_layer", xc, pos, i), x.reshape(b // rows_a, rows_a, n, h))
+            cache_rows.extend(rows.reshape(b, n, rows.shape[-1]) for rows in pair)
+            x = x.reshape(b, n, h)
+            continue
         x, rows = _over_chunks(lambda xc, i=i: scoped("attend_layer", xc, pos, i), x.reshape(b // rows_a, rows_a, n, h))
         if c.layer_types is None:
             cache_rows.append(rows.reshape(b, n, rows.shape[-1]))
@@ -593,7 +689,8 @@ class _Decoder:
         c = self.model.config
         itemsize = jnp.dtype(cache_dtype).itemsize
         # how a prompt chunk's expert rows get back to their tokens (a step of few tokens takes the dense path)
-        moe = {"moe_combine": grouped_combine(c.n_held_experts, c.n_routed_experts)}
+        router_width = c.n_routed_experts + c.zero_expert_num
+        moe = {"moe_combine": grouped_combine(c.n_held_experts, router_width)}
         if c.layer_types is not None:
             row_bytes = 2 * c.num_key_value_heads * c.head_dim * itemsize  # a token's keys and values in one layer
             # the module's block keeps a cache of its own kind beside the stack's
@@ -615,11 +712,15 @@ class _Decoder:
                            kv_cache_window_slack_rows=slack)
             return row
         row_bytes = (c.kv_lora_rank + c.qk_rope_head_dim) * itemsize
+        n_caches = c.num_hidden_layers
+        if c.block == "shortcut":  # two attentions a layer, each with a cache; the router's outputs past the experts' weights
+            n_caches *= 2
+            moe.update(block=c.block, moe_router_width=router_width, moe_zero_experts=c.zero_expert_num)
         return {
             "latent_cache_row_bytes": row_bytes,
             "latent_cache_capacity": prompt_len + max_new_tokens,
-            "latent_cache_layers": c.num_hidden_layers,
-            "latent_cache_bytes": batch * (prompt_len + max_new_tokens) * row_bytes * c.num_hidden_layers,
+            "latent_cache_layers": n_caches,
+            "latent_cache_bytes": batch * (prompt_len + max_new_tokens) * row_bytes * n_caches,
             **moe,
         }
 

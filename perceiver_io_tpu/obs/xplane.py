@@ -286,7 +286,7 @@ LAYER_SCOPES = frozenset({
     "logits", "qkv_proj", "rotary", "loss", "sample", "optimizer",
     # decoder-only class (models/text/decoder_lm.py, core/mla.py, core/gqa.py, core/moe.py)
     "mla/expand", "mla/absorb", "attn/window", "attn/full", "moe/route", "moe/experts", "moe/combine",
-    "moe/shared", "mtp/project", "mtp/block", "mtp/draft", "spec/verify", "spec/accept", "spec/rollback",
+    "moe/shared", "moe/zero", "mtp/project", "mtp/block", "mtp/draft", "spec/verify", "spec/accept", "spec/rollback",
     "dense_mlp", "norm", "residual", "chunk_io", "cache_fill", "loop_io",
 })
 # flax module names that mark a layer no scope is opened for

@@ -443,6 +443,41 @@ def test_the_kexaone_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch
     assert len(re.findall(r"bf16\[(?:512,129|66048),128\]\{[^}]*\} scatter\(", text)) == 2 * 4 + 2 * 4
 
 
+# ------------------------------------------ LongCat-Flash: the shortcut-connected block at the cell's sizes
+
+
+def test_the_longcat_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch):
+    """``longcat-ep32-decode-b64`` as the benchmark builds it (5.17B bfloat16
+    parameters, 64 prompts of 1024 tokens, 512 new tokens, eight bfloat16
+    latent caches), compiled for a described v5e: under the 16.9 GB the
+    runtime offers with 2 GB to spare, the flash kernel at the latent
+    attention's head widths and the grouped expert kernels in it, and a step
+    that appends one row to each of the eight caches."""
+    import re
+
+    from benchmarks import run
+
+    gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
+    monkeypatch.setattr(gm, "_interpret_default", lambda: False)
+    cell = run.load_json("workloads", "longcat-ep32-decode-b64")
+    family = importlib.import_module("benchmarks.families.longcat_flash").Family(run.load_json("configs", cell["config"]))
+    p = cell["params"]
+    model = family.model()
+    shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), family.param_shapes(model))
+    ids = jax.ShapeDtypeStruct((p["batch_size"], p["prompt_len"]), jnp.int32, sharding=one_chip)
+    generate = family.generate_fn(model, p["num_latents"], p["new_tokens"], p["cache_dtype"])
+    with fa.default_flash(True), jax.default_matmul_precision("default"):
+        compiled = generate.lower(shapes, ids).compile()
+    m = compiled.memory_analysis()
+    assert 10.34e9 < m.argument_size_in_bytes < 10.36e9  # the weights and the prompts
+    total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    assert total < 14.9e9, f"{total / 1e9:.2f} GB"
+    text = compiled.as_text()
+    assert set(re.findall(r"flash_fwd_q\d+_kv\d+(?:_w\d+)?", text)) == {"flash_fwd_q1024_kv1024"}
+    assert "moe_experts_prefill_m1024_k6144_n2048" in text and "moe_experts_prefill_m1024_k2048_n6144" in text
+    assert len(re.findall(r"bf16\[64,1536,576\]\{[^}]*\} dynamic-update-slice\(", text)) >= 8
+
+
 # ------------------------------------------ the MLP's exact GELU: evaluated once a layer and kept
 
 
