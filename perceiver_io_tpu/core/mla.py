@@ -17,7 +17,11 @@ head. One set of weights, two ways to compute the same function:
     into the latent space, scores and values are read from the cached rows
     that all heads share, and ``W_uv`` is applied to the attended latent.
     The cache is read once a row for all heads, and nothing per head is
-    ever built for the cached tokens.
+    ever built for the cached tokens. Where the flash kernels run (a TPU)
+    the cache side of the step, the append of the new row and the attention
+    over the rows, is one kernel over the row-major cache, which it updates
+    in place (``ops/mla_absorb.py``); elsewhere ``LatentCache.append`` and
+    :func:`latent_decode_attention`, the same arithmetic in XLA.
 
 Rotary: YaRN frequencies on the ``qk_rope_head_dim`` channels of the queries
 and on ``k_rope``, adjacent channels paired (``core/position.py``); the
@@ -46,6 +50,7 @@ from perceiver_io_tpu.core.cache import LatentCache
 from perceiver_io_tpu.core.position import apply_rotary_interleaved, yarn_inv_freq, yarn_mscale
 from perceiver_io_tpu.ops.flash_attention import flash_attention, flash_enabled
 from perceiver_io_tpu.ops.layernorm import RMSNorm
+from perceiver_io_tpu.ops.mla_absorb import mla_absorb, mla_absorb_supported
 
 
 class MultiHeadLatentAttention(nn.Module):
@@ -168,13 +173,22 @@ class MultiHeadLatentAttention(nn.Module):
         heads, rank = c.num_attention_heads, c.kv_lora_rank
         with jax.named_scope("mla/absorb"):
             q_nope, q_rope = self._queries(x, pos)
-            with jax.named_scope("latent_cache_append"):
-                cache = cache.append(self._latent_rows(x, pos))
+            row = self._latent_rows(x, pos)
+            fused = flash_enabled() and mla_absorb_supported(cache.rows.shape, cache.rows.dtype, rank)
+            if not fused:  # XLA's append, where it always stood in the step (``tests/test_decoder_lm.py`` pins the order)
+                with jax.named_scope("latent_cache_append"):
+                    cache = cache.append(row)
             w_ukv = self._w_ukv()
             w_uk, w_uv = w_ukv[..., : c.qk_nope_head_dim], w_ukv[..., c.qk_nope_head_dim:]
             q_abs = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_uk)
             q_cat = jnp.concatenate([q_abs, q_rope[:, 0].astype(q_abs.dtype)], axis=-1)
-            o_lat = latent_decode_attention(q_cat, cache, self.sm_scale)[..., :rank]
+            if fused:  # append and attend in one kernel over the cache, which it updates in place
+                rows, o_lat = mla_absorb(
+                    q_cat, row, cache.rows, cache.length, sm_scale=self.sm_scale, keep=rank, out_dtype=self.dtype
+                )
+                cache = LatentCache(rows=rows, length=cache.length + 1)
+            else:
+                o_lat = latent_decode_attention(q_cat, cache, self.sm_scale)[..., :rank]
             o = jnp.einsum("bhc,chd->bhd", o_lat.astype(self.dtype), w_uv)
             return self._mm(o.reshape(b, 1, heads * c.v_head_dim), self.w_o), cache
 

@@ -497,6 +497,15 @@ def _prefill_cuts(b: int, n: int) -> Tuple[int, int]:
     return _chunks(b, _PREFILL_ATTENTION_TOKENS // n), _chunks(b * n, _PREFILL_FFN_TOKENS)
 
 
+def _batch_rows(stacked, b: int, n: int):
+    """A chunk loop's stacked latent rows (chunks, rows a chunk, N, width) as
+    the batch's (B, N, width). Under ``chunk_io``: the loop leaves them with
+    the positions on the lanes and the row-major cache takes them through a
+    relayout copy, which then has a layer (0.12 ms a cache a call, PERF.md 6, PR 40)."""
+    with jax.named_scope("chunk_io"):
+        return stacked.reshape(b, n, stacked.shape[-1])
+
+
 def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = False) -> Tuple[jnp.ndarray, tuple]:
     """The prompt pass: last-position logits (B, V) and, a layer, the cache
     rows of the prompt: (B, N, width) of a latent layer (of a shortcut-connected
@@ -522,12 +531,12 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
     for i in range(c.num_hidden_layers):
         if c.block == "shortcut":  # the whole layer over a chunk of whole rows: the branch's output lives a chunk long
             x, pair = _over_chunks(lambda xc, i=i: scoped("whole_layer", xc, pos, i), x.reshape(b // rows_a, rows_a, n, h))
-            cache_rows.extend(rows.reshape(b, n, rows.shape[-1]) for rows in pair)
+            cache_rows.extend(_batch_rows(rows, b, n) for rows in pair)
             x = x.reshape(b, n, h)
             continue
         x, rows = _over_chunks(lambda xc, i=i: scoped("attend_layer", xc, pos, i), x.reshape(b // rows_a, rows_a, n, h))
         if c.layer_types is None:
-            cache_rows.append(rows.reshape(b, n, rows.shape[-1]))
+            cache_rows.append(_batch_rows(rows, b, n))
         else:  # (chunks, rows a chunk, Hkv, positions, D): a key-value head is a row of the cache
             cache_rows.append(tuple(r.reshape(b * r.shape[2], *r.shape[3:]) for r in rows))
         x, _ = _over_chunks(lambda xc, i=i: (scoped("ffn_layer", xc, i), ()), x.reshape(b * n // tokens_f, tokens_f, h))

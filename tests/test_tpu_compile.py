@@ -371,6 +371,35 @@ def test_grouped_query_flash_forward_lowers(one_chip, mosaic, window):
     assert plan.block_q == 1024 and plan.tiles_run == (600 if window else 2112)
 
 
+_GENERATORS = {}
+
+
+def _cell_generator(workload: str, family: str, one_chip, monkeypatch):
+    """A decode cell's generator as the benchmark builds it (the family's
+    model and generator at the cell's sizes), compiled for a described v5e
+    with every Pallas kernel lowered for Mosaic; once a module run."""
+    from benchmarks import run
+
+    if workload not in _GENERATORS:
+        gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
+        monkeypatch.setattr(gm, "_interpret_default", lambda: False)
+        cell = run.load_json("workloads", workload)
+        fam = importlib.import_module(f"benchmarks.families.{family}").Family(run.load_json("configs", cell["config"]))
+        p = cell["params"]
+        model = fam.model()
+        shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), fam.param_shapes(model))
+        ids = jax.ShapeDtypeStruct((p["batch_size"], p["prompt_len"]), jnp.int32, sharding=one_chip)
+        generate = fam.generate_fn(model, p["num_latents"], p["new_tokens"], p["cache_dtype"])
+        with fa.default_flash(True), jax.default_matmul_precision("default"):
+            _GENERATORS[workload] = generate.lower(shapes, ids).compile()
+    return _GENERATORS[workload]
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+
+
 def test_the_mellum_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch):
     """``mellum2-pp4-decode-b32`` as the benchmark builds it (the family's
     model and generator at the cell's sizes: 3.795B bfloat16 parameters, 32
@@ -380,22 +409,10 @@ def test_the_mellum_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch)
     and the grouped expert kernels in it."""
     import re
 
-    from benchmarks import run
-
-    gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
-    monkeypatch.setattr(gm, "_interpret_default", lambda: False)
-    cell = run.load_json("workloads", "mellum2-pp4-decode-b32")
-    family = importlib.import_module("benchmarks.families.mellum").Family(run.load_json("configs", cell["config"]))
-    p = cell["params"]
-    model = family.model()
-    shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), family.param_shapes(model))
-    ids = jax.ShapeDtypeStruct((p["batch_size"], p["prompt_len"]), jnp.int32, sharding=one_chip)
-    generate = family.generate_fn(model, p["num_latents"], p["new_tokens"], p["cache_dtype"])
-    with fa.default_flash(True), jax.default_matmul_precision("default"):
-        compiled = generate.lower(shapes, ids).compile()
+    compiled = _cell_generator("mellum2-pp4-decode-b32", "mellum", one_chip, monkeypatch)
     m = compiled.memory_analysis()
     assert 7.58e9 < m.argument_size_in_bytes < 7.60e9  # the weights and the prompts
-    total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    total = _device_bytes(compiled)
     assert total < 14.9e9, f"{total / 1e9:.2f} GB"
     text = compiled.as_text()
     assert set(re.findall(r"flash_fwd_q\d+_kv\d+(?:_w\d+)?", text)) == {"flash_fwd_q8192_kv8192", "flash_fwd_q8192_kv8192_w1024"}
@@ -417,22 +434,10 @@ def test_the_kexaone_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch
     ``while`` whose body scatters two positions a row into every cache."""
     import re
 
-    from benchmarks import run
-
-    gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
-    monkeypatch.setattr(gm, "_interpret_default", lambda: False)
-    cell = run.load_json("workloads", "kexaone-ep8-mtp-decode-b64")
-    family = importlib.import_module("benchmarks.families.exaone_moe").Family(run.load_json("configs", cell["config"]))
-    p = cell["params"]
-    model = family.model()
-    shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), family.param_shapes(model))
-    ids = jax.ShapeDtypeStruct((p["batch_size"], p["prompt_len"]), jnp.int32, sharding=one_chip)
-    generate = family.generate_fn(model, p["num_latents"], p["new_tokens"], p["cache_dtype"])
-    with fa.default_flash(True), jax.default_matmul_precision("default"):
-        compiled = generate.lower(shapes, ids).compile()
+    compiled = _cell_generator("kexaone-ep8-mtp-decode-b64", "exaone_moe", one_chip, monkeypatch)
     m = compiled.memory_analysis()
     assert 9.08e9 < m.argument_size_in_bytes < 9.10e9  # the weights and the prompts
-    total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    total = _device_bytes(compiled)
     assert total < 14.9e9, f"{total / 1e9:.2f} GB"
     text = compiled.as_text()
     assert set(re.findall(r"flash_fwd_q\d+_kv\d+(?:_w\d+)?", text)) == {"flash_fwd_q1024_kv1024", "flash_fwd_q1024_kv1024_w128"}
@@ -451,31 +456,88 @@ def test_the_longcat_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch
     parameters, 64 prompts of 1024 tokens, 512 new tokens, eight bfloat16
     latent caches), compiled for a described v5e: under the 16.9 GB the
     runtime offers with 2 GB to spare, the flash kernel at the latent
-    attention's head widths and the grouped expert kernels in it, and a step
-    that appends one row to each of the eight caches."""
+    attention's head widths and the grouped expert kernels in it, and the
+    eight caches filled row-major by the prompt pass."""
     import re
 
-    from benchmarks import run
-
-    gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
-    monkeypatch.setattr(gm, "_interpret_default", lambda: False)
-    cell = run.load_json("workloads", "longcat-ep32-decode-b64")
-    family = importlib.import_module("benchmarks.families.longcat_flash").Family(run.load_json("configs", cell["config"]))
-    p = cell["params"]
-    model = family.model()
-    shapes = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), family.param_shapes(model))
-    ids = jax.ShapeDtypeStruct((p["batch_size"], p["prompt_len"]), jnp.int32, sharding=one_chip)
-    generate = family.generate_fn(model, p["num_latents"], p["new_tokens"], p["cache_dtype"])
-    with fa.default_flash(True), jax.default_matmul_precision("default"):
-        compiled = generate.lower(shapes, ids).compile()
+    compiled = _cell_generator("longcat-ep32-decode-b64", "longcat_flash", one_chip, monkeypatch)
     m = compiled.memory_analysis()
     assert 10.34e9 < m.argument_size_in_bytes < 10.36e9  # the weights and the prompts
-    total = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes - m.alias_size_in_bytes
+    total = _device_bytes(compiled)
     assert total < 14.9e9, f"{total / 1e9:.2f} GB"
     text = compiled.as_text()
     assert set(re.findall(r"flash_fwd_q\d+_kv\d+(?:_w\d+)?", text)) == {"flash_fwd_q1024_kv1024"}
     assert "moe_experts_prefill_m1024_k6144_n2048" in text and "moe_experts_prefill_m1024_k2048_n6144" in text
-    assert len(re.findall(r"bf16\[64,1536,576\]\{[^}]*\} dynamic-update-slice\(", text)) >= 8
+    # the prompt pass lays each cache's rows out row-major (a pad to the capacity); a step's append is the kernel's (below)
+    assert len(re.findall(r"bf16\[64,1536,576\]\{2,1,0[^}]*\} pad\(", text)) == 8
+
+
+# ------------------------------------------ the absorbed step's cache side: one kernel, a row-major cache updated in place
+
+
+def _loop_around(text: str, needle: str):
+    """The one ``while`` whose body (its fusions and calls included) holds
+    instructions named ``needle...``: the ``while`` and every instruction its body reaches."""
+    import re
+
+    from perceiver_io_tpu.analysis.graph import parse_hlo_computations
+
+    computations = parse_hlo_computations(text)
+
+    def reach(name, seen):
+        if name in seen or name not in computations:
+            return []
+        seen.add(name)
+        found = list(computations[name])
+        for ins in computations[name]:
+            for callee in re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)", ins.line):
+                found += reach(callee, seen)
+        return found
+
+    loops = []
+    for instructions in computations.values():
+        for ins in instructions:
+            if ins.opcode == "while":
+                inside = reach(re.search(r"body=%?([\w.\-]+)", ins.line).group(1), set())
+                if any(i.name.startswith(needle) for i in inside):
+                    loops.append((ins, inside))
+    assert len(loops) == 1, [ins.name for ins, _ in loops]
+    return loops[0]
+
+
+@pytest.mark.parametrize(
+    "workload,family,heads,capacity,sites",
+    [("longcat-ep32-decode-b64", "longcat_flash", 64, 1536, 8), ("dsv3-ep16-decode-b64", "deepseek_v3", 128, 1280, 5)],
+    ids=["longcat", "dsv3"],
+)
+def test_the_absorbed_step_is_one_kernel_over_a_row_major_cache(one_chip, mosaic, monkeypatch, workload, family, heads, capacity, sites):
+    """Both latent-attention generators compiled for a described v5e: every
+    absorbed attention of a decode step is one ``mla_absorb_*`` call under
+    ``decode/.../mla/absorb``; the loop carries each cache row-major and
+    nothing in its body appends to, copies or turns a cache, or writes the
+    float32 scores (PERF.md 6, PR 40: the 167 us lane-strided append and the
+    25 MB round trip a ``{1,2,0}`` carry cost an attention)."""
+    import re
+
+    compiled = _cell_generator(workload, family, one_chip, monkeypatch)
+    assert _device_bytes(compiled) < 14.9e9
+    text = compiled.as_text()
+    name = f"mla_absorb_h{heads}_s{capacity}_w576"
+    loop, body = _loop_around(text, name)
+    calls = [i for i in body if i.opcode == "custom-call" and i.name.startswith(name)]
+    assert len(calls) == sites and len(re.findall(rf"%{name}[.\d]* = ", text)) == sites  # none outside the loop
+    for call in calls:
+        assert re.search(r'op_name="[^"]*decode/[^"]*mla/absorb/[^"]*pallas_call"', call.line), call.line
+        assert "output_to_operand_aliasing={{0}: (3, {})}" in call.line  # the cache comes back in its own buffer
+    cache = rf"bf16\[64,{capacity},576\]"
+    result = lambda ins: ins.line.split(" = ", 1)[1].split(f" {ins.opcode}(", 1)[0]  # noqa: E731
+    for ins in body:
+        if re.search(cache, result(ins)):
+            assert ins.opcode not in ("dynamic-update-slice", "copy", "transpose", "copy-start", "copy-done"), ins.line[:300]
+            assert not re.search(cache + r"\{(?!2,1,0)", result(ins)), ins.line[:300]  # row-major wherever it appears
+        assert not re.search(rf"f32\[64,{heads},{capacity}\]", result(ins)), ins.line[:300]
+    # the carry itself: ``sites`` caches, each ``{2,1,0}``
+    assert len(re.findall(cache + r"\{2,1,0[:}]", result(loop))) == sites and not re.search(cache + r"\{(?!2,1,0)", result(loop))
 
 
 # ------------------------------------------ the MLP's exact GELU: evaluated once a layer and kept

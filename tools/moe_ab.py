@@ -39,8 +39,13 @@ heads; and the prompt pass's window-128 flash forward on a chunk of four
   and the prompt pass's flash forward (``flash_attention_gqa``) on one
   8192-token row, window and full, its edge tiles in bands or whole, blocks of
   1024 or 512;
-- absorbed decode attention (batch 64, 128 heads, 1280 x 576 cache): XLA's two
-  batched products against a Pallas kernel kept in this file (measured, not kept in the program).
+- the absorbed step's cache side (batch 64; 128 heads over a 1280 x 576 cache,
+  DeepSeek-V3's, and 64 heads over 1536 x 576, LongCat-Flash's), eight steps in
+  one program so that the cache is a loop's carry in the layout the loop gives
+  it: ``LatentCache.append`` then ``core/mla.py::latent_decode_attention``
+  (XLA's dynamic-update-slice, two batched products and float32 softmax)
+  against the program's kernel ``ops/mla_absorb.py::mla_absorb``, which does
+  both over a row-major cache it updates in place (PERF.md 6, PR 40).
 
 Times are device times from a profiler capture of ``--iters`` calls each
 (the summed duration of the device operations inside the call's annotation
@@ -80,58 +85,12 @@ def set_geometry(name: str) -> None:
         SHORT_PASSES = {8192: ((256, 512), (256, 768), (256, 1536), (256, 2048), (256, 4096), (256, 10240))}
 
 
-# ---------------------------------------------------------------------------
-# The Pallas decode attention that was measured and not kept (PERF.md 6, PR
-# 28): a grid step takes one row of the batch, holds that row's whole latent
-# cache in VMEM and reads it once for scores and values both. On the v5e the
-# kernel alone takes 0.173 ms against 0.196 ms for XLA's two batched products
-# (which run at the HBM peak), and XLA puts 0.35 ms of layout copies of the
-# cache in front of it. It lives here so that the measurement can be made
-# again; the program runs ``core/mla.py::latent_decode_attention``.
-# ---------------------------------------------------------------------------
-
 import functools  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.experimental import pallas as pl  # noqa: E402
 from jax.experimental.pallas import tpu as pltpu  # noqa: E402
-
-def _kernel(length_ref, q_ref, rows_ref, out_ref, *, sm_scale: float):
-    q, rows = q_ref[0], rows_ref[0]  # (H, W), (S, W)
-    s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * sm_scale
-    slot = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    s = jnp.where(slot < length_ref[0], s, -jnp.inf)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
-    out_ref[0] = jnp.dot(p.astype(rows.dtype), rows, preferred_element_type=jnp.float32).astype(out_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("sm_scale",))
-def mla_decode_attention(q_cat, rows, length, *, sm_scale: float):
-    """``softmax(q . row) @ row``: ``q_cat`` (B, H, W) against ``rows``
-    (B, capacity, W), slots at or past ``length`` (a scalar) masked. Returns
-    (B, H, W) float32, all ``W`` channels (the caller keeps the latent ones)."""
-    b, h, w = q_cat.shape
-    s = rows.shape[1]
-    return pl.pallas_call(
-        functools.partial(_kernel, sm_scale=sm_scale),
-        name=f"mla_decode_h{h}_s{s}_w{w}",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b,),
-            in_specs=[
-                pl.BlockSpec((1, h, w), lambda i, n: (i, 0, 0)),
-                pl.BlockSpec((1, s, w), lambda i, n: (i, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, h, w), lambda i, n: (i, 0, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, w), jnp.float32),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",), vmem_limit_bytes=64 * 1024 * 1024),
-        interpret=False,  # this tool runs on the chip or compiles for one
-    )(jnp.reshape(length, (1,)).astype(jnp.int32), q_cat.astype(rows.dtype), rows)
-
 
 # The Pallas decode attention measured for grouped queries and not kept
 # (PERF.md 6, PR 32): a grid step takes one key-value head of one row, holds
@@ -182,6 +141,7 @@ def variants():
     from perceiver_io_tpu.core.cache import LatentCache
     from perceiver_io_tpu.core.mla import latent_decode_attention
     from perceiver_io_tpu.ops.grouped_matmul import grouped_matmul
+    from perceiver_io_tpu.ops.mla_absorb import mla_absorb
 
     bf = jnp.bfloat16
 
@@ -261,14 +221,28 @@ def variants():
             out[f"gqa_decode/{kind}/pallas"] = (
                 lambda q, k, v, n=n: gqa_decode_attention(q, k, v, n, sm_scale=128 ** -0.5), (q, kv, kv), "gqa")
         return out
-    q = jax.ShapeDtypeStruct((64, 128, 576), bf)
-    cache = jax.ShapeDtypeStruct((64, 1280, 576), bf)
-    scale = 192 ** -0.5
-    out["mla_decode/xla"] = (
-        lambda q, r: latent_decode_attention(q, LatentCache(rows=r, length=jnp.asarray(1200, jnp.int32)), scale),
-        (q, cache), "mla")
-    out["mla_decode/pallas"] = (
-        lambda q, r: mla_decode_attention(q, r, jnp.asarray(1200, jnp.int32), sm_scale=scale), (q, cache), "mla")
+    scale, rank, steps = 192 ** -0.5, 512, 8
+
+    def absorbed(fused: bool):
+        def run(q, rows, new):
+            def body(_, carry):
+                cache, acc = carry
+                if fused:
+                    kept, o = mla_absorb(q, new, cache.rows, cache.length, sm_scale=scale, keep=rank, out_dtype=bf)
+                    cache = LatentCache(rows=kept, length=cache.length + 1)
+                else:
+                    cache = cache.append(new)
+                    o = latent_decode_attention(q, cache, scale)[..., :rank].astype(bf)
+                return cache, acc + o.astype(jnp.float32).sum()
+
+            start = LatentCache(rows=rows, length=jnp.asarray(1200, jnp.int32))
+            return jax.lax.fori_loop(0, steps, body, (start, jnp.zeros((), jnp.float32)))[1]
+        return run
+
+    for heads, capacity in ((128, 1280), (64, 1536)):
+        shapes = tuple(jax.ShapeDtypeStruct(s, bf) for s in ((64, heads, 576), (64, capacity, 576), (64, 1, 576)))
+        out[f"mla_absorb/h{heads}_s{capacity}/xla_x{steps}"] = (absorbed(False), shapes, "mla")
+        out[f"mla_absorb/h{heads}_s{capacity}/kernel_x{steps}"] = (absorbed(True), shapes, "mla")
     return out
 
 
