@@ -442,6 +442,31 @@ def init_latent_cache(batch_size: int, capacity: int, width: int, dtype=jnp.floa
 
 
 # ---------------------------------------------------------------------------
+# recurrent state (no discipline of slots: it does not grow)
+# ---------------------------------------------------------------------------
+
+
+@struct.dataclass
+class RecurrentState:
+    """What a state-space layer (``core/ssm.py``) keeps of a row's past, of
+    one size whatever the context: ``conv`` (B, d_conv - 1, d_inner), the last
+    inputs of the causal convolution, oldest first, and ``ssm`` (B, d_state,
+    d_inner) float32, the recurrence's state with the channels on the minor
+    axis. Unlike the caches around it in a generator's state it has no length
+    and no slots: a step reads and writes it whole."""
+
+    conv: jnp.ndarray
+    ssm: jnp.ndarray
+
+
+def init_recurrent_state(batch_size: int, d_conv: int, d_state: int, d_inner: int, dtype=jnp.float32) -> RecurrentState:
+    """The state before a row's first token: a window of zeros (the convolution
+    pads with zeros) and ``h_0 = 0``. ``dtype`` is the window's; the state is float32."""
+    return RecurrentState(conv=jnp.zeros((batch_size, d_conv - 1, d_inner), dtype),
+                          ssm=jnp.zeros((batch_size, d_state, d_inner), jnp.float32))
+
+
+# ---------------------------------------------------------------------------
 # window discipline
 # ---------------------------------------------------------------------------
 

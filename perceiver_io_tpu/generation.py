@@ -1815,6 +1815,10 @@ def make_instrumented_generate_fn(
     m_moe_gathered = registry.counter("moe_pairs_gathered_total") if moe_taps else None
     m_moe_dropped = registry.counter("moe_pairs_dropped_total") if moe_taps else None
     m_moe_load = registry.gauge("moe_expert_load_max") if moe_taps else None
+    # a state-space layer's recurrent state (``core/ssm.py`` taps ``ssm.state``): its largest element, its non-finite ones
+    ssm_taps = probes and "ssm.*" in decoder.tap_scopes
+    m_ssm_abs_max = registry.gauge("ssm_state_abs_max") if ssm_taps else None
+    m_ssm_nonfinite = registry.counter("ssm_state_nonfinite_total") if ssm_taps else None
     # a model that drafts for itself (a ``speculative`` decoder): a step yields 0 to 2 tokens a row, every
     # step is host-timed as one TPOT sample, and the ``spec.step`` taps keep the drafting's books
     self_drafting = getattr(decoder, "speculative", False)
@@ -1947,6 +1951,11 @@ def make_instrumented_generate_fn(
                         registry.counter("moe_pairs_zero_total").inc(zero)
                         registry.gauge("moe_real_experts_per_token").set(max(int(h["real_experts_per_token_max"]) for h in hh))
                         health_row["moe_zero_share"] = round(zero / max(routed, 1), 6)
+                if ssm_taps:
+                    health_row["ssm_state_abs_max"] = round(max(float(h["state_abs_max"]) for h in hh), 6)
+                    health_row["ssm_state_nonfinite"] = sum(int(h["state_nonfinite"]) for h in hh)
+                    m_ssm_abs_max.set(health_row["ssm_state_abs_max"])
+                    m_ssm_nonfinite.inc(health_row["ssm_state_nonfinite"])
                 if spec_taps:
                     drafts, accepted = (sum(int(h[k]) for h in hh) for k in ("drafts", "accepted"))
                     m_spec_drafts.inc(drafts)

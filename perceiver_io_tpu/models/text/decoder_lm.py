@@ -68,17 +68,20 @@ import jax.numpy as jnp
 from jax import lax
 
 from perceiver_io_tpu.core.cache import (
-    KVCache, LatentCache, RaggedKVCache, RaggedWindowKVCache, WindowKVCache, init_kv_cache, init_latent_cache,
-    init_ragged_kv_cache, init_ragged_window_kv_cache, init_window_kv_cache,
+    KVCache, LatentCache, RaggedKVCache, RaggedWindowKVCache, RecurrentState, WindowKVCache, init_kv_cache,
+    init_latent_cache, init_ragged_kv_cache, init_ragged_window_kv_cache, init_window_kv_cache,
 )
 from perceiver_io_tpu.core.gqa import GroupedQueryAttention
 from perceiver_io_tpu.core.mla import MultiHeadLatentAttention
 from perceiver_io_tpu.core.moe import MoELayer, SwiGLU, grouped_combine
+from perceiver_io_tpu.core.ssm import MambaMixer
 from perceiver_io_tpu.obs import probes
 from perceiver_io_tpu.ops.layernorm import RMSNorm
+from perceiver_io_tpu.ops.selective_scan import ssm_scan_plans
 
 
-_LAYER_TYPES = ("sliding_attention", "full_attention")
+_ATTENTION_TYPES = ("sliding_attention", "full_attention")
+_LAYER_TYPES = _ATTENTION_TYPES + ("mamba",)
 _BLOCKS = ("serial", "shortcut")
 
 
@@ -109,7 +112,14 @@ class DecoderLanguageModelConfig:
     ``qk_norm`` and ``full_attention_rotary`` are the grouped-query layers'
     (``core/gqa.py``). ``num_nextn_predict_layers`` 1 builds the
     multi-token-prediction module, a block of ``mtp_layer_types[0]``; the
-    generator then drafts with it (no option selects that)."""
+    generator then drafts with it (no option selects that).
+
+    A ``"mamba"`` entry of ``layer_types`` makes that layer's mixer a
+    state-space layer (``core/ssm.py``) of ``mamba_expand``, ``mamba_d_state``,
+    ``mamba_dt_rank`` and ``mamba_d_conv`` (the published keys of the Jamba
+    family); ``first_k_dense_replace`` at the depth gives every layer the dense
+    SwiGLU. ``tie_word_embeddings`` reads the logits off the embedding table
+    (no ``head``)."""
 
     vocab_size: int = 129280
     hidden_size: int = 7168
@@ -149,6 +159,11 @@ class DecoderLanguageModelConfig:
     zero_expert_num: int = 0
     mla_scale_q_lora: bool = False
     mla_scale_kv_lora: bool = False
+    mamba_expand: int = 2
+    mamba_d_state: int = 16
+    mamba_dt_rank: int = 160
+    mamba_d_conv: int = 4
+    tie_word_embeddings: bool = False
 
     def __post_init__(self):
         if self.block not in _BLOCKS:
@@ -160,15 +175,19 @@ class DecoderLanguageModelConfig:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             if len(self.layer_types) != self.num_hidden_layers or set(self.layer_types) - set(_LAYER_TYPES):
                 raise ValueError(f"layer_types: one of {_LAYER_TYPES} for each of the {self.num_hidden_layers} layers")
-            if not (self.num_key_value_heads and self.head_dim and self.sliding_window):
-                raise ValueError("layer_types needs num_key_value_heads, head_dim and sliding_window")
-            if self.num_attention_heads % self.num_key_value_heads:
+            attends = set(self.layer_types) & set(_ATTENTION_TYPES)
+            if attends and not (self.num_key_value_heads and self.head_dim):
+                raise ValueError("attention entries of layer_types need num_key_value_heads and head_dim")
+            if "sliding_attention" in attends and not self.sliding_window:
+                raise ValueError("a sliding_attention layer needs sliding_window")
+            if attends and self.num_attention_heads % self.num_key_value_heads:
                 raise ValueError("num_key_value_heads must divide num_attention_heads")
         if self.num_nextn_predict_layers:
             object.__setattr__(self, "mtp_layer_types", tuple(self.mtp_layer_types))
             if self.layer_types is None or self.num_nextn_predict_layers != 1 or len(self.mtp_layer_types) != 1 \
-                    or set(self.mtp_layer_types) - set(_LAYER_TYPES):
-                raise ValueError("a multi-token-prediction module: one block of a grouped-query layer type, under layer_types")
+                    or set(self.mtp_layer_types + self.layer_types) - set(_ATTENTION_TYPES) or self.tie_word_embeddings:
+                raise ValueError("a multi-token-prediction module: one block of a grouped-query layer type, under "
+                                 "layer_types of attention alone, with a head of its own")
         if self.n_held_experts is None:
             object.__setattr__(self, "n_held_experts", self.n_routed_experts)
         if self.held_experts_start + self.n_held_experts > self.n_routed_experts:
@@ -210,6 +229,8 @@ class DecoderBlock(nn.Module):
         self.attn_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
         if self.layer_type is None:
             self.attn = MultiHeadLatentAttention(c, **kw)
+        elif self.layer_type == "mamba":
+            self.mixer = MambaMixer(c, **kw)
         else:
             self.attn = GroupedQueryAttention(c, window=self.layer_type == "sliding_attention", **kw)
         self.ffn_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
@@ -221,7 +242,11 @@ class DecoderBlock(nn.Module):
     def attend(self, x, pos):
         """``x + Attn(RMSNorm(x))`` over whole rows, expanded; also the cache
         rows (latent attention: one array; grouped-query: rotated keys and
-        values, of which a window layer hands on its last ``sliding_window``)."""
+        values, of which a window layer hands on its last ``sliding_window``;
+        a state-space layer: the rows' :class:`RecurrentState`)."""
+        if self.layer_type == "mamba":
+            a, state = self.mixer.expand(self.attn_norm(x))
+            return _residual(x, a), state
         a, rows = self.attn.expand(self.attn_norm(x), pos)
         if self.layer_type == "sliding_attention":
             with jax.named_scope("chunk_io"):  # what a window layer hands on to its cache
@@ -235,6 +260,9 @@ class DecoderBlock(nn.Module):
             return x + self.ffn(self.ffn_norm(x))
 
     def step(self, x, cache, pos):
+        if self.layer_type == "mamba":  # the state has no positions
+            a, cache = self.mixer.step(self.attn_norm(x), cache)
+            return self.feed_forward(_residual(x, a)), cache
         one_token = self.attn.absorb if self.layer_type is None else self.attn.step
         a, cache = one_token(self.attn_norm(x), cache, pos)
         return self.feed_forward(_residual(x, a)), cache
@@ -380,9 +408,10 @@ class DecoderLanguageModel(nn.Module):
                 for i in range(c.num_hidden_layers)
             ]
         self.out_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
-        self.head = self.param(
-            "head", nn.initializers.normal(c.init_scale), (c.hidden_size, c.vocab_size), self.param_dtype
-        )
+        if not c.tie_word_embeddings:
+            self.head = self.param(
+                "head", nn.initializers.normal(c.init_scale), (c.hidden_size, c.vocab_size), self.param_dtype
+            )
         if c.num_nextn_predict_layers:
             self.mtp = MTPModule(c, **kw)
 
@@ -396,6 +425,9 @@ class DecoderLanguageModel(nn.Module):
 
     def logits(self, x):
         with jax.named_scope("logits"):
+            if self.config.tie_word_embeddings:  # ``h E^T``: the table is read as it lies, rows on the contraction's other side
+                return lax.dot_general(self.out_norm(x), self.embedding.astype(self.dtype),
+                                       (((x.ndim - 1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
             return jnp.dot(self.out_norm(x), self.head.astype(self.dtype), preferred_element_type=jnp.float32)
 
     def attend_layer(self, x, pos, i: int):
@@ -439,10 +471,12 @@ class DecoderLanguageModel(nn.Module):
         u, _ = self.mtp_attend(u, pos[:, :-1])
         return self.logits(x), self.mtp_logits(self.mtp_ffn(u))
 
-    def decode_step(self, token, caches: Tuple[Union[LatentCache, KVCache, WindowKVCache], ...]):
+    def decode_step(self, token, caches: Tuple[Union[LatentCache, KVCache, WindowKVCache, RecurrentState], ...]):
         """One new token a row against the caches: logits (B, V) and the advanced caches."""
         b = token.shape[0]
-        pos = jnp.broadcast_to(caches[0].length, (b, 1)).astype(jnp.int32)
+        # the position is the length of a cache that has one; a stack of state-space layers alone has no use for it
+        length = next((cache.length for cache in caches if not isinstance(cache, RecurrentState)), 0)
+        pos = jnp.broadcast_to(length, (b, 1)).astype(jnp.int32)
         x = self.embed(token)[:, None]
         new = []
         if self.config.block == "shortcut":  # a layer's two caches lie side by side
@@ -511,7 +545,10 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
     rows of the prompt: (B, N, width) of a latent layer (of a shortcut-connected
     layer two of them, side by side in the tuple); of a grouped-query
     layer the keys and the values, each (B * Hkv, N, D), a window layer's
-    last ``sliding_window`` positions only. The hidden state of the whole batch
+    last ``sliding_window`` positions only; of a state-space layer the rows'
+    :class:`RecurrentState` (such a layer runs over chunks of whole rows like an
+    attention: a row's time axis is the scan kernel's to chunk, and a padded
+    row would run its padding through the state). The hidden state of the whole batch
     stays in memory between layers (B * N * h); within a layer the attention
     runs over chunks of whole rows and the feed-forward over chunks of tokens
     (``_PREFILL_ATTENTION_TOKENS``, ``_PREFILL_FFN_TOKENS``), inside the one
@@ -537,6 +574,8 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
         x, rows = _over_chunks(lambda xc, i=i: scoped("attend_layer", xc, pos, i), x.reshape(b // rows_a, rows_a, n, h))
         if c.layer_types is None:
             cache_rows.append(_batch_rows(rows, b, n))
+        elif c.layer_types[i] == "mamba":  # (chunks, rows a chunk, ...): the rows' states, as they leave the scan
+            cache_rows.append(jax.tree.map(lambda r: r.reshape(b, *r.shape[2:]), rows))
         else:  # (chunks, rows a chunk, Hkv, positions, D): a key-value head is a row of the cache
             cache_rows.append(tuple(r.reshape(b * r.shape[2], *r.shape[3:]) for r in rows))
         x, _ = _over_chunks(lambda xc, i=i: (scoped("ffn_layer", xc, i), ()), x.reshape(b * n // tokens_f, tokens_f, h))
@@ -587,7 +626,6 @@ class _Decoder:
 
     window_names = ("cache",)
     const_names = ()
-    tap_scopes = ("moe.*", "spec.*")
     # a speculative step verifies a row's last emitted token and one draft after it; a ring
     # needs a slot of slack for each position that may be written and not kept
     spec_positions = 2
@@ -600,16 +638,26 @@ class _Decoder:
     def speculative(self) -> bool:
         return bool(self.model.config.num_nextn_predict_layers)
 
+    @property
+    def tap_scopes(self) -> Tuple[str, ...]:
+        """The probe sites this configuration's layers have: an expert layer's books, a state-space layer's state."""
+        c = self.model.config
+        sparse = c.block == "shortcut" or c.first_k_dense_replace < c.num_hidden_layers
+        return (("moe.*",) if sparse else ()) + ("spec.*",) + (("ssm.*",) if "mamba" in (c.layer_types or ()) else ())
+
     def _caches(self, rows, batch: int, n: int, max_new_tokens: int, cache_dtype):
         c = self.model.config
         if c.layer_types is None:
             return tuple(init_latent_cache(batch, n + max_new_tokens, r.shape[-1], cache_dtype).append(r) for r in rows)
-        slots, d = batch * c.num_key_value_heads, c.head_dim
-        return tuple(
-            init_window_kv_cache(slots, c.sliding_window, d, d, cache_dtype).fill(k, v, n)
-            if kind == "sliding_attention" else init_kv_cache(slots, n + max_new_tokens, d, d, cache_dtype).append(k, v)
-            for kind, (k, v) in zip(c.layer_types, rows)
-        )
+        def cache_of(kind, kept):
+            if kind == "mamba":  # the state as the scan left it (float32), the window in the caches' dtype
+                return RecurrentState(conv=kept.conv.astype(cache_dtype), ssm=kept.ssm)
+            slots, d = batch * c.num_key_value_heads, c.head_dim
+            if kind == "sliding_attention":
+                return init_window_kv_cache(slots, c.sliding_window, d, d, cache_dtype).fill(*kept, n)
+            return init_kv_cache(slots, n + max_new_tokens, d, d, cache_dtype).append(*kept)
+
+        return tuple(cache_of(kind, kept) for kind, kept in zip(c.layer_types, rows))
 
     def _refuse(self, pad_mask, n: int, max_new_tokens: int):
         c = self.model.config
@@ -688,9 +736,9 @@ class _Decoder:
         return (tuple(cache.keep(m) for cache in window[0]),)
 
     def health(self, logits, window):
-        # the occupancy gauge reads a cache that grows: a ring is full from its window on
-        rings = (WindowKVCache, RaggedWindowKVCache)
-        grows = next((cache for cache in window[0] if not isinstance(cache, rings)), window[0][0])
+        # the occupancy gauge reads a cache that grows: a ring is full from its window on, a recurrent state has one size
+        fixed = (WindowKVCache, RaggedWindowKVCache, RecurrentState)
+        grows = next((cache for cache in window[0] if not isinstance(cache, fixed)), window[0][0])
         return probes.decode_health(logits, grows, jnp.zeros((), jnp.int32))
 
     def compile_row(self, batch: int, prompt_len: int, max_new_tokens: int, cache_dtype) -> dict:
@@ -701,12 +749,23 @@ class _Decoder:
         router_width = c.n_routed_experts + c.zero_expert_num
         moe = {"moe_combine": grouped_combine(c.n_held_experts, router_width)}
         if c.layer_types is not None:
-            row_bytes = 2 * c.num_key_value_heads * c.head_dim * itemsize  # a token's keys and values in one layer
+            row_bytes = 2 * (c.num_key_value_heads or 0) * (c.head_dim or 0) * itemsize  # a token's keys and values in one layer
             # the module's block keeps a cache of its own kind beside the stack's
             kinds = c.layer_types + (c.mtp_layer_types if self.speculative else ())
             slack = self.ring_slack if self.speculative else 0
             n_window = kinds.count("sliding_attention")
-            n_full = len(kinds) - n_window
+            n_full = kinds.count("full_attention")
+            if "mamba" in kinds:  # a state of one size beside the caches that grow; no expert layer, no ring
+                d_inner, n_ssm = c.mamba_expand * c.hidden_size, kinds.count("mamba")
+                return {
+                    "ssm_layers": n_ssm,
+                    "ssm_state_bytes": batch * c.mamba_d_state * d_inner * 4 * n_ssm,
+                    "ssm_conv_bytes": batch * (c.mamba_d_conv - 1) * d_inner * itemsize * n_ssm,
+                    "ssm_state_dtype": "float32",
+                    "kv_cache_full_layers": n_full,
+                    "kv_cache_full_bytes": batch * (prompt_len + max_new_tokens) * row_bytes * n_full,
+                    "ssm_scan": ssm_scan_plans(),
+                }
             row = {
                 "kv_cache_full_layers": n_full,
                 "kv_cache_window_layers": n_window,

@@ -475,9 +475,10 @@ def test_the_longcat_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch
 # ------------------------------------------ the absorbed step's cache side: one kernel, a row-major cache updated in place
 
 
-def _loop_around(text: str, needle: str):
+def _loop_around(text: str, needle):
     """The one ``while`` whose body (its fusions and calls included) holds
-    instructions named ``needle...``: the ``while`` and every instruction its body reaches."""
+    instructions named ``needle...``, or for which ``needle(while, body)``
+    holds: the ``while`` and every instruction its body reaches."""
     import re
 
     from perceiver_io_tpu.analysis.graph import parse_hlo_computations
@@ -499,7 +500,7 @@ def _loop_around(text: str, needle: str):
         for ins in instructions:
             if ins.opcode == "while":
                 inside = reach(re.search(r"body=%?([\w.\-]+)", ins.line).group(1), set())
-                if any(i.name.startswith(needle) for i in inside):
+                if needle(ins, inside) if callable(needle) else any(i.name.startswith(needle) for i in inside):
                     loops.append((ins, inside))
     assert len(loops) == 1, [ins.name for ins, _ in loops]
     return loops[0]
@@ -538,6 +539,41 @@ def test_the_absorbed_step_is_one_kernel_over_a_row_major_cache(one_chip, mosaic
         assert not re.search(rf"f32\[64,{heads},{capacity}\]", result(ins)), ins.line[:300]
     # the carry itself: ``sites`` caches, each ``{2,1,0}``
     assert len(re.findall(cache + r"\{2,1,0[:}]", result(loop))) == sites and not re.search(cache + r"\{(?!2,1,0)", result(loop))
+
+
+# ------------------------------------------ the hybrid stack: a float32 recurrent state carried in place beside two caches
+
+
+def test_the_jamba_cells_generator_carries_its_state_in_place(one_chip, mosaic, monkeypatch):
+    """``jamba2-3b-decode-b256`` as the benchmark builds it (3.03B bfloat16
+    parameters whole, 256 prompts of 256 tokens, 384 new tokens), compiled for a
+    described v5e: under the 16.9 GB the runtime offers, the scan kernel in the
+    prompt pass (one geometry, 26 calls, none in the decode loop), and the
+    decode loop carrying the 26 states ``[256, 16, 5120]`` **float32 and
+    row-major**: nothing in its body turns, converts or slices into a state
+    (what the compiler's memory-space assignment does with one, a
+    ``copy-start`` / ``slice-start`` between HBM and VMEM at the same layout,
+    moves the bytes the update has to move anyway and is no relayout), and no
+    state of half the precision exists anywhere in the program."""
+    import re
+
+    compiled = _cell_generator("jamba2-3b-decode-b256", "jamba", one_chip, monkeypatch)
+    m = compiled.memory_analysis()
+    assert 6.05e9 < m.argument_size_in_bytes < 6.07e9  # the weights whole (the tied table once) and the prompts
+    total = _device_bytes(compiled)
+    assert total < 14.9e9, f"{total / 1e9:.2f} GB"
+    text = compiled.as_text()
+    assert set(re.findall(r"ssm_scan_l\d+_d\d+_n\d+", text)) == {"ssm_scan_l256_d5120_n16"}
+    state = r"f32\[256,16,5120\]"
+    result = lambda ins: ins.line.split(" = ", 1)[1].split(f" {ins.opcode}(", 1)[0]  # noqa: E731
+    loop, body = _loop_around(text, lambda loop, inside: re.search(state, result(loop)))  # the decode loop: the one that carries a state
+    assert len(re.findall(state + r"\{2,1,0[:}]", result(loop))) == 26 and not re.search(state + r"\{(?!2,1,0)", result(loop))
+    assert not any(i.opcode == "custom-call" and "ssm_scan" in i.name for i in body)  # the kernel is the prompt pass's
+    for ins in body:
+        if re.search(state, result(ins)):
+            assert ins.opcode not in ("copy", "transpose", "convert", "dynamic-update-slice"), ins.line[:300]
+            assert not re.search(state + r"\{(?!2,1,0)", result(ins)), ins.line[:300]
+    assert not re.search(r"bf16\[256,16,5120\]", text) and not re.search(r"f32\[65536,5120,16\]|f32\[256,256,5120,16\]", text)
 
 
 # ------------------------------------------ the MLP's exact GELU: evaluated once a layer and kept
