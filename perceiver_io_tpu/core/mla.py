@@ -10,7 +10,16 @@ head. One set of weights, two ways to compute the same function:
     per-head keys ``[k_nope; k_rope]`` and values are built from the latent
     and go through ordinary causal attention (the flash kernels on a TPU,
     ``d_qk`` = nope + rope, ``d_v`` = v). Right for many queries at once:
-    the up-projection is paid once a token.
+    the up-projection is paid once a token. At the published head widths
+    (128 + 64 rotary, 128 value channels; an even number of heads, rows in
+    whole blocks) the kernel is ``flash_attention_mla``, which reads the
+    up-projections' outputs token-major as they are written: a score is
+    ``q_nope . k_nope + q_rope . k_rope`` with the one ``k_rope`` a token
+    read by every head, the queries' rotary halves are turned by the
+    lane-rolling kernel of ``ops/rotary.py``, and nothing is concatenated,
+    written out a head at a time or turned heads-major (PERF.md 6, PR 42).
+    Other shapes, and the CPU, take the heads-major kernel or XLA on
+    concatenated operands: the same function to rounding.
 
 ``absorb`` (one new token against the cache)
     ``q_nope . (c_kv W_uk) = (q_nope W_uk^T) . c_kv``: the query is carried
@@ -48,9 +57,12 @@ import jax.numpy as jnp
 
 from perceiver_io_tpu.core.cache import LatentCache
 from perceiver_io_tpu.core.position import apply_rotary_interleaved, yarn_inv_freq, yarn_mscale
-from perceiver_io_tpu.ops.flash_attention import flash_attention, flash_enabled
+from perceiver_io_tpu.ops.flash_attention import (
+    flash_attention, flash_attention_mla, flash_enabled, mla_flash_supported,
+)
 from perceiver_io_tpu.ops.layernorm import RMSNorm
 from perceiver_io_tpu.ops.mla_absorb import mla_absorb, mla_absorb_supported
+from perceiver_io_tpu.ops.rotary import rotary_angles, rotate_packed
 
 
 class MultiHeadLatentAttention(nn.Module):
@@ -108,16 +120,36 @@ class MultiHeadLatentAttention(nn.Module):
         """A normed latent times ``sqrt(hidden / rank)`` where the configuration switches that on."""
         return latent * (self.config.hidden_size / rank) ** 0.5 if on else latent
 
+    def _c_q(self, x):
+        c = self.config
+        return self._scaled(self.q_norm(self._mm(x, self.w_dq)), c.q_lora_rank, c.mla_scale_q_lora)
+
     def _queries(self, x, pos) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """``x`` (B, N, h), ``pos`` (B, N) -> ``q_nope`` (B, N, H, nope) and
         the rotated ``q_rope`` (B, N, H, rope)."""
         c = self.config
         b, n, _ = x.shape
-        c_q = self._scaled(self.q_norm(self._mm(x, self.w_dq)), c.q_lora_rank, c.mla_scale_q_lora)
-        q = self._mm(c_q, self.w_uq)
+        q = self._mm(self._c_q(x), self.w_uq)
         q = q.reshape(b, n, c.num_attention_heads, c.qk_nope_head_dim + c.qk_rope_head_dim)
         q_nope, q_rope = q[..., : c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:]
         return q_nope, apply_rotary_interleaved(q_rope, pos[:, :, None], self._inv_freq())
+
+    def _w_uq_packed(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """``w_uq``'s columns, ``[nope | rope]`` a head, as the two sets ``(rank, H * nope)`` and ``(rank, H * rope)``:
+        from the ``views`` collection where the caller took them once in front of a loop (:func:`expand_views`)."""
+        if self.has_variable(VIEWS, "w_uq_nope"):
+            return self.get_variable(VIEWS, "w_uq_nope"), self.get_variable(VIEWS, "w_uq_rope")
+        return split_w_uq(self.w_uq.astype(self.dtype), self.config)
+
+    def _queries_packed(self, x, pos) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        """:meth:`_queries` token-major, ``q_nope`` (B, N, H * nope) and the
+        rotated ``q_rope`` (B, N, H * rope): a product a column set of
+        ``w_uq``, and the rotation by the lane-rolling kernel
+        (``ops/rotary.py``; the values are ``apply_rotary_interleaved``'s)."""
+        c_q = self._c_q(x)
+        w_nope, w_rope = self._w_uq_packed()
+        q_rope = rotate_interleaved_packed(jnp.dot(c_q, w_rope), pos, self._inv_freq(), self.config.num_attention_heads)
+        return jnp.dot(c_q, w_nope), q_rope
 
     def _latent_rows(self, x, pos) -> jnp.ndarray:
         """The cache's rows for ``x``: ``[RMSNorm(c_kv), scaled where switched on; rotated k_rope]`` (B, N, rank + rope)."""
@@ -143,6 +175,13 @@ class MultiHeadLatentAttention(nn.Module):
         b, n, _ = x.shape
         heads = c.num_attention_heads
         with jax.named_scope("mla/expand"):
+            if flash_enabled() and mla_flash_supported(n, heads, c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim):
+                # the kernel reads what the up-projections write: nothing heads-major, nothing a head wide in between
+                q_nope, q_rope = self._queries_packed(x, pos)
+                rows = self._latent_rows(x, pos)
+                kv = self._mm(rows[..., : c.kv_lora_rank], self.w_ukv)
+                o = flash_attention_mla(q_nope, q_rope, kv, rows[..., c.kv_lora_rank:], heads, sm_scale=self.sm_scale)
+                return self._mm(o, self.w_o), rows
             q_nope, q_rope = self._queries(x, pos)
             rows = self._latent_rows(x, pos)
             kv = jnp.einsum("bnc,chd->bnhd", rows[..., : c.kv_lora_rank], self._w_ukv())
@@ -191,6 +230,46 @@ class MultiHeadLatentAttention(nn.Module):
                 o_lat = latent_decode_attention(q_cat, cache, self.sm_scale)[..., :rank]
             o = jnp.einsum("bhc,chd->bhd", o_lat.astype(self.dtype), w_uv)
             return self._mm(o.reshape(b, 1, heads * c.v_head_dim), self.w_o), cache
+
+
+VIEWS = "views"  # the collection of :func:`expand_views`
+
+
+def split_w_uq(w_uq: jnp.ndarray, config) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``w_uq`` (rank, H * (nope + rope)), ``[nope | rope]`` a head -> (rank, H * nope) and (rank, H * rope)."""
+    c = config
+    rank, nope = c.q_lora_rank, c.qk_nope_head_dim
+    w = w_uq.reshape(rank, c.num_attention_heads, nope + c.qk_rope_head_dim)
+    return w[..., :nope].reshape(rank, -1), w[..., nope:].reshape(rank, -1)
+
+
+@jax.named_scope("rotary")
+def rotate_interleaved_packed(t: jnp.ndarray, pos: jnp.ndarray, inv_freq, heads: int) -> jnp.ndarray:
+    """``apply_rotary_interleaved`` on every head of token-major ``t`` (B, N,
+    H * R) at ``pos`` (B, N), by the lane-rolling kernel: a pair's partner is
+    one lane away, and no lane is shuffled in XLA. The values are
+    ``apply_rotary_interleaved``'s to the bit (float32 arithmetic, rounded
+    once to ``t``'s dtype)."""
+    angles = pos.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq, jnp.float32)
+    # a pair's two lanes turn by one angle
+    return rotate_packed(t, rotary_angles(jnp.repeat(angles, 2, axis=-1)), heads)
+
+
+def expand_views(params, config, dtype) -> dict:
+    """The ``views`` collection for a tree of ``params`` that holds latent
+    attentions: beside every ``w_uq`` its two column sets in ``dtype``, as
+    :meth:`MultiHeadLatentAttention.expand` multiplies by them where the
+    kernel runs. A prompt pass that loops over chunks takes them here, once a
+    call: inside the loop's body the compiler would cut them out of the
+    weight again every chunk (a slice is not hoisted for its own sake)."""
+    views = {}
+    for key, sub in params.items():
+        if hasattr(sub, "items") and (found := expand_views(sub, config, dtype)):
+            views[key] = found
+    if "w_uq" in params:
+        with jax.named_scope("mla/expand"):
+            views["w_uq_nope"], views["w_uq_rope"] = split_w_uq(params["w_uq"].astype(dtype), config)
+    return views
 
 
 def latent_decode_attention(q_cat: jnp.ndarray, cache: LatentCache, sm_scale: float) -> jnp.ndarray:

@@ -72,7 +72,7 @@ from perceiver_io_tpu.core.cache import (
     init_latent_cache, init_ragged_kv_cache, init_ragged_window_kv_cache, init_window_kv_cache,
 )
 from perceiver_io_tpu.core.gqa import GroupedQueryAttention
-from perceiver_io_tpu.core.mla import MultiHeadLatentAttention
+from perceiver_io_tpu.core.mla import VIEWS, MultiHeadLatentAttention, expand_views
 from perceiver_io_tpu.core.moe import MoELayer, SwiGLU, grouped_combine
 from perceiver_io_tpu.core.ssm import MambaMixer
 from perceiver_io_tpu.obs import probes
@@ -561,6 +561,9 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
     rows_a, tokens_f = _prefill_cuts(b, n)
     pos = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None], (rows_a, n))
 
+    if c.layer_types is None:  # latent attention: the weight views its expanded pass takes, once and not a chunk
+        with jax.named_scope("prefill"):
+            params = {**params, VIEWS: expand_views(params["params"], c, model.dtype)}
     scoped = functools.partial(_scoped, model, params)
 
     x = scoped("embed", input_ids)

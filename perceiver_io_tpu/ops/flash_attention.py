@@ -37,6 +37,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import functools
+import operator
 from typing import NamedTuple, Optional
 
 import jax
@@ -1681,10 +1682,13 @@ def _make_window_plan(n: int, block: int, window: int) -> TilePlan:
     return TilePlan(block, block, _BAND_ROWS if cut else 0, run, masked, (n_blocks * block) ** 2 // unit - run, "none")
 
 
-def _fwd_gqa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, sm_scale: float, window: int, tiles: tuple):
-    # q, o (1, block, d); k, v (1, block, d); scratch m/l (block, LANES) f32, acc (block, d) f32
+def _fwd_gqa_kernel(*refs, sm_scale: float, window: int, tiles: tuple, pairs: int = 1):
+    # ``pairs`` q then as many k (1, block, d_i), a score the sum of their products; v, o (1, block, d);
+    # scratch m/l (block, LANES) f32, acc (block, d) f32
+    q_refs, k_refs = refs[:pairs], refs[pairs: 2 * pairs]
+    v_ref, o_ref, m_scr, l_scr, acc_scr = refs[2 * pairs:]
     iq, s = pl.program_id(2), pl.program_id(3)
-    block = q_ref.shape[1]
+    block = o_ref.shape[1]
 
     @pl.when(s == 0)
     def _init():
@@ -1693,7 +1697,9 @@ def _fwd_gqa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, sm_sca
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     def _band(r0, r1, c0, c1, masked):
-        scores = _dot(q_ref[0, r0:r1, :], k_ref[0, c0:c1, :], ((1,), (1,))) * sm_scale
+        scores = functools.reduce(
+            operator.add, (_dot(q[0, r0:r1, :], k[0, c0:c1, :], ((1,), (1,))) for q, k in zip(q_refs, k_refs))
+        ) * sm_scale
         if masked:
             rows = lax.broadcasted_iota(jnp.int32, scores.shape, 0) + (r0 + s * block)
             cols = lax.broadcasted_iota(jnp.int32, scores.shape, 1) + c0
@@ -1803,3 +1809,117 @@ def flash_attention_gqa(
     kf = _pad_to(k.reshape(b * kv_heads, n, d), 1, block)
     vf = _pad_to(v.reshape(b * kv_heads, n, d), 1, block)
     return _flash_gqa(qf, kf, vf, num_heads, sm_scale, block, reach, geom)[:, :n]
+
+
+# ---------------------------------------------------------------------------
+# expanded latent attention, causal self-attention (forward only)
+# ---------------------------------------------------------------------------
+#
+# The prompt pass of multi-head latent attention (core/mla.py::expand), on the
+# operands as its up-projections write them, token-major: a head's query is a
+# 128-lane block of ``q_nope`` and half a block of ``q_rope``, its key and its
+# value the lane blocks ``2h`` and ``2h + 1`` of the ``[k_nope | v]`` product,
+# and the one rotary key a token is read by every head. A score is the window
+# kernel's with two products in place of one, ``q_nope . k_nope + q_rope .
+# k_rope`` into one float32 tile; nothing is sliced, concatenated, written out
+# a head at a time or turned heads-major between the products and the kernel.
+# The rotary halves are 64 lanes: a grid step takes the 128-lane block that
+# holds its head's ``q_rope`` beside a neighbour's, against the token's
+# ``k_rope`` with zeros on the neighbour's lanes (``[k | 0]`` for an even
+# head, ``[0 | k]`` for an odd one: exact, and a contraction of 64 costs the
+# MXU what one of 128 does).
+
+MLA_ROPE = LANES // 2
+
+
+def mla_flash_supported(n: int, num_heads: int, nope: int, rope: int, v_dim: int) -> bool:
+    """The widths are the kernel's lane blocks, heads come in pairs (the
+    rotary halves of two share a block) and the rows are whole blocks."""
+    widths = nope == LANES and v_dim == LANES and rope == MLA_ROPE
+    return widths and num_heads % 2 == 0 and n >= LANES and n % _choose_block(n, 1024) == 0
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_mla(q_nope, q_rope, kv, k_rope, num_heads, sm_scale, block, geom):
+    b, n, _ = q_nope.shape
+    n_blocks = n // block
+
+    def q_block(of):
+        return pl.BlockSpec((1, block, LANES), lambda b_, h, i, s: (b_, i, of(h)))
+
+    def kv_block(of):
+        return pl.BlockSpec((1, block, LANES), lambda b_, h, i, s: (b_, jnp.maximum(i - s, 0), of(h)))
+
+    return pl.pallas_call(
+        functools.partial(_fwd_gqa_kernel, sm_scale=sm_scale, window=n, tiles=_gqa_tiles(n_blocks, block, n), pairs=2),
+        name=_kernel_name("mla_fwd", geom),
+        grid=(b, num_heads, n_blocks, n_blocks),
+        in_specs=[
+            q_block(lambda h: h), q_block(lambda h: h // 2),
+            kv_block(lambda h: 2 * h), kv_block(lambda h: h % 2), kv_block(lambda h: 2 * h + 1),
+        ],
+        out_specs=q_block(lambda h: h),
+        out_shape=jax.ShapeDtypeStruct(q_nope.shape, q_nope.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block, LANES), jnp.float32),
+            pltpu.VMEM((block, LANES), jnp.float32),
+            pltpu.VMEM((block, LANES), jnp.float32),
+        ],
+        compiler_params=_compiler_params("parallel", "parallel", "parallel", "arbitrary"),
+        interpret=_interpret_default(),
+    )(q_nope, q_rope, kv, k_rope, kv)
+
+
+def _flash_mla_no_backward(*_):
+    raise NotImplementedError(
+        "flash_attention_mla is forward only (the prompt pass of a served decoder): no backward kernel is written"
+    )
+
+
+_flash_mla.defvjp(_flash_mla_no_backward, _flash_mla_no_backward)
+
+
+def _mla_geometry(n: int, num_heads: int) -> str:
+    return _geometry(n, n) + f"_h{num_heads}"
+
+
+def mla_kernel_name(n: int, num_heads: int) -> str:
+    """``flash_mla_fwd_q<N>_kv<N>_h<H>``: what a device trace prints for the call."""
+    return _kernel_name("mla_fwd", _mla_geometry(n, num_heads))
+
+
+@jax.named_scope("flash_attention_mla")
+def flash_attention_mla(
+    q_nope: jnp.ndarray,
+    q_rope: jnp.ndarray,
+    kv: jnp.ndarray,
+    k_rope: jnp.ndarray,
+    num_heads: int,
+    sm_scale: float = 1.0,
+    block: Optional[int] = None,
+) -> jnp.ndarray:
+    """Causal self-attention of expanded latent attention, token-major.
+
+    :param q_nope: (B, N, H*128), head h in lanes ``[128 h, 128 h + 128)``.
+    :param q_rope: (B, N, H*64), already rotated.
+    :param kv: (B, N, H*256), a head's ``k_nope`` then its ``v``: the up-projection's output as it is.
+    :param k_rope: (B, N, 64), the one rotated key a token that every head reads.
+    :param block: None = the tuned hint, a value = an upper bound.
+    :returns: (B, N, H*128) in ``q_nope``'s dtype. Forward only.
+    """
+    b, n, _ = q_nope.shape
+    shapes = (b, n, num_heads * LANES), (b, n, num_heads * MLA_ROPE), (b, n, 2 * num_heads * LANES), (b, n, MLA_ROPE)
+    if (q_nope.shape, q_rope.shape, kv.shape, k_rope.shape) != shapes or num_heads % 2:
+        raise ValueError(
+            f"flash_attention_mla: q_nope {q_nope.shape}, q_rope {q_rope.shape}, kv {kv.shape}, k_rope {k_rope.shape}, "
+            f"{num_heads} heads do not fit"
+        )
+    block = _choose_block(n, 1024 if block is None else block, exact=block is not None)
+    if n % block:
+        raise ValueError(f"flash_attention_mla: {n} rows are not whole blocks of {block}")
+    geom = _mla_geometry(n, num_heads)
+    _TILE_PLANS[(geom, True)] = _make_window_plan(n, block, n)
+    zeros = jnp.zeros_like(k_rope)
+    # lane block 0 for the even heads, block 1 for the odd ones
+    k_rope = jnp.concatenate([k_rope, zeros, zeros, k_rope], axis=-1).astype(kv.dtype)
+    return _flash_mla(q_nope, q_rope.astype(q_nope.dtype), kv, k_rope, num_heads, sm_scale, block, geom)

@@ -148,6 +148,37 @@ def test_prompt_pass_then_cached_decode_matches_the_references_full_forward(seed
     np.testing.assert_array_equal(want.argmax(-1)[clear], tokens[clear])
 
 
+@pytest.mark.parametrize("seed", [0, 1])
+def test_prompt_pass_through_the_token_major_kernel_then_cached_decode_matches_the_reference(seed):
+    """The published head widths (128 + 64 rotary, 128 value channels), where
+    the expanded pass runs ``flash_attention_mla`` on what its up-projections
+    write (here in interpret mode, ``default_flash(True)``), a prompt of two
+    attention chunks: every served position against the float32 reference,
+    and the caches the steps read hold the rows of the XLA path to the bit."""
+    import importlib
+
+    fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
+    new, n = 3, 128
+    config = tiny_config(num_attention_heads=2, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, num_hidden_layers=2,
+                         max_position_embeddings=256)
+    model, params, ids = seeded(config, seed, batch=2, n=n)
+    decoder = generation._decoder_of(model)
+    with fa.default_flash(True):
+        lowered = jax.jit(lambda p, i: decoder.prefill(p, i, None, 1, new, jnp.float32)).lower(params, ids).as_text(debug_info=True)
+        got, tokens = served_logits(model, params, ids, new)
+    assert f"flash_mla_fwd_q{n}_kv{n}_h2" in lowered and not re.search(r"flash_fwd_q\d", lowered)
+    full = np.concatenate([np.asarray(ids), tokens[:, :-1]], axis=1)
+    want = np.asarray(reference.logits(flat_dict(params), jnp.asarray(full), reference_cfg(config), last=new))
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+    with fa.default_flash(True):
+        _, with_kernel = decoder_lm.prefill(model, params, ids)
+    with fa.default_flash(False):
+        _, without = decoder_lm.prefill(model, params, ids)
+    for a, b in zip(with_kernel, without):
+        np.testing.assert_array_equal(np.asarray(a[:, 0]), np.asarray(b[:, 0]))  # position 0: no layer before it read the kernel's output
+    np.testing.assert_array_equal(np.asarray(with_kernel[0]), np.asarray(without[0]))  # the first layer's rows, every position
+
+
 def test_bfloat16_cache_serves_within_the_caches_rounding():
     config = tiny_config()
     model, params, ids = seeded(config, 3)

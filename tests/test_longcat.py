@@ -172,6 +172,29 @@ def served_logits(model, params, ids, new_tokens: int, cache_dtype=jnp.float32):
     return np.stack([np.asarray(o) for o in out], axis=1), np.stack([np.asarray(t) for t in tokens], axis=1)
 
 
+@pytest.mark.parametrize("scaled", [True, False], ids=["scaled_latents", "plain"])
+def test_prompt_pass_through_the_token_major_kernel_then_cached_decode_matches_the_reference(scaled):
+    """The published head widths, where both attentions of a layer run
+    ``flash_attention_mla`` on what their up-projections write (interpret
+    mode here), the latents' two scale factors on and off: every served
+    position against the float32 reference."""
+    import importlib
+
+    fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
+    new, n = 3, 128
+    config = tiny_config(num_attention_heads=2, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, num_hidden_layers=1,
+                         max_position_embeddings=256, mla_scale_q_lora=scaled, mla_scale_kv_lora=scaled)
+    model, params, ids = seeded(config, 5, batch=2, n=n)
+    decoder = generation._decoder_of(model)
+    with fa.default_flash(True):
+        lowered = jax.jit(lambda p, i: decoder.prefill(p, i, None, 1, new, jnp.float32)).lower(params, ids).as_text(debug_info=True)
+        got, tokens = served_logits(model, params, ids, new)
+    assert lowered.count(f"flash_mla_fwd_q{n}_kv{n}_h2") >= 2 and not re.search(r"flash_fwd_q\d", lowered)
+    full = np.concatenate([np.asarray(ids), tokens[:, :-1]], axis=1)
+    want = np.asarray(reference.logits(flat_dict(params), jnp.asarray(full), reference_cfg(config), last=new))
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
+
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_prompt_pass_then_cached_decode_matches_the_references_full_forward(seed):
     """Every served position: the logits of prefill + decoding through the

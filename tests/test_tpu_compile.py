@@ -274,6 +274,22 @@ def test_flash_attention_at_mla_head_widths(one_chip, mosaic):
     assert text.count("tpu_custom_call") >= 1 and "flash_fwd_q1024_kv1024" in text
 
 
+@pytest.mark.parametrize("heads", [DSV3_HEADS, 64], ids=["dsv3", "longcat"])
+def test_token_major_latent_flash_forward_lowers(one_chip, mosaic, heads):
+    """The expanded prompt pass on what its up-projections write: four
+    1024-token rows a chunk, a head's ``q_nope``, ``k_nope`` and ``v`` each a
+    128-lane block of a token-major operand, the rotary halves of two heads
+    one block, the diagonal tile in four bands."""
+    def sds(width):
+        return jax.ShapeDtypeStruct((4, 1024, width), jnp.bfloat16, sharding=one_chip)
+
+    text = _compile(lambda *operands: fa.flash_attention_mla(*operands, heads, sm_scale=0.1),
+                    sds(heads * 128), sds(heads * 64), sds(heads * 256), sds(64))
+    assert "tpu_custom_call" in text and f"flash_mla_fwd_q1024_kv1024_h{heads}" in text
+    plan = {row["geometry"]: row for row in fa.tile_plans()}[f"q1024_kv1024_h{heads}"]
+    assert (plan["block_q"], plan["band_rows"], plan["tiles_run"], plan["tiles_skipped"]) == (1024, 256, 40, 24)
+
+
 @pytest.mark.parametrize("rows", [1024, 256], ids=["prompt_chunk", "smallest_pass"])
 @pytest.mark.parametrize("k,n", [(DSV3_HIDDEN, DSV3_EXPERT_WIDTH), (DSV3_EXPERT_WIDTH, DSV3_HIDDEN)], ids=["up", "down"])
 def test_grouped_expert_product_lowers(one_chip, monkeypatch, rows, k, n):
@@ -374,16 +390,23 @@ def test_grouped_query_flash_forward_lowers(one_chip, mosaic, window):
 _GENERATORS = {}
 
 
-def _cell_generator(workload: str, family: str, one_chip, monkeypatch):
+def _cell_generator(workload: str, family: str, one_chip, monkeypatch, heads_major: bool = False):
     """A decode cell's generator as the benchmark builds it (the family's
     model and generator at the cell's sizes), compiled for a described v5e
-    with every Pallas kernel lowered for Mosaic; once a module run."""
+    with every Pallas kernel lowered for Mosaic; once a module run.
+    ``heads_major``: a latent-attention cell's prompt pass on the path that
+    shapes off the token-major kernel's take (the program of before PR 42)."""
     from benchmarks import run
 
+    if heads_major:
+        monkeypatch.setattr(importlib.import_module("perceiver_io_tpu.core.mla"), "mla_flash_supported", lambda *_: False)
+        workload, cell_name = workload + "/heads_major", workload
+    else:
+        cell_name = workload
     if workload not in _GENERATORS:
         gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
         monkeypatch.setattr(gm, "_interpret_default", lambda: False)
-        cell = run.load_json("workloads", workload)
+        cell = run.load_json("workloads", cell_name)
         fam = importlib.import_module(f"benchmarks.families.{family}").Family(run.load_json("configs", cell["config"]))
         p = cell["params"]
         model = fam.model()
@@ -466,7 +489,7 @@ def test_the_longcat_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch
     total = _device_bytes(compiled)
     assert total < 14.9e9, f"{total / 1e9:.2f} GB"
     text = compiled.as_text()
-    assert set(re.findall(r"flash_fwd_q\d+_kv\d+(?:_w\d+)?", text)) == {"flash_fwd_q1024_kv1024"}
+    assert set(re.findall(r"flash_\w*fwd_q\d+_kv\d+(?:_[wh]\d+)?", text)) == {"flash_mla_fwd_q1024_kv1024_h64"}
     assert "moe_experts_prefill_m1024_k6144_n2048" in text and "moe_experts_prefill_m1024_k2048_n6144" in text
     # the prompt pass lays each cache's rows out row-major (a pad to the capacity); a step's append is the kernel's (below)
     assert len(re.findall(r"bf16\[64,1536,576\]\{2,1,0[^}]*\} pad\(", text)) == 8
@@ -539,6 +562,77 @@ def test_the_absorbed_step_is_one_kernel_over_a_row_major_cache(one_chip, mosaic
         assert not re.search(rf"f32\[64,{heads},{capacity}\]", result(ins)), ins.line[:300]
     # the carry itself: ``sites`` caches, each ``{2,1,0}``
     assert len(re.findall(cache + r"\{2,1,0[:}]", result(loop))) == sites and not re.search(cache + r"\{(?!2,1,0)", result(loop))
+
+
+# ------------------------------------------ the expanded prompt pass: the kernel reads what the up-projections write
+
+_PREFETCHES = ("copy-start", "copy-done", "slice-start", "slice-done")  # memory-space assignment's moves between HBM and VMEM
+
+
+def _result(ins) -> str:
+    return ins.line.split(" = ", 1)[1].split(f" {ins.opcode}(", 1)[0]
+
+
+def _elements(result: str) -> int:
+    """The largest array a result type names, in elements."""
+    import math
+    import re
+
+    return max((math.prod(int(d) for d in dims.split(",") if d) for dims in re.findall(r"[a-z]+\d*\[([\d,]*)\]", result)), default=0)
+
+
+@pytest.mark.parametrize(
+    "workload,family,heads,sites",
+    [("longcat-ep32-decode-b64", "longcat_flash", 64, 8), ("dsv3-ep16-decode-b64", "deepseek_v3", 128, 5)],
+    ids=["longcat", "dsv3"],
+)
+def test_the_expanded_prompt_pass_hands_the_kernel_what_the_up_projections_write(one_chip, mosaic, monkeypatch, workload, family, heads, sites):
+    """Both latent-attention generators compiled for a described v5e: every
+    attention of a prompt chunk is one ``flash_mla_fwd_*`` call and one
+    rotation of the queries' rotary halves; in the chunk loops' bodies nothing
+    under ``mla/expand`` copies, turns, broadcasts, concatenates, gathers or
+    slices an array of more than 2^24 elements (PERF.md 6, PR 42: sixteen such
+    passes over 67 to 201 MB a chunk stood between the products and the
+    heads-major kernel); ``w_uq``'s column sets are cut in front of the loops,
+    once a call; and the decode loop's body computes what it computed with the
+    heads-major prompt pass in front of it (the same instructions in the same
+    order; which weights the compiler prefetches into VMEM is its own)."""
+    import re
+
+    from perceiver_io_tpu.analysis.graph import parse_hlo_computations
+
+    text = _cell_generator(workload, family, one_chip, monkeypatch).as_text()
+    name = f"flash_mla_fwd_q1024_kv1024_h{heads}"
+    computations = parse_hlo_computations(text)
+    bodies = [ins_list for ins_list in computations.values() if any(i.opcode == "custom-call" and i.name.startswith(name) for i in ins_list)]
+    assert sum(len([i for i in body if i.name.startswith(name)]) for body in bodies) == sites == len(re.findall(rf"%{name}[.\d]* = ", text))
+    assert not re.search(r"flash_fwd_q\d+", text)  # the heads-major kernel is in neither phase
+    moved = ("copy", "transpose", "broadcast", "concatenate", "gather", "slice", "pad", "reshape")
+    for body in bodies:
+        assert len([i for i in body if i.name.startswith(f"rotary_fwd_n1024_c{heads * 64}")]) == len([i for i in body if i.name.startswith(name)])
+        assert any(i.opcode == "dynamic-update-slice" or "dynamic_update_slice" in i.line for i in body)  # a chunk loop's body
+        for ins in body:
+            if "mla/expand" in ins.line and ins.opcode in moved:
+                assert _elements(_result(ins)) <= 2 ** 24, ins.line[:300]
+            called = re.search(r"calls=%?([\w.\-]+)", ins.line)
+            if "mla/expand" in ins.line and ins.opcode == "fusion" and "kind=kLoop" in ins.line and called:
+                # a loop fusion that only moves data is a copy by another name: it computes (a norm, a rotation) or is small
+                inside = {i.opcode for i in computations[called.group(1)]} - {"parameter", "bitcast", "tuple", "get-tuple-element", "constant"}
+                assert not inside <= set(moved) or _elements(_result(ins)) <= 2 ** 24, ins.line[:300]
+    # the weight's two column sets: cut in the entry computation (``sites`` weights, two sets each), in no loop's body
+    cuts = [i for ins_list in computations.values() for i in ins_list
+            if "split_w_uq" in i.line or re.search(rf"bf16\[1536,{heads},(?:128|64)\]", _result(i))]
+    entry = {i.name for i in computations[re.search(r"^ENTRY\s+%?([\w.\-]+)", text, re.M).group(1)]}
+    assert cuts and all(i.name in entry for i in cuts), [i.name for i in cuts if i.name not in entry]
+
+    def decode_body(program: str):
+        _, body = _loop_around(program, f"mla_absorb_h{heads}")
+        return [(i.opcode, re.sub(r"\{[^}]*\}", "", _result(i))) for i in body
+                if i.opcode not in _PREFETCHES + ("parameter", "tuple", "get-tuple-element", "bitcast", "custom-call")]
+
+    before = _cell_generator(workload, family, one_chip, monkeypatch, heads_major=True).as_text()
+    assert re.search(r"flash_fwd_q1024_kv1024", before) and name not in before
+    assert decode_body(text) == decode_body(before)
 
 
 # ------------------------------------------ the hybrid stack: a float32 recurrent state carried in place beside two caches

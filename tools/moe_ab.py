@@ -45,7 +45,16 @@ heads; and the prompt pass's window-128 flash forward on a chunk of four
   it: ``LatentCache.append`` then ``core/mla.py::latent_decode_attention``
   (XLA's dynamic-update-slice, two batched products and float32 softmax)
   against the program's kernel ``ops/mla_absorb.py::mla_absorb``, which does
-  both over a row-major cache it updates in place (PERF.md 6, PR 40).
+  both over a row-major cache it updates in place (PERF.md 6, PR 40);
+- the expanded latent attention of a prompt pass (``--only mla_expand``): one
+  layer's ``MultiHeadLatentAttention.expand`` as the body of the chunk loop
+  over the stacked batch (16 chunks of 4 rows x 1024 tokens), at DeepSeek-V3's
+  128 heads and LongCat-Flash's 64: the heads-major ``flash_attention`` on
+  concatenated operands against ``flash_attention_mla`` on what the
+  up-projections write, device ms a chunk with the kernels' share and XLA's
+  apart, and the token-major path with each band of the kernel cut at the
+  diagonal (``token_major_cut``, by patching the tiles when the call is
+  traced: how another cut of the tile is tried) (PERF.md 6, PR 42).
 
 Times are device times from a profiler capture of ``--iters`` calls each
 (the summed duration of the device operations inside the call's annotation
@@ -243,6 +252,80 @@ def variants():
         shapes = tuple(jax.ShapeDtypeStruct(s, bf) for s in ((64, heads, 576), (64, capacity, 576), (64, 1, 576)))
         out[f"mla_absorb/h{heads}_s{capacity}/xla_x{steps}"] = (absorbed(False), shapes, "mla")
         out[f"mla_absorb/h{heads}_s{capacity}/kernel_x{steps}"] = (absorbed(True), shapes, "mla")
+    out.update(mla_expand_variants())
+    return out
+
+
+EXPAND_CHUNKS, EXPAND_ROWS, EXPAND_TOKENS = 16, 4, 1024  # a prompt pass of 64 rows in attention chunks of four
+
+
+def mla_expand_variants():
+    """The expanded latent attention of one layer over a prompt pass's stacked batch, as the body of the chunk
+    loop that ``decoder_lm.prefill`` runs (an isolated call hands XLA the layouts its author wrote down; inside
+    the loop the chunk is a slice of the one buffer): the heads-major path (``flash_attention`` on
+    concatenated 192-channel operands) against the token-major one (``flash_attention_mla``, ``w_uq``'s column
+    sets taken in front of the loop), at both latent cells' configurations."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks import run
+    from perceiver_io_tpu.core import mla
+    from perceiver_io_tpu.models.text import decoder_lm
+
+    bf = jnp.bfloat16
+    out = {}
+    for workload, family in (("dsv3-ep16-decode-b64", "deepseek_v3"), ("longcat-ep32-decode-b64", "longcat_flash")):
+        config = run.load_json("configs", run.load_json("workloads", workload)["config"])
+        c = importlib.import_module(f"benchmarks.families.{family}").Family(config).model().config
+        attn = mla.MultiHeadLatentAttention(c, dtype=bf, param_dtype=bf)
+        pos = jnp.broadcast_to(jnp.arange(EXPAND_TOKENS, dtype=jnp.int32)[None], (EXPAND_ROWS, EXPAND_TOKENS))
+        shapes = jax.eval_shape(lambda attn=attn, pos=pos: attn.init(
+            jax.random.PRNGKey(0), jnp.zeros((EXPAND_ROWS, EXPAND_TOKENS, c.hidden_size), bf), pos, method="expand"))
+        leaves, tree = jax.tree.flatten(shapes)
+
+        def expand(token_major, attn=attn, pos=pos, tree=tree, c=c):
+            def run_(x, *weights):
+                params = jax.tree.unflatten(tree, weights)
+                supported = mla.mla_flash_supported  # read when the call is traced
+                mla.mla_flash_supported = supported if token_major else lambda *_: False
+                try:
+                    if token_major:
+                        params = {**params, mla.VIEWS: mla.expand_views(params["params"], c, bf)}
+                    return decoder_lm._over_chunks(lambda chunk: attn.apply(params, chunk, pos, method="expand"), x)
+                finally:
+                    mla.mla_flash_supported = supported
+            return run_
+
+        x = jax.ShapeDtypeStruct((EXPAND_CHUNKS, EXPAND_ROWS, EXPAND_TOKENS, c.hidden_size), bf)
+        for name, token_major in (("heads_major", False), ("token_major", True)):
+            out[f"mla_expand/h{c.num_attention_heads}/{name}_x{EXPAND_CHUNKS}"] = (expand(token_major), (x, *leaves), "mla_expand")
+
+        def cut_at_diagonal(run_):
+            """Each masked band of the diagonal tile in two, the slots before its first row unmasked (seven bands
+            where four): measured 50% slower than the kernel as it is and not adopted (PERF.md 6, PR 42)."""
+            fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
+
+            def tiles(n_blocks, block, window):
+                def cut(band):
+                    r0, r1, c0, c1, masked = band
+                    edge = min(c1, r0 // fa.LANES * fa.LANES)
+                    return [band] if not masked or edge <= c0 else [(r0, r1, c0, edge, False), (r0, r1, edge, c1, True)]
+                return tuple((lo, hi, tuple(b for band in bands for b in (cut(band) if lo == hi == 0 else [band])))
+                             for lo, hi, bands in plain(n_blocks, block, window))
+
+            plain = fa._gqa_tiles
+
+            def patched(*args):
+                fa._gqa_tiles = tiles
+                try:
+                    return run_(*args)
+                finally:
+                    fa._gqa_tiles = plain
+            return patched
+
+        out[f"mla_expand/h{c.num_attention_heads}/token_major_cut_x{EXPAND_CHUNKS}"] = (cut_at_diagonal(expand(True)), (x, *leaves), "mla_expand")
     return out
 
 
@@ -398,6 +481,15 @@ def main(argv=None) -> int:
                 top = trace.top(trace.totals_by_name(events), 8)
                 results[label] = {"device_ms": busy_ms, "top": [[n, 1e3 * s / args.iters] for n, s in top]}
                 print(f"{label}: {busy_ms:.4f} ms a call; {results[label]['top']}", flush=True)
+                if kind == "mla_expand":  # a chunk's time, the Pallas kernels' share and XLA's apart
+                    leaf = {n: ns for n, ns in trace.totals_by_name(events).items() if not n.startswith("while")}
+                    kernels = sum(ns for n, ns in leaf.items() if n.startswith(("flash_", "rotary_")))
+                    per = 1e6 * args.iters * EXPAND_CHUNKS
+                    chunk = dict(chunk_ms=busy_ms / EXPAND_CHUNKS, kernels_chunk_ms=kernels / per,
+                                 xla_chunk_ms=(sum(leaf.values()) - kernels) / per)
+                    results[label].update(chunk)
+                    print(f"{label}: a chunk {chunk['chunk_ms']:.4f} ms: kernels {chunk['kernels_chunk_ms']:.4f}, "
+                          f"XLA {chunk['xla_chunk_ms']:.4f}", flush=True)
             except Exception as e:  # noqa: BLE001 - one variant failing must not lose the others
                 results[label] = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
                 print(f"{label}: FAILED {results[label]['error']}", flush=True)
