@@ -543,8 +543,15 @@ def init_window_kv_cache(batch_size: int, window: int, num_qk_channels: int, num
 # a row's H heads. ``write`` puts ``n`` positions a row at ``length`` and on
 # and leaves ``length`` alone; ``keep`` advances it by the positions that
 # stay. What was written past the kept positions is dead: no query sees it
-# (``visible``) and the next step's write starts on top of it. These stand
-# beside the classes above, whose programs are the accepted cells'.
+# (``visible``) and the next step's write starts on top of it. Any capacity
+# and any slack of a ring from ``n - 1`` on is sound: a slot no position of the
+# row maps to yet, or one past what a query sees, is masked by where it lies.
+# The speculative generator builds both kinds in whole sublane tiles of the
+# cache's dtype (``decoder_lm._Decoder.ring_slack``, ``full_capacity``), which
+# is what ``ops/gqa_verify.py``, the step's kernel on the chip, writes back;
+# ``write`` and ``visible`` here are the path of every other cache and what the
+# kernel is held to. These stand beside the classes above, whose programs are
+# the accepted cells'.
 
 
 def _query_positions(length: jnp.ndarray, heads: int, n: int, group: int) -> jnp.ndarray:

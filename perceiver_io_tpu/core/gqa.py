@@ -38,9 +38,18 @@ One set of weights, two ways through them, as in ``core/mla.py``:
     ``n`` positions a row, each row at its own length, against the caches
     with a length a row (``core/cache.py::RaggedKVCache``,
     ``RaggedWindowKVCache``): the positions are written, not yet kept, and
-    each query sees what ``cache.visible`` says of its position. The same two
-    batched products, ``n`` times the group's queries against one read of the
-    cache.
+    each query sees what ``cache.visible`` says of its position. Where the
+    flash kernels run and ``ops/gqa_verify.py::gqa_verify_supported`` holds
+    (a head of whole lanes, a capacity of whole sublane tiles, the step's
+    positions within a tile and a ring's slack, a row's blocks within the
+    VMEM limit: shapes and dtypes alone) the write, the scores of the ``n *
+    group`` queries a key-value head, the mask, the softmax and the values
+    product are one Pallas call a layer over the caches the decode loop
+    carries row-major, each read once and updated in place by the tiles that
+    hold the new rows (``gqa_verify_<ring|full>_r.._q.._s.._d..``; PERF.md 6,
+    PR 44). Elsewhere ``cache.write`` (XLA's per-row scatters) and the same two
+    batched products as ``step``, ``n`` times the group's queries against one
+    read of the cache: what the tests hold the kernel to.
 
 Scores and the softmax are float32; products take ``dtype`` operands and
 accumulate in float32.
@@ -57,6 +66,7 @@ import jax.numpy as jnp
 from perceiver_io_tpu.core.cache import KVCache, RaggedKVCache, RaggedWindowKVCache, WindowKVCache
 from perceiver_io_tpu.core.position import apply_rotary_half, yarn_inv_freq
 from perceiver_io_tpu.ops.flash_attention import flash_attention_gqa, flash_enabled, gqa_flash_supported
+from perceiver_io_tpu.ops.gqa_verify import gqa_verify, gqa_verify_supported
 from perceiver_io_tpu.ops.layernorm import RMSNorm
 
 Cache = Union[KVCache, WindowKVCache]
@@ -173,13 +183,28 @@ class GroupedQueryAttention(nn.Module):
         group = heads // kv_heads
         with jax.named_scope(self.span):
             q, k, v = self._project(x, pos)
-            with jax.named_scope("kv_cache_write"):
-                cache = cache.write(k.transpose(0, 2, 1, 3).reshape(b * kv_heads, n, d),
-                                    v.transpose(0, 2, 1, 3).reshape(b * kv_heads, n, d))
+            window = cache.window if isinstance(cache, RaggedWindowKVCache) else None
+            fused = verify_fused(cache.k.shape, cache.k.dtype, kv_heads, n, group, window)
+            k, v = (r.transpose(0, 2, 1, 3).reshape(b * kv_heads, n, d) for r in (k, v))
+            if not fused:  # XLA's per-row scatters, where they always stood in the step
+                with jax.named_scope("kv_cache_write"):
+                    cache = cache.write(k, v)
             qg = q.reshape(b, n, kv_heads, group, d).transpose(0, 2, 1, 3, 4).reshape(b * kv_heads, n * group, d)
-            o = cached_verify_attention(qg, cache, cache.visible(n, group), d ** -0.5)
+            if fused:  # write and attend in one kernel over the caches, which it updates in place
+                k, v, o = gqa_verify(qg, k, v, cache.k, cache.v, cache.length, heads=kv_heads, window=window, sm_scale=d ** -0.5)
+                cache = cache.replace(k=k, v=v)
+            else:
+                o = cached_verify_attention(qg, cache, cache.visible(n, group), d ** -0.5)
             o = o.astype(self.dtype).reshape(b, kv_heads, n, group, d).transpose(0, 2, 1, 3, 4).reshape(b, n, heads * d)
             return self._mm(o, self.w_o), cache
+
+
+def verify_fused(cache_shape, dtype, kv_heads: int, n: int, group: int, window=None) -> bool:
+    """Whether :meth:`GroupedQueryAttention.verify` hands a cache of
+    ``cache_shape`` (B * Hkv, slots, D) and ``dtype`` to the kernel
+    (``ops/gqa_verify.py``): where the flash kernels run and the kernel's own
+    rule takes the shapes. The one rule: the decoder's ``compile`` row asks it too."""
+    return flash_enabled() and gqa_verify_supported(cache_shape, dtype, kv_heads, n, group, window)
 
 
 def cached_verify_attention(q: jnp.ndarray, cache: RaggedCache, visible: jnp.ndarray, sm_scale: float) -> jnp.ndarray:

@@ -71,12 +71,14 @@ from perceiver_io_tpu.core.cache import (
     KVCache, LatentCache, RaggedKVCache, RaggedWindowKVCache, RecurrentState, WindowKVCache, init_kv_cache,
     init_latent_cache, init_ragged_kv_cache, init_ragged_window_kv_cache, init_window_kv_cache,
 )
-from perceiver_io_tpu.core.gqa import GroupedQueryAttention
+from perceiver_io_tpu.core.gqa import GroupedQueryAttention, verify_fused
 from perceiver_io_tpu.core.mla import VIEWS, MultiHeadLatentAttention, expand_views
 from perceiver_io_tpu.core.moe import MoELayer, SwiGLU, grouped_combine
 from perceiver_io_tpu.core.ssm import MambaMixer
 from perceiver_io_tpu.obs import probes
+from perceiver_io_tpu.ops.gqa_verify import verify_plan
 from perceiver_io_tpu.ops.layernorm import RMSNorm
+from perceiver_io_tpu.ops.mla_absorb import row_tile
 from perceiver_io_tpu.ops.selective_scan import ssm_scan_plans
 
 
@@ -629,10 +631,8 @@ class _Decoder:
 
     window_names = ("cache",)
     const_names = ()
-    # a speculative step verifies a row's last emitted token and one draft after it; a ring
-    # needs a slot of slack for each position that may be written and not kept
+    # a speculative step verifies a row's last emitted token and one draft after it
     spec_positions = 2
-    ring_slack = spec_positions - 1
 
     def __init__(self, model: DecoderLanguageModel):
         self.model = model
@@ -689,13 +689,30 @@ class _Decoder:
 
     # ---------------------------------------------- the speculative decoder
 
+    def ring_slack(self, cache_dtype) -> int:
+        """The slots a speculative ring holds past its window: one for each
+        position that may be written and not kept, then up to whole sublane
+        tiles of the cache's dtype (``ops/gqa_verify.py`` writes tiles back; a
+        slot of slack more is masked by where it lies, like the others)."""
+        return self._whole_tiles(self.model.config.sliding_window + self.spec_positions - 1, cache_dtype) - self.model.config.sliding_window
+
+    def full_capacity(self, prompt_len: int, max_new_tokens: int, cache_dtype) -> int:
+        """The slots of a speculative growing cache: the last step of a row
+        writes its draft one slot past the last token the row is asked for;
+        then up to whole sublane tiles, a dead tail every query's mask hides."""
+        return self._whole_tiles(prompt_len + max_new_tokens + self.spec_positions - 1, cache_dtype)
+
+    @staticmethod
+    def _whole_tiles(slots: int, cache_dtype) -> int:
+        tile = row_tile(cache_dtype)
+        return -(-slots // tile) * tile
+
     def _ragged_cache(self, kind: str, k, v, batch: int, n: int, max_new_tokens: int, cache_dtype):
         c = self.model.config
         heads, d = c.num_key_value_heads, c.head_dim
         if kind == "sliding_attention":
-            return init_ragged_window_kv_cache(batch, heads, c.sliding_window, self.ring_slack, d, d, cache_dtype).fill(k, v, n)
-        # the last step of a row writes its draft one slot past the last token the row is asked for
-        return init_ragged_kv_cache(batch, heads, n + max_new_tokens + self.ring_slack, d, d, cache_dtype).fill(k, v)
+            return init_ragged_window_kv_cache(batch, heads, c.sliding_window, self.ring_slack(cache_dtype), d, d, cache_dtype).fill(k, v, n)
+        return init_ragged_kv_cache(batch, heads, self.full_capacity(n, max_new_tokens, cache_dtype), d, d, cache_dtype).fill(k, v)
 
     def spec_prefill(self, params, input_ids, pad_mask, max_new_tokens, cache_dtype, sample):
         """The prompt pass of the stack, the first token by ``sample(logits
@@ -755,7 +772,8 @@ class _Decoder:
             row_bytes = 2 * (c.num_key_value_heads or 0) * (c.head_dim or 0) * itemsize  # a token's keys and values in one layer
             # the module's block keeps a cache of its own kind beside the stack's
             kinds = c.layer_types + (c.mtp_layer_types if self.speculative else ())
-            slack = self.ring_slack if self.speculative else 0
+            slack = self.ring_slack(cache_dtype) if self.speculative else 0
+            full_slots = self.full_capacity(prompt_len, max_new_tokens, cache_dtype) if self.speculative else prompt_len + max_new_tokens
             n_window = kinds.count("sliding_attention")
             n_full = kinds.count("full_attention")
             if "mamba" in kinds:  # a state of one size beside the caches that grow; no expert layer, no ring
@@ -772,15 +790,24 @@ class _Decoder:
             row = {
                 "kv_cache_full_layers": n_full,
                 "kv_cache_window_layers": n_window,
-                "kv_cache_full_bytes": batch * (prompt_len + max_new_tokens + slack) * row_bytes * n_full,
+                "kv_cache_full_bytes": batch * full_slots * row_bytes * n_full,
                 "kv_cache_window_bytes": batch * (c.sliding_window + slack) * row_bytes * n_window,
                 "kv_cache_window_rows": c.sliding_window,
                 "kv_cache_lengths": "row" if self.speculative else "batch",
                 **moe,
             }
             if self.speculative:
-                row.update(mtp_layers=c.num_nextn_predict_layers, spec_positions_per_step=self.spec_positions,
-                           kv_cache_window_slack_rows=slack)
+                # a cache kind's step attention by ``core/gqa.py::verify``'s rule, and how the kernel cuts what it takes
+                heads, n = c.num_key_value_heads, self.spec_positions
+                group = c.num_attention_heads // heads
+                kinds = {"full": ((batch * heads, full_slots, c.head_dim), None),
+                         "window": ((batch * heads, c.sliding_window + slack, c.head_dim), c.sliding_window)}
+                fused = {kind: verify_fused(shape, cache_dtype, heads, n, group, window) for kind, (shape, window) in kinds.items()}
+                row.update(mtp_layers=c.num_nextn_predict_layers, spec_positions_per_step=n,
+                           kv_cache_window_slack_rows=slack,
+                           verify_attention={kind: "kernel" if fused[kind] else "xla" for kind in kinds},
+                           gqa_verify=[verify_plan(shape, cache_dtype, heads, n * group, window)._asdict()
+                                       for kind, (shape, window) in kinds.items() if fused[kind]])
             return row
         row_bytes = (c.kv_lora_rank + c.qk_rope_head_dim) * itemsize
         n_caches = c.num_hidden_layers

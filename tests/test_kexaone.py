@@ -324,6 +324,47 @@ def test_a_ring_without_slack_refuses_a_second_position():
         cache.fill(jnp.zeros((2, 3, 8)), jnp.zeros((2, 3, 8)), 9)
 
 
+@pytest.mark.parametrize("window,dtype,slack", [(4, jnp.float32, 4), (4, jnp.bfloat16, 12), (8, jnp.float32, 8), (128, jnp.bfloat16, 16),
+                                                 (128, jnp.float32, 8), (127, jnp.bfloat16, 1), (120, jnp.float32, 8)],
+                         ids=["w4_f32", "w4_bf16", "w8_f32", "the_cell", "w128_f32", "w127_bf16", "w120_f32"])
+def test_a_speculative_rings_slack_fills_whole_sublane_tiles(window, dtype, slack):
+    """At least the one slot a step's draft needs, then up to whole tiles of
+    the cache's dtype (``ops/gqa_verify.py`` writes tiles back): 144 slots
+    for the cell's window of 128 in bfloat16."""
+    from perceiver_io_tpu.ops.mla_absorb import row_tile
+
+    decoder = DecoderLanguageModel(tiny_config(sliding_window=window)).generation_decoder()
+    assert decoder.ring_slack(dtype) == slack and slack >= decoder.spec_positions - 1
+    assert (window + slack) % row_tile(dtype) == 0 and slack - row_tile(dtype) < decoder.spec_positions - 1
+
+
+@pytest.mark.parametrize("prompt,new,dtype,slots", [(1024, 512, jnp.bfloat16, 1552), (1024, 512, jnp.float32, 1544), (9, 6, jnp.float32, 16),
+                                                    (9, 7, jnp.float32, 24), (7, 4, jnp.bfloat16, 16)],
+                         ids=["the_cell", "the_cell_f32", "whole_as_it_is", "one_over", "tiny_bf16"])
+def test_a_speculative_growing_cache_is_whole_sublane_tiles(prompt, new, dtype, slots):
+    """The prompt, the new tokens and the slot of the last step's draft, rounded up: a dead tail that every query's mask hides."""
+    decoder = DecoderLanguageModel(tiny_config()).generation_decoder()
+    assert decoder.full_capacity(prompt, new, dtype) == slots >= prompt + new + 1
+
+
+@pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_the_generator_builds_the_caches_the_compile_row_names(cache_dtype):
+    config = tiny_config(vocab_size=12)
+    model, params, ids = seeded(config, 0, batch=2, n=9)
+    decoder = model.generation_decoder()
+    _, _, _, (caches,) = decoder.spec_prefill(params, ids, None, 6, cache_dtype, lambda logits: jnp.argmax(logits, axis=-1))
+    rings = [c for c in caches if hasattr(c, "window")]
+    full = [c for c in caches if not hasattr(c, "window")]
+    assert len(rings) == 4 and len(full) == 2
+    assert {c.capacity for c in rings} == {WINDOW + decoder.ring_slack(cache_dtype)} and {c.slack for c in rings} == {decoder.ring_slack(cache_dtype)}
+    assert {c.capacity for c in full} == {decoder.full_capacity(9, 6, cache_dtype)} and all(c.k.dtype == cache_dtype for c in caches)
+    row = decoder.compile_row(2, 9, 6, cache_dtype)
+    row_bytes = 2 * 2 * 16 * jnp.dtype(cache_dtype).itemsize
+    assert row["kv_cache_window_slack_rows"] == rings[0].slack
+    assert row["kv_cache_window_bytes"] == 2 * rings[0].capacity * row_bytes * 4 == sum(c.k.nbytes + c.v.nbytes for c in rings)
+    assert row["kv_cache_full_bytes"] == 2 * full[0].capacity * row_bytes * 2 == sum(c.k.nbytes + c.v.nbytes for c in full)
+
+
 # ---------------------------------------------------- spans, counters, the compile row
 
 
@@ -350,12 +391,15 @@ def test_scopes_taps_and_the_compile_row(tmp_path):
     rows = [json.loads(line) for line in open(tmp_path / "events.jsonl")]
     compiled = [r for r in rows if r.get("event") == "compile" and "kv_cache_lengths" in r][0]
     assert compiled["kv_cache_lengths"] == "row" and compiled["mtp_layers"] == 1 and compiled["spec_positions_per_step"] == 2
-    assert compiled["kv_cache_window_slack_rows"] == 1 and compiled["kv_cache_window_rows"] == WINDOW
-    # four rings of the stack; the stack's full layer and the module's, each one slot past the prompt and the new tokens
+    # a ring of whole float32 sublane tiles: the window of 4 and 4 slots of slack (one would do for the step's draft)
+    assert compiled["kv_cache_window_slack_rows"] == 4 and compiled["kv_cache_window_rows"] == WINDOW
+    # four rings of the stack; the stack's full layer and the module's, each one slot past the prompt and the new tokens (16: whole tiles as it is)
     assert compiled["kv_cache_window_layers"] == 4 and compiled["kv_cache_full_layers"] == 2
     row_bytes = 2 * 2 * 16 * 4
     assert compiled["kv_cache_full_bytes"] == 4 * (9 + 6 + 1) * row_bytes * 2
-    assert compiled["kv_cache_window_bytes"] == 4 * (WINDOW + 1) * row_bytes * 4 and compiled["moe_combine"] == "scatter"
+    assert compiled["kv_cache_window_bytes"] == 4 * (WINDOW + 4) * row_bytes * 4 and compiled["moe_combine"] == "scatter"
+    # off the chip (and at a head of 16 channels anywhere) the step's attention is XLA's products over ``_row_scatter``'s writes
+    assert compiled["verify_attention"] == {"full": "xla", "window": "xla"} and compiled["gqa_verify"] == []
     request = [r for r in rows if r.get("event") == "request"][-1]
     assert request["tokens_out"] == 6 and 0.0 <= request["spec_accept_rate"] <= 1.0 and request["spec_drafts"] >= 4
 
@@ -377,7 +421,7 @@ def test_the_probe_tool_runs_at_a_tiny_size(tmp_path, monkeypatch, capsys):
     out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     books = out["instrumented"]
     assert books["spec_drafts_total"] <= books["rows_x_steps_x_requests"] and books["spec_drafts_total"] >= 2 * 3 * 5
-    assert out["compile_row"]["kv_cache_lengths"] == "row"
+    assert out["compile_row"]["kv_cache_lengths"] == "row" and out["compile_row"]["verify_attention"] == {"full": "xla", "window": "xla"}
     found = out["against_reference"]
     assert found["positions"] >= 3 * 6 and found["main_abs_diff"] < TOL and found["draft_abs_diff"] < TOL
     assert found["main_gap"] == 0.0 and found["draft_gap"] == 0.0

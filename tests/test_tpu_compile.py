@@ -12,6 +12,8 @@ process that describes it holds libtpu until it exits, so a second test file
 (another xdist worker) or a topology call at import would fail.
 """
 
+import collections
+import hashlib
 import importlib
 
 import jax
@@ -388,6 +390,7 @@ def test_grouped_query_flash_forward_lowers(one_chip, mosaic, window):
 
 
 _GENERATORS = {}
+_LOWERED = {}  # sha256 of each generator's canonical lowered text (``_canonical``)
 
 
 def _cell_generator(workload: str, family: str, one_chip, monkeypatch, heads_major: bool = False):
@@ -414,7 +417,9 @@ def _cell_generator(workload: str, family: str, one_chip, monkeypatch, heads_maj
         ids = jax.ShapeDtypeStruct((p["batch_size"], p["prompt_len"]), jnp.int32, sharding=one_chip)
         generate = fam.generate_fn(model, p["num_latents"], p["new_tokens"], p["cache_dtype"])
         with fa.default_flash(True), jax.default_matmul_precision("default"):
-            _GENERATORS[workload] = generate.lower(shapes, ids).compile()
+            lowered = generate.lower(shapes, ids)
+            _LOWERED[workload] = hashlib.sha256(_canonical(lowered.as_text())[0].encode()).hexdigest()
+            _GENERATORS[workload] = lowered.compile()
     return _GENERATORS[workload]
 
 
@@ -453,8 +458,7 @@ def test_the_kexaone_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch
     bfloat16 parameters, 64 prompts of 1024 tokens, 512 new tokens, bfloat16
     caches with a length a row), compiled for a described v5e: under the
     16.9 GB the runtime offers with 2 GB to spare, the window-128 and the full
-    flash kernels and the grouped expert kernels in it, and the decode loop a
-    ``while`` whose body scatters two positions a row into every cache."""
+    flash kernels and the grouped expert kernels in it."""
     import re
 
     compiled = _cell_generator("kexaone-ep8-mtp-decode-b64", "exaone_moe", one_chip, monkeypatch)
@@ -465,10 +469,85 @@ def test_the_kexaone_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch
     text = compiled.as_text()
     assert set(re.findall(r"flash_fwd_q\d+_kv\d+(?:_w\d+)?", text)) == {"flash_fwd_q1024_kv1024", "flash_fwd_q1024_kv1024_w128"}
     assert "moe_experts_prefill_m1024_k6144_n2048" in text and "moe_experts_prefill_m1024_k2048_n6144" in text
-    # six caches, keys and values: twelve per-row writes a step (XLA flattens the rows and slots of some). The two
-    # that grow to 1537 slots are filled by the prompt pass in place; the four rings of 129 slots by a scatter too
-    assert len(re.findall(r"bf16\[(?:512,1537|786944),128\]\{[^}]*\} scatter\(", text)) == 2 * 2
-    assert len(re.findall(r"bf16\[(?:512,129|66048),128\]\{[^}]*\} scatter\(", text)) == 2 * 4 + 2 * 4
+
+
+@pytest.mark.parametrize("slots,window", [(1552, None), (144, 128)], ids=["growing", "ring"])
+def test_the_speculative_steps_attention_kernel_lowers(one_chip, mosaic, slots, window):
+    """``ops/gqa_verify.py`` at the cell's shapes (64 rows of 8 key-value
+    heads, two positions of 8 queries, bfloat16 caches of whole tiles), alone:
+    one Mosaic call whose two caches alias their operands."""
+    from perceiver_io_tpu.ops.gqa_verify import gqa_verify, gqa_verify_kernel_name, gqa_verify_supported
+
+    assert gqa_verify_supported((512, slots, 128), jnp.bfloat16, 8, 2, 8, window)
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)  # noqa: E731
+    lengths = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+    text = _compile(lambda q, kn, vn, k, v, n: gqa_verify(q, kn, vn, k, v, n, heads=8, window=window, sm_scale=128 ** -0.5),
+                    shape(512, 16, 128), shape(512, 2, 128), shape(512, 2, 128), shape(512, slots, 128), shape(512, slots, 128), lengths)
+    assert gqa_verify_kernel_name(window is not None, 512, 16, slots, 128) in text and "tpu_custom_call" in text
+    assert "output_to_operand_aliasing={{0}: (4, {}), {1}: (5, {})}" in text
+
+
+def test_the_speculative_step_is_one_kernel_a_layer_over_row_major_caches(one_chip, mosaic, monkeypatch):
+    """The same generator: each of a speculative step's six attentions (the
+    stack's four rings and its full layer under ``spec/verify``, the module's
+    full layer under ``mtp/block``) is one ``gqa_verify_*`` call that takes the
+    two carried cache arrays as they are and hands them back in their own
+    buffers; the decode ``while`` carries the twelve arrays row-major, in
+    whole bfloat16 sublane tiles (1552 slots where the prompt and the new
+    tokens need 1537, rings of 144 where the window needs 129), and nothing
+    in its body copies, turns, scatters into or slices into a cache or holds
+    float32 scores of a growing layer (PERF.md 6, PR 44: the parent's carry
+    was slot-major ``{2,0,1}``, each write a scatter behind a relayout copy,
+    a layer three passes). The rings' fill by the prompt pass stays XLA's
+    scatter, once a call."""
+    import re
+
+    text = _cell_generator("kexaone-ep8-mtp-decode-b64", "exaone_moe", one_chip, monkeypatch).as_text()
+    ring, full = "gqa_verify_ring_r512_q16_s144_d128", "gqa_verify_full_r512_q16_s1552_d128"
+    loop, body = _loop_around(text, ring)
+    calls = [i for i in body if i.opcode == "custom-call" and i.name.startswith("gqa_verify_")]
+    assert sorted(re.sub(r"\.\d+$", "", i.name) for i in calls) == [full] * 2 + [ring] * 4
+    assert len(re.findall(r"%gqa_verify_\w+[.\d]* = ", text)) == 6  # none outside the loop
+    scopes = collections.Counter()
+    for call in calls:
+        assert "output_to_operand_aliasing={{0}: (4, {}), {1}: (5, {})}" in call.line  # the caches come back in their own buffers
+        scope = re.search(r'op_name="[^"]*while/body/decode/(?:[^"/]*/)?(spec/verify|mtp/block)/[^"]*attn/(window|full)/jit\(gqa_verify\)', call.line)
+        assert scope, call.line[:600]
+        scopes[scope.groups()] += 1
+    assert scopes == {("spec/verify", "window"): 4, ("spec/verify", "full"): 1, ("mtp/block", "full"): 1}
+    cache = r"bf16\[512,(?:1552|144),128\]"
+    result = lambda ins: ins.line.split(" = ", 1)[1].split(f" {ins.opcode}(", 1)[0]  # noqa: E731
+    for ins in body:
+        assert "kv_cache_write" not in ins.line, ins.line[:300]  # XLA's per-row scatters are gone with their scope
+        assert not re.search(r"f32\[512,16,15\d\d\]", result(ins)), ins.line[:300]
+        if re.search(cache, result(ins)):
+            assert ins.opcode not in ("scatter", "dynamic-update-slice", "copy", "transpose", "copy-start", "copy-done"), ins.line[:300]
+            assert not re.search(cache + r"\{(?!2,1,0)", result(ins)), ins.line[:300]  # row-major wherever it appears
+    carried = result(loop)
+    assert len(re.findall(r"bf16\[512,1552,128\]\{2,1,0[:}]", carried)) == 2 * 2 and len(re.findall(r"bf16\[512,144,128\]\{2,1,0[:}]", carried)) == 2 * 4
+    assert not re.search(cache + r"\{(?!2,1,0)", carried) and not re.search(r"bf16\[512,(?:1537|129),128\]", text)
+
+
+# sha256 of the canonical lowered text (``_canonical``) of the four decode
+# cells whose generators run none of the code PR 44 changed, taken on its
+# parent commit (d708ae6) by the same lowering: ``core/gqa.py::step``, the
+# ``KVCache`` / ``WindowKVCache`` / ``LatentCache`` paths and the prompt passes
+# lower to the parent's programs (``tools/step_hlo.py --same`` says the same of
+# the compiled modules). A PR that means to change one of these programs
+# updates its hash.
+PARENT_GENERATORS = {
+    "mellum2-pp4-decode-b32": ("mellum", "08eadbcfdc5bc1c8749a61bdbc7da024abab0e97773459e0829fdfd8910eb692"),
+    "jamba2-3b-decode-b256": ("jamba", "d9553c32c5a52f3c44fc7c7f797aa3c4e4af11b2db6e73694f44fa39df3d3bf9"),
+    "dsv3-ep16-decode-b64": ("deepseek_v3", "edac860ab3e61a0d6a6e7a1308f0ab55668b3895726f88303d7687bb98ed8ffb"),
+    "longcat-ep32-decode-b64": ("longcat_flash", "9e78ee1b21106e65e0891d3d5ddc75aed1508da468a0eb9c439d10ebe413f936"),
+}
+
+
+@pytest.mark.parametrize("workload", sorted(PARENT_GENERATORS))
+def test_a_generator_without_a_speculative_step_lowers_to_the_parents_program(one_chip, mosaic, monkeypatch, workload):
+    family, golden = PARENT_GENERATORS[workload]
+    _cell_generator(workload, family, one_chip, monkeypatch)
+    assert _LOWERED[workload] == golden
 
 
 # ------------------------------------------ LongCat-Flash: the shortcut-connected block at the cell's sizes

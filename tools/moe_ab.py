@@ -12,11 +12,17 @@ growing cache of 8448 slots and over a ring of 1024, batch 32 x 4 key-value
 heads, 8 query heads each); ``--geom kexaone`` is K-EXAONE's share of PR 34
 (16 held experts of 128, hidden 6144, width 2048: a speculative step's 128
 positions dense against grouped, rows a pass at a prompt chunk's 8192 tokens
-with one local pair a token; the speculative step's attention, eight steps in
-one program so that the caches are written in place: the per-row write of two
-positions, XLA's scatter, and the two batched products with a mask a query,
-over a growing cache of 1537 slots and a ring of 129, batch 64 x 8 key-value
-heads; and the prompt pass's window-128 flash forward on a chunk of four
+with one local pair a token; the speculative step's attention (``--only
+gqa_verify``), eight steps in one program with the caches the loop's carry,
+batch 64 x 8 key-value heads at a length a row: the per-row write of two
+positions, XLA's scatter, alone and with the two batched products and a mask
+a query (``write_xla``, ``write_and_attend_xla``), the same over a carry
+pinned row-major (``..._xla_row_major``), and the program's kernel
+``ops/gqa_verify.py::gqa_verify`` (``..._kernel``, where its rule takes the
+capacity), over a growing cache and a ring at the parent's capacities (1537
+and 129 slots, which XLA carries slot-major) and the program's (1552 and 144,
+whole bfloat16 tiles); every variant prints the layouts its loop carries
+(PERF.md 6, PR 44); and the prompt pass's window-128 flash forward on a chunk of four
 1024-token rows, in bands or whole, blocks of 1024 down to 128).
 
 - the grouped product alone (8192 live rows of 16384, 16 experts, even and
@@ -337,22 +343,32 @@ def kexaone_attention_variants():
     import jax.numpy as jnp
     from jax import lax
 
+    from jax.experimental.layout import Layout, with_layout_constraint
+
     from perceiver_io_tpu.core.cache import RaggedKVCache, RaggedWindowKVCache
     from perceiver_io_tpu.core.gqa import cached_verify_attention
+    from perceiver_io_tpu.ops.gqa_verify import gqa_verify, gqa_verify_supported
 
     fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
     bf = jnp.bfloat16
     batch, kv_heads, group, d, steps = 64, 8, 8, 128, 8
     rows = batch * kv_heads
+    row_major = Layout(major_to_minor=(0, 1, 2))
     out = {}
 
-    def step_loop(make, what):
+    def step_loop(make, what, path="xla"):
         def run(q, k, v, k_new, v_new):
             cache = make(k, v)
+            window = getattr(cache, "window", None)
 
             def body(_, carry):
                 cache, acc = carry
+                if path == "kernel":  # the program's: write and attend in one call over the caches it updates in place
+                    k, v, o = gqa_verify(q, k_new, v_new, cache.k, cache.v, cache.length, heads=kv_heads, window=window, sm_scale=d ** -0.5)
+                    return cache.replace(k=k, v=v).keep(jnp.ones((batch,), jnp.int32)), acc + o.sum()
                 cache = cache.write(k_new, v_new)
+                if path == "xla_row_major":  # XLA's products over the carry the kernel asks for
+                    cache = cache.replace(k=with_layout_constraint(cache.k, row_major), v=with_layout_constraint(cache.v, row_major))
                 if what == "write":  # the write alone: read one row back so that it is not dead
                     return cache.keep(jnp.ones((batch,), jnp.int32)), acc + cache.k[:, :1].astype(jnp.float32).sum()
                 o = cached_verify_attention(q, cache, cache.visible(2, group), d ** -0.5)
@@ -363,14 +379,19 @@ def kexaone_attention_variants():
 
     q = jax.ShapeDtypeStruct((rows, 2 * group, d), bf)
     new = jax.ShapeDtypeStruct((rows, 2, d), bf)
-    length = jnp.full((batch,), 1280, jnp.int32)
-    for kind, slots, make in (
-        ("full", 1537, lambda k, v: RaggedKVCache(k=k, v=v, length=length)),
-        ("window", 129, lambda k, v: RaggedWindowKVCache(k=k, v=v, length=length, window=128)),
+    length = 1280 + jnp.arange(batch, dtype=jnp.int32) % 16  # a length a row: one row in sixteen writes two tiles
+    # the parent's capacities (PR 34: no whole tiles, XLA's path alone) and the program's (whole bfloat16 tiles)
+    for kind, make in (
+        ("full", lambda k, v: RaggedKVCache(k=k, v=v, length=length)),
+        ("window", lambda k, v: RaggedWindowKVCache(k=k, v=v, length=length, window=128)),
     ):
-        kv = jax.ShapeDtypeStruct((rows, slots, d), bf)
-        for what in ("write", "write_and_attend"):
-            out[f"gqa_verify/{kind}/{what}_x{steps}"] = (step_loop(make, what), (q, kv, kv, new, new), "gqa")
+        for slots in ((1537, 1552) if kind == "full" else (129, 144)):
+            kv = jax.ShapeDtypeStruct((rows, slots, d), bf)
+            for what, path in (("write", "xla"), ("write_and_attend", "xla"), ("write_and_attend", "xla_row_major"),
+                               ("write_and_attend", "kernel")):
+                if path == "kernel" and not gqa_verify_supported(kv.shape, bf, kv_heads, 2, group, 128 if kind == "window" else None):
+                    continue
+                out[f"gqa_verify/{kind}/s{slots}/{what}_{path}_x{steps}"] = (step_loop(make, what, path), (q, kv, kv, new, new), "gqa_verify")
 
     def flash(bands, block):
         def run(q, k, v):
@@ -387,6 +408,14 @@ def kexaone_attention_variants():
         for block in (1024, 512, 256, 128):
             out[f"gqa_prefill/window/{'bands' if bands else 'whole'}_block{block}"] = (flash(bands, block), (fq, fkv, fkv), "gqa")
     return out
+
+
+def carried_caches(compiled_text: str) -> list:
+    """The layouts of the (rows, slots, D) arrays a compiled variant's loop carries: ``{2,1,0}`` is row-major, ``{2,0,1}`` slot-major."""
+    import re
+
+    carries = re.findall(r"= \(([^()]*(?:\([^()]*\)[^()]*)*)\) while\(", compiled_text)
+    return sorted({m for carry in carries for m in re.findall(r"bf16\[512,\d+,128\]\{[\d,]+", carry)})
 
 
 def group_sizes(skew: bool):
@@ -437,8 +466,8 @@ def main(argv=None) -> int:
             if wanted(name):
                 shapes = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one) for s in shapes]
                 try:
-                    jax.jit(fn).lower(*shapes).compile()
-                    print(f"{name}: compiles", flush=True)
+                    compiled = jax.jit(fn).lower(*shapes).compile()
+                    print(f"{name}: compiles" + (f"; carries {carried_caches(compiled.as_text())}" if "gqa_verify" in name else ""), flush=True)
                 except Exception as e:  # noqa: BLE001 - report every variant
                     print(f"{name}: REFUSED {type(e).__name__}: {str(e)[:400]}", flush=True)
         return 0
@@ -467,6 +496,8 @@ def main(argv=None) -> int:
             label = name + ("/skewed" if skew else "")
             try:
                 run = jax.jit(fn)
+                if kind == "gqa_verify":
+                    print(f"{label}: carries {carried_caches(run.lower(*operands).compile().as_text())}", flush=True)
                 jax.block_until_ready(run(*operands))
                 trace_dir = tempfile.mkdtemp(prefix="moe-ab-")
                 jax.profiler.start_trace(trace_dir)

@@ -80,7 +80,7 @@ def main(argv=None) -> int:
         "tpot_p50_ms": None if stats.tpot_p50_s is None else 1e3 * stats.tpot_p50_s, "ttft_s": stats.ttft_s,
     }
     rows = [json.loads(line) for line in open(os.path.join(log_dir, "events.jsonl"))]
-    out["compile_row"] = next(({k: v for k, v in r.items() if k.startswith(("kv_cache", "mtp", "spec", "moe_combine"))}
+    out["compile_row"] = next(({k: v for k, v in r.items() if k.startswith(("kv_cache", "mtp", "spec", "moe_combine", "verify_attention", "gqa_verify"))}
                                for r in rows if r.get("event") == "compile" and "kv_cache_lengths" in r), None)
     print(json.dumps(out), flush=True)
     del fn
