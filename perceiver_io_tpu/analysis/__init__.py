@@ -13,8 +13,8 @@ lowered/compiled HLO of any jitted function against declared intent:
     assert report.ok()
 
 Entry points: :func:`check` (pytest/programmatic), ``tools/graphlint.py``
-(CLI over the flagship functions), the trainer's ``graphlint`` event
-(obs/events.py) and bench.py's ``telemetry.graphlint`` block. On top of
+(CLI over the flagship functions) and the trainer's ``graphlint`` event
+(obs/events.py). On top of
 the scope/shape rules, :mod:`dataflow` adds a def-use/provenance engine
 (value threading through pjit/scan/cond/shard_map/custom_vjp bodies) and
 the four dataflow rules — ``rng-key-reuse``, ``dead-compute``,

@@ -175,8 +175,6 @@ def check(
             return bool(policy.donate_argnums) or policy.expect_donation or _fn_donates(fn)
         if rule_name == "collective-budget":
             return policy.collective_budget is not None
-        if rule_name == "collective-overlap":
-            return policy.expect_overlap
         if rule_name == "peak-memory-budget":
             return policy.peak_memory_budget_bytes is not None
         if rule_name == "replicated-large-tensor":

@@ -2,10 +2,10 @@
 
 PR 3's graphlint answers "is this graph acceptable *now*"; nothing stopped a
 later PR from silently regressing what an earlier one certified — the hot
-scopes' concat inventory, the overlap step's collective budget, peak memory.
+scopes' concat inventory, the sharded step's collectives, peak memory.
 This module makes those guarantees *contracts*: a fingerprint is extracted
-from each flagship program (train flat, train data x fsdp, train overlap,
-prefill, decode), committed under ``contracts/``, and every
+from each flagship program (train flat, train data x fsdp, prefill,
+decode), committed under ``contracts/``, and every
 ``tools/graphcheck.py`` run re-extracts the live graphs and semantically
 diffs them against the committed snapshots — classifying each change as
 regression / improvement / neutral instead of failing on any byte drift.
@@ -518,14 +518,13 @@ def flagship_fingerprints(
     mesh_spec: str = DEFAULT_MESH_SPEC,
     features: Optional[Sequence[str]] = None,
 ) -> Dict[str, GraphFingerprint]:
-    """Fingerprint the flagship programs — the SAME functions bench.py
-    measures and graphlint lints (:mod:`perceiver_io_tpu.analysis.flagship`
-    builds them). ``features`` follows :func:`~perceiver_io_tpu.analysis.
+    """Fingerprint the flagship programs — the SAME functions graphlint
+    lints (:mod:`perceiver_io_tpu.analysis.flagship` builds them).
+    ``features`` follows :func:`~perceiver_io_tpu.analysis.
     flagship.lint_flagship` semantics: an explicit set also forces the flash
-    routes on; ``None`` keeps the ambient/default kernels. The sharded pair
-    (``train_sharded`` GSPMD, ``train_overlap`` explicit shard_map) needs
-    the ``mesh_spec`` submesh worth of devices — tools/graphcheck.py
-    provisions virtual CPU devices when the host is short."""
+    routes on; ``None`` keeps the ambient/default kernels. The sharded step
+    (``train_sharded``, GSPMD) needs the ``mesh_spec`` submesh worth of
+    devices — tools/graphcheck.py provisions virtual CPU devices when the host is short."""
     from perceiver_io_tpu.analysis.flagship import build_programs, features_context
 
     with features_context(features):
@@ -584,33 +583,3 @@ def check_contracts(
         if rank[entry["status"]] > rank[status]:
             status = entry["status"]
     return {"status": status, "programs": results, "fingerprints": fps}
-
-
-def graphcheck_telemetry(
-    contracts_dir: Optional[str] = None,
-    programs: Sequence[str] = ("train_flat", "decode"),
-) -> dict:
-    """The ``telemetry.graphcheck`` block for bench.py results: diff the two
-    cheapest flagship programs against the committed contracts and record
-    the verdict. Like ``graphlint_telemetry``, a contract regression (or a
-    missing contracts/ dir) is a recorded status, while an exception inside
-    the check propagates: a gate that cannot run must not read as a pass."""
-    if contracts_dir is None:
-        contracts_dir = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
-            "contracts",
-        )
-    from perceiver_io_tpu.analysis import ledger as L
-
-    led = L.load_ledger(contracts_dir)
-    features = None
-    if led is not None and not L.validate_ledger(led):
-        features = L.default_on_features(led) or None
-    result = check_contracts(contracts_dir, programs=programs, features=features)
-    return {
-        "status": result["status"],
-        "programs": {
-            p: {k: v for k, v in entry.items() if k in ("status", "detail")}
-            for p, entry in result["programs"].items()
-        },
-    }

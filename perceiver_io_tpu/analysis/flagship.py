@@ -1,13 +1,11 @@
 """Graphlint targets for the flagship workload: the 16k Perceiver AR CLM
-train step, prefill, and decode functions (the programs BASELINE.json and
-bench.py measure).
+train step, prefill, and decode functions.
 
-``tools/graphlint.py`` (CLI), bench.py's ``telemetry.graphlint`` block and
-``tests/test_analysis.py``'s real-graph smoke all build the SAME functions
-through :func:`build_targets`, so the lint gate and the measured program
-can't drift apart; :func:`build_programs` extends that to the five
-graphcheck programs (adding the GSPMD and overlap-scheduled sharded train
-steps), shared by ``analysis/fingerprint.py``'s contracts and the dataflow
+``tools/graphlint.py`` (CLI) and ``tests/test_analysis.py``'s real-graph
+smoke build the SAME functions through :func:`build_targets`, so the lint
+gate and the linted program can't drift apart; :func:`build_programs`
+extends that to the graphcheck programs (adding the GSPMD sharded train
+step), shared by ``analysis/fingerprint.py``'s contracts and the dataflow
 rule gate (``tools/graphlint.py --programs all``, ``tasks.py perf``). The
 per-target policies arm the dataflow rules — rng-key-reuse and
 dead-compute everywhere, sharding-flow on the sharded steps, the decode ↔
@@ -16,9 +14,9 @@ prefill cross-program companion. Geometries:
 - ``micro`` — the flagship architecture at toy sizes (same op structure,
   same scopes, seconds to compile on CPU). Graph-shape rules are geometry-
   invariant, so this is the default gate everywhere.
-- ``flagship`` — the real 16384/1024 single-chip geometry (bench.py
-  ``flagship_config`` numbers); trace is fine anywhere, compiling it is a
-  TPU-sized job.
+- ``flagship`` — the real 16384/1024 single-chip geometry
+  (``chip_smoke.flagship_config``'s numbers); trace is fine anywhere,
+  compiling it is a TPU-sized job.
 """
 
 from __future__ import annotations
@@ -71,7 +69,7 @@ GEOMETRIES = {
     # eligible when a feature-set lint forces flash on
     "micro": dict(seq_len=512, latents=128, channels=64, heads=4, layers=2,
                   batch=2, decode_tokens=8),
-    # bench.py flagship_config numbers (single v5e chip, 37M params)
+    # chip_smoke.flagship_config's numbers (single v5e chip, 37M params)
     "flagship": dict(seq_len=16384, latents=1024, channels=512, heads=8,
                      layers=8, batch=4, decode_tokens=8),
 }
@@ -107,7 +105,6 @@ def build_targets(
     dtype=None,
     collective_budget: Optional[Dict[str, int]] = None,
     mesh=None,
-    overlap: bool = True,
     microbatch: Optional[int] = None,
     probes=None,
 ) -> Dict[str, LintTarget]:
@@ -115,12 +112,8 @@ def build_targets(
 
     ``mesh``: a data/fsdp ``jax.sharding.Mesh`` shards the TRAIN target
     (state via ``shard_train_state``, batch via ``shard_batch``; the batch
-    is padded up to the submesh). ``overlap=True`` (default) builds the
-    explicit ``parallel/overlap.py`` step with ``expect_overlap`` set and a
-    collective budget derived from :func:`~perceiver_io_tpu.parallel.overlap.
-    expected_collectives`; ``overlap=False`` lints the GSPMD step instead
-    (no overlap claim — XLA owns the schedule). ``microbatch`` defaults to
-    2 on the sharded step (the chunk-interleaving claim needs >= 2 chunks).
+    is padded up to the submesh): the GSPMD step, XLA owns the schedule.
+    ``microbatch`` defaults to 2 on the sharded step.
 
     ``probes``: an ``obs.probes.ProbeConfig`` compiles the Probeline
     numerics telemetry into the (unsharded) TRAIN target — the
@@ -129,7 +122,7 @@ def build_targets(
 
     Trace-time kernel features (``fast_kernels``) must be active around BOTH
     this call and the subsequent ``check`` — callers own the feature
-    context, exactly as tools/step_ab.py does for its variants."""
+    context."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -181,8 +174,7 @@ def build_targets(
         if probes is not None and mesh is not None:
             # loud, not dropped: a caller asking to fingerprint/lint a probed
             # SHARDED step would otherwise get a verdict about the unprobed
-            # graph (the overlap step rejects probes in make_train_step; the
-            # GSPMD sharded contract program simply isn't built probed yet)
+            # graph (the sharded contract program isn't built probed)
             raise ValueError(
                 "probes= is only supported for the unsharded train target "
                 "(the train_probed contract program); drop mesh= or probes="
@@ -199,46 +191,17 @@ def build_targets(
             )
         else:
             from perceiver_io_tpu.parallel.mesh import shard_batch
-            from perceiver_io_tpu.parallel.overlap import (
-                DEFAULT_BUCKET_BYTES,
-                OverlapConfig,
-                expected_collectives,
-            )
             from perceiver_io_tpu.training.loop import shard_train_state
 
-            # min_weight_size=0 so the micro model actually fsdp-shards;
-            # small buckets at micro geometry so multiple gather/scatter
-            # buckets (the interleaving structure) exist to lint
-            bucket_bytes = DEFAULT_BUCKET_BYTES if geometry == "flagship" else 128 << 10
+            # min_weight_size=0 so the micro model actually fsdp-shards
             k = 2 if microbatch is None else microbatch
             state = shard_train_state(state, mesh, min_weight_size=0)
             batch = shard_batch(batch, mesh)
-            if overlap:
-                step = make_train_step(
-                    loss_fn,
-                    microbatch=k,
-                    overlap=OverlapConfig(
-                        mesh=mesh, bucket_bytes=bucket_bytes, min_weight_size=0
-                    ),
-                )
-            else:
-                step = make_train_step(loss_fn, microbatch=k)
-            budget = collective_budget
-            if budget is None and overlap:
-                budget = expected_collectives(
-                    state.params, mesh, microbatch=k,
-                    bucket_bytes=bucket_bytes, min_weight_size=0,
-                )
-                # the GSPMD optimizer update outside the shard_map region
-                # adds per-leaf global-norm partial all-reduces: budget one
-                # per parameter leaf plus headroom for the metrics tree
-                n_leaves = len(jax.tree_util.tree_leaves(state.params))
-                budget["all-reduce"] += n_leaves + 16
+            step = make_train_step(loss_fn, microbatch=k)
             policy = LintPolicy(
                 bf16_scopes=bf16_scopes,
                 expect_donation=backend != "cpu",
-                expect_overlap=overlap,
-                collective_budget=budget,
+                collective_budget=collective_budget,
                 # the sharded step's args carry committed NamedShardings —
                 # propagate them and predict GSPMD reshard points pre-compile
                 # (the GSPMD microbatch chunk slices along the data-sharded
@@ -430,13 +393,11 @@ def lint_flagship(
     collective_budget: Optional[Dict[str, int]] = None,
     features: Optional[Sequence[str]] = None,
     mesh=None,
-    overlap: bool = True,
 ) -> Dict[str, Report]:
     """Lint the flagship functions; returns ``{target: Report}``.
 
-    ``mesh``/``overlap``: shard the train target over a data/fsdp mesh and
-    lint the overlap-scheduled (or, with ``overlap=False``, the GSPMD)
-    distributed step — see :func:`build_targets`.
+    ``mesh``: shard the train target over a data/fsdp mesh and lint the
+    GSPMD distributed step — see :func:`build_targets`.
 
     ``features``: trace-time kernel feature set to lint under (e.g.
     ``("paged",)``); ``None`` keeps the ambient/default set. Feature sets
@@ -445,9 +406,7 @@ def lint_flagship(
     trace off-TPU), making the linted graph match the TPU program the
     feature set actually changes."""
     with features_context(features):
-        built = build_targets(
-            geometry, targets, collective_budget=collective_budget, mesh=mesh, overlap=overlap
-        )
+        built = build_targets(geometry, targets, collective_budget=collective_budget, mesh=mesh)
         return {
             key: check(
                 t.fn,
@@ -465,13 +424,13 @@ def lint_flagship(
 # the flagship programs graphcheck snapshots and the dataflow rules gate
 # (tasks.py perf): flat train, the Probeline-instrumented flat train (the
 # contract that probes add zero collectives/callbacks and bounded bytes),
-# the GSPMD and overlap-scheduled sharded train steps on the
-# DEFAULT_MESH_SPEC submesh, prefill, decode, the engine's batched paged
-# decode step (decode_paged — PR 13 Pageline), and the speculative
-# draft/verify span (decode_spec — PR 14 Specline)
+# the GSPMD sharded train step on the DEFAULT_MESH_SPEC submesh, prefill,
+# decode, the engine's batched paged decode step (decode_paged — PR 13
+# Pageline), and the speculative draft/verify span (decode_spec — PR 14
+# Specline)
 PROGRAMS = (
-    "train_flat", "train_probed", "train_sharded", "train_overlap", "prefill",
-    "decode", "decode_paged", "decode_spec",
+    "train_flat", "train_probed", "train_sharded", "prefill", "decode",
+    "decode_paged", "decode_spec",
 )
 DEFAULT_MESH_SPEC = "data=2,fsdp=2"
 
@@ -484,7 +443,7 @@ def build_programs(
     """The flagship programs as lint targets — the SAME builds
     :func:`~perceiver_io_tpu.analysis.fingerprint.flagship_fingerprints`
     snapshots, so the lint gate and the contract gate cannot drift apart.
-    The sharded pair needs the ``mesh_spec`` submesh worth of devices
+    The sharded step needs the ``mesh_spec`` submesh worth of devices
     (CLIs respawn with virtual CPU devices when the host is short)."""
     unknown = [p for p in programs if p not in PROGRAMS]
     if unknown:
@@ -507,16 +466,11 @@ def build_programs(
 
         t = build_targets(geometry, targets=("train",), probes=ProbeConfig())["train"]
         out["train_probed"] = dataclasses.replace(t, name="train_probed")
-    sharded = [p for p in ("train_sharded", "train_overlap") if p in programs]
-    if sharded:
-        from perceiver_io_tpu.parallel.overlap import mesh_from_spec
+    if "train_sharded" in programs:
+        from perceiver_io_tpu.parallel.mesh import mesh_from_spec
 
-        mesh = mesh_from_spec(mesh_spec)
-        for p in sharded:
-            t = build_targets(
-                geometry, targets=("train",), mesh=mesh, overlap=(p == "train_overlap")
-            )["train"]
-            out[p] = dataclasses.replace(t, name=p)
+        t = build_targets(geometry, targets=("train",), mesh=mesh_from_spec(mesh_spec))["train"]
+        out["train_sharded"] = dataclasses.replace(t, name="train_sharded")
     return out
 
 
@@ -546,48 +500,3 @@ def lint_programs(
             )
             for name, t in built.items()
         }
-
-
-def graphlint_telemetry(geometry: str = "micro", mesh_spec: Optional[str] = None) -> dict:
-    """The ``telemetry.graphlint`` block for bench.py results: lint the
-    flagship train + decode graphs at micro sizes and summarize. A lint
-    finding is a recorded verdict (``status: failed``); an exception inside
-    the lint propagates, so a gate that cannot run never reads as a pass.
-
-    ``mesh_spec`` (bench ``--mesh``): additionally lint the SHARDED micro
-    train step — the overlap-scheduled shard_map step with the
-    ``collective-overlap`` rule and its derived collective budget — as a
-    ``train_sharded`` target (skipped with a note when the host has fewer
-    devices than the mesh needs)."""
-    sharded_note = None
-    reports = lint_flagship(geometry=geometry, targets=("train", "decode"))
-    if mesh_spec:
-        from perceiver_io_tpu.parallel.overlap import mesh_from_spec
-
-        try:
-            mesh = mesh_from_spec(mesh_spec)
-        except ValueError as e:
-            # too few devices: the CLI path (tools/graphlint.py --mesh)
-            # respawns with virtual devices; telemetry records the skip
-            sharded_note = f"skipped: {e}"
-        else:
-            reports["train_sharded"] = lint_flagship(
-                geometry=geometry, targets=("train",), mesh=mesh
-            )["train"]
-    status = "passed" if all(r.ok() for r in reports.values()) else "failed"
-    return {
-        "status": status,
-        **({"sharded": sharded_note} if sharded_note else {}),
-        "targets": {
-            k: {
-                "errors": r.count("error"),
-                "warnings": r.count("warn"),
-                "allowed": len(r.allowed),
-                "violations": [v.key for v in r.violations],
-                # which rules actually ran (the dataflow rules are policy-
-                # gated — this records that the armed set covered them)
-                "rules": list(r.rules_run),
-            }
-            for k, r in reports.items()
-        },
-    }

@@ -333,7 +333,7 @@ def parse_hlo_computations(hlo_text: str) -> Dict[str, List[HloInstr]]:
 
 def collective_stats(hlo_text: str) -> Dict[str, Dict[str, int]]:
     """Per-kind collective ``{count, bytes}`` over a compiled module — the
-    ``telemetry.collectives`` block bench results and the multichip dryrun
+    collective table graphcheck's fingerprints and ``chip_smoke.py --chips 4``
     record. ``bytes`` is an *estimate* from the result-type shape literals of
     each collective instruction (async ``-start`` tuples include the operand
     alias, so async modules over-count roughly 2x — comparable run-over-run,

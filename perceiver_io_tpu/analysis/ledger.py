@@ -1,7 +1,7 @@
 """The feature-graduation ledger — staged → measured → default_on as data.
 
-Perf levers (the overlap-scheduled distributed step, the paged decode
-kernel, speculative decode) shipped default-off with A/Bs staged but
+Perf levers (the paged decode kernel, speculative decode, the int8 cache
+and weights) shipped default-off with A/Bs staged but
 unmeasured; "remember to flip it after the TPU run" is not a system. The
 ledger (``contracts/ledger.json``, committed next to the BENCH_*.json
 artifacts it cites) makes graduation a state machine:

@@ -137,19 +137,6 @@ class LintPolicy:
     # companion, not an allowlist hole. Empty = this program has no paged
     # discipline.
     paged_cache_scopes: Tuple[str, ...] = ()
-    # collective-overlap: declare that the compiled module's collectives are
-    # meant to overlap compute (the parallel/overlap.py scheduling claim).
-    # On async backends (TPU) each *-start/*-done pair must have compute
-    # scheduled between it; on sync backends (XLA:CPU emits no async pairs)
-    # the rule checks DATAFLOW overlap-eligibility instead: each collective
-    # must have at least one significant compute op neither upstream nor
-    # downstream of it — something a latency-hiding scheduler could run
-    # concurrently. Inert until declared.
-    expect_overlap: bool = False
-    # which collective kinds the overlap claim covers. all-reduce is off by
-    # default: the optimizer's global-norm all-reduce is a genuine sync
-    # point every clipped optimizer pays
-    overlap_kinds: Tuple[str, ...] = ("all-gather", "reduce-scatter")
     # per-rule severity overrides, e.g. {"hot-concat": "warn"}
     severity_overrides: Dict[str, str] = dataclasses.field(default_factory=dict)
 
@@ -674,120 +661,6 @@ def implicit_reshard(ctx: RuleContext) -> List[Violation]:
     return out
 
 
-# HLO opcodes that count as "significant compute" a scheduler could hide a
-# collective under — fused loops, matmul-class ops, reductions, control flow.
-# Pure data movement (bitcast/copy/slice/tuple plumbing) deliberately absent.
-_HLO_COMPUTE_OPS = frozenset(
-    {
-        "fusion", "dot", "convolution", "custom-call", "reduce", "reduce-window",
-        "scatter", "gather", "sort", "while", "conditional", "call",
-        "select-and-scatter", "cholesky", "triangular-solve", "fft",
-        "rng", "rng-bit-generator",
-    }
-)
-
-
-def _reachable(start: str, edges: Dict[str, set]) -> set:
-    seen: set = set()
-    stack = [start]
-    while stack:
-        n = stack.pop()
-        for m in edges.get(n, ()):
-            if m not in seen:
-                seen.add(m)
-                stack.append(m)
-    return seen
-
-
-@register_rule(
-    "collective-overlap",
-    severity="error",
-    needs="compiled",
-    doc="reduce-scatter/all-gather with no compute to overlap: async start/done "
-    "pairs scheduled back-to-back, or (sync backends) dependency-serialized "
-    "collectives with zero schedulable-independent compute",
-)
-def collective_overlap(ctx: RuleContext) -> List[Violation]:
-    p = ctx.policy
-    if not p.expect_overlap:
-        return []
-    kinds = tuple(p.overlap_kinds)
-    out: List[Violation] = []
-    for comp_name, instrs in G.parse_hlo_computations(ctx.compiled_text).items():
-        index = {ins.name: i for i, ins in enumerate(instrs)}
-        uses: Dict[str, set] = {ins.name: set() for ins in instrs}
-        defs: Dict[str, set] = {ins.name: set(ins.operands) for ins in instrs}
-        for ins in instrs:
-            for op in ins.operands:
-                uses[op].add(ins.name)
-        for ins in instrs:
-            kind = next((k for k in kinds if ins.opcode in (k, k + "-start")), None)
-            if kind is None:
-                continue
-            where = f"{kind} in {comp_name}" + (f" [{ins.scope}]" if ins.scope else "")
-            if ins.opcode.endswith("-start"):
-                # async form: the actual schedule is in the text — compute
-                # must be placed between the start and its done
-                done = next(
-                    (
-                        other
-                        for other in instrs
-                        if other.opcode == kind + "-done" and ins.name in other.operands
-                    ),
-                    None,
-                )
-                if done is None:
-                    continue  # unmatched start: leave to XLA verification
-                between = instrs[index[ins.name] + 1 : index[done.name]]
-                if not any(b.opcode in _HLO_COMPUTE_OPS for b in between):
-                    out.append(
-                        Violation(
-                            rule="collective-overlap",
-                            severity=_severity(ctx, "collective-overlap"),
-                            scope=ins.scope,
-                            op=kind,
-                            message=(
-                                f"{where}: nothing scheduled between "
-                                f"{ins.opcode} and {done.opcode} — the "
-                                "collective runs exposed instead of riding "
-                                "under compute"
-                            ),
-                        )
-                    )
-            else:
-                # sync form (XLA:CPU): no schedule to read — check the
-                # DATAFLOW instead: compute neither upstream nor downstream
-                # of the collective is what a latency-hiding scheduler could
-                # run concurrently with it
-                anc = _reachable(ins.name, defs)
-                desc = _reachable(ins.name, uses)
-                independent = sum(
-                    1
-                    for other in instrs
-                    if other.opcode in _HLO_COMPUTE_OPS
-                    and other.name not in anc
-                    and other.name not in desc
-                    and other.name != ins.name
-                )
-                if independent == 0:
-                    out.append(
-                        Violation(
-                            rule="collective-overlap",
-                            severity=_severity(ctx, "collective-overlap"),
-                            scope=ins.scope,
-                            op=kind,
-                            message=(
-                                f"{where}: dependency-serialized — every "
-                                "compute op is upstream or downstream of this "
-                                "collective, so no schedule can overlap it "
-                                "(interleave the sync with independent work, "
-                                "see parallel/overlap.py)"
-                            ),
-                        )
-                    )
-    return out
-
-
 # ----------------------------------------------------------- dataflow rules
 
 
@@ -847,8 +720,7 @@ def rng_key_reuse(ctx: RuleContext) -> List[Violation]:
                     "a PRNG key enters the shard_map region REPLICATED "
                     "(in_specs=P()) and reaches a random draw with no "
                     "device-index fold_in on the path — every shard draws "
-                    "IDENTICAL randomness (fold in lax.axis_index first, as "
-                    "parallel/overlap.py does)"
+                    "IDENTICAL randomness (fold in lax.axis_index first)"
                     + (f"; path:\n{chain}" if chain else "")
                 ),
             )

@@ -576,7 +576,7 @@ class MultiHeadAttention(nn.Module):
                 qd = (qh[:, :, None, :] * eye[None, :, :, None]).reshape(b, h, h * qk_per_head)
                 quant = kv_cache.quantized
                 # int8 storage: the convert feeds the GEMM's operand stream (no
-                # materialized bf16 cache copy — measured, tools/int8_cache_probe),
+                # materialized bf16 cache copy — measured by a probe since deleted),
                 # so HBM moves int8 bytes; the per-token scales fold into
                 # elementwise (B, H, M) ops outside both GEMMs.
                 k_op = k_slots.astype(qh.dtype) if quant else k_slots

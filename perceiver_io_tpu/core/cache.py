@@ -58,8 +58,8 @@ class KVCache:
     Decode is HBM-bandwidth-bound (docs/performance.md: batch-8 runs at the
     chip's physical ceiling), so halving cache bytes buys real throughput —
     the scales fold into elementwise ops OUTSIDE the two cache GEMMs, and
-    XLA reads the int8 operands at int8 bytes (measured:
-    tools/int8_cache_probe.py, 1.69x on the decode attention core)."""
+    XLA reads the int8 operands at int8 bytes (measured by a probe since
+    deleted: 1.69x on the decode attention core)."""
 
     k: jnp.ndarray
     v: jnp.ndarray

@@ -109,7 +109,7 @@ _PACK_EXACT_DTYPES = frozenset(
     jnp.dtype(d) for d in (jnp.float32, jnp.bfloat16, jnp.float16)
 )
 
-# trace-time lever (tools/decode_ab.py): None = auto — pack at batch >= 4,
+# trace-time lever (A/B tool since deleted): None = auto — pack at batch >= 4,
 # where the scan's schedule-spread dominates (measured bf16 A/B: +12.5%
 # tok/s at b=8, +2.5% at b=4, -30% at b=2, -8% at b=1 — below the boundary
 # the loop is latency-bound and the barrier serializes staging that
@@ -127,8 +127,8 @@ def pack_small_params(mode: Optional[bool]):
     ops.flash_attention.default_flash): a function already compiled by
     ``make_generate_fn``/``jax.jit`` keeps whatever mode it was traced
     with, and calling it inside this context has no effect. Build AND
-    first-call the generate fn inside the block (tools/decode_ab.py shows
-    the pattern)."""
+    first-call the generate fn inside the block (as a ``default_flash``
+    block is used)."""
     token = _PACK_SMALL.set(mode)
     try:
         yield

@@ -4,10 +4,10 @@ MFU (model FLOPs utilization, the pjit-era scaling studies' primary health
 metric) is analytic model FLOPs per second over the device's peak matmul
 rate: ``mfu = model_flops_per_sec / (peak_flops * n_devices)``. The
 numerator counts only the FLOPs the *model math* requires (the
-``utils.flops.train_step_flops`` cost model, shared with ``bench.py`` —
+``utils.flops.train_step_flops`` cost model —
 rematerialization, padding and layout copies do not inflate it), so MFU is
-comparable across implementations of the same model and across the
-trainer/bench surfaces.
+comparable across implementations of the same model (the benchmark's
+``mfu.train`` counts with its own ``benchmarks/lib/flops.py``).
 
 Goodput is the productive fraction of wall time: step execution vs. the
 compile / checkpoint / eval / other overheads a :class:`GoodputTracker`
@@ -60,9 +60,9 @@ def clm_train_telemetry(model_config) -> Optional[Tuple[int, float]]:
     report ``tokens_per_sec`` / ``model_flops_per_sec`` / ``mfu``.
 
     Tokens are *latent* tokens (the positions that receive a loss); FLOPs
-    are fwd+bwd per sample from ``utils.flops.train_step_flops`` — the SAME
-    analytic model ``bench.py``'s telemetry block uses, so a run's logged
-    MFU and the bench MFU for the same config agree. Prefix cross-attention
+    are fwd+bwd per sample from ``utils.flops.train_step_flops``, the
+    package's one analytic model, so every run's logged MFU counts the
+    same operations for the same config. Prefix cross-attention
     is discounted by the configured prefix-dropout rate. Returns None for
     configs that are not CLM-shaped (no analytic cost model wired up).
     """

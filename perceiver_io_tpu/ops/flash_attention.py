@@ -1604,10 +1604,10 @@ def flash_enabled(explicit: Optional[bool] = None) -> bool:
 # prototyped and REJECTED on measurement: cross-process A/B suggested the
 # latent self-attention (1024x1024) was ~35% faster on einsum, but the chip's
 # burst-vs-sustained clocking (1.5-1.8x) had inflated the comparison — the
-# same-process interleaved A/B (tools/flash_ab.py) shows all-flash fastest at
+# same-process interleaved A/B (a tool since deleted) shows all-flash fastest at
 # batch 4 (25.5 vs 29.0 ms/step) and within 4% at batch 1. Keep flash
-# everywhere it is supported; re-measure with tools/flash_ab.py before
-# revisiting.
+# everywhere it is supported; re-measure in a cell (a traced benchmark run)
+# before revisiting.
 
 
 # ---------------------------------------------------------------------------

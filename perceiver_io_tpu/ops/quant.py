@@ -19,7 +19,7 @@ Design notes, TPU-specific:
   doubles bytes); the multiply-by-scale then cannot hoist either (its
   operand is in-loop). The convert+scale fuse into each matmul's operand
   read, so HBM sees int8. Verified empirically: ``bench.py --mode decode
-  --weight-dtype int8`` at batch 1 measures the speedup this predicts and
+  --weight-dtype int8`` (since deleted) at batch 1 measured this speedup and
   its ``ceiling_fraction`` against the int8-bytes floor reads ~0.99 — a
   hoisted (bf16-materializing) convert would cap it near 0.78
   (``BENCH_extra_r4.json: decode_b1_int8w``; docs/performance.md).

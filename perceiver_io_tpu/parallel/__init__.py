@@ -10,16 +10,11 @@ from perceiver_io_tpu.parallel.mesh import (
     fsdp_param_shardings,
     param_shardings,
     make_mesh,
-    replicated,
-    shard_batch,
-)
-from perceiver_io_tpu.parallel.overlap import (
-    OverlapConfig,
-    expected_collectives,
-    make_overlap_train_step,
     mesh_from_spec,
     parse_mesh_spec,
+    replicated,
     required_devices,
+    shard_batch,
 )
 from perceiver_io_tpu.parallel.ring_attention import (
     make_ring_cross_attention,
@@ -44,9 +39,6 @@ __all__ = [
     "make_ring_self_attention",
     "ring_self_attention",
     "seq_sharded_cross_attention",
-    "OverlapConfig",
-    "expected_collectives",
-    "make_overlap_train_step",
     "mesh_from_spec",
     "parse_mesh_spec",
     "required_devices",

@@ -24,7 +24,7 @@ the same program and feeds its per-process batch shard
 from __future__ import annotations
 
 import math
-from typing import Optional, Sequence
+from typing import Dict, Optional, Sequence
 
 import jax
 import numpy as np
@@ -207,3 +207,48 @@ def param_shardings(params, mesh: Mesh, min_weight_size: int = 2**14):
         return NamedSharding(mesh, _spec(spec))
 
     return jax.tree_util.tree_map_with_path(spec_for, params)
+
+
+def required_devices(spec: Dict[str, int]) -> int:
+    """Device count a parsed mesh spec needs (product of axis sizes)."""
+    need = 1
+    for v in spec.values():
+        need *= int(v)
+    return need
+
+
+def mesh_from_spec(spec_str: str, devices=None) -> Mesh:
+    """Build the data/fsdp mesh a ``--mesh`` spec describes — the ONE
+    implementation behind tools/graphlint.py, tools/graphcheck.py and
+    ``analysis.flagship``. Raises ``ValueError`` (with the XLA_FLAGS hint)
+    when too few devices are visible; callers own their shortage policy
+    (exit, skip-note, or virtual-device respawn)."""
+    spec = parse_mesh_spec(spec_str)
+    devices = list(jax.devices() if devices is None else devices)
+    need = required_devices(spec)
+    if len(devices) < need:
+        raise ValueError(
+            f"mesh {spec_str!r} needs {need} devices, have {len(devices)} (for a "
+            f"CPU dryrun: XLA_FLAGS=--xla_force_host_platform_device_count={need})"
+        )
+    return make_mesh(devices=devices[:need], **spec)
+
+
+def parse_mesh_spec(spec: str) -> Dict[str, int]:
+    """``"data=2,fsdp=4"`` -> ``{"data": 2, "fsdp": 4}`` (the ``--mesh``
+    argument of tools/graphlint.py and tools/graphcheck.py)."""
+    out: Dict[str, int] = {}
+    for part in spec.split(","):
+        part = part.strip()
+        if not part:
+            continue
+        if "=" not in part:
+            raise ValueError(f"bad mesh spec {spec!r}: expected axis=N[,axis=N...]")
+        axis, _, n = part.partition("=")
+        axis = axis.strip()
+        if axis not in (AXIS_DATA, AXIS_FSDP):
+            raise ValueError(f"bad mesh spec {spec!r}: axis {axis!r} (allowed: data, fsdp)")
+        out[axis] = int(n)
+    if not out:
+        raise ValueError(f"bad mesh spec {spec!r}: empty")
+    return out

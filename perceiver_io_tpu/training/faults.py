@@ -172,9 +172,9 @@ class SentinelConfig:
     # from the last checkpoint; a run that keeps diverging past the same
     # point is burning its budget)
     rollback_limit: int = 2
-    # compile the finiteness check + conditional update into the train step
-    # (unsupported by the overlap-scheduled step: there detection is
-    # host-side only and non-finite losses go straight to the rollback rung)
+    # compile the finiteness check + conditional update into the train step;
+    # False leaves detection host-side only, where a non-finite loss goes
+    # straight to the rollback rung
     in_graph_skip: bool = True
 
 
@@ -233,8 +233,8 @@ class DivergenceSentinel:
                 self._consecutive_skips = 0
                 return self._rollback_or_halt("persistent-nonfinite", detail)
             if not skipped:
-                # non-finite loss NOT held off by an in-graph skip (overlap
-                # step, or in_graph_skip=False): the update already landed in
+                # non-finite loss NOT held off by an in-graph skip
+                # (in_graph_skip=False): the update already landed in
                 # params — waiting out skip_limit would train on garbage
                 detail = {"loss": None, "step": int(step)}
                 self._consecutive_skips = 0

@@ -24,7 +24,6 @@ def make_train_step(
     donate: bool = True,
     jit: bool = True,
     microbatch: int = 1,
-    overlap=None,
     sentinel: bool = False,
     probes=None,
     mesh: Optional[Mesh] = None,
@@ -43,16 +42,6 @@ def make_train_step(
     kernels per batch shard (``ops.flash_attention.kernel_mesh``): GSPMD
     cannot partition a Mosaic kernel, and without it the sharded step does
     not lower on a TPU. Tensor and sequence meshes are left as they were.
-
-    ``overlap``: a ``parallel.overlap.OverlapConfig`` (or a bare ``Mesh``)
-    switches to the explicit shard_map distributed step — chunk-interleaved
-    gradient reduce-scatter + bucket-chained FSDP all-gather prefetch
-    (``parallel/overlap.py``) — instead of leaving the collectives to GSPMD.
-    Same loss contract and the same uniform-weighting precondition; the
-    state must be placed by ``shard_train_state`` (matching
-    ``min_weight_size``) and every batch by ``shard_batch``. Default
-    ``None`` keeps the GSPMD path (the overlap step is feature-gated off
-    until its TPU A/B lands — docs/performance.md round 7).
 
     ``jit=False`` returns the raw step function — for callers embedding the
     step in a larger jitted computation (e.g. a multi-step ``lax.scan``),
@@ -89,9 +78,7 @@ def make_train_step(
     still advance (the run keeps its batch schedule and cannot spin on a
     persistent NaN source). Metrics gain ``sentinel_skipped`` (0/1) so the
     host-side :class:`~perceiver_io_tpu.training.faults.DivergenceSentinel`
-    can walk its policy ladder. Not supported by the overlap-scheduled step
-    (the update runs sharded outside the shard_map region); there detection
-    stays host-side.
+    can walk its policy ladder.
 
     ``probes=ProbeConfig(...)`` (obs/probes.py, docs/observability.md#probes)
     compiles the Probeline numerics telemetry into the SAME XLA program:
@@ -105,33 +92,8 @@ def make_train_step(
     contracts. Trace-time static, like the sentinel. With ``microbatch>1``
     activation stats are chunk-averaged (absmax becomes a mean of per-chunk
     maxima — documented, not a bug); grad/update stats see the averaged
-    grads and the single real update. Not supported with ``overlap=`` (the
-    update runs sharded outside the shard_map region).
+    grads and the single real update.
     """
-
-    if overlap is not None:
-        if sentinel:
-            raise ValueError(
-                "sentinel=True (in-graph skip) is not supported by the overlap-"
-                "scheduled step; use SentinelConfig(in_graph_skip=False) — "
-                "host-side detection with the rollback rung still applies"
-            )
-        if probes is not None:
-            raise ValueError(
-                "probes= is not supported by the overlap-scheduled step (its "
-                "update runs on reduce-scattered shards outside the shard_map "
-                "region, so per-bucket update ratios have no full-tree view); "
-                "use the GSPMD step for probed runs"
-            )
-        from jax.sharding import Mesh as _Mesh
-
-        from perceiver_io_tpu.parallel.overlap import OverlapConfig, make_overlap_train_step
-
-        if isinstance(overlap, _Mesh):
-            overlap = OverlapConfig(mesh=overlap)
-        return make_overlap_train_step(
-            loss_fn, overlap, microbatch=microbatch, donate=donate, jit=jit
-        )
 
     if microbatch > 1 and getattr(loss_fn, "uniform_weighting", None) is False:
         raise ValueError(

@@ -68,15 +68,6 @@ class TrainerConfig:
     # time BLOCKED waiting for the consumed batch, near zero when the
     # buffer hits
     input_double_buffer: bool = True
-    # --- distributed step (parallel/overlap.py) ---------------------------
-    # explicit overlap-scheduled shard_map train step: chunk-interleaved
-    # gradient reduce-scatter + bucket-chained FSDP all-gather prefetch
-    # instead of GSPMD-placed collectives. Requires a data/fsdp mesh.
-    # Default OFF until the TPU A/B lands (measure-before-shipping;
-    # docs/performance.md round 7, docs/parallelism.md overlap section)
-    overlap: bool = False
-    overlap_bucket_mb: float = 4.0
-    overlap_prefetch: bool = True
     # --- robustness (training/faults.py; docs/robustness.md) --------------
     # SIGTERM/SIGINT request a final checkpoint at the next step boundary
     # and a clean return instead of killing the loop mid-save (preemption-
@@ -191,19 +182,6 @@ class Trainer:
         self.recompiles = RecompileTracker()
         self._events: Optional[EventLog] = None
         self._manifest_written = False
-        overlap_cfg = None
-        if self.config.overlap:
-            if mesh is None:
-                raise ValueError("TrainerConfig.overlap requires a mesh (data/fsdp axes)")
-            from perceiver_io_tpu.parallel.overlap import OverlapConfig
-
-            overlap_cfg = OverlapConfig(
-                mesh=mesh,
-                bucket_bytes=int(self.config.overlap_bucket_mb * (1 << 20)),
-                prefetch=self.config.overlap_prefetch,
-                # must match fit()'s shard_train_state placement
-                min_weight_size=self.config.fsdp_min_weight_size,
-            )
         # divergence sentinel (training/faults.py): resolve the config once;
         # the in-graph skip half is compiled into the step below, the
         # host-side ladder walker is created fresh per fit()
@@ -216,15 +194,6 @@ class Trainer:
                 if isinstance(self.config.sentinel, SentinelConfig)
                 else SentinelConfig()
             )
-            if overlap_cfg is not None and self._sentinel_cfg.in_graph_skip:
-                # the overlap step's update runs outside the shard_map region;
-                # detection stays host-side there (non-finite losses go
-                # straight to the rollback rung — faults.py)
-                import dataclasses
-
-                self._sentinel_cfg = dataclasses.replace(
-                    self._sentinel_cfg, in_graph_skip=False
-                )
         in_graph_sentinel = self._sentinel_cfg is not None and self._sentinel_cfg.in_graph_skip
         # Probeline (obs/probes.py): resolve the probe config once; the
         # in-graph stats compile into the step below, the ring/blast host
@@ -243,7 +212,6 @@ class Trainer:
         self._train_step = self.recompiles.wrap(
             make_train_step(
                 loss_fn,
-                overlap=overlap_cfg,
                 sentinel=in_graph_sentinel,
                 probes=self._probe_cfg,
                 **layout,
@@ -252,12 +220,9 @@ class Trainer:
         )
         # the raw (unjitted) step for the graphlint trace: linting through
         # the recompile-tracked jit wrapper would pollute its compile
-        # bookkeeping, and the raw fn traces identically. Built with the
-        # SAME overlap config so the linted graph is the trained program
-        # (the jaxpr walker descends into the shard_map body)
+        # bookkeeping, and the raw fn traces identically
         self._lint_step = make_train_step(
-            loss_fn, jit=False, overlap=overlap_cfg, sentinel=in_graph_sentinel,
-            probes=self._probe_cfg, **layout,
+            loss_fn, jit=False, sentinel=in_graph_sentinel, probes=self._probe_cfg, **layout
         )
         # the fit-scoped preemption guard, exposed so tests and the chaos
         # harness can trip it deterministically (tools/chaos.py)

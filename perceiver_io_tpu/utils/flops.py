@@ -164,9 +164,9 @@ def train_step_flops(config, batch_size: int, prefix_dropout_keep: float) -> flo
     Perceiver AR CLM config: self-attention part over latents +
     cross-attention over the (dropout-discounted) prefix.
 
-    This is THE shared cost model for MFU across surfaces — ``bench.py``'s
-    telemetry block and the trainer's per-log-row ``mfu``
-    (``obs.mfu.clm_train_telemetry``) both use it, so the two numbers are
+    This is the package's one cost model for MFU: the trainer's per-log-row
+    ``mfu`` (``obs.mfu.clm_train_telemetry``) and every reader of that row
+    use it, so two runs' numbers are
     directly comparable for the same config on the same chip. Unlike the
     reference :class:`ComputeEstimator` (kept for scaling-study parity) it
     counts the CA q/o projections and CA MLP and honors the config's

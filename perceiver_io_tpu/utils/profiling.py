@@ -70,9 +70,9 @@ class StepTimer:
         interpolation) and the row carries ``low_n`` — a 3-sample window has
         no p99 tail, and interpolating one would print a fake number
         consumers (obs_report, obs_diff) cannot distinguish from a real
-        tail. (``bench.py`` builds its telemetry percentiles from
-        :func:`percentile` directly — its samples need per-chain
-        normalization before summarizing — and applies the same rule.)"""
+        tail. (A caller whose samples need normalization before
+        summarizing builds its percentiles from :func:`percentile`
+        directly and applies the same rule.)"""
         return summarize_latencies(self.steps)
 
     def steps_per_sec(self) -> float:

@@ -8,9 +8,9 @@ equivalent with the same task names:
     python tasks.py clean              # caches + test + build artifacts
     python tasks.py build              # sdist/wheel via pyproject
     python tasks.py docker [--tag TAG]
-    python tasks.py bench [...args]    # the driver benchmark (real chip)
+    python tasks.py bench [...args]    # BENCHMARK.json's command: benchmarks/run.py (real chip)
     python tasks.py graphlint [...]    # static-analysis gate (compiled graphs)
-    python tasks.py perf [...]         # perf CI: graphcheck contracts + graphlint + bench floors + obs gate
+    python tasks.py perf [...]         # perf CI: graphcheck contracts + graphlint + ledger floors + obs gate
     python tasks.py obs [...]          # observability gate (spans/requests/SLO + obs_diff self-check)
     python tasks.py load [...]         # serving load gate (closed-loop loadgen + flight recorder + /metrics)
     python tasks.py sim [...]          # discrete-event scale gate (multi-tenant sim of the real engine)
@@ -112,15 +112,17 @@ def docker(args):
 
 @task
 def bench(args):
-    run(sys.executable, "bench.py", *args.rest)
+    """The benchmark the driver reads (``BENCHMARK.json``'s command), e.g.
+    ``--workload ar16k-train-b32 --seed 1 --seconds 20 --trace 1``."""
+    run("python3", "benchmarks/run.py", *args.rest)
 
 
 @task
 def dryrun(args):
     """Multichip certification gate: the forced-8-device dryrun (every mesh
-    kind, the ring strategy, the overlap-scheduled step, sharded decode) plus
+    kind, the ring strategy, sharded decode, the pipelines) plus
     the distributed test suites — which otherwise only run when someone
-    remembers to. Extra args go to pytest (e.g. ``-k overlap``)."""
+    remembers to. Extra args go to pytest (e.g. ``-k ring``)."""
     env = dict(os.environ)
     flags = re.sub(r"--xla_force_host_platform_device_count=\S+", "", env.get("XLA_FLAGS", ""))
     env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
@@ -128,7 +130,7 @@ def dryrun(args):
     run(sys.executable, "-c", "import __graft_entry__; __graft_entry__.dryrun_multichip(8)")
     run(
         sys.executable, "-m", "pytest",
-        "tests/test_overlap.py", "tests/test_distributed.py",
+        "tests/test_sharded_step.py", "tests/test_distributed.py",
         "tests/test_seq_parallel_step.py", "tests/test_ring_attention.py",
         "-q", *args.rest,
         env=env,
@@ -222,9 +224,11 @@ def sim(args):
 def perf(args):
     """The standing perf-CI gate (docs/static-analysis.md): graphcheck —
     compiled-graph contracts vs contracts/, graduation-ledger validation,
-    committed-bench floors — then the graphlint rule gate, then the
+    the ledger's floors on the committed BENCH_extra / LOAD / SIM records
+    (no script of this tree writes a BENCH_extra file any more) — then the
+    graphlint rule gate, then the
     dataflow rules (rng-key-reuse, dead-compute, sharding-flow,
-    cross-program-consistency) over all five flagship programs, then the
+    cross-program-consistency) over all seven flagship programs, then the
     observability gate — the RUNTIME leg: with ``OBS_BASELINE_RUN`` set to
     a recorded baseline run directory (``tasks.py obs --out DIR --keep``),
     obs_diff classifies MFU/goodput/step-p99/SLO drift against it under
@@ -252,7 +256,7 @@ def perf(args):
     run(sys.executable, "tools/hostlint.py", "--fail-on", "warn")
     run(sys.executable, "tools/graphcheck.py", *args.rest)
     run(sys.executable, "tools/graphlint.py", "--fail-on", "error")
-    # trace-only on purpose: graphcheck just compiled the same five
+    # trace-only on purpose: graphcheck just compiled the same
     # programs; the dataflow rules need only the jaxpr
     run(sys.executable, "tools/graphlint.py", "--programs", "all",
         "--no-compiled", "--fail-on", "error")

@@ -457,54 +457,6 @@ def test_rng_key_reuse_clean_with_device_index_fold():
     ).clean
 
 
-def test_rng_key_reuse_catches_the_pr4_unfolded_overlap_key():
-    """The PR-4 regression, replayed statically: the REAL overlap step built
-    with its device-index fold_in stripped (the shipped bug) must be caught
-    by rng-key-reuse; the shipped step must lint clean. The runtime
-    draw-variance test (tests/test_overlap.py) pins the behavior; this pins
-    that the bug class can no longer reach runtime."""
-    from unittest import mock
-
-    from perceiver_io_tpu.parallel import make_mesh, shard_batch
-    from perceiver_io_tpu.parallel.overlap import OverlapConfig, make_overlap_train_step
-    from perceiver_io_tpu.training import TrainState, make_optimizer
-    from perceiver_io_tpu.training.loop import shard_train_state
-
-    def rng_loss(params, batch, rng):
-        u = jax.random.uniform(rng, ())  # the in-graph draw (dropout stand-in)
-        loss = jnp.mean(batch["x"]) * sum(jnp.sum(v) for v in jax.tree.leaves(params))
-        return loss * 0.0 + u, {"loss": u}
-
-    rng_loss.uniform_weighting = True
-
-    mesh = make_mesh(data=2, fsdp=4)
-    cfg = OverlapConfig(mesh=mesh, bucket_bytes=1 << 14, min_weight_size=32)
-    state = shard_train_state(
-        TrainState.create(
-            lambda *a, **k: None, {"w": jnp.ones((16, 8))},
-            make_optimizer(1e-2, optimizer="sgd"), jax.random.PRNGKey(1),
-        ),
-        mesh, min_weight_size=32,
-    )
-    batch = shard_batch({"x": jnp.ones((16, 8), jnp.float32)}, mesh)
-    policy = LintPolicy(check_rng=True)
-
-    shipped = make_overlap_train_step(rng_loss, cfg, microbatch=2, donate=False)
-    assert analysis.check(
-        shipped, (state, batch), rules=("rng-key-reuse",), policy=policy
-    ).clean
-
-    # strip the fold at trace time: exactly the code PR 4 shipped with
-    with mock.patch.object(jax.random, "fold_in", lambda key, data: key):
-        bugged = make_overlap_train_step(rng_loss, cfg, microbatch=2, donate=False)
-        report = analysis.check(
-            bugged, (state, batch), rules=("rng-key-reuse",), policy=policy
-        )
-    assert not report.ok(), "the PR-4 replicated-key bug must be caught statically"
-    assert all(v.rule == "rng-key-reuse" for v in report.violations)
-    assert "REPLICATED" in report.violations[0].message
-
-
 # --------------------------------------------------------------- dead-compute
 
 
@@ -576,29 +528,28 @@ def test_sharding_flow_clean_when_aligned_and_skipped_undeclared():
 def test_sharding_flow_agrees_with_compiled_reshard_contracts():
     """The acceptance pin: sharding-flow's pre-compile predictions must
     agree with the compiled-HLO reshard findings recorded in the committed
-    contracts — train_sharded (GSPMD microbatch chunk slices along the
+    contract — train_sharded (GSPMD microbatch chunk slices along the
     data-sharded batch axis) compiles with collective-permutes and must be
-    predicted; train_overlap (explicit shard_map, per-shard chunking) has
-    none and must predict none."""
+    predicted."""
     from perceiver_io_tpu.analysis.flagship import build_programs
 
     contracts_dir = os.path.join(os.path.dirname(os.path.dirname(__file__)), "contracts")
-    for name in ("train_sharded", "train_overlap"):
-        target = build_programs((name,))[name]
-        report = analysis.check(
-            target.fn, target.args, rules=("sharding-flow",),
-            policy=target.policy, compiled=False, name=name,
-        )
-        with open(os.path.join(contracts_dir, f"{name}.json")) as f:
-            coll = json.load(f)["fingerprint"].get("collectives", {})
-        compiled_reshards = sum(
-            coll.get(k, {}).get("count", 0) for k in ("all-to-all", "collective-permute")
-        )
-        predicted = len(report.violations)
-        assert (predicted > 0) == (compiled_reshards > 0), (
-            f"{name}: predicted {predicted} reshard point(s) vs "
-            f"{compiled_reshards} compiled reshard collective(s)\n{report.format()}"
-        )
+    name = "train_sharded"
+    target = build_programs((name,))[name]
+    report = analysis.check(
+        target.fn, target.args, rules=("sharding-flow",),
+        policy=target.policy, compiled=False, name=name,
+    )
+    with open(os.path.join(contracts_dir, f"{name}.json")) as f:
+        coll = json.load(f)["fingerprint"].get("collectives", {})
+    compiled_reshards = sum(
+        coll.get(k, {}).get("count", 0) for k in ("all-to-all", "collective-permute")
+    )
+    predicted = len(report.violations)
+    assert (predicted > 0) == (compiled_reshards > 0), (
+        f"{name}: predicted {predicted} reshard point(s) vs "
+        f"{compiled_reshards} compiled reshard collective(s)\n{report.format()}"
+    )
 
 
 # -------------------------------------------------- cross-program-consistency
@@ -851,8 +802,8 @@ def test_trainer_graphlint_off_emits_nothing(tmp_path):
 
 def test_flagship_micro_lint_is_clean():
     """The real flagship train/prefill/decode graphs lint clean at micro
-    geometry with the documented default allowlist — the gate bench.py and
-    `tasks.py graphlint` run."""
+    geometry with the documented default allowlist — the gate
+    `tasks.py graphlint` runs."""
     from perceiver_io_tpu.analysis.flagship import lint_flagship
 
     reports = lint_flagship(geometry="micro")
@@ -861,13 +812,3 @@ def test_flagship_micro_lint_is_clean():
         assert report.ok(), f"{name}:\n{report.format()}"
         # the default-route kv concat is allowlisted, not silently absent
     assert any("kv_concat" in v.key for v in reports["train"].allowed)
-
-
-def test_graphlint_telemetry_block_shape():
-    from perceiver_io_tpu.analysis.flagship import graphlint_telemetry
-
-    block = graphlint_telemetry()
-    assert block["status"] in ("passed", "failed")
-    assert set(block["targets"]) == {"train", "decode"}
-    for t in block["targets"].values():
-        assert {"errors", "warnings", "allowed", "violations"} <= set(t)

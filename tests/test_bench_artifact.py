@@ -59,51 +59,3 @@ def test_bench_extra_artifact_shape_and_int8_wins():
             assert t["mfu"] == pytest.approx(
                 t["model_flops_per_sec"] / t["peak_flops_per_device"], rel=0.01
             ), k
-
-
-def test_bench_telemetry_fields_shape():
-    """The telemetry block every bench result carries (ISSUE 1 satellite):
-    MFU against the obs.mfu peak table plus the StepTimer percentile
-    summary — validated on synthetic numbers so no device work runs."""
-    import bench
-    from perceiver_io_tpu.obs.mfu import device_peak_flops
-
-    t = bench.telemetry_fields(1e12, 0.5, step_times_s=[0.4, 0.5, 0.6])["telemetry"]
-    assert t["model_flops_per_sec"] == pytest.approx(2e12)
-    assert device_peak_flops() is None  # the CPU is off the peak table
-    assert t["peak_flops_per_device"] is None and t["mfu"] is None
-    assert t["step_ms"]["p50"] == pytest.approx(500.0)
-    assert t["step_ms"]["p50"] <= t["step_ms"]["p90"] <= t["step_ms"]["p99"]
-
-    # decode rows: no FLOPs model (bandwidth-bound), per-token latency only
-    td = bench.telemetry_fields(None, 0.01, step_times_s=[0.01], times_key="token_ms")[
-        "telemetry"
-    ]
-    assert "mfu" not in td and "model_flops_per_sec" not in td
-    assert td["token_ms"]["p99"] == pytest.approx(10.0)
-    assert td["device_kind"]
-
-
-def test_bench_telemetry_records_kernel_features_and_smoke_status():
-    """Committed results must self-describe the A/B state that produced
-    them (ISSUE 2 satellites): the active trace-time kernel feature set,
-    and the kernel_smoke gate's pass/fail/skipped status once main()
-    resolves it (a --skip-smoke run is visible in the artifact)."""
-    import bench
-    from perceiver_io_tpu.ops.flash_attention import fast_kernels
-
-    t = bench.telemetry_fields(None, 0.01)["telemetry"]
-    assert t["kernel_features"] == []
-    assert "kernel_smoke" not in t  # unresolved outside main()
-
-    with fast_kernels({"paged"}):
-        t = bench.telemetry_fields(None, 0.01)["telemetry"]
-    assert t["kernel_features"] == ["paged"]
-
-    old = bench._SMOKE_STATUS
-    try:
-        bench._SMOKE_STATUS = "skipped"
-        t = bench.telemetry_fields(None, 0.01)["telemetry"]
-        assert t["kernel_smoke"] == "skipped"
-    finally:
-        bench._SMOKE_STATUS = old

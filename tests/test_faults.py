@@ -408,11 +408,6 @@ def test_in_graph_skip_holds_params_and_advances_step():
     assert np.isfinite(float(m3["loss"]))
 
 
-def test_sentinel_rejected_with_overlap():
-    with pytest.raises(ValueError, match="overlap"):
-        make_train_step(loss_fn, overlap=object(), sentinel=True)
-
-
 # ---------------------------------------------------------------------------
 # trainer wiring: preempt -> auto-resume equivalence, rollback, halt
 # ---------------------------------------------------------------------------

@@ -225,13 +225,13 @@ def test_planted_kv_concat_regression_caught(flagship_fps):
 
 
 def test_planted_extra_all_gather_caught(flagship_fps):
-    live = flagship_fps["train_overlap"]
+    live = flagship_fps["train_sharded"]
     coll = {k: dict(v) for k, v in live.collectives.items()}
     coll["all-gather"]["count"] += 1
-    res = check_contracts(CONTRACTS, programs=("train_overlap",),
-                          live={"train_overlap": _doctor(live, collectives=coll)})
+    res = check_contracts(CONTRACTS, programs=("train_sharded",),
+                          live={"train_sharded": _doctor(live, collectives=coll)})
     assert res["status"] == "regressed"
-    assert "all-gather" in res["programs"]["train_overlap"]["detail"]
+    assert "all-gather" in res["programs"]["train_sharded"]["detail"]
 
 
 def test_planted_peak_memory_growth_caught(flagship_fps):
@@ -259,8 +259,6 @@ def test_committed_ledger_validates_and_floors_hold():
     ledger = L.load_ledger(CONTRACTS)
     assert ledger is not None, "contracts/ledger.json must be committed"
     assert L.validate_ledger(ledger) == []
-    # the overlap step is tracked, still staged until a TPU A/B lands
-    assert L.feature_state(ledger, "overlap") == "staged"
     assert L.default_on_features(ledger) == ()
     # the committed BENCH artifacts meet their own pinned floors
     assert L.check_bench_floors(ledger, REPO) == []
@@ -367,34 +365,6 @@ def test_floor_match_clause_selects_latest_matching_round(tmp_path):
     assert committed["floors"]["engine_throughput_tok_s"]["match"]["mode"] == "closed"
 
 
-# --------------------------------------------------------- bench.py telemetry
-
-
-def test_graphcheck_telemetry_block_shape():
-    """The `telemetry.graphcheck` block bench results carry: the contract
-    verdict for the two cheapest programs."""
-    from perceiver_io_tpu.analysis.fingerprint import graphcheck_telemetry
-
-    block = graphcheck_telemetry()
-    assert block["status"] in ("passed", "regressed", "stale", "missing")
-    assert block["status"] == "passed", block  # contracts are committed + clean
-    assert set(block["programs"]) == {"train_flat", "decode"}
-
-
-def test_bench_telemetry_records_graphcheck_status():
-    import bench
-
-    t = bench.telemetry_fields(None, 0.01)["telemetry"]
-    assert "graphcheck" not in t  # unresolved outside main()
-    old = bench._GRAPHCHECK_STATUS
-    try:
-        bench._GRAPHCHECK_STATUS = {"status": "skipped"}
-        t = bench.telemetry_fields(None, 0.01)["telemetry"]
-        assert t["graphcheck"] == {"status": "skipped"}
-    finally:
-        bench._GRAPHCHECK_STATUS = old
-
-
 # ------------------------------------------------- graphlint CLI exit semantics
 
 
@@ -464,4 +434,4 @@ def test_graphlint_cli_unknown_rule_is_usage_error(capsys):
     with pytest.raises(SystemExit) as e2:
         gl.main(["--programs", "bogus"])
     assert e2.value.code == 2
-    assert "train_overlap" in capsys.readouterr().err
+    assert "train_sharded" in capsys.readouterr().err

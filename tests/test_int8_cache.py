@@ -4,9 +4,9 @@ generation performs, and run end-to-end through generate/beam search.
 
 Capability beyond the reference (its torch cache is full-precision,
 huggingface.py:158-185): decode is bandwidth-bound, so int8 halves the
-dominant traffic — measured 1.69x on the decode attention core
-(tools/int8_cache_probe.py) and benchable via
-``bench.py --mode decode --cache-dtype int8``.
+dominant traffic — measured 1.69x on the decode attention core by a probe
+and 1.295x end to end by ``bench.py --mode decode --cache-dtype int8``
+(BENCH_extra_r5; both scripts since deleted).
 """
 
 import jax

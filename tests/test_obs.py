@@ -174,10 +174,9 @@ def test_trainer_telemetry_off_without_logger(tmp_path):
     assert not os.path.exists(os.path.join(str(tmp_path), "events.jsonl"))
 
 
-def test_clm_train_telemetry_matches_bench_cost_model():
-    """The trainer's MFU numerator and bench.py's telemetry block must share
-    ONE cost model, or the two surfaces report incomparable MFU for the
-    same config on the same chip."""
+def test_clm_train_telemetry_matches_the_cost_model():
+    """The trainer's MFU numerator is ``utils.flops.train_step_flops`` at
+    the configured prefix-dropout rate: the package's one cost model."""
     _, config = tiny_clm()
     tokens, flops = clm_train_telemetry(config)
     assert tokens == config.max_latents
@@ -185,9 +184,6 @@ def test_clm_train_telemetry_matches_bench_cost_model():
 
     keep = 1.0 - config.cross_attention_dropout
     assert flops == pytest.approx(train_step_flops(config, 1, prefix_dropout_keep=keep))
-    import bench
-
-    assert bench.train_step_flops is train_step_flops  # bench re-exports, not forks
     # non-CLM configs have no analytic model: None, not a bogus number
     assert clm_train_telemetry(object()) is None
 
@@ -462,14 +458,6 @@ def test_steptimer_summary_low_n_uses_exact_order_statistics():
     assert exact_percentile([3.0, 1.0, 2.0], 0) == 1.0
     with pytest.raises(ValueError):
         exact_percentile([], 50)
-    # bench telemetry blocks apply the same rule
-    import bench
-
-    t = bench.telemetry_fields(None, 1.0, [0.1, 0.2, 0.3])["telemetry"]
-    assert t["step_ms"]["low_n"] is True
-    assert t["step_ms"]["p99"] == pytest.approx(300.0)  # exact max, in ms
-    t5 = bench.telemetry_fields(None, 1.0, [0.1] * 5)["telemetry"]
-    assert "low_n" not in t5["step_ms"]
 
 
 # -------------------------------------------------------------- goodput

@@ -372,15 +372,6 @@ def test_flagship_build_targets_rejects_probes_with_mesh():
         build_targets("micro", targets=("train",), mesh=mesh, probes=P.ProbeConfig())
 
 
-def test_probes_rejected_on_overlap_step(setup):
-    _, _, _, _, loss_fn = setup
-    from perceiver_io_tpu.parallel.overlap import OverlapConfig
-
-    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "fsdp"))
-    with pytest.raises(ValueError, match="overlap"):
-        make_train_step(loss_fn, overlap=OverlapConfig(mesh=mesh), probes=P.ProbeConfig())
-
-
 # ---------------------------------------------------------------------------
 # decode health gauges
 # ---------------------------------------------------------------------------

@@ -28,7 +28,7 @@ from perceiver_io_tpu.ops import paged_attention as pa
 # the package re-exports a function under the module's name
 fa = importlib.import_module("perceiver_io_tpu.ops.flash_attention")
 
-# flagship attention geometry (bench.flagship_config): 512 channels, 8 heads
+# flagship attention geometry (chip_smoke.flagship_config): 512 channels, 8 heads
 HEADS, CHANNELS = 8, 512
 CONTEXT, LATENTS = 16384, 1024
 TRAIN_CHUNK = 4  # samples per gradient chunk of the batch-32 train step
@@ -808,3 +808,29 @@ def test_mlp_gelu_is_not_expanded_again_inside_the_gemms(four_chips, one_chip, m
     assert "transpose" in backward["op_name"] and "/mlp/" in backward["op_name"]  # the ``dy W2^T`` GEMM, named by its root
     assert (backward["exponential"], backward["divide"]) == (1, 0)
     assert step_hlo.entry_buffers(text, "f32" + hidden) == []
+
+
+def test_chip_smoke_imports_on_the_cpu_and_keeps_the_benchmarks_widths():
+    """``chip_smoke.py`` carries ``kernel_smoke`` and ``flagship_config`` itself
+    (``bench.py`` is gone), imports where JAX has only the CPU, and its flagship
+    is the benchmark's ``perceiver-ar-small-16k`` field for field."""
+    import dataclasses
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location("chip_smoke", os.path.join(root, "chip_smoke.py"))
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+    assert "bench" not in sys.modules and not hasattr(chip_smoke, "bench")
+    assert callable(chip_smoke.kernel_smoke)
+
+    config = chip_smoke.flagship_config(chip_smoke.SEQ_LEN, chip_smoke.LATENTS)
+    with open(os.path.join(root, "benchmarks", "configs", "perceiver-ar-small-16k.json")) as f:
+        published = json.load(f)
+    fields = {f.name for f in dataclasses.fields(config)}
+    held = {k: v for k, v in published.items() if k in fields}
+    assert {"max_seq_len", "max_latents", "num_channels", "num_heads", "num_self_attention_layers",
+            "cross_attention_dropout", "vocab_size"} <= set(held)
+    assert {k: getattr(config, k) for k in held} == held

@@ -4,7 +4,7 @@ committed-bench floors, as one perf-CI gate.
 Extracts a GraphFingerprint (analysis/fingerprint.py: collectives, hot-scope
 concats, donation aliases, captured consts, dtype histogram, FLOPs, static
 peak-HBM breakdown) from each flagship program — train flat, train
-data x fsdp (GSPMD), train overlap (explicit shard_map), prefill, decode —
+data x fsdp (GSPMD), prefill, decode —
 and semantically diffs it against the committed snapshot in ``contracts/``.
 A regression (more collectives, a new hot concat, fewer donation aliases,
 fatter memory/FLOPs beyond tolerance) fails the gate; an improvement or
@@ -70,7 +70,7 @@ def main(argv=None) -> int:
                         "CPU devices when the host has too few)")
     p.add_argument("--features", default=None,
                    help="override the kernel feature set ('all', 'none', or a "
-                        "comma list, same tokens as bench.py); default: the "
+                        "comma list); default: the "
                         "ledger's default_on features")
     p.add_argument("--update", action="store_true",
                    help="re-snapshot the selected programs' contracts instead "
@@ -91,14 +91,14 @@ def main(argv=None) -> int:
             flagship_fingerprints,
             save_contract,
         )
-        from perceiver_io_tpu.parallel.overlap import parse_mesh_spec, required_devices
+        from perceiver_io_tpu.parallel.mesh import parse_mesh_spec, required_devices
 
         programs = tuple(x for x in args.programs.split(",") if x)
         unknown = [x for x in programs if x not in PROGRAMS]
         if unknown:
             print(f"unknown program(s) {unknown}; known: {PROGRAMS}")
             return 3
-        if any(x in ("train_sharded", "train_overlap") for x in programs):
+        if "train_sharded" in programs:
             _ensure_devices(required_devices(parse_mesh_spec(args.mesh)))
 
         ledger = L.load_ledger(args.contracts)

@@ -1,9 +1,8 @@
 """graphlint CLI — static analysis of the flagship compiled graphs.
 
 Lints the flagship train step, prefill and decode functions
-(perceiver_io_tpu/analysis/flagship.py builds the same programs bench.py
-measures) against the full rule set and prints a human report per target
-plus, optionally, one JSON artifact. Exit status follows ``--fail-on``, so
+(perceiver_io_tpu/analysis/flagship.py builds them) against the full rule
+set and prints a human report per target plus, optionally, one JSON artifact. Exit status follows ``--fail-on``, so
 this is the CI gate `tasks.py graphlint` wraps:
 
     python tools/graphlint.py --fail-on error
@@ -11,20 +10,19 @@ this is the CI gate `tasks.py graphlint` wraps:
     python tools/graphlint.py --kernel-features paged             # A/B the lint
     python tools/graphlint.py --json graphlint.json --allow 'hot-concat:*mlp*'
     python tools/graphlint.py --mesh data=2,fsdp=4 --targets train  # sharded step
-    python tools/graphlint.py --programs all --no-compiled  # the 5 graphcheck
+    python tools/graphlint.py --programs all --no-compiled  # the graphcheck
                                                             # programs, dataflow rules
 
-``--mesh data=N[,fsdp=M]`` lints the SHARDED flagship train step — by
-default the overlap-scheduled shard_map step (parallel/overlap.py) with the
-``collective-overlap`` rule armed and a collective budget derived from its
-bucket plan; ``--overlap off`` lints the GSPMD step instead. When the host
+``--mesh data=N[,fsdp=M]`` lints the SHARDED flagship train step (GSPMD:
+``make_train_step`` on ``shard_train_state`` inputs). When the host
 has fewer devices than the mesh needs, the CLI re-execs itself with that
 many virtual CPU devices (the __graft_entry__ dryrun trick).
 
-``--programs all`` lints the five graphcheck programs (train_flat,
-train_sharded, train_overlap, prefill, decode) with per-program policies
+``--programs all`` lints the graphcheck programs (train_flat,
+train_sharded, prefill, decode and the rest of
+``analysis.flagship.PROGRAMS``) with per-program policies
 that arm the dataflow rules — rng-key-reuse, dead-compute, sharding-flow
-(on the sharded steps), cross-program-consistency (decode vs prefill).
+(on the sharded step), cross-program-consistency (decode vs prefill).
 This is the gate ``tasks.py perf`` runs after graphcheck.
 
 Exit codes: 0 — no violation at/above ``--fail-on``; 1 — violations found;
@@ -75,10 +73,10 @@ def main(argv=None) -> int:
     p.add_argument("--targets", default="train,prefill,decode",
                    help="comma list of flagship functions to lint")
     p.add_argument("--programs", default=None, metavar="P1,P2|all",
-                   help="lint the five graphcheck programs instead of the "
+                   help="lint the graphcheck programs instead of the "
                         "--targets trio: train_flat, train_sharded (GSPMD), "
-                        "train_overlap (shard_map), prefill, decode — 'all' "
-                        "or a comma list; the sharded pair re-execs with "
+                        "prefill, decode, ... — 'all' "
+                        "or a comma list; the sharded step re-execs with "
                         "virtual CPU devices when the host is short. This is "
                         "the dataflow-rule gate `tasks.py perf` runs")
     add_common_lint_args(
@@ -92,8 +90,7 @@ def main(argv=None) -> int:
                    help="forbid compiling — trace-only rules")
     p.add_argument("--kernel-features", default=None,
                    help="trace-time flash kernel feature set to lint under: "
-                        "'all', 'none', or a comma list (e.g. 'paged') — same "
-                        "tokens as bench.py --kernel-features")
+                        "'all', 'none', or a comma list (e.g. 'paged')")
     p.add_argument("--collective-budget", default=None,
                    help="JSON dict enabling the collective-budget rule, e.g. "
                         "'{\"all-gather\": 2, \"total\": 4}'")
@@ -101,10 +98,6 @@ def main(argv=None) -> int:
                    help="shard the train target over this data/fsdp mesh and "
                         "lint the distributed step (re-execs with virtual CPU "
                         "devices when the host has too few)")
-    p.add_argument("--overlap", choices=("on", "off"), default="on",
-                   help="with --mesh: lint the overlap-scheduled shard_map "
-                        "step (on, default — arms the collective-overlap rule "
-                        "and a derived collective budget) or the GSPMD step (off)")
     args = p.parse_args(argv)
 
     from perceiver_io_tpu.analysis.rules import RULES
@@ -126,14 +119,14 @@ def main(argv=None) -> int:
                 f"unknown program(s) {', '.join(unknown_programs)}; known: "
                 f"{', '.join(PROGRAMS)}"
             )
-        if any(x in ("train_sharded", "train_overlap") for x in programs):
-            from perceiver_io_tpu.parallel.overlap import parse_mesh_spec, required_devices
+        if "train_sharded" in programs:
+            from perceiver_io_tpu.parallel.mesh import parse_mesh_spec, required_devices
 
             _ensure_devices(required_devices(parse_mesh_spec(DEFAULT_MESH_SPEC)))
 
     mesh = None
     if args.mesh:
-        from perceiver_io_tpu.parallel.overlap import (
+        from perceiver_io_tpu.parallel.mesh import (
             mesh_from_spec,
             parse_mesh_spec,
             required_devices,
@@ -173,7 +166,6 @@ def main(argv=None) -> int:
                 collective_budget=budget,
                 features=features,
                 mesh=mesh,
-                overlap=args.overlap == "on",
             )
     except Exception as e:  # noqa: BLE001 — a rule/build CRASH is not a verdict
         # exit 3, distinct from 1 (violations found): CI must not read "the
