@@ -466,6 +466,31 @@ def init_recurrent_state(batch_size: int, d_conv: int, d_state: int, d_inner: in
                           ssm=jnp.zeros((batch_size, d_state, d_inner), jnp.float32))
 
 
+@struct.dataclass
+class RetentionState:
+    """What a power retention layer (``core/retention.py``) keeps of a row's
+    past, of one size whatever the context: ``s`` (B, Hkv, R, D) float32, a
+    key-value head's decayed sum of ``phi(k) v^T``, and ``z`` (B, Hkv, R / D, D)
+    float32, its decayed sum of ``phi(k)``, over the ``R`` rows of the feature
+    map ``phi`` (``ops/power_retention.py``: ``D / 2 + 1`` tiles of ``D``, the
+    values' channel on a tile's rows and the feature on the lanes). No slots: a
+    step reads and writes both whole. **It has a length** (the tokens the state
+    holds, a scalar as a :class:`KVCache`'s): a stack made of such states alone
+    has no growing cache to read a step's rotary position off, so the state
+    carries it."""
+
+    s: jnp.ndarray
+    z: jnp.ndarray
+    length: jnp.ndarray
+
+
+def init_retention_state(batch_size: int, kv_heads: int, feature_rows: int, head_dim: int) -> RetentionState:
+    """The state before a row's first token: nothing summed, no token held. Float32 whatever the caches' dtype."""
+    return RetentionState(s=jnp.zeros((batch_size, kv_heads, feature_rows, head_dim), jnp.float32),
+                          z=jnp.zeros((batch_size, kv_heads, feature_rows // head_dim, head_dim), jnp.float32),
+                          length=jnp.zeros((), jnp.int32))
+
+
 # ---------------------------------------------------------------------------
 # window discipline
 # ---------------------------------------------------------------------------

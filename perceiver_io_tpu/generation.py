@@ -1819,6 +1819,10 @@ def make_instrumented_generate_fn(
     ssm_taps = probes and "ssm.*" in decoder.tap_scopes
     m_ssm_abs_max = registry.gauge("ssm_state_abs_max") if ssm_taps else None
     m_ssm_nonfinite = registry.counter("ssm_state_nonfinite_total") if ssm_taps else None
+    # a retention layer's state (``core/retention.py`` taps ``ret.state``): the same two readings of ``S``
+    ret_taps = probes and "ret.*" in decoder.tap_scopes
+    m_ret_abs_max = registry.gauge("ret_state_abs_max") if ret_taps else None
+    m_ret_nonfinite = registry.counter("ret_state_nonfinite_total") if ret_taps else None
     # a model that drafts for itself (a ``speculative`` decoder): a step yields 0 to 2 tokens a row, every
     # step is host-timed as one TPOT sample, and the ``spec.step`` taps keep the drafting's books
     self_drafting = getattr(decoder, "speculative", False)
@@ -1956,6 +1960,11 @@ def make_instrumented_generate_fn(
                     health_row["ssm_state_nonfinite"] = sum(int(h["state_nonfinite"]) for h in hh)
                     m_ssm_abs_max.set(health_row["ssm_state_abs_max"])
                     m_ssm_nonfinite.inc(health_row["ssm_state_nonfinite"])
+                if ret_taps:
+                    health_row["ret_state_abs_max"] = round(max(float(h["ret_state_abs_max"]) for h in hh), 6)
+                    health_row["ret_state_nonfinite"] = sum(int(h["ret_state_nonfinite"]) for h in hh)
+                    m_ret_abs_max.set(health_row["ret_state_abs_max"])
+                    m_ret_nonfinite.inc(health_row["ret_state_nonfinite"])
                 if spec_taps:
                     drafts, accepted = (sum(int(h[k]) for h in hh) for k in ("drafts", "accepted"))
                     m_spec_drafts.inc(drafts)

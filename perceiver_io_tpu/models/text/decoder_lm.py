@@ -18,6 +18,14 @@ further down):
   context, a window layer's a :class:`WindowKVCache` ring of
   ``sliding_window`` slots, both kinds side by side in one generator state.
 
+- **Jamba** and **Brumby** (``layer_types`` with ``"mamba"`` or
+  ``"power_retention"`` entries): a layer whose mixer keeps a state of one size
+  whatever the context in attention's place, a state-space layer's
+  :class:`RecurrentState` (``core/ssm.py``) beside grouped-query layers, or a
+  power retention layer's :class:`RetentionState` (``core/retention.py``) in
+  every layer, which also carries the length a step's rotary position is read
+  off; a dense SwiGLU in every block.
+
 Unlike the Perceiver models every position passes the whole stack, so there
 is no latent window. The model meets :mod:`perceiver_io_tpu.generation`
 through :meth:`DecoderLanguageModel.generation_decoder`.
@@ -68,22 +76,25 @@ import jax.numpy as jnp
 from jax import lax
 
 from perceiver_io_tpu.core.cache import (
-    KVCache, LatentCache, RaggedKVCache, RaggedWindowKVCache, RecurrentState, WindowKVCache, init_kv_cache,
+    KVCache, LatentCache, RaggedKVCache, RaggedWindowKVCache, RecurrentState, RetentionState, WindowKVCache, init_kv_cache,
     init_latent_cache, init_ragged_kv_cache, init_ragged_window_kv_cache, init_window_kv_cache,
 )
 from perceiver_io_tpu.core.gqa import GroupedQueryAttention, verify_fused
 from perceiver_io_tpu.core.mla import VIEWS, MultiHeadLatentAttention, expand_views
 from perceiver_io_tpu.core.moe import MoELayer, SwiGLU, grouped_combine
+from perceiver_io_tpu.core.retention import PowerRetention
 from perceiver_io_tpu.core.ssm import MambaMixer
 from perceiver_io_tpu.obs import probes
 from perceiver_io_tpu.ops.gqa_verify import verify_plan
 from perceiver_io_tpu.ops.layernorm import RMSNorm
 from perceiver_io_tpu.ops.mla_absorb import row_tile
+from perceiver_io_tpu.ops.power_retention import chunk_of, feature_rows, power_retention_plans
 from perceiver_io_tpu.ops.selective_scan import ssm_scan_plans
 
 
 _ATTENTION_TYPES = ("sliding_attention", "full_attention")
-_LAYER_TYPES = _ATTENTION_TYPES + ("mamba",)
+_RETENTION = "power_retention"
+_LAYER_TYPES = _ATTENTION_TYPES + ("mamba", _RETENTION)
 _BLOCKS = ("serial", "shortcut")
 
 
@@ -121,7 +132,11 @@ class DecoderLanguageModelConfig:
     ``mamba_dt_rank`` and ``mamba_d_conv`` (the published keys of the Jamba
     family); ``first_k_dense_replace`` at the depth gives every layer the dense
     SwiGLU. ``tie_word_embeddings`` reads the logits off the embedding table
-    (no ``head``)."""
+    (no ``head``).
+
+    A ``"power_retention"`` entry makes that layer's mixer a power retention
+    layer (``core/retention.py``) on the grouped-query sizes and rotary, at
+    degree 2 (the power of the query-key product the kernels are written for)."""
 
     vocab_size: int = 129280
     hidden_size: int = 7168
@@ -177,9 +192,11 @@ class DecoderLanguageModelConfig:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             if len(self.layer_types) != self.num_hidden_layers or set(self.layer_types) - set(_LAYER_TYPES):
                 raise ValueError(f"layer_types: one of {_LAYER_TYPES} for each of the {self.num_hidden_layers} layers")
-            attends = set(self.layer_types) & set(_ATTENTION_TYPES)
+            attends = set(self.layer_types) & (set(_ATTENTION_TYPES) | {_RETENTION})
             if attends and not (self.num_key_value_heads and self.head_dim):
-                raise ValueError("attention entries of layer_types need num_key_value_heads and head_dim")
+                raise ValueError("attention and retention entries of layer_types need num_key_value_heads and head_dim")
+            if _RETENTION in attends and self.head_dim % 2:
+                raise ValueError("a power_retention layer: the symmetric square over a head of even width is what is built")
             if "sliding_attention" in attends and not self.sliding_window:
                 raise ValueError("a sliding_attention layer needs sliding_window")
             if attends and self.num_attention_heads % self.num_key_value_heads:
@@ -233,6 +250,8 @@ class DecoderBlock(nn.Module):
             self.attn = MultiHeadLatentAttention(c, **kw)
         elif self.layer_type == "mamba":
             self.mixer = MambaMixer(c, **kw)
+        elif self.layer_type == _RETENTION:
+            self.attn = PowerRetention(c, **kw)
         else:
             self.attn = GroupedQueryAttention(c, window=self.layer_type == "sliding_attention", **kw)
         self.ffn_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
@@ -245,7 +264,8 @@ class DecoderBlock(nn.Module):
         """``x + Attn(RMSNorm(x))`` over whole rows, expanded; also the cache
         rows (latent attention: one array; grouped-query: rotated keys and
         values, of which a window layer hands on its last ``sliding_window``;
-        a state-space layer: the rows' :class:`RecurrentState`)."""
+        a state-space layer: the rows' :class:`RecurrentState`; a retention
+        layer: the rows' final ``(S, z)``)."""
         if self.layer_type == "mamba":
             a, state = self.mixer.expand(self.attn_norm(x))
             return _residual(x, a), state
@@ -473,10 +493,12 @@ class DecoderLanguageModel(nn.Module):
         u, _ = self.mtp_attend(u, pos[:, :-1])
         return self.logits(x), self.mtp_logits(self.mtp_ffn(u))
 
-    def decode_step(self, token, caches: Tuple[Union[LatentCache, KVCache, WindowKVCache, RecurrentState], ...]):
+    def decode_step(self, token, caches: Tuple[Union[LatentCache, KVCache, WindowKVCache, RecurrentState, RetentionState], ...]):
         """One new token a row against the caches: logits (B, V) and the advanced caches."""
         b = token.shape[0]
-        # the position is the length of a cache that has one; a stack of state-space layers alone has no use for it
+        # the position is the length of whatever carries one: a cache that grows, a ring, or a retention state (a
+        # stack with no growing cache still rotates by it). Only a state-space layer's state has none, and such a
+        # layer reads no position: a stack of those alone decodes at 0 and nothing reads it
         length = next((cache.length for cache in caches if not isinstance(cache, RecurrentState)), 0)
         pos = jnp.broadcast_to(length, (b, 1)).astype(jnp.int32)
         x = self.embed(token)[:, None]
@@ -550,7 +572,8 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
     last ``sliding_window`` positions only; of a state-space layer the rows'
     :class:`RecurrentState` (such a layer runs over chunks of whole rows like an
     attention: a row's time axis is the scan kernel's to chunk, and a padded
-    row would run its padding through the state). The hidden state of the whole batch
+    row would run its padding through the state); of a retention layer the
+    rows' final ``(S, z)``, float32. The hidden state of the whole batch
     stays in memory between layers (B * N * h); within a layer the attention
     runs over chunks of whole rows and the feed-forward over chunks of tokens
     (``_PREFILL_ATTENTION_TOKENS``, ``_PREFILL_FFN_TOKENS``), inside the one
@@ -579,7 +602,7 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
         x, rows = _over_chunks(lambda xc, i=i: scoped("attend_layer", xc, pos, i), x.reshape(b // rows_a, rows_a, n, h))
         if c.layer_types is None:
             cache_rows.append(_batch_rows(rows, b, n))
-        elif c.layer_types[i] == "mamba":  # (chunks, rows a chunk, ...): the rows' states, as they leave the scan
+        elif c.layer_types[i] in ("mamba", _RETENTION):  # (chunks, rows a chunk, ...): the rows' states, as they leave the kernel
             cache_rows.append(jax.tree.map(lambda r: r.reshape(b, *r.shape[2:]), rows))
         else:  # (chunks, rows a chunk, Hkv, positions, D): a key-value head is a row of the cache
             cache_rows.append(tuple(r.reshape(b * r.shape[2], *r.shape[3:]) for r in rows))
@@ -643,10 +666,12 @@ class _Decoder:
 
     @property
     def tap_scopes(self) -> Tuple[str, ...]:
-        """The probe sites this configuration's layers have: an expert layer's books, a state-space layer's state."""
+        """The probe sites this configuration's layers have: an expert layer's books, a state-space or retention layer's state."""
         c = self.model.config
         sparse = c.block == "shortcut" or c.first_k_dense_replace < c.num_hidden_layers
-        return (("moe.*",) if sparse else ()) + ("spec.*",) + (("ssm.*",) if "mamba" in (c.layer_types or ()) else ())
+        kinds = c.layer_types or ()
+        return ((("moe.*",) if sparse else ()) + ("spec.*",) + (("ssm.*",) if "mamba" in kinds else ())
+                + (("ret.*",) if _RETENTION in kinds else ()))
 
     def _caches(self, rows, batch: int, n: int, max_new_tokens: int, cache_dtype):
         c = self.model.config
@@ -655,6 +680,8 @@ class _Decoder:
         def cache_of(kind, kept):
             if kind == "mamba":  # the state as the scan left it (float32), the window in the caches' dtype
                 return RecurrentState(conv=kept.conv.astype(cache_dtype), ssm=kept.ssm)
+            if kind == _RETENTION:  # float32 whatever the caches' dtype; the state holds the prompt's ``n`` tokens
+                return RetentionState(s=kept[0], z=kept[1], length=jnp.asarray(n, jnp.int32))
             slots, d = batch * c.num_key_value_heads, c.head_dim
             if kind == "sliding_attention":
                 return init_window_kv_cache(slots, c.sliding_window, d, d, cache_dtype).fill(*kept, n)
@@ -757,9 +784,10 @@ class _Decoder:
 
     def health(self, logits, window):
         # the occupancy gauge reads a cache that grows: a ring is full from its window on, a recurrent state has one size
-        fixed = (WindowKVCache, RaggedWindowKVCache, RecurrentState)
+        fixed = (WindowKVCache, RaggedWindowKVCache, RecurrentState, RetentionState)
         grows = next((cache for cache in window[0] if not isinstance(cache, fixed)), window[0][0])
-        return probes.decode_health(logits, grows, jnp.zeros((), jnp.int32))
+        # a stack of retention states alone: nothing fills
+        return probes.decode_health(logits, None if isinstance(grows, RetentionState) else grows, jnp.zeros((), jnp.int32))
 
     def compile_row(self, batch: int, prompt_len: int, max_new_tokens: int, cache_dtype) -> dict:
         """The caches' geometry for a ``compile`` event row."""
@@ -776,6 +804,18 @@ class _Decoder:
             full_slots = self.full_capacity(prompt_len, max_new_tokens, cache_dtype) if self.speculative else prompt_len + max_new_tokens
             n_window = kinds.count("sliding_attention")
             n_full = kinds.count("full_attention")
+            if _RETENTION in kinds:  # states alone, of one size whatever the context: no cache has a length to bound
+                d, n_ret = c.head_dim, kinds.count(_RETENTION)
+                rows = feature_rows(d)
+                return {
+                    "ret_layers": n_ret,
+                    "ret_state_bytes": batch * c.num_key_value_heads * (rows * d + rows) * 4 * n_ret,
+                    "ret_state_dtype": "float32",
+                    "ret_feature_dim": d * (d + 1) // 2,
+                    "ret_state_rows": rows,
+                    "ret_chunk": chunk_of(prompt_len),
+                    "power_retention": power_retention_plans(),
+                }
             if "mamba" in kinds:  # a state of one size beside the caches that grow; no expert layer, no ring
                 d_inner, n_ssm = c.mamba_expand * c.hidden_size, kinds.count("mamba")
                 return {

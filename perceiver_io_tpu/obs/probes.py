@@ -284,7 +284,8 @@ def decode_health(logits, kv_cache, kv_start) -> Dict:
     """The per-token decode gauges, computed in-graph from the step body's
     last-position logits and the post-append cross-attention cache:
     KV-window occupancy fraction (the batch's mean where the cache keeps a
-    length a row), mean logit entropy (nats — collapsing
+    length a row; 0 where ``kv_cache`` is ``None``: a stack whose states have one
+    size and no cache that fills), mean logit entropy (nats — collapsing
     entropy is the classic degenerate-sampling signal), and the non-finite
     logit fraction (the serving-side numerics probe)."""
     import jax
@@ -294,12 +295,15 @@ def decode_health(logits, kv_cache, kv_start) -> Dict:
         l32 = logits.astype(jnp.float32)
         logp = jax.nn.log_softmax(l32, axis=-1)
         ent = -jnp.sum(jnp.where(jnp.isfinite(logp), jnp.exp(logp) * logp, 0.0), axis=-1)
-        used = (kv_cache.length - kv_start).astype(jnp.float32)
-        if used.ndim:  # a cache that keeps a length a row
-            used = jnp.mean(used)
+        if kv_cache is None:
+            used, capacity = jnp.zeros((), jnp.float32), 1.0
+        else:
+            used, capacity = (kv_cache.length - kv_start).astype(jnp.float32), float(kv_cache.capacity)
+            if used.ndim:  # a cache that keeps a length a row
+                used = jnp.mean(used)
         return {
             "logit_entropy": jnp.mean(ent),
-            "kv_cache_frac": used / float(kv_cache.capacity),
+            "kv_cache_frac": used / capacity,
             "nonfinite_logit_frac": jnp.mean((~jnp.isfinite(l32)).astype(jnp.float32)),
         }
 

@@ -290,6 +290,8 @@ LAYER_SCOPES = frozenset({
     "dense_mlp", "norm", "residual", "chunk_io", "cache_fill", "loop_io",
     # a state-space mixer (core/ssm.py): the scan is the prompt pass's, the update a step's
     "ssm/proj_in", "ssm/conv", "ssm/select", "ssm/scan", "ssm/update", "ssm/out",
+    # a power retention layer (core/retention.py): the chunked form is the prompt pass's, the update a step's
+    "ret/proj", "ret/gate", "ret/chunk", "ret/update", "ret/out",
 })
 # flax module names that mark a layer no scope is opened for
 MODULE_LAYERS = {"q_proj": "qkv_proj", "k_proj": "qkv_proj", "v_proj": "qkv_proj", "o_proj": "o_proj"}
@@ -297,7 +299,8 @@ _NORM_MODULE = re.compile(r"(^|_)norm$|^(Layer|RMS)Norm_\d+$")
 # a layer that is read whole: what it holds inside (the MLP's own LayerNorm, an attention's projections
 # and q/k norms) is its own
 CLOSED_LAYERS = frozenset({"mlp", "dense_mlp", "mla/expand", "mla/absorb", "attn/window", "attn/full",
-                           "ssm/proj_in", "ssm/conv", "ssm/select", "ssm/scan", "ssm/update", "ssm/out"})
+                           "ssm/proj_in", "ssm/conv", "ssm/select", "ssm/scan", "ssm/update", "ssm/out",
+                           "ret/proj", "ret/gate", "ret/chunk", "ret/update", "ret/out"})
 # parts of a name stack that are no scope: what a transform or a loop wraps around the names. A transform
 # wraps the first scope opened under it (``transpose(jvp(loss))`` is the scope ``loss``); ``jit`` wraps the name
 # of a function, which is no scope

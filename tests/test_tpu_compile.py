@@ -749,6 +749,49 @@ def test_the_jamba_cells_generator_carries_its_state_in_place(one_chip, mosaic, 
     assert not re.search(r"bf16\[256,16,5120\]", text) and not re.search(r"f32\[65536,5120,16\]|f32\[256,256,5120,16\]", text)
 
 
+# ------------------------------------------ states alone: five float32 retention states updated in place by the step's kernel
+
+
+def test_the_brumby_cells_generator_carries_its_states_in_place(one_chip, mosaic, monkeypatch):
+    """``brumby-pp8-decode-b32-p4k`` as the benchmark builds it (3.21B bfloat16
+    parameters, 32 prompts of 4096 tokens, 256 new tokens), compiled for a
+    described v5e: one geometry of the chunk kernel in the prompt pass (five
+    calls, none in the decode loop) and one of the step's kernel in the loop
+    (five calls, none outside it); the decode loop carries the five states
+    ``f32[32,8,8320,128]`` row-major, each the aliased operand and result of its
+    layer's kernel (``output_to_operand_aliasing``), and nothing in the body
+    copies, turns, converts or slices into one; no state of half the precision
+    and no feature map of a token (``[.., 8320]`` or ``[.., 65, 128]`` wide over
+    the prompt's tokens) exists anywhere outside the kernels. The memory
+    analysis here counts each aliased kernel result as a buffer of its own
+    (5.5 GB): without them the program is under the 14.9 GB the other cells
+    are held to, and the chip reads 14.18 GB (PERF.md 6, PR 46)."""
+    import re
+
+    compiled = _cell_generator("brumby-pp8-decode-b32-p4k", "brumby", one_chip, monkeypatch)
+    m = compiled.memory_analysis()
+    assert 6.41e9 < m.argument_size_in_bytes < 6.43e9  # the weights (embedding and head apart) and the prompts
+    states = 5 * 32 * 8 * (8320 * 128 + 65 * 128) * 4
+    total = _device_bytes(compiled) - states
+    assert total < 14.9e9, f"{total / 1e9:.2f} GB"
+    text = compiled.as_text()
+    assert set(re.findall(r"power_ret_chunk_l\d+_c\d+_h\d+_d\d+", text)) == {"power_ret_chunk_l4096_c256_h40_d128"}
+    assert set(re.findall(r"power_ret_step_b\d+_h\d+_d\d+", text)) == {"power_ret_step_b32_h40_d128"}
+    state = r"f32\[32,8,8320,128\]"
+    result = lambda ins: ins.line.split(" = ", 1)[1].split(f" {ins.opcode}(", 1)[0]  # noqa: E731
+    loop, body = _loop_around(text, lambda loop, inside: re.search(state, result(loop)))  # the decode loop: the one that carries a state
+    assert len(re.findall(state + r"\{3,2,1,0[:}]", result(loop))) == 5 and not re.search(state + r"\{(?!3,2,1,0)", result(loop))
+    kernels = [i for i in body if i.opcode == "custom-call" and "power_ret_step" in i.name]
+    assert len(kernels) == 5 and all("output_to_operand_aliasing={{1}: (4, {}), {2}: (5, {})}" in i.line for i in kernels)
+    assert not any(i.opcode == "custom-call" and "power_ret_chunk" in i.name for i in body)  # the chunk kernel is the prompt pass's
+    for ins in body:
+        if re.search(state, result(ins)):
+            assert ins.opcode in ("custom-call", "get-tuple-element", "tuple", "parameter", "bitcast"), ins.line[:300]
+            assert not re.search(state + r"\{(?!3,2,1,0)", result(ins)), ins.line[:300]
+    assert not re.search(r"bf16\[32,8,8320,128\]", text)
+    assert not re.search(r"(bf16|f32)\[(\d+,)*(4096|131072)(,\d+)*,(8320|8256|65,128)\]", text)
+
+
 # ------------------------------------------ the MLP's exact GELU: evaluated once a layer and kept
 
 
