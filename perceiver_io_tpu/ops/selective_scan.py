@@ -15,15 +15,34 @@ of time) with time innermost and sequential, and the state of a tile lives in
 VMEM scratch across a row's time chunks and is written out once, as the row's
 final state: no ``[T, D, N]`` array exists anywhere.
 
-**Layout.** Channels lie on the lanes *and* the sublanes: the wrapper views
-``[.., D]`` as ``[.., D / 128, 128]`` and a tile is 8 x 128 = 1024 channels, one
-float32 vector register a state row. The ``N`` (16) state rows of a tile are
-then 16 registers, each updated by whole-register operations, and ``B_t[n]`` and
-``C_t[n]`` are *scalars* to such a register: they come in through SMEM and
-splat, where a layout with ``N`` on the sublanes would need a lane broadcast of
-a column per token for ``B`` and a cross-sublane sum per token for ``C``. The
-sum over ``n`` is 16 register adds in a fixed order. A ``[.., D, N]`` layout would
-fill 16 lanes of 128.
+**Layout.** In the recurrence channels lie on the lanes *and* the sublanes: a
+tile is 8 x 128 = 1024 channels of one token, one float32 vector register a
+state row. The ``N`` (16) state rows of a tile are then 16 registers, each
+updated by whole-register operations, and ``B_t[n]`` and ``C_t[n]`` are
+*scalars* to such a register: they come in through SMEM and splat, where a
+layout with ``N`` on the sublanes would need a lane broadcast of a column per
+token for ``B`` and a cross-sublane sum per token for ``C``. The sum over ``n``
+is 16 register adds in a fixed order. A ``[.., D, N]`` layout would fill 16
+lanes of 128.
+
+The arrays do not lie that way, and the kernel takes them as they lie: ``x``
+(in the mixer's dtype), the step size and ``y`` are ``(rows, T, D)`` with blocks
+``(1, chunk, 1024)``, which the chip tiles 8 *tokens* x 128 channels, and ``A``
+and the final state ``(N, D)`` / ``(rows, N, D)``, 8 *states* x 128 channels. A
+``[.., D / 128, 128]`` view of any of them is a physical copy in HBM (until PR
+47 the wrapper made three a call, 0.35 s of a 10.6 s call of
+``jamba2-3b-decode-b256``). So the token loop walks slabs of 8 tokens: a slab of
+a stream is 8 registers of (8 tokens x 128 channels), one a lane tile; turned
+among themselves (:func:`_turn`: register ``g``'s sublane ``t`` becomes register
+``t``'s sublane ``g``) they are 8 registers of (8 lane tiles x 128 channels), one
+a token; the 8 tokens run in order, and their 8 ``y`` registers are turned back
+and stored as one slab. ``x`` is widened to float32 on the loaded registers.
+``A`` is turned once a tile into scratch and the state once a row, at the end.
+The turn rides the load, store and shuffle slots under the vector ALUs and the
+``exp`` that bind the kernel, and eight tokens a loop trip schedule better than
+one: with the turn inside, a chunk of 16 rows of 256 tokens takes 0.699 ms where
+the kernel over the views took 0.787, bit for bit the same ``y`` and state
+(``tools/ssm_scan_ab.py`` on a v5e, PR 47: ``program`` against ``view4d``).
 
 **Nothing is fused beside the recurrence.** The kernel is bound by the vector
 and transcendental units (an ``exp`` and six operations a state element a
@@ -54,7 +73,7 @@ from jax.experimental.pallas import tpu as pltpu
 LANES = 128
 SUBLANES = 8
 CHANNEL_TILE = SUBLANES * LANES  # channels of a tile: one float32 register a state row
-TIME_CHUNK = 128  # tokens a grid step: three streams of 512 KB a buffer, the scalars 16 KB of SMEM (64 and 256 read within 2%)
+TIME_CHUNK = 128  # tokens a grid step, a multiple of 8: two float32 streams of 512 KB a buffer and x's, the scalars 16 KB of SMEM (64 and 256 read within 3%)
 
 
 def ssm_scan_kernel_name(length: int, d_inner: int, d_state: int) -> str:
@@ -83,19 +102,20 @@ def ssm_scan_plans() -> list:
 
 
 def _tile_shape(d_inner: int):
-    """``(groups, sublanes a tile, lanes)`` of the channel view ``[D / lanes, lanes]``."""
+    """``(groups, groups a tile, lanes)``: a row's channels cut into lane tiles (``groups`` of ``lanes``), and how many
+    of them a grid step takes: 8, the sublanes of a register (every one where that does not divide: interpret mode's)."""
     lanes = LANES if d_inner % LANES == 0 else d_inner
     groups = d_inner // lanes
     return groups, (SUBLANES if groups % SUBLANES == 0 else groups), lanes
 
 
-def scan_plan(length: int, d_inner: int, d_state: int) -> ScanPlan:
+def scan_plan(length: int, d_inner: int, d_state: int, x_itemsize: int) -> ScanPlan:
     groups, sub, lanes = _tile_shape(d_inner)
     chunk = min(TIME_CHUNK, -(-length // SUBLANES) * SUBLANES)
     tile = sub * lanes
-    streams = 3 * 2 * chunk * tile * 4  # x, dt and y, double-buffered, float32
-    consts = 2 * d_state * tile * 4  # A
-    state = 2 * d_state * tile * 4  # the scratch and the final state's block
+    streams = 2 * chunk * tile * (x_itemsize + 4 + 4)  # x as it arrives, dt and y float32, double-buffered
+    consts = 3 * d_state * tile * 4  # A's block, double-buffered, and its turned copy
+    state = 3 * d_state * tile * 4  # the scratch and the final state's block, double-buffered
     return ScanPlan(length, d_inner, d_state, tile, chunk, (groups // sub) * -(-length // chunk),
                     streams + consts + state)
 
@@ -107,37 +127,75 @@ def ssm_scan_supported(d_inner: int) -> bool:
     return d_inner % CHANNEL_TILE == 0 or _interpret_default()
 
 
-def _scan_kernel(bc_ref, x_ref, dt_ref, a_ref, y_ref, state_ref, h_scr, *, d_state: int, chunk: int, length: int):
+def _turn(slab, groups: int, lanes: int):
+    """``(rows, groups * lanes)`` to ``(rows, groups, lanes)``: a row's lane tiles, which lie side by side, a register
+    each, become the sublanes of one register a row. On the chip (8 rows, 8 tiles of 128 lanes) register ``g``'s
+    sublane ``r`` becomes register ``r``'s sublane ``g``: Mosaic's transpose of a major axis with the sublanes."""
+    return jnp.swapaxes(jnp.stack([slab[:, g * lanes:(g + 1) * lanes] for g in range(groups)]), 0, 1)
+
+
+def _turn_back(regs):
+    """``(rows, groups, lanes)`` to ``(rows, groups * lanes)``: :func:`_turn` undone."""
+    tiles = jnp.swapaxes(regs, 0, 1)
+    return jnp.concatenate([tiles[g] for g in range(tiles.shape[0])], axis=-1)
+
+
+def _scan_kernel(bc_ref, x_ref, dt_ref, a_ref, y_ref, state_ref, h_scr, a_scr, *, d_state: int, chunk: int, length: int,
+                 sub: int, lanes: int):
     j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _start():
         h_scr[...] = jnp.zeros_like(h_scr)
+        for n0 in range(0, d_state, SUBLANES):  # A, 8 states x 128 channels a register, to a register a state: once a tile
+            n1 = min(n0 + SUBLANES, d_state)
+            a_scr[n0:n1] = _turn(a_ref[n0:n1], sub, lanes)
 
-    def token(t, h):
-        dt = dt_ref[0, t]
-        dtx = dt * x_ref[0, t]
-        y = None
-        base = t * (2 * d_state)
-        new = []
-        for n in range(d_state):  # a state row a register; the sum over n in this order
-            h_n = jnp.exp(dt * a_ref[n]) * h[n] + dtx * bc_ref[base + n]
-            y_n = h_n * bc_ref[base + d_state + n]
-            y = y_n if y is None else y + y_n
-            new.append(h_n)
-        y_ref[0, t] = y
-        return tuple(new)
+    def slab(s, h, valid=None):
+        """Tokens ``8 s .. 8 s + 7`` of the chunk in order; ``valid`` (the tail's) is how many of them are the row's."""
+        rows = pl.ds(pl.multiple_of(s * SUBLANES, SUBLANES), SUBLANES)
+        dt8 = dt_ref[0, rows]
+        dts = _turn(dt8, sub, lanes)
+        dtxs = _turn(dt8 * x_ref[0, rows].astype(jnp.float32), sub, lanes)  # x widened on the loaded registers
+        ys = []
+        for k in range(SUBLANES):
+            dt, dtx = dts[k], dtxs[k]
+            base = (s * SUBLANES + k) * (2 * d_state)
+            y = None
+            new = []
+            for n in range(d_state):  # a state row a register; the sum over n in this order
+                h_n = jnp.exp(dt * a_scr[n]) * h[n] + dtx * bc_ref[base + n]
+                y_n = h_n * bc_ref[base + d_state + n]
+                y = y_n if y is None else y + y_n
+                new.append(h_n)
+            ys.append(y)
+            h = tuple(new) if valid is None else tuple(jnp.where(k < valid, h_n, old) for h_n, old in zip(new, h))
+        y_ref[0, rows] = _turn_back(jnp.stack(ys))
+        return h
+
+    def carried():
+        return tuple(h_scr[n] for n in range(d_state))
+
+    def keep(h):
+        for n in range(d_state):
+            h_scr[n] = h[n]
 
     # the last chunk of a length that is no multiple of the chunk stops at the row's end: what lies
     # past it in the blocks is not the row's
     steps = chunk if length % chunk == 0 else jnp.minimum(chunk, length - j * chunk)
-    h = lax.fori_loop(0, steps, token, tuple(h_scr[n] for n in range(d_state)))
-    for n in range(d_state):
-        h_scr[n] = h[n]
+    keep(lax.fori_loop(0, steps // SUBLANES, slab, carried()))
+
+    if length % SUBLANES:  # the row's last, partial slab: its tokens in order, the state kept from there on
+
+        @pl.when(steps % SUBLANES > 0)
+        def _tail():
+            keep(slab(steps // SUBLANES, carried(), steps % SUBLANES))
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finish():
-        state_ref[0] = h_scr[...]
+        for n0 in range(0, d_state, SUBLANES):  # the state, a register a state, to 8 states x 128 channels a register: once a row
+            n1 = min(n0 + SUBLANES, d_state)
+            state_ref[0, n0:n1] = _turn_back(h_scr[n0:n1])
 
 
 @jax.jit
@@ -147,35 +205,32 @@ def _scan(x, dt, b, c, a):
     rows, length, d_inner = x.shape
     d_state = b.shape[-1]
     groups, sub, lanes = _tile_shape(d_inner)
-    plan = _SCAN_PLANS[(length, d_inner, d_state)] = scan_plan(length, d_inner, d_state)
-    chunk = plan.time_chunk
+    plan = _SCAN_PLANS[(length, d_inner, d_state)] = scan_plan(length, d_inner, d_state, x.dtype.itemsize)
+    chunk, tile = plan.time_chunk, plan.channel_tile
     n_chunks = -(-length // chunk)
 
     f32 = jnp.float32
     # a token's 2N scalars side by side, the rows' chunks end to end: a chunk's block of SMEM is one run of the array
     bc = jnp.concatenate([b.astype(f32), c.astype(f32)], axis=-1)
     bc = jnp.pad(bc, ((0, 0), (0, n_chunks * chunk - length), (0, 0))).reshape(-1)
-    view = lambda t: t.astype(f32).reshape(*t.shape[:-1], groups, lanes)  # noqa: E731
-    stream = pl.BlockSpec((1, chunk, sub, lanes), lambda r, i, j: (r, j, i, 0))
-    y, state = pl.pallas_call(
-        functools.partial(_scan_kernel, d_state=d_state, chunk=chunk, length=length),
+    stream = pl.BlockSpec((1, chunk, tile), lambda r, i, j: (r, j, i))
+    return pl.pallas_call(
+        functools.partial(_scan_kernel, d_state=d_state, chunk=chunk, length=length, sub=sub, lanes=lanes),
         name=ssm_scan_kernel_name(length, d_inner, d_state),
         grid=(rows, groups // sub, n_chunks),
         in_specs=[
             pl.BlockSpec((chunk * 2 * d_state,), lambda r, i, j: (r * n_chunks + j,), memory_space=pltpu.SMEM),
             stream,
             stream,
-            pl.BlockSpec((d_state, sub, lanes), lambda r, i, j: (0, i, 0)),
+            pl.BlockSpec((d_state, tile), lambda r, i, j: (0, i)),
         ],
-        out_specs=[stream, pl.BlockSpec((1, d_state, sub, lanes), lambda r, i, j: (r, 0, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((rows, length, groups, lanes), f32),
-                   jax.ShapeDtypeStruct((rows, d_state, groups, lanes), f32)],
-        scratch_shapes=[pltpu.VMEM((d_state, sub, lanes), f32)],
+        out_specs=[stream, pl.BlockSpec((1, d_state, tile), lambda r, i, j: (r, 0, i))],
+        out_shape=[jax.ShapeDtypeStruct((rows, length, d_inner), f32), jax.ShapeDtypeStruct((rows, d_state, d_inner), f32)],
+        scratch_shapes=[pltpu.VMEM((d_state, sub, lanes), f32), pltpu.VMEM((d_state, sub, lanes), f32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"),
                                              vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret_default(),
-    )(bc, view(x), view(dt), view(a))
-    return y.reshape(rows, length, d_inner), state.reshape(rows, d_state, d_inner)
+    )(bc, x, dt.astype(f32), a.astype(f32))
 
 
 @jax.custom_vjp

@@ -16,12 +16,21 @@ decay rates 1 to N, step sizes of 1e-3 to 1e-1), so the state remembers the
 whole of these sequences."""
 
 import dataclasses
+import functools
 import importlib
+import json
+import os
+import subprocess
+import sys
+import traceback
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from benchmarks.families.jamba import layer_types, remembering
 from benchmarks.lib import jamba_cost
@@ -186,25 +195,129 @@ def test_a_module_needs_a_head_and_attention_layers():
 # ----------------------------------------------------------------- the kernel
 
 
-@pytest.mark.parametrize("rows,length,d_inner,d_state", [
-    (2, 20, 256, 4), (2, 256, 2048, 16), (1, 300, 1024, 16), (3, 7, 64, 8), (1, 128, 1024, 16),
-], ids=["short", "two_chunks_two_tiles", "no_multiple_of_the_chunk", "narrower_than_the_lanes", "one_chunk"])
-def test_the_scan_kernel_agrees_with_a_token_by_token_scan(rows, length, d_inner, d_state):
+@pytest.mark.parametrize("rows,length,d_inner,d_state,x_dtype", [
+    (2, 20, 256, 4, jnp.float32), (2, 256, 2048, 16, jnp.float32), (1, 300, 1024, 16, jnp.float32), (3, 7, 64, 8, jnp.float32),
+    (1, 128, 1024, 16, jnp.float32), (1, 256, 5120, 16, jnp.float32), (2, 300, 2048, 16, jnp.bfloat16),
+], ids=["short", "two_chunks_two_tiles", "no_multiple_of_the_chunk", "narrower_than_the_lanes", "one_chunk", "the_cells_width", "bfloat16_x"])
+def test_the_scan_kernel_agrees_with_a_token_by_token_scan(rows, length, d_inner, d_state, x_dtype):
     """Interpret mode against ``lax.scan``: ``y`` at every token and the rows'
     final state, at lengths that are and are not multiples of the time chunk
-    (128) and widths of one and two channel tiles. Float32 on both sides: the
+    (128) or of a slab of 8 tokens and widths of one, two and the cell's five
+    channel tiles. Float32 on both sides (a bfloat16 ``x`` is widened on the
+    loaded registers, and the reference is held to the widened values): the
     sum over the states runs in another order, 1e-5 on values of magnitude 1."""
     args = scan_args(rows, length, d_inner, d_state)
+    args = (args[0].astype(x_dtype),) + args[1:]
     y, state = ss.selective_scan(*args)
-    want_y, want_state = ss.selective_scan_reference(*args)
-    assert y.shape == (rows, length, d_inner) and state.shape == (rows, d_state, d_inner) and state.dtype == jnp.float32
+    want_y, want_state = ss.selective_scan_reference(args[0].astype(jnp.float32), *args[1:])
+    assert y.shape == (rows, length, d_inner) and state.shape == (rows, d_state, d_inner)
+    assert y.dtype == jnp.float32 and state.dtype == jnp.float32
     assert np.abs(np.asarray(want_y)).max() > 0.3 and np.abs(np.asarray(want_state)).max() > 0.1
     np.testing.assert_allclose(np.asarray(y), np.asarray(want_y), atol=1e-5, rtol=0)
     np.testing.assert_allclose(np.asarray(state), np.asarray(want_state), atol=1e-6, rtol=0)
     plan = next(p for p in ss.ssm_scan_plans() if (p["length"], p["d_inner"], p["d_state"]) == (length, d_inner, d_state))
     assert plan["time_chunk"] == min(128, -(-length // 8) * 8) and plan["channel_tile"] == min(d_inner, 1024)
     assert plan["grid_steps"] == (d_inner // plan["channel_tile"]) * -(-length // plan["time_chunk"])
+    # x as it arrives, the step size and y float32, double-buffered; A and the state: a block's two buffers and a turned copy each
+    assert plan["vmem_bytes"] == (2 * plan["time_chunk"] * (jnp.dtype(x_dtype).itemsize + 8) + 6 * d_state * 4) * plan["channel_tile"]
     assert ss.ssm_scan_kernel_name(length, d_inner, d_state) == f"ssm_scan_l{length}_d{d_inner}_n{d_state}"
+
+
+# The scan as the program ran it until PR 47, kept as the plain function the kernel is held to **to the bit**: the
+# wrapper views ``[.., D]`` as ``[.., D / 128, 128]`` (on the chip: a physical copy of each stream) and the kernel
+# takes a token a loop trip off that view. The kernel of ``ops/selective_scan.py`` reads the rows as they are and
+# turns 8 tokens x 8 lane tiles on registers: the same operations on the same values in the same order.
+
+
+def _view4d_kernel(bc_ref, x_ref, dt_ref, a_ref, y_ref, state_ref, h_scr, *, d_state, chunk, length):
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _start():
+        h_scr[...] = jnp.zeros_like(h_scr)
+
+    def token(t, h):
+        dt = dt_ref[0, t]
+        dtx = dt * x_ref[0, t]
+        y = None
+        base = t * (2 * d_state)
+        new = []
+        for n in range(d_state):
+            h_n = jnp.exp(dt * a_ref[n]) * h[n] + dtx * bc_ref[base + n]
+            y_n = h_n * bc_ref[base + d_state + n]
+            y = y_n if y is None else y + y_n
+            new.append(h_n)
+        y_ref[0, t] = y
+        return tuple(new)
+
+    steps = chunk if length % chunk == 0 else jnp.minimum(chunk, length - j * chunk)
+    h = lax.fori_loop(0, steps, token, tuple(h_scr[n] for n in range(d_state)))
+    for n in range(d_state):
+        h_scr[n] = h[n]
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finish():
+        state_ref[0] = h_scr[...]
+
+
+@jax.jit
+def scan_over_the_4d_view(x, dt, b, c, a):
+    rows, length, d_inner = x.shape
+    d_state = b.shape[-1]
+    groups, sub, lanes = ss._tile_shape(d_inner)
+    chunk = min(ss.TIME_CHUNK, -(-length // 8) * 8)
+    n_chunks = -(-length // chunk)
+    f32 = jnp.float32
+    bc = jnp.concatenate([b.astype(f32), c.astype(f32)], axis=-1)
+    bc = jnp.pad(bc, ((0, 0), (0, n_chunks * chunk - length), (0, 0))).reshape(-1)
+    view = lambda t: t.astype(f32).reshape(*t.shape[:-1], groups, lanes)  # noqa: E731
+    stream = pl.BlockSpec((1, chunk, sub, lanes), lambda r, i, j: (r, j, i, 0))
+    y, state = pl.pallas_call(
+        functools.partial(_view4d_kernel, d_state=d_state, chunk=chunk, length=length),
+        grid=(rows, groups // sub, n_chunks),
+        in_specs=[pl.BlockSpec((chunk * 2 * d_state,), lambda r, i, j: (r * n_chunks + j,), memory_space=pltpu.SMEM),
+                  stream, stream, pl.BlockSpec((d_state, sub, lanes), lambda r, i, j: (0, i, 0))],
+        out_specs=[stream, pl.BlockSpec((1, d_state, sub, lanes), lambda r, i, j: (r, 0, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((rows, length, groups, lanes), f32), jax.ShapeDtypeStruct((rows, d_state, groups, lanes), f32)],
+        scratch_shapes=[pltpu.VMEM((d_state, sub, lanes), f32)],
+        interpret=True,
+    )(bc, view(x), view(dt), view(a))
+    return y.reshape(rows, length, d_inner), state.reshape(rows, d_state, d_inner)
+
+
+BIT_CASES = {  # lengths under a slab, with a partial slab, of whole chunks, and three chunks with a partial slab at the end
+    "7": (3, 7, 64, 8, "float32"), "20": (2, 20, 256, 4, "float32"), "256": (1, 256, 2048, 16, "bfloat16"), "300": (1, 300, 1024, 16, "float32"),
+}
+
+
+def same_bits_as_the_4d_view(rows, length, d_inner, d_state, x_dtype):
+    args = scan_args(rows, length, d_inner, d_state)
+    args = (args[0].astype(x_dtype),) + args[1:]
+    for got, want in zip(ss.selective_scan(*args), scan_over_the_4d_view(*args), strict=True):
+        np.testing.assert_array_equal(np.asarray(got).view(np.uint32), np.asarray(want).view(np.uint32))
+
+
+@pytest.fixture(scope="module")
+def bit_results():
+    """``BIT_CASES`` run by one child process (``python tests/test_jamba.py``) held to an instruction set without
+    FMA: XLA's CPU backend contracts ``a * b + c`` where its vectoriser pleases, so two interpret-mode programs of
+    the same arithmetic differ in the last bit by how their loops were cut (``tests/test_rotary_kernel.py``); without
+    the instruction both round every product, as the chip's vector unit does. ``{length: "ok" or a traceback}``."""
+    flags = f"{os.environ.get('XLA_FLAGS', '')} --xla_cpu_max_isa=AVX".strip()
+    env = dict(os.environ, XLA_FLAGS=flags, JAX_PLATFORMS="cpu", JAX_ENABLE_COMPILATION_CACHE="false")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env["PYTHONPATH"] = os.pathsep.join([root, env.get("PYTHONPATH", "")])
+    done = subprocess.run([sys.executable, os.path.abspath(__file__)], env=env, capture_output=True, text=True, timeout=900)
+    assert done.returncode == 0, done.stderr[-4000:]
+    return json.loads(done.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("length", sorted(BIT_CASES, key=int))
+def test_the_scan_kernel_is_the_scan_over_the_4d_view_to_the_bit(length, bit_results):
+    """``y`` and the final state of the kernel equal, bit for bit, what the
+    4-D-view formulation computes: moving the layout turn from HBM into VMEM
+    changed no operation, no operand and no order of a sum."""
+    assert bit_results[length] == "ok", bit_results[length]
 
 
 def test_the_state_carries_across_time_chunks():
@@ -296,3 +409,14 @@ def test_the_instrumented_generator_taps_the_state(tmp_path):
     assert compile_row["ssm_state_bytes"] == 3 * 2 * 4 * 128 * 4 and compile_row["ssm_conv_bytes"] == 3 * 2 * 3 * 128 * 4
     assert compile_row["kv_cache_full_bytes"] == 2 * 13 * 2 * 16 * 4
     assert isinstance(compile_row["ssm_scan"], list)  # the kernels' plans traced so far (none where the kernels are off)
+
+
+if __name__ == "__main__":  # the child of ``bit_results``
+    results = {}
+    for name, case in BIT_CASES.items():
+        try:
+            same_bits_as_the_4d_view(*case)
+            results[name] = "ok"
+        except Exception:  # reported to the parent's case of that name
+            results[name] = traceback.format_exc()[-3000:]
+    print(json.dumps(results))

@@ -534,10 +534,11 @@ def test_the_speculative_step_is_one_kernel_a_layer_over_row_major_caches(one_ch
 # ``KVCache`` / ``WindowKVCache`` / ``LatentCache`` paths and the prompt passes
 # lower to the parent's programs (``tools/step_hlo.py --same`` says the same of
 # the compiled modules). A PR that means to change one of these programs
-# updates its hash.
+# updates its hash: Jamba's is PR 47's own (the scan kernel reads and writes
+# the rows as the projections leave them); the other three stand since PR 44.
 PARENT_GENERATORS = {
     "mellum2-pp4-decode-b32": ("mellum", "08eadbcfdc5bc1c8749a61bdbc7da024abab0e97773459e0829fdfd8910eb692"),
-    "jamba2-3b-decode-b256": ("jamba", "d9553c32c5a52f3c44fc7c7f797aa3c4e4af11b2db6e73694f44fa39df3d3bf9"),
+    "jamba2-3b-decode-b256": ("jamba", "37c48df32f761ff881f8e7a99ecd629d39bf5825793aa83afac1ef2a1f1020cd"),
     "dsv3-ep16-decode-b64": ("deepseek_v3", "edac860ab3e61a0d6a6e7a1308f0ab55668b3895726f88303d7687bb98ed8ffb"),
     "longcat-ep32-decode-b64": ("longcat_flash", "9e78ee1b21106e65e0891d3d5ddc75aed1508da468a0eb9c439d10ebe413f936"),
 }
@@ -721,7 +722,8 @@ def test_the_jamba_cells_generator_carries_its_state_in_place(one_chip, mosaic, 
     """``jamba2-3b-decode-b256`` as the benchmark builds it (3.03B bfloat16
     parameters whole, 256 prompts of 256 tokens, 384 new tokens), compiled for a
     described v5e: under the 16.9 GB the runtime offers, the scan kernel in the
-    prompt pass (one geometry, 26 calls, none in the decode loop), and the
+    prompt pass (one geometry, 26 calls on ``bf16[16,256,5120]`` / ``f32[16,256,5120]``
+    operands as the projections leave them, none in the decode loop), and the
     decode loop carrying the 26 states ``[256, 16, 5120]`` **float32 and
     row-major**: nothing in its body turns, converts or slices into a state
     (what the compiler's memory-space assignment does with one, a
@@ -747,6 +749,21 @@ def test_the_jamba_cells_generator_carries_its_state_in_place(one_chip, mosaic, 
             assert ins.opcode not in ("copy", "transpose", "convert", "dynamic-update-slice"), ins.line[:300]
             assert not re.search(state + r"\{(?!2,1,0)", result(ins)), ins.line[:300]
     assert not re.search(r"bf16\[256,16,5120\]", text) and not re.search(r"f32\[65536,5120,16\]|f32\[256,256,5120,16\]", text)
+    # the scan kernel takes the rows as the projections leave them and writes ``y`` and the final state in the mixer's
+    # shapes (PR 47): the ``[.., 40, 128]`` view of a stream or of the state, a physical copy on the chip, exists nowhere,
+    # and under ``ssm/scan`` XLA produces no array of a stream's size (a convert of ``x``, a reshape of ``y``)
+    from perceiver_io_tpu.analysis.graph import parse_hlo_computations
+
+    assert not re.search(r"\[16,(?:256|16),40,128\]", text)
+    scan = [i for instructions in parse_hlo_computations(text).values() for i in instructions if "ssm/scan" in i.line]
+    assert sum(i.opcode == "custom-call" for i in scan) == 26
+    operands = r"operand_layout_constraints=\{f32\[131072\]\{0\}, bf16\[16,256,5120\]\{2,1,0\}, f32\[16,256,5120\]\{2,1,0\}, f32\[16,5120\]\{1,0\}\}"
+    results = r"\(f32\[16,256,5120\]\{2,1,0[:}].*?, f32\[16,16,5120\]\{2,1,0[:}]"
+    for ins in scan:
+        if ins.opcode == "custom-call":
+            assert ins.name.startswith("ssm_scan_l256_d5120_n16") and re.search(operands, ins.line) and re.match(results, result(ins)), ins.line[:400]
+        elif ins.opcode != "get-tuple-element":
+            assert _elements(result(ins)) < 16 * 256 * 5120, ins.line[:300]
 
 
 # ------------------------------------------ states alone: five float32 retention states updated in place by the step's kernel

@@ -12,8 +12,14 @@ every operation of the call is read from the capture, and every result is
 compared with the token-by-token ``lax.scan``'s.
 
 - ``program``: ``ops.selective_scan.selective_scan`` as the mixer calls it: the
-  recurrence alone in the kernel; the step size's bias and softplus, the skip
-  and the gate are XLA's fusions around it;
+  recurrence alone in the kernel, which reads ``x`` and the step size and
+  writes ``y`` as ``(rows, T, D)`` and turns 8 tokens x 8 lane tiles on
+  registers; the step size's bias and softplus, the skip and the gate are XLA's
+  fusions around it;
+- ``view4d``: the wrapper the program had until PR 47: the kernel addresses
+  ``[.., D / 128, 128]`` views, which on the chip are physical copies of ``x``
+  (widened), the step size and ``y`` around it (one call prints both sides'
+  kernel and XLA's share);
 - ``chunk64``, ``chunk256``: the same with another time chunk (``TIME_CHUNK``
   is 128);
 - ``dt_fused``: the bias, the softplus and the skip inside the kernel (it reads
@@ -29,7 +35,7 @@ updated in place as in the generator, as the one XLA fusion ``core/ssm.py``
 leaves it; its time a step against the state's bytes read and written once at
 the HBM peak (167.8 MB: 205 us) says whether a kernel could gain anything.
 
-PERF.md 6 (PR 41) has the readings; the program has no switch for the variants.
+PERF.md 6 (PR 41, PR 47) has the readings; the program has no switch for the variants.
 """
 
 from __future__ import annotations
@@ -53,35 +59,37 @@ from jax.experimental.pallas import tpu as pltpu
 from perceiver_io_tpu.ops import selective_scan as ss
 
 ROWS, LENGTH, D_INNER, D_STATE, BATCH = 16, 256, 5120, 16, 256
-SCAN_VARIANTS = ("program", "chunk64", "chunk256", "dt_fused", "gate_fused", "lax_scan")
+SCAN_VARIANTS = ("program", "view4d", "chunk64", "chunk256", "dt_fused", "gate_fused", "lax_scan")
 
 
 def _softplus(x):
     return jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
 
 
-def _variant_kernel(bc_ref, x_ref, pre_ref, *rest, d_state: int, chunk: int, fuse_gate: bool):
-    """``ops.selective_scan._scan_kernel`` with the step size's bias, softplus and skip inside, and the gate as a switch (whole chunks only)."""
+def _variant_kernel(bc_ref, x_ref, pre_ref, *rest, d_state: int, chunk: int, fuse_dt: bool, fuse_gate: bool):
+    """The scan over ``[.., D / 128, 128]`` views, a token a loop trip (whole chunks only): the recurrence alone
+    (``view4d``: ``pre_ref`` is the step size), or with the step size's bias, softplus and skip inside, and the gate as a switch."""
     z_ref, rest = (rest[0], rest[1:]) if fuse_gate else (None, rest)
-    a_ref, bias_ref, skip_ref, y_ref, state_ref, h_scr = rest
+    a_ref, *consts, y_ref, state_ref, h_scr = rest
     j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _start():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    bias, skip = bias_ref[...], skip_ref[...]
+    bias, skip = (ref[...] for ref in consts) if fuse_dt else (None, None)
 
     def token(t, h):
         x = x_ref[0, t]
-        dt = _softplus(pre_ref[0, t] + bias)
+        dt = _softplus(pre_ref[0, t] + bias) if fuse_dt else pre_ref[0, t]
         dtx = dt * x
-        y = skip * x
+        y = skip * x if fuse_dt else None
         base = t * (2 * d_state)
         new = []
         for n in range(d_state):
             h_n = jnp.exp(dt * a_ref[n]) * h[n] + dtx * bc_ref[base + n]
-            y = y + h_n * bc_ref[base + d_state + n]
+            y_n = h_n * bc_ref[base + d_state + n]
+            y = y_n if y is None else y + y_n
             new.append(h_n)
         if fuse_gate:
             z = z_ref[0, t].astype(jnp.float32)
@@ -98,7 +106,7 @@ def _variant_kernel(bc_ref, x_ref, pre_ref, *rest, d_state: int, chunk: int, fus
         state_ref[0] = h_scr[...]
 
 
-def _variant_scan(x, pre, b, c, z, a, dt_bias, d_skip, *, fuse_gate: bool):
+def _variant_scan(x, pre, b, c, z, a, dt_bias, d_skip, *, fuse_dt: bool = True, fuse_gate: bool = False):
     from perceiver_io_tpu.ops.flash_attention import _VMEM_LIMIT, _interpret_default
 
     rows, length, d_inner = x.shape
@@ -110,21 +118,22 @@ def _variant_scan(x, pre, b, c, z, a, dt_bias, d_skip, *, fuse_gate: bool):
     bc = jnp.concatenate([b.astype(f32), c.astype(f32)], axis=-1).reshape(-1)
     view = lambda t, dtype=f32: t.astype(dtype).reshape(*t.shape[:-1], groups, lanes)  # noqa: E731
     stream = pl.BlockSpec((1, chunk, sub, lanes), lambda r, i, j: (r, j, i, 0))
-    const = pl.BlockSpec((sub, lanes), lambda r, i, j: (i, 0))
+    consts = [view(dt_bias), view(d_skip)] if fuse_dt else []
     gate = [stream] if fuse_gate else []
     y, state = pl.pallas_call(
-        functools.partial(_variant_kernel, d_state=d_state, chunk=chunk, fuse_gate=fuse_gate),
-        name=f"ssm_scan_ab_dt1_gate{int(fuse_gate)}",
+        functools.partial(_variant_kernel, d_state=d_state, chunk=chunk, fuse_dt=fuse_dt, fuse_gate=fuse_gate),
+        name=f"ssm_scan_ab_dt{int(fuse_dt)}_gate{int(fuse_gate)}",
         grid=(rows, groups // sub, n_chunks),
         in_specs=[pl.BlockSpec((chunk * 2 * d_state,), lambda r, i, j: (r * n_chunks + j,), memory_space=pltpu.SMEM),
-                  stream, stream, *gate, pl.BlockSpec((d_state, sub, lanes), lambda r, i, j: (0, i, 0)), const, const],
+                  stream, stream, *gate, pl.BlockSpec((d_state, sub, lanes), lambda r, i, j: (0, i, 0)),
+                  *[pl.BlockSpec((sub, lanes), lambda r, i, j: (i, 0)) for _ in consts]],
         out_specs=[stream, pl.BlockSpec((1, d_state, sub, lanes), lambda r, i, j: (r, 0, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((rows, length, groups, lanes), jnp.bfloat16 if fuse_gate else f32),
                    jax.ShapeDtypeStruct((rows, d_state, groups, lanes), f32)],
         scratch_shapes=[pltpu.VMEM((d_state, sub, lanes), f32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret_default(),
-    )(bc, view(x), view(pre), *([view(z, z.dtype)] if fuse_gate else []), view(a), view(dt_bias), view(d_skip))
+    )(bc, view(x), view(pre), *([view(z, z.dtype)] if fuse_gate else []), view(a), *consts)
     return y.reshape(rows, length, d_inner), state.reshape(rows, d_state, d_inner)
 
 
@@ -147,9 +156,14 @@ def scan_variant(name: str):
             finally:
                 ss.TIME_CHUNK = kept
             return _tail(y, x, z, d_skip), h
+    elif name == "view4d":
+        def fn(x, pre, b, c, z, a, dt_bias, d_skip):
+            dt = jax.nn.softplus(pre + dt_bias.astype(jnp.float32))
+            y, h = _variant_scan(x, dt, b, c, z, a, dt_bias, d_skip, fuse_dt=False)
+            return _tail(y, x, z, d_skip), h
     elif name == "dt_fused":
         def fn(x, pre, b, c, z, a, dt_bias, d_skip):
-            y, h = _variant_scan(x, pre, b, c, z, a, dt_bias, d_skip, fuse_gate=False)
+            y, h = _variant_scan(x, pre, b, c, z, a, dt_bias, d_skip)
             return (y * jax.nn.silu(z.astype(jnp.float32))).astype(jnp.bfloat16), h
     elif name == "gate_fused":
         def fn(x, pre, b, c, z, a, dt_bias, d_skip):
@@ -236,11 +250,14 @@ def main():
     update_args = lambda: (jnp.zeros((bsz, n, d), f32), jax.nn.softplus(normal((steps, bsz, d), f32, 0.5, -4.0)),  # noqa: E731
                            normal((steps, bsz, d), f32, 0.5), normal((steps, bsz, n), f32), normal((steps, bsz, n), f32), a)
     want = [np.asarray(v, np.float32) for v in compiled.get("lax_scan", jax.jit(scan_variant("lax_scan")))(*scan_args)]
-    differ = {}
+    differ, results = {}, {}
     for name in args.variants:
         if name != "update":
-            got = [np.asarray(v, np.float32) for v in compiled[name](*scan_args)]
+            got = results[name] = [np.asarray(v, np.float32) for v in compiled[name](*scan_args)]
             differ[name] = [float(np.abs(g - w).max()) for g, w in zip(got, want)]
+    if "program" in results and "view4d" in results:  # the turn moved, the arithmetic did not
+        same = [bool((g == w).all()) for g, w in zip(results["program"], results["view4d"])]
+        print(f"program against view4d, to the bit: gated y {same[0]}, final state {same[1]}", flush=True)
 
     rounds = {name: [] for name in args.variants}
     for _ in range(args.rounds):
@@ -269,9 +286,10 @@ def main():
                   + ", ".join(f"{k} {v:.3f}" for k, v in sorted(ops.items(), key=lambda kv: -kv[1])[:4]))
             rows.append(dict(variant=name, ms_a_step=total / steps, floor_ms=floor, ops=ops))
             continue
-        rows.append(dict(variant=name, ms=total, ops=ops, differ=differ[name]))
+        kernel = sum(v for k, v in ops.items() if k.startswith("ssm_scan"))
+        rows.append(dict(variant=name, ms=total, kernel_ms=kernel, ops=ops, differ=differ[name]))
         top = ", ".join(f"{k} {v:.3f}" for k, v in sorted(ops.items(), key=lambda kv: -kv[1])[:5])
-        print(f"{name:<14} {total:8.3f} ms   differ {differ[name][0]:.2e} {differ[name][1]:.2e}   {top}")
+        print(f"{name:<14} {total:8.3f} ms = kernel {kernel:.3f} + XLA {total - kernel:.3f}   differ {differ[name][0]:.2e} {differ[name][1]:.2e}   {top}")
     if args.out:
         os.makedirs(args.out, exist_ok=True)
         with open(os.path.join(args.out, "ssm_scan_ab.json"), "w") as f:
