@@ -16,7 +16,8 @@ VMEM. One mask form covers every attention in the framework:
     ``causal=False`` disables the mask (Perceiver IO encoder/decoder).
 
 Key padding is an additive f32 bias row per batch (0 or ``MASK_VALUE``),
-streamed in kv blocks — O(B·Nkv) traffic, not O(Nq·Nkv).
+streamed in kv blocks — O(B·Nkv) traffic, not O(Nq·Nkv). A packed call with
+no pad mask and no padded keys has no such operand (``TilePlan.bias``).
 
 Training support is a ``jax.custom_vjp`` using the standard flash
 recomputation scheme: forward saves the row logsumexp; backward recomputes
@@ -24,7 +25,9 @@ probabilities blockwise from (q, k, lse). With several query blocks that is
 two kernels (dKV accumulating over query blocks, dQ over kv blocks), each
 rebuilding the scores; where the queries are one block (every call of the
 Perceiver train steps) it is one kernel that rebuilds each score tile once
-for dq, dk and dv (``_backward``).
+for dq, dk and dv (``_backward``). The packed forward likewise: the online
+softmax over several kv blocks, a plain one where the keys are one block
+(``_forward``).
 
 All shapes are static; inputs are padded to block multiples by the wrapper
 (padded kv slots are masked via the bias row, padded q rows are sliced off).
@@ -55,6 +58,10 @@ LANES = 128
 # visible causal tiles, narrower running-stat scratch) were measured on the
 # v5e and lost, each alone and all together (+0.5% to +3.9% step time); they
 # were deleted in PR 30 (table: docs/performance.md, "Round-3 ... REJECTED").
+# Those readings were of the batch-4 step and the kernels of before PR 27 and
+# PR 29. On today's kernels at batch 32 the bias row costs the packed calls 1
+# to 2%, so a call without one has no such operand, chosen from the call and
+# not by a flag (PR 48: the packed path's head comment).
 #
 # The one trace-time choice left is "paged": it routes the engine's paged
 # decode attention through the page-walk kernel (ops/paged_attention.py)
@@ -295,15 +302,28 @@ def _fwd_kernel(
 # ---------------------------------------------------------------------------
 
 
+def _scores(q, k, bias_row, keep, sm_scale):
+    """The masked score tile ``q k^T * sm_scale + bias_row`` under a
+    caller-built keep mask (None = no mask). ``bias_row`` None (a packed call
+    without a pad mask or padded keys) emits no add: Mosaic makes the bias
+    the product's accumulator, and an accumulator of zeros costs nothing
+    where the bias row costs the forward 1% and the backward 2% (PERF.md 6,
+    PR 48). The multiply is emitted whatever ``sm_scale`` is: Mosaic's
+    canonicalizer folds a multiply by one (read in its dump of the
+    1024 x 1024 forward)."""
+    s = _dot(q, k, ((1,), (1,)))
+    s = s * sm_scale
+    if bias_row is not None:
+        s = s + bias_row
+    if keep is not None:
+        s = jnp.where(keep, s, MASK_VALUE)
+    return s
+
+
 def _recompute_p_keep(q, k, bias_row, lse_col, keep, sm_scale):
     """Recompute the probability tile p = exp(s_masked - lse) from a
     caller-built keep mask (None = no mask)."""
-    s = _dot(q, k, ((1,), (1,)))
-    s = s * sm_scale
-    s = s + bias_row
-    if keep is not None:
-        s = jnp.where(keep, s, MASK_VALUE)
-    return jnp.exp(s - lse_col)
+    return jnp.exp(_scores(q, k, bias_row, keep, sm_scale) - lse_col)
 
 
 def _recompute_p(q, k, bias_row, lse_col, iq, ikv, block_q, block_kv, offset, sm_scale, apply_mask):
@@ -568,6 +588,19 @@ def _backward(num_q_blocks: int) -> str:
     return "one" if num_q_blocks == 1 else "split"
 
 
+def _forward(num_kv_blocks: int) -> str:
+    """Which forward a packed call runs, from its shapes alone. The online
+    softmax carries a running maximum, sum and accumulator from kv block to
+    kv block, and its cost goes by rows: statistics scratch loaded, rescaled
+    and stored by every band of every head, zero-filled before and read back
+    after. Where the keys are one block a row meets every key in one band and
+    none of that computes anything (``exp(-inf - m) = 0`` times zeros), so the
+    ``"plain"`` forward takes the softmax of the band and writes its rows of
+    the output and the logsumexp straight from it (PERF.md 6, PR 48); several
+    kv blocks keep the ``"online"`` one."""
+    return "plain" if num_kv_blocks == 1 else "online"
+
+
 def _flash_bwd(causal, offset, sm_scale, block_q, block_kv, num_heads, geom, residuals, g):
     block_q, block_kv = _bwd_blocks(block_q, block_kv)
     one = _backward(residuals[0].shape[1] // block_q) == "one"
@@ -800,20 +833,22 @@ class TilePlan(NamedTuple):
     tiles_masked: int  # of those run: in a band, or a whole grid tile, that the diagonal crosses
     tiles_skipped: int
     backward: str  # "one" kernel for dq, dk and dv, the "split" pair (``_backward``), or "none" (forward only)
+    forward: str = "online"  # "plain" softmax where the keys are one block, else the "online" one (``_forward``)
+    bias: bool = False  # the kernels take a bias row: the call has a pad mask, or its keys are padded to the block
 
     @property
     def run_share(self) -> float:
         return self.tiles_run / (self.tiles_run + self.tiles_skipped)
 
 
-def _make_plan(n_q: int, n_kv: int, causal: bool, block_q: int, block_kv: int) -> TilePlan:
+def _make_plan(n_q: int, n_kv: int, causal: bool, block_q: int, block_kv: int, pad_mask: bool = False) -> TilePlan:
     """The plan of a call at these grid blocks (what the kernels will do)."""
     nqb, nkvb = _round_up(n_q, block_q) // block_q, _round_up(n_kv, block_kv) // block_kv
-    backward = _backward(nqb * block_q // _bwd_blocks(block_q, block_kv)[0])
+    kernels = (_backward(nqb * block_q // _bwd_blocks(block_q, block_kv)[0]), _forward(nkvb), pad_mask or n_kv % block_kv != 0)
     unit = LANES * LANES
     total = nqb * nkvb * block_q * block_kv // unit
     if not causal:
-        return TilePlan(block_q, block_kv, 0, total, 0, 0, backward)
+        return TilePlan(block_q, block_kv, 0, total, 0, 0, *kernels)
     offset = n_kv - n_q
     cut = dict(_diagonals(causal, offset, block_q, block_kv, nqb, nkvb)["diagonals"])
     run = masked = 0
@@ -823,16 +858,17 @@ def _make_plan(n_q: int, n_kv: int, causal: bool, block_q: int, block_kv: int) -
         bands = cut.get(delta, ((0, block_q, block_kv, 0 if delta < block_kv - 1 else None),))
         run += _band_area(bands) // unit
         masked += _band_area([band for band in bands if band[3] is not None]) // unit
-    return TilePlan(block_q, block_kv, _BAND_ROWS if cut else 0, run, masked, total - run, backward)
+    return TilePlan(block_q, block_kv, _BAND_ROWS if cut else 0, run, masked, total - run, *kernels)
 
 
 def tile_plan(
     n_q: int, n_kv: int, causal: bool, block_q: Optional[int] = None, block_kv: Optional[int] = None,
-    window: Optional[int] = None,
+    window: Optional[int] = None, pad_mask: bool = False,
 ) -> TilePlan:
     """The tile plan of ``flash_attention_packed`` for a call of these
     lengths: a pure function of its arguments. ``block_q``/``block_kv`` are
-    the wrapper's (None = the tuned hint, a value = an upper bound).
+    the wrapper's (None = the tuned hint, a value = an upper bound);
+    ``pad_mask`` says whether the call has one (its ``bias``).
 
     With a ``window`` (query i sees ``i - window < j <= i``) it is the plan
     of :func:`flash_attention_gqa`, whose grid walks only the kv blocks a q
@@ -850,10 +886,10 @@ def tile_plan(
     # the kernel; the bands are not.
     bq = _choose_block(n_q, 1024 if block_q is None else block_q, exact=block_q is not None)
     bkv = _choose_block(n_kv, 2048 if block_kv is None else block_kv, exact=block_kv is not None)
-    return _make_plan(n_q, n_kv, causal, bq, bkv)
+    return _make_plan(n_q, n_kv, causal, bq, bkv, pad_mask)
 
 
-# plans of the calls traced in this process, by (geometry, causal): a
+# plans of the calls traced in this process, by (geometry, causal, bias): a
 # trace-time fact like the feature set, read by obs.recompile for the
 # ``compile`` event row (docs/observability.md)
 _TILE_PLANS: dict = {}
@@ -865,7 +901,7 @@ def tile_plans() -> list:
     return [
         {"geometry": geom, "causal": causal, "window": _window_of(geom), **plan._asdict(),
          "run_share": round(plan.run_share, 4)}
-        for (geom, causal), plan in sorted(_TILE_PLANS.items())
+        for (geom, causal, _), plan in sorted(_TILE_PLANS.items())
     ]
 
 
@@ -887,10 +923,33 @@ def _window_of(geom: str) -> Optional[int]:
 # cheap VMEM minor-dim slices. Head dims must be multiples of 8 (no per-head
 # zero padding is possible in a packed minor dim); other shapes use the
 # heads-major path.
+#
+# A kernel's band body is specialised at trace time by what its call
+# statically is, from shapes and operands alone (no flag; ``TilePlan`` and the
+# ``compile`` event row say what was chosen): the backward is one kernel where
+# the queries are one block (``_backward``); the forward is a plain softmax
+# with no statistics scratch where the keys are one block (``_forward``); and
+# the bias row is an operand of the forward and backward kernels only where
+# the call has a pad mask or padded keys (``has_bias``): Mosaic makes
+# ``s + bias`` the score product's accumulator, and on the v5e an accumulator
+# that holds a bias row costs a vector add a score where one of zeros costs
+# none (the 1024 x 8704 call: forward 9.63 -> 9.53 ms, backward 17.50 ->
+# 17.12; PERF.md 6, PR 48). A multiply by ``sm_scale`` = 1 (the call sites
+# scale the queries) needs no such care: Mosaic's canonicalizer folds it.
+
+
+def _split_bias(refs, has_bias: bool):
+    """``(bias_ref, the other refs)`` of a packed kernel: the bias row comes first, where the call has one."""
+    return (refs[0], refs[1:]) if has_bias else (None, refs)
+
+
+def _bias_row(bias_ref, width: int):
+    """The first ``width`` slots of a kv block's bias row, None for a call without one."""
+    return None if bias_ref is None else bias_ref[0, :, :width]
 
 
 def _fwd_packed_kernel(
-    *refs,  # bias, q, k, v, o, lse, m_scr, l_scr, acc_scr
+    *refs,  # [bias], q, k, v, o, lse, m_scr, l_scr, acc_scr
     causal: bool,
     offset: int,
     sm_scale: float,
@@ -898,14 +957,17 @@ def _fwd_packed_kernel(
     num_heads: int,
     d_qk: int,
     d_v: int,
+    has_bias: bool,
     diagonals: tuple = (),
     whole: bool = True,
 ):
-    # refs: bias (1, 1, block_kv) f32; q (1, block_q, h*d_qk);
+    # The online forward (``_forward``: several kv blocks). refs: bias
+    # (1, 1, block_kv) f32 where the call has one; q (1, block_q, h*d_qk);
     # k (1, block_kv, h*d_qk); v (1, block_kv, h*d_v); outs
     # o (1, block_q, h*d_v), lse (1, block_q, h*RES_LANES) f32; scratch
     # m/l (h, block_q, LANES) f32, acc (h, block_q, d_v)
-    bias_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
+    bias_ref, refs = _split_bias(refs, has_bias)
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     iq, ikv = pl.program_id(1), pl.program_id(2)
     h = num_heads
     block_q = q_ref.shape[1]
@@ -921,16 +983,12 @@ def _fwd_packed_kernel(
         # rows [r0, r1) of the q block against the kv block's first ``width``
         # slots. Per-head minor-dim slices: Mosaic supports static lane
         # slices but not the (block, h*d) -> (block, h, d) vector reshape
-        bias = bias_ref[0, :, :width]
+        bias = _bias_row(bias_ref, width)
         for hh in range(h):
             qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
             kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
             vh = v_ref[0, :width, hh * d_v : (hh + 1) * d_v]
-            s = _dot(qh, kh, ((1,), (1,)))
-            s = s * sm_scale
-            s = s + bias
-            if keep is not None:
-                s = jnp.where(keep, s, MASK_VALUE)
+            s = _scores(qh, kh, bias, keep, sm_scale)
             m_prev = m_scr[hh, r0:r1]
             l_prev = l_scr[hh, r0:r1]
             m_curr = jnp.max(s, axis=1)[:, None]
@@ -957,8 +1015,70 @@ def _fwd_packed_kernel(
             lse_ref[0, :, hh * RES_LANES : (hh + 1) * RES_LANES] = lse[:, :RES_LANES]
 
 
+def _fwd_plain_packed_kernel(
+    *refs,  # [bias], q, k, v, o, lse
+    causal: bool,
+    offset: int,
+    sm_scale: float,
+    num_heads: int,
+    d_qk: int,
+    d_v: int,
+    has_bias: bool,
+    diagonals: tuple = (),
+    whole: bool = True,
+):
+    # The plain forward (``_forward``: the call's keys are ONE block, grid
+    # (b, q blocks, 1)). refs as the online kernel's, without scratch: a row
+    # meets each of its keys in one band, so the band's own maximum and sum
+    # are the row's, and its rows of o and lse are written from it. The
+    # expressions are the online kernel's ``_store``, in its order, so both
+    # give the same o and lse (a running maximum of -inf and sums of zero drop
+    # out exactly; the sign of an exact zero of o is all that may differ).
+    # ``_store`` guards ``l == 0``, the sum of a q block no tile touched; a
+    # band's own sum holds exp(0) = 1 for the row's maximum, so it is at
+    # least 1 and the two selects would pick ``1 / l`` and ``l`` every time.
+    # The statistics are one column wide: Mosaic keeps a (rows, 1) value one
+    # row a sublane with the lanes replicated, so it costs the vregs a
+    # (rows, LANES) one does (a compare or select of it is 32 operations a
+    # band a head), and what is saved is the scratch traffic, the rescale and
+    # the second exponential, not lanes.
+    bias_ref, refs = _split_bias(refs, has_bias)
+    q_ref, k_ref, v_ref, o_ref, lse_ref = refs
+    iq = pl.program_id(1)
+    h = num_heads
+    block_q = q_ref.shape[1]
+    block_kv = k_ref.shape[1]
+
+    def _band(r0, r1, width, keep):
+        # rows [r0, r1) of the q block against the kv block's first ``width`` slots
+        bias = _bias_row(bias_ref, width)
+        for hh in range(h):
+            qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
+            kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
+            vh = v_ref[0, :width, hh * d_v : (hh + 1) * d_v]
+            s = _scores(qh, kh, bias, keep, sm_scale)
+            m = jnp.max(s, axis=1)[:, None]
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=1)[:, None]
+            o = _dot(p.astype(vh.dtype), vh, ((1,), (0,)))
+            o_ref[0, r0:r1, hh * d_v : (hh + 1) * d_v] = (o * (1.0 / l)).astype(o_ref.dtype)
+            lse = m + jnp.log(l)
+            lse_ref[0, r0:r1, hh * RES_LANES : (hh + 1) * RES_LANES] = jnp.broadcast_to(lse, (r1 - r0, RES_LANES))
+
+    _body = _tile_body(_band, iq, 0, block_q, block_kv, offset)
+    _causal_dispatch(_body, causal, iq, 0, block_q, block_kv, offset, diagonals, whole)
+
+    if causal and block_q - 1 + offset < 0:
+        # more queries than keys: a q block that sees no key at all is what the
+        # online kernel's untouched scratch stores, zeros and a logsumexp of -inf
+        @pl.when(jnp.logical_not(_block_visible(iq, 0, block_q, block_kv, offset)))
+        def _hidden():
+            o_ref[...] = jnp.zeros_like(o_ref)
+            lse_ref[...] = jnp.full_like(lse_ref, -jnp.inf)
+
+
 def _dkv_packed_kernel(
-    *refs,  # bias, q, k, v, do, lse, delta, dk, dv, dk_scr, dv_scr
+    *refs,  # [bias], q, k, v, do, lse, delta, dk, dv, dk_scr, dv_scr
     causal: bool,
     offset: int,
     sm_scale: float,
@@ -966,14 +1086,16 @@ def _dkv_packed_kernel(
     num_heads: int,
     d_qk: int,
     d_v: int,
+    has_bias: bool,
     diagonals: tuple = (),
     whole: bool = True,
 ):
-    # refs: bias (1, 1, block_kv); q (1, block_q, h*d_qk);
+    # refs: bias (1, 1, block_kv) where the call has one; q (1, block_q, h*d_qk);
     # k (1, block_kv, h*d_qk); v (1, block_kv, h*d_v); do (1, block_q, h*d_v);
     # lse/delta (1, block_q, h*RES_LANES); outs dk (1, block_kv, h*d_qk),
     # dv (1, block_kv, h*d_v); scratch dk/dv (h, block, d) f32
-    bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
+    bias_ref, refs = _split_bias(refs, has_bias)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr = refs
     ikv, iq = pl.program_id(1), pl.program_id(2)
     h = num_heads
     block_kv = k_ref.shape[1]
@@ -986,7 +1108,7 @@ def _dkv_packed_kernel(
 
     def _band(r0, r1, width, keep):
         # rows [r0, r1) of the q block against the kv block's first ``width`` slots
-        bias = bias_ref[0, :, :width]
+        bias = _bias_row(bias_ref, width)
         for hh in range(h):
             qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
             kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
@@ -1011,7 +1133,7 @@ def _dkv_packed_kernel(
 
 
 def _dq_packed_kernel(
-    *refs,  # bias, q, k, v, do, lse, delta, dq, dq_scr
+    *refs,  # [bias], q, k, v, do, lse, delta, dq, dq_scr
     causal: bool,
     offset: int,
     sm_scale: float,
@@ -1019,14 +1141,16 @@ def _dq_packed_kernel(
     num_heads: int,
     d_qk: int,
     d_v: int,
+    has_bias: bool,
     diagonals: tuple = (),
     whole: bool = True,
 ):
-    # refs: bias (1, 1, block_kv); q (1, block_q, h*d_qk);
+    # refs: bias (1, 1, block_kv) where the call has one; q (1, block_q, h*d_qk);
     # k (1, block_kv, h*d_qk); v (1, block_kv, h*d_v); do (1, block_q, h*d_v);
     # lse/delta (1, block_q, h*RES_LANES); out dq (1, block_q, h*d_qk);
     # scratch dq (h, block_q, d_qk) f32
-    bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs
+    bias_ref, refs = _split_bias(refs, has_bias)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr = refs
     iq, ikv = pl.program_id(1), pl.program_id(2)
     h = num_heads
     block_q = q_ref.shape[1]
@@ -1038,7 +1162,7 @@ def _dq_packed_kernel(
 
     def _band(r0, r1, width, keep):
         # rows [r0, r1) of the q block against the kv block's first ``width`` slots
-        bias = bias_ref[0, :, :width]
+        bias = _bias_row(bias_ref, width)
         for hh in range(h):
             qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
             kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
@@ -1061,7 +1185,7 @@ def _dq_packed_kernel(
 
 
 def _bwd_packed_kernel(
-    *refs,  # bias, q, k, v, do, lse, delta, dq, dk, dv, dq_scr, [dk_scr, dv_scr]
+    *refs,  # [bias], q, k, v, do, lse, delta, dq, dk, dv, dq_scr, [dk_scr, dv_scr]
     causal: bool,
     offset: int,
     sm_scale: float,
@@ -1069,6 +1193,7 @@ def _bwd_packed_kernel(
     num_heads: int,
     d_qk: int,
     d_v: int,
+    has_bias: bool,
     diagonals: tuple = (),
     whole: bool = True,
 ):
@@ -1081,7 +1206,8 @@ def _bwd_packed_kernel(
     # the call cuts no tile there is no such scratch and they are written
     # straight from the tile's one band (the 1024 x 8704 call ran 6.8% faster
     # without it: PERF.md 6, PR 29).
-    bias_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr, *kv_scr = refs
+    bias_ref, refs = _split_bias(refs, has_bias)
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, dq_scr, *kv_scr = refs
     ikv = pl.program_id(1)
     h = num_heads
     block_q = q_ref.shape[1]
@@ -1098,7 +1224,7 @@ def _bwd_packed_kernel(
 
     def _band(r0, r1, width, keep):
         # rows [r0, r1) of the q block against the kv block's first ``width`` slots
-        bias = bias_ref[0, :, :width]
+        bias = _bias_row(bias_ref, width)
         for hh in range(h):
             qh = q_ref[0, r0:r1, hh * d_qk : (hh + 1) * d_qk]
             kh = k_ref[0, :width, hh * d_qk : (hh + 1) * d_qk]
@@ -1142,12 +1268,29 @@ def _flash_packed(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h,
 
 
 def _flash_packed_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom):
+    plain = _forward(k.shape[1] // block_kv) == "plain"
+    return (_flash_packed_fwd_plain if plain else _flash_packed_fwd_online)(
+        q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom
+    )
+
+
+def _bias_spec(bias, block_kv: int, index_map) -> list:
+    """The ``in_specs`` entry of a packed kernel's bias row: none for a call without one."""
+    return [] if bias is None else [pl.BlockSpec((1, 1, block_kv), index_map)]
+
+
+def _with_bias(bias, operands: list) -> list:
+    """A packed kernel's operands: the bias row first, where the call has one."""
+    return operands if bias is None else [bias] + operands
+
+
+def _packed_fwd_call(kernel, scratch, q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom):
+    """Either packed forward: ``kernel`` over the grid (b, q blocks, kv blocks) with ``scratch``."""
     b, nq, _ = q.shape
     nkv = k.shape[1]
     grid = (b, nq // block_q, nkv // block_kv)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, block_kv), lambda b_, i, j: (b_, 0, j)),
+    in_specs = _bias_spec(bias, block_kv, lambda b_, i, j: (b_, 0, j)) + [
         pl.BlockSpec((1, block_q, h * d_qk), lambda b_, i, j: (b_, i, 0)),
         pl.BlockSpec((1, block_kv, h * d_qk), lambda b_, i, j: (b_, j, 0)),
         pl.BlockSpec((1, block_kv, h * d_v), lambda b_, i, j: (b_, j, 0)),
@@ -1155,14 +1298,14 @@ def _flash_packed_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, blo
 
     out, lse = pl.pallas_call(
         functools.partial(
-            _fwd_packed_kernel,
+            kernel,
             causal=causal,
             offset=offset,
             sm_scale=sm_scale,
-            num_kv_blocks=grid[2],
             num_heads=h,
             d_qk=d_qk,
             d_v=d_v,
+            has_bias=bias is not None,
             **_diagonals(causal, offset, block_q, block_kv, grid[1], grid[2]),
         ),
         name=_kernel_name("fwd", geom),
@@ -1176,24 +1319,42 @@ def _flash_packed_fwd_impl(q, k, v, bias, causal, offset, sm_scale, block_q, blo
             jax.ShapeDtypeStruct((b, nq, h * d_v), q.dtype),
             jax.ShapeDtypeStruct((b, nq, h * RES_LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((h, block_q, LANES), jnp.float32),
-            pltpu.VMEM((h, block_q, LANES), jnp.float32),
-            pltpu.VMEM((h, block_q, d_v), jnp.float32),
-        ],
+        scratch_shapes=scratch,
         compiler_params=_compiler_params("parallel", "parallel", "arbitrary"),
         interpret=_interpret_default(),
-    )(bias, q, k, v)
+    )(*_with_bias(bias, [q, k, v]))
     return out, lse
+
+
+def _flash_packed_fwd_online(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom):
+    """The forward of a call with several kv blocks: the online softmax over statistics scratch."""
+    scratch = [
+        pltpu.VMEM((h, block_q, LANES), jnp.float32),
+        pltpu.VMEM((h, block_q, LANES), jnp.float32),
+        pltpu.VMEM((h, block_q, d_v), jnp.float32),
+    ]
+    kernel = functools.partial(_fwd_packed_kernel, num_kv_blocks=k.shape[1] // block_kv)
+    return _packed_fwd_call(kernel, scratch, q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom)
+
+
+def _flash_packed_fwd_plain(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom):
+    """The forward of a call whose keys are one block: a plain softmax, no scratch."""
+    assert k.shape[1] == block_kv, (k.shape, block_kv)
+    return _packed_fwd_call(
+        _fwd_plain_packed_kernel, [], q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom
+    )
+
+
+def _slim_lse(lse, h: int):
+    """The residual form of a packed forward's lse: one lane per head (see the heads-major path note)."""
+    return lse.reshape(lse.shape[0], lse.shape[1], h, RES_LANES)[..., :1]
 
 
 def _flash_packed_fwd(q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom):
     out, lse = _flash_packed_fwd_impl(
         q, k, v, bias, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom
     )
-    # slim residual: one lane per head (see the heads-major path note)
-    lse_slim = lse.reshape(lse.shape[0], lse.shape[1], h, RES_LANES)[..., :1]
-    return out, (q, k, v, bias, out, lse_slim)
+    return out, (q, k, v, bias, out, _slim_lse(lse, h))
 
 
 def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom, residuals, g):
@@ -1205,8 +1366,8 @@ def _flash_packed_bwd(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v,
 
 
 def _packed_bwd_operands(residuals, g, h, d_v):
-    """The operands of the packed backward kernels: bias, q, k, v, do, lse,
-    delta, the last two as RES_LANES lanes per head."""
+    """The operands of the packed backward kernels: bias (where the call has
+    one), q, k, v, do, lse, delta, the last two as RES_LANES lanes per head."""
     q, k, v, bias, out, lse_slim = residuals
     b, nq, _ = q.shape
     lse = jnp.broadcast_to(lse_slim, (b, nq, h, RES_LANES)).reshape(b, nq, h * RES_LANES)
@@ -1215,12 +1376,12 @@ def _packed_bwd_operands(residuals, g, h, d_v):
     out4 = out.astype(jnp.float32).reshape(b, nq, h, d_v)
     delta = jnp.sum(g4 * out4, axis=-1)  # (b, nq, h)
     delta = jnp.broadcast_to(delta[..., None], (b, nq, h, RES_LANES)).reshape(b, nq, h * RES_LANES)
-    return [bias, q, k, v, g, lse, delta]
+    return _with_bias(bias, [q, k, v, g, lse, delta])
 
 
 def _flash_packed_bwd_one(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom, residuals, g):
     """The backward of a call whose queries are one block: one kernel."""
-    q, k, v = residuals[:3]
+    q, k, v, bias = residuals[:4]
     b, nq, _ = q.shape
     nkv = k.shape[1]
     assert nq == block_q, (nq, block_q)
@@ -1236,8 +1397,7 @@ def _flash_packed_bwd_one(causal, offset, sm_scale, block_q, block_kv, h, d_qk, 
         scratch += [pltpu.VMEM((h, block_kv, d_qk), jnp.float32), pltpu.VMEM((h, block_kv, d_v), jnp.float32)]
     row = lambda b_, j: (b_, 0, 0)  # the one q block of a batch row
     kv = lambda b_, j: (b_, j, 0)
-    in_specs = [
-        pl.BlockSpec((1, 1, block_kv), lambda b_, j: (b_, 0, j)),
+    in_specs = _bias_spec(bias, block_kv, lambda b_, j: (b_, 0, j)) + [
         pl.BlockSpec((1, block_q, h * d_qk), row),
         pl.BlockSpec((1, block_kv, h * d_qk), kv),
         pl.BlockSpec((1, block_kv, h * d_v), kv),
@@ -1255,6 +1415,7 @@ def _flash_packed_bwd_one(causal, offset, sm_scale, block_q, block_kv, h, d_qk, 
             num_heads=h,
             d_qk=d_qk,
             d_v=d_v,
+            has_bias=bias is not None,
             **cut,
         ),
         name=_kernel_name("bwd", geom),
@@ -1274,7 +1435,7 @@ def _flash_packed_bwd_one(causal, offset, sm_scale, block_q, block_kv, h, d_qk, 
         compiler_params=_compiler_params("parallel", "arbitrary"),
         interpret=_interpret_default(),
     )(*inputs)
-    return dq, dk, dv, jnp.zeros_like(residuals[3])
+    return dq, dk, dv, None if bias is None else jnp.zeros_like(bias)
 
 
 def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom, residuals, g):
@@ -1285,8 +1446,7 @@ def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk
     nqb, nkvb = nq // block_q, nkv // block_kv
     inputs = _packed_bwd_operands(residuals, g, h, d_v)
 
-    dkv_in_specs = [
-        pl.BlockSpec((1, 1, block_kv), lambda b_, j, i: (b_, 0, j)),
+    dkv_in_specs = _bias_spec(bias, block_kv, lambda b_, j, i: (b_, 0, j)) + [
         pl.BlockSpec((1, block_q, h * d_qk), lambda b_, j, i: (b_, i, 0)),
         pl.BlockSpec((1, block_kv, h * d_qk), lambda b_, j, i: (b_, j, 0)),
         pl.BlockSpec((1, block_kv, h * d_v), lambda b_, j, i: (b_, j, 0)),
@@ -1294,8 +1454,7 @@ def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk
         pl.BlockSpec((1, block_q, h * RES_LANES), lambda b_, j, i: (b_, i, 0)),
         pl.BlockSpec((1, block_q, h * RES_LANES), lambda b_, j, i: (b_, i, 0)),
     ]
-    dq_in_specs = [
-        pl.BlockSpec((1, 1, block_kv), lambda b_, i, j: (b_, 0, j)),
+    dq_in_specs = _bias_spec(bias, block_kv, lambda b_, i, j: (b_, 0, j)) + [
         pl.BlockSpec((1, block_q, h * d_qk), lambda b_, i, j: (b_, i, 0)),
         pl.BlockSpec((1, block_kv, h * d_qk), lambda b_, i, j: (b_, j, 0)),
         pl.BlockSpec((1, block_kv, h * d_v), lambda b_, i, j: (b_, j, 0)),
@@ -1314,6 +1473,7 @@ def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk
             num_heads=h,
             d_qk=d_qk,
             d_v=d_v,
+            has_bias=bias is not None,
             **_diagonals(causal, offset, block_q, block_kv, nqb, nkvb),
         ),
         name=_kernel_name("dkv", geom),
@@ -1345,6 +1505,7 @@ def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk
             num_heads=h,
             d_qk=d_qk,
             d_v=d_v,
+            has_bias=bias is not None,
             **_diagonals(causal, offset, block_q, block_kv, nqb, nkvb),
         ),
         name=_kernel_name("dq", geom),
@@ -1359,7 +1520,7 @@ def _flash_packed_bwd_split(causal, offset, sm_scale, block_q, block_kv, h, d_qk
         interpret=_interpret_default(),
     )(*inputs)
 
-    return dq, dk, dv, jnp.zeros_like(bias)
+    return dq, dk, dv, None if bias is None else jnp.zeros_like(bias)
 
 
 _flash_packed.defvjp(_flash_packed_fwd, _flash_packed_bwd)
@@ -1420,27 +1581,31 @@ def flash_attention_packed(
     offset = nkv - nq
 
     geom = _geometry(nq, nkv)
-    plan = _TILE_PLANS[(geom, causal)] = tile_plan(nq, nkv, causal, block_q, block_kv)
+    plan = tile_plan(nq, nkv, causal, block_q, block_kv, pad_mask=pad_mask is not None)
+    _TILE_PLANS[(geom, causal, plan.bias)] = plan
     block_q, block_kv = plan.block_q, plan.block_kv
 
     qf = _pad_to(q, 1, block_q)
     kf = _pad_to(k, 1, block_kv)
     vf = _pad_to(v, 1, block_kv)
 
-    # additive kv bias per batch row: padded slots + user pad mask
-    nkv_p = kf.shape[1]
-    bias = jnp.zeros((b, nkv_p), jnp.float32)
-    if pad_mask is not None:
-        bias = bias.at[:, :nkv].set(jnp.where(pad_mask, MASK_VALUE, 0.0))
-    if nkv_p != nkv:
-        bias = bias.at[:, nkv:].set(MASK_VALUE)
-    bias = bias[:, None, :]
+    # additive kv bias per batch row: padded slots + user pad mask; a call
+    # with neither has no bias operand (``plan.bias``)
+    biases = ()
+    if plan.bias:
+        nkv_p = kf.shape[1]
+        bias = jnp.zeros((b, nkv_p), jnp.float32)
+        if pad_mask is not None:
+            bias = bias.at[:, :nkv].set(jnp.where(pad_mask, MASK_VALUE, 0.0))
+        if nkv_p != nkv:
+            bias = bias.at[:, nkv:].set(MASK_VALUE)
+        biases = (bias[:, None, :],)
 
     out = _on_batch_shards(
-        lambda q_, k_, v_, bias_: _flash_packed_cached(
-            q_, k_, v_, bias_, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom
+        lambda q_, k_, v_, *bias_: _flash_packed_cached(
+            q_, k_, v_, bias_[0] if bias_ else None, causal, offset, sm_scale, block_q, block_kv, h, d_qk, d_v, geom
         ),
-        qf, kf, vf, bias,
+        qf, kf, vf, *biases,
     )
     return out[:, :nq, :]
 
@@ -1803,7 +1968,7 @@ def flash_attention_gqa(
     geom = _geometry(n, n) + ("" if window is None else f"_w{window}")
     n_pad = _round_up(n, block)
     reach = n_pad if window is None else window  # without a window every earlier block is seen
-    _TILE_PLANS[(geom, True)] = _make_window_plan(n, block, reach)
+    _TILE_PLANS[(geom, True, False)] = _make_window_plan(n, block, reach)
     # padded kv slots lie after every real query: the causal mask hides them
     qf = _pad_to(q, 1, block)
     kf = _pad_to(k.reshape(b * kv_heads, n, d), 1, block)
@@ -1918,7 +2083,7 @@ def flash_attention_mla(
     if n % block:
         raise ValueError(f"flash_attention_mla: {n} rows are not whole blocks of {block}")
     geom = _mla_geometry(n, num_heads)
-    _TILE_PLANS[(geom, True)] = _make_window_plan(n, block, n)
+    _TILE_PLANS[(geom, True, False)] = _make_window_plan(n, block, n)
     zeros = jnp.zeros_like(k_rope)
     # lane block 0 for the even heads, block 1 for the odd ones
     k_rope = jnp.concatenate([k_rope, zeros, zeros, k_rope], axis=-1).astype(kv.dtype)

@@ -231,6 +231,9 @@ def test_tile_plan(n_q, n_kv, causal, blocks, max_share):
         assert asked is None or got <= asked
     # the grid blocks are the ones of before PR 27: the program around the kernels does not change
     assert (plan.block_q, plan.block_kv) == _today(n_q, n_kv, *blocks)
+    # the forward and the bias operand follow from the lengths and the blocks alone (PR 48)
+    assert plan.forward == ("plain" if -(-n_kv // plan.block_kv) == 1 else "online")
+    assert plan.bias == (n_kv % plan.block_kv != 0) and tile_plan(n_q, n_kv, causal, *blocks, pad_mask=True).bias
     if max_share is None:
         # not worth cutting (or nothing to cut): every tile runs whole
         assert plan.band_rows == 0 and plan.tiles_skipped == 0 and (causal or plan.tiles_masked == 0)
@@ -262,19 +265,26 @@ def test_compile_event_carries_the_tile_plans():
     (kind, fields), = Sink.rows
     row = next(r for r in fields["flash_tiles"] if r["geometry"] == "q512_kv512" and r["causal"])
     assert kind == "compile" and row["block_q"] == 256 and row["tiles_skipped"] == 4 and row["run_share"] == 0.75
+    # two kv blocks of 256 and no pad mask: the online forward, no bias operand (PR 48)
+    assert (row["forward"], row["bias"], row["backward"]) == ("online", False, "split")
 
 
 # --- the backward (PR 29): one kernel where the queries are one block --------
 
 
-def _pallas_names(jaxpr, into):
-    """Names of the ``pallas_call`` equations under ``jaxpr``, nested calls included."""
+def _pallas_eqns(jaxpr, into):
+    """The ``pallas_call`` equations under ``jaxpr``, nested calls included."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            into.append(eqn.params["name"])
+            into.append(eqn)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            _pallas_names(sub, into)
+            _pallas_eqns(sub, into)
     return into
+
+
+def _pallas_names(jaxpr, into):
+    """Names of the ``pallas_call`` equations under ``jaxpr``, nested calls included."""
+    return into + [eqn.params["name"] for eqn in _pallas_eqns(jaxpr, [])]
 
 
 @pytest.mark.parametrize(
@@ -331,3 +341,132 @@ def test_backward_is_chosen_by_the_number_of_q_blocks():
     assert (row()["block_q"], row()["backward"]) == (128, "split")
     assert {p.backward for p in (tile_plan(1024, 8704, True), tile_plan(1024, 1024, True), tile_plan(512, 512, False))} == {"one"}
     assert tile_plan(2048, 2048, True).backward == "split"
+
+
+# --- the forward (PR 48): a plain softmax where the keys are one block, a bias operand only where the call has one ---
+
+
+def _forward_case(nq, nkv, h, d, causal, block_q, pad, dtype):
+    """Operands and statics of a call whose (padded) keys are one block, as the wrapper hands them to the
+    private forwards: ``pad`` adds a pad mask over the first three keys; keys short of a lane tile are padded
+    and masked by the bias row, which such a call keeps."""
+    rng = np.random.default_rng(nq + nkv + d)
+    q, k, v, w = (jnp.asarray(rng.normal(size=(B, n, h * d)), dtype) for n in (nq, nkv, nkv, nq))
+    block_kv = -(-nkv // 128) * 128
+    kf, vf = (fa._pad_to(x, 1, block_kv) for x in (k, v))
+    bias = None
+    if pad or block_kv != nkv:
+        bias = jnp.zeros((B, 1, block_kv), jnp.float32).at[:, :, nkv:].set(MASK_VALUE)
+        if pad:
+            bias = bias.at[:, :, :3].set(MASK_VALUE)
+    statics = (causal, nkv - nq, d**-0.5, block_q, block_kv, h, d, d, f"q{nq}_kv{nkv}")
+    return (q, kf, vf, bias), statics, w
+
+
+FORWARD_CASES = {
+    # the latent self-attention of the 16k step: one tile of 1024 x 1024 cut into four bands
+    "causal-banded-1024x1024": (1024, 1024, 2, 16, True, 1024, False),
+    # the image model's latent self-attention: no mask, heads of 128
+    "plain-512x512-d128": (512, 512, 2, 128, False, 512, False),
+    "padded-into-one-block-256x600": (256, 600, 2, 16, False, 256, True),
+    "causal-padded-256x600": (256, 600, 2, 16, True, 256, False),
+    "one-wide-head": (128, 256, 1, 136, False, 128, False),
+    "two-q-blocks-256x128": (256, 128, 2, 16, False, 128, False),
+    # more queries than keys: the first q blocks see no key at all
+    "causal-hidden-q-blocks-512x128": (512, 128, 2, 16, True, 128, False),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(FORWARD_CASES))
+def test_plain_forward_equals_the_online_forward(case, dtype):
+    """Same operands through both private forwards: with one kv block the
+    online form's running maximum starts at -inf and its sums at zero, so the
+    plain form's ``o`` and ``lse`` are the online form's exactly, and the
+    backward of either's residuals is the other's."""
+    operands, statics, w = _forward_case(*FORWARD_CASES[case], dtype)
+    h = statics[5]
+    plain = fa._flash_packed_fwd_plain(*operands, *statics)
+    online = fa._flash_packed_fwd_online(*operands, *statics)
+    for name, a, b in zip(("o", "lse"), plain, online):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        if "hidden" not in case:
+            assert bool(jnp.all(jnp.isfinite(a.astype(jnp.float32)))), name
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32), err_msg=name)
+    grads = []
+    for out, lse in (plain, online):
+        grads.append(fa._flash_packed_bwd(*statics, (*operands, out, fa._slim_lse(lse, h)), w))
+    for name, a, b in zip(("dq", "dk", "dv"), *grads):
+        if "hidden" not in case:
+            assert float(jnp.max(jnp.abs(b.astype(jnp.float32)))) > 0.05, name
+        np.testing.assert_array_equal(np.asarray(a, np.float32), np.asarray(b, np.float32), err_msg=name)
+    assert (grads[0][3] is None) == (operands[3] is None)
+
+
+@pytest.mark.parametrize(
+    "nq,nkv,causal,blocks,forward,backward",
+    [
+        (256, 256, True, (256, 256), "plain", "one"),
+        (256, 256, True, (128, 256), "plain", "split"),
+        (256, 512, True, (256, 256), "online", "one"),
+        (256, 512, False, (128, 256), "online", "split"),
+    ],
+    ids=["plain-one", "plain-split", "online-one", "online-split"],
+)
+def test_kernels_without_a_bias_equal_the_same_call_under_an_all_false_pad_mask(nq, nkv, causal, blocks, forward, backward):
+    """A call without a pad mask and with whole key blocks has no bias
+    operand in any of its kernels; ``s + 0`` is ``s``, so it returns what the
+    same call returns under a pad mask that hides nothing."""
+    h, d = 2, 16
+    rng = np.random.default_rng(nq + nkv)
+    q, k, v, w = (jnp.asarray(rng.normal(size=(B, n, h * d)), jnp.float32) for n in (nq, nkv, nkv, nq))
+    plan = tile_plan(nq, nkv, causal, *blocks)
+    assert (plan.forward, plan.backward, plan.bias) == (forward, backward, False)
+
+    def run(pad_mask):
+        def attn(q_, k_, v_):
+            return flash_attention_packed(
+                q_, k_, v_, num_heads=h, pad_mask=pad_mask, causal=causal, sm_scale=d**-0.5, block_q=blocks[0], block_kv=blocks[1]
+            )
+
+        out, vjp = jax.vjp(attn, q, k, v)
+        return (out,) + vjp(w)
+
+    bare, masked = run(None), run(jnp.zeros((B, nkv), bool))
+    for name, a, b in zip(("o", "dq", "dk", "dv"), bare, masked):
+        assert float(jnp.max(jnp.abs(b))) > 0.05, name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+
+
+def test_forward_is_chosen_by_the_number_of_kv_blocks():
+    """One kv block runs the plain forward (no scratch), two keep the online
+    one (m, l and acc); the bias row is an operand only under a pad mask or
+    padded keys. Nothing but the shapes and the operands decides; the plan
+    rows say which."""
+    assert (fa._forward(1), fa._forward(2), fa._forward(7)) == ("plain", "online", "online")
+    x = jnp.zeros((1, 256, 16), jnp.float32)
+
+    def fwd(block_kv, pad_mask=None, n_kv=256):
+        kv = jnp.zeros((1, n_kv, 16), jnp.float32)
+        def attn(q, k, v):
+            return flash_attention_packed(q, k, v, num_heads=2, pad_mask=pad_mask, causal=True, block_q=256, block_kv=block_kv)
+
+        (eqn,) = _pallas_eqns(jax.make_jaxpr(attn)(x, kv, kv).jaxpr, [])  # the forward alone: nothing is differentiated
+        return eqn.params["grid_mapping"].num_scratch_operands, len(eqn.invars)
+
+    def row(geometry="q256_kv256"):
+        rows = [r for r in fa.tile_plans() if r["geometry"] == geometry and r["causal"]]
+        return [(r["forward"], r["bias"]) for r in rows]
+
+    fa._TILE_PLANS.clear()
+    assert fwd(256) == (0, 3) and row() == [("plain", False)]
+    assert fwd(128) == (3, 3) and row() == [("online", False)]
+    mask = jnp.zeros((1, 256), bool)
+    assert fwd(256, mask) == (0, 4) and fwd(128, mask) == (3, 4)
+    # a call with a pad mask and one without are two calls: two rows
+    assert row() == [("online", False), ("online", True)]
+    # 200 keys are padded to two blocks of 128: online, and the bias row masks the padding
+    assert fwd(256, n_kv=200) == (3, 4) and row("q256_kv200") == [("online", True)]
+    assert {(p.forward, p.bias) for p in (tile_plan(1024, 1024, True), tile_plan(512, 512, False))} == {("plain", False)}
+    assert (tile_plan(1024, 8704, True).forward, tile_plan(768, 768, True).forward) == ("online", "online")
+    assert tile_plan(768, 768, True).bias and not tile_plan(768, 16128, True).bias and tile_plan(768, 16128, True, pad_mask=True).bias

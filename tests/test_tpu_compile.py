@@ -97,6 +97,47 @@ def test_flash_attention_packed_fwd_bwd(one_chip, mosaic, n_kv):
     assert text.count("tpu_custom_call") >= 2 and f"flash_bwd_q{LATENTS}_kv{n_kv}" in text
 
 
+def _kernel_arguments(lowered_text: str, name: str) -> dict:
+    """How many arguments of the Mosaic kernel ``name``'s body are the call's
+    inputs and how many its scratch (by the serialized module's own
+    attributes), and the operands of its custom call."""
+    import base64
+    import re
+
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    (call,) = [line for line in lowered_text.splitlines() if "tpu_custom_call" in line and f'kernel_name = "{name}"' in line]
+    body = re.search(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', call).group(1)
+    with ir.Context() as ctx:
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        module = ir.Module.parse(base64.b64decode(body))
+        func = module.body.operations[0]  # ``main``; the index maps follow it as ``transform_<i>``
+        return {
+            "arguments": len(func.regions[0].blocks[0].arguments),
+            "scratch": ir.IntegerAttr(func.attributes["scratch_operands"]).value,
+            "grid": len(ir.DenseI64ArrayAttr(func.attributes["iteration_bounds"])),
+            "operands": len(re.search(r"stablehlo\.custom_call @tpu_custom_call\(([^)]*)\)", call).group(1).split(",")),
+        }
+
+
+@pytest.mark.parametrize("n_kv,scratch", [(1024, 0), (8704, 3)], ids=["self_1024", "cross_8704"])
+def test_the_packed_forward_takes_the_scratch_and_the_bias_its_call_needs(one_chip, mosaic, n_kv, scratch):
+    """PR 48, at the 16k step's two calls: the latent self-attention's keys
+    are one block, so its forward is the plain softmax with no statistics
+    scratch; the cross-attention's four kv blocks keep the online one's m, l
+    and acc. Neither call has a pad mask or padded keys, so neither kernel
+    has a bias operand: q, k and v in, o and lse out. (That Mosaic compiles
+    both kernels is ``test_flash_attention_packed_fwd_bwd``'s to hold.)"""
+    q = jax.ShapeDtypeStruct((TRAIN_CHUNK, LATENTS, CHANNELS), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((TRAIN_CHUNK, n_kv, CHANNELS), jnp.bfloat16, sharding=one_chip)
+    with jax.default_matmul_precision("default"):
+        lowered = jax.jit(lambda q, k, v: fa.flash_attention_packed(q, k, v, HEADS, causal=True, sm_scale=1.0)).lower(q, kv, kv)
+    kernel = _kernel_arguments(lowered.as_text(), f"flash_fwd_q{LATENTS}_kv{n_kv}")
+    assert kernel == {"arguments": 3 + 3 + 2 + scratch, "scratch": scratch, "grid": 3, "operands": 3}
+
+
 def test_flash_attention_image_cross_fwd_bwd(one_chip, mosaic):
     """The image model's cross-attention (512 latents over 224 x 224 pixels,
     one head of 261 channels, padded to 264): the heads-major forward and its
@@ -344,10 +385,12 @@ def _canonical(text: str):
 # ``tile_plan`` a window and the file a windowed forward, and a call without
 # one lowers to the parent's program (the four accepted cells' whole programs
 # were compared the same way before any chip time: PERF.md 6, PR 32). A PR
-# that means to change these kernels updates the hashes.
+# that means to change these kernels updates the hashes: the packed call's is
+# PR 48's own (2176 keys are one block and the call has no pad mask: the plain
+# forward, no bias operand in either kernel); the heads-major one stands.
 UNWINDOWED_GOLDEN = {
     "heads_major_causal": "fa11ab7986d9763ab6888566fb0ec5e8eb3db4dfbe0539feb8d1760a20997966",
-    "packed_causal_fwd_bwd": "5f0174ba637f05b3a4129548c87440e98f775340c1be894d1a535740f9a0c42e",
+    "packed_causal_fwd_bwd": "9da2911f9ca060194c9e42a8a7f811070d2a3ba682d48d76a99d7dfecd00c80d",
 }
 
 

@@ -1,4 +1,4 @@
-"""Same-process A/B of tile plans, and of the two backwards, of the flash kernels.
+"""Same-process A/B of tile plans, of the two backwards and of the two forwards of the flash kernels.
 
 One process builds every variant (grid blocks, and bands inside a tile on
 the diagonal) of one attention geometry, runs them round-robin, each round
@@ -23,6 +23,17 @@ chip) and runs nothing. PERF.md 6 (PR 27) has the readings that set
 on the same residuals: ``split`` (dkv, then dq) against ``one`` kernel, each
 through its private function (``_flash[_packed]_bwd_split`` / ``_bwd_one``; the
 program picks between them by shape and has no switch). PERF.md 6 (PR 29).
+
+    python tools/tile_plan_ab.py --geom sa ca img_sa prompt --forward
+
+``--forward`` times the two packed forwards on the same operands, each with
+and without a bias row: ``plain`` (a call whose keys are one block: ``sa``,
+``img_sa``) against ``online``, through ``_flash_packed_fwd_plain`` /
+``_fwd_online``, and the backward the program picks with and without the bias
+operand. A call of several kv blocks (``ca``, ``prompt``) has no plain form
+and gets the bias comparison alone. ``sm_scale`` is 1 as in the program (the
+queries arrive scaled). The program decides both from the call's shapes and
+operands (``tile_plan(...).forward`` / ``.bias``). PERF.md 6 (PR 48).
 """
 
 from __future__ import annotations
@@ -59,6 +70,14 @@ GEOMS = {
 BACKWARDS = ("split", "one")
 
 
+def forwards(geom: dict) -> list:
+    """The ``--forward`` variants of a geometry, the program's own first."""
+    plan = fa.tile_plan(geom["nq"], geom["nkv"], geom.get("causal", True))
+    forms = ["plain", "online"] if plan.forward == "plain" else ["online"]
+    variants = [form + bias for form in forms for bias in ("", "+bias")]
+    return sorted(variants, key=lambda v: v != plan.forward + ("+bias" if plan.bias else ""))
+
+
 def parse_variant(text: str):
     """``512x512/256`` -> (512, 512, 256); ``plan`` -> None blocks."""
     if text == "plan":
@@ -86,7 +105,7 @@ def build(geom: dict, variant: str, sharding=None):
     args = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=sharding) for s in shapes]
     chosen = fa.tile_plan, fa._BAND_ROWS, fa._BAND_MAX_SHARE
     if bq is not None:
-        fa.tile_plan = lambda n_q, n_kv, causal, *_: fa._make_plan(n_q, n_kv, causal, bq, bkv)
+        fa.tile_plan = lambda n_q, n_kv, causal, *_, pad_mask=False: fa._make_plan(n_q, n_kv, causal, bq, bkv, pad_mask)
         fa._BAND_ROWS, fa._BAND_MAX_SHARE = (band, 1.0) if band else (chosen[1], 0.0)
     try:
         with jax.default_matmul_precision("default"):
@@ -119,6 +138,36 @@ def build_backward(geom: dict, which: str, sharding=None):
         return jax.jit(fwd_bwd).lower(*args), plan
 
 
+def build_forward(geom: dict, variant: str, sharding=None):
+    """The jitted ``variant`` forward (``plain`` / ``online``, ``+bias`` for a
+    bias row) of one call on the operands its wrapper makes (the plan's
+    blocks, keys padded to them), and the backward the program picks for it."""
+    b, nq, nkv, h, d = (geom[key] for key in ("b", "nq", "nkv", "h", "d"))
+    causal = geom.get("causal", True)
+    form, _, with_bias = variant.partition("+")
+    plan = fa.tile_plan(nq, nkv, causal)
+    # without its bias row a call with padded keys is sound only where the causal mask hides the padding
+    assert with_bias or causal or nkv % plan.block_kv == 0, "padded keys need their bias row"
+    statics = (causal, nkv - nq, 1.0, plan.block_q, plan.block_kv, h, d, d, fa._geometry(nq, nkv))
+    forward = getattr(fa, f"_flash_packed_fwd_{form}")
+
+    def fwd_bwd(q, k, v, w):
+        qf, wf = (fa._pad_to(x, 1, plan.block_q) for x in (q, w))
+        kf, vf = (fa._pad_to(x, 1, plan.block_kv) for x in (k, v))
+        bias = None
+        if with_bias:
+            bias = jnp.zeros((b, 1, kf.shape[1]), jnp.float32).at[:, :, nkv:].set(fa.MASK_VALUE)
+        out, lse = forward(qf, kf, vf, bias, *statics)
+        if not geom["bwd"]:
+            return (out[:, :nq],)
+        dq, dk, dv, _ = fa._flash_packed_bwd(*statics, (qf, kf, vf, bias, out, fa._slim_lse(lse, h)), wf)
+        return out[:, :nq], dq[:, :nq], dk[:, :nkv], dv[:, :nkv]
+
+    args = [jax.ShapeDtypeStruct((b, n, h * d), jnp.bfloat16, sharding=sharding) for n in (nq, nkv, nkv, nq)]
+    with jax.default_matmul_precision("default"):
+        return jax.jit(fwd_bwd).lower(*args), plan
+
+
 def flash_ms(trace_dir: str) -> dict:
     """Device ms per flash pass (``fwd``, ``dq``, ``dkv``, ``bwd``) in one capture."""
     from perceiver_io_tpu.obs.xplane import load_capture
@@ -137,14 +186,16 @@ def main():
     p.add_argument("--geom", choices=sorted(GEOMS), nargs="+", required=True)
     p.add_argument("--variants", nargs="+", help="tile plans to compare (not with --backward)")
     p.add_argument("--backward", action="store_true", help="compare the split backward with the one-kernel one")
+    p.add_argument("--forward", action="store_true", help="compare the plain forward with the online one, with and without a bias row")
     p.add_argument("--calls", type=int, default=8)
     p.add_argument("--rounds", type=int, default=3)
     p.add_argument("--compile-only", action="store_true")
     p.add_argument("--out", default=None, help="write the table as JSON here")
     args = p.parse_args()
-    if args.backward == bool(args.variants):
-        p.error("give --variants or --backward")
-    variants = list(BACKWARDS) if args.backward else args.variants
+    if args.backward + args.forward + bool(args.variants) != 1:
+        p.error("give --variants, --backward or --forward")
+    if args.forward and not all(GEOMS[name].get("packed", True) for name in args.geom):
+        p.error("--forward compares the packed forwards: img_ca is heads-major")
 
     sharding = None
     if args.compile_only:
@@ -160,6 +211,7 @@ def main():
 
     rows = []
     for name in args.geom:
+        variants = list(BACKWARDS) if args.backward else forwards(GEOMS[name]) if args.forward else args.variants
         rows += run_geom(name, variants, args, sharding)
     if args.out and rows:
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
@@ -171,7 +223,7 @@ def run_geom(name: str, variants: list, args, sharding) -> list:
     geom = GEOMS[name]
     lowered = {}
     for variant in variants:
-        lowered[variant] = (build_backward if args.backward else build)(geom, variant, sharding)
+        lowered[variant] = (build_backward if args.backward else build_forward if args.forward else build)(geom, variant, sharding)
         print(f"{name} {variant}: {lowered[variant][1]}", flush=True)
 
     def compile_one(variant):
@@ -210,7 +262,7 @@ def run_geom(name: str, variants: list, args, sharding) -> list:
             times[variant].append({k: v / args.calls for k, v in ms.items()})
 
     rows = []
-    passes = ["fwd"] + (["dq", "dkv"] + (["bwd"] if args.backward else []) if geom["bwd"] else [])
+    passes = ["fwd"] + ((["bwd"] if args.forward else ["dq", "dkv"] + (["bwd"] if args.backward else [])) if geom["bwd"] else [])
     print(f"\n{name} {geom}: device ms a call, median of {args.rounds} rounds of {args.calls} calls")
     head = " ".join(f"{p_:>8}" for p_ in passes)
     print(f"{'variant':<28} {head} {'sum':>8}  run_share  gap to first (out, dq, dk, dv)")
