@@ -491,6 +491,31 @@ def init_retention_state(batch_size: int, kv_heads: int, feature_rows: int, head
                           length=jnp.zeros((), jnp.int32))
 
 
+@struct.dataclass
+class DeltaState:
+    """What a Kimi delta attention layer (``core/kda.py``) keeps of a row's
+    past, of one size whatever the context: ``s`` (B, H, D_v, D_k) float32, a
+    head's delta-rule state **stored transposed** (the value's channel on the
+    rows, the key's on the lanes: ``ops/kda.py``), and the last ``K - 1`` inputs
+    of the three causal convolutions, ``conv_q``, ``conv_k``, ``conv_v`` (B, K -
+    1, H * D) each, oldest first. No slots and **no length**: the layer reads no
+    position, and a stack that mixes it with attention reads a step's position
+    off the attention's cache. A step reads and writes ``s`` whole, in place."""
+
+    s: jnp.ndarray
+    conv_q: jnp.ndarray
+    conv_k: jnp.ndarray
+    conv_v: jnp.ndarray
+
+
+def init_delta_state(batch_size: int, heads: int, head_dim: int, d_conv: int, dtype=jnp.float32) -> DeltaState:
+    """The state before a row's first token: nothing written, windows of zeros
+    (the convolutions pad with zeros). ``dtype`` is the windows'; the state is float32."""
+    window = jnp.zeros((batch_size, d_conv - 1, heads * head_dim), dtype)
+    return DeltaState(s=jnp.zeros((batch_size, heads, head_dim, head_dim), jnp.float32),
+                      conv_q=window, conv_k=window, conv_v=window)
+
+
 # ---------------------------------------------------------------------------
 # window discipline
 # ---------------------------------------------------------------------------

@@ -45,6 +45,13 @@ and ``mla_scale_kv_lora`` ``c_kv = RMSNorm(.) * sqrt(hidden / kv_lora_rank)``
 (``k_rope`` is not scaled). The cache's rows hold the scaled ``c_kv``: both
 paths read it from :meth:`_latent_rows`, so the absorbed step agrees with the
 expanded pass by construction. ``rope_scaling`` ``None`` is plain rotary.
+
+Two switches of the Ling 3.0 family: ``q_lora_rank`` ``None`` takes the queries
+straight from the hidden state (``q = x W_uq`` with ``W_uq`` ``hidden`` rows
+tall: no ``w_dq``, no ``q_norm``), and ``mla_head_gate`` multiplies every
+head's attended values by one gate, ``o_h <- o_h * sigmoid(x w_gate)_h``
+(``w_gate`` ``hidden`` x heads, the sigmoid float32), before ``W_o``, on both
+paths.
 """
 
 from __future__ import annotations
@@ -67,12 +74,12 @@ from perceiver_io_tpu.ops.rotary import rotary_angles, rotate_packed
 
 class MultiHeadLatentAttention(nn.Module):
     """``config`` needs: ``hidden_size``, ``num_attention_heads``,
-    ``q_lora_rank``, ``kv_lora_rank``, ``qk_nope_head_dim``,
+    ``q_lora_rank`` (``None``: no query latent), ``kv_lora_rank``, ``qk_nope_head_dim``,
     ``qk_rope_head_dim``, ``v_head_dim``, ``rms_norm_eps``, ``rope_theta``,
     ``rope_scaling`` (``None`` or an object with YaRN's ``factor``,
     ``beta_fast``, ``beta_slow``, ``mscale``, ``mscale_all_dim``,
     ``original_max_position_embeddings``), ``mla_scale_q_lora``,
-    ``mla_scale_kv_lora`` and ``init_scale``."""
+    ``mla_scale_kv_lora``, ``mla_head_gate`` and ``init_scale``."""
 
     config: object
     dtype: jnp.dtype = jnp.float32
@@ -83,16 +90,19 @@ class MultiHeadLatentAttention(nn.Module):
         heads = c.num_attention_heads
         init = nn.initializers.normal(c.init_scale)
         norm = dict(epsilon=c.rms_norm_eps, dtype=self.dtype, param_dtype=self.param_dtype)
-        self.w_dq = self.param("w_dq", init, (c.hidden_size, c.q_lora_rank), self.param_dtype)
-        self.q_norm = RMSNorm(**norm)
+        if c.q_lora_rank is not None:
+            self.w_dq = self.param("w_dq", init, (c.hidden_size, c.q_lora_rank), self.param_dtype)
+            self.q_norm = RMSNorm(**norm)
         self.w_uq = self.param(
-            "w_uq", init, (c.q_lora_rank, heads * (c.qk_nope_head_dim + c.qk_rope_head_dim)), self.param_dtype
+            "w_uq", init, (query_rank(c), heads * (c.qk_nope_head_dim + c.qk_rope_head_dim)), self.param_dtype
         )
         self.w_dkv = self.param("w_dkv", init, (c.hidden_size, c.kv_lora_rank + c.qk_rope_head_dim), self.param_dtype)
         self.kv_norm = RMSNorm(**norm)
         self.w_ukv = self.param(
             "w_ukv", init, (c.kv_lora_rank, heads * (c.qk_nope_head_dim + c.v_head_dim)), self.param_dtype
         )
+        if c.mla_head_gate:
+            self.w_gate = self.param("w_gate", init, (c.hidden_size, heads), self.param_dtype)
         self.w_o = self.param("w_o", init, (heads * c.v_head_dim, c.hidden_size), self.param_dtype)
 
     # ------------------------------------------------------------ shared
@@ -121,8 +131,19 @@ class MultiHeadLatentAttention(nn.Module):
         return latent * (self.config.hidden_size / rank) ** 0.5 if on else latent
 
     def _c_q(self, x):
+        """What ``w_uq`` multiplies: the normed query latent, or the hidden state itself where there is none."""
         c = self.config
+        if c.q_lora_rank is None:
+            return x.astype(self.dtype)
         return self._scaled(self.q_norm(self._mm(x, self.w_dq)), c.q_lora_rank, c.mla_scale_q_lora)
+
+    def _project_out(self, o, x):
+        """``o`` (B, N, H * v) through the head-wise gate, where the configuration has one, and ``W_o``."""
+        c = self.config
+        if c.mla_head_gate:
+            gate = jax.nn.sigmoid(jnp.dot(x.astype(self.dtype), self.w_gate.astype(self.dtype), preferred_element_type=jnp.float32))
+            o = (o.reshape(*o.shape[:-1], c.num_attention_heads, c.v_head_dim) * gate[..., None]).reshape(o.shape).astype(self.dtype)
+        return self._mm(o, self.w_o)
 
     def _queries(self, x, pos) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """``x`` (B, N, h), ``pos`` (B, N) -> ``q_nope`` (B, N, H, nope) and
@@ -181,7 +202,7 @@ class MultiHeadLatentAttention(nn.Module):
                 rows = self._latent_rows(x, pos)
                 kv = self._mm(rows[..., : c.kv_lora_rank], self.w_ukv)
                 o = flash_attention_mla(q_nope, q_rope, kv, rows[..., c.kv_lora_rank:], heads, sm_scale=self.sm_scale)
-                return self._mm(o, self.w_o), rows
+                return self._project_out(o, x), rows
             q_nope, q_rope = self._queries(x, pos)
             rows = self._latent_rows(x, pos)
             kv = jnp.einsum("bnc,chd->bnhd", rows[..., : c.kv_lora_rank], self._w_ukv())
@@ -199,7 +220,7 @@ class MultiHeadLatentAttention(nn.Module):
                 p = jax.nn.softmax(jnp.where(visible[None, None], s, -jnp.inf), axis=-1)
                 o = jnp.einsum("bhij,bhjc->bhic", p.astype(v.dtype), v)
             o = o.transpose(0, 2, 1, 3).reshape(b, n, heads * c.v_head_dim)
-            return self._mm(o, self.w_o), rows
+            return self._project_out(o, x), rows
 
     # ---------------------------------------------------------- absorbed
 
@@ -229,16 +250,21 @@ class MultiHeadLatentAttention(nn.Module):
             else:
                 o_lat = latent_decode_attention(q_cat, cache, self.sm_scale)[..., :rank]
             o = jnp.einsum("bhc,chd->bhd", o_lat.astype(self.dtype), w_uv)
-            return self._mm(o.reshape(b, 1, heads * c.v_head_dim), self.w_o), cache
+            return self._project_out(o.reshape(b, 1, heads * c.v_head_dim), x), cache
 
 
 VIEWS = "views"  # the collection of :func:`expand_views`
 
 
+def query_rank(config) -> int:
+    """The rows of ``w_uq``: the query latent's rank, or the hidden size where the configuration has no query latent."""
+    return config.hidden_size if config.q_lora_rank is None else config.q_lora_rank
+
+
 def split_w_uq(w_uq: jnp.ndarray, config) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``w_uq`` (rank, H * (nope + rope)), ``[nope | rope]`` a head -> (rank, H * nope) and (rank, H * rope)."""
     c = config
-    rank, nope = c.q_lora_rank, c.qk_nope_head_dim
+    rank, nope = query_rank(c), c.qk_nope_head_dim
     w = w_uq.reshape(rank, c.num_attention_heads, nope + c.qk_rope_head_dim)
     return w[..., :nope].reshape(rank, -1), w[..., nope:].reshape(rank, -1)
 

@@ -64,7 +64,7 @@ kept on a chip measurement (``tools/moe_ab.py``; PERF.md 6, PR 28):
     local pair adding zeros to token 0. The inverse gather would move all
     ``T * k`` rows to use that sixteenth.
 
-``dense`` (a decode step: under 384 tokens)
+``dense`` (a decode step: under 384 tokens, under the first two sets of cuts)
     every held expert on every token, weighted (zero where not routed). Up to
     the ridge (about 240 tokens an expert layer's worth of weights) a step is
     bound by reading the experts' weights, and this path reads all of them
@@ -74,11 +74,15 @@ kept on a chip measurement (``tools/moe_ab.py``; PERF.md 6, PR 28):
     a step's pairs was 5.8% faster end to end at batch 64 (it reads the 87%
     of experts a step hits) and its speed followed the seed's tokens (0.7%
     spread against 0.1%): left out here, open in PERF.md 7. ``ragged_dot``
-    measured 2x slower than either.
+    measured 2x slower than either. A layer under the third set
+    (``_MANY_EXPERTS``, measured at 128 held of 512) never takes this path: its
+    steps are grouped too; the two paths' times tie there, and the dense
+    einsum's relayout of that many experts does not fit the chip.
 """
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Tuple
 
 import flax.linen as nn
@@ -132,17 +136,49 @@ _WIDE_EXPERTS = _Cuts(grouped_min_tokens=384, row_tile=256, pass_rows=1024)
 # 14.97 and of 8192 14.76: no cliff, and within 3% the passes do not matter;
 # one pass stays, whose body needs no loop (PERF.md 7).
 _SMALL_EXPERTS = _Cuts(grouped_min_tokens=384, row_tile=256, pass_rows=65536)
+# 128 held experts of width 768, hidden 2560, a quarter of the router's 512 (Ling
+# 3.0 flash, one chip of four: two local pairs a token, a scatter-add combine;
+# ``tools/moe_ab.py --geom ling``, PERF.md 6, PR 49). **Every call takes the
+# grouped path**: a decode step's 128 tokens hit 111 of the 128 held experts,
+# and grouped reads those (1.94 ms at tile 64, 1.96 at 32, 1.98 at 128, 2.06 at
+# 16, 2.47 at 256) where dense reads all (2.01); 256 tokens 2.20 (tiles 64 and
+# 128) against 2.26. And the dense path does not fit: inside the generator XLA
+# lays ``experts_w1`` and ``experts_w3`` of every layer out again for its
+# einsum, twelve copies of 480 MB beside 10.5 GB of weights. Row tile: a prompt
+# chunk's 8192 tokens (16 384 local pairs, 128 rows an expert) take 11.20 ms at
+# 128 and 10.95 at 256 (12.6 at 64, 15.3 at 32, 21.1 at 16); 128 serves the step
+# and the chunk within 2% of either's best. Rows a pass at 8192 tokens, tile
+# 128: 11.45 ms in passes of 512, 11.20 of 1024, 10.85 in one of 16 384, and
+# 33.4 of 2048, 20.0 of 4096 (the scatter-add's cliff past 1024 updates, as in
+# the wide set); 1024 stays, 3% over the one pass, so that a layer the routing
+# sends more pairs costs a pass more and not a second sweep.
+_MANY_EXPERTS = _Cuts(grouped_min_tokens=1, row_tile=128, pass_rows=1024)
+# Each set with the geometry it was measured at: an expert's size (hidden x
+# width) and how many experts the layer holds.
+_MEASURED = (
+    (7168 * 2048, 16, _WIDE_EXPERTS),
+    (2304 * 896, 64, _SMALL_EXPERTS),
+    (2560 * 768, 128, _MANY_EXPERTS),
+)
 # Fewer tokens than fill a pass take one pass of the pairs an even routing
 # sends here and a quarter more (at 2048 tokens of the wide geometry that would
 # be 1280 rows in 4.8 ms; capped, two passes take 7.0).
 _PASS_SLACK = 1.25
 
 
-def _cuts(hidden: int, width: int) -> _Cuts:
-    """The cuts of an expert layer whose experts are ``hidden`` x ``width``:
-    those of the measured geometry whose expert is nearer in size (by ratio)."""
-    wide, small = 7168 * 2048, 2304 * 896
-    return _WIDE_EXPERTS if (hidden * width) ** 2 >= wide * small else _SMALL_EXPERTS
+def _cuts(hidden: int, width: int, held: int) -> _Cuts:
+    """The cuts of an expert layer that holds ``held`` experts of ``hidden`` x
+    ``width``: those of the measured geometry nearest to it, by the ratios of
+    the expert's size and of the number held (the sum of the two logarithms).
+    Every set is a reading at its own geometry and no rule: the third's
+    crossing of dense and grouped was measured at one point (128 and 256 tokens
+    over 128 held experts), where the two paths' times tie within 3% and memory
+    decides, so a geometry between the measured ones gets cuts nobody measured
+    for it (ROADMAP.md D15)."""
+    def far(measured):
+        size, count, _ = measured
+        return abs(math.log(hidden * width / size)) + abs(math.log(held / count))
+    return min(_MEASURED, key=far)[2]
 
 
 def _pass_rows(pairs: int, held_share: float, cuts: _Cuts) -> int:
@@ -362,7 +398,7 @@ class MoELayer(nn.Module):
 
         with jax.named_scope("moe/experts"):
             unserved = passes = jnp.zeros((), jnp.int32)
-            cuts = _cuts(h, width)
+            cuts = _cuts(h, width, g)
             back = None  # how a grouped pass's rows get back to their tokens; the dense path has no such step
             if t >= cuts.grouped_min_tokens:
                 rows = _pass_rows(t * c.num_experts_per_tok, g / n_outputs, cuts)

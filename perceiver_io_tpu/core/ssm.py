@@ -57,6 +57,37 @@ from perceiver_io_tpu.ops.layernorm import RMSNorm
 from perceiver_io_tpu.ops.selective_scan import selective_scan, selective_scan_reference, ssm_scan_supported
 
 
+def causal_conv(window, w, bias=None):
+    """The causal depthwise convolution of ``K`` taps and its silu, shared by
+    every mixer that has one (this one; ``core/kda.py``): ``window`` (B, T + K -
+    1, d) holds every position's ``K`` inputs, oldest first, ``w`` (K, d) the
+    taps and ``bias`` (d,) or ``None``. Returns ``silu(sum_j w[j] * window[:, j:j
+    + T] + bias)`` (B, T, d) float32: ``K`` shifted sums in float32."""
+    k = w.shape[0]
+    t = window.shape[1] - k + 1
+    w = w.astype(jnp.float32)
+    acc = None if bias is None else bias.astype(jnp.float32)
+    for j in range(k):
+        term = w[j] * window[:, j:j + t].astype(jnp.float32)
+        acc = term if acc is None else acc + term
+    return jax.nn.silu(acc)
+
+
+def rows_window(x_in, k: int):
+    """Whole rows ``x_in`` (B, T, d) from an empty past: the convolution's window (B, T + K - 1, d), zeros before the first token."""
+    return jnp.concatenate([jnp.zeros((x_in.shape[0], k - 1, x_in.shape[2]), x_in.dtype), x_in], axis=1)
+
+
+def step_window(kept, x_in):
+    """One new input a row, ``x_in`` (B, 1, d), after the ``K - 1`` inputs ``kept``: the token's window (B, K, d), in ``kept``'s dtype."""
+    return jnp.concatenate([kept, x_in.astype(kept.dtype)], axis=1)
+
+
+def window_tail(window, k: int):
+    """What a step needs of a window afterwards: its last ``K - 1`` inputs."""
+    return window[:, -(k - 1):]
+
+
 class MambaMixer(nn.Module):
     """``config`` needs ``hidden_size``, ``mamba_expand``, ``mamba_d_state``,
     ``mamba_dt_rank``, ``mamba_d_conv``, ``rms_norm_eps`` and ``init_scale``."""
@@ -91,14 +122,8 @@ class MambaMixer(nn.Module):
     # ------------------------------------------------------------ shared
 
     def _convolve(self, window):
-        """``window`` (B, T + K - 1, d): every position's ``K`` inputs, oldest
-        first. Returns ``x`` (B, T, d) in ``dtype``: ``K`` shifted sums in float32, the bias, silu."""
-        k, t = self.config.mamba_d_conv, window.shape[1] - self.config.mamba_d_conv + 1
-        w = self.conv_w.astype(jnp.float32)
-        acc = self.conv_b.astype(jnp.float32)
-        for j in range(k):
-            acc = acc + w[j] * window[:, j:j + t].astype(jnp.float32)
-        return jax.nn.silu(acc).astype(self.dtype)
+        """:func:`causal_conv` of ``window`` under the mixer's taps and bias, in ``dtype``."""
+        return causal_conv(window, self.conv_w, self.conv_b).astype(self.dtype)
 
     def _select(self, x):
         """``x`` (B, T, d) -> the step size ``D_t`` (B, T, d) and ``B_t``, ``C_t``
@@ -132,14 +157,13 @@ class MambaMixer(nn.Module):
         and the rows' state after their last token (the window in ``dtype``)."""
         c = self.config
         k, d = c.mamba_d_conv, self.d_inner
-        b = u.shape[0]
         with jax.named_scope("ssm/proj_in"):
             xz = self._mm(u, self.w_in)
             x_in, z = xz[..., :d], xz[..., d:]
         with jax.named_scope("ssm/conv"):
-            window = jnp.concatenate([jnp.zeros((b, k - 1, d), x_in.dtype), x_in], axis=1)
+            window = rows_window(x_in, k)
             x = self._convolve(window)
-            kept = window[:, -(k - 1):]
+            kept = window_tail(window, k)
         with jax.named_scope("ssm/select"):
             dt, bb, cc = self._select(x)
         with jax.named_scope("ssm/scan"):
@@ -158,9 +182,9 @@ class MambaMixer(nn.Module):
             xz = self._mm(u, self.w_in)
             x_in, z = xz[..., :d], xz[..., d:]
         with jax.named_scope("ssm/conv"):
-            window = jnp.concatenate([state.conv, x_in.astype(state.conv.dtype)], axis=1)
+            window = step_window(state.conv, x_in)
             x = self._convolve(window)
-            kept = window[:, 1:]
+            kept = window_tail(window, self.config.mamba_d_conv)
         with jax.named_scope("ssm/select"):
             dt, bb, cc = self._select(x)
         with jax.named_scope("ssm/update"):

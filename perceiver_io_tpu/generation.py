@@ -1823,6 +1823,12 @@ def make_instrumented_generate_fn(
     ret_taps = probes and "ret.*" in decoder.tap_scopes
     m_ret_abs_max = registry.gauge("ret_state_abs_max") if ret_taps else None
     m_ret_nonfinite = registry.counter("ret_state_nonfinite_total") if ret_taps else None
+    # a delta layer's state (``core/kda.py`` taps ``kda.state``): the same two readings of ``S``, its mean decay and step
+    kda_taps = probes and "kda.*" in decoder.tap_scopes
+    m_kda_abs_max = registry.gauge("kda_state_abs_max") if kda_taps else None
+    m_kda_nonfinite = registry.counter("kda_state_nonfinite_total") if kda_taps else None
+    m_kda_decay = registry.gauge("kda_decay_mean") if kda_taps else None
+    m_kda_beta = registry.gauge("kda_beta_mean") if kda_taps else None
     # a model that drafts for itself (a ``speculative`` decoder): a step yields 0 to 2 tokens a row, every
     # step is host-timed as one TPOT sample, and the ``spec.step`` taps keep the drafting's books
     self_drafting = getattr(decoder, "speculative", False)
@@ -1965,6 +1971,16 @@ def make_instrumented_generate_fn(
                     health_row["ret_state_nonfinite"] = sum(int(h["ret_state_nonfinite"]) for h in hh)
                     m_ret_abs_max.set(health_row["ret_state_abs_max"])
                     m_ret_nonfinite.inc(health_row["ret_state_nonfinite"])
+                if kda_taps:
+                    health_row["kda_state_abs_max"] = round(max(float(h["kda_state_abs_max"]) for h in hh), 6)
+                    health_row["kda_state_nonfinite"] = sum(int(h["kda_state_nonfinite"]) for h in hh)
+                    sites = max(sum(int(h["kda_sites"]) for h in hh), 1)  # every layer of every call taps once
+                    health_row["kda_decay_mean"] = round(sum(float(h["kda_decay_sum"]) for h in hh) / sites, 6)
+                    health_row["kda_beta_mean"] = round(sum(float(h["kda_beta_sum"]) for h in hh) / sites, 6)
+                    m_kda_abs_max.set(health_row["kda_state_abs_max"])
+                    m_kda_nonfinite.inc(health_row["kda_state_nonfinite"])
+                    m_kda_decay.set(health_row["kda_decay_mean"])
+                    m_kda_beta.set(health_row["kda_beta_mean"])
                 if spec_taps:
                     drafts, accepted = (sum(int(h[k]) for h in hh) for k in ("drafts", "accepted"))
                     m_spec_drafts.inc(drafts)

@@ -26,6 +26,15 @@ further down):
   every layer, which also carries the length a step's rotary position is read
   off; a dense SwiGLU in every block.
 
+- **Ling 3.0** (``layer_types`` of ``"kda"`` and ``"latent_attention"``): Kimi
+  delta attention layers (``core/kda.py``; arXiv:2510.26692), whose
+  :class:`DeltaState` is a float32 ``S`` of ``[batch, heads, 128, 128]`` a
+  layer, stored transposed, with three convolution windows and no length,
+  beside latent attention **as a layer kind** (the first family's attention,
+  here without a query latent and with a head-wise output gate), whose
+  :class:`LatentCache` carries the length a step's position is read off;
+  leading dense layers, then sigmoid-routed experts with a shared expert.
+
 Unlike the Perceiver models every position passes the whole stack, so there
 is no latent window. The model meets :mod:`perceiver_io_tpu.generation`
 through :meth:`DecoderLanguageModel.generation_decoder`.
@@ -76,16 +85,18 @@ import jax.numpy as jnp
 from jax import lax
 
 from perceiver_io_tpu.core.cache import (
-    KVCache, LatentCache, RaggedKVCache, RaggedWindowKVCache, RecurrentState, RetentionState, WindowKVCache, init_kv_cache,
-    init_latent_cache, init_ragged_kv_cache, init_ragged_window_kv_cache, init_window_kv_cache,
+    DeltaState, KVCache, LatentCache, RaggedKVCache, RaggedWindowKVCache, RecurrentState, RetentionState, WindowKVCache,
+    init_kv_cache, init_latent_cache, init_ragged_kv_cache, init_ragged_window_kv_cache, init_window_kv_cache,
 )
 from perceiver_io_tpu.core.gqa import GroupedQueryAttention, verify_fused
+from perceiver_io_tpu.core.kda import KimiDeltaAttention
 from perceiver_io_tpu.core.mla import VIEWS, MultiHeadLatentAttention, expand_views
 from perceiver_io_tpu.core.moe import MoELayer, SwiGLU, grouped_combine
 from perceiver_io_tpu.core.retention import PowerRetention
 from perceiver_io_tpu.core.ssm import MambaMixer
 from perceiver_io_tpu.obs import probes
 from perceiver_io_tpu.ops.gqa_verify import verify_plan
+from perceiver_io_tpu.ops.kda import chunk_of as kda_chunk_of, kda_plans
 from perceiver_io_tpu.ops.layernorm import RMSNorm
 from perceiver_io_tpu.ops.mla_absorb import row_tile
 from perceiver_io_tpu.ops.power_retention import chunk_of, feature_rows, power_retention_plans
@@ -94,7 +105,16 @@ from perceiver_io_tpu.ops.selective_scan import ssm_scan_plans
 
 _ATTENTION_TYPES = ("sliding_attention", "full_attention")
 _RETENTION = "power_retention"
-_LAYER_TYPES = _ATTENTION_TYPES + ("mamba", _RETENTION)
+_KDA = "kda"
+_LATENT = "latent_attention"
+_LAYER_TYPES = _ATTENTION_TYPES + ("mamba", _RETENTION, _KDA, _LATENT)
+_STATEFUL = ("mamba", _RETENTION, _KDA)  # a mixer whose past is a state of one size, handed on as it leaves the prompt pass
+_POSITIONLESS = ("mamba", _KDA)  # of those, the mixers that read no position (``self.mixer``, not ``self.attn``)
+
+
+def _latent(kind: Optional[str]) -> bool:
+    """Whether a layer of ``kind`` attends through the latent cache: every layer where ``layer_types`` is ``None``."""
+    return kind is None or kind == _LATENT
 _BLOCKS = ("serial", "shortcut")
 
 
@@ -136,7 +156,15 @@ class DecoderLanguageModelConfig:
 
     A ``"power_retention"`` entry makes that layer's mixer a power retention
     layer (``core/retention.py``) on the grouped-query sizes and rotary, at
-    degree 2 (the power of the query-key product the kernels are written for)."""
+    degree 2 (the power of the query-key product the kernels are written for).
+
+    A ``"kda"`` entry makes that layer's mixer a Kimi delta attention layer
+    (``core/kda.py``) of ``num_attention_heads`` heads of ``head_dim`` on q, k
+    and v, with ``short_conv_kernel_size`` and ``kda_lower_bound`` (the
+    published keys of the Ling 3.0 family); a ``"latent_attention"`` entry is
+    the latent attention that ``layer_types`` ``None`` gives every layer.
+    ``q_lora_rank`` ``None`` and ``mla_head_gate`` are that attention's
+    (``core/mla.py``)."""
 
     vocab_size: int = 129280
     hidden_size: int = 7168
@@ -145,7 +173,7 @@ class DecoderLanguageModelConfig:
     intermediate_size: int = 18432
     moe_intermediate_size: int = 2048
     num_attention_heads: int = 128
-    q_lora_rank: int = 1536
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -181,6 +209,9 @@ class DecoderLanguageModelConfig:
     mamba_dt_rank: int = 160
     mamba_d_conv: int = 4
     tie_word_embeddings: bool = False
+    mla_head_gate: bool = False
+    short_conv_kernel_size: int = 4
+    kda_lower_bound: float = -5.0
 
     def __post_init__(self):
         if self.block not in _BLOCKS:
@@ -199,6 +230,13 @@ class DecoderLanguageModelConfig:
                 raise ValueError("a power_retention layer: the symmetric square over a head of even width is what is built")
             if "sliding_attention" in attends and not self.sliding_window:
                 raise ValueError("a sliding_attention layer needs sliding_window")
+            if _KDA in self.layer_types and not (self.head_dim and self.short_conv_kernel_size > 1 and self.kda_lower_bound < 0):
+                raise ValueError("a kda layer needs head_dim, a convolution of at least 2 taps and a negative kda_lower_bound")
+            if _KDA in self.layer_types and _LATENT not in self.layer_types:
+                # a delta state has no length: a step reads its position off the latent cache beside it
+                raise ValueError("kda layers stand beside at least one latent_attention layer")
+            if {_KDA, _LATENT} & set(self.layer_types) and set(self.layer_types) - {_KDA, _LATENT}:
+                raise ValueError("kda and latent_attention entries mix with each other alone")
             if attends and self.num_attention_heads % self.num_key_value_heads:
                 raise ValueError("num_key_value_heads must divide num_attention_heads")
         if self.num_nextn_predict_layers:
@@ -238,7 +276,7 @@ def _residual(x, y):
 class DecoderBlock(nn.Module):
     config: DecoderLanguageModelConfig
     sparse: bool
-    layer_type: Optional[str] = None  # None: latent attention
+    layer_type: Optional[str] = None  # None: latent attention, as ``"latent_attention"``
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -246,10 +284,12 @@ class DecoderBlock(nn.Module):
         c = self.config
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
         self.attn_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
-        if self.layer_type is None:
+        if _latent(self.layer_type):
             self.attn = MultiHeadLatentAttention(c, **kw)
         elif self.layer_type == "mamba":
             self.mixer = MambaMixer(c, **kw)
+        elif self.layer_type == _KDA:
+            self.mixer = KimiDeltaAttention(c, **kw)
         elif self.layer_type == _RETENTION:
             self.attn = PowerRetention(c, **kw)
         else:
@@ -264,9 +304,9 @@ class DecoderBlock(nn.Module):
         """``x + Attn(RMSNorm(x))`` over whole rows, expanded; also the cache
         rows (latent attention: one array; grouped-query: rotated keys and
         values, of which a window layer hands on its last ``sliding_window``;
-        a state-space layer: the rows' :class:`RecurrentState`; a retention
-        layer: the rows' final ``(S, z)``)."""
-        if self.layer_type == "mamba":
+        a state-space layer: the rows' :class:`RecurrentState`; a delta layer:
+        the rows' :class:`DeltaState`; a retention layer: the rows' final ``(S, z)``)."""
+        if self.layer_type in _POSITIONLESS:
             a, state = self.mixer.expand(self.attn_norm(x))
             return _residual(x, a), state
         a, rows = self.attn.expand(self.attn_norm(x), pos)
@@ -282,10 +322,10 @@ class DecoderBlock(nn.Module):
             return x + self.ffn(self.ffn_norm(x))
 
     def step(self, x, cache, pos):
-        if self.layer_type == "mamba":  # the state has no positions
+        if self.layer_type in _POSITIONLESS:  # the state has no positions
             a, cache = self.mixer.step(self.attn_norm(x), cache)
             return self.feed_forward(_residual(x, a)), cache
-        one_token = self.attn.absorb if self.layer_type is None else self.attn.step
+        one_token = self.attn.absorb if _latent(self.layer_type) else self.attn.step
         a, cache = one_token(self.attn_norm(x), cache, pos)
         return self.feed_forward(_residual(x, a)), cache
 
@@ -493,13 +533,13 @@ class DecoderLanguageModel(nn.Module):
         u, _ = self.mtp_attend(u, pos[:, :-1])
         return self.logits(x), self.mtp_logits(self.mtp_ffn(u))
 
-    def decode_step(self, token, caches: Tuple[Union[LatentCache, KVCache, WindowKVCache, RecurrentState, RetentionState], ...]):
+    def decode_step(self, token, caches: Tuple[Union[LatentCache, KVCache, WindowKVCache, RecurrentState, RetentionState, DeltaState], ...]):
         """One new token a row against the caches: logits (B, V) and the advanced caches."""
         b = token.shape[0]
         # the position is the length of whatever carries one: a cache that grows, a ring, or a retention state (a
-        # stack with no growing cache still rotates by it). Only a state-space layer's state has none, and such a
-        # layer reads no position: a stack of those alone decodes at 0 and nothing reads it
-        length = next((cache.length for cache in caches if not isinstance(cache, RecurrentState)), 0)
+        # stack with no growing cache still rotates by it). Only a state-space layer's and a delta layer's state have
+        # none, and such a layer reads no position: a stack of those alone decodes at 0 and nothing reads it
+        length = next((cache.length for cache in caches if not isinstance(cache, (RecurrentState, DeltaState))), 0)
         pos = jnp.broadcast_to(length, (b, 1)).astype(jnp.int32)
         x = self.embed(token)[:, None]
         new = []
@@ -573,7 +613,8 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
     :class:`RecurrentState` (such a layer runs over chunks of whole rows like an
     attention: a row's time axis is the scan kernel's to chunk, and a padded
     row would run its padding through the state); of a retention layer the
-    rows' final ``(S, z)``, float32. The hidden state of the whole batch
+    rows' final ``(S, z)``, float32; of a delta layer the rows'
+    :class:`DeltaState`. The hidden state of the whole batch
     stays in memory between layers (B * N * h); within a layer the attention
     runs over chunks of whole rows and the feed-forward over chunks of tokens
     (``_PREFILL_ATTENTION_TOKENS``, ``_PREFILL_FFN_TOKENS``), inside the one
@@ -586,7 +627,8 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
     rows_a, tokens_f = _prefill_cuts(b, n)
     pos = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None], (rows_a, n))
 
-    if c.layer_types is None:  # latent attention: the weight views its expanded pass takes, once and not a chunk
+    kinds = c.layer_types or (None,) * c.num_hidden_layers
+    if any(_latent(kind) for kind in kinds):  # latent attention: the weight views its expanded pass takes, once and not a chunk
         with jax.named_scope("prefill"):
             params = {**params, VIEWS: expand_views(params["params"], c, model.dtype)}
     scoped = functools.partial(_scoped, model, params)
@@ -600,9 +642,9 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
             x = x.reshape(b, n, h)
             continue
         x, rows = _over_chunks(lambda xc, i=i: scoped("attend_layer", xc, pos, i), x.reshape(b // rows_a, rows_a, n, h))
-        if c.layer_types is None:
+        if _latent(kinds[i]):
             cache_rows.append(_batch_rows(rows, b, n))
-        elif c.layer_types[i] in ("mamba", _RETENTION):  # (chunks, rows a chunk, ...): the rows' states, as they leave the kernel
+        elif kinds[i] in _STATEFUL:  # (chunks, rows a chunk, ...): the rows' states, as they leave the kernel
             cache_rows.append(jax.tree.map(lambda r: r.reshape(b, *r.shape[2:]), rows))
         else:  # (chunks, rows a chunk, Hkv, positions, D): a key-value head is a row of the cache
             cache_rows.append(tuple(r.reshape(b * r.shape[2], *r.shape[3:]) for r in rows))
@@ -666,20 +708,22 @@ class _Decoder:
 
     @property
     def tap_scopes(self) -> Tuple[str, ...]:
-        """The probe sites this configuration's layers have: an expert layer's books, a state-space or retention layer's state."""
+        """The probe sites this configuration's layers have: an expert layer's books, a state-space, retention or delta layer's state."""
         c = self.model.config
         sparse = c.block == "shortcut" or c.first_k_dense_replace < c.num_hidden_layers
         kinds = c.layer_types or ()
         return ((("moe.*",) if sparse else ()) + ("spec.*",) + (("ssm.*",) if "mamba" in kinds else ())
-                + (("ret.*",) if _RETENTION in kinds else ()))
+                + (("ret.*",) if _RETENTION in kinds else ()) + (("kda.*",) if _KDA in kinds else ()))
 
     def _caches(self, rows, batch: int, n: int, max_new_tokens: int, cache_dtype):
         c = self.model.config
-        if c.layer_types is None:
-            return tuple(init_latent_cache(batch, n + max_new_tokens, r.shape[-1], cache_dtype).append(r) for r in rows)
         def cache_of(kind, kept):
+            if _latent(kind):
+                return init_latent_cache(batch, n + max_new_tokens, kept.shape[-1], cache_dtype).append(kept)
             if kind == "mamba":  # the state as the scan left it (float32), the window in the caches' dtype
                 return RecurrentState(conv=kept.conv.astype(cache_dtype), ssm=kept.ssm)
+            if kind == _KDA:  # the state as the chunk kernel left it (float32), the three windows in the caches' dtype
+                return DeltaState(s=kept.s, **{name: getattr(kept, name).astype(cache_dtype) for name in ("conv_q", "conv_k", "conv_v")})
             if kind == _RETENTION:  # float32 whatever the caches' dtype; the state holds the prompt's ``n`` tokens
                 return RetentionState(s=kept[0], z=kept[1], length=jnp.asarray(n, jnp.int32))
             slots, d = batch * c.num_key_value_heads, c.head_dim
@@ -687,7 +731,7 @@ class _Decoder:
                 return init_window_kv_cache(slots, c.sliding_window, d, d, cache_dtype).fill(*kept, n)
             return init_kv_cache(slots, n + max_new_tokens, d, d, cache_dtype).append(*kept)
 
-        return tuple(cache_of(kind, kept) for kind, kept in zip(c.layer_types, rows))
+        return tuple(cache_of(kind, kept) for kind, kept in zip(c.layer_types or (None,) * len(rows), rows))
 
     def _refuse(self, pad_mask, n: int, max_new_tokens: int):
         c = self.model.config
@@ -784,7 +828,7 @@ class _Decoder:
 
     def health(self, logits, window):
         # the occupancy gauge reads a cache that grows: a ring is full from its window on, a recurrent state has one size
-        fixed = (WindowKVCache, RaggedWindowKVCache, RecurrentState, RetentionState)
+        fixed = (WindowKVCache, RaggedWindowKVCache, RecurrentState, RetentionState, DeltaState)
         grows = next((cache for cache in window[0] if not isinstance(cache, fixed)), window[0][0])
         # a stack of retention states alone: nothing fills
         return probes.decode_health(logits, None if isinstance(grows, RetentionState) else grows, jnp.zeros((), jnp.int32))
@@ -796,7 +840,7 @@ class _Decoder:
         # how a prompt chunk's expert rows get back to their tokens (a step of few tokens takes the dense path)
         router_width = c.n_routed_experts + c.zero_expert_num
         moe = {"moe_combine": grouped_combine(c.n_held_experts, router_width)}
-        if c.layer_types is not None:
+        if c.layer_types is not None and not set(c.layer_types) & {_KDA, _LATENT}:
             row_bytes = 2 * (c.num_key_value_heads or 0) * (c.head_dim or 0) * itemsize  # a token's keys and values in one layer
             # the module's block keeps a cache of its own kind beside the stack's
             kinds = c.layer_types + (c.mtp_layer_types if self.speculative else ())
@@ -850,7 +894,18 @@ class _Decoder:
                                        for kind, (shape, window) in kinds.items() if fused[kind]])
             return row
         row_bytes = (c.kv_lora_rank + c.qk_rope_head_dim) * itemsize
-        n_caches = c.num_hidden_layers
+        kinds = c.layer_types or (None,) * c.num_hidden_layers
+        n_caches = sum(_latent(kind) for kind in kinds)
+        if _KDA in kinds:  # delta states of one size beside the latent caches that grow
+            n_kda, width = kinds.count(_KDA), c.num_attention_heads * c.head_dim
+            moe.update(
+                kda_layers=n_kda,
+                kda_state_bytes=batch * c.num_attention_heads * c.head_dim * c.head_dim * 4 * n_kda,
+                kda_state_dtype="float32",
+                kda_conv_bytes=batch * 3 * (c.short_conv_kernel_size - 1) * width * itemsize * n_kda,
+                kda_chunk=kda_chunk_of(prompt_len),
+                kda=kda_plans(),
+            )
         if c.block == "shortcut":  # two attentions a layer, each with a cache; the router's outputs past the experts' weights
             n_caches *= 2
             moe.update(block=c.block, moe_router_width=router_width, moe_zero_experts=c.zero_expert_num)

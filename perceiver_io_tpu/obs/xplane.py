@@ -292,6 +292,8 @@ LAYER_SCOPES = frozenset({
     "ssm/proj_in", "ssm/conv", "ssm/select", "ssm/scan", "ssm/update", "ssm/out",
     # a power retention layer (core/retention.py): the chunked form is the prompt pass's, the update a step's
     "ret/proj", "ret/gate", "ret/chunk", "ret/update", "ret/out",
+    # a Kimi delta attention layer (core/kda.py): the chunked form is the prompt pass's, the update a step's
+    "kda/proj", "kda/conv", "kda/gate", "kda/chunk", "kda/update", "kda/out",
 })
 # flax module names that mark a layer no scope is opened for
 MODULE_LAYERS = {"q_proj": "qkv_proj", "k_proj": "qkv_proj", "v_proj": "qkv_proj", "o_proj": "o_proj"}
@@ -300,7 +302,8 @@ _NORM_MODULE = re.compile(r"(^|_)norm$|^(Layer|RMS)Norm_\d+$")
 # and q/k norms) is its own
 CLOSED_LAYERS = frozenset({"mlp", "dense_mlp", "mla/expand", "mla/absorb", "attn/window", "attn/full",
                            "ssm/proj_in", "ssm/conv", "ssm/select", "ssm/scan", "ssm/update", "ssm/out",
-                           "ret/proj", "ret/gate", "ret/chunk", "ret/update", "ret/out"})
+                           "ret/proj", "ret/gate", "ret/chunk", "ret/update", "ret/out",
+                           "kda/proj", "kda/conv", "kda/gate", "kda/chunk", "kda/update", "kda/out"})
 # parts of a name stack that are no scope: what a transform or a loop wraps around the names. A transform
 # wraps the first scope opened under it (``transpose(jvp(loss))`` is the scope ``loss``); ``jit`` wraps the name
 # of a function, which is no scope

@@ -45,7 +45,7 @@ from perceiver_io_tpu.ops.layernorm import rms_norm
 
 TOL = 2e-4  # float32 against float32, see the module docstring
 VOCAB = 96
-CUTS = moe._cuts(64, 32)  # how the expert layer cuts its work at these tiny widths (hidden 64, width 32)
+CUTS = moe._cuts(64, 32, 4)  # how the expert layer cuts its work at these tiny widths (hidden 64, width 32, 4 experts held)
 
 
 def tiny_config(**kw) -> DecoderLanguageModelConfig:

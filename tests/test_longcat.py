@@ -36,7 +36,7 @@ from perceiver_io_tpu.obs import probes
 TOL = 2e-4
 VOCAB = 96
 REAL, ZERO, TOP_K = 16, 8, 3
-CUTS = moe._cuts(64, 32)
+CUTS = moe._cuts(64, 32, 4)
 
 
 def tiny_config(**kw) -> DecoderLanguageModelConfig:

@@ -284,10 +284,11 @@ def test_both_expert_paths_serve_every_pair_when_all_experts_are_held(case, monk
     all), the dense path has no such step (0)."""
     tokens, pass_rows, skewed = EXPERT_PATH_CASES[case]
     config = tiny_config()
-    grouped = tokens >= moe._cuts(64, 32).grouped_min_tokens
+    cuts = moe._cuts(64, 32, config.n_held_experts)
+    grouped = tokens >= cuts.grouped_min_tokens
     assert grouped == (case != "dense_path")
     if pass_rows:
-        monkeypatch.setattr(moe, "_SMALL_EXPERTS", moe._SMALL_EXPERTS._replace(pass_rows=pass_rows))
+        monkeypatch.setattr(moe, "_cuts", lambda hidden, width, held: cuts._replace(pass_rows=pass_rows))
     x = jax.random.normal(jax.random.PRNGKey(5), (tokens, 64))
     params = moe.MoELayer(config).init(jax.random.PRNGKey(6), x)
     if skewed:  # one channel the same in every token, and a router that reads it for experts 0 and 1
@@ -313,10 +314,10 @@ def test_both_expert_paths_serve_every_pair_when_all_experts_are_held(case, monk
 
 
 def test_the_cuts_follow_the_geometry_they_were_measured_at():
-    assert moe._cuts(7168, 2048) == (384, 256, 1024)  # DeepSeek-V3's share keeps PR 28's values
-    assert moe._cuts(6144, 2048) == (384, 256, 1024)  # K-EXAONE's share: its own readings came out the same (PR 34)
-    small = moe._cuts(2304, 896)
-    assert small == moe._cuts(64, 32) and small.pass_rows % small.row_tile == 0 and small.row_tile % 128 == 0
+    assert moe._cuts(7168, 2048, 16) == (384, 256, 1024)  # DeepSeek-V3's share keeps PR 28's values
+    assert moe._cuts(6144, 2048, 16) == (384, 256, 1024)  # K-EXAONE's share: its own readings came out the same (PR 34)
+    small = moe._cuts(2304, 896, 64)
+    assert small == moe._cuts(64, 32, 8) and small.pass_rows % small.row_tile == 0 and small.row_tile % 128 == 0
 
 
 def test_half_split_rotary_with_yarn_against_complex_numbers():

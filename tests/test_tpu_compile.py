@@ -343,7 +343,7 @@ def test_grouped_expert_product_lowers(one_chip, monkeypatch, rows, k, n):
     gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
     moe = importlib.import_module("perceiver_io_tpu.core.moe")
     monkeypatch.setattr(gm, "_interpret_default", lambda: False)
-    cuts = moe._cuts(DSV3_HIDDEN, DSV3_EXPERT_WIDTH)
+    cuts = moe._cuts(DSV3_HIDDEN, DSV3_EXPERT_WIDTH, DSV3_HELD)
     assert cuts == (384, 256, 1024)  # the values PR 28 measured at this geometry stay
     assert moe._pass_rows(8192 * 8, 16 / 256, cuts) == 1024 and moe._pass_rows(cuts.grouped_min_tokens * 8, 16 / 256, cuts) == 256
     lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
@@ -850,6 +850,52 @@ def test_the_brumby_cells_generator_carries_its_states_in_place(one_chip, mosaic
             assert not re.search(state + r"\{(?!3,2,1,0)", result(ins)), ins.line[:300]
     assert not re.search(r"bf16\[32,8,8320,128\]", text)
     assert not re.search(r"(bf16|f32)\[(\d+,)*(4096|131072)(,\d+)*,(8320|8256|65,128)\]", text)
+
+
+def test_the_ling_cells_generator_carries_its_delta_states_in_place(one_chip, mosaic, monkeypatch):
+    """``ling3-ep4-decode-b128-p2k`` as the benchmark builds it (5.23B bfloat16
+    parameters, 128 prompts of 2048 tokens, 256 new tokens), compiled for a
+    described v5e: one geometry of the chunk kernel in the prompt pass (six
+    calls, none in the decode loop) and one of the step's kernel in the loop
+    (six calls, none outside it); the decode loop carries the six states
+    ``f32[128,32,128,128]`` row-major, each the aliased operand and result of its
+    layer's kernel (``output_to_operand_aliasing``), and nothing in the body
+    copies, turns, converts or slices into one; **no state of half the
+    precision exists anywhere**, and no second copy: the memory analysis here
+    counts each aliased kernel result as a buffer of its own (the six states
+    and the latent cache), and without them the program is under the 14.9 GB
+    the other cells are held to. A decode step's experts run the grouped
+    kernels (no ``[128,2560,768]`` array is laid out again: the dense path's
+    5.8 GB of copies are what would not fit), and the one latent layer's step
+    is the absorbed kernel over its cache."""
+    import re
+
+    compiled = _cell_generator("ling3-ep4-decode-b128-p2k", "ling", one_chip, monkeypatch)
+    m = compiled.memory_analysis()
+    assert 10.46e9 < m.argument_size_in_bytes < 10.47e9  # the weights and the prompts
+    states = 6 * 128 * 32 * 128 * 128 * 4
+    total = _device_bytes(compiled) - states - 128 * 2304 * 576 * 2
+    assert total < 14.9e9, f"{total / 1e9:.2f} GB"
+    text = compiled.as_text()
+    assert set(re.findall(r"kda_chunk_l\d+_c\d+_h\d+_d\d+", text)) == {"kda_chunk_l2048_c128_h32_d128"}
+    assert set(re.findall(r"kda_step_b\d+_h\d+_d\d+", text)) == {"kda_step_b128_h32_d128"}
+    assert set(re.findall(r"mla_absorb_h\d+_s\d+_w\d+", text)) == {"mla_absorb_h32_s2304_w576"}
+    state = r"f32\[128,32,128,128\]"
+    result = lambda ins: ins.line.split(" = ", 1)[1].split(f" {ins.opcode}(", 1)[0]  # noqa: E731
+    loop, body = _loop_around(text, lambda loop, inside: re.search(state, result(loop)))  # the decode loop: the one that carries a state
+    assert len(re.findall(state + r"\{3,2,1,0[:}]", result(loop))) == 6 and not re.search(state + r"\{(?!3,2,1,0)", result(loop))
+    kernels = [i for i in body if i.opcode == "custom-call" and "kda_step" in i.name]
+    assert len(kernels) == 6 and all("output_to_operand_aliasing={{1}: (5, {})}" in i.line for i in kernels)
+    assert not any(i.opcode == "custom-call" and "kda_chunk" in i.name for i in body)  # the chunk kernel is the prompt pass's
+    assert len(re.findall(r"%kda_chunk_l2048_c128_h32_d128[.\d]* = ", text)) == 6 and len(re.findall(r"%kda_step_b128_h32_d128[.\d]* = ", text)) == 6
+    for ins in body:
+        if re.search(state, result(ins)):
+            assert ins.opcode in ("custom-call", "get-tuple-element", "tuple", "parameter", "bitcast"), ins.line[:300]
+            assert not re.search(state + r"\{(?!3,2,1,0)", result(ins)), ins.line[:300]
+    assert not re.search(r"bf16\[128,32,128,128\]", text) and not re.search(r"bf16\[\d+,\d+,32,128,128\]", text)
+    # a step's experts: the grouped kernels on its 384 rows, in the loop; the weights as the arguments hold them
+    assert any(i.opcode == "custom-call" and "moe_experts_prefill_m384_k2560_n768" in i.name for i in body)
+    assert not re.search(r"bf16\[128,2560,768\]\{(?!2,1,0)", text) and not re.search(r"bf16\[128,768,2560\]\{(?!2,1,0)", text)
 
 
 # ------------------------------------------ the MLP's exact GELU: evaluated once a layer and kept

@@ -3,7 +3,7 @@ and through the decode attention at a decoder-only model's published widths,
 so that ``core/moe.py``, ``core/mla.py`` and ``core/gqa.py`` keep one per path
 on a measurement:
 
-    chiprun -- python tools/moe_ab.py [--geom dsv3|mellum|kexaone] [--only experts_layer] [--compile-only]
+    chiprun -- python tools/moe_ab.py [--geom dsv3|mellum|kexaone|ling] [--only experts_layer] [--compile-only]
 
 ``--geom dsv3`` (the default) is DeepSeek-V3's share of PR 28 (16 held experts
 of 256, hidden 7168, width 2048; absorbed MLA); ``--geom mellum`` is Mellum 2
@@ -23,7 +23,11 @@ capacity), over a growing cache and a ring at the parent's capacities (1537
 and 129 slots, which XLA carries slot-major) and the program's (1552 and 144,
 whole bfloat16 tiles); every variant prints the layouts its loop carries
 (PERF.md 6, PR 44); and the prompt pass's window-128 flash forward on a chunk of four
-1024-token rows, in bands or whole, blocks of 1024 down to 128).
+1024-token rows, in bands or whole, blocks of 1024 down to 128); ``--geom ling``
+is Ling-3.0-flash's share of PR 49 (128 held experts of 512, hidden 2560, width
+768: a decode step's 128 tokens, two pairs a held expert, dense against grouped
+at row tiles of 16 to 256, and rows a pass at a prompt chunk's 8192 tokens with
+two local pairs a token; the expert layer alone).
 
 - the grouped product alone (8192 live rows of 16384, 16 experts, even and
   skewed group sizes): the Pallas kernel (``ops/grouped_matmul.py``) against
@@ -88,12 +92,17 @@ GEOM = "dsv3"
 
 def set_geometry(name: str) -> None:
     """``--geom mellum``: every expert held, a decode step of 32 tokens and a prompt chunk of 8192."""
-    global H, WIDTH, EXPERTS, ROUTED, TOP_K, LAYER_TOKENS, SHORT_PASSES, GEOM
+    global H, WIDTH, EXPERTS, ROUTED, TOP_K, LAYER_TOKENS, LAYER_TILINGS, SHORT_PASSES, GEOM
     GEOM = name
     if name == "mellum":
         H, WIDTH, EXPERTS, ROUTED, TOP_K = 2304, 896, 64, 64, 8
         LAYER_TOKENS = (32, 256, 512, 8192)
         SHORT_PASSES = {8192: ((256, 512), (256, 2048), (256, 4096), (256, 8192), (512, 8192), (256, 16384), (256, 32768), (256, 65536))}
+    if name == "ling":  # 128 of 512 small experts: a step's 128 tokens hit an expert twice in the mean
+        H, WIDTH, EXPERTS, ROUTED, TOP_K = 2560, 768, 128, 512, 8
+        LAYER_TOKENS = (128, 256, 512, 1024, 8192)
+        LAYER_TILINGS = ((16, 0), (32, 0), (64, 0), (128, 0), (256, 0))
+        SHORT_PASSES = {8192: ((128, 512), (128, 2048), (128, 4096), (128, 16384), (64, 1024), (256, 2048))}
     if name == "kexaone":
         H, WIDTH, EXPERTS, ROUTED, TOP_K = 6144, 2048, 16, 128, 8
         LAYER_TOKENS = (128, 256, 384, 512, 8192)
@@ -167,7 +176,7 @@ def variants():
 
     def grouped(tile, pass_rows, combine):
         def run(x, local, weights, w1, w3, w2):
-            cuts = moe._cuts(H, WIDTH)._replace(row_tile=tile)
+            cuts = moe._cuts(H, WIDTH, EXPERTS)._replace(row_tile=tile)
             rows = pass_rows or moe._pass_rows(local.size, EXPERTS / ROUTED, cuts)  # 0: the rows the program takes
             rows = min(rows, -(-local.size // tile) * tile)
             return moe.experts_grouped(x, local, weights, w1, w3, w2, rows, tile, combine)[0]
@@ -198,6 +207,8 @@ def variants():
                 for suffix, combine in combines.items():
                     name = f"experts_layer/T{t}/grouped_tm{tile}_rows{pass_rows}{suffix}"
                     out[name] = (grouped(tile, pass_rows, combine), layer, "layer")
+    if GEOM == "ling":  # the expert layer alone: its attentions are the latent cells' and ``tools/kda_ab.py``'s
+        return {k: v for k, v in out.items() if "experts_layer" in k}
     if GEOM == "kexaone":
         return {**{k: v for k, v in out.items() if "experts_layer" in k}, **kexaone_attention_variants()}
     if GEOM == "mellum":
@@ -443,7 +454,7 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--compile-only", action="store_true")
     p.add_argument("--iters", type=int, default=5)
-    p.add_argument("--geom", default="dsv3", choices=("dsv3", "mellum", "kexaone"))
+    p.add_argument("--geom", default="dsv3", choices=("dsv3", "mellum", "kexaone", "ling"))
     p.add_argument("--only", default="", help="substrings of variant names, comma-separated; a variant runs if it holds one")
     args = p.parse_args(argv)
     set_geometry(args.geom)
