@@ -43,10 +43,14 @@ kept on a chip measurement (``tools/moe_ab.py``; PERF.md 6, PR 28):
 
 ``grouped`` (a prompt pass, or a step of 384 tokens and more)
     the routed pairs are sorted by expert, and the pairs that fall to held
-    experts go, a pass of at most ``_cuts(...).pass_rows`` rows at a time, through a gather
-    and a grouped matrix product (``ops/grouped_matmul.py``); as many passes
-    as the routing sent pairs here: no pair is dropped however skewed the
-    routing is, and the work follows the pairs that are really here.
+    experts go, a pass of ``_pass_rows(...)`` rows at a time (the pairs an
+    even routing sends here and a quarter more), through a gather and a
+    grouped matrix product (``ops/grouped_matmul.py``); one pass where the
+    routing is even and no cap of the geometry's binds (Ling's passes are
+    4096 rows, four or five a chunk), and as many more as the routing sent
+    pairs here: no pair is dropped however skewed the routing is, the work
+    follows the pairs that are really here, and the buffers are a pass's,
+    not ``T * k`` rows.
     ``jax.lax.ragged_dot`` in the kernel's place measured 1.5x slower. How a
     pass's rows get back to their tokens (``grouped_combine``) is a fact of
     the configuration, the share of the experts the layer holds:
@@ -58,11 +62,17 @@ kept on a chip measurement (``tools/moe_ab.py``; PERF.md 6, PR 28):
     reduction where the scatter-add of the same 65 536 rows of 2304 channels
     was the prompt pass's largest single operation (PERF.md 6, PR 33).
 
-    *a share held* (DeepSeek-V3, 16 of 256): a token has 0 to ``k`` local
-    pairs, about a sixteenth of all; only those are moved, weighted in
-    float32 and scatter-added into the tokens' buffer, rows past the last
-    local pair adding zeros to token 0. The inverse gather would move all
-    ``T * k`` rows to use that sixteenth.
+    *a share held* (DeepSeek-V3, 16 of 256; Ling, 128 of 512): a token has 0
+    to ``k`` local pairs, about a sixteenth or a quarter of all, and only
+    those are moved. The flat pair list is in token order, so a pass's pairs
+    sorted by their number are sorted by token: one sort of the pass's keys
+    (each bringing its row of the pass and its float32 weight along, no row
+    touched), one row gather into that order, and ``ops/moe_combine.py``'s
+    kernel adds each weighted row into its token in float32, a tile of tokens
+    owning a contiguous run of rows (``"segment_sum"``, PERF.md 6, PR 50).
+    Rows past the last local pair sort last and are never read. The inverse
+    gather would move all ``T * k`` rows to use that share; XLA's scatter-add
+    of the same rows ran at thirty times its bytes' time.
 
 ``dense`` (a decode step: under 384 tokens, under the first two sets of cuts)
     every held expert on every token, weighted (zero where not routed). Up to
@@ -92,6 +102,7 @@ from jax import lax
 
 from perceiver_io_tpu.obs import probes
 from perceiver_io_tpu.ops.grouped_matmul import grouped_matmul
+from perceiver_io_tpu.ops.moe_combine import moe_combine
 
 
 # How the work is cut, not what is computed: a function of the expert layer's
@@ -101,28 +112,26 @@ from perceiver_io_tpu.ops.grouped_matmul import grouped_matmul
 class _Cuts(NamedTuple):
     grouped_min_tokens: int  # from this many tokens the grouped path is taken
     row_tile: int  # the grouped kernel's row tile
-    pass_rows: int  # a pass gathers at most this many sorted pairs
+    pass_rows: int  # a pass gathers at most this many sorted pairs (and what ``_pass_rows`` reckons, if that is fewer)
 
 
 # 16 held experts of width 2048, hidden 7168 (DeepSeek-V3, one chip of sixteen).
 # Dense and grouped cross between 256 tokens (dense 2.06 ms, grouped 2.43) and
 # 384 (2.95 against 2.52; 4.74 against 2.90 at 512). Row tile: 256 is the
 # fastest from 2048 tokens up (by 9% at a prompt chunk's 8192); 128 rows are 6
-# to 11% faster from 384 to 512 tokens. Rows a pass: 8192 tokens with 4096
-# pairs here take 10.0 ms in passes of 1024 rows (9.5 to 9.6 in passes of 512
-# or 768) against 14.2 in one of 5120 and 26 in passes of 1536 or 2048 (XLA's
-# scatter-add into 8192 rows is slow past 1024 updates: this geometry holds a
-# share of the experts and keeps the scatter-add, so the cliff is its own), and
-# a layer that a seed's routing sends a quarter more pairs costs a pass of 2 ms
-# more, not a second sweep of 8.6. Measured again at 16 held experts of width
-# 2048, hidden 6144 (K-EXAONE, one chip of eight: one local pair a token, twice
-# this share's; ``tools/moe_ab.py --geom kexaone``, PERF.md 6, PR 34), with the
-# same outcome: 128 positions dense 1.64 ms, grouped 1.71 (tile 128) to 1.94;
-# 256 tokens 1.81 against 2.01; 384 tokens 2.51 against 2.16; a prompt chunk's
-# 8192 pairs at tile 256 12.81 ms in passes of 1024 rows (12.81 at 512, 12.84 at
-# 768, 13.62 at 4096, 13.00 in one pass), 23.3 at 1536 and 18.2 at 2048 (the
-# same cliff); tile 128 15.3, tile 512 14.0.
-_WIDE_EXPERTS = _Cuts(grouped_min_tokens=384, row_tile=256, pass_rows=1024)
+# to 11% faster from 384 to 512 tokens. Rows a pass, under the segment-sum
+# combine (PERF.md 6, PR 50; every pass reads and writes the tokens' whole
+# float32 buffer, so few passes): 8192 tokens with 4096 pairs here take 7.77 ms
+# in one pass of the 5120 rows ``_pass_rows`` reckons, 8.02 in passes of 2048
+# and 9.10 of 1024: no cap. Measured again at 16 held experts of width 2048,
+# hidden 6144 (K-EXAONE, one chip of eight: one local pair a token, twice this
+# share's; ``tools/moe_ab.py --geom kexaone``, PERF.md 6, PR 34 and PR 50), with
+# the same outcome: 128 positions dense 1.64 ms, grouped 1.71 (tile 128) to
+# 1.94; 256 tokens 1.81 against 2.01; 384 tokens 2.51 against 2.16; a prompt
+# chunk's 8192 pairs at tile 256 10.20 ms in one pass of 10 240 rows, 10.10 in
+# passes of 4096 and 12.35 of 1024; tile 128 and tile 512 were 19% and 9%
+# slower under the combine of before.
+_WIDE_EXPERTS = _Cuts(grouped_min_tokens=384, row_tile=256, pass_rows=65536)
 # 64 held experts of width 896, hidden 2304 (Mellum 2, every expert held, 8
 # pairs a token all of them here: the grouped path combines by the gather).
 # Dense and grouped cross between 256 tokens (dense 1.17 ms, grouped 1.50) and
@@ -131,28 +140,33 @@ _WIDE_EXPERTS = _Cuts(grouped_min_tokens=384, row_tile=256, pass_rows=1024)
 # tokens (128 was 8% slower). Rows a pass were set under the scatter-add (PR
 # 32: the 65 536 pairs of such a chunk 17.4 ms in one pass, 19.5 in two, 23.2
 # in passes of 8192, 28.5 of 1024 and 42.7 of 2048, the cliff past 1024
-# updates, which describes the share-held side alone now). Under the gather
+# updates of XLA's scatter-add, which no side runs since PR 50). Under the gather
 # (PR 33) one pass takes 13.76 ms, two of 32 768 rows 13.36, passes of 16 384
 # 14.97 and of 8192 14.76: no cliff, and within 3% the passes do not matter;
 # one pass stays, whose body needs no loop (PERF.md 7).
 _SMALL_EXPERTS = _Cuts(grouped_min_tokens=384, row_tile=256, pass_rows=65536)
 # 128 held experts of width 768, hidden 2560, a quarter of the router's 512 (Ling
-# 3.0 flash, one chip of four: two local pairs a token, a scatter-add combine;
-# ``tools/moe_ab.py --geom ling``, PERF.md 6, PR 49). **Every call takes the
-# grouped path**: a decode step's 128 tokens hit 111 of the 128 held experts,
+# 3.0 flash, one chip of four: two local pairs a token, the segment-sum combine;
+# ``tools/moe_ab.py --geom ling``, PERF.md 6, PR 49 and PR 50). **Every call takes
+# the grouped path**: a decode step's 128 tokens hit 111 of the 128 held experts,
 # and grouped reads those (1.94 ms at tile 64, 1.96 at 32, 1.98 at 128, 2.06 at
 # 16, 2.47 at 256) where dense reads all (2.01); 256 tokens 2.20 (tiles 64 and
 # 128) against 2.26. And the dense path does not fit: inside the generator XLA
 # lays ``experts_w1`` and ``experts_w3`` of every layer out again for its
 # einsum, twelve copies of 480 MB beside 10.5 GB of weights. Row tile: a prompt
-# chunk's 8192 tokens (16 384 local pairs, 128 rows an expert) take 11.20 ms at
-# 128 and 10.95 at 256 (12.6 at 64, 15.3 at 32, 21.1 at 16); 128 serves the step
-# and the chunk within 2% of either's best. Rows a pass at 8192 tokens, tile
-# 128: 11.45 ms in passes of 512, 11.20 of 1024, 10.85 in one of 16 384, and
-# 33.4 of 2048, 20.0 of 4096 (the scatter-add's cliff past 1024 updates, as in
-# the wide set); 1024 stays, 3% over the one pass, so that a layer the routing
-# sends more pairs costs a pass more and not a second sweep.
-_MANY_EXPERTS = _Cuts(grouped_min_tokens=1, row_tile=128, pass_rows=1024)
+# chunk's 8192 tokens (16 384 local pairs, 128 rows an expert) take 8.07 ms at
+# 128 and 7.68 at 256 in one pass (12.6 at 64, 15.3 at 32, 21.1 at 16 under the
+# combine of before); 128 serves the step and the chunk within 5% of either's
+# best. Rows a pass at 8192 tokens, tile 128: 10.57 ms in passes of 1024 (every
+# pass reads and writes the tokens' whole float32 buffer, 0.26 ms), **7.17 of
+# 4096**, 7.77 of 8192, 7.58 of 16 384 and 8.07 in the one pass of 20 480 that
+# ``_pass_rows`` reckons: XLA gathers the rows of a pass of 4096 (21 MB) at the
+# HBM's rate and those of a larger one a row a DMA, at 120 to 190 GB/s
+# (``x[token]`` and the combine's row gather, 1.70 ms against 0.68), and the
+# grouped kernels take 4.65 ms in one pass against 4.14 in passes of 4096. The
+# cell decided the same way: 3255 tokens/s in one pass, 3293 in passes of 5120,
+# 3298 of 4096 (the parent's scatter-add in passes of 1024: 3062).
+_MANY_EXPERTS = _Cuts(grouped_min_tokens=1, row_tile=128, pass_rows=4096)
 # Each set with the geometry it was measured at: an expert's size (hidden x
 # width) and how many experts the layer holds.
 _MEASURED = (
@@ -160,9 +174,10 @@ _MEASURED = (
     (2304 * 896, 64, _SMALL_EXPERTS),
     (2560 * 768, 128, _MANY_EXPERTS),
 )
-# Fewer tokens than fill a pass take one pass of the pairs an even routing
-# sends here and a quarter more (at 2048 tokens of the wide geometry that would
-# be 1280 rows in 4.8 ms; capped, two passes take 7.0).
+# A pass is the pairs an even routing sends here and a quarter more, in whole
+# row tiles, up to the set's cap: a layer whose routing is within a quarter of
+# even takes one pass where no cap binds, and a skewed one as many more as
+# serve every pair.
 _PASS_SLACK = 1.25
 
 
@@ -258,11 +273,12 @@ def grouped_combine(n_held: int, n_routed: int) -> str:
     """How the grouped path's rows get back to their tokens: ``"gather"``
     where the layer holds every expert (a token's ``k`` pairs are all here, so
     the sorted order is a permutation of all pairs and its inverse finds
-    them), ``"scatter"`` where it holds a share (a token has 0 to ``k`` local
-    pairs: only those are moved). ``n_routed`` is the router's width: a layer
+    them), ``"segment_sum"`` where it holds a share (a token has 0 to ``k``
+    local pairs: only those are moved, into token order, and summed a token by
+    ``ops/moe_combine.py``). ``n_routed`` is the router's width: a layer
     with experts that have no weights never holds every output, since an
     identity pair has no row to find."""
-    return "gather" if n_held == n_routed else "scatter"
+    return "gather" if n_held == n_routed else "segment_sum"
 
 
 def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int, row_tile: int, combine: str):
@@ -285,7 +301,7 @@ def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int, row_tile: int
     padded = jnp.concatenate([order, jnp.zeros((pass_rows,), jnp.int32)])  # a pass may read past the end
 
     def one_pass(p):
-        """The kernels' rows for pass ``p``'s sorted pairs; also the pairs, which rows are pairs at all, and their tokens."""
+        """The kernels' rows for pass ``p``'s sorted pairs; also the pairs and which rows are pairs at all."""
         lo = p * pass_rows
         live = (lo + jnp.arange(pass_rows, dtype=jnp.int32)) < n_local
         pair = lax.dynamic_slice(padded, (lo,), (pass_rows,))
@@ -294,7 +310,7 @@ def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int, row_tile: int
         group_sizes = in_pass[1:] - in_pass[:-1]
         xs = x[token]
         mm = lambda a, w: grouped_matmul(a, w, group_sizes, tm=row_tile)  # noqa: E731
-        return mm(_silu_gate(mm(xs, w1), mm(xs, w3), x.dtype), w2), pair, live, token
+        return mm(_silu_gate(mm(xs, w1), mm(xs, w3), x.dtype), w2), pair, live
 
     if combine == "gather":
         # every pair is local: the passes are counted at trace time, their rows stay in the kernel's dtype in
@@ -314,11 +330,13 @@ def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int, row_tile: int
     w_flat = weights.reshape(-1)
 
     def add_pass(p, y):
-        ys, pair, live, token = one_pass(p)
+        ys, pair, live = one_pass(p)
         with jax.named_scope("moe/combine"):
-            # dead rows add zeros to token 0: the scatter-add costs by the distinct rows it touches
-            ys = jnp.where(live[:, None], ys.astype(jnp.float32) * w_flat[pair][:, None], 0.0)
-            return y.at[token].add(ys)
+            # the flat pair list is in token order, so the pass's pairs sorted by their number are sorted by token:
+            # each brings the row it has in the pass and its weight along, and the rows past the last pair go last
+            in_order, row, w = lax.sort(
+                (jnp.where(live, pair, t * k), jnp.arange(pass_rows, dtype=jnp.int32), w_flat[pair]), num_keys=1)
+            return moe_combine(y, ys[row], w, in_order // k, row_tile=row_tile)
 
     n_pass = (n_local + pass_rows - 1) // pass_rows
     y = lax.fori_loop(0, n_pass, add_pass, jnp.zeros((t, x.shape[-1]), jnp.float32))
@@ -421,7 +439,7 @@ class MoELayer(nn.Module):
             taps = {
                 "pairs_routed": jnp.asarray(t * c.num_experts_per_tok, jnp.int32),
                 "pairs_local": pairs_local,
-                "pairs_gathered": pairs_local if back == "gather" else jnp.zeros((), jnp.int32),
+                "pairs_gathered": pairs_local if back else jnp.zeros((), jnp.int32),
                 "pairs_dropped": unserved.astype(jnp.int32),
                 "passes": passes.astype(jnp.int32),
                 "expert_load_max": load.max(),

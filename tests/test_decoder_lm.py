@@ -222,7 +222,7 @@ def test_a_prompt_pass_cut_into_chunks_is_the_uncut_forward(seed):
     for load in stats.values():
         assert int(load["pairs_routed"]) == 2 * b * n and int(load["pairs_dropped"]) == 0
         assert 0 < int(load["pairs_local"]) < 2 * b * n and int(load["expert_load_max"]) >= b * n // 16
-        assert int(load["pairs_gathered"]) == 0  # a share of the experts: the grouped path's rows are scatter-added
+        assert int(load["pairs_gathered"]) == int(load["pairs_local"])  # a share of the experts: every local pair's row read back by the segment sum
     rows = np.array([0, b - 1])
     want = np.asarray(reference.logits(flat_dict(params), ids[rows], reference_cfg(config), last=1))[:, 0]
     np.testing.assert_allclose(np.asarray(logits)[rows], want, atol=TOL, rtol=0)
@@ -407,17 +407,19 @@ def test_no_pair_is_dropped_under_a_skewed_routing(held):
     np.testing.assert_allclose(np.asarray(grouped), np.asarray(want), atol=TOL, rtol=0)
 
 
-def test_a_share_of_the_experts_keeps_the_scatter_add():
+def test_a_share_of_the_experts_sums_its_rows_by_token_without_a_scatter_add():
     """The rule between the grouped path's two combines is the
     configuration's own fact: a layer that holds 4 of 16 experts has 0 to 2
-    local pairs a token, moves only those, and adds them into the tokens'
-    buffer as before (``pairs_gathered`` 0, a float32 scatter-add in its
-    jaxpr, ``moe_combine`` ``"scatter"`` in the ``compile`` row); the whole
-    layer, every expert held, gathers all of them and has no such scatter."""
+    local pairs a token, moves only those, brought into token order, and sums
+    them a token in ``ops/moe_combine.py``'s kernel (``pairs_gathered`` the
+    local pairs, **no** float32 scatter-add of rows in its jaxpr, one pass at
+    an even routing, ``moe_combine`` ``"segment_sum"`` in the ``compile`` row);
+    the whole layer, every expert held, gathers all pairs and has no such
+    scatter or kernel either."""
     tokens = CUTS.grouped_min_tokens
     config, x, params = moe_layer_and_weights(0, tokens)
-    adds_rows = lambda c, p: bool(re.search(  # noqa: E731
-        rf"f32\[{tokens},64\] = scatter-add", str(jax.make_jaxpr(moe.MoELayer(c).apply)({"params": p}, x))))
+    jaxpr = lambda c, p: str(jax.make_jaxpr(moe.MoELayer(c).apply)({"params": p}, x))  # noqa: E731
+    adds_rows = lambda c, p: bool(re.search(rf"f32\[{tokens},64\] = scatter-add", jaxpr(c, p)))  # noqa: E731
 
     def tapped(c, p):
         with probes.collecting(probes.ProbeConfig(scopes=("moe.*",))) as col:
@@ -428,13 +430,15 @@ def test_a_share_of_the_experts_keeps_the_scatter_add():
     share = dataclasses.replace(config, n_held_experts=4, held_experts_start=4)
     p = {k: v[4:8] if k.startswith("experts_") else v for k, v in params["params"].items()}
     load = tapped(share, p)
-    assert int(load["pairs_gathered"]) == 0 < int(load["pairs_local"]) < int(load["pairs_routed"]) == 2 * tokens
-    assert adds_rows(share, p) and moe.grouped_combine(4, 16) == "scatter"
+    assert 0 < int(load["pairs_gathered"]) == int(load["pairs_local"]) < int(load["pairs_routed"]) == 2 * tokens
+    assert int(load["passes"]) == 1 and int(load["pairs_dropped"]) == 0
+    assert not adds_rows(share, p) and "moe_combine_t" in jaxpr(share, p) and moe.grouped_combine(4, 16) == "segment_sum"
     load = tapped(config, params["params"])
     assert int(load["pairs_gathered"]) == int(load["pairs_local"]) == int(load["pairs_routed"]) == 2 * tokens
-    assert not adds_rows(config, params["params"]) and moe.grouped_combine(16, 16) == "gather"
+    assert not adds_rows(config, params["params"]) and "moe_combine_t" not in jaxpr(config, params["params"])
+    assert moe.grouped_combine(16, 16) == "gather"
     row = lambda c: generation._decoder_of(DecoderLanguageModel(c)).compile_row(4, 8, 3, jnp.float32)  # noqa: E731
-    assert row(tiny_config())["moe_combine"] == "scatter"
+    assert row(tiny_config())["moe_combine"] == "segment_sum"
     assert row(tiny_config(n_held_experts=16, held_experts_start=0))["moe_combine"] == "gather"
 
 
@@ -490,13 +494,13 @@ def test_scopes_and_taps_reach_the_compiled_programs_and_the_registry(tmp_path):
     assert snap["moe_pairs_routed_total"] == 2 * 2 * (32 + 4 + 4)
     assert 0 < snap["moe_pairs_local_total"] < snap["moe_pairs_routed_total"]
     assert snap["moe_pairs_dropped_total"] == 0 and snap["moe_expert_load_max"] >= 1
-    assert snap["moe_pairs_gathered_total"] == 0
+    assert snap["moe_pairs_gathered_total"] == 0  # 32 prompt tokens and steps of 4: every call under the grouped path's cut
     import json
 
     rows = [json.loads(line) for line in open(tmp_path / "events.jsonl")]
     compiles = [r for r in rows if r.get("event") == "compile" and "latent_cache_row_bytes" in r]
     assert compiles and compiles[0]["latent_cache_row_bytes"] == 24 * 4 and compiles[0]["latent_cache_capacity"] == 11
-    assert compiles[0]["moe_combine"] == "scatter"
+    assert compiles[0]["moe_combine"] == "segment_sum"
     request = [r for r in rows if r.get("event") == "request"][-1]
     assert request["moe_pairs_dropped"] == 0 and request["moe_local_share"] == pytest.approx(
         snap["moe_pairs_local_total"] / snap["moe_pairs_routed_total"], abs=1e-6)

@@ -397,7 +397,7 @@ def test_scopes_taps_and_the_compile_row(tmp_path):
     assert compiled["kv_cache_window_layers"] == 4 and compiled["kv_cache_full_layers"] == 2
     row_bytes = 2 * 2 * 16 * 4
     assert compiled["kv_cache_full_bytes"] == 4 * (9 + 6 + 1) * row_bytes * 2
-    assert compiled["kv_cache_window_bytes"] == 4 * (WINDOW + 4) * row_bytes * 4 and compiled["moe_combine"] == "scatter"
+    assert compiled["kv_cache_window_bytes"] == 4 * (WINDOW + 4) * row_bytes * 4 and compiled["moe_combine"] == "segment_sum"
     # off the chip (and at a head of 16 channels anywhere) the step's attention is XLA's products over ``_row_scatter``'s writes
     assert compiled["verify_attention"] == {"full": "xla", "window": "xla"} and compiled["gqa_verify"] == []
     request = [r for r in rows if r.get("event") == "request"][-1]

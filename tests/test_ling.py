@@ -519,7 +519,7 @@ def test_the_instrumented_generator_taps_the_state(tmp_path):
     compile_row = next(r for r in rows if r.get("event") == "compile" and "kda_layers" in r)
     assert compile_row["kda_layers"] == 3 and compile_row["kda_state_dtype"] == "float32" and compile_row["latent_cache_layers"] == 1
     assert compile_row["kda_state_bytes"] == 3 * 2 * 4 * 16 * 16 * 4 and compile_row["kda_conv_bytes"] == 3 * 2 * 3 * 3 * 64 * 4
-    assert compile_row["kda_chunk"] == 16 and compile_row["moe_combine"] == "scatter" and isinstance(compile_row["kda"], list)
+    assert compile_row["kda_chunk"] == 16 and compile_row["moe_combine"] == "segment_sum" and isinstance(compile_row["kda"], list)
     assert compile_row["latent_cache_bytes"] == 2 * 13 * 24 * 4
 
 
@@ -554,4 +554,4 @@ def test_the_published_share_counts_5_231_790_016_parameters():
     assert shapes["params"]["head"].shape == (2560, 39296) and shapes["params"]["embedding"].shape == (39296, 2560)
     row = generation._decoder_of(family.model()).compile_row(128, 2048, 256, jnp.bfloat16)
     assert row["kda_layers"] == 6 and row["kda_state_bytes"] == 6 * 128 * 2_097_152 and row["kda_chunk"] == kda.CHUNK
-    assert row["latent_cache_layers"] == 1 and row["latent_cache_bytes"] == 128 * 2304 * 1152 and row["moe_combine"] == "scatter"
+    assert row["latent_cache_layers"] == 1 and row["latent_cache_bytes"] == 128 * 2304 * 1152 and row["moe_combine"] == "segment_sum"

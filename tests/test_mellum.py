@@ -208,7 +208,7 @@ def test_the_gather_combine_is_counted_where_it_ran(tmp_path):
 
 
 def test_the_benchmarks_two_configurations_sit_on_either_side_of_the_rule():
-    """``mellum2-12b-pp4`` holds its 64 experts and gathers; ``deepseek-v3-ep16`` holds 16 of 256 and keeps the scatter-add."""
+    """``mellum2-12b-pp4`` holds its 64 experts and gathers; ``deepseek-v3-ep16`` holds 16 of 256 and sums its rows by token (``ops/moe_combine.py``)."""
     from benchmarks import run
 
     def combine(name):
@@ -216,7 +216,7 @@ def test_the_benchmarks_two_configurations_sit_on_either_side_of_the_rule():
         model = importlib.import_module(f"benchmarks.families.{config['family']}").Family(config).model()
         return generation._decoder_of(model).compile_row(32, 8192, 256, jnp.bfloat16)["moe_combine"]
 
-    assert combine("mellum2-12b-pp4") == "gather" and combine("deepseek-v3-ep16") == "scatter"
+    assert combine("mellum2-12b-pp4") == "gather" and combine("deepseek-v3-ep16") == "segment_sum"
 
 
 def test_the_prompt_pass_through_the_flash_kernels_matches_the_reference():
@@ -314,8 +314,11 @@ def test_both_expert_paths_serve_every_pair_when_all_experts_are_held(case, monk
 
 
 def test_the_cuts_follow_the_geometry_they_were_measured_at():
-    assert moe._cuts(7168, 2048, 16) == (384, 256, 1024)  # DeepSeek-V3's share keeps PR 28's values
-    assert moe._cuts(6144, 2048, 16) == (384, 256, 1024)  # K-EXAONE's share: its own readings came out the same (PR 34)
+    wide = moe._cuts(7168, 2048, 16)
+    assert wide == (384, 256, 65536)  # DeepSeek-V3's share keeps PR 28's crossing and tile; no cap on a pass since PR 50
+    assert moe._cuts(6144, 2048, 16) == wide  # K-EXAONE's share: its own readings came out the same (PR 34)
+    # a share-held pass is what an even routing sends here and a quarter more, in whole row tiles: a prompt chunk's 8192 tokens
+    assert moe._pass_rows(8192 * 8, 16 / 256, wide) == 5120 and moe._pass_rows(8192 * 8, 16 / 128, wide) == 10240
     small = moe._cuts(2304, 896, 64)
     assert small == moe._cuts(64, 32, 8) and small.pass_rows % small.row_tile == 0 and small.row_tile % 128 == 0
 

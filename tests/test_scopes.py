@@ -65,9 +65,12 @@ RULE_CASES = [
     (f"{CHUNK}/ffn/moe/experts/while/body/jit(grouped_matmul)/moe_experts_prefill_m1024_k2048_n6144/pallas_call",
      OpScope("prefill", "moe/experts", "prefill/prefill/chunk_io/prefill/DecoderLanguageModel.ffn_layer/layer_2.feed_forward/ffn/"
              "moe/experts/moe_experts_prefill_m1024_k2048_n6144")),
-    (f"{CHUNK}/ffn/moe/experts/while/body/moe/combine/scatter-add",
+    (f"{CHUNK}/ffn/moe/experts/while/body/moe/combine/sort",
      OpScope("prefill", "moe/combine", "prefill/prefill/chunk_io/prefill/DecoderLanguageModel.ffn_layer/layer_2.feed_forward/ffn/"
              "moe/experts/moe/combine")),
+    (f"{CHUNK}/ffn/moe/experts/while/body/moe/combine/jit(moe_combine)/moe_combine_t8192_r2560_h7168/pallas_call",
+     OpScope("prefill", "moe/combine", "prefill/prefill/chunk_io/prefill/DecoderLanguageModel.ffn_layer/layer_2.feed_forward/ffn/"
+             "moe/experts/moe/combine/moe_combine_t8192_r2560_h7168")),
     (f"{CHUNK}/ffn_norm/mul", OpScope("prefill", "norm", "prefill/prefill/chunk_io/prefill/DecoderLanguageModel.ffn_layer/"
                                       "layer_2.feed_forward/ffn_norm")),
     ("jit(fn)/prefill/prefill/chunk_io/while/body/dynamic_update_slice", OpScope("prefill", "chunk_io", "prefill/prefill/chunk_io")),

@@ -333,7 +333,7 @@ def test_token_major_latent_flash_forward_lowers(one_chip, mosaic, heads):
     assert (plan["block_q"], plan["band_rows"], plan["tiles_run"], plan["tiles_skipped"]) == (1024, 256, 40, 24)
 
 
-@pytest.mark.parametrize("rows", [1024, 256], ids=["prompt_chunk", "smallest_pass"])
+@pytest.mark.parametrize("rows", [5120, 256], ids=["prompt_chunk", "smallest_pass"])
 @pytest.mark.parametrize("k,n", [(DSV3_HIDDEN, DSV3_EXPERT_WIDTH), (DSV3_EXPERT_WIDTH, DSV3_HIDDEN)], ids=["up", "down"])
 def test_grouped_expert_product_lowers(one_chip, monkeypatch, rows, k, n):
     """The held experts' grouped product (a traced number of visits in the
@@ -344,13 +344,34 @@ def test_grouped_expert_product_lowers(one_chip, monkeypatch, rows, k, n):
     moe = importlib.import_module("perceiver_io_tpu.core.moe")
     monkeypatch.setattr(gm, "_interpret_default", lambda: False)
     cuts = moe._cuts(DSV3_HIDDEN, DSV3_EXPERT_WIDTH, DSV3_HELD)
-    assert cuts == (384, 256, 1024)  # the values PR 28 measured at this geometry stay
-    assert moe._pass_rows(8192 * 8, 16 / 256, cuts) == 1024 and moe._pass_rows(cuts.grouped_min_tokens * 8, 16 / 256, cuts) == 256
+    assert cuts == (384, 256, 65536)  # the crossing and the tile PR 28 measured at this geometry stay; a pass has no cap since PR 50
+    assert moe._pass_rows(8192 * 8, 16 / 256, cuts) == 5120 and moe._pass_rows(cuts.grouped_min_tokens * 8, 16 / 256, cuts) == 256
     lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
     rhs = jax.ShapeDtypeStruct((DSV3_HELD, k, n), jnp.bfloat16, sharding=one_chip)
     sizes = jax.ShapeDtypeStruct((DSV3_HELD,), jnp.int32, sharding=one_chip)
     text = _compile(lambda a, w, s: gm.grouped_matmul(a, w, s, tm=cuts.row_tile), lhs, rhs, sizes)
     assert f"moe_experts_prefill_m{rows}_k{k}_n{n}" in text and "tpu_custom_call" in text
+
+
+# tokens of a prompt chunk, rows of its pass (``moe._pass_rows``), hidden size, row tile: the share-held cells' expert layers
+COMBINE_SHAPES = {"ling": (8192, 4096, 2560, 128), "kexaone": (8192, 10240, 6144, 256), "dsv3": (8192, 5120, 7168, 256),
+                  "ling_step": (128, 384, 2560, 128)}
+
+
+@pytest.mark.parametrize("geometry", sorted(COMBINE_SHAPES))
+def test_the_segment_sum_combine_lowers(one_chip, monkeypatch, geometry):
+    """``ops/moe_combine.py``'s kernel (a traced number of visits, the tokens'
+    buffer aliased and updated in place, a row added at a time at an offset
+    read from prefetched scalars) at a prompt chunk of each share-held
+    geometry and at Ling's decode step."""
+    gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
+    mc = importlib.import_module("perceiver_io_tpu.ops.moe_combine")
+    monkeypatch.setattr(gm, "_interpret_default", lambda: False)
+    t, r, h, tile = COMBINE_SHAPES[geometry]
+    shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=one_chip)  # noqa: E731
+    text = _compile(lambda y, rows, w, tokens: mc.moe_combine(y, rows, w, tokens, row_tile=tile),
+                    shape((t, h), jnp.float32), shape((r, h), jnp.bfloat16), shape((r,), jnp.float32), shape((r,), jnp.int32))
+    assert f"moe_combine_t{t}_r{r}_h{h}" in text and "tpu_custom_call" in text and "output_to_operand_aliasing" in text
 
 
 # ------------------------------------------ Mellum 2: the windowed forward, the cell's generator, what stays the parent's
@@ -511,7 +532,7 @@ def test_the_kexaone_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch
     assert total < 14.9e9, f"{total / 1e9:.2f} GB"
     text = compiled.as_text()
     assert set(re.findall(r"flash_fwd_q\d+_kv\d+(?:_w\d+)?", text)) == {"flash_fwd_q1024_kv1024", "flash_fwd_q1024_kv1024_w128"}
-    assert "moe_experts_prefill_m1024_k6144_n2048" in text and "moe_experts_prefill_m1024_k2048_n6144" in text
+    assert "moe_experts_prefill_m10240_k6144_n2048" in text and "moe_experts_prefill_m10240_k2048_n6144" in text  # a chunk's one pass
 
 
 @pytest.mark.parametrize("slots,window", [(1552, None), (144, 128)], ids=["growing", "ring"])
@@ -578,12 +599,15 @@ def test_the_speculative_step_is_one_kernel_a_layer_over_row_major_caches(one_ch
 # lower to the parent's programs (``tools/step_hlo.py --same`` says the same of
 # the compiled modules). A PR that means to change one of these programs
 # updates its hash: Jamba's is PR 47's own (the scan kernel reads and writes
-# the rows as the projections leave them); the other three stand since PR 44.
+# the rows as the projections leave them); DeepSeek-V3's and LongCat's are PR
+# 50's own (a share-held expert layer's rows return to their tokens through
+# ``ops/moe_combine.py``, one pass a chunk: K-EXAONE's and Ling's generators,
+# which change with them, are not pinned); Mellum's stands since PR 44.
 PARENT_GENERATORS = {
     "mellum2-pp4-decode-b32": ("mellum", "08eadbcfdc5bc1c8749a61bdbc7da024abab0e97773459e0829fdfd8910eb692"),
     "jamba2-3b-decode-b256": ("jamba", "37c48df32f761ff881f8e7a99ecd629d39bf5825793aa83afac1ef2a1f1020cd"),
-    "dsv3-ep16-decode-b64": ("deepseek_v3", "edac860ab3e61a0d6a6e7a1308f0ab55668b3895726f88303d7687bb98ed8ffb"),
-    "longcat-ep32-decode-b64": ("longcat_flash", "9e78ee1b21106e65e0891d3d5ddc75aed1508da468a0eb9c439d10ebe413f936"),
+    "dsv3-ep16-decode-b64": ("deepseek_v3", "258bb95e3be637e0b1cf56d071f5b75d0c27a9d92b3e6396aec1710824cc5618"),
+    "longcat-ep32-decode-b64": ("longcat_flash", "322bf32d3f3a753b67674ab83f0416a7b113f2d9f67407c6dc65b2b826dd4e22"),
 }
 
 
@@ -592,6 +616,38 @@ def test_a_generator_without_a_speculative_step_lowers_to_the_parents_program(on
     family, golden = PARENT_GENERATORS[workload]
     _cell_generator(workload, family, one_chip, monkeypatch)
     assert _LOWERED[workload] == golden
+
+
+# a share-held cell's family, the hidden size, and the kernels of a prompt chunk's pass: combine and down-projection
+SHARE_HELD_GENERATORS = {
+    "dsv3-ep16-decode-b64": ("deepseek_v3", 7168, "moe_combine_t8192_r5120_h7168", "moe_experts_prefill_m5120_k2048_n7168"),
+    "kexaone-ep8-mtp-decode-b64": ("exaone_moe", 6144, "moe_combine_t8192_r10240_h6144", "moe_experts_prefill_m10240_k2048_n6144"),
+    "longcat-ep32-decode-b64": ("longcat_flash", 6144, "moe_combine_t4096_r1280_h6144", "moe_experts_prefill_m1280_k2048_n6144"),
+    "ling3-ep4-decode-b128-p2k": ("ling", 2560, "moe_combine_t8192_r4096_h2560", "moe_experts_prefill_m4096_k768_n2560"),
+}
+
+
+@pytest.mark.parametrize("workload", sorted(SHARE_HELD_GENERATORS))
+def test_a_share_held_generator_sums_its_rows_by_token_and_fits_the_chip(one_chip, mosaic, monkeypatch, workload):
+    """The four cells whose expert layers hold a share of the experts, as the
+    benchmark builds them: no float32 scatter-add of rows into a chunk's
+    tokens anywhere in the compiled generator (PR 50), the segment-sum kernel
+    at the rows of a pass sized for an even routing (``moe._pass_rows``: one
+    pass a chunk a layer, four or five of 4096 rows at Ling's geometry, where
+    the scatter-add's passes were 1024 rows), and
+    the program still under the 14.9 GB the cells are held to (Ling's counted
+    as its own test counts it: without the aliased states and cache that the
+    analysis holds twice)."""
+    import re
+
+    family, hidden, combine, down = SHARE_HELD_GENERATORS[workload]
+    compiled = _cell_generator(workload, family, one_chip, monkeypatch)
+    text = compiled.as_text()
+    assert not re.search(rf"f32\[\d+,{hidden}\]\{{[^}}]*\}} scatter\(", text)
+    assert combine in text and down in text and "moe_experts_prefill_m1024_" not in text
+    twice = 6 * 128 * 32 * 128 * 128 * 4 + 128 * 2304 * 576 * 2 if family == "ling" else 0
+    total = _device_bytes(compiled) - twice
+    assert total < 14.9e9, f"{total / 1e9:.2f} GB"
 
 
 # ------------------------------------------ LongCat-Flash: the shortcut-connected block at the cell's sizes
@@ -613,7 +669,7 @@ def test_the_longcat_cells_generator_fits_the_chip(one_chip, mosaic, monkeypatch
     assert total < 14.9e9, f"{total / 1e9:.2f} GB"
     text = compiled.as_text()
     assert set(re.findall(r"flash_\w*fwd_q\d+_kv\d+(?:_[wh]\d+)?", text)) == {"flash_mla_fwd_q1024_kv1024_h64"}
-    assert "moe_experts_prefill_m1024_k6144_n2048" in text and "moe_experts_prefill_m1024_k2048_n6144" in text
+    assert "moe_experts_prefill_m1280_k6144_n2048" in text and "moe_experts_prefill_m1280_k2048_n6144" in text  # a chunk's one pass
     # the prompt pass lays each cache's rows out row-major (a pad to the capacity); a step's append is the kernel's (below)
     assert len(re.findall(r"bf16\[64,1536,576\]\{2,1,0[^}]*\} pad\(", text)) == 8
 

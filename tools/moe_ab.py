@@ -41,9 +41,23 @@ two local pairs a token; the expert layer alone).
   fastest tile ``moe._ROW_TILE``, the fastest rows a pass at 8192 tokens
   ``moe._PASS_ROWS`` (since PR 32 the three are ``moe._cuts`` of the geometry).
   The grouped path's combine is the geometry's own (``moe.grouped_combine``:
-  the scatter-add at ``dsv3``, the inverse-permutation gather at ``mellum``,
-  where every expert is held); at ``mellum``'s prompt chunk each variant runs
-  both, ``.../gather`` beside ``.../scatter`` (PR 33);
+  the segment sum where a share is held, ``dsv3``, ``kexaone`` and ``ling``;
+  the inverse-permutation gather at ``mellum``, where every expert is held);
+  at ``mellum``'s prompt chunk each variant runs both, ``.../gather`` beside
+  ``.../scatter`` (PR 33); at a share-held geometry's prompt chunk (and at
+  ``ling``'s step of 128 tokens) ``..._rows1024/scatter`` is the parent's
+  layer of before PR 50, XLA's scatter-add in passes of 1024 rows, kept in
+  this file;
+- the share-held combine alone (``--only combine/``, PR 50; ``dsv3``,
+  ``kexaone``, ``ling``): at a prompt chunk's 8192 tokens and the program's
+  pass, the parent's scatter-add (in passes of 1024 rows, whole, and over
+  token-sorted rows with the indices declared sorted) against the program's
+  combine, its parts cumulatively (``index``: the sort into token order;
+  ``index_gather``: and the row gather; ``program``: and
+  ``ops/moe_combine.py``'s kernel), the kernel at other row tiles and with
+  the add on the matrix unit (``onehot``, kept in this file); every result
+  is compared with the scatter-add's, to the bit at tokens of at most two
+  rows;
 - grouped-query decode attention (``--geom mellum``): ``core/gqa.py``'s two
   batched products against a Pallas kernel kept in this file, over both caches;
   and the prompt pass's flash forward (``flash_attention_gqa``) on one
@@ -83,10 +97,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 H, WIDTH, EXPERTS, ROUTED, TOP_K = 7168, 2048, 16, 256, 8
 LAYER_TOKENS = (64, 128, 256, 384, 512, 1024, 2048, 8192)
-# (row tile, rows a pass): 0 rows is what the program takes (``moe._pass_rows``: at most 1024)
+# (row tile, rows a pass): 0 rows is what the program takes (``moe._pass_rows``: an even routing's pairs and a quarter more)
 LAYER_TILINGS = ((128, 0), (256, 0), (512, 0))
-# other rows a pass at a prompt chunk's tokens: the scatter-add of a pass has a sweet spot
-SHORT_PASSES = {8192: ((256, 512), (256, 768), (256, 1536), (256, 2048), (256, 3072), (256, 5120), (256, 8192))}
+# other rows a pass at a prompt chunk's tokens (the parent's scatter-add had a sweet spot at 1024: ``.../scatter``)
+SHORT_PASSES = {8192: ((256, 1024), (256, 2048), (256, 8192))}
 GEOM = "dsv3"
 
 
@@ -102,11 +116,11 @@ def set_geometry(name: str) -> None:
         H, WIDTH, EXPERTS, ROUTED, TOP_K = 2560, 768, 128, 512, 8
         LAYER_TOKENS = (128, 256, 512, 1024, 8192)
         LAYER_TILINGS = ((16, 0), (32, 0), (64, 0), (128, 0), (256, 0))
-        SHORT_PASSES = {8192: ((128, 512), (128, 2048), (128, 4096), (128, 16384), (64, 1024), (256, 2048))}
+        SHORT_PASSES = {8192: ((128, 1024), (128, 4096), (128, 8192), (128, 16384)), 128: ()}
     if name == "kexaone":
         H, WIDTH, EXPERTS, ROUTED, TOP_K = 6144, 2048, 16, 128, 8
         LAYER_TOKENS = (128, 256, 384, 512, 8192)
-        SHORT_PASSES = {8192: ((256, 512), (256, 768), (256, 1536), (256, 2048), (256, 4096), (256, 10240))}
+        SHORT_PASSES = {8192: ((256, 1024), (256, 2048), (256, 4096))}
 
 
 import functools  # noqa: E402
@@ -182,6 +196,35 @@ def variants():
             return moe.experts_grouped(x, local, weights, w1, w3, w2, rows, tile, combine)[0]
         return run
 
+    def grouped_scatter(tile, pass_rows):
+        """The share-held side as it stood until PR 50 (``experts_grouped`` with XLA's scatter-add of a pass's weighted
+        rows into the tokens' buffer), kept here as the variant the program's combine is read against."""
+        def run(x, local, weights, w1, w3, w2):
+            t, k = local.shape
+            rows = pass_rows or min(1024, moe._pass_rows(local.size, EXPERTS / ROUTED, moe._cuts(H, WIDTH, EXPERTS)._replace(row_tile=tile)))
+            flat = local.reshape(-1)
+            order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+            sizes = jnp.zeros((EXPERTS + 1,), jnp.int32).at[flat].add(1)[:EXPERTS]
+            offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
+            n_local = offsets[-1]
+            padded = jnp.concatenate([order, jnp.zeros((rows,), jnp.int32)])
+            w_flat = weights.reshape(-1)
+
+            def add_pass(p, y):
+                lo = p * rows
+                live = (lo + jnp.arange(rows, dtype=jnp.int32)) < n_local
+                pair = jax.lax.dynamic_slice(padded, (lo,), (rows,))
+                token = jnp.where(live, pair // k, 0)
+                in_pass = jnp.clip(offsets, lo, lo + rows) - lo
+                xs, group_sizes = x[token], in_pass[1:] - in_pass[:-1]
+                mm = lambda a, w: grouped_matmul(a, w, group_sizes, tm=tile)  # noqa: E731
+                ys = mm(moe._silu_gate(mm(xs, w1), mm(xs, w3), x.dtype), w2)
+                with jax.named_scope("moe/combine"):
+                    return y.at[token].add(jnp.where(live[:, None], ys.astype(jnp.float32) * w_flat[pair][:, None], 0.0))
+
+            return jax.lax.fori_loop(0, (n_local + rows - 1) // rows, add_pass, jnp.zeros((t, x.shape[-1]), jnp.float32))
+        return run
+
     def dense(x, local, weights, w1, w3, w2):
         combine = (jax.nn.one_hot(local, EXPERTS, dtype=jnp.float32) * weights[:, :, None]).sum(axis=1)
         return moe.experts_dense(x, combine, w1, w3, w2)
@@ -207,10 +250,15 @@ def variants():
                 for suffix, combine in combines.items():
                     name = f"experts_layer/T{t}/grouped_tm{tile}_rows{pass_rows}{suffix}"
                     out[name] = (grouped(tile, pass_rows, combine), layer, "layer")
+                # where a share is held, the parent's scatter-add beside the program's combine, at the parent's passes of 1024
+                if own != "gather" and t in SHORT_PASSES and not pass_rows:
+                    out[f"experts_layer/T{t}/grouped_tm{tile}_rows1024/scatter"] = (grouped_scatter(tile, pass_rows), layer, "layer")
+    if own != "gather":
+        out.update(combine_variants())
     if GEOM == "ling":  # the expert layer alone: its attentions are the latent cells' and ``tools/kda_ab.py``'s
-        return {k: v for k, v in out.items() if "experts_layer" in k}
+        return {k: v for k, v in out.items() if "experts_layer" in k or "combine/" in k}
     if GEOM == "kexaone":
-        return {**{k: v for k, v in out.items() if "experts_layer" in k}, **kexaone_attention_variants()}
+        return {**{k: v for k, v in out.items() if "experts_layer" in k or "combine/" in k}, **kexaone_attention_variants()}
     if GEOM == "mellum":
         from perceiver_io_tpu.core.cache import KVCache
         from perceiver_io_tpu.core.gqa import cached_decode_attention
@@ -270,6 +318,122 @@ def variants():
         out[f"mla_absorb/h{heads}_s{capacity}/xla_x{steps}"] = (absorbed(False), shapes, "mla")
         out[f"mla_absorb/h{heads}_s{capacity}/kernel_x{steps}"] = (absorbed(True), shapes, "mla")
     out.update(mla_expand_variants())
+    return out
+
+
+COMBINE_TOKENS = 8192  # a prompt chunk
+
+
+def _onehot_kernel(offsets_ref, tile_ids_ref, row_tile_ids_ref, rows_ref, weights_ref, tokens_ref, y_ref, out_ref, *, tt):
+    """The add on the matrix unit (not the program's): a visit's selection of rows by token times the weighed rows,
+    float32 operands at the highest precision. A run of two rows is summed inside the product, so a token's bits
+    are not the scatter-add's; a dead row must be finite."""
+    v = pl.program_id(0)
+    tile = tile_ids_ref[v]
+
+    @pl.when((v == 0) | (tile_ids_ref[jnp.maximum(v - 1, 0)] != tile))
+    def _start():
+        out_ref[...] = y_ref[...]
+
+    weighed = rows_ref[...].astype(jnp.float32) * weights_ref[...]
+    mine = tokens_ref[...] - tile * tt == jax.lax.broadcasted_iota(jnp.int32, (tt, tokens_ref.shape[1]), 0)
+    out_ref[...] += jnp.dot(mine.astype(jnp.float32), weighed, precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32)
+
+
+def combine_onehot(y, rows, weights, tokens, *, row_tile: int):
+    """``ops/moe_combine.py::moe_combine``'s contract with the add on the matrix unit."""
+    from perceiver_io_tpu.ops.moe_combine import token_tile, visits
+
+    (t, h), r = y.shape, rows.shape[0]
+    tt = token_tile(t)
+    offsets, tile_ids, row_tile_ids, num_visits = visits(tokens, t, row_tile)
+    return pl.pallas_call(
+        functools.partial(_onehot_kernel, tt=tt),
+        name=f"moe_combine_onehot_t{t}_r{r}_h{h}",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(num_visits,),
+            in_specs=[
+                pl.BlockSpec((row_tile, h), lambda v, off, tid, rid: (rid[v], 0)),
+                pl.BlockSpec((row_tile, 1), lambda v, off, tid, rid: (rid[v], 0)),
+                pl.BlockSpec((1, row_tile), lambda v, off, tid, rid: (0, rid[v])),
+                pl.BlockSpec((tt, h), lambda v, off, tid, rid: (tid[v], 0)),
+            ],
+            out_specs=pl.BlockSpec((tt, h), lambda v, off, tid, rid: (tid[v], 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((t, h), jnp.float32),
+        input_output_aliases={6: 0},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=100 * 1024 * 1024),
+        interpret=False,  # this tool runs on the chip or compiles for one
+    )(offsets, tile_ids, row_tile_ids, rows, weights[:, None], tokens[None, :], y)
+
+
+def combine_variants():
+    """The way back to the tokens alone, at a prompt chunk's tokens and the rows of the program's pass (``moe._pass_rows``):
+    a pass's rows (random, in the grouped kernel's dtype, sorted by expert as the seeded routing sorts them) to the
+    tokens' float32 buffer. ``program`` is ``core/moe.py``'s (the pairs sorted into token order, the row gather,
+    ``ops/moe_combine.py``'s kernel); ``index`` and ``index_gather`` stop after its first and second part;
+    ``row_tile<n>`` runs the kernel at another row tile; ``onehot`` puts the add on the matrix unit; ``scatter_add`` is
+    the parent's (XLA's scatter-add in passes of 1024 rows), ``scatter_add_whole`` the same in one pass, and
+    ``scatter_add_sorted`` XLA's scatter-add over the token-ordered rows, the indices declared sorted. Every variant
+    that ends in the buffer is compared with ``scatter_add`` after the run."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    from perceiver_io_tpu.core import moe
+    from perceiver_io_tpu.ops.moe_combine import moe_combine
+
+    t, k = COMBINE_TOKENS, TOP_K
+    cuts = moe._cuts(H, WIDTH, EXPERTS)
+    rows = moe._pass_rows(t * k, EXPERTS / ROUTED, cuts)
+
+    def sorted_pairs(local):
+        flat = local.reshape(-1)
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        n_local = (flat < EXPERTS).sum()
+        return order[:rows], jnp.arange(rows, dtype=jnp.int32) < n_local
+
+    def in_token_order(local, weights):
+        pair, live = sorted_pairs(local)
+        return lax.sort((jnp.where(live, pair, t * k), jnp.arange(rows, dtype=jnp.int32), weights.reshape(-1)[pair]), num_keys=1)
+
+    def program(stop, kernel=moe_combine, tile=cuts.row_tile):
+        def run(ys, local, weights):
+            in_order, row, w = in_token_order(local, weights)
+            if stop == "index":
+                return in_order, row, w
+            if stop == "index_gather":
+                return ys[row]
+            return kernel(jnp.zeros((t, H), jnp.float32), ys[row], w, in_order // k, row_tile=tile)
+        return run
+
+    def scatter_add(pass_rows):
+        def run(ys, local, weights):
+            pair, live = sorted_pairs(local)
+            w = weights.reshape(-1)[pair]
+            y = jnp.zeros((t, H), jnp.float32)
+            for lo in range(0, rows, pass_rows):  # the parent ran as many as held pairs: all of an even routing's here
+                part = slice(lo, lo + pass_rows)
+                y = y.at[jnp.where(live[part], pair[part] // k, 0)].add(
+                    jnp.where(live[part, None], ys[part].astype(jnp.float32) * w[part, None], 0.0))
+            return y
+        return run
+
+    def scatter_add_sorted(ys, local, weights):
+        in_order, row, w = in_token_order(local, weights)
+        live = in_order < t * k
+        return jnp.zeros((t, H), jnp.float32).at[jnp.where(live, in_order // k, t - 1)].add(
+            jnp.where(live[:, None], ys[row].astype(jnp.float32) * w[:, None], 0.0), indices_are_sorted=True)
+
+    shapes = (jax.ShapeDtypeStruct((rows, H), jnp.bfloat16), jax.ShapeDtypeStruct((t, k), jnp.int32), jax.ShapeDtypeStruct((t, k), jnp.float32))
+    out = {f"combine/T{t}_R{rows}/{name}": (fn, shapes, "combine") for name, fn in (
+        ("scatter_add", scatter_add(1024)), ("scatter_add_whole", scatter_add(rows)), ("scatter_add_sorted", scatter_add_sorted),
+        ("index", program("index")), ("index_gather", program("index_gather")), ("program", program("all")),
+        ("onehot", program("all", combine_onehot)))}
+    for tile in (128, 256, 512):
+        if tile != cuts.row_tile and rows % tile == 0:
+            out[f"combine/T{t}_R{rows}/row_tile{tile}"] = (program("all", tile=tile), shapes, "combine")
     return out
 
 
@@ -487,7 +651,7 @@ def main(argv=None) -> int:
         raise SystemExit("tools/moe_ab.py: needs a TPU (or --compile-only)")
     from benchmarks.lib import trace
 
-    results, drawn = {}, {}
+    results, drawn, combined, per_token = {}, {}, {}, None
     for name, (fn, shapes, kind) in variants().items():
         if not wanted(name):
             continue
@@ -498,8 +662,9 @@ def main(argv=None) -> int:
                 key, k = jax.random.split(key)
                 if s.dtype == jnp.int32:
                     operands.append(jnp.asarray(group_sizes(skew) if kind == "kernel" else routing(s.shape[0])))
-                elif s.dtype == jnp.float32:
-                    operands.append(jnp.full(s.shape, 2.5 / TOP_K, jnp.float32))
+                elif s.dtype == jnp.float32:  # a combine's weights differ pair by pair, so that a wrong pairing of row and weight shows
+                    operands.append(jax.random.uniform(k, s.shape, jnp.float32, 0.1, 0.6) if kind == "combine"
+                                    else jnp.full(s.shape, 2.5 / TOP_K, jnp.float32))
                 else:  # one draw a shape: the experts' weights are 1.4 GB
                     if s.shape not in drawn:
                         drawn[s.shape] = (jax.random.normal(k, s.shape, jnp.float32) * 0.05).astype(s.dtype)
@@ -523,6 +688,9 @@ def main(argv=None) -> int:
                 top = trace.top(trace.totals_by_name(events), 8)
                 results[label] = {"device_ms": busy_ms, "top": [[n, 1e3 * s / args.iters] for n, s in top]}
                 print(f"{label}: {busy_ms:.4f} ms a call; {results[label]['top']}", flush=True)
+                if kind == "combine" and getattr(out, "shape", None) == (COMBINE_TOKENS, H):
+                    combined[label] = np.asarray(out)
+                    per_token = np.bincount(np.nonzero(np.asarray(operands[1]) < EXPERTS)[0], minlength=COMBINE_TOKENS)
                 if kind == "mla_expand":  # a chunk's time, the Pallas kernels' share and XLA's apart
                     leaf = {n: ns for n, ns in trace.totals_by_name(events).items() if not n.startswith("while")}
                     kernels = sum(ns for n, ns in leaf.items() if n.startswith(("flash_", "rotary_")))
@@ -536,6 +704,15 @@ def main(argv=None) -> int:
                 results[label] = {"error": f"{type(e).__name__}: {str(e)[:300]}"}
                 print(f"{label}: FAILED {results[label]['error']}", flush=True)
             del operands
+    want = next((y for label, y in combined.items() if label.endswith("/scatter_add")), None)
+    for label, y in combined.items():  # every way back against the parent's: the widest difference, and the bits of tokens with at most two rows
+        if want is not None and y is not want:
+            few = per_token <= 2
+            gap = dict(max_abs_gap=float(np.abs(y - want).max()), scale=float(np.abs(want).max()),
+                       tokens_of_at_most_two_rows=int(few.sum()), of_them_not_bit_equal=int((y[few] != want[few]).any(axis=1).sum()),
+                       tokens_of_more_rows=int((~few).sum()), of_them_not_bit_equal_more=int((y[~few] != want[~few]).any(axis=1).sum()))
+            results[label].update(gap)
+            print(f"{label} against scatter_add: {gap}", flush=True)
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/moe_ab.json" if GEOM == "dsv3" else f"chiprun_out/moe_ab_{GEOM}.json", "w") as f:
         json.dump(results, f, indent=1)
