@@ -67,35 +67,17 @@ LOSS_TOL = 3e-2
 # ----------------------------------------------------------------- accounting
 
 
-class Programs:
-    """Counts the programs JAX builds (compiled or read from the persistent
-    cache) and the cache's hits and misses, from JAX's own monitoring
-    events."""
+def programs() -> tuple:
+    """Programs JAX has made ready so far (compiled or read from the
+    persistent cache), the seconds that took, and how many the cache served
+    and did not: the start-up record's counters (``obs/startup.py``, fed by
+    JAX's own monitoring events from the package's import on)."""
+    from perceiver_io_tpu.obs import default_registry
 
-    def __init__(self):
-        self.n = 0
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.n += 1
-            self.seconds += duration
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self):
-        return (self.n, self.seconds, self.hits, self.misses)
-
-
-PROGRAMS = None  # set in main(): listeners are process-wide
+    registry = default_registry()
+    total = registry.counter("startup_programs_total")
+    return (int(total.value), registry.counter("startup_compile_seconds").value,
+            int(total.labels(cache="hit").value), int(total.labels(cache="miss").value))
 
 
 @contextlib.contextmanager
@@ -103,11 +85,11 @@ def phase(name: str):
     """Time one phase and print its JSON line: what the phase put into
     ``found``, plus programs built, compile seconds and run seconds apart."""
     found = {}
-    n0, s0, h0, m0 = PROGRAMS.snapshot()
+    n0, s0, h0, m0 = programs()
     t0 = time.perf_counter()
     yield found
     wall = time.perf_counter() - t0
-    n1, s1, h1, m1 = PROGRAMS.snapshot()
+    n1, s1, h1, m1 = programs()
     line = {
         "phase": name,
         **found,
@@ -589,12 +571,12 @@ def serve_phase(model, params, reference_logits, out: str, seed: int):
         fe = make_engine(model, params, os.path.join(out, "serve"))
         records = fe.run_closed(warm, concurrency=SLOTS)
         check_served(fe, records, warm)
-        programs_warm, engine_programs_warm = PROGRAMS.n, engine_compile_events(fe)
+        programs_warm, engine_programs_warm = programs()[0], engine_compile_events(fe)
         # six more at a low fixed rate: nothing left to compile
         records_late = fe.run_open(late, offsets=[0.5 * i for i in range(len(late))])
         check_served(fe, records + records_late, warm + late)
-        assert (PROGRAMS.n, engine_compile_events(fe)) == (programs_warm, engine_programs_warm), (
-            f"compiled after warm-up: programs {programs_warm} -> {PROGRAMS.n}, "
+        assert (programs()[0], engine_compile_events(fe)) == (programs_warm, engine_programs_warm), (
+            f"compiled after warm-up: programs {programs_warm} -> {programs()[0]}, "
             f"engine {engine_programs_warm} -> {engine_compile_events(fe)}"
         )
         prefix_hits = fe.registry.counter("serve_prefix_hits_total").value
@@ -603,7 +585,7 @@ def serve_phase(model, params, reference_logits, out: str, seed: int):
         found.update(
             requests=len(warm) + len(late), books=fe.books(),
             engine_programs=engine_programs_warm, programs_before_open_loop=programs_warm,
-            programs_after_open_loop=PROGRAMS.n,
+            programs_after_open_loop=programs()[0],
             ttft_p50_s=p50([r.ttft_s for r in steady]),
             inter_token_p50_s=p50([r.decode_s / (r.tokens_out - 1) for r in steady]),
             engine_steps=fe._engine_steps, batch_fill=round(fe.mean_batch_fill, 3),
@@ -764,8 +746,6 @@ def main(argv=None) -> int:
     out = os.path.abspath(args.out)
     os.makedirs(out, exist_ok=True)
 
-    global PROGRAMS
-    PROGRAMS = Programs()
     cache_dir = enable_compile_cache()
     device = device_phase(args.chips)
     print(json.dumps({"phase": "cache", "dir": cache_dir,
@@ -777,8 +757,9 @@ def main(argv=None) -> int:
         model, params = train_phase(out, args.seed)
         reference_logits = decode_phase(model, params, args.seed)
         serve_phase(model, params, reference_logits, out, args.seed)
-    print(json.dumps({"phase": "total", "programs": PROGRAMS.n, "cache_hits": PROGRAMS.hits,
-                      "cache_misses": PROGRAMS.misses, "compile_s": round(PROGRAMS.seconds, 1)}), flush=True)
+    n, seconds, hits, misses = programs()
+    print(json.dumps({"phase": "total", "programs": n, "cache_hits": hits, "cache_misses": misses,
+                      "compile_s": round(seconds, 1)}), flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": device.platform, "kind": device.device_kind, "count": len(jax.devices()),
     }}), flush=True)

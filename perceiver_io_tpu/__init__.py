@@ -25,6 +25,10 @@ Layer map (mirrors the reference's four stacked layers, re-drawn for JAX):
   analysis      perceiver_io_tpu.analysis     graph lint/contracts over jaxprs + HLO
 """
 
+from perceiver_io_tpu._startup import RECORD as _STARTUP
+
+_IMPORTING = _STARTUP.open("startup/import", package=__name__)
+
 __version__ = "0.1.0"
 
 from perceiver_io_tpu.core import config as config  # noqa: F401
@@ -32,3 +36,5 @@ from perceiver_io_tpu.core import config as config  # noqa: F401
 __all__ = [
     "config",
 ]
+
+_STARTUP.close(_IMPORTING)

@@ -29,6 +29,10 @@ the five protocol rules — ``books-exactness``, ``shared-state-race``,
 (docs/static-analysis.md#hostlint).
 """
 
+from perceiver_io_tpu._startup import RECORD as _STARTUP
+
+_IMPORTING = _STARTUP.open("startup/import", package=__name__)
+
 from perceiver_io_tpu.analysis.check import GraphLintError, Report, check
 from perceiver_io_tpu.analysis.dataflow import (
     CacheSite,
@@ -133,3 +137,5 @@ __all__ = [
     "register_rule",
     "trace",
 ]
+
+_STARTUP.close(_IMPORTING)

@@ -1,3 +1,7 @@
+from perceiver_io_tpu._startup import RECORD as _STARTUP
+
+_IMPORTING = _STARTUP.open("startup/import", package=__name__)
+
 from perceiver_io_tpu.core.adapter import (
     ClassificationOutputAdapter,
     TiedTokenOutputAdapter,
@@ -67,3 +71,5 @@ __all__ = [
     "frequency_position_encoding",
     "positions",
 ]
+
+_STARTUP.close(_IMPORTING)

@@ -1,3 +1,7 @@
+from perceiver_io_tpu._startup import RECORD as _STARTUP
+
+_IMPORTING = _STARTUP.open("startup/import", package=__name__)
+
 from perceiver_io_tpu.hf.auto import auto_model_for_config, from_pretrained  # noqa: F401
 from perceiver_io_tpu.hf.convert import (  # noqa: F401
     convert_image_classifier,
@@ -56,3 +60,5 @@ __all__ = [
     "TextGenerationPipeline",
     "pipeline",
 ]
+
+_STARTUP.close(_IMPORTING)

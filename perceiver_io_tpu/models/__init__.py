@@ -1,0 +1,5 @@
+from perceiver_io_tpu._startup import RECORD as _STARTUP
+
+_IMPORTING = _STARTUP.open("startup/import", package=__name__)
+
+_STARTUP.close(_IMPORTING)

@@ -1,3 +1,7 @@
+from perceiver_io_tpu._startup import RECORD as _STARTUP
+
+_IMPORTING = _STARTUP.open("startup/import", package=__name__)
+
 from perceiver_io_tpu.models.vision.image_classifier import (
     ImageClassifier,
     ImageClassifierConfig,
@@ -21,3 +25,5 @@ __all__ = [
     "OpticalFlowDecoderConfig",
     "OpticalFlowEncoderConfig",
 ]
+
+_STARTUP.close(_IMPORTING)

@@ -27,6 +27,10 @@ run directory with ``tools/obs_report.py``; diff two runs with
 ``tools/obs_diff.py``; drive and gate load with ``tools/loadgen.py``.
 """
 
+from perceiver_io_tpu._startup import RECORD as _STARTUP
+
+_IMPORTING = _STARTUP.open("startup/import", package=__name__)
+
 from perceiver_io_tpu.obs.events import (  # noqa: F401
     EVENT_SCHEMA_VERSION,
     KNOWN_EVENT_KINDS,
@@ -82,6 +86,7 @@ from perceiver_io_tpu.obs.trace import (  # noqa: F401
     current_span_id,
     host_device_breakdown,
 )
+from perceiver_io_tpu.obs import startup  # noqa: F401  (adopts the start-up record, registers JAX's listeners)
 
 __all__ = [
     "EVENT_SCHEMA_VERSION",
@@ -128,3 +133,5 @@ __all__ = [
     "current_span_id",
     "host_device_breakdown",
 ]
+
+_STARTUP.close(_IMPORTING)

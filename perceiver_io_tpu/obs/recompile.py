@@ -7,7 +7,12 @@ on TPU and is invisible in ``metrics.csv``: throughput just dips. The
 executable cache (``fn._cache_size()``) across calls — a size increase
 means THIS call compiled, its wall time is (trace + compile + dispatch)
 time, and the argument shape signature says what drove it. Each miss is
-emitted as a ``compile`` event and accounted against goodput.
+emitted as a ``compile`` event and accounted against goodput. The row splits
+``wall_s`` by what the start-up record (``obs/startup.py``, JAX's own compile
+events) saw close inside the call: ``trace_s``, ``lower_s``, ``backend_s``
+(self times) and ``cache`` (``"hit"``, ``"miss"`` or ``"off"``: whether the
+persistent cache served the programs); the rest of ``wall_s`` is the first
+dispatch.
 
 The first call's compile is expected; any later ``compile`` event on the
 same function is the smoking gun for a shape leak.
@@ -31,6 +36,8 @@ from __future__ import annotations
 import collections
 import time
 from typing import Callable, Dict, Optional
+
+from perceiver_io_tpu.obs import startup
 
 
 def _cache_size(fn) -> Optional[int]:
@@ -90,6 +97,7 @@ class RecompileTracker:
 
                 gelu_sites = mlp_gelu_sites()  # the row below counts this call's trace alone
             before = _cache_size(fn)
+            closed = startup.mark()
             t0 = time.perf_counter()
             out = fn(*args, **kwargs)
             dt = time.perf_counter() - t0
@@ -114,6 +122,7 @@ class RecompileTracker:
                         "compile",
                         fn=name,
                         wall_s=round(dt, 6),
+                        **startup.compile_split(closed),
                         n_compiles=st["compiles"],
                         cache_size=after,
                         arg_shapes=shape_signature(args, kwargs),

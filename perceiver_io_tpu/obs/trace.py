@@ -55,6 +55,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
+from perceiver_io_tpu._startup import RECORD as _STARTUP
+
 _CURRENT: "contextvars.ContextVar[Optional[Span]]" = contextvars.ContextVar(
     "obs_current_span", default=None
 )
@@ -282,6 +284,7 @@ class Tracer:
         """Close ``span`` and queue its row; nothing is written before the
         queue holds ``flush_every`` rows, and nothing at all under a hold."""
         span.close()
+        self._take_startup()
         with self._lock:
             self._rows.append(("span", span))
             full = len(self._rows) >= self.flush_every and not self._held
@@ -299,12 +302,22 @@ class Tracer:
             sid = current_span_id()
             if sid is not None:
                 fields["span_id"] = sid
+        self._take_startup()
         with self._lock:
             self._rows.append((str(event), fields))
             self._n_events += 1
             held = self._held
         if not held:
             self.flush()
+
+    def _take_startup(self) -> None:
+        """The first ``Tracer`` of the process that has a sink queues the
+        start-up record's spans in front of its own first row
+        (``obs/startup.py``): what ran before any tracer existed."""
+        if self.events is not None and not _STARTUP.handed:
+            from perceiver_io_tpu.obs import startup
+
+            startup.hand_to(self)
 
     @contextlib.contextmanager
     def hold(self):
@@ -396,8 +409,11 @@ def host_device_breakdown(span_rows, capture=None) -> Dict:
     """
     spans = [r for r in span_rows if r.get("event", "span") == "span"]
     child_ms: Dict[str, float] = {}
+    opened = {r.get("span_id"): r.get("start_ns", 0) for r in spans}
     for r in spans:
-        if r.get("parent_id") is not None:
+        # a child that began before its parent opened (the start-up record's
+        # spans, handed over under ``fit``) took none of the parent's time
+        if r.get("parent_id") is not None and r.get("start_ns", 0) >= opened.get(r["parent_id"], 0):
             child_ms[r["parent_id"]] = child_ms.get(r["parent_id"], 0.0) + float(r["dur_ms"])
     by_name: Dict[str, Dict] = {}
     for r in spans:

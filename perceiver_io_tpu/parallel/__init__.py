@@ -1,3 +1,7 @@
+from perceiver_io_tpu._startup import RECORD as _STARTUP
+
+_IMPORTING = _STARTUP.open("startup/import", package=__name__)
+
 from perceiver_io_tpu.parallel.dist import (
     is_main_process,
     main_process_only,
@@ -43,3 +47,5 @@ __all__ = [
     "parse_mesh_spec",
     "required_devices",
 ]
+
+_STARTUP.close(_IMPORTING)

@@ -18,6 +18,10 @@ least-outstanding dispatch, drain/join, and journal-backed failover.
 See docs/robustness.md#serving-hardening.
 """
 
+from perceiver_io_tpu._startup import RECORD as _STARTUP
+
+_IMPORTING = _STARTUP.open("startup/import", package=__name__)
+
 from perceiver_io_tpu.serving.breaker import (  # noqa: F401
     STATE_VALUES,
     BreakerConfig,
@@ -83,3 +87,5 @@ __all__ = [
     "FleetRouter",
     "ReplicaHandle",
 ]
+
+_STARTUP.close(_IMPORTING)

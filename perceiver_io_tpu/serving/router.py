@@ -143,9 +143,9 @@ class FleetRouter:
         self._readmit_skipped = 0  # dedupe hits across failover replays
         from perceiver_io_tpu.obs import trace as obs_trace
 
-        self._tracer = (
-            obs_trace.Tracer(events, flush_every=1) if events is not None else None
-        )
+        # write-behind like every other Tracer: the one span the router opens
+        # (``failover``) is flushed where the failover ends, in front of its row
+        self._tracer = obs_trace.Tracer(events) if events is not None else None
         r = self.registry
         self._m_dispatch = r.counter("router_dispatch_total")
         self._m_redispatch = r.counter("router_redispatch_total")

@@ -1,3 +1,7 @@
+from perceiver_io_tpu._startup import RECORD as _STARTUP
+
+_IMPORTING = _STARTUP.open("startup/import", package=__name__)
+
 from perceiver_io_tpu.training.optim import (
     constant_with_warmup,
     cosine_with_warmup,
@@ -11,6 +15,7 @@ from perceiver_io_tpu.training.losses import (
     mse_loss_fn,
 )
 from perceiver_io_tpu.training.optim import freeze_mask
+_MODULE = _STARTUP.open("startup/import", package=__name__, module="checkpoint")
 from perceiver_io_tpu.training.checkpoint import (
     CheckpointManager,
     ResumePreflightError,
@@ -23,6 +28,8 @@ from perceiver_io_tpu.training.checkpoint import (
     save_pretrained,
     sharding_fingerprint,
 )
+_STARTUP.close(_MODULE)
+_MODULE = _STARTUP.open("startup/import", package=__name__, module="faults")
 from perceiver_io_tpu.training.faults import (
     DivergenceHalt,
     DivergenceSentinel,
@@ -34,13 +41,16 @@ from perceiver_io_tpu.training.faults import (
     call_with_retry,
     fetch_retry_emitter,
 )
+_STARTUP.close(_MODULE)
 from perceiver_io_tpu.training.metrics import MetricsLogger
 from perceiver_io_tpu.training.prefix_dropout import (
     prefix_keep_count,
     sample_prefix_keep_idx,
     with_prefix_keep_idx,
 )
+_MODULE = _STARTUP.open("startup/import", package=__name__, module="trainer")
 from perceiver_io_tpu.training.trainer import Trainer, TrainerConfig
+_STARTUP.close(_MODULE)
 
 __all__ = [
     "constant_with_warmup",
@@ -78,3 +88,5 @@ __all__ = [
     "Trainer",
     "TrainerConfig",
 ]
+
+_STARTUP.close(_IMPORTING)

@@ -20,8 +20,31 @@ class TrainState(struct.PyTreeNode):
 
     @classmethod
     def create(cls, apply_fn, params, tx, rng):
-        import jax.numpy as jnp
+        """The state at step 0, timed as the ``startup/state_create`` span of
+        the start-up record (``obs/startup.py``; attrs ``leaves`` and
+        ``param_bytes``). The span closes once the optimizer state exists and
+        waits for no device: it times what the host did, ``tx.init`` building
+        its small programs leaf by leaf among it. The body is ``_create`` at
+        the end of this file, and this method keeps its ten lines: compiled
+        programs' metadata names the lines of ``apply_gradients``, which
+        therefore stay where they were.
+        """
+        return _create(cls, apply_fn, params, tx, rng)
 
+    def apply_gradients(self, grads):
+        updates, opt_state = self.tx.update(grads, self.opt_state, self.params)
+        params = optax.apply_updates(self.params, updates)
+        return self.replace(step=self.step + 1, params=params, opt_state=opt_state)
+
+
+def _create(cls, apply_fn, params, tx, rng):
+    import jax.numpy as jnp
+
+    from perceiver_io_tpu.obs import startup
+
+    leaves = jax.tree_util.tree_leaves(params)
+    param_bytes = sum(x.size * x.dtype.itemsize for x in leaves if hasattr(x, "dtype"))
+    with startup.span(startup.STATE_CREATE, leaves=len(leaves), param_bytes=int(param_bytes)):
         return cls(
             step=jnp.zeros((), jnp.int32),
             params=params,
@@ -30,8 +53,3 @@ class TrainState(struct.PyTreeNode):
             apply_fn=apply_fn,
             tx=tx,
         )
-
-    def apply_gradients(self, grads):
-        updates, opt_state = self.tx.update(grads, self.opt_state, self.params)
-        params = optax.apply_updates(self.params, updates)
-        return self.replace(step=self.step + 1, params=params, opt_state=opt_state)

@@ -1,3 +1,7 @@
+from perceiver_io_tpu._startup import RECORD as _STARTUP
+
+_IMPORTING = _STARTUP.open("startup/import", package=__name__)
+
 from perceiver_io_tpu.utils.flops import (  # noqa: F401
     ComputeEstimator,
     ModelInfo,
@@ -28,3 +32,5 @@ __all__ = [
     "StepTimer",
     "trace",
 ]
+
+_STARTUP.close(_IMPORTING)

@@ -26,6 +26,13 @@ if not os.environ.get("JAX_TEST_NO_COMPILE_CACHE"):
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+from perceiver_io_tpu import _startup  # noqa: E402
+
+# The first Tracer of a process that has a sink writes the start-up record into its stream (obs/startup.py): in a
+# worker that would be whichever test comes first, so a test that counts a stream's rows would depend on the order.
+# Here the record counts as taken; tests/test_obs_startup.py hands it out again where it tests the hand-over.
+_startup.RECORD.handed = True
+
 
 @pytest.fixture
 def rng():

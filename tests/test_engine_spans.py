@@ -27,8 +27,13 @@ ENGINE_SPANS = {
     "engine/decode_dispatch", "engine/token_fetch", "engine/account", "engine/retire",
 }
 # a shared CPU host can deschedule the process between a row's stamp and its
-# annotation; on the chip the same comparison is held to 50 us (PERF.md)
-CLOCK_TOLERANCE_NS = 2_000_000
+# annotation, a scheduler quantum at a time: of 48 captures taken beside six
+# busy workers (PR 51) 46 read a worst span of 17 to 193 us and two read 4.06
+# and 4.13 ms, over the 2 ms this was held to until then. A row may lie six
+# such quanta off; the clocks' identity is held by the median, which no
+# descheduling moves (on the chip the comparison is held to 50 us, PERF.md)
+CLOCK_TOLERANCE_NS = 25_000_000
+CLOCK_MEDIAN_TOLERANCE_NS = 1_000_000
 
 
 @pytest.fixture(scope="module")
@@ -139,15 +144,16 @@ def test_span_rows_lie_on_the_captures_clock(traced_pump):
     spans, capture = traced_pump["spans"], traced_pump["capture"]
     ann = {a[3]: a for a in capture["annotations"]}
     t0 = capture["profile_start_ns"]
-    checked = 0
+    off = []
     for r in spans:
         if r["span_id"] not in ann:
             continue
         _, start, dur, _ = ann[r["span_id"]]
         assert abs((r["start_ns"] - t0) - start) < CLOCK_TOLERANCE_NS, r["name"]
         assert abs((r["end_ns"] - t0) - (start + dur)) < CLOCK_TOLERANCE_NS, r["name"]
-        checked += 1
-    assert checked >= 20
+        off += [abs((r["start_ns"] - t0) - start), abs((r["end_ns"] - t0) - (start + dur))]
+    assert len(off) >= 40
+    assert float(np.median(off)) < CLOCK_MEDIAN_TOLERANCE_NS
     detached = [r for r in spans if r.get("detached")]
     assert detached and all(r["name"] == "request" and r["span_id"] not in ann for r in detached)
     assert all(0 <= r["start_ns"] - t0 <= capture["length_ns"] for r in detached)
