@@ -27,14 +27,19 @@ rows in float32 (``ops/kda.py``).
 One set of weights, two ways through them, as in ``core/ssm.py``:
 
 ``expand`` (the prompt pass)
-    whole rows from an empty state: projections, convolutions and gates in
-    XLA, the recurrence in ``ops/kda.py``'s chunk kernel where it may run
-    (``flash_enabled()`` and a head of 128) and as a ``lax.scan`` of a token a
-    step elsewhere. Also returns what a step needs of the rows' past: the final
-    ``S`` and the three windows.
+    whole rows from an empty state: projections and gates in XLA. Where the
+    kernels may run (``flash_enabled()`` and a head of 128) ``ops/kda.py``'s
+    chunk kernel takes the three raw projections and the tap tables and
+    **shapes q, k and v itself**, on the tiles it holds, in front of the
+    recurrence (convolution, silu, l2 norm, q's scale, one rounding: no XLA
+    pass over them, PR 52); elsewhere ``_shape`` shapes them in XLA and the
+    recurrence is a ``lax.scan`` of a token a step. Also returns what a step
+    needs of the rows' past: the final ``S`` and the three windows (the raw
+    projections' last ``K - 1`` rows, XLA's on both paths).
 
 ``step`` (one new token a row against the state)
-    the windows shift by one row; ``S`` is read, decayed, corrected and written
+    the windows shift by one row and ``_shape`` shapes the token's q, k and v
+    in XLA (on every path); ``S`` is read, decayed, corrected and written
     once: where the kernels run one Pallas call over the state where it lies,
     updated in place (``ops/kda.py::kda_step``), elsewhere the same arithmetic
     in XLA (``kda_update``).
@@ -57,10 +62,9 @@ from perceiver_io_tpu.core.cache import DeltaState
 from perceiver_io_tpu.core.ssm import causal_conv, rows_window, step_window, window_tail
 from perceiver_io_tpu.obs import probes
 from perceiver_io_tpu.ops.flash_attention import flash_enabled
+from perceiver_io_tpu.ops.kda import L2_EPS as _L2_EPS
 from perceiver_io_tpu.ops.kda import kda_chunked, kda_reference, kda_step, kda_supported, kda_update, sub_chunk_safe
 from perceiver_io_tpu.ops.layernorm import RMSNorm
-
-_L2_EPS = 1e-6
 
 
 class KimiDeltaAttention(nn.Module):
@@ -147,17 +151,19 @@ class KimiDeltaAttention(nn.Module):
         and the rows' state after their last token (the windows in ``dtype``)."""
         c = self.config
         taps = c.short_conv_kernel_size
+        kernels = flash_enabled() and kda_supported(c.head_dim)
         with jax.named_scope("kda/proj"):
             inputs = [self._mm(x, w) for w in (self.w_q, self.w_k, self.w_v)]
         with jax.named_scope("kda/conv"):
             windows = [rows_window(t, taps) for t in inputs]
-            q, k, v = self._shape(windows)
             kept = [window_tail(w, taps) for w in windows]
+            if not kernels:
+                q, k, v = self._shape(windows)
         with jax.named_scope("kda/gate"):
             g, b = self._gates(x)
         with jax.named_scope("kda/chunk"):
-            if flash_enabled() and kda_supported(c.head_dim):
-                o, s = kda_chunked(q, k, v, g, b, c.num_attention_heads)
+            if kernels:  # the chunk kernel shapes the raw projections on the tiles it holds: nothing of ``_shape`` runs in XLA
+                o, s = kda_chunked(*inputs, g, b, c.num_attention_heads, taps=(self.conv_q, self.conv_k, self.conv_v))
             else:
                 o, s = kda_reference(self._heads(q), self._heads(k), self._heads(v), self._heads(g), b)
                 o = o.reshape(q.shape)

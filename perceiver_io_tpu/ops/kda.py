@@ -51,6 +51,24 @@ chunks and written to HBM once, as the row's final state. The running sum of
 the log-decays within a chunk is XLA's, a product with a triangle of ones in
 front of the kernel (one pass over the gates).
 
+**Where q, k and v are shaped.** The layer's ``q = l2norm(silu(conv(x W_q))) *
+D^-0.5``, ``k = l2norm(silu(conv(x W_k)))`` and ``v = silu(conv(x W_v))``
+(``core/kda.py``) hold no product, and XLA's fusions spent five times their
+bytes on them in front of this kernel (the l2 norm's sum over a head's 128
+lanes between relayouts). A call that carries the three tap tables
+(``kda_chunked(..., taps=)``, what the mixer's prompt pass makes) therefore
+hands the kernel the projections' raw outputs, and each head's ``(chunk, D)``
+tile is shaped where it already lies, before the recurrence reads it
+(:func:`_shaped`, :func:`_convolved`): the ``K``-tap causal convolution in float32 (the tile turned
+along its sublanes, its first ``K - 1`` rows taken from the previous chunk's
+last raw rows, which a VMEM scratch carries from a grid step to the next and
+``j == 0`` zeroes: zeros before a row's first token), silu, the l2 norm as a
+reduction along the tile's own lanes (a head is one lane tile), q's scale, and
+**one rounding to the operand dtype**, as XLA's form rounds. A call without
+tables is the recurrence alone on shaped inputs (the tests' entry, against
+:func:`kda_reference`). The one-token step, and every path where the kernels
+may not run, keep XLA's form (``core/kda.py::KimiDeltaAttention._shape``).
+
 **The step** (the second kernel). Grid (row,): a row's heads' states come in
 and go out through the same HBM array (``input_output_aliases``), each decayed,
 read for the prediction, corrected and read for ``o`` while it is in registers.
@@ -80,6 +98,8 @@ CHUNK = 128  # tokens a grid step: 2.85 ms for two rows of 2048 against 3.27 at 
 SUB = 16  # rows that share one reference for their decays (8: 2.98 ms)
 HEADS_BLOCK = 4  # heads a grid step of the prompt pass: 3.00, 2.93, 2.85, 2.82 ms at 1, 2, 4, 8 (their chains of products hardly interleave)
 _CAP = 80.0  # the largest exponent a factor may take: exp(80) times 128 unit products stays inside float32
+L2_EPS = 1e-6  # under the root of q's and k's l2 norms
+_TAIL = 8  # raw rows of q, k and v carried from a chunk to the next for the convolution's taps: one float32 sublane tile
 
 _NN = ((1,), (0,))
 _NT = ((1,), (1,))
@@ -215,6 +235,7 @@ class KdaPlan(NamedTuple):
     head_dim: int
     grid_steps: int  # a row: blocks of heads x chunks of time
     solve_products: int  # a chunk a head: the products of the triangular solve (a factor and the next power in one)
+    conv_taps: int  # the taps the kernel convolves q, k and v with on its tiles; 0 where the caller hands it shaped inputs
 
 
 _PLANS: dict = {}
@@ -237,11 +258,11 @@ def _heads_block(heads: int, want: int) -> int:
 
 
 def kda_plan(length: int, heads: int, head_dim: int, chunk: int = CHUNK, sub: int = SUB,
-             heads_block: int = HEADS_BLOCK) -> KdaPlan:
+             heads_block: int = HEADS_BLOCK, conv_taps: int = 0) -> KdaPlan:
     c = chunk_of(length, chunk, sub)
     block = _heads_block(heads, heads_block)
     doublings = max((c - 1).bit_length() - 1, 0)  # I - N, then a factor I + N^(2^i) while 2^i < C
-    return KdaPlan(length, c, sub, heads, block, head_dim, heads // block * -(-length // c), doublings + (doublings > 0))
+    return KdaPlan(length, c, sub, heads, block, head_dim, heads // block * -(-length // c), doublings + (doublings > 0), conv_taps)
 
 
 def _inverse_unit_lower(n, dt):
@@ -263,13 +284,59 @@ def _inverse_unit_lower(n, dt):
         inverse, power = inverse + both[:c], both[c:]
 
 
-def _chunk_kernel(beta_ref, g_ref, q_ref, k_ref, v_ref, y_ref, s_ref, *, heads_block: int, head_dim: int, chunk: int, sub: int):
+def _convolved(x_ref, taps_ref, tail_ref, which: int, lanes, n_taps: int):
+    """One head's raw tile of q, k or v (``which`` 0, 1, 2) under its causal
+    convolution of ``n_taps`` taps, (chunk, D) float32, summed oldest tap first
+    (:func:`~perceiver_io_tpu.core.ssm.causal_conv`'s order of addition). A
+    token's taps reach ``n_taps - 1`` rows back: within the tile by a turn
+    along the sublanes, before it from ``tail_ref``, which holds the last
+    sublane tile of the previous chunk's raw rows (zeros at a row's start) and
+    is left holding this chunk's."""
+    x = x_ref[0, :, lanes].astype(jnp.float32)  # (chunk, D)
+    before = tail_ref[which, :, lanes]  # (_TAIL, D)
+    tail_ref[which, :, lanes] = x[x.shape[0] - _TAIL:]
+    first_rows = lax.broadcasted_iota(jnp.int32, before.shape, 0)
+    acc = None
+    for tap in range(n_taps):
+        back = n_taps - 1 - tap
+        if back:
+            turned = pltpu.roll(x, back, 0)  # row t holds x[t - back]; the tile's first ``back`` rows hold its last
+            first = jnp.where(first_rows < back, pltpu.roll(before, back, 0), turned[:_TAIL])
+            term = taps_ref[which, tap:tap + 1, lanes] * jnp.concatenate([first, turned[_TAIL:]], axis=0)
+        else:
+            term = taps_ref[which, tap:tap + 1, lanes] * x
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _shaped(q_ref, k_ref, v_ref, taps_ref, tail_ref, lanes, n_taps: int):
+    """One head's raw tiles made what the recurrence reads, as
+    ``core/kda.py::KimiDeltaAttention._shape`` makes them in XLA: each under
+    its convolution and silu in float32, q and k normed to length 1 over the
+    head's lanes, q scaled by ``D^-0.5``, **one rounding to the operand dtype**."""
+    def one(x_ref, which, unit, scale):
+        y = jax.nn.silu(_convolved(x_ref, taps_ref, tail_ref, which, lanes, n_taps))
+        if unit:
+            y = y * lax.rsqrt(jnp.sum(y * y, axis=1, keepdims=True) + L2_EPS)  # along the tile's own lanes: no relayout
+        if scale != 1.0:
+            y = y * scale
+        return y.astype(x_ref.dtype)
+
+    d = lanes.stop - lanes.start
+    return one(q_ref, 0, True, d ** -0.5), one(k_ref, 1, True, 1.0), one(v_ref, 2, False, 1.0)
+
+
+def _chunk_kernel(beta_ref, g_ref, q_ref, k_ref, v_ref, taps_ref, y_ref, s_ref, tail_ref, *, heads_block: int, head_dim: int, chunk: int, sub: int):
+    # ``taps_ref`` (the three tap tables) and ``tail_ref`` (the raw rows' tails, scratch) are None where the caller hands in shaped q, k and v
     hb, j = pl.program_id(1), pl.program_id(2)
     d, f32 = head_dim, jnp.float32
+    n_taps = 0 if taps_ref is None else taps_ref.shape[1]
 
     @pl.when(j == 0)
     def _start():
         s_ref[...] = jnp.zeros_like(s_ref)
+        if n_taps:
+            tail_ref[...] = jnp.zeros_like(tail_ref)  # zeros before a row's first token
 
     beta_all = beta_ref[0]  # (chunk, H): every head's step, along the sublanes; a head's lane is picked by a masked sum
     head_lane = lax.broadcasted_iota(jnp.int32, beta_all.shape, 1)
@@ -277,7 +344,13 @@ def _chunk_kernel(beta_ref, g_ref, q_ref, k_ref, v_ref, y_ref, s_ref, *, heads_b
     j_pos = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     for h in range(heads_block):
         lanes = slice(h * d, (h + 1) * d)
-        q, k, v = q_ref[0, :, lanes], k_ref[0, :, lanes], v_ref[0, :, lanes]
+        if n_taps:
+            # tokens past the row's end are zeros of the *raw* rows here, so the first ``n_taps - 1`` of them see real tokens
+            # through their taps and have a key and a value; their step and log-decay are still zero, so their pseudo-value is
+            # zero and they write nothing and forget nothing (their ``y`` is cut off by the caller)
+            q, k, v = _shaped(q_ref, k_ref, v_ref, taps_ref, tail_ref, lanes, n_taps)
+        else:
+            q, k, v = q_ref[0, :, lanes], k_ref[0, :, lanes], v_ref[0, :, lanes]
         dt = k.dtype
         big_g = g_ref[0, :, lanes]  # (chunk, D): the log-decays summed from the chunk's first token on
         beta = jnp.sum(jnp.where(head_lane == hb * heads_block + h, beta_all, 0.0), axis=1, keepdims=True)  # (chunk, 1)
@@ -309,13 +382,21 @@ def _chunk_kernel(beta_ref, g_ref, q_ref, k_ref, v_ref, y_ref, s_ref, *, heads_b
         s_ref[0, h] = state * jnp.exp(total) + _dot(u.astype(dt), to_end, _TN)
 
 
+def _on_shaped_inputs(kernel, beta_ref, g_ref, q_ref, k_ref, v_ref, y_ref, s_ref):
+    """The chunk kernel's entry where the caller has shaped q, k and v: no tap tables among the inputs, no tails behind the outputs."""
+    kernel(beta_ref, g_ref, q_ref, k_ref, v_ref, None, y_ref, s_ref, None)
+
+
 @functools.partial(jax.jit, static_argnames=("heads", "chunk", "sub", "heads_block"))
-def _chunked(q, k, v, g, beta, heads: int, chunk: int, sub: int, heads_block: int):
+def _chunked(q, k, v, g, beta, taps, heads: int, chunk: int, sub: int, heads_block: int):
     from perceiver_io_tpu.ops.flash_attention import _VMEM_LIMIT, _interpret_default  # at call time: tests steer the second
 
     b, length, width = q.shape
     d = width // heads
-    plan = _PLANS[(length, chunk, sub, heads, heads_block, d)] = kda_plan(length, heads, d, chunk, sub, heads_block)
+    n_taps = 0 if taps is None else taps[0].shape[0]
+    if n_taps - 1 > _TAIL:
+        raise ValueError(f"kda_chunked: {n_taps} taps reach further back than the {_TAIL} rows the kernel carries")
+    plan = _PLANS[(length, chunk, sub, heads, heads_block, d, n_taps)] = kda_plan(length, heads, d, chunk, sub, heads_block, n_taps)
     c, block = plan.chunk, plan.heads_block
     n_chunks = -(-length // c)
     pad = n_chunks * c - length
@@ -330,23 +411,32 @@ def _chunked(q, k, v, g, beta, heads: int, chunk: int, sub: int, heads_block: in
     big_g = jnp.matmul(below, g.reshape(b, n_chunks, c, width), precision=lax.Precision.HIGHEST).reshape(b, n_chunks * c, width)
 
     token_block = pl.BlockSpec((1, c, block * d), lambda r, hb, j: (r, j, hb))
+    operands, in_specs, scratch = [beta, big_g, q, k, v], [pl.BlockSpec((1, c, heads), lambda r, hb, j: (r, j, 0))] + [token_block] * 4, []
+    kernel = functools.partial(_chunk_kernel, heads_block=block, head_dim=d, chunk=c, sub=sub)
+    if n_taps:
+        operands.append(jnp.stack([t.astype(jnp.float32) for t in taps]))  # (3, K, H * D): a head's taps are its lanes of a row
+        in_specs.append(pl.BlockSpec((3, n_taps, block * d), lambda r, hb, j: (0, 0, hb)))
+        scratch.append(pltpu.VMEM((3, _TAIL, block * d), jnp.float32))
+    else:
+        kernel = functools.partial(_on_shaped_inputs, kernel)
     y, s = pl.pallas_call(
-        functools.partial(_chunk_kernel, heads_block=block, head_dim=d, chunk=c, sub=sub),
+        kernel,
         name=kda_chunk_kernel_name(length, c, heads, d),
         grid=(b, heads // block, n_chunks),
-        in_specs=[pl.BlockSpec((1, c, heads), lambda r, hb, j: (r, j, 0)), token_block, token_block, token_block, token_block],
+        in_specs=in_specs,
         out_specs=[token_block, pl.BlockSpec((1, block, d, d), lambda r, hb, j: (r, hb, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((b, n_chunks * c, width), q.dtype),
                    jax.ShapeDtypeStruct((b, heads, d, d), jnp.float32)],
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"),
                                              vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret_default(),
-    )(beta, big_g, q, k, v)
+    )(*operands)
     return y[:, :length], s
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def kda_chunked(q, k, v, g, beta, heads: int, chunk: int = CHUNK, sub: int = SUB, heads_block: int = HEADS_BLOCK):
+def kda_chunked(q, k, v, g, beta, heads: int, chunk: int = CHUNK, sub: int = SUB, heads_block: int = HEADS_BLOCK, taps=None):
     """The chunked form over whole rows from an empty state.
 
     ``q``, ``k``, ``v`` (B, T, H * D), heads side by side as their projections
@@ -355,10 +445,17 @@ def kda_chunked(q, k, v, g, beta, heads: int, chunk: int = CHUNK, sub: int = SUB
     ``beta`` (B, T, H) float32. Returns ``o`` (B, T, H * D) in ``q``'s dtype and
     the rows' final state (B, H, D, D) float32, stored transposed (the module
     docstring). ``D`` is 128 on the chip (:func:`kda_supported`); ``chunk`` is
-    cut to a shorter row and is whole sub-chunks."""
+    cut to a shorter row and is whole sub-chunks.
+
+    Without ``taps`` the three are what the recurrence reads (q scaled and of
+    unit length, k of unit length, v after its silu). With ``taps``, the three
+    tables (K, H * D) of the causal depthwise convolutions, they are **the
+    projections' raw outputs** and the kernel shapes each tile itself
+    (:func:`_shaped`: convolution, silu, l2 norm, q's ``D^-0.5``, one rounding
+    to their dtype), from zeros before a row's first token."""
     if chunk % sub:
         raise ValueError(f"kda_chunked: a chunk of {chunk} tokens is not whole sub-chunks of {sub}")
-    return _chunked(q, k, v, g, beta, heads=heads, chunk=chunk, sub=sub, heads_block=heads_block)
+    return _chunked(q, k, v, g, beta, taps, heads=heads, chunk=chunk, sub=sub, heads_block=heads_block)
 
 
 def _no_backward(*_):

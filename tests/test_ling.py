@@ -108,7 +108,7 @@ def served_gap(model, params, ids, config, new_tokens: int = 6) -> float:
 
 def short_chunks(monkeypatch, chunk: int = 32, sub: int = 16):
     """The mixer's prompt pass in chunks of ``chunk`` tokens, so that these short rows cross chunk boundaries."""
-    monkeypatch.setattr(kda_core, "kda_chunked", lambda q, k, v, g, b, heads: kda.kda_chunked(q, k, v, g, b, heads, chunk, sub, 2))
+    monkeypatch.setattr(kda_core, "kda_chunked", lambda q, k, v, g, b, heads, taps=None: kda.kda_chunked(q, k, v, g, b, heads, chunk, sub, 2, taps))
 
 
 def delta_args(rows, length, heads, d, seed=0, at_the_bound=False):
@@ -185,12 +185,12 @@ def test_bfloat16_products_are_not_the_model():
     assert np.abs(lower - want).max() > 100 * TOL
 
 
-def _two_halves_without_a_carry(q, k, v, g, b, heads):
+def _two_halves_without_a_carry(q, k, v, g, b, heads, taps=None):
     half = (q.shape[1] // 32) * 16
     if half == 0:
-        return kda.kda_chunked(q, k, v, g, b, heads, 16, 16, 2)
-    o0, _ = kda.kda_chunked(q[:, :half], k[:, :half], v[:, :half], g[:, :half], b[:, :half], heads, 16, 16, 2)
-    o1, s = kda.kda_chunked(q[:, half:], k[:, half:], v[:, half:], g[:, half:], b[:, half:], heads, 16, 16, 2)  # from an empty state
+        return kda.kda_chunked(q, k, v, g, b, heads, 16, 16, 2, taps)
+    o0, _ = kda.kda_chunked(q[:, :half], k[:, :half], v[:, :half], g[:, :half], b[:, :half], heads, 16, 16, 2, taps)
+    o1, s = kda.kda_chunked(q[:, half:], k[:, half:], v[:, half:], g[:, half:], b[:, half:], heads, 16, 16, 2, taps)  # from an empty state
     return jnp.concatenate([o0, o1], axis=1), s
 
 
@@ -315,7 +315,7 @@ def test_the_chunked_form_is_the_recurrence(rows, length, heads, d, chunk, sub, 
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(s).all())
     np.testing.assert_allclose(np.asarray(o).reshape(want_o.shape), np.asarray(want_o), atol=1e-5 * float(jnp.abs(want_o).max()), rtol=0)
     np.testing.assert_allclose(np.asarray(s), np.asarray(want_s), atol=1e-5 * float(jnp.abs(want_s).max()), rtol=0)
-    plan = next(p for p in kda.kda_plans() if (p["length"], p["heads"], p["head_dim"], p["sub_chunk"]) == (length, heads, d, sub))
+    plan = next(p for p in kda.kda_plans() if (p["length"], p["heads"], p["head_dim"], p["sub_chunk"], p["conv_taps"]) == (length, heads, d, sub, 0))
     assert plan["chunk"] == min(chunk, -(-length // sub) * sub) and plan["grid_steps"] == heads // block * -(-length // plan["chunk"])
     assert kda.kda_chunk_kernel_name(2048, 128, 32, 128) == "kda_chunk_l2048_c128_h32_d128"
 
@@ -345,6 +345,99 @@ def test_the_state_carries_across_chunks():
     _, alone = kda.kda_reference(q[:, 64:], k[:, 64:], v[:, 64:], g[:, 64:], b[:, 64:])
     np.testing.assert_allclose(np.asarray(s), np.asarray(want), atol=1e-5, rtol=0)
     assert np.abs(np.asarray(alone) - np.asarray(want)).max() > 0.3
+
+
+def _raw_args(rows, length, config, seed=0):
+    """A mixer of ``config``'s shape with its weights, and what its prompt pass hands the recurrence's entry: the three
+    projections' raw outputs (B, T, H * D), the gates, and the tap tables as the mixer holds them."""
+    mixer = kda_core.KimiDeltaAttention(config)
+    x = jax.random.normal(jax.random.PRNGKey(seed), (rows, length, config.hidden_size))
+    params = handed_on(mixer.init(jax.random.PRNGKey(seed + 1), x, method="expand"), config)
+    leaves = params["params"]
+    raw = [x @ leaves[name] for name in ("w_q", "w_k", "w_v")]
+    g, b = mixer.apply(params, x, method="_gates")
+    return mixer, params, x, raw, g, b, tuple(leaves[name] for name in ("conv_q", "conv_k", "conv_v"))
+
+
+@pytest.mark.parametrize("rows,length,chunk,block", [(2, 64, 32, 4), (2, 70, 32, 1), (2, 2, 32, 4), (2, 133, 32, 1), (1, 128, 128, 4)],
+                         ids=["whole_chunks", "a_last_chunk_padded", "a_row_shorter_than_the_taps", "five_chunks_of_two_rows", "one_chunk_of_128"])
+def test_the_kernel_shapes_q_k_and_v_on_its_tiles_as_the_mixer_does_in_xla(rows, length, chunk, block):
+    """The chunk kernel **with taps** (interpret mode) on the projections' raw
+    outputs against the mixer's own ``_shape`` (XLA: ``causal_conv``, silu, l2
+    norms, q's scale) followed by the token scan: ``o`` at every token and the
+    rows' final state to the 1e-5 the recurrence's entry is held to. Whole
+    chunks; a last chunk padded, where the first three pad tokens see real
+    tokens through their taps and so have a key and a value, and still write
+    nothing and forget nothing (their step and log-decay are zero); a row
+    shorter than the taps; several chunks of two rows, so that the ``K - 1``
+    raw rows carried from a grid step to the next are the row's own and start
+    from zeros (row 1 does not see row 0's last tokens); a head and four heads
+    a grid step. The plan says which entry a call took (0 taps where
+    ``test_the_chunked_form_is_the_recurrence`` hands the kernel shaped inputs)."""
+    config = tiny_config()
+    mixer, params, _, raw, g, b, taps = _raw_args(rows, length, config)
+    heads, d, n_taps = config.num_attention_heads, config.head_dim, config.short_conv_kernel_size
+    q, k, v = mixer.apply(params, [ssm.rows_window(t, n_taps) for t in raw], method="_shape")
+    in_heads = lambda t: t.reshape(rows, length, heads, d)  # noqa: E731
+    want_o, want_s = kda.kda_reference(in_heads(q), in_heads(k), in_heads(v), in_heads(g), b)
+    o, s = kda.kda_chunked(*raw, g, b, heads, chunk, 16, block, taps)
+    assert o.shape == (rows, length, heads * d) and s.shape == (rows, heads, d, d) and s.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(o).reshape(want_o.shape), np.asarray(want_o), atol=1e-5 * float(jnp.abs(want_o).max()), rtol=0)
+    np.testing.assert_allclose(np.asarray(s), np.asarray(want_s), atol=1e-5 * float(jnp.abs(want_s).max()), rtol=0)
+    plans = [p for p in kda.kda_plans() if (p["length"], p["heads"], p["head_dim"], p["heads_block"]) == (length, heads, d, block)]
+    assert n_taps in {p["conv_taps"] for p in plans}
+
+
+def test_rows_under_a_pad_tokens_taps_do_not_reach_the_state():
+    """A row of 70 tokens in chunks of 32 is padded by 26: the state after it
+    is the state of its 70 tokens, whatever the raw rows after the end would
+    be (the kernel pads with zeros; here the same row cut out of a longer one,
+    whose tokens 70 to 95 are real, gives a state that differs)."""
+    config = tiny_config()
+    _, _, _, raw, g, b, taps = _raw_args(1, 96, config, seed=3)
+    cut = lambda t: t[:, :70]  # noqa: E731
+    _, s = kda.kda_chunked(*(cut(t) for t in raw), cut(g), cut(b), 4, 32, 16, 2, taps)
+    _, longer = kda.kda_chunked(*raw, g, b, 4, 32, 16, 2, taps)
+    assert np.abs(np.asarray(s) - np.asarray(longer)).max() > 1e-3
+    # zero steps and log-decays after the row's end, as the wrapper pads them, and real raw rows there: the same state
+    zero_after = lambda t: t.at[:, 70:].set(0.0)  # noqa: E731
+    _, s_real_rows = kda.kda_chunked(*raw, zero_after(g), zero_after(b), 4, 32, 16, 2, taps)
+    np.testing.assert_allclose(np.asarray(s_real_rows), np.asarray(s), atol=1e-6 * float(jnp.abs(s).max()), rtol=0)
+
+
+@pytest.mark.parametrize("length", [2, 70], ids=["shorter_than_the_taps", "two_chunks_and_a_padded_one"])
+def test_expand_on_the_kernel_path_runs_no_shaping_in_xla_and_keeps_the_windows_to_the_bit(length, monkeypatch):
+    """``expand`` where the kernels run hands the chunk kernel the raw
+    projections and the mixer's three tap tables: ``_shape`` is not called (it
+    raises here), the plan carries the taps, the three kept windows are bit for
+    bit what the scan path keeps (``window_tail`` of the raw rows, zeros before
+    a short row), and output and state agree with the scan path's, which still
+    runs ``_shape``, as does a step on either path."""
+    config = tiny_config()
+    mixer, params, x, _, _, _, _ = _raw_args(2, length, config, seed=5)
+    want, want_state = mixer.apply(params, x, method="expand")
+    short_chunks(monkeypatch)
+    real = kda_core.KimiDeltaAttention._shape
+    calls = []
+
+    def counted(self, windows):
+        calls.append(windows[0].shape)
+        return real(self, windows)
+
+    monkeypatch.setattr(kda_core.KimiDeltaAttention, "_shape", counted)
+    with fa.default_flash(True):
+        got, state = mixer.apply(params, x, method="expand")
+        assert calls == []
+        _, stepped = mixer.apply(params, x[:, :1], state, method="step")
+        assert calls == [(2, 4, 64)]
+    mixer.apply(params, x, method="expand")
+    assert calls[1:] == [(2, length + 3, 64)]
+    for name in ("conv_q", "conv_k", "conv_v"):
+        assert getattr(state, name).shape == (2, 3, 64) and np.array_equal(np.asarray(getattr(state, name)), np.asarray(getattr(want_state, name)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5 * float(jnp.abs(want).max()), rtol=0)
+    np.testing.assert_allclose(np.asarray(state.s), np.asarray(want_state.s), atol=1e-5 * float(jnp.abs(want_state.s).max()), rtol=0)
+    assert any(p["conv_taps"] == 4 and p["length"] == length for p in kda.kda_plans())
+    assert kda_core._L2_EPS == kda.L2_EPS == reference.L2_EPS
 
 
 def test_the_steps_kernel_is_the_update_in_place():

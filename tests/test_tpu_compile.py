@@ -926,6 +926,8 @@ def test_the_ling_cells_generator_carries_its_delta_states_in_place(one_chip, mo
     is the absorbed kernel over its cache."""
     import re
 
+    from perceiver_io_tpu.analysis.graph import parse_hlo_computations
+
     compiled = _cell_generator("ling3-ep4-decode-b128-p2k", "ling", one_chip, monkeypatch)
     m = compiled.memory_analysis()
     assert 10.46e9 < m.argument_size_in_bytes < 10.47e9  # the weights and the prompts
@@ -944,6 +946,18 @@ def test_the_ling_cells_generator_carries_its_delta_states_in_place(one_chip, mo
     assert len(kernels) == 6 and all("output_to_operand_aliasing={{1}: (5, {})}" in i.line for i in kernels)
     assert not any(i.opcode == "custom-call" and "kda_chunk" in i.name for i in body)  # the chunk kernel is the prompt pass's
     assert len(re.findall(r"%kda_chunk_l2048_c128_h32_d128[.\d]* = ", text)) == 6 and len(re.findall(r"%kda_step_b128_h32_d128[.\d]* = ", text)) == 6
+    # the prompt pass shapes q, k and v inside the chunk kernel (PR 52): each call takes the three raw projections of a chunk of two rows
+    # and the three tap tables, and under ``kda/conv`` nothing is left that is larger than the windows (the prompt pass's tails
+    # ``[2,3,4096]`` a chunk; a step's ``[128,4,4096]``): no convolution, silu or l2 norm of a ``[2,2048,4096]`` array in XLA
+    instructions = [i for ins_list in parse_hlo_computations(text).values() for i in ins_list]
+    chunk_calls = [i for i in instructions if i.opcode == "custom-call" and i.name.startswith("kda_chunk_l2048_c128_h32_d128")]
+    assert len(chunk_calls) == 6
+    for call in chunk_calls:
+        operands = re.search(r"operand_layout_constraints=\{([^=]*)\}, ", call.line).group(1)
+        assert len(re.findall(r"bf16\[2,2048,4096\]", operands)) == 3 and len(re.findall(r"f32\[3,4,4096\]", operands)) == 1, call.line[:600]
+    for ins in instructions:
+        if "kda/conv" in ins.line and ins.opcode not in ("parameter", "get-tuple-element", "tuple", "bitcast"):
+            assert _elements(_result(ins)) <= 128 * 4 * 4096, ins.line[:300]
     for ins in body:
         if re.search(state, result(ins)):
             assert ins.opcode in ("custom-call", "get-tuple-element", "tuple", "parameter", "bitcast"), ins.line[:300]
