@@ -22,6 +22,7 @@ children's. Always on: two clock reads and a list append a span, no I/O.
 from __future__ import annotations
 
 import collections
+import heapq
 import itertools
 import os
 import threading
@@ -30,6 +31,10 @@ import time
 # the record keeps this many closed spans; older ones fall off the front (a
 # serving process that compiles now and then must not grow without bound)
 MAX_SPANS = 16384
+# the package imports are kept apart from that ring, for as long as the process lives: a package is imported once
+# (twenty rows a process), they are a process's first spans, and a process of many compiles (a test worker: an
+# interpret-mode Pallas call closes 500 to 1300 trace spans) would else push them off the front before anyone reads them
+IMPORT = "startup/import"
 # a child reported by another clock read (JAX's ``time.time()``) may start a
 # float's rounding before the parent that holds it
 _START_SLACK_NS = 1000
@@ -42,12 +47,12 @@ class StartupSpan:
     """One closed (or still open) interval of the record."""
 
     __slots__ = ("name", "attrs", "span_id", "parent_id", "start_ns", "end_ns", "dur_s", "self_s",
-                 "thread", "_perf0", "_annotation")
+                 "thread", "seq", "_perf0", "_annotation")
 
     def __init__(self, name: str, attrs: dict, start_ns: int):
         self.name, self.attrs, self.start_ns = name, attrs, start_ns
         self.span_id = f"{_ID_PREFIX}{next(_ID_COUNT):08x}"
-        self.parent_id = self.end_ns = self.dur_s = self.self_s = self._annotation = None
+        self.parent_id = self.end_ns = self.dur_s = self.self_s = self.seq = self._annotation = None
         self.thread = threading.get_ident()
         self._perf0 = time.perf_counter()
 
@@ -67,8 +72,9 @@ class StartupSpan:
 class Record:
     def __init__(self):
         self.clock = (time.time_ns(), time.perf_counter())
-        self.spans = collections.deque(maxlen=MAX_SPANS)
-        self.closed = 0  # spans ever closed; ``closed - len(spans)`` fell off the front
+        self.spans = collections.deque(maxlen=MAX_SPANS)  # the ring: every closed span but the imports
+        self.imports = []  # the ``startup/import`` spans, never dropped
+        self.closed = 0  # spans ever closed; a span's ``seq`` is the count when it closed
         self.handed = False  # whether a Tracer has taken the record (obs/startup.py)
         # set by obs/startup.py: a stamped span's profiler annotation entered and left, the counters
         self.on_open = self.on_close = self.on_closed = None
@@ -107,21 +113,26 @@ class Record:
         roots.append(span)
         if len(roots) > 2 * MAX_SPANS:
             del roots[:MAX_SPANS]
-        self.spans.append(span)
+        span.seq = self.closed
+        (self.imports if span.name == IMPORT else self.spans).append(span)
         self.closed += 1
         if self.on_closed is not None:
             self.on_closed(span)
 
     @property
     def dropped(self) -> int:
-        """Closed spans that fell off the front."""
-        return self.closed - len(self.spans)
+        """Closed spans that fell off the ring's front."""
+        return self.closed - len(self.spans) - len(self.imports)
 
     def since(self, mark: int) -> list:
         """The spans closed since ``mark`` was read off :attr:`closed` (those
-        of them the record still holds)."""
-        n = min(self.closed - mark, len(self.spans))
-        return list(itertools.islice(reversed(self.spans), max(n, 0)))[::-1]
+        of them the record still holds), in the order they closed."""
+        ring = list(itertools.takewhile(lambda s: s.seq >= mark, reversed(self.spans)))[::-1]
+        return list(heapq.merge((s for s in self.imports if s.seq >= mark), ring, key=lambda s: s.seq))
+
+    def held(self) -> list:
+        """Every span the record holds, imports and ring, in the order they closed."""
+        return self.since(0)
 
 
 RECORD = Record()

@@ -90,9 +90,15 @@ def window_tail(window, k: int):
 
 class MambaMixer(nn.Module):
     """``config`` needs ``hidden_size``, ``mamba_expand``, ``mamba_d_state``,
-    ``mamba_dt_rank``, ``mamba_d_conv``, ``rms_norm_eps`` and ``init_scale``."""
+    ``mamba_dt_rank``, ``mamba_d_conv``, ``mamba_inner_norms``, ``rms_norm_eps`` and
+    ``init_scale``; ``mamba_inner_norms`` off leaves the three RMSNorms out (the SambaY family's
+    mixer is Mamba-1's own). ``memory`` makes this the layer whose scan output
+    the gated memory units above read: ``expand`` and ``step`` then also return
+    ``m_t = sum_n h_t[n] C_t[n] + d_skip x_t`` (B, T, d_inner) float32, as it
+    stands **before** ``silu(z_t)`` gates it."""
 
     config: object
+    memory: bool = False
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
@@ -104,8 +110,9 @@ class MambaMixer(nn.Module):
         self.conv_w = self.param("conv_w", init, (c.mamba_d_conv, d), self.param_dtype)
         self.conv_b = self.param("conv_b", init, (d,), self.param_dtype)
         self.w_x = self.param("w_x", init, (d, r + 2 * n), self.param_dtype)
-        f32 = dict(epsilon=c.rms_norm_eps, dtype=jnp.float32, param_dtype=self.param_dtype)
-        self.dt_norm, self.b_norm, self.c_norm = RMSNorm(**f32), RMSNorm(**f32), RMSNorm(**f32)
+        if c.mamba_inner_norms:
+            f32 = dict(epsilon=c.rms_norm_eps, dtype=jnp.float32, param_dtype=self.param_dtype)
+            self.dt_norm, self.b_norm, self.c_norm = RMSNorm(**f32), RMSNorm(**f32), RMSNorm(**f32)
         self.w_dt = self.param("w_dt", init, (r, d), self.param_dtype)
         self.dt_bias = self.param("dt_bias", init, (d,), self.param_dtype)
         self.a_log = self.param("a_log", init, (n, d), self.param_dtype)
@@ -132,17 +139,20 @@ class MambaMixer(nn.Module):
         n, r = c.mamba_d_state, c.mamba_dt_rank
         sel = self._mm(x, self.w_x).astype(jnp.float32)
         dt, b, cc = sel[..., :r], sel[..., r:r + n], sel[..., r + n:]
-        pre = jnp.dot(self.dt_norm(dt).astype(self.dtype), self.w_dt.astype(self.dtype), preferred_element_type=jnp.float32)
-        return jax.nn.softplus(pre + self.dt_bias.astype(jnp.float32)), self.b_norm(b), self.c_norm(cc)
+        normed = c.mamba_inner_norms
+        pre = jnp.dot((self.dt_norm(dt) if normed else dt).astype(self.dtype), self.w_dt.astype(self.dtype), preferred_element_type=jnp.float32)
+        return jax.nn.softplus(pre + self.dt_bias.astype(jnp.float32)), self.b_norm(b) if normed else b, self.c_norm(cc) if normed else cc
 
     def _a(self):
         return -jnp.exp(self.a_log.astype(jnp.float32))
 
     def _out(self, y, x, z):
         """The skip, the gate and ``W_out``: ``y`` (float32, as the recurrence
-        left it), ``x`` the convolved inputs and ``z`` the gate's, all (B, T, d)."""
+        left it), ``x`` the convolved inputs and ``z`` the gate's, all (B, T, d).
+        The memory layer's output is the pair ``(out, m)``, ``m`` the skip's sum before the gate."""
         y = y + self.d_skip.astype(jnp.float32) * x.astype(jnp.float32)
-        return self._mm(y * jax.nn.silu(z.astype(jnp.float32)), self.w_out)
+        out = self._mm(y * jax.nn.silu(z.astype(jnp.float32)), self.w_out)
+        return (out, y) if self.memory else out
 
     @staticmethod
     def _tap(state):
@@ -154,7 +164,8 @@ class MambaMixer(nn.Module):
 
     def expand(self, u) -> Tuple[jnp.ndarray, RecurrentState]:
         """Whole rows ``u`` (B, T, h) from an empty state: the output (B, T, h)
-        and the rows' state after their last token (the window in ``dtype``)."""
+        (the memory layer: the pair of it and ``m`` (B, T, d_inner) float32) and
+        the rows' state after their last token (the window in ``dtype``)."""
         c = self.config
         k, d = c.mamba_d_conv, self.d_inner
         with jax.named_scope("ssm/proj_in"):
@@ -176,7 +187,8 @@ class MambaMixer(nn.Module):
     # ------------------------------------------------------------- one step
 
     def step(self, u, state: RecurrentState) -> Tuple[jnp.ndarray, RecurrentState]:
-        """One new token a row, ``u`` (B, 1, h), against ``state``: the output (B, 1, h) and the advanced state."""
+        """One new token a row, ``u`` (B, 1, h), against ``state``: the output (B, 1, h) (the memory layer: the pair
+        of it and ``m`` (B, 1, d_inner)) and the advanced state."""
         d = self.d_inner
         with jax.named_scope("ssm/proj_in"):
             xz = self._mm(u, self.w_in)
@@ -194,3 +206,36 @@ class MambaMixer(nn.Module):
             self._tap(h)
         with jax.named_scope("ssm/out"):
             return self._out(y[:, None], x, z), RecurrentState(conv=kept, ssm=h)
+
+
+class GatedMemoryUnit(nn.Module):
+    """A gated memory unit (arXiv:2507.06607 section 2): a layer of the
+    cross-decoder that mixes no tokens and keeps no state, and gates the
+    **memory** ``m`` the last state-space layer below handed on (a
+    :class:`MambaMixer` with ``memory``) with the layer's own input::
+
+        out_t = W_out (silu(W_in u_t) * m_t)            W_in: h -> d_inner, W_out: d_inner -> h, no bias
+
+    ``m`` is float32 and the gate is float32; the two products take ``dtype``
+    operands. ``config`` needs ``hidden_size``, ``mamba_expand`` and ``init_scale``."""
+
+    config: object
+    dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
+
+    def setup(self):
+        c = self.config
+        init = nn.initializers.normal(c.init_scale)
+        d = c.mamba_expand * c.hidden_size
+        self.w_in = self.param("w_in", init, (c.hidden_size, d), self.param_dtype)
+        self.w_out = self.param("w_out", init, (d, c.hidden_size), self.param_dtype)
+
+    def __call__(self, u, memory):
+        """``u`` (B, T, h) and the memory at the same positions (B, T, d_inner): the output (B, T, h)."""
+        if probes.active():
+            probes.tap("gmu.memory", {"gmu_memory_rms_sum": jnp.sqrt(jnp.mean(jnp.square(memory))),
+                                      "gmu_sites": jnp.ones((), jnp.int32)})
+        with jax.named_scope("gmu"):  # one scope: the compiler fuses the gate into the product after it
+            gate = jnp.dot(u.astype(self.dtype), self.w_in.astype(self.dtype))
+            gated = jax.nn.silu(gate.astype(jnp.float32)) * memory
+            return jnp.dot(gated.astype(self.dtype), self.w_out.astype(self.dtype))

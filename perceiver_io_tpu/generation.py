@@ -1829,6 +1829,15 @@ def make_instrumented_generate_fn(
     m_kda_nonfinite = registry.counter("kda_state_nonfinite_total") if kda_taps else None
     m_kda_decay = registry.gauge("kda_decay_mean") if kda_taps else None
     m_kda_beta = registry.gauge("kda_beta_mean") if kda_taps else None
+    # a decoder-hybrid-decoder stack (``core/diff_attention.py`` taps ``yoco.cache`` a reading layer and ``yoco.lam`` an
+    # attention, ``core/ssm.py`` ``gmu.memory`` a unit): the one cache's length and bytes, its reads, the memory's rms
+    yoco_taps = probes and "yoco.*" in decoder.tap_scopes
+    m_yoco_length = registry.gauge("yoco_cache_length") if yoco_taps else None
+    m_yoco_bytes = registry.gauge("yoco_cache_bytes") if yoco_taps else None
+    m_yoco_reads = registry.counter("yoco_cache_reads_total") if yoco_taps else None
+    m_lam = registry.gauge("diff_lam_mean") if yoco_taps else None
+    gmu_taps = probes and "gmu.*" in decoder.tap_scopes
+    m_gmu_rms = registry.gauge("gmu_memory_rms") if gmu_taps else None
     # a model that drafts for itself (a ``speculative`` decoder): a step yields 0 to 2 tokens a row, every
     # step is host-timed as one TPOT sample, and the ``spec.step`` taps keep the drafting's books
     self_drafting = getattr(decoder, "speculative", False)
@@ -1981,6 +1990,19 @@ def make_instrumented_generate_fn(
                     m_kda_nonfinite.inc(health_row["kda_state_nonfinite"])
                     m_kda_decay.set(health_row["kda_decay_mean"])
                     m_kda_beta.set(health_row["kda_beta_mean"])
+                if yoco_taps:
+                    health_row["yoco_cache_length"] = max(int(h["yoco_cache_length_max"]) for h in hh)
+                    health_row["yoco_cache_bytes"] = int(max(float(h["yoco_cache_bytes_max"]) for h in hh))
+                    health_row["yoco_cache_reads"] = sum(int(h["yoco_reads"]) for h in hh)
+                    health_row["diff_lam_mean"] = round(sum(float(h["diff_lam_sum"]) for h in hh) / max(sum(int(h["diff_lam_sites"]) for h in hh), 1), 6)
+                    health_row["diff_lam_max"] = round(max(float(h["diff_lam_max"]) for h in hh), 6)
+                    m_yoco_length.set(health_row["yoco_cache_length"])
+                    m_yoco_bytes.set(health_row["yoco_cache_bytes"])
+                    m_yoco_reads.inc(health_row["yoco_cache_reads"])
+                    m_lam.set(health_row["diff_lam_mean"])
+                if gmu_taps:
+                    health_row["gmu_memory_rms"] = round(sum(float(h["gmu_memory_rms_sum"]) for h in hh) / max(sum(int(h["gmu_sites"]) for h in hh), 1), 6)
+                    m_gmu_rms.set(health_row["gmu_memory_rms"])
                 if spec_taps:
                     drafts, accepted = (sum(int(h[k]) for h in hh) for k in ("drafts", "accepted"))
                     m_spec_drafts.inc(drafts)

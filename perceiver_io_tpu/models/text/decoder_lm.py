@@ -1,7 +1,8 @@
 """A decoder-only language model: token embedding, blocks
-``x + Attn(RMSNorm(x))`` then ``x + FFN(RMSNorm(x))``, a final RMSNorm and an
-untied head (``docs/decoder-lm.md``). One class, two published families, told
-apart by the configuration (a third, K-EXAONE, puts the first's expert layer
+``x + Mix(Norm(x))`` then ``x + FFN(Norm(x))``, a final norm and a head, untied
+or the embedding table itself (``docs/decoder-lm.md``); the norm is an RMSNorm
+or, where the configuration gives ``layer_norm_eps``, a LayerNorm with scale and
+bias. One class, eight published families, told apart by the configuration (a third, K-EXAONE, puts the first's expert layer
 on the second's skeleton and adds the drafting module below; a fourth,
 LongCat-Flash, changes the block itself: **the shortcut-connected block**,
 further down):
@@ -35,8 +36,24 @@ further down):
   :class:`LatentCache` carries the length a step's position is read off;
   leading dense layers, then sigmoid-routed experts with a shared expert.
 
-Unlike the Perceiver models every position passes the whole stack, so there
-is no latent window. The model meets :mod:`perceiver_io_tpu.generation`
+- **Phi-4-mini-flash** (SambaY; ``layer_types`` with ``"gmu"`` and
+  ``"cross_attention"`` entries, ``differential_attention``): a
+  decoder-hybrid-decoder stack. Below, state-space layers without Jamba's inner
+  norms and differential window attention (``core/diff_attention.py``: the
+  difference of two softmax maps a head pair under a learned scalar, an RMSNorm
+  over the pair); one differential full attention whose :class:`KVCache` is
+  **the shared cache**; above it gated memory units (``core/ssm.py``), which
+  gate the last state-space layer's scan output with their own input and keep
+  no state, and cross-attentions that project a query only and read the shared
+  cache. **A layer that reads owns no entry of the generator's state**, which is
+  one entry a layer that owns a cache or a state; the cache is written once a
+  step and read by every layer above the one that owns it, never copied. The
+  prompt pass runs the layers below the owning one over every position, that
+  layer's key and value projections over the prompt, and everything above at
+  the last position alone (``config.prompt_layers``, :func:`prefill`).
+
+Unlike the Perceiver models every position passes the whole stack (the last
+family's prompt pass apart), so there is no latent window. The model meets :mod:`perceiver_io_tpu.generation`
 through :meth:`DecoderLanguageModel.generation_decoder`.
 
 **The multi-token-prediction module** (``num_nextn_predict_layers`` 1; the
@@ -88,16 +105,17 @@ from perceiver_io_tpu.core.cache import (
     DeltaState, KVCache, LatentCache, RaggedKVCache, RaggedWindowKVCache, RecurrentState, RetentionState, WindowKVCache,
     init_kv_cache, init_latent_cache, init_ragged_kv_cache, init_ragged_window_kv_cache, init_window_kv_cache,
 )
+from perceiver_io_tpu.core.diff_attention import DifferentialAttention
 from perceiver_io_tpu.core.gqa import GroupedQueryAttention, verify_fused
 from perceiver_io_tpu.core.kda import KimiDeltaAttention
 from perceiver_io_tpu.core.mla import VIEWS, MultiHeadLatentAttention, expand_views
 from perceiver_io_tpu.core.moe import MoELayer, SwiGLU, grouped_combine
 from perceiver_io_tpu.core.retention import PowerRetention
-from perceiver_io_tpu.core.ssm import MambaMixer
+from perceiver_io_tpu.core.ssm import GatedMemoryUnit, MambaMixer
 from perceiver_io_tpu.obs import probes
 from perceiver_io_tpu.ops.gqa_verify import verify_plan
 from perceiver_io_tpu.ops.kda import chunk_of as kda_chunk_of, kda_plans
-from perceiver_io_tpu.ops.layernorm import RMSNorm
+from perceiver_io_tpu.ops.layernorm import LayerNorm, RMSNorm
 from perceiver_io_tpu.ops.mla_absorb import row_tile
 from perceiver_io_tpu.ops.power_retention import chunk_of, feature_rows, power_retention_plans
 from perceiver_io_tpu.ops.selective_scan import ssm_scan_plans
@@ -107,7 +125,10 @@ _ATTENTION_TYPES = ("sliding_attention", "full_attention")
 _RETENTION = "power_retention"
 _KDA = "kda"
 _LATENT = "latent_attention"
-_LAYER_TYPES = _ATTENTION_TYPES + ("mamba", _RETENTION, _KDA, _LATENT)
+_GMU = "gmu"
+_CROSS = "cross_attention"
+_READERS = (_GMU, _CROSS)  # layers that own no cache and no state: they read what a layer below handed on or wrote
+_LAYER_TYPES = _ATTENTION_TYPES + ("mamba", _RETENTION, _KDA, _LATENT) + _READERS
 _STATEFUL = ("mamba", _RETENTION, _KDA)  # a mixer whose past is a state of one size, handed on as it leaves the prompt pass
 _POSITIONLESS = ("mamba", _KDA)  # of those, the mixers that read no position (``self.mixer``, not ``self.attn``)
 
@@ -212,6 +233,29 @@ class DecoderLanguageModelConfig:
     mla_head_gate: bool = False
     short_conv_kernel_size: int = 4
     kda_lower_bound: float = -5.0
+    layer_norm_eps: Optional[float] = None
+    differential_attention: bool = False
+    mamba_inner_norms: bool = True
+
+    @property
+    def memory_layer(self) -> Optional[int]:
+        """The state-space layer whose scan output the ``gmu`` layers read: the last ``mamba`` layer below the first of them."""
+        kinds = self.layer_types or ()
+        return max(i for i in range(kinds.index(_GMU)) if kinds[i] == "mamba") if _GMU in kinds else None
+
+    @property
+    def shared_cache_layer(self) -> Optional[int]:
+        """The layer whose keys and values the ``cross_attention`` layers read: the last ``full_attention`` layer below the first of them."""
+        kinds = self.layer_types or ()
+        return max(i for i in range(kinds.index(_CROSS)) if kinds[i] == "full_attention") if _CROSS in kinds else None
+
+    @property
+    def prompt_layers(self) -> int:
+        """How many layers a prompt pass runs over every position: readers
+        alone stand above the layer that owns the shared cache, so no layer
+        reads what it or they would compute at a position but the last."""
+        shared = self.shared_cache_layer
+        return self.num_hidden_layers if shared is None else shared
 
     def __post_init__(self):
         if self.block not in _BLOCKS:
@@ -239,6 +283,21 @@ class DecoderLanguageModelConfig:
                 raise ValueError("kda and latent_attention entries mix with each other alone")
             if attends and self.num_attention_heads % self.num_key_value_heads:
                 raise ValueError("num_key_value_heads must divide num_attention_heads")
+            kinds = self.layer_types
+            if _GMU in kinds and "mamba" not in kinds[:kinds.index(_GMU)]:
+                raise ValueError("a gmu layer reads the memory of a mamba layer below it")
+            if _CROSS in kinds and "full_attention" not in kinds[:kinds.index(_CROSS)]:
+                raise ValueError("a cross_attention layer reads the cache of a full_attention layer below it")
+            if _CROSS in kinds and not self.differential_attention:
+                raise ValueError("a cross_attention layer is built in the differential form alone")
+            if set(kinds) & set(_READERS) and (_CROSS not in kinds or set(kinds[self.shared_cache_layer + 1:]) - set(_READERS)):
+                # what the cut prompt pass rests on: nothing above the owning layer is read at a position but the last
+                raise ValueError("gmu and cross_attention layers, and they alone, stand above the layer that owns the shared cache")
+            if self.differential_attention and self.num_key_value_heads % 2:
+                raise ValueError("differential attention pairs the heads: an even number of key-value heads")
+        if self.differential_attention and (self.layer_types is None or set(self.layer_types) & {_RETENTION, _KDA, _LATENT}
+                                            or self.num_nextn_predict_layers):
+            raise ValueError("differential attention: the grouped-query layer types' form, with no drafting module")
         if self.num_nextn_predict_layers:
             object.__setattr__(self, "mtp_layer_types", tuple(self.mtp_layer_types))
             if self.layer_types is None or self.num_nextn_predict_layers != 1 or len(self.mtp_layer_types) != 1 \
@@ -273,41 +332,67 @@ def _residual(x, y):
     return x + y
 
 
+def _norm(c: DecoderLanguageModelConfig, **kw):
+    """A block's norm: RMSNorm, or where the configuration gives ``layer_norm_eps`` a LayerNorm with scale and bias."""
+    if c.layer_norm_eps is not None:
+        return LayerNorm(epsilon=c.layer_norm_eps, **kw)
+    return RMSNorm(epsilon=c.rms_norm_eps, **kw)
+
+
 class DecoderBlock(nn.Module):
     config: DecoderLanguageModelConfig
     sparse: bool
     layer_type: Optional[str] = None  # None: latent attention, as ``"latent_attention"``
+    index: int = 0  # the layer's depth: differential attention's ``lam0`` and the memory layer are read off it
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
 
     def setup(self):
         c = self.config
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype)
-        self.attn_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
+        self.attn_norm = _norm(c, **kw)
         if _latent(self.layer_type):
             self.attn = MultiHeadLatentAttention(c, **kw)
         elif self.layer_type == "mamba":
-            self.mixer = MambaMixer(c, **kw)
+            self.mixer = MambaMixer(c, memory=self.hands_on_memory, **kw)
+        elif self.layer_type == _GMU:
+            self.mixer = GatedMemoryUnit(c, **kw)
         elif self.layer_type == _KDA:
             self.mixer = KimiDeltaAttention(c, **kw)
         elif self.layer_type == _RETENTION:
             self.attn = PowerRetention(c, **kw)
+        elif c.differential_attention:
+            self.attn = DifferentialAttention(c, kind=self.layer_type, index=self.index, **kw)
         else:
             self.attn = GroupedQueryAttention(c, window=self.layer_type == "sliding_attention", **kw)
-        self.ffn_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
+        self.ffn_norm = _norm(c, **kw)
         if self.sparse:
             self.ffn = MoELayer(c, **kw)
         else:
             self.ffn = SwiGLU(c.hidden_size, c.intermediate_size, c.init_scale, **kw)
 
-    def attend(self, x, pos):
+    @property
+    def hands_on_memory(self) -> bool:
+        return self.index == self.config.memory_layer
+
+    def attend(self, x, pos, source=None):
         """``x + Attn(RMSNorm(x))`` over whole rows, expanded; also the cache
         rows (latent attention: one array; grouped-query: rotated keys and
         values, of which a window layer hands on its last ``sliding_window``;
-        a state-space layer: the rows' :class:`RecurrentState`; a delta layer:
-        the rows' :class:`DeltaState`; a retention layer: the rows' final ``(S, z)``)."""
+        a state-space layer: the rows' :class:`RecurrentState`, the memory
+        layer's the pair of it and the memory ``m``; a delta layer:
+        the rows' :class:`DeltaState`; a retention layer: the rows' final ``(S, z)``).
+        A layer that owns nothing reads ``source`` (a ``gmu`` layer the memory
+        at the same positions, a ``cross_attention`` layer the owning layer's
+        rows) and hands on ``()``."""
+        if self.layer_type == _GMU:
+            return _residual(x, self.mixer(self.attn_norm(x), source)), ()
+        if self.layer_type == _CROSS:
+            return _residual(x, self.attn.expand(self.attn_norm(x), pos, kv=source)[0]), ()
         if self.layer_type in _POSITIONLESS:
             a, state = self.mixer.expand(self.attn_norm(x))
+            if self.hands_on_memory:  # the mixer's output is the pair of it and the memory
+                return _residual(x, a[0]), (state, a[1])
             return _residual(x, a), state
         a, rows = self.attn.expand(self.attn_norm(x), pos)
         if self.layer_type == "sliding_attention":
@@ -321,18 +406,33 @@ class DecoderBlock(nn.Module):
         with jax.named_scope("dense_mlp"):
             return x + self.ffn(self.ffn_norm(x))
 
+    def read(self, x, source):
+        """A step, or a prompt pass's last position, of a layer that writes
+        nothing: ``x`` (B, 1, h) against ``source``, a ``gmu`` layer the memory
+        of the same position, an attention the cache as it lies (a
+        ``cross_attention`` layer's whole step; the owning layer's own last position)."""
+        if self.layer_type == _GMU:
+            return self.feed_forward(_residual(x, self.mixer(self.attn_norm(x), source)))
+        return self.feed_forward(_residual(x, self.attn.read(self.attn_norm(x), source)))
+
     def step(self, x, cache, pos):
         if self.layer_type in _POSITIONLESS:  # the state has no positions
             a, cache = self.mixer.step(self.attn_norm(x), cache)
+            if self.hands_on_memory:  # the advanced state and, beside it, what the layers above read this step
+                return self.feed_forward(_residual(x, a[0])), (cache, a[1])
             return self.feed_forward(_residual(x, a)), cache
         one_token = self.attn.absorb if _latent(self.layer_type) else self.attn.step
         a, cache = one_token(self.attn_norm(x), cache, pos)
         return self.feed_forward(_residual(x, a)), cache
 
-    def __call__(self, x, pos):
+    def __call__(self, x, pos, source=None):
         """Whole rows ``x`` (B, N, h): the block's output and its cache rows."""
-        x, rows = self.attend(x, pos)
+        x, rows = self.attend(x, pos, source)
         return self.feed_forward(x), rows
+
+    def shared_kv(self, x):
+        """The owning layer's cache rows of whole rows ``x`` (B, N, h), its norm and its two projections alone."""
+        return self.attn.kv(self.attn_norm(x))
 
     def verify(self, x, cache, pos):
         """A speculative step's positions, each row at its own length: written to ``cache``, not yet kept."""
@@ -466,10 +566,10 @@ class DecoderLanguageModel(nn.Module):
         else:
             self.layers = [
                 DecoderBlock(c, sparse=i >= c.first_k_dense_replace, layer_type=c.layer_types and c.layer_types[i],
-                             name=f"layer_{i}", **kw)
+                             index=i, name=f"layer_{i}", **kw)
                 for i in range(c.num_hidden_layers)
             ]
-        self.out_norm = RMSNorm(epsilon=c.rms_norm_eps, **kw)
+        self.out_norm = _norm(c, **kw)
         if not c.tie_word_embeddings:
             self.head = self.param(
                 "head", nn.initializers.normal(c.init_scale), (c.hidden_size, c.vocab_size), self.param_dtype
@@ -501,6 +601,23 @@ class DecoderLanguageModel(nn.Module):
     def whole_layer(self, x, pos, i: int):
         return self.layers[i](x, pos)
 
+    def shared_kv(self, x, i: int):
+        return self.layers[i].shared_kv(x)
+
+    def last_position(self, x, memory, rows):
+        """The prompt pass above the self-decoder, at the last position alone:
+        ``x`` (B, 1, h) as layer ``prompt_layers - 1`` left it, the memory
+        (B, 1, d_inner) of that position and the owning layer's ``rows`` of the
+        whole prompt, key pairs and values (B * Hkv/2, N, 2d), every slot live.
+        The owning layer's query side, then the readers: logits (B, V)."""
+        c = self.config
+        with jax.named_scope("prefill/last"):
+            shared = KVCache(k=rows[0], v=rows[1], length=jnp.asarray(rows[0].shape[1], jnp.int32))
+            x = self.layers[c.prompt_layers].read(x, shared)
+            for i in range(c.prompt_layers + 1, c.num_hidden_layers):
+                x = self.layers[i].read(x, memory if c.layer_types[i] == _GMU else shared)
+        return self.logits(x[:, 0])
+
     # the module's parts, for the prompt pass's chunk loops in the same way
 
     def mtp_project(self, x, next_ids):
@@ -522,11 +639,18 @@ class DecoderLanguageModel(nn.Module):
         """Logits (B, N, V) float32 of a full causal forward, no cache. With
         ``drafts`` also the module's logits (B, N - 1, V): at position i, from
         ``h_i`` and ``t_{i+1}``, its prediction of ``t_{i+2}``."""
+        c = self.config
         b, n = input_ids.shape
         pos = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None], (b, n))
         x = self.embed(input_ids)
-        for layer in self.layers:
-            x, _ = layer(x, pos)
+        handed = {}  # what the readers read: the memory layer's ``m`` and the owning layer's rows, over every position
+        for i, layer in enumerate(self.layers):
+            reads = handed.get(getattr(layer, "layer_type", None))
+            x, rows = layer(x, pos) if reads is None else layer(x, pos, reads)
+            if i == c.memory_layer:
+                handed[_GMU] = rows[1]
+            if i == c.shared_cache_layer:
+                handed[_CROSS] = rows
         if not drafts:
             return self.logits(x)
         u = self.mtp_project(x[:, :-1], input_ids[:, 1:])
@@ -548,8 +672,17 @@ class DecoderLanguageModel(nn.Module):
                 x, pair = layer.step(x, caches[2 * i: 2 * i + 2], pos)
                 new.extend(pair)
             return self.logits(x[:, 0]), tuple(new)
-        for layer, cache in zip(self.layers, caches):
-            x, cache = layer.step(x, cache, pos)
+        owned = iter(caches)  # one entry a layer that owns a cache or a state; the readers own none
+        handed = {}  # the memory of this step's position, and the shared cache with this step's row written
+        for i, layer in enumerate(self.layers):
+            if layer.layer_type in _READERS:  # nothing of its own to advance
+                x = layer.read(x, handed[layer.layer_type])
+                continue
+            x, cache = layer.step(x, next(owned), pos)
+            if i == self.config.memory_layer:
+                cache, handed[_GMU] = cache
+            if i == self.config.shared_cache_layer:
+                handed[_CROSS] = cache
             new.append(cache)
         return self.logits(x[:, 0]), tuple(new)
 
@@ -614,7 +747,15 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
     attention: a row's time axis is the scan kernel's to chunk, and a padded
     row would run its padding through the state); of a retention layer the
     rows' final ``(S, z)``, float32; of a delta layer the rows'
-    :class:`DeltaState`. The hidden state of the whole batch
+    :class:`DeltaState`. A ``gmu`` or ``cross_attention`` layer owns nothing
+    and has no entry. **Where such layers alone stand above the layer that owns
+    the shared cache** (``config.prompt_layers``; the SambaY family) the pass
+    over every position stops below that layer: its keys and values are
+    projected over the prompt (the cache rows, (B * Hkv/2, N, 2d) each), and its
+    query side and every layer above run **at the last position only**, one row
+    a batch row against those rows and the memory layer's ``m`` of that position
+    (:meth:`DecoderLanguageModel.last_position`): nothing reads what they would
+    compute elsewhere. The hidden state of the whole batch
     stays in memory between layers (B * N * h); within a layer the attention
     runs over chunks of whole rows and the feed-forward over chunks of tokens
     (``_PREFILL_ATTENTION_TOKENS``, ``_PREFILL_FFN_TOKENS``), inside the one
@@ -635,13 +776,24 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
 
     x = scoped("embed", input_ids)
     cache_rows = []
-    for i in range(c.num_hidden_layers):
+    stop = c.prompt_layers
+    memory = None
+    for i in range(stop):
         if c.block == "shortcut":  # the whole layer over a chunk of whole rows: the branch's output lives a chunk long
             x, pair = _over_chunks(lambda xc, i=i: scoped("whole_layer", xc, pos, i), x.reshape(b // rows_a, rows_a, n, h))
             cache_rows.extend(_batch_rows(rows, b, n) for rows in pair)
             x = x.reshape(b, n, h)
             continue
-        x, rows = _over_chunks(lambda xc, i=i: scoped("attend_layer", xc, pos, i), x.reshape(b // rows_a, rows_a, n, h))
+        if i == c.memory_layer:  # of the memory, the last position is all that is read
+            def attend(xc, i=i):
+                xc, (state, m) = scoped("attend_layer", xc, pos, i)
+                return xc, (state, m[:, -1:])
+        else:
+            attend = lambda xc, i=i: scoped("attend_layer", xc, pos, i)  # noqa: E731
+        x, rows = _over_chunks(attend, x.reshape(b // rows_a, rows_a, n, h))
+        if i == c.memory_layer:  # (the state, the memory): the second is handed on, not kept
+            rows, memory = rows
+            memory = memory.reshape(b, *memory.shape[2:])
         if _latent(kinds[i]):
             cache_rows.append(_batch_rows(rows, b, n))
         elif kinds[i] in _STATEFUL:  # (chunks, rows a chunk, ...): the rows' states, as they leave the kernel
@@ -650,6 +802,12 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
             cache_rows.append(tuple(r.reshape(b * r.shape[2], *r.shape[3:]) for r in rows))
         x, _ = _over_chunks(lambda xc, i=i: (scoped("ffn_layer", xc, i), ()), x.reshape(b * n // tokens_f, tokens_f, h))
         x = x.reshape(b, n, h)
+    if stop < c.num_hidden_layers:  # the owning layer's rows over the prompt, then everything above at the last position
+        x = x.reshape(b // rows_a, rows_a, n, h)
+        _, rows = _over_chunks(lambda xc: (xc, scoped("shared_kv", xc, stop)), x)
+        rows = tuple(r.reshape(b * r.shape[2], *r.shape[3:]) for r in rows)
+        cache_rows.append(rows)
+        return scoped("last_position", x.reshape(b, n, h)[:, -1:], memory, rows), tuple(cache_rows)
     if keep_hidden:
         return scoped("logits", x[:, -1]), tuple(cache_rows), x
     return scoped("logits", x[:, -1]), tuple(cache_rows)
@@ -713,7 +871,8 @@ class _Decoder:
         sparse = c.block == "shortcut" or c.first_k_dense_replace < c.num_hidden_layers
         kinds = c.layer_types or ()
         return ((("moe.*",) if sparse else ()) + ("spec.*",) + (("ssm.*",) if "mamba" in kinds else ())
-                + (("ret.*",) if _RETENTION in kinds else ()) + (("kda.*",) if _KDA in kinds else ()))
+                + (("ret.*",) if _RETENTION in kinds else ()) + (("kda.*",) if _KDA in kinds else ())
+                + (("yoco.*",) if _CROSS in kinds else ()) + (("gmu.*",) if _GMU in kinds else ()))
 
     def _caches(self, rows, batch: int, n: int, max_new_tokens: int, cache_dtype):
         c = self.model.config
@@ -727,11 +886,15 @@ class _Decoder:
             if kind == _RETENTION:  # float32 whatever the caches' dtype; the state holds the prompt's ``n`` tokens
                 return RetentionState(s=kept[0], z=kept[1], length=jnp.asarray(n, jnp.int32))
             slots, d = batch * c.num_key_value_heads, c.head_dim
+            if c.differential_attention:  # a pair of key heads, and of value heads, is a row (``core/diff_attention.py``)
+                slots, d = slots // 2, 2 * d
             if kind == "sliding_attention":
                 return init_window_kv_cache(slots, c.sliding_window, d, d, cache_dtype).fill(*kept, n)
             return init_kv_cache(slots, n + max_new_tokens, d, d, cache_dtype).append(*kept)
 
-        return tuple(cache_of(kind, kept) for kind, kept in zip(c.layer_types or (None,) * len(rows), rows))
+        # one entry a layer that owns a cache or a state: a ``gmu`` or ``cross_attention`` layer reads another's
+        owners = tuple(kind for kind in c.layer_types or (None,) * len(rows) if kind not in _READERS)
+        return tuple(cache_of(kind, kept) for kind, kept in zip(owners, rows))
 
     def _refuse(self, pad_mask, n: int, max_new_tokens: int):
         c = self.model.config
@@ -860,16 +1023,31 @@ class _Decoder:
                     "ret_chunk": chunk_of(prompt_len),
                     "power_retention": power_retention_plans(),
                 }
-            if "mamba" in kinds:  # a state of one size beside the caches that grow; no expert layer, no ring
+            if "mamba" in kinds:  # states of one size beside the caches that grow; no expert layer
                 d_inner, n_ssm = c.mamba_expand * c.hidden_size, kinds.count("mamba")
-                return {
+                ssm = {
                     "ssm_layers": n_ssm,
                     "ssm_state_bytes": batch * c.mamba_d_state * d_inner * 4 * n_ssm,
                     "ssm_conv_bytes": batch * (c.mamba_d_conv - 1) * d_inner * itemsize * n_ssm,
                     "ssm_state_dtype": "float32",
-                    "kv_cache_full_layers": n_full,
-                    "kv_cache_full_bytes": batch * (prompt_len + max_new_tokens) * row_bytes * n_full,
                     "ssm_scan": ssm_scan_plans(),
+                }
+                full_bytes = batch * (prompt_len + max_new_tokens) * row_bytes
+                if _CROSS not in kinds:  # no ring: the attention layers' caches grow, one a layer
+                    return {**ssm, "kv_cache_full_layers": n_full, "kv_cache_full_bytes": full_bytes * n_full}
+                readers = 1 + kinds.count(_CROSS)  # a decoder-hybrid-decoder stack: rings, and one cache that every cross layer reads
+                return {
+                    **ssm,
+                    "kv_cache_window_layers": n_window,
+                    "kv_cache_window_bytes": batch * c.sliding_window * row_bytes * n_window,
+                    "kv_cache_window_rows": c.sliding_window,
+                    "shared_cache_layer": c.shared_cache_layer,
+                    "shared_cache_bytes": full_bytes,
+                    "shared_cache_readers": readers,
+                    "shared_cache_bytes_unshared": full_bytes * readers,
+                    "memory_layer": c.memory_layer,
+                    "gmu_layers": kinds.count(_GMU),
+                    "prompt_layers": c.prompt_layers,
                 }
             row = {
                 "kv_cache_full_layers": n_full,

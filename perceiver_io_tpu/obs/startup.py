@@ -150,7 +150,7 @@ def _install() -> None:
     if RECORD.on_closed is not None:
         return
     RECORD.on_open, RECORD.on_close, RECORD.on_closed = _on_open, _on_close, _count
-    for earlier in list(RECORD.spans):  # the packages imported before this one
+    for earlier in RECORD.held():  # the packages imported before this one
         _count(earlier)
     try:
         from jax import monitoring
@@ -181,8 +181,9 @@ def clock() -> tuple:
 
 def dropped() -> int:
     """Closed spans the record no longer holds (it keeps the newest
-    ``_startup.MAX_SPANS``): a reader of set-up that finds any has lost the
-    process's first spans."""
+    ``_startup.MAX_SPANS`` and, apart from them, every import span): a reader
+    of set-up that finds any has lost the first traces, lowers or compiles of
+    the process, never its imports."""
     return RECORD.dropped
 
 
@@ -226,7 +227,7 @@ def hand_to(tracer) -> None:
     with obs_trace._AMBIENT_LOCK:
         under = obs_trace._AMBIENT[-1].span_id if obs_trace._AMBIENT else None
     process = obs_trace._process_index()
-    for s in list(RECORD.spans):
+    for s in RECORD.held():
         tracer.record(obs_trace.Span(
             name=s.name, span_id=s.span_id, parent_id=s.parent_id or under, process_index=process,
             attrs={**s.attrs, "self_ms": round(1e3 * s.self_s, 3)},

@@ -294,6 +294,13 @@ LAYER_SCOPES = frozenset({
     "ret/proj", "ret/gate", "ret/chunk", "ret/update", "ret/out",
     # a Kimi delta attention layer (core/kda.py): the chunked form is the prompt pass's, the update a step's
     "kda/proj", "kda/conv", "kda/gate", "kda/chunk", "kda/update", "kda/out",
+    # differential attention (core/diff_attention.py): the flash form is the prompt pass's, the step a token's; the
+    # layer that owns the shared cache writes under ``yoco/kv`` and the cross layers read under ``yoco/cross``
+    "diff/proj", "diff/flash", "diff/step", "diff/combine", "yoco/kv", "yoco/cross",
+    # a gated memory unit (core/ssm.py). ``prefill/last`` is a cut prompt pass's last position (models/text/decoder_lm.py):
+    # every layer under it opens its own scope, so it names a table row only for what none of them claims; it is there for
+    # the compiled text, where tests/test_tpu_compile.py finds by it what the pass runs at one position a row
+    "gmu", "prefill/last",
 })
 # flax module names that mark a layer no scope is opened for
 MODULE_LAYERS = {"q_proj": "qkv_proj", "k_proj": "qkv_proj", "v_proj": "qkv_proj", "o_proj": "o_proj"}
@@ -303,7 +310,9 @@ _NORM_MODULE = re.compile(r"(^|_)norm$|^(Layer|RMS)Norm_\d+$")
 CLOSED_LAYERS = frozenset({"mlp", "dense_mlp", "mla/expand", "mla/absorb", "attn/window", "attn/full",
                            "ssm/proj_in", "ssm/conv", "ssm/select", "ssm/scan", "ssm/update", "ssm/out",
                            "ret/proj", "ret/gate", "ret/chunk", "ret/update", "ret/out",
-                           "kda/proj", "kda/conv", "kda/gate", "kda/chunk", "kda/update", "kda/out"})
+                           "kda/proj", "kda/conv", "kda/gate", "kda/chunk", "kda/update", "kda/out",
+                           "diff/proj", "diff/flash", "diff/step", "diff/combine", "yoco/kv", "yoco/cross",
+                           "gmu"})
 # parts of a name stack that are no scope: what a transform or a loop wraps around the names. A transform
 # wraps the first scope opened under it (``transpose(jvp(loss))`` is the scope ``loss``); ``jit`` wraps the name
 # of a function, which is no scope

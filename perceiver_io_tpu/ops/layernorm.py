@@ -47,18 +47,20 @@ class LayerNorm(nn.Module):
     Scope deviations from ``nn.LayerNorm`` (intentional, ADVICE r3): with a
     narrow ``dtype`` the normalize stays in f32 end-to-end and only the
     output is cast (flax casts before normalizing — slightly looser
-    numerics); the ``use_scale``/``use_bias``/``param_dtype`` knobs are not
-    reproduced (no caller in this framework disables scale/bias or narrows
-    parameter storage)."""
+    numerics); the ``use_scale``/``use_bias`` knobs are not reproduced (no
+    caller in this framework disables scale/bias). ``param_dtype`` is the
+    parameters' storage (float32 unless the decoder-only class stores a
+    published bfloat16 model's norms as it does every other weight)."""
 
     epsilon: float = 1e-5
     dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x):
         c = x.shape[-1]
-        scale = self.param("scale", nn.initializers.ones_init(), (c,))
-        bias = self.param("bias", nn.initializers.zeros_init(), (c,))
+        scale = self.param("scale", nn.initializers.ones_init(), (c,), self.param_dtype)
+        bias = self.param("bias", nn.initializers.zeros_init(), (c,), self.param_dtype)
         return layer_norm(x, scale, bias, self.epsilon, self.dtype)
 
 

@@ -865,6 +865,47 @@ def test_the_jamba_cells_generator_carries_its_state_in_place(one_chip, mosaic, 
             assert _elements(result(ins)) < 16 * 256 * 5120, ins.line[:300]
 
 
+# ------------------------------------------ a decoder-hybrid-decoder stack: one cache that eight layers read, a prompt pass cut at it
+
+
+def test_the_phi4flash_cells_generator_shares_one_cache_and_cuts_its_prompt_pass(one_chip, mosaic, monkeypatch):
+    """``phi4flash-decode-b32-p8k`` as the benchmark builds it (3.85B bfloat16
+    parameters whole, 32 prompts of 8192 tokens, 256 new tokens), compiled for a
+    described v5e: under the 16.9 GB the runtime offers with 2 GB to spare; the
+    decode loop carries **one** pair of arrays of the shared cache's shape
+    (``bf16[320, 8448, 128]``: 10 key-value pairs a row, keys and values), which
+    its body updates in place by two ``dynamic-update-slice`` and never copies,
+    turns or converts, whatever the number of layers that read it; and the
+    prompt pass runs 35 chunk loops (17 layers' mixers and feed-forwards and the
+    owning layer's key and value projections), not 64, with nothing under
+    ``prefill/last`` larger than one position a row against the prompt's keys."""
+    import re
+
+    compiled = _cell_generator("phi4flash-decode-b32-p8k", "phi4flash", one_chip, monkeypatch)
+    m = compiled.memory_analysis()
+    assert 7.70e9 < m.argument_size_in_bytes < 7.72e9  # the weights whole (the tied table once) and the prompts
+    total = _device_bytes(compiled)
+    assert total < 14.9e9, f"{total / 1e9:.2f} GB"
+    text = compiled.as_text()
+    # the window layers alone run a flash forward over the prompt: the owning layer's attention is cut to its last position
+    assert set(re.findall(r"flash_diff_fwd_q\d+_kv\d+(?:_w\d+)?", text)) == {"flash_diff_fwd_q8192_kv8192_w512"}
+    assert set(re.findall(r"ssm_scan_l\d+_d\d+_n\d+", text)) == {"ssm_scan_l8192_d5120_n16"}
+    shared = r"bf16\[320,8448,128\]"
+    result = lambda ins: ins.line.split(" = ", 1)[1].split(f" {ins.opcode}(", 1)[0]  # noqa: E731
+    loop, body = _loop_around(text, lambda loop, inside: re.search(shared, result(loop)))  # the decode loop: the one that carries the cache
+    assert len(re.findall(shared, result(loop))) == 2  # keys and values, once: no copy a reading layer
+    assert len(re.findall(r"bf16\[320,512,128\]", result(loop))) == 2 * 8 and len(re.findall(r"f32\[32,16,5120\]", result(loop))) == 9
+    written = [ins for ins in body if re.search(shared, result(ins)) and ins.opcode not in ("parameter", "get-tuple-element", "bitcast", "fusion", "tuple")]
+    assert sorted(ins.opcode for ins in written) == ["dynamic-update-slice"] * 2, [ins.line[:200] for ins in written]
+    from perceiver_io_tpu.analysis.graph import parse_hlo_computations
+
+    everything = [ins for instructions in parse_hlo_computations(text).values() for ins in instructions]
+    chunk_loops = [ins for ins in everything if ins.opcode == "while" and "prefill/chunk_io" in ins.line]
+    assert len(chunk_loops) == 2 * 17 + 1
+    last = [ins for ins in everything if "prefill/last" in ins.line and ins.opcode not in ("parameter", "get-tuple-element", "tuple")]
+    assert last and max(_elements(_result(ins)) for ins in last) <= 320 * 4 * 8192  # a key pair's four queries against the prompt's keys
+
+
 # ------------------------------------------ states alone: five float32 retention states updated in place by the step's kernel
 
 

@@ -253,22 +253,25 @@ def render(run_dir: str, max_compile_rows: int = 20) -> str:
                 )
             # what the process did before its first step (obs/startup.py): imports by
             # package, trace / lower / compile by function, largest self time first
-            from perceiver_io_tpu.obs.startup import startup_table
+            from perceiver_io_tpu.obs.startup import IMPORT, startup_table
 
             table = startup_table(spans)
             if table:
                 lines.append("")
                 lines.append(f"== start-up ({sum(a['self_ms'] for a in table) / 1e3:.4g} s in {len(table)} rows) ==")
+                # every import row (twenty a process: the record keeps them whatever else it drops), then the largest of the rest
+                rest = [a for a in table if a["name"] != IMPORT]
+                shown = sorted([a for a in table if a["name"] == IMPORT] + rest[:max_compile_rows], key=lambda a: -a["self_ms"])
                 rows = [
                     [a["name"], str(a["what"]), a["cache"] or "", str(a["count"]), f"{a['total_ms']:.4g}",
                      f"{a['self_ms']:.4g}"]
-                    for a in table[:max_compile_rows]
+                    for a in shown
                 ]
                 lines.extend("  " + r for r in _table(rows, ["span", "package / fn", "cache", "count", "total ms",
                                                             "self ms"]))
-                if len(table) > max_compile_rows:
-                    rest = sum(a["self_ms"] for a in table[max_compile_rows:])
-                    lines.append(f"  ... {len(table) - max_compile_rows} more rows, {rest:.4g} ms of self time")
+                if len(rest) > max_compile_rows:
+                    left = sum(a["self_ms"] for a in rest[max_compile_rows:])
+                    lines.append(f"  ... {len(rest) - max_compile_rows} more rows, {left:.4g} ms of self time")
 
     # Probeline per-scope trends (probe events: one snapshot per log
     # boundary, scopes keyed "NNN:name" — sorted == topological order) and
