@@ -101,14 +101,22 @@ import jax.numpy as jnp
 from jax import lax
 
 from perceiver_io_tpu.obs import probes
-from perceiver_io_tpu.ops.grouped_matmul import grouped_matmul
+from perceiver_io_tpu.ops.grouped_matmul import block_plan, grouped_matmul, visit_plan
 from perceiver_io_tpu.ops.moe_combine import moe_combine
 
 
 # How the work is cut, not what is computed: a function of the expert layer's
 # geometry (held experts, hidden size, expert width), each value from
 # ``tools/moe_ab.py`` on the v5e at the published widths of the
-# geometries the program runs (PERF.md 6, PR 28, PR 32 and PR 34).
+# geometries the program runs (PERF.md 6, PR 28, PR 32 and PR 34). The times
+# in these notes were read with the grouped kernel's blocks of before PR 54
+# (the contraction and the column cut at 1024, an expert's weights fetched
+# again at every visit); with an expert's whole matrix a block the three
+# kernels of a pass take (``tools/moe_ab.py --only products/``, PERF.md 6, PR
+# 54) 5.63 ms where they took 7.27 at Mellum's pass of 65 536 rows, 0.86 for
+# 1.16 at Ling's pass of 4096 and 1.85 for 2.08 at its step's 384 rows, 4.00 for
+# 4.90 at DeepSeek-V3's 5120 and 4.87 for 6.31 at K-EXAONE's 10 240: every
+# tiling got faster by its kernels' share, and the cuts were not read again.
 class _Cuts(NamedTuple):
     grouped_min_tokens: int  # from this many tokens the grouped path is taken
     row_tile: int  # the grouped kernel's row tile
@@ -343,6 +351,33 @@ def experts_grouped(x, local, weights, w1, w3, w2, pass_rows: int, row_tile: int
     return y, n_local - jnp.minimum(n_pass * pass_rows, n_local), n_pass
 
 
+def grouped_fetches(sizes, pairs: int, hidden: int, width: int, itemsize: int, pass_rows: int, row_tile: int):
+    """What the grouped path's kernels fetch of the experts' weights where the
+    held experts got ``sizes`` (G,) of the call's ``pairs`` routed pairs (the
+    ``moe.load`` tap, never a cell's program): ``(visits, fetches, blocks)``,
+    int32 scalars summed over the passes. ``visits`` are a product's
+    grid visits (row tile x expert); ``fetches`` the weight blocks the three
+    products bring into VMEM, a block anew where its index differs from the
+    grid step's before: with the contraction whole, at a visit whose expert is
+    not the one before's, a column tile each; with it cut, every block at every
+    visit. ``blocks`` are the blocks of the experts a pass hits, the fetches
+    if each came once."""
+    offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
+    plans = [block_plan(pass_rows, *kn, row_tile, itemsize) for kn in ((hidden, width), (hidden, width), (width, hidden))]
+
+    def of_pass(p):
+        in_pass = jnp.clip(offsets, p * pass_rows, (p + 1) * pass_rows) - p * pass_rows
+        group_sizes = in_pass[1:] - in_pass[:-1]
+        _, group_ids, _, visits = visit_plan(group_sizes, pass_rows, row_tile)
+        live = jnp.arange(group_ids.shape[0]) < visits
+        turns = (live & (group_ids != jnp.concatenate([jnp.full((1,), -1, jnp.int32), group_ids[:-1]]))).sum()
+        fetches = sum((turns if plan["weights_resident"] else visits * plan["tiles_k"]) * plan["tiles_n"] for plan in plans)
+        blocks = (group_sizes > 0).sum() * sum(plan["tiles_k"] * plan["tiles_n"] for plan in plans)
+        return jnp.stack([visits, fetches, blocks]).astype(jnp.int32)
+
+    return tuple(jax.vmap(of_pass)(jnp.arange(-(-pairs // pass_rows), dtype=jnp.int32)).sum(axis=0))
+
+
 class SwiGLU(nn.Module):
     """``W_2 (silu(W_1 x) * W_3 x)``, no biases."""
 
@@ -436,13 +471,20 @@ class MoELayer(nn.Module):
         if probes.active():
             load = jnp.zeros((g + 1,), jnp.int32).at[local.reshape(-1)].add(1)[:g]
             pairs_local = load.sum()
+            zero = jnp.zeros((), jnp.int32)
+            visits, fetches, blocks = (
+                grouped_fetches(load, local.size, h, width, jnp.dtype(self.dtype).itemsize, rows, cuts.row_tile) if back else (zero,) * 3)
             taps = {
                 "pairs_routed": jnp.asarray(t * c.num_experts_per_tok, jnp.int32),
                 "pairs_local": pairs_local,
-                "pairs_gathered": pairs_local if back else jnp.zeros((), jnp.int32),
+                "pairs_gathered": pairs_local if back else zero,
                 "pairs_dropped": unserved.astype(jnp.int32),
                 "passes": passes.astype(jnp.int32),
                 "expert_load_max": load.max(),
+                # the grouped kernels' grid visits, the weight blocks they fetch, and the blocks of the experts hit
+                "expert_visits": visits,
+                "expert_weight_fetches": fetches,
+                "expert_weight_blocks": blocks,
             }
             if c.zero_expert_num:  # the pairs that need no expert's weights, and the most experts with weights a token runs
                 n_zero = is_zero.sum(axis=1).astype(jnp.int32)

@@ -1815,6 +1815,10 @@ def make_instrumented_generate_fn(
     m_moe_gathered = registry.counter("moe_pairs_gathered_total") if moe_taps else None
     m_moe_dropped = registry.counter("moe_pairs_dropped_total") if moe_taps else None
     m_moe_load = registry.gauge("moe_expert_load_max") if moe_taps else None
+    # the grouped kernels' visits and weight-block fetches beside the blocks of the experts hit (fetches / blocks is 1.0
+    # where an expert's weights stay in VMEM across its visits)
+    moe_fetch_keys = ("expert_visits", "expert_weight_fetches", "expert_weight_blocks")
+    m_moe_fetch = [registry.counter(f"moe_{k}_total") for k in moe_fetch_keys] if moe_taps else None
     # a state-space layer's recurrent state (``core/ssm.py`` taps ``ssm.state``): its largest element, its non-finite ones
     ssm_taps = probes and "ssm.*" in decoder.tap_scopes
     m_ssm_abs_max = registry.gauge("ssm_state_abs_max") if ssm_taps else None
@@ -1963,6 +1967,8 @@ def make_instrumented_generate_fn(
                     m_moe_gathered.inc(gathered)
                     m_moe_dropped.inc(dropped)
                     m_moe_load.set(max(int(h["expert_load_max"]) for h in hh))
+                    for counter, k in zip(m_moe_fetch, moe_fetch_keys):
+                        counter.inc(sum(int(h[k]) for h in hh))
                     health_row["moe_local_share"] = round(local / max(routed, 1), 6)
                     health_row["moe_pairs_dropped"] = dropped
                     if "pairs_zero" in hh[0]:  # experts without weights: the pairs they took, the most real experts a token ran

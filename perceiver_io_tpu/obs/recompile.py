@@ -24,7 +24,10 @@ trace time from the lengths alone, ``ops.flash_attention.tile_plan``), and
 about the position-table gradient of the compact prefix-dropout embedding
 (``embed_tiles``: one row per distinct call with its tiles, grid steps and
 one-hot FLOPs, and whether it took the kernel or XLA's scatter-add;
-``ops.gathers.embed_tile_plan``, from the shapes alone), and about the MLPs'
+``ops.gathers.embed_tile_plan``, from the shapes alone), about the experts'
+grouped products (``moe_tiles``: one row per distinct product with its blocks,
+the VMEM they take and whether an expert's weight block stays resident across
+its visits; ``ops.grouped_matmul.block_plan``, from the shapes alone), and about the MLPs'
 exact GELU under differentiation (``mlp_gelu``: one row per distinct hidden
 shape with its sites, what each keeps for the backward and its ``erfc``
 evaluations; ``core.modules.mlp_gelu_plans``, counted over this call's trace
@@ -116,8 +119,10 @@ class RecompileTracker:
                 if self.events is not None:
                     from perceiver_io_tpu.ops.flash_attention import tile_plans
                     from perceiver_io_tpu.ops.gathers import embed_tile_plans
+                    from perceiver_io_tpu.ops.grouped_matmul import moe_tile_plans
 
                     flash_tiles, embed_tiles, mlp_gelu = tile_plans(), embed_tile_plans(), mlp_gelu_plans(since=gelu_sites)
+                    moe_tiles = moe_tile_plans()
                     self.events.emit(
                         "compile",
                         fn=name,
@@ -128,6 +133,7 @@ class RecompileTracker:
                         arg_shapes=shape_signature(args, kwargs),
                         **({"flash_tiles": flash_tiles} if flash_tiles else {}),
                         **({"embed_tiles": embed_tiles} if embed_tiles else {}),
+                        **({"moe_tiles": moe_tiles} if moe_tiles else {}),
                         **({"mlp_gelu": mlp_gelu} if mlp_gelu else {}),
                         **(extra(args, kwargs) if extra is not None else {}),
                     )

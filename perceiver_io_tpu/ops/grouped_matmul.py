@@ -14,7 +14,10 @@ overlap. A row tile that straddles a group boundary is visited once per group,
 each visit storing only its own rows; consecutive visits of one row tile keep
 the output block resident, so nothing is written twice to HBM. The number of
 live visits is a traced grid bound: a tile no row falls in costs nothing, and
-only the weights of groups that have rows are read.
+only the weights of groups that have rows are read. The contraction is held
+whole wherever its blocks fit VMEM (:func:`block_plan`: every geometry a cell
+runs), so a group's weight block has the same index on consecutive visits of
+the group and is fetched once a group, not once a visit.
 
 Kernel names, as a device trace shows them: ``moe_experts_prefill_m<M>_k<K>_n<N>``
 (``benchmarks/layers/moe_experts_roofline.decode.py`` reads them): the pass
@@ -25,7 +28,7 @@ more, which no cell runs; a second pass name waits for a path that needs one).
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,8 +36,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _VMEM_LIMIT = 100 * 1024 * 1024
-# the contraction and column tiles asked for (the largest multiple of 128 at most this that divides the axis)
-_TILE_K = _TILE_N = 1024
+# the column tile where the contraction has to be cut (at most this: the blocks then stay near square)
+_TILE_N = 1024
 
 
 def _interpret_default() -> bool:
@@ -72,32 +75,70 @@ def visit_plan(group_sizes: jnp.ndarray, m: int, tm: int) -> Tuple[jnp.ndarray, 
     return offsets, group_ids, m_tile_ids, group_tiles.sum()
 
 
-def _gmm_kernel(offsets_ref, group_ids_ref, m_tile_ids_ref, lhs_ref, rhs_ref, out_ref, acc_ref, *, tm, tn, tiles_k):
+def _gmm_kernel(offsets_ref, group_ids_ref, m_tile_ids_ref, lhs_ref, rhs_ref, out_ref, *acc, tm, tn, tiles_k):
     v, k = pl.program_id(1), pl.program_id(2)
+    lhs, rhs = lhs_ref[...], rhs_ref[0]
+    precision = jax.lax.Precision.HIGHEST if lhs.dtype == jnp.float32 else None
+    product = jnp.dot(lhs, rhs, preferred_element_type=jnp.float32, precision=precision)
+
+    def store(total):
+        group = group_ids_ref[v]
+        rows = m_tile_ids_ref[v] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 0)
+        mine = (rows >= offsets_ref[group]) & (rows < offsets_ref[group + 1])
+        out_ref[...] = jnp.where(mine, total, out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+
+    if tiles_k == 1:  # the contraction whole: no partial sums to carry
+        return store(product)
+    (acc_ref,) = acc
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    lhs, rhs = lhs_ref[...], rhs_ref[0]
-    precision = jax.lax.Precision.HIGHEST if lhs.dtype == jnp.float32 else None
-    acc_ref[...] += jnp.dot(lhs, rhs, preferred_element_type=jnp.float32, precision=precision)
-
-    @pl.when(k == tiles_k - 1)
-    def _store():
-        group = group_ids_ref[v]
-        rows = m_tile_ids_ref[v] * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, tn), 0)
-        mine = (rows >= offsets_ref[group]) & (rows < offsets_ref[group + 1])
-        out_ref[...] = jnp.where(mine, acc_ref[...], out_ref[...].astype(jnp.float32)).astype(out_ref.dtype)
+    acc_ref[...] += product
+    pl.when(k == tiles_k - 1)(lambda: store(acc_ref[...]))
 
 
-def _tile(n: int, want: int) -> int:
-    """The largest multiple of 128 that divides ``n`` and is at most ``want``; ``n`` itself if none."""
-    best = 0
-    for t in range(128, min(n, want) + 1, 128):
-        if n % t == 0:
-            best = t
-    return best or n
+def _divisors(n: int) -> List[int]:
+    """``n`` and the multiples of 128 under it that divide it, largest first."""
+    return [n] + [t for t in range((n - 1) // 128 * 128, 0, -128) if n % t == 0]
+
+
+def _vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """What a grid step holds: the ``lhs``, ``rhs`` and ``out`` blocks twice each (the pipeline's two buffers) and the float32 product."""
+    return 2 * (tm * tk + tk * tn + tm * tn) * itemsize + tm * tn * 4
+
+
+def _blocks(k: int, n: int, tm: int, itemsize: int) -> Tuple[int, int]:
+    """``(tk, tn)``: the contraction whole with the widest column that fits the
+    kernel's VMEM (every geometry a cell runs: an expert's weights are fetched
+    once, and with the column whole each ``lhs`` tile is too); where no column
+    holds the contraction whole, the largest cut of ``k`` that fits beside a
+    column of at most ``_TILE_N``."""
+    fits = lambda tk, tn: _vmem_bytes(tm, tk, tn, itemsize) <= _VMEM_LIMIT  # noqa: E731
+    for tn in _divisors(n):
+        if fits(k, tn):
+            return k, tn
+    tn = next((t for t in _divisors(n) if t <= _TILE_N), n)
+    return next((tk for tk in _divisors(k)[1:] if fits(tk, tn)), _divisors(k)[-1]), tn
+
+
+def block_plan(m: int, k: int, n: int, tm: int, itemsize: int) -> dict:
+    """How a product of these shapes is cut, from the shapes alone (a ``moe_tiles`` row of the ``compile`` event)."""
+    tk, tn = _blocks(k, n, tm, itemsize)
+    return {
+        "m": m, "k": k, "n": n, "tm": tm, "tk": tk, "tn": tn, "tiles_k": k // tk, "tiles_n": n // tn,
+        "rhs_block_bytes": tk * tn * itemsize, "vmem_bytes": _vmem_bytes(tm, tk, tn, itemsize),
+        "weights_resident": tk == k,  # a group's block keeps its index from one visit of the group to the next
+    }
+
+
+_PLANS: dict = {}  # the products traced in this process, read by obs.recompile for the ``compile`` event row
+
+
+def moe_tile_plans() -> List[dict]:
+    """One row per distinct grouped product traced so far."""
+    return [plan for _, plan in sorted(_PLANS.items())]
 
 
 @functools.partial(jax.jit, static_argnames=("tm",))
@@ -108,8 +149,8 @@ def grouped_matmul(lhs, rhs, group_sizes, *, tm: int):
     g, _, n = rhs.shape
     if m % tm:
         raise ValueError(f"grouped_matmul: {m} rows are not a multiple of the row tile {tm}")
-    tk, tn = _tile(k, _TILE_K), _tile(n, _TILE_N)
-    tiles_k, tiles_n = k // tk, n // tn
+    plan = _PLANS[(m, k, n, tm, lhs.dtype.itemsize)] = block_plan(m, k, n, tm, lhs.dtype.itemsize)
+    tk, tn, tiles_k, tiles_n = plan["tk"], plan["tn"], plan["tiles_k"], plan["tiles_n"]
     offsets, group_ids, m_tile_ids, num_visits = visit_plan(group_sizes, m, tm)
     return pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm, tn=tn, tiles_k=tiles_k),
@@ -122,7 +163,7 @@ def grouped_matmul(lhs, rhs, group_sizes, *, tm: int):
                 pl.BlockSpec((1, tk, tn), lambda j, v, kk, off, gid, mid: (gid[v], kk, j)),
             ],
             out_specs=pl.BlockSpec((tm, tn), lambda j, v, kk, off, gid, mid: (mid[v], j)),
-            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)] * (tiles_k > 1),
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -40,6 +40,7 @@ from perceiver_io_tpu.generation import GenerationConfig, make_decode_fns, make_
 from perceiver_io_tpu.models.text import decoder_lm
 from perceiver_io_tpu.models.text.decoder_lm import DecoderLanguageModel, DecoderLanguageModelConfig, YarnConfig
 from perceiver_io_tpu.obs import probes
+from perceiver_io_tpu.ops import grouped_matmul as gm
 from perceiver_io_tpu.ops.grouped_matmul import grouped_matmul, visit_plan
 from perceiver_io_tpu.ops.layernorm import rms_norm
 
@@ -446,8 +447,13 @@ GROUP_SIZES = [[3, 0, 9, 1, 0, 7, 0, 0], [8, 8, 8, 8, 0, 0, 0, 0], [0, 0, 0, 0, 
                [0, 0, 0, 0, 0, 0, 0, 0], [5, 11, 0, 0, 2, 0, 14, 0]]
 
 
+# (K, N, dtype): one lane tile, and contractions of several 128-lane tiles that the kernel holds whole
+PRODUCT_SHAPES = [(16, 128, "float32"), (384, 256, "float32"), (640, 256, "float32"), (384, 256, "bfloat16"), (640, 256, "bfloat16")]
+
+
+@pytest.mark.parametrize("k_n_dtype", PRODUCT_SHAPES, ids=lambda s: f"k{s[0]}_n{s[1]}_{s[2]}")
 @pytest.mark.parametrize("sizes", GROUP_SIZES, ids=lambda s: "-".join(map(str, s)))
-def test_grouped_matmul_and_its_visit_plan(sizes):
+def test_grouped_matmul_and_its_visit_plan(sizes, k_n_dtype):
     m, tm = 32, 8
     offsets, group_ids, m_tile_ids, visits = (np.asarray(a) for a in visit_plan(jnp.asarray(sizes, jnp.int32), m, tm))
     ends = np.cumsum(sizes)
@@ -457,12 +463,72 @@ def test_grouped_matmul_and_its_visit_plan(sizes):
     assert int(visits) == len(want)
     assert list(zip(m_tile_ids[:visits], group_ids[:visits])) == want
 
+    width, n, dtype = k_n_dtype
     k = jax.random.split(jax.random.PRNGKey(sum(sizes)), 2)
-    lhs, rhs = jax.random.normal(k[0], (m, 16)), jax.random.normal(k[1], (len(sizes), 16, 128))
-    out = np.asarray(grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32), tm=tm))
+    lhs, rhs = jax.random.normal(k[0], (m, width)).astype(dtype), jax.random.normal(k[1], (len(sizes), width, n)).astype(dtype)
+    assert gm.block_plan(m, width, n, tm, lhs.dtype.itemsize)["tiles_k"] == 1  # the whole contraction is what runs
+    out = grouped_matmul(lhs, rhs, jnp.asarray(sizes, jnp.int32), tm=tm)
+    assert out.dtype == lhs.dtype
+    lhs, rhs, out = (np.asarray(a, np.float32) for a in (lhs, rhs, out))
     for g in range(len(sizes)):
         rows = slice(offsets[g], offsets[g + 1])
-        np.testing.assert_allclose(out[rows], np.asarray(lhs)[rows] @ np.asarray(rhs)[g], atol=1e-4, rtol=0)
+        # float32: summation order alone; bfloat16: one rounding of a float32 sum to the output's 8 bits
+        tol = dict(atol=1e-4, rtol=0) if dtype == "float32" else dict(atol=2 ** -8 * 4 * width ** 0.5, rtol=2 ** -8)
+        np.testing.assert_allclose(out[rows], lhs[rows] @ rhs[g], **tol)
+
+
+# the grouped products the five cells with expert layers run: (hidden, width, row tile) in bfloat16
+CELL_EXPERTS = {"dsv3": (7168, 2048, 256), "kexaone": (6144, 2048, 256), "longcat": (6144, 2048, 256), "mellum": (2304, 896, 256),
+                "ling": (2560, 768, 128)}
+
+
+@pytest.mark.parametrize("direction", ["up", "down"])
+@pytest.mark.parametrize("cell", sorted(CELL_EXPERTS))
+def test_the_block_rule_holds_the_contraction_whole_at_every_cells_products(cell, direction):
+    """An expert's weight block keeps its index across the expert's visits (``tiles_k`` 1), inside the kernel's VMEM."""
+    hidden, width, tile = CELL_EXPERTS[cell]
+    k, n = (hidden, width) if direction == "up" else (width, hidden)
+    assert moe._cuts(hidden, width, {"mellum": 64, "ling": 128}.get(cell, 16)).row_tile == tile
+    plan = gm.block_plan(4096, k, n, tile, 2)
+    assert (plan["tk"], plan["tiles_k"], plan["weights_resident"]) == (k, 1, True)
+    # the column whole too at these sizes: an expert's whole matrix a block, each ``lhs`` tile read once
+    assert (plan["tn"], plan["tiles_n"], plan["rhs_block_bytes"]) == (n, 1, k * n * 2)
+    assert plan["vmem_bytes"] == 2 * 2 * (tile * k + k * n + tile * n) + 4 * tile * n <= gm._VMEM_LIMIT
+
+
+def test_a_contraction_too_large_for_vmem_is_cut_by_the_same_rule(monkeypatch):
+    """A made-up K whose whole block does not fit falls back to the largest
+    divisor in whole lane tiles that does, and the cut kernel (partial sums in
+    a float32 scratch) gives the whole one's result."""
+    plan = gm.block_plan(4096, 262144, 2048, 256, 2)
+    assert not plan["weights_resident"] and plan["tk"] % 128 == 0 and plan["tk"] * plan["tiles_k"] == 262144
+    assert plan["tn"] == 1024 and plan["vmem_bytes"] <= gm._VMEM_LIMIT < gm._vmem_bytes(256, 2 * plan["tk"], plan["tn"], 2)
+    # a contraction that fits beside a narrower column keeps the weights resident and narrows the column
+    plan = gm.block_plan(4096, 20480, 4096, 256, 2)
+    assert plan["weights_resident"] and plan["tn"] == 512 and gm._vmem_bytes(256, 20480, 1024, 2) > gm._VMEM_LIMIT >= plan["vmem_bytes"]
+    sizes = jnp.asarray(GROUP_SIZES[0], jnp.int32)
+    k = jax.random.split(jax.random.PRNGKey(0), 2)
+    lhs, rhs = jax.random.normal(k[0], (32, 640)), jax.random.normal(k[1], (8, 640, 256))
+    whole = gm.grouped_matmul.__wrapped__(lhs, rhs, sizes, tm=8)
+    monkeypatch.setattr(gm, "_VMEM_LIMIT", gm._vmem_bytes(8, 128, 256, 4))  # room for one lane tile of K
+    assert gm.block_plan(32, 640, 256, 8, 4)["tiles_k"] == 5
+    cut = gm.grouped_matmul.__wrapped__(lhs, rhs, sizes, tm=8)
+    live = int(sizes.sum())
+    np.testing.assert_allclose(np.asarray(cut)[:live], np.asarray(whole)[:live], atol=1e-4, rtol=0)
+
+
+def test_the_tap_counts_a_weight_block_once_an_expert_where_the_contraction_is_whole(monkeypatch):
+    """``moe.grouped_fetches`` on a made-up routing: 3 held experts of 256 x
+    128 in passes of 64 rows at a row tile of 16. With the contraction whole a
+    block is fetched when the visit's expert changes; cut in two (the parent's
+    kind of cut) every visit fetches every block."""
+    sizes = jnp.asarray([40, 40, 8], jnp.int32)  # the held experts' shares of 96 routed pairs; 8 went to an expert held elsewhere
+    count = lambda: tuple(int(c) for c in moe.grouped_fetches(sizes, 96, 256, 128, 4, 64, 16))  # noqa: E731
+    # pass 0: expert 0 on tiles 0 to 2, expert 1 on tiles 2 and 3; pass 1: expert 1 on tile 0, expert 2 on tile 1.
+    # Seven visits of four (pass, expert) pairs; a column tile a product
+    assert count() == (7, 4 * 3, 4 * 3)
+    monkeypatch.setattr(gm, "_blocks", lambda k, n, *_: (128, n))  # the up-projections' 256 in two; the down-projection's 128 stays whole
+    assert count() == (7, 7 * (2 + 2) + 4, 4 * (2 + 2 + 1))
 
 
 # --------------------------------------------------- spans, taps, counters
@@ -552,14 +618,17 @@ def test_the_perceiver_ar_generators_lowered_program_is_the_parents(program):
 # configuration, caches with a length a row and a speculative generator
 # beside these): the one-token generator, prompt pass and step that the
 # latent-attention and the window/full grouped-query configurations trace are
-# the parent's to the character. A PR that means to change them updates these.
+# the parent's to the character. A PR that means to change them updates these:
+# the probed ``_prefill`` and ``_step`` are PR 54's (the ``moe.load`` tap gained
+# ``expert_visits``, ``expert_weight_fetches`` and ``expert_weight_blocks``); the
+# ``_generate`` programs, which hold no tap, stand.
 DECODER_GOLDEN = {
     "dsv3_generate": "cc2078bfe6bb6924fca1cbf90373efac1e20b8d647febe0c165013bcb1139976",
-    "dsv3_prefill": "fba0c6827d7026a9a0db2f512985207aa72cb3abb63a34efcf7b030b8c094e9b",
-    "dsv3_step": "4261771d334aa9c299470092e1e0f08a6dc844f910de7c1b16533133701c6feb",
+    "dsv3_prefill": "0b9e0c5172dd67cc6034a0b11799fec1c245cffa4162d9da97f78384034dbeca",
+    "dsv3_step": "1147aeb68ce2bc7a4b1aafe8448668fd580f0c63677df6a119e8acb1668822fb",
     "mellum_generate": "67b1747f7062e960de892be626600397e8c99b4ccda74c499b1c2b96bbe91302",
-    "mellum_prefill": "3bbf084c3ae43a405f9111f379c590a20da7deee040ec4c9ee9e6b7dcd2d753b",
-    "mellum_step": "97e7bf94b89628ce650a95460eb7bd0c59e6c3618a1af7dd8585cd9b01bf0c21",
+    "mellum_prefill": "221f9e3af8503510a35594f24ceed59e4925aa85a75b04d7df6212fd627628d5",
+    "mellum_step": "775ed358b58eccc388974a4c87d7d8aeb1f8eee49a63ce2d40985fdfb24aeb34",
 }
 
 

@@ -422,7 +422,8 @@ def test_a_serial_configurations_taps_and_compile_row_keep_their_keys():
     with probes.collecting(probes.ProbeConfig(scopes=("moe.*",))) as col:
         layer.apply(params, x)
     (load,) = col.stats.values()
-    assert sorted(load) == ["expert_load_max", "pairs_dropped", "pairs_gathered", "pairs_local", "pairs_routed", "passes"]
+    assert sorted(load) == ["expert_load_max", "expert_visits", "expert_weight_blocks", "expert_weight_fetches",
+                            "pairs_dropped", "pairs_gathered", "pairs_local", "pairs_routed", "passes"]
     row = generation._decoder_of(DecoderLanguageModel(config)).compile_row(4, 8, 3, jnp.float32)
     assert sorted(row) == ["latent_cache_bytes", "latent_cache_capacity", "latent_cache_layers", "latent_cache_row_bytes", "moe_combine"]
     assert row["latent_cache_layers"] == config.num_hidden_layers

@@ -207,6 +207,36 @@ def test_the_gather_combine_is_counted_where_it_ran(tmp_path):
     assert [r["moe_combine"] for r in rows if r.get("event") == "compile" and "moe_combine" in r][:1] == ["gather"]
 
 
+def test_the_grouped_kernels_fetch_an_experts_weights_once_and_the_compile_row_says_so(tmp_path):
+    """The same 416 prompt tokens through the instrumented generator: every
+    grouped product of its ``compile`` rows (``moe_tiles``) holds the
+    contraction whole, and the ``moe.load`` tap counts one fetch of a weight
+    block for each expert a pass hit and each column tile of the three
+    products (one each at these widths), however many row tiles the expert's
+    rows straddle; the steps' dense path visits and fetches nothing."""
+    import json
+
+    from perceiver_io_tpu.generation import make_instrumented_generate_fn
+    from perceiver_io_tpu.obs.events import EventLog
+    from perceiver_io_tpu.ops.grouped_matmul import block_plan
+
+    model, params, ids = seeded(tiny_config(), 4, batch=32, n=13)
+    probed = make_instrumented_generate_fn(model, config=GenerationConfig(max_new_tokens=3), events=EventLog(str(tmp_path)), probes=True)
+    _, stats = probed(params, ids)
+    assert stats.outcome == "ok"
+    rows = [json.loads(line) for line in open(tmp_path / "events.jsonl")]
+    tiles = [r["moe_tiles"] for r in rows if r.get("event") == "compile" and "moe_tiles" in r][0]
+    pass_rows = moe._pass_rows(2 * 416, 1.0, moe._cuts(64, 32, 8))
+    ours = [t for t in tiles if t["m"] == pass_rows and {t["k"], t["n"]} == {64, 32}]
+    assert sorted((t["k"], t["n"]) for t in ours) == [(32, 64), (64, 32)] and all(t["weights_resident"] for t in tiles)
+    assert ours[0] == block_plan(pass_rows, ours[0]["k"], ours[0]["n"], ours[0]["tm"], 4) and ours[0]["tiles_k"] == ours[0]["tiles_n"] == 1
+    counters = probed.registry.snapshot()["counters"]
+    # 4 expert layers of 8 experts, 104 pairs an expert in the mean: every expert is hit, in the one pass a layer takes
+    hit, column_tiles = 4 * 8, 3
+    assert counters["moe_expert_weight_fetches_total"] == counters["moe_expert_weight_blocks_total"] == hit * column_tiles
+    assert counters["moe_expert_visits_total"] >= hit
+
+
 def test_the_benchmarks_two_configurations_sit_on_either_side_of_the_rule():
     """``mellum2-12b-pp4`` holds its 64 experts and gathers; ``deepseek-v3-ep16`` holds 16 of 256 and sums its rows by token (``ops/moe_combine.py``)."""
     from benchmarks import run
@@ -304,6 +334,11 @@ def test_both_expert_paths_serve_every_pair_when_all_experts_are_held(case, monk
     assert int(load["pairs_local"]) == int(load["pairs_routed"]) == 2 * tokens and int(load["pairs_dropped"]) == 0
     assert int(load["pairs_gathered"]) == (2 * tokens if grouped else 0)
     assert int(load["expert_load_max"]) == tokens or not skewed
+    # a weight block a (pass, expert hit) and product, whatever the visits (the contraction is whole); none on the dense path
+    assert int(load["expert_weight_fetches"]) == int(load["expert_weight_blocks"]) <= 3 * int(load["expert_visits"])
+    assert (int(load["expert_visits"]) > 0) == grouped
+    if skewed:  # experts 0 and 1 take 768 rows each: three row tiles of 256 each in one pass, four (pass, expert) pairs in passes of 512
+        assert (int(load["expert_visits"]), int(load["expert_weight_blocks"])) == ((6, 3 * 4) if pass_rows else (6, 3 * 2))
     if grouped:
         assert int(load["passes"]) == -(-2 * tokens // (pass_rows or 2048))
         # no row is added into the tokens' buffer (the integer scatter-adds left count group sizes)

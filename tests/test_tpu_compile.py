@@ -333,24 +333,44 @@ def test_token_major_latent_flash_forward_lowers(one_chip, mosaic, heads):
     assert (plan["block_q"], plan["band_rows"], plan["tiles_run"], plan["tiles_skipped"]) == (1024, 256, 40, 24)
 
 
-@pytest.mark.parametrize("rows", [5120, 256], ids=["prompt_chunk", "smallest_pass"])
-@pytest.mark.parametrize("k,n", [(DSV3_HIDDEN, DSV3_EXPERT_WIDTH), (DSV3_EXPERT_WIDTH, DSV3_HIDDEN)], ids=["up", "down"])
-def test_grouped_expert_product_lowers(one_chip, monkeypatch, rows, k, n):
+# the rows of a pass the cells hand the grouped product, with the geometry (experts held, hidden, width) and its row tile
+GROUPED_PRODUCTS = {
+    "prompt_chunk": (5120, DSV3_HELD, DSV3_HIDDEN, DSV3_EXPERT_WIDTH, 256),
+    "smallest_pass": (256, DSV3_HELD, DSV3_HIDDEN, DSV3_EXPERT_WIDTH, 256),
+    "mellum_chunk": (65536, 64, 2304, 896, 256),
+    "ling_pass": (4096, 128, 2560, 768, 128),
+    "ling_step": (384, 128, 2560, 768, 128),
+    "kexaone_chunk": (10240, 16, 6144, 2048, 256),
+}
+
+
+@pytest.mark.parametrize("pass_", list(GROUPED_PRODUCTS))
+@pytest.mark.parametrize("direction", ["up", "down"])
+def test_grouped_expert_product_lowers(one_chip, monkeypatch, pass_, direction):
     """The held experts' grouped product (a traced number of visits in the
-    grid, the group of a visit read from prefetched scalars) at the rows of a
-    pass the expert layer takes for a prompt chunk of 8192 tokens and for the
-    384 tokens from which it takes this path at all."""
+    grid, the group of a visit read from prefetched scalars, the contraction
+    whole so that an expert's weight block stays in VMEM across its visits) at
+    the rows of a pass the expert layer takes for a prompt chunk of 8192
+    tokens and for the 384 tokens from which it takes this path at all, and
+    at Mellum's, Ling's (its decode step's 384 rows too) and K-EXAONE's
+    products: every block a cell runs, compiled before a chip is asked."""
     gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
     moe = importlib.import_module("perceiver_io_tpu.core.moe")
     monkeypatch.setattr(gm, "_interpret_default", lambda: False)
-    cuts = moe._cuts(DSV3_HIDDEN, DSV3_EXPERT_WIDTH, DSV3_HELD)
-    assert cuts == (384, 256, 65536)  # the crossing and the tile PR 28 measured at this geometry stay; a pass has no cap since PR 50
-    assert moe._pass_rows(8192 * 8, 16 / 256, cuts) == 5120 and moe._pass_rows(cuts.grouped_min_tokens * 8, 16 / 256, cuts) == 256
+    rows, held, hidden, width, tile = GROUPED_PRODUCTS[pass_]
+    cuts = moe._cuts(hidden, width, held)
+    assert cuts.row_tile == tile
+    if hidden == DSV3_HIDDEN:
+        assert cuts == (384, 256, 65536)  # the crossing and the tile PR 28 measured at this geometry stay; a pass has no cap since PR 50
+        assert moe._pass_rows(8192 * 8, 16 / 256, cuts) == 5120 and moe._pass_rows(cuts.grouped_min_tokens * 8, 16 / 256, cuts) == 256
+    k, n = (hidden, width) if direction == "up" else (width, hidden)
     lhs = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
-    rhs = jax.ShapeDtypeStruct((DSV3_HELD, k, n), jnp.bfloat16, sharding=one_chip)
-    sizes = jax.ShapeDtypeStruct((DSV3_HELD,), jnp.int32, sharding=one_chip)
-    text = _compile(lambda a, w, s: gm.grouped_matmul(a, w, s, tm=cuts.row_tile), lhs, rhs, sizes)
+    rhs = jax.ShapeDtypeStruct((held, k, n), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
+    text = _compile(lambda a, w, s: gm.grouped_matmul(a, w, s, tm=tile), lhs, rhs, sizes)
     assert f"moe_experts_prefill_m{rows}_k{k}_n{n}" in text and "tpu_custom_call" in text
+    plan = next(p for p in gm.moe_tile_plans() if (p["m"], p["k"], p["n"], p["tm"]) == (rows, k, n, tile))
+    assert plan["weights_resident"] and plan["tiles_k"] == 1 and plan["vmem_bytes"] <= gm._VMEM_LIMIT
 
 
 # tokens of a prompt chunk, rows of its pass (``moe._pass_rows``), hidden size, row tile: the share-held cells' expert layers
@@ -599,15 +619,16 @@ def test_the_speculative_step_is_one_kernel_a_layer_over_row_major_caches(one_ch
 # lower to the parent's programs (``tools/step_hlo.py --same`` says the same of
 # the compiled modules). A PR that means to change one of these programs
 # updates its hash: Jamba's is PR 47's own (the scan kernel reads and writes
-# the rows as the projections leave them); DeepSeek-V3's and LongCat's are PR
-# 50's own (a share-held expert layer's rows return to their tokens through
-# ``ops/moe_combine.py``, one pass a chunk: K-EXAONE's and Ling's generators,
-# which change with them, are not pinned); Mellum's stands since PR 44.
+# the rows as the projections leave them) and **stands through PR 54, whose
+# change it bypasses** (no expert layer); Mellum's, DeepSeek-V3's and LongCat's
+# are PR 54's own (``ops/grouped_matmul.py`` holds the contraction and the
+# column whole: the kernels' blocks and bodies differ, the rest of each program is PR 50's;
+# K-EXAONE's and Ling's generators, which change with them, are not pinned).
 PARENT_GENERATORS = {
-    "mellum2-pp4-decode-b32": ("mellum", "08eadbcfdc5bc1c8749a61bdbc7da024abab0e97773459e0829fdfd8910eb692"),
+    "mellum2-pp4-decode-b32": ("mellum", "7b50b744f8b6428b96d5040be7c6bc6ed85225444bd5d2fe72c6e486f5adc608"),
     "jamba2-3b-decode-b256": ("jamba", "37c48df32f761ff881f8e7a99ecd629d39bf5825793aa83afac1ef2a1f1020cd"),
-    "dsv3-ep16-decode-b64": ("deepseek_v3", "258bb95e3be637e0b1cf56d071f5b75d0c27a9d92b3e6396aec1710824cc5618"),
-    "longcat-ep32-decode-b64": ("longcat_flash", "322bf32d3f3a753b67674ab83f0416a7b113f2d9f67407c6dc65b2b826dd4e22"),
+    "dsv3-ep16-decode-b64": ("deepseek_v3", "5d4bdcf814047da9f4c759635ffba248ce53686661cdc9616a512aeebf11f32c"),
+    "longcat-ep32-decode-b64": ("longcat_flash", "9133383956b4104dae1791e8f992df5e644522b081cb6d109211e60ca3ba881b"),
 }
 
 

@@ -48,6 +48,20 @@ two local pairs a token; the expert layer alone).
   ``ling``'s step of 128 tokens) ``..._rows1024/scatter`` is the parent's
   layer of before PR 50, XLA's scatter-add in passes of 1024 rows, kept in
   this file;
+- the three grouped products of a pass as a cell runs them (``--only
+  products/``, PR 54; every geometry): the rows of the cell's pass and its
+  row tile, the group sizes a uniform routing of a prompt chunk's 8192 tokens
+  leaves in the pass (``ling``: also a decode step's 384 rows), with the
+  blocks as the program's rule cuts them (``program``: the contraction and
+  the column whole, an expert's weight block stays in VMEM across its
+  visits), with the cut of before PR 54 (``parent``: both in multiples of
+  128 up to 1024, so the weight block's index changes at every grid step and
+  every visit fetches the expert's weights again) and with the contraction
+  whole beside that column (``column_1024``), each by patching the rule
+  when the call is traced; prints ms a kernel by kernel name, the bytes
+  each form fetches (from its visit plan and blocks) and, before any timing,
+  the up and the down product of each form against ``jax.lax.ragged_dot``
+  (``lhs[rows of g] @ rhs[g]`` in float32) on the chip;
 - the share-held combine alone (``--only combine/``, PR 50; ``dsv3``,
   ``kexaone``, ``ling``): at a prompt chunk's 8192 tokens and the program's
   pass, the parent's scatter-add (in passes of 1024 rows, whole, and over
@@ -253,17 +267,18 @@ def variants():
                 # where a share is held, the parent's scatter-add beside the program's combine, at the parent's passes of 1024
                 if own != "gather" and t in SHORT_PASSES and not pass_rows:
                     out[f"experts_layer/T{t}/grouped_tm{tile}_rows1024/scatter"] = (grouped_scatter(tile, pass_rows), layer, "layer")
+    out.update(product_variants())
     if own != "gather":
         out.update(combine_variants())
     if GEOM == "ling":  # the expert layer alone: its attentions are the latent cells' and ``tools/kda_ab.py``'s
-        return {k: v for k, v in out.items() if "experts_layer" in k or "combine/" in k}
+        return {k: v for k, v in out.items() if "experts_layer" in k or "combine/" in k or "products/" in k}
     if GEOM == "kexaone":
-        return {**{k: v for k, v in out.items() if "experts_layer" in k or "combine/" in k}, **kexaone_attention_variants()}
+        return {**{k: v for k, v in out.items() if "experts_layer" in k or "combine/" in k or "products/" in k}, **kexaone_attention_variants()}
     if GEOM == "mellum":
         from perceiver_io_tpu.core.cache import KVCache
         from perceiver_io_tpu.core.gqa import cached_decode_attention
 
-        out = {k: v for k, v in out.items() if "experts_layer" in k}
+        out = {k: v for k, v in out.items() if "experts_layer" in k or "products/" in k}
         # the prompt pass's flash forward on one 8192-token row (an attention chunk of the cell): the edge
         # tiles cut into bands of 256 rows (what ``_BAND_MAX_SHARE`` 0.75 chooses) against run whole, blocks of 1024 and 512
         import importlib
@@ -322,6 +337,114 @@ def variants():
 
 
 COMBINE_TOKENS = 8192  # a prompt chunk
+
+
+def _rule(name: str):
+    """A context in which ``ops/grouped_matmul.py`` cuts its blocks by another rule (read when a call is traced):
+    ``program`` is the module's own, ``parent`` the contraction and the column in multiples of 128 up to 1024 as
+    until PR 54, ``column_1024`` the contraction whole beside the parent's column."""
+    import contextlib
+    import importlib
+
+    gm = importlib.import_module("perceiver_io_tpu.ops.grouped_matmul")
+    upto = lambda n: next((t for t in gm._divisors(n) if t <= 1024), n)  # noqa: E731
+    rules = {"program": gm._blocks, "parent": lambda k, n, *_: (upto(k), upto(n)), "column_1024": lambda k, n, *_: (k, upto(n))}
+
+    @contextlib.contextmanager
+    def patched():
+        kept, gm._blocks = gm._blocks, rules[name]
+        try:
+            yield gm
+        finally:
+            gm._blocks = kept
+    return patched()
+
+
+def pass_rows_and_tile(step: bool = False):
+    """The rows and the row tile of the pass the geometry's cell hands the grouped kernels for a prompt chunk of 8192 tokens (or Ling's decode step of 128)."""
+    from perceiver_io_tpu.core import moe
+
+    cuts = moe._cuts(H, WIDTH, EXPERTS)
+    return moe._pass_rows((128 if step else COMBINE_TOKENS) * TOP_K, EXPERTS / ROUTED, cuts), cuts.row_tile
+
+
+def pass_sizes(step: bool = False):
+    """The group sizes of the first pass: the held experts' pairs of a uniform routing, as many as the pass's rows hold."""
+    import numpy as np
+
+    rows, _ = pass_rows_and_tile(step)
+    local = routing(128 if step else COMBINE_TOKENS).reshape(-1)
+    ends = np.minimum(np.cumsum(np.bincount(local, minlength=EXPERTS + 1)[:EXPERTS]), rows)
+    return np.diff(np.concatenate([[0], ends])).astype(np.int32)
+
+
+def fetched_bytes(sizes, rows: int, tile: int, rule: str) -> dict:
+    """What the three products of a pass fetch and write under a rule, from the visit plan and the blocks: a block is
+    fetched again when its index differs from the grid step's before (with K cut, at every step)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    with _rule(rule) as gm:
+        plans = [gm.block_plan(rows, k, n, tile, 2) for k, n in ((H, WIDTH), (H, WIDTH), (WIDTH, H))]
+    _, gid, mid, visits = (np.asarray(a) for a in gm.visit_plan(jnp.asarray(sizes), rows, tile))
+    visits = int(visits)
+    turns = lambda ids: int(visits > 0) + int((ids[1:visits] != ids[:visits - 1]).sum())  # noqa: E731
+    out = {"visits": visits, "experts_hit": int((np.asarray(sizes) > 0).sum()), "weights": 0, "lhs": 0, "out": 0, "blocks": []}
+    for p in plans:
+        whole = p["tiles_k"] == 1
+        out["weights"] += (turns(gid) if whole else visits * p["tiles_k"]) * p["tiles_n"] * p["rhs_block_bytes"]
+        out["lhs"] += (turns(mid) if whole else visits * p["tiles_k"]) * p["tiles_n"] * p["tm"] * p["tk"] * 2
+        out["out"] += turns(mid) * p["tiles_n"] * p["tm"] * p["tn"] * 2
+        out["blocks"].append(f"{p['tm']}x{p['tk']}x{p['tn']}")
+    out["once"] = out["experts_hit"] * 3 * H * WIDTH * 2  # the hit experts' weights read once
+    return out
+
+
+def product_variants():
+    """The module docstring's ``products/`` variants: ``(fn, shapes, "products")`` with the rule in the name."""
+    import jax
+    import jax.numpy as jnp
+
+    from perceiver_io_tpu.core import moe
+
+    bf = jnp.bfloat16
+    out = {}
+    for step in ((False, True) if GEOM == "ling" else (False,)):
+        rows, tile = pass_rows_and_tile(step)
+
+        def products(rule, tile=tile):
+            def run(xs, sizes, w1, w3, w2):
+                with _rule(rule) as gm:  # the function under the jit: a cached trace would keep the rule it was traced with
+                    mm = lambda a, w: gm.grouped_matmul.__wrapped__(a, w, sizes, tm=tile)  # noqa: E731
+                    return mm(moe._silu_gate(mm(xs, w1), mm(xs, w3), bf), w2)
+            return run
+
+        shapes = (jax.ShapeDtypeStruct((rows, H), bf), jax.ShapeDtypeStruct((EXPERTS,), jnp.int32),
+                  *(jax.ShapeDtypeStruct(s, bf) for s in ((EXPERTS, H, WIDTH), (EXPERTS, H, WIDTH), (EXPERTS, WIDTH, H))))
+        for rule in ("program", "parent", "column_1024"):
+            out[f"products/{'step' if step else 'chunk'}_m{rows}_tm{tile}/{rule}"] = (products(rule), shapes, "products")
+    return out
+
+
+def check_products(rule: str, tile: int, xs, sizes, w1, w2) -> dict:
+    """The up and the down product under a rule against XLA's ``ragged_dot`` in float32, on the live rows: the widest
+    gap as a share of the widest value (one rounding to bfloat16 is 2^-8 = 0.0039 of a value)."""
+    import jax
+    import jax.numpy as jnp
+
+    def gaps(xs, sizes, w1, w2):
+        live = (jnp.arange(xs.shape[0]) < sizes.sum())[:, None]
+        with _rule(rule) as gm:
+            mm = lambda a, w: gm.grouped_matmul.__wrapped__(a, w, sizes, tm=tile)  # noqa: E731
+            up, a = mm(xs, w1), xs[:, :WIDTH]  # an expert is narrower than the hidden size at every geometry
+            down = mm(a, w2)
+        want_up = jax.lax.ragged_dot(xs, w1, sizes, preferred_element_type=jnp.float32)
+        want_down = jax.lax.ragged_dot(a, w2, sizes, preferred_element_type=jnp.float32)
+        gap = lambda got, want: jnp.where(live, jnp.abs(got.astype(jnp.float32) - want), 0).max() / jnp.abs(jnp.where(live, want, 0)).max()  # noqa: E731
+        return gap(up, want_up), gap(down, want_down)
+
+    up, down = (float(g) for g in jax.jit(gaps)(xs, sizes, w1, w2))
+    return {"up_gap_share": up, "down_gap_share": down, "agrees": bool(up < 2 ** -7 and down < 2 ** -7)}
 
 
 def _onehot_kernel(offsets_ref, tile_ids_ref, row_tile_ids_ref, rows_ref, weights_ref, tokens_ref, y_ref, out_ref, *, tt):
@@ -660,7 +783,9 @@ def main(argv=None) -> int:
             operands = []
             for s in shapes:
                 key, k = jax.random.split(key)
-                if s.dtype == jnp.int32:
+                if s.dtype == jnp.int32 and kind == "products":
+                    operands.append(jnp.asarray(pass_sizes(step="/step_" in name)))
+                elif s.dtype == jnp.int32:
                     operands.append(jnp.asarray(group_sizes(skew) if kind == "kernel" else routing(s.shape[0])))
                 elif s.dtype == jnp.float32:  # a combine's weights differ pair by pair, so that a wrong pairing of row and weight shows
                     operands.append(jax.random.uniform(k, s.shape, jnp.float32, 0.1, 0.6) if kind == "combine"
@@ -672,6 +797,11 @@ def main(argv=None) -> int:
             label = name + ("/skewed" if skew else "")
             try:
                 run = jax.jit(fn)
+                if kind == "products":  # the products against XLA's own, before any timing
+                    rule, tile = name.rsplit("/", 1)[1], pass_rows_and_tile("/step_" in name)[1]
+                    checked = check_products(rule, tile, operands[0], operands[1], operands[2], operands[4])
+                    moved = fetched_bytes(np.asarray(operands[1]), operands[0].shape[0], tile, rule)
+                    print(f"{label}: against ragged_dot {checked}; fetches {moved}", flush=True)
                 if kind == "gqa_verify":
                     print(f"{label}: carries {carried_caches(run.lower(*operands).compile().as_text())}", flush=True)
                 jax.block_until_ready(run(*operands))
@@ -688,6 +818,8 @@ def main(argv=None) -> int:
                 top = trace.top(trace.totals_by_name(events), 8)
                 results[label] = {"device_ms": busy_ms, "top": [[n, 1e3 * s / args.iters] for n, s in top]}
                 print(f"{label}: {busy_ms:.4f} ms a call; {results[label]['top']}", flush=True)
+                if kind == "products":
+                    results[label].update(checked, fetches=moved)
                 if kind == "combine" and getattr(out, "shape", None) == (COMBINE_TOKENS, H):
                     combined[label] = np.asarray(out)
                     per_token = np.bincount(np.nonzero(np.asarray(operands[1]) < EXPERTS)[0], minlength=COMBINE_TOKENS)

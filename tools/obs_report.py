@@ -168,6 +168,16 @@ def render(run_dir: str, max_compile_rows: int = 20) -> str:
                 for r in plans
             ]
             lines.extend("  " + r for r in _table(rows, ["call", "route", "tile", "grid_steps", "one-hot GFLOP"]))
+        plans = next((e["moe_tiles"] for e in reversed(compiles) if e.get("moe_tiles")), [])
+        if plans:
+            lines.append("  the experts' grouped products:")
+            rows = [
+                [f"{r['m']} x {r['k']} x {r['n']}", f"{r['tm']} x {r['tk']} x {r['tn']}", f"{r['tiles_k']} x {r['tiles_n']}",
+                 f"{r['rhs_block_bytes'] / 1e6:.2f}", f"{r['vmem_bytes'] / 1e6:.1f}", "yes" if r["weights_resident"] else "no"]
+                for r in plans
+            ]
+            header = ["product", "blocks", "k x n tiles", "weight block MB", "VMEM MB", "weights resident"]
+            lines.extend("  " + r for r in _table(rows, header))
         plans = next((e["mlp_gelu"] for e in reversed(compiles) if e.get("mlp_gelu")), [])
         if plans:
             lines.append("  the MLPs' exact GELU under differentiation:")
