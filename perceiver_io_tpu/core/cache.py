@@ -745,3 +745,100 @@ def init_ragged_window_kv_cache(batch_size: int, heads: int, window: int, slack:
         length=jnp.zeros((batch_size,), jnp.int32),
         window=window,
     )
+
+
+# ---------------------------------------------------------------------------
+# latent attention that chooses its keys, and latent attention behind a window
+# ---------------------------------------------------------------------------
+#
+# A stack whose full layers run learned sparse attention (``core/dsa.py``) over
+# latent attention and whose window layers run a second latent attention keeps
+# three cache kinds in one generator state: a full layer's growing
+# :class:`LatentCache` of joint rows **and**, beside it, the growing cache of its
+# indexer's keys (one key of ``index_head_dim`` channels a token; the class that
+# holds it is :class:`LatentCache` itself, its "joint row" the one key: no
+# second class for an array and a length), both in one
+# :class:`IndexedLatentCache`; and a window layer's :class:`LatentRingCache`.
+
+
+@struct.dataclass
+class IndexedLatentCache:
+    """A full layer's two growing caches: ``latent`` the joint rows the
+    attention reads (B, capacity, kv_lora_rank + rope), ``index`` the indexer's
+    rotated keys (B, capacity, index_head_dim) the selection scores. Both are
+    written at the same position, so one length serves."""
+
+    latent: LatentCache
+    index: LatentCache
+
+    @property
+    def length(self) -> jnp.ndarray:
+        return self.latent.length
+
+    @property
+    def capacity(self) -> int:
+        return self.latent.capacity
+
+    def append(self, rows: jnp.ndarray, keys: jnp.ndarray) -> "IndexedLatentCache":
+        return IndexedLatentCache(latent=self.latent.append(rows), index=self.index.append(keys))
+
+
+def init_indexed_latent_cache(batch_size: int, capacity: int, width: int, index_width: int, dtype=jnp.float32) -> IndexedLatentCache:
+    return IndexedLatentCache(latent=init_latent_cache(batch_size, capacity, width, dtype),
+                              index=init_latent_cache(batch_size, capacity, index_width, dtype))
+
+
+@struct.dataclass
+class LatentRingCache:
+    """The cache of a latent attention behind a sliding window: a ring of
+    joint rows. ``rows`` is (B, slots, width) with ``slots >= window``; the
+    token at position ``p`` lives in slot ``p % slots``, so a write at
+    ``length % slots`` overwrites a position that left the window ``slots -
+    window`` steps ago or more. ``slots`` may be more than ``window`` (whole
+    sublane tiles of the cache's dtype, so that the loop carries the ring
+    row-major): what a slot holds is known from where it lies, and
+    :meth:`visible` hides a slot whose position is out of the window or was
+    never written. ``length`` is a traced int32 scalar, the tokens seen so
+    far (one for the batch)."""
+
+    rows: jnp.ndarray
+    length: jnp.ndarray
+    window: int = struct.field(pytree_node=False)
+
+    @property
+    def capacity(self) -> int:
+        return self.rows.shape[1]
+
+    def fill(self, rows: jnp.ndarray, n: int) -> "LatentRingCache":
+        """The ring after a prompt pass of ``n`` positions over an empty one:
+        ``rows`` (B, min(n, slots), width) are the prompt's last positions, each put in its slot."""
+        slots = self.capacity
+        if rows.shape[1] != min(n, slots):
+            raise ValueError(f"a prompt of {n} positions fills a ring of {slots} with its last {min(n, slots)}, got {rows.shape[1]}")
+        rows = rows.astype(self.rows.dtype)
+        if n <= slots:
+            placed = lax.dynamic_update_slice(self.rows, rows, (0, 0, 0))
+        else:  # position p sits at slot p % slots: the rows from n - slots on, turned by (n - slots) % slots
+            placed = jnp.roll(rows, (n - slots) % slots, axis=1)
+        return LatentRingCache(rows=placed, length=jnp.asarray(n, jnp.int32), window=self.window)
+
+    def append(self, row: jnp.ndarray) -> "LatentRingCache":
+        """Write one token a row, ``row`` (B, 1, width), at ``length % slots``."""
+        if row.shape[1] != 1:
+            raise ValueError(f"a ring takes one token a step, got {row.shape[1]}")
+        placed = lax.dynamic_update_slice(self.rows, row.astype(self.rows.dtype), (0, self.length % self.capacity, 0))
+        return LatentRingCache(rows=placed, length=self.length + 1, window=self.window)
+
+    def visible(self) -> jnp.ndarray:
+        """(slots,) bool: the slots the query at position ``length - 1`` (the
+        token last written) sees: slot ``j`` holds position ``t - (t - j) % slots``
+        with ``t = length - 1``, seen where it is not negative and within ``window`` of ``t``."""
+        t = self.length - 1
+        age = (t - jnp.arange(self.capacity, dtype=jnp.int32)) % self.capacity
+        return (age < self.window) & (age <= t)
+
+
+def init_latent_ring_cache(batch_size: int, window: int, slots: int, width: int, dtype=jnp.float32) -> LatentRingCache:
+    if slots < window:
+        raise ValueError(f"a ring of {slots} slots cannot hold a window of {window}")
+    return LatentRingCache(rows=jnp.zeros((batch_size, slots, width), dtype), length=jnp.zeros((), jnp.int32), window=window)

@@ -1842,6 +1842,11 @@ def make_instrumented_generate_fn(
     m_lam = registry.gauge("diff_lam_mean") if yoco_taps else None
     gmu_taps = probes and "gmu.*" in decoder.tap_scopes
     m_gmu_rms = registry.gauge("gmu_memory_rms") if gmu_taps else None
+    # latent attention that chooses its keys (``core/dsa.py`` taps ``dsa.select`` a full layer, in the pass and in a step):
+    # the keys a query keeps, and the share of them that lies within the window layers' window
+    dsa_taps = probes and "dsa.*" in decoder.tap_scopes
+    m_dsa_selected = registry.gauge("dsa_selected_keys_mean") if dsa_taps else None
+    m_dsa_recent = registry.gauge("dsa_recent_share") if dsa_taps else None
     # a model that drafts for itself (a ``speculative`` decoder): a step yields 0 to 2 tokens a row, every
     # step is host-timed as one TPOT sample, and the ``spec.step`` taps keep the drafting's books
     self_drafting = getattr(decoder, "speculative", False)
@@ -2009,6 +2014,13 @@ def make_instrumented_generate_fn(
                 if gmu_taps:
                     health_row["gmu_memory_rms"] = round(sum(float(h["gmu_memory_rms_sum"]) for h in hh) / max(sum(int(h["gmu_sites"]) for h in hh), 1), 6)
                     m_gmu_rms.set(health_row["gmu_memory_rms"])
+                if dsa_taps:
+                    sites = max(sum(int(h["dsa_sites"]) for h in hh), 1)  # every full layer of the pass and of every step taps once
+                    health_row["dsa_selected_mean"] = round(sum(float(h["dsa_selected_sum"]) for h in hh) / sites, 3)
+                    health_row["dsa_selected_max"] = max(int(h["dsa_selected_max"]) for h in hh)
+                    health_row["dsa_recent_share"] = round(sum(float(h["dsa_recent_share_sum"]) for h in hh) / sites, 6)
+                    m_dsa_selected.set(health_row["dsa_selected_mean"])
+                    m_dsa_recent.set(health_row["dsa_recent_share"])
                 if spec_taps:
                     drafts, accepted = (sum(int(h[k]) for h in hh) for k in ("drafts", "accepted"))
                     m_spec_drafts.inc(drafts)

@@ -2,7 +2,7 @@
 ``x + Mix(Norm(x))`` then ``x + FFN(Norm(x))``, a final norm and a head, untied
 or the embedding table itself (``docs/decoder-lm.md``); the norm is an RMSNorm
 or, where the configuration gives ``layer_norm_eps``, a LayerNorm with scale and
-bias. One class, eight published families, told apart by the configuration (a third, K-EXAONE, puts the first's expert layer
+bias. One class, nine published families, told apart by the configuration (a third, K-EXAONE, puts the first's expert layer
 on the second's skeleton and adds the drafting module below; a fourth,
 LongCat-Flash, changes the block itself: **the shortcut-connected block**,
 further down):
@@ -52,8 +52,24 @@ further down):
   layer's key and value projections over the prompt, and everything above at
   the last position alone (``config.prompt_layers``, :func:`prefill`).
 
-Unlike the Perceiver models every position passes the whole stack (the last
-family's prompt pass apart), so there is no latent window. The model meets :mod:`perceiver_io_tpu.generation`
+- **dots3-note** (``layer_types`` of ``"full_attention"`` and
+  ``"sliding_attention"`` **with the ``swa_*`` sizes given**, which makes both
+  entries *latent* attentions; ``core/dsa.py``): a full layer is the first
+  family's latent attention **over the keys a lightning indexer selects**
+  (``index_n_heads`` heads of ``index_head_dim`` score every earlier token,
+  ``I = sum_j w_j relu(q_j . k)``, and the softmax runs over the
+  ``index_topk`` best, found exactly; the indexer's one key a token is cached
+  beside the latent row: an :class:`IndexedLatentCache`, two arrays that grow), a
+  sliding layer a **second latent attention of other sizes** (rank, heads, head
+  widths and rotary base its own) behind ``sliding_window_size`` positions, its
+  cache a :class:`LatentRingCache` of latent rows: **three cache kinds in one
+  generator state**. The prompt pass runs the expanded attention under the
+  selection's mask, a step the absorbed attention over the gathered rows; both
+  attentions rescale their normed latents and gate their heads; a leading dense
+  layer, then sigmoid-routed experts with a shared expert.
+
+Unlike the Perceiver models every position passes the whole stack (the
+Phi-4-mini-flash family's prompt pass apart), so there is no latent window. The model meets :mod:`perceiver_io_tpu.generation`
 through :meth:`DecoderLanguageModel.generation_decoder`.
 
 **The multi-token-prediction module** (``num_nextn_predict_layers`` 1; the
@@ -102,10 +118,12 @@ import jax.numpy as jnp
 from jax import lax
 
 from perceiver_io_tpu.core.cache import (
-    DeltaState, KVCache, LatentCache, RaggedKVCache, RaggedWindowKVCache, RecurrentState, RetentionState, WindowKVCache,
-    init_kv_cache, init_latent_cache, init_ragged_kv_cache, init_ragged_window_kv_cache, init_window_kv_cache,
+    DeltaState, IndexedLatentCache, KVCache, LatentCache, LatentRingCache, RaggedKVCache, RaggedWindowKVCache, RecurrentState,
+    RetentionState, WindowKVCache, init_indexed_latent_cache, init_kv_cache, init_latent_cache, init_latent_ring_cache,
+    init_ragged_kv_cache, init_ragged_window_kv_cache, init_window_kv_cache,
 )
 from perceiver_io_tpu.core.diff_attention import DifferentialAttention
+from perceiver_io_tpu.core.dsa import SparseLatentAttention, WindowLatentAttention, window_sizes
 from perceiver_io_tpu.core.gqa import GroupedQueryAttention, verify_fused
 from perceiver_io_tpu.core.kda import KimiDeltaAttention
 from perceiver_io_tpu.core.mla import VIEWS, MultiHeadLatentAttention, expand_views
@@ -185,7 +203,15 @@ class DecoderLanguageModelConfig:
     published keys of the Ling 3.0 family); a ``"latent_attention"`` entry is
     the latent attention that ``layer_types`` ``None`` gives every layer.
     ``q_lora_rank`` ``None`` and ``mla_head_gate`` are that attention's
-    (``core/mla.py``)."""
+    (``core/mla.py``).
+
+    With the ``swa_*`` sizes and ``sliding_window_size`` given (the published
+    keys of the dots3-note family) ``"full_attention"`` and
+    ``"sliding_attention"`` entries are latent attentions (``core/dsa.py``): a
+    full layer of the configuration's own sizes, a sliding layer of the ``swa_*``
+    ones behind the window (position ``t`` sees ``t - sliding_window_size < s <=
+    t``); ``index_n_heads``, ``index_head_dim`` and ``index_topk`` (DeepSeek-V3.2's
+    keys) give every full layer an indexer and a top-``index_topk`` selection."""
 
     vocab_size: int = 129280
     hidden_size: int = 7168
@@ -236,6 +262,34 @@ class DecoderLanguageModelConfig:
     layer_norm_eps: Optional[float] = None
     differential_attention: bool = False
     mamba_inner_norms: bool = True
+    # the dots3-note family (``core/dsa.py``): with the ``swa_*`` sizes given, ``layer_types`` of ``"full_attention"`` and
+    # ``"sliding_attention"`` select *latent* attention, a full layer the configuration's own sizes and a sliding layer
+    # the ``swa_*`` ones behind ``sliding_window_size`` positions; with ``index_topk`` a full layer chooses its keys
+    index_n_heads: Optional[int] = None
+    index_head_dim: Optional[int] = None
+    index_topk: Optional[int] = None
+    swa_q_lora_rank: Optional[int] = None
+    swa_kv_lora_rank: Optional[int] = None
+    swa_num_attention_heads: Optional[int] = None
+    swa_qk_nope_head_dim: Optional[int] = None
+    swa_qk_rope_head_dim: Optional[int] = None
+    swa_v_head_dim: Optional[int] = None
+    swa_rope_theta: Optional[float] = None
+    sliding_window_size: Optional[int] = None
+
+    @property
+    def windowed_latent(self) -> bool:
+        """Whether ``"full_attention"`` and ``"sliding_attention"`` entries of ``layer_types`` are latent attentions (the ``swa_*`` sizes say so)."""
+        return self.swa_kv_lora_rank is not None
+
+    def latent_kind(self, kind: Optional[str]) -> bool:
+        """Whether a layer of ``kind`` is a latent attention (``absorb`` is its step), of whichever cache."""
+        return _latent(kind) or (self.windowed_latent and kind in _ATTENTION_TYPES)
+
+    @property
+    def latent_ring_slots(self) -> int:
+        """The slots of a window layer's ring of latent rows: the window, up to whole sublane tiles of any cache dtype."""
+        return -(-self.sliding_window_size // 32) * 32
 
     @property
     def memory_layer(self) -> Optional[int]:
@@ -267,7 +321,7 @@ class DecoderLanguageModelConfig:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             if len(self.layer_types) != self.num_hidden_layers or set(self.layer_types) - set(_LAYER_TYPES):
                 raise ValueError(f"layer_types: one of {_LAYER_TYPES} for each of the {self.num_hidden_layers} layers")
-            attends = set(self.layer_types) & (set(_ATTENTION_TYPES) | {_RETENTION})
+            attends = set() if self.windowed_latent else set(self.layer_types) & (set(_ATTENTION_TYPES) | {_RETENTION})
             if attends and not (self.num_key_value_heads and self.head_dim):
                 raise ValueError("attention and retention entries of layer_types need num_key_value_heads and head_dim")
             if _RETENTION in attends and self.head_dim % 2:
@@ -295,6 +349,19 @@ class DecoderLanguageModelConfig:
                 raise ValueError("gmu and cross_attention layers, and they alone, stand above the layer that owns the shared cache")
             if self.differential_attention and self.num_key_value_heads % 2:
                 raise ValueError("differential attention pairs the heads: an even number of key-value heads")
+        swa = ("swa_q_lora_rank", "swa_kv_lora_rank", "swa_num_attention_heads", "swa_qk_nope_head_dim", "swa_qk_rope_head_dim",
+               "swa_v_head_dim", "swa_rope_theta", "sliding_window_size")
+        if any(getattr(self, key) is not None for key in swa):
+            if (any(getattr(self, key) is None for key in swa) or self.layer_types is None or set(self.layer_types) - set(_ATTENTION_TYPES)
+                    or self.differential_attention or self.num_nextn_predict_layers or self.sliding_window_size < 1):
+                raise ValueError("window latent attention: every swa_* size and sliding_window_size, under layer_types of "
+                                 "full_attention and sliding_attention alone, with no differential form and no drafting module")
+        if any(getattr(self, key) is not None for key in ("index_n_heads", "index_head_dim", "index_topk")):
+            if (not (self.index_n_heads and self.index_head_dim and self.index_topk) or not self.windowed_latent
+                    or self.q_lora_rank is None or self.qk_rope_head_dim > self.index_head_dim or self.qk_rope_head_dim % 2):
+                raise ValueError("an indexer (index_n_heads, index_head_dim, index_topk, all three): built for the full layers of a "
+                                 "stack whose layer_types select latent attention, its queries read off a query latent, its rotary "
+                                 "on an even number of channels no wider than its head")
         if self.differential_attention and (self.layer_types is None or set(self.layer_types) & {_RETENTION, _KDA, _LATENT}
                                             or self.num_nextn_predict_layers):
             raise ValueError("differential attention: the grouped-query layer types' form, with no drafting module")
@@ -353,6 +420,10 @@ class DecoderBlock(nn.Module):
         self.attn_norm = _norm(c, **kw)
         if _latent(self.layer_type):
             self.attn = MultiHeadLatentAttention(c, **kw)
+        elif c.windowed_latent and self.layer_type == "sliding_attention":  # a second latent attention, of the ``swa_*`` sizes
+            self.attn = WindowLatentAttention(window_sizes(c), window=c.sliding_window_size, **kw)
+        elif c.windowed_latent:  # a full layer: the configuration's own sizes, over the keys an indexer selects where it has one
+            self.attn = (SparseLatentAttention if c.index_topk else MultiHeadLatentAttention)(c, **kw)
         elif self.layer_type == "mamba":
             self.mixer = MambaMixer(c, memory=self.hands_on_memory, **kw)
         elif self.layer_type == _GMU:
@@ -397,7 +468,10 @@ class DecoderBlock(nn.Module):
         a, rows = self.attn.expand(self.attn_norm(x), pos)
         if self.layer_type == "sliding_attention":
             with jax.named_scope("chunk_io"):  # what a window layer hands on to its cache
-                rows = tuple(r[:, :, -self.config.sliding_window:] for r in rows)
+                if self.config.windowed_latent:  # latent rows (B, N, width): the last positions a ring holds
+                    rows = rows[:, -self.config.latent_ring_slots:]
+                else:
+                    rows = tuple(r[:, :, -self.config.sliding_window:] for r in rows)
         return _residual(x, a), rows
 
     def feed_forward(self, x):
@@ -421,7 +495,7 @@ class DecoderBlock(nn.Module):
             if self.hands_on_memory:  # the advanced state and, beside it, what the layers above read this step
                 return self.feed_forward(_residual(x, a[0])), (cache, a[1])
             return self.feed_forward(_residual(x, a)), cache
-        one_token = self.attn.absorb if _latent(self.layer_type) else self.attn.step
+        one_token = self.attn.absorb if self.config.latent_kind(self.layer_type) else self.attn.step
         a, cache = one_token(self.attn_norm(x), cache, pos)
         return self.feed_forward(_residual(x, a)), cache
 
@@ -772,6 +846,10 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
     if any(_latent(kind) for kind in kinds):  # latent attention: the weight views its expanded pass takes, once and not a chunk
         with jax.named_scope("prefill"):
             params = {**params, VIEWS: expand_views(params["params"], c, model.dtype)}
+    elif c.windowed_latent:  # the full layers' alone: a window layer's ``w_uq`` has sizes of its own and is cut as it lies
+        with jax.named_scope("prefill"):
+            full = (f"layer_{i}" for i, kind in enumerate(kinds) if kind == "full_attention")
+            params = {**params, VIEWS: {name: expand_views(params["params"][name], c, model.dtype) for name in full}}
     scoped = functools.partial(_scoped, model, params)
 
     x = scoped("embed", input_ids)
@@ -796,6 +874,8 @@ def prefill(model: DecoderLanguageModel, params, input_ids, keep_hidden: bool = 
             memory = memory.reshape(b, *memory.shape[2:])
         if _latent(kinds[i]):
             cache_rows.append(_batch_rows(rows, b, n))
+        elif c.latent_kind(kinds[i]):  # latent rows (and, of a layer that selects, its index keys); a window layer's last positions
+            cache_rows.append(jax.tree.map(lambda r: _batch_rows(r, b, r.shape[2]), rows))
         elif kinds[i] in _STATEFUL:  # (chunks, rows a chunk, ...): the rows' states, as they leave the kernel
             cache_rows.append(jax.tree.map(lambda r: r.reshape(b, *r.shape[2:]), rows))
         else:  # (chunks, rows a chunk, Hkv, positions, D): a key-value head is a row of the cache
@@ -840,7 +920,9 @@ class _Decoder:
     the one-token step, and the state they hand each other. The window is the
     tuple of the layers' caches, each of its layer's kind (latent; or, under
     grouped-query attention, a growing :class:`KVCache` for a full layer and a
-    :class:`WindowKVCache` ring for a window layer, side by side). Nothing the
+    :class:`WindowKVCache` ring for a window layer, side by side; or, where both
+    kinds are latent attentions, an :class:`IndexedLatentCache` for a full layer
+    that selects its keys and a :class:`LatentRingCache` for a window layer). Nothing the
     generator owns slides: a growing cache's capacity is the prompt plus the
     new tokens, which must fit ``max_position_embeddings``, and a ring
     overwrites the position that left its window.
@@ -872,12 +954,21 @@ class _Decoder:
         kinds = c.layer_types or ()
         return ((("moe.*",) if sparse else ()) + ("spec.*",) + (("ssm.*",) if "mamba" in kinds else ())
                 + (("ret.*",) if _RETENTION in kinds else ()) + (("kda.*",) if _KDA in kinds else ())
-                + (("yoco.*",) if _CROSS in kinds else ()) + (("gmu.*",) if _GMU in kinds else ()))
+                + (("yoco.*",) if _CROSS in kinds else ()) + (("gmu.*",) if _GMU in kinds else ())
+                + (("dsa.*",) if c.index_topk else ()))
 
     def _caches(self, rows, batch: int, n: int, max_new_tokens: int, cache_dtype):
         c = self.model.config
         def cache_of(kind, kept):
             if _latent(kind):
+                return init_latent_cache(batch, n + max_new_tokens, kept.shape[-1], cache_dtype).append(kept)
+            if c.windowed_latent and kind == "sliding_attention":  # a ring of latent rows
+                ring = init_latent_ring_cache(batch, c.sliding_window_size, c.latent_ring_slots, kept.shape[-1], cache_dtype)
+                return ring.fill(kept, n)
+            if c.windowed_latent and c.index_topk:  # the latent rows and, beside them, the indexer's keys: both grow
+                rows, keys = kept
+                return init_indexed_latent_cache(batch, n + max_new_tokens, rows.shape[-1], keys.shape[-1], cache_dtype).append(rows, keys)
+            if c.windowed_latent:
                 return init_latent_cache(batch, n + max_new_tokens, kept.shape[-1], cache_dtype).append(kept)
             if kind == "mamba":  # the state as the scan left it (float32), the window in the caches' dtype
                 return RecurrentState(conv=kept.conv.astype(cache_dtype), ssm=kept.ssm)
@@ -991,7 +1082,7 @@ class _Decoder:
 
     def health(self, logits, window):
         # the occupancy gauge reads a cache that grows: a ring is full from its window on, a recurrent state has one size
-        fixed = (WindowKVCache, RaggedWindowKVCache, RecurrentState, RetentionState, DeltaState)
+        fixed = (WindowKVCache, RaggedWindowKVCache, RecurrentState, RetentionState, DeltaState, LatentRingCache)
         grows = next((cache for cache in window[0] if not isinstance(cache, fixed)), window[0][0])
         # a stack of retention states alone: nothing fills
         return probes.decode_health(logits, None if isinstance(grows, RetentionState) else grows, jnp.zeros((), jnp.int32))
@@ -1003,6 +1094,31 @@ class _Decoder:
         # how a prompt chunk's expert rows get back to their tokens (a step of few tokens takes the dense path)
         router_width = c.n_routed_experts + c.zero_expert_num
         moe = {"moe_combine": grouped_combine(c.n_held_experts, router_width)}
+        if c.windowed_latent:  # three cache kinds: latent rows and index keys that grow, rings of latent rows
+            n_full, n_window = c.layer_types.count("full_attention"), c.layer_types.count("sliding_attention")
+            capacity = prompt_len + max_new_tokens
+            row_bytes = (c.kv_lora_rank + c.qk_rope_head_dim) * itemsize
+            ring_row_bytes = (c.swa_kv_lora_rank + c.swa_qk_rope_head_dim) * itemsize
+            row = {
+                "latent_cache_row_bytes": row_bytes,
+                "latent_cache_capacity": capacity,
+                "latent_cache_layers": n_full,
+                "latent_cache_bytes": batch * capacity * row_bytes * n_full,
+                "latent_ring_layers": n_window,
+                "latent_ring_row_bytes": ring_row_bytes,
+                "latent_ring_window": c.sliding_window_size,
+                "latent_ring_slots": c.latent_ring_slots,
+                "latent_ring_bytes": batch * c.latent_ring_slots * ring_row_bytes * n_window,
+                **moe,
+            }
+            if c.index_topk:
+                row.update(index_cache_row_bytes=c.index_head_dim * itemsize,
+                           index_cache_bytes=batch * capacity * c.index_head_dim * itemsize * n_full,
+                           index_topk=c.index_topk, index_n_heads=c.index_n_heads,
+                           # what a step reads of a full layer's caches a row: every index key and the chosen latent rows, against every latent row
+                           dsa_step_bytes_a_row=capacity * c.index_head_dim * itemsize + min(c.index_topk, capacity) * row_bytes,
+                           dense_step_bytes_a_row=capacity * row_bytes)
+            return row
         if c.layer_types is not None and not set(c.layer_types) & {_KDA, _LATENT}:
             row_bytes = 2 * (c.num_key_value_heads or 0) * (c.head_dim or 0) * itemsize  # a token's keys and values in one layer
             # the module's block keeps a cache of its own kind beside the stack's

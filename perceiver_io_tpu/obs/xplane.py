@@ -301,6 +301,12 @@ LAYER_SCOPES = frozenset({
     # every layer under it opens its own scope, so it names a table row only for what none of them claims; it is there for
     # the compiled text, where tests/test_tpu_compile.py finds by it what the pass runs at one position a row
     "gmu", "prefill/last",
+    # latent attention that chooses its keys (core/dsa.py): the indexer's projections, its scores, the selection and the
+    # attention over the selected keys in the prompt pass; a step's score, selection, gather and attention; what the layer
+    # shares with plain latent attention stays under ``mla/expand`` and ``mla/absorb``. ``mla/window`` is the second latent
+    # attention behind its window in the pass, ``mla/window_step`` a step over its ring
+    "dsa/index", "dsa/score", "dsa/select", "dsa/attend", "dsa/step_score", "dsa/step_select", "dsa/step_gather",
+    "dsa/step_attend", "mla/window", "mla/window_step",
 })
 # flax module names that mark a layer no scope is opened for
 MODULE_LAYERS = {"q_proj": "qkv_proj", "k_proj": "qkv_proj", "v_proj": "qkv_proj", "o_proj": "o_proj"}
@@ -312,7 +318,8 @@ CLOSED_LAYERS = frozenset({"mlp", "dense_mlp", "mla/expand", "mla/absorb", "attn
                            "ret/proj", "ret/gate", "ret/chunk", "ret/update", "ret/out",
                            "kda/proj", "kda/conv", "kda/gate", "kda/chunk", "kda/update", "kda/out",
                            "diff/proj", "diff/flash", "diff/step", "diff/combine", "yoco/kv", "yoco/cross",
-                           "gmu"})
+                           "gmu", "dsa/index", "dsa/score", "dsa/select", "dsa/attend", "dsa/step_score", "dsa/step_select",
+                           "dsa/step_gather", "dsa/step_attend", "mla/window", "mla/window_step"})
 # parts of a name stack that are no scope: what a transform or a loop wraps around the names. A transform
 # wraps the first scope opened under it (``transpose(jvp(loss))`` is the scope ``loss``); ``jit`` wraps the name
 # of a function, which is no scope

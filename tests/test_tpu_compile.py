@@ -1033,6 +1033,71 @@ def test_the_ling_cells_generator_carries_its_delta_states_in_place(one_chip, mo
 # ------------------------------------------ the MLP's exact GELU: evaluated once a layer and kept
 
 
+# ------------------------------------------ dots3-note: latent attention that chooses its keys, at the cell's sizes
+
+
+DOTS3_TOKENS, DOTS3_CHUNK, DOTS3_TOPK, DOTS3_WINDOW, DOTS3_HEADS_A_PASS = 32768, 2048, 2048, 513, 8
+
+
+@pytest.mark.parametrize("kernel", ["index_scores", "select", "masked_flash", "window_flash"])
+def test_the_sparse_latent_attentions_kernels_lower(one_chip, mosaic, kernel):
+    """The four kernels of ``ops/dsa.py`` at the shapes of ``dots3-ep8-decode-b4-p32k`` (one row of 32 768 tokens, a
+    chunk of 2048 queries, 8 heads a pass): a one-lane slice broadcast over a tile, a bitcast, an int8 tile written
+    into an aliased buffer at a prefetched offset and a (1024, 1024) int8 mask tile are what interpret mode cannot refuse."""
+    from perceiver_io_tpu.ops import dsa
+
+    n, h = DOTS3_TOKENS, DOTS3_HEADS_A_PASS
+    shape = lambda dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)  # noqa: E731
+    if kernel == "index_scores":
+        text = _compile(lambda q, k, w, first: dsa.index_scores(q, k, w, 64, first), shape((1, DOTS3_CHUNK, 64 * 128)), shape((1, n, 128)),
+                        shape((1, DOTS3_CHUNK, 64), jnp.float32), shape((), jnp.int32))
+        name = dsa.index_scores_kernel_name(DOTS3_CHUNK, n, 64)
+    elif kernel == "select":
+        text = _compile(lambda mask, scores, first: dsa.select_mask_into(mask, scores, DOTS3_TOPK, first), shape((1, n, n), jnp.int8),
+                        shape((1, DOTS3_CHUNK, n), jnp.float32), shape((), jnp.int32))
+        name = dsa.select_kernel_name(DOTS3_CHUNK, n, DOTS3_TOPK)
+    elif kernel == "masked_flash":
+        text = _compile(lambda a, b, c, d, m: dsa.flash_attention_mla_masked(a, b, c, d, m, h, sm_scale=192 ** -0.5), shape((1, n, h * 128)),
+                        shape((1, n, h * 64)), shape((1, n, h * 256)), shape((1, n, 64)), shape((1, n, n), jnp.int8))
+        name = dsa.masked_flash_kernel_name(n, h)
+    else:
+        text = _compile(lambda q, a, b, v: dsa.flash_attention_mla_window(q, a, b, v, h, DOTS3_WINDOW, sm_scale=0.0625), shape((1, n, h * 256)),
+                        shape((1, n, h * 128)), shape((1, n, h * 128)), shape((1, n, h * 128)))
+        name = dsa.window_flash_kernel_name(n, h, DOTS3_WINDOW)
+    assert "tpu_custom_call" in text and name in text
+
+
+def test_the_dots3_cells_generator_fits_the_chip_and_carries_three_cache_kinds_in_place(one_chip, mosaic, monkeypatch):
+    """``dots3-ep8-decode-b4-p32k`` as the benchmark builds it (4.09B bfloat16 parameters, 4 prompts of 32 768 tokens,
+    256 new tokens), compiled for a described v5e: under the 14.9 GB the other cells are held to (14.56 GB here at 8
+    heads a pass); the four kernels of the prompt pass each in one geometry and none of them in the decode loop; the
+    loop carries two latent caches ``bf16[4,33024,576]``, two index caches ``bf16[4,33024,128]`` and three rings
+    ``bf16[4,544,1088]`` row-major, written by ``dynamic-update-slice`` in place, and nothing in its body copies or turns
+    one; the selection's mask is never an XLA array of more than a chunk's scores beside it (no ``[1,32768,32768]``
+    float array anywhere); a step selects with one ``top-k`` / sort a full layer and gathers 2048 rows of 576."""
+    import re
+
+    compiled = _cell_generator("dots3-ep8-decode-b4-p32k", "dots3", one_chip, monkeypatch)
+    m = compiled.memory_analysis()
+    assert 8.17e9 < m.argument_size_in_bytes < 8.18e9  # the weights and the prompts
+    total = _device_bytes(compiled)
+    assert total < 14.9e9, f"{total / 1e9:.2f} GB"
+    text = compiled.as_text()
+    assert set(re.findall(r"dsa_index_scores_q\d+_kv\d+_h\d+", text)) == {"dsa_index_scores_q2048_kv32768_h64"}
+    assert set(re.findall(r"dsa_select_q\d+_kv\d+_k\d+", text)) == {"dsa_select_q2048_kv32768_k2048"}
+    assert set(re.findall(r"flash_mla_masked_fwd_q\d+_kv\d+_h\d+", text)) == {"flash_mla_masked_fwd_q32768_kv32768_h8"}
+    assert set(re.findall(r"flash_mla_window_fwd_q\d+_kv\d+_h\d+_w\d+", text)) == {"flash_mla_window_fwd_q32768_kv32768_h8_w513"}
+    assert not re.search(r"(f32|bf16|s32)\[1,32768,32768\]", text)  # the score matrix of a row is never whole
+    latent, index, ring = r"bf16\[4,33024,576\]", r"bf16\[4,33024,128\]", r"bf16\[4,544,1088\]"
+    result = lambda ins: ins.line.split(" = ", 1)[1].split(f" {ins.opcode}(", 1)[0]  # noqa: E731
+    loop, body = _loop_around(text, lambda loop, inside: re.search(ring, result(loop)))  # the decode loop: the one that carries a ring
+    for shape, count in ((latent, 2), (index, 2), (ring, 3)):
+        assert len(re.findall(shape + r"\{2,1,0[:}]", result(loop))) == count and not re.search(shape + r"\{(?!2,1,0)", result(loop))
+        moved = [i for i in body if i.opcode in ("copy", "transpose") and re.search(shape, result(i))]
+        assert not moved, [i.name for i in moved]
+    assert not any(i.opcode == "custom-call" and ("dsa_" in i.name or "flash_mla" in i.name) for i in body)  # the prompt pass's kernels
+
+
 @pytest.mark.parametrize("mesh_shape", [None, (2, 2)], ids=["one_chip", "data_x_fsdp"])
 def test_mlp_gelu_is_not_expanded_again_inside_the_gemms(four_chips, one_chip, mesh_shape):
     """An ``MLP`` with its residual, forward and backward, at the hidden shape
